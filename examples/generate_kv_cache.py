@@ -21,13 +21,11 @@ Run:  python examples/generate_kv_cache.py [nranks]
 import os
 import sys
 
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 os.environ.setdefault("JAX_ENABLE_X64", "1")
 
 import jax
-
-if os.environ.get("MPI4TORCH_TPU_REAL_DEVICES") != "1":
-    jax.config.update("jax_platforms", "cpu")
 import jax.numpy as jnp
 import numpy as np
 

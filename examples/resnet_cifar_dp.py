@@ -18,12 +18,10 @@ Run:  python examples/resnet_cifar_dp.py [nranks] [steps]
 import os
 import sys
 
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax
-
-if os.environ.get("MPI4TORCH_TPU_REAL_DEVICES") != "1":
-    jax.config.update("jax_platforms", "cpu")
 
 import jax.numpy as jnp
 import numpy as np

@@ -20,13 +20,11 @@ Run:  python examples/tensor_parallel_mlp.py [nranks]
 import os
 import sys
 
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 os.environ.setdefault("JAX_ENABLE_X64", "1")
 
 import jax
-
-if os.environ.get("MPI4TORCH_TPU_REAL_DEVICES") != "1":
-    jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_x64", True)
 
 import jax.numpy as jnp

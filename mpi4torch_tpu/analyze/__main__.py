@@ -145,4 +145,7 @@ def main(argv) -> int:
 
 
 if __name__ == "__main__":
+    from mpi4torch_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     sys.exit(main(sys.argv[1:]))

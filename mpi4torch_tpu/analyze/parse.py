@@ -262,8 +262,7 @@ class ParsedProgram:
 def _as_text(lowered_or_text, debug_info: bool = True) -> str:
     if isinstance(lowered_or_text, str):
         return lowered_or_text
-    from .._compat import lowered_text
-    return lowered_text(lowered_or_text, debug_info=debug_info)
+    return lowered_or_text.as_text(debug_info=debug_info)
 
 
 def _scope_of(line: str, loc_names: Dict[str, str]) -> str:

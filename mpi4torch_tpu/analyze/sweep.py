@@ -61,7 +61,7 @@ def _flat_lowerer(nranks: int):
     from jax.sharding import Mesh, PartitionSpec as P
 
     import mpi4torch_tpu as mpi
-    from .._compat import lowered_text, shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.asarray(jax.devices()[:nranks]), ("w",))
     comm = mpi.comm_from_mesh(mesh, "w")
@@ -69,7 +69,7 @@ def _flat_lowerer(nranks: int):
     def lower(body, *args):
         fn = shard_map(lambda *a: body(comm, *a), mesh=mesh,
                        in_specs=P(), out_specs=P(), check_vma=False)
-        return lowered_text(jax.jit(fn).lower(*args), debug_info=True)
+        return jax.jit(fn).lower(*args).as_text(debug_info=True)
 
     return lower, comm
 
@@ -80,7 +80,7 @@ def _mesh2d_lowerer(shape: Tuple[int, int]):
     from jax.sharding import Mesh, PartitionSpec as P
 
     import mpi4torch_tpu as mpi
-    from .._compat import lowered_text, shard_map
+    from jax import shard_map
 
     a, b = shape
     mesh = Mesh(np.asarray(jax.devices()[:a * b]).reshape(a, b),
@@ -90,7 +90,7 @@ def _mesh2d_lowerer(shape: Tuple[int, int]):
     def lower(body, *args):
         fn = shard_map(lambda *a_: body(comm, *a_), mesh=mesh,
                        in_specs=P(), out_specs=P(), check_vma=False)
-        return lowered_text(jax.jit(fn).lower(*args), debug_info=True)
+        return jax.jit(fn).lower(*args).as_text(debug_info=True)
 
     return lower, comm
 
@@ -311,7 +311,6 @@ def _sweep_serve(records: List[dict], nranks: int):
     import jax.numpy as jnp
     import numpy as np
 
-    from .._compat import lowered_text
     from ..models import transformer as T
     from ..serve import Engine, ServeConfig
 
@@ -326,7 +325,7 @@ def _sweep_serve(records: List[dict], nranks: int):
                      spmd=True, nranks=size)
         eng.submit(np.array([1, 2, 3]), max_new=2)
         eng.step()
-        txt = lowered_text(eng.lower_step(), debug_info=True)
+        txt = eng.lower_step().as_text(debug_info=True)
         _lint_case(
             records, f"({size},) serve.decode.{name}", txt,
             extra={"scheduled_exposure":

@@ -33,7 +33,7 @@ def _smoke() -> int:
 
     import mpi4torch_tpu as mpi
     from mpi4torch_tpu import constants as C
-    from mpi4torch_tpu._compat import shard_map
+    from jax import shard_map
     from mpi4torch_tpu.compress import get_codec
     from mpi4torch_tpu.ops import quant_kernels as qk
 
@@ -133,4 +133,7 @@ def main(argv) -> int:
 
 
 if __name__ == "__main__":
+    from mpi4torch_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     sys.exit(main(sys.argv[1:]))

@@ -61,7 +61,7 @@ def _lower_text(fn, n: int, x, det: bool) -> str:
     from jax.sharding import Mesh, PartitionSpec as P
 
     from .. import config as _config
-    from .._compat import shard_map
+    from jax import shard_map
     from ..ops.spmd import SpmdContext
 
     mesh = Mesh(np.asarray(jax.devices()[:n]), ("w",))
@@ -260,7 +260,7 @@ def _mode_a_rows(name: str, n: int, vals, det: bool = True):
 
     from .. import config as _config
     from .. import constants as C
-    from .._compat import shard_map
+    from jax import shard_map
     from ..ops.spmd import SpmdContext
     from ..ops import spmd as _spmd
 
@@ -422,7 +422,7 @@ def _run_tiers() -> int:
         "2-level tier_stack changes the flat hier lowering")
     # (b) mesh world: the 2-axis TierStackBackend vs HierMeshBackend.
     from jax.sharding import Mesh, PartitionSpec as P
-    from .._compat import shard_map
+    from jax import shard_map
     from ..ops.spmd import HierMeshBackend, TierStackBackend
 
     mesh2 = Mesh(np.asarray(jax.devices()[:8]).reshape(2, 4),
@@ -489,4 +489,7 @@ def _main(argv: Iterable[str]) -> int:
 
 
 if __name__ == "__main__":
+    from mpi4torch_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     sys.exit(_main(sys.argv[1:]))

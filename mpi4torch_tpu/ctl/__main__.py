@@ -472,7 +472,7 @@ def _cell_off_path(failures) -> None:
 
     import mpi4torch_tpu as mpi
     from .. import config as _cfg
-    from .._compat import shard_map
+    from jax import shard_map
     from .controller import SelfTuningController
 
     mesh = Mesh(np.asarray(jax.devices()), ("w",))
@@ -599,4 +599,7 @@ def main(argv) -> int:
 
 
 if __name__ == "__main__":
+    from mpi4torch_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     sys.exit(main(sys.argv[1:]))

@@ -50,7 +50,6 @@ import jax.numpy as jnp
 
 from .. import config as _config
 from .. import constants as C
-from .._compat import optimization_barrier as _opt_barrier
 from ..ops.spmd import _ring_table
 from ..resilience import guards as _guards
 from ..runtime import CommError
@@ -436,7 +435,13 @@ def fused_allreduce_tree(comm, tree, op: int = C.MPI_SUM, *,
                 if seg * size != b.size:
                     padded = jnp.concatenate(
                         [b, jnp.zeros((seg * size - b.size,), b.dtype)])
-                part = comm.Reduce_scatter(padded.reshape(size, seg), op, 0)
+                # Scatter the FLAT bucket (rank r keeps elements
+                # [r*seg, (r+1)*seg)), never a (size, seg) view of it:
+                # on the TPU that reshape is a relayout whose kernel
+                # took ~2 minutes to compile per 128 MiB bucket and
+                # brought the compiler down at 541M parameters on four
+                # chips (PERF.md, PR 22).  Same segments, same bits.
+                part = comm.Reduce_scatter(padded, op, 0)
                 stage.append(("part", i, part, b.size))
 
     # Overlap staging: tie bucket i's scattered part to bucket i+1's
@@ -450,7 +455,7 @@ def fused_allreduce_tree(comm, tree, op: int = C.MPI_SUM, *,
         for j in range(len(part_idx) - 1):
             k = part_idx[j]
             kind, i, _, nelem = stage[k]
-            tied = _opt_barrier((orig[j], orig[j + 1]))[0]
+            tied = jax.lax.optimization_barrier((orig[j], orig[j + 1]))[0]
             stage[k] = (kind, i, tied, nelem)
 
     # Phase 2: complete — all-gather the scattered parts, unpad, scale.
@@ -459,7 +464,7 @@ def fused_allreduce_tree(comm, tree, op: int = C.MPI_SUM, *,
         if kind == "part":
             with bucket_scope("Allreduce_tree", i, nb):
                 full = comm.Allgather(val, 0, compression=False)
-                val = full.reshape(-1)[:nelem]
+                val = full[:nelem]
         reduced[i] = val / size if mean else val
     return unflatten_buckets(reduced, layout)
 

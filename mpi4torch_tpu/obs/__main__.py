@@ -46,7 +46,7 @@ def _lower(fn, *args):
     from jax.sharding import Mesh, PartitionSpec as P
 
     import mpi4torch_tpu as mpi
-    from mpi4torch_tpu._compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.asarray(jax.devices()), ("w",))
     cm = mpi.comm_from_mesh(mesh, "w")
@@ -256,7 +256,7 @@ def _smoke_offpath(failures) -> None:
 
     import mpi4torch_tpu as mpi
     from mpi4torch_tpu import obs
-    from mpi4torch_tpu._compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.asarray(jax.devices()), ("w",))
     cm = mpi.comm_from_mesh(mesh, "w")
@@ -411,4 +411,7 @@ def main(argv) -> int:
 
 
 if __name__ == "__main__":
+    from mpi4torch_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     sys.exit(main(sys.argv[1:]))

@@ -108,7 +108,7 @@ def _equivalent_text(ev, key: tuple) -> str:
     from jax.sharding import Mesh, PartitionSpec as P
 
     import mpi4torch_tpu as mpi
-    from .._compat import shard_map
+    from jax import shard_map
 
     n = ev.world_size
     devs = jax.devices()

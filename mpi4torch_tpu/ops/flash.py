@@ -57,6 +57,12 @@ _STAT_LANES = 128
 # back to the jnp path (ring attention keeps per-rank blocks short anyway).
 _KV_VMEM_BUDGET = 8 * 1024 * 1024
 
+# Stable names of the three Mosaic kernels (forward, backward dq,
+# backward dk/dv): what a lowered program's ``kernel_name`` attributes
+# and a profiler trace's kernel events are matched against.
+KERNEL_NAMES = ("mpi4torch_flash_fwd", "mpi4torch_flash_bwd_dq",
+                "mpi4torch_flash_bwd_dkv")
+
 
 def _lane_pad(d: int) -> int:
     """Head dim as staged in VMEM: the next lane multiple (128)."""
@@ -82,10 +88,7 @@ def _eligible(q, k) -> bool:
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # backend not initialized
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -234,9 +237,9 @@ def _parallel_grid_params():
     fully independent (each step writes a distinct output block; all
     reduction lives in in-core fori_loops), so Mosaic may pipeline the
     grid and split it across cores on megacore parts."""
-    from .._compat import tpu_compiler_params
+    from jax.experimental.pallas import tpu as pltpu
 
-    return tpu_compiler_params(
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel"))
 
 
@@ -376,6 +379,7 @@ def _pallas_block(q, k, v, q_off, kv_off, causal: bool, interpret: bool,
         ),
         compiler_params=_parallel_grid_params(),
         interpret=interpret,
+        name=KERNEL_NAMES[0],
     )(qoff, kvoff, qb, kb, vb)
 
     if dp != d:
@@ -600,6 +604,7 @@ def _pallas_bwd(q, k, v, do, lse, dd, q_off, kv_off,
         out_specs=vmem((1, qt, dp), lambda i, j: (i, j, 0)),
         compiler_params=_parallel_grid_params(),
         interpret=interpret,
+        name=KERNEL_NAMES[1],
     )(qoff, kvoff, qb, kb, vb, dob, lse_r, dd_r)
 
     # Under GQA (g > 1) the dkv grid still walks q heads: each grid row
@@ -634,6 +639,7 @@ def _pallas_bwd(q, k, v, do, lse, dd, q_off, kv_off,
         ),
         compiler_params=_parallel_grid_params(),
         interpret=interpret,
+        name=KERNEL_NAMES[2],
     )(qoff, kvoff, qb, kb, vb, dob, lse_r, dd_r)
     if g == 1:
         dk, dv = dk_p, dv_p
@@ -670,80 +676,9 @@ def _bwd_eligible(q, k) -> bool:
     return staged <= _KV_VMEM_BUDGET
 
 
-def _pallas_bwd_compiles(sq, sk, d, dtype, causal: bool,
-                         g: int = 1, window: int = 0) -> bool:
-    # _pallas_bwd takes (q, k, v, do, lse, dd, ...): do mirrors q, and the
-    # two row stats are (b, sq, h) f32.
-    def args(sq, d, dtype):
-        x = jax.ShapeDtypeStruct((1, sq, g, d), dtype)
-        r = jax.ShapeDtypeStruct((1, sq, g), jnp.float32)
-        return (x, r, r)
-
-    return _probe_compiles(_BWD_PROBE_CACHE, _pallas_bwd,
-                           args(sq, d, dtype), "backward",
-                           sq, sk, d, dtype, causal, g, window)
-
-
 # ---------------------------------------------------------------------------
 # Differentiable public entry
 # ---------------------------------------------------------------------------
-
-
-# One-time compiled-lowering probes, keyed by everything the kernel's
-# block shapes depend on.  ``impl="auto"`` must never expose a caller to a
-# Mosaic lowering failure (round-3 verdict: the flagship transformer was
-# one BlockSpec bug away from unusable on TPU): shape eligibility alone is
-# a *necessary* condition, so before first use of a given tiling we
-# compile a batch/head-reduced instance (identical block shapes, tiny
-# grid) out-of-line and fall back to jnp — with a warning — if Mosaic
-# rejects it.
-_PROBE_CACHE: dict = {}
-_BWD_PROBE_CACHE: dict = {}
-
-
-def _probe_compiles(cache, fn, extra_args, label, sq, sk, d, dtype,
-                    causal: bool, g: int = 1, window: int = 0) -> bool:
-    """Shared one-time compile probe (forward and backward kernels): the
-    block shapes depend only on (sq, sk, d, dtype, causal) — plus the GQA
-    group count ``g`` (it changes the KV index maps and, backward, the
-    partial-output dtype) and whether a sliding ``window`` is active (it
-    changes loop bounds/masking; the window LENGTH is loop arithmetic
-    with no lowering effect, so one probe covers every positive value) —
-    so a batch/head-reduced instance (q heads = g, one KV head; tiny
-    grid) proves lowering for the whole family.  The tunable tile sizes
-    (module globals, swept by bench_tradeoffs.py flash_tiling) are part
-    of the key: a verdict probed under one tiling must not be reused
-    after the tiles change."""
-    key = (sq, sk, d, jnp.dtype(dtype).name, causal, g, bool(window),
-           _Q_TILE, _KV_TILE)
-    ok = cache.get(key)
-    if ok is None:
-        import warnings
-
-        try:
-            probe = jax.jit(functools.partial(
-                fn, q_off=jnp.int32(0), kv_off=jnp.int32(0),
-                causal=causal, interpret=False, window=window))
-            q = jax.ShapeDtypeStruct((1, sq, g, d), dtype)
-            kv = jax.ShapeDtypeStruct((1, sk, 1, d), dtype)
-            probe.lower(q, kv, kv, *extra_args).compile()
-            ok = True
-        except Exception as e:  # Mosaic/XLA lowering failure
-            warnings.warn(
-                f"flash_block_attention: Pallas {label} kernel failed "
-                f"compiled lowering for tiling (sq={sq}, sk={sk}, d={d}, "
-                f"dtype={jnp.dtype(dtype).name}, causal={causal}); falling "
-                f"back to the jnp path. Error: {type(e).__name__}: "
-                f"{str(e)[:500]}")
-            ok = False
-        cache[key] = ok
-    return ok
-
-
-def _pallas_compiles(sq, sk, d, dtype, causal: bool, g: int = 1,
-                     window: int = 0) -> bool:
-    return _probe_compiles(_PROBE_CACHE, _pallas_block, (), "forward",
-                           sq, sk, d, dtype, causal, g, window)
 
 
 def _block_fwd_dispatch(q, k, v, q_off, kv_off, causal: bool, impl: str,
@@ -759,11 +694,11 @@ def _block_fwd_dispatch(q, k, v, q_off, kv_off, causal: bool, impl: str,
                 f"k{k.shape} — use impl='auto' to fall back to jnp")
         return _pallas_block(q, k, v, q_off, kv_off, causal,
                              interpret=not _on_tpu(), window=window)
-    # auto
-    if (_eligible(q, k) and _on_tpu()
-            and _pallas_compiles(q.shape[1], k.shape[1], q.shape[3],
-                                 q.dtype, causal, _gqa_groups(q, k),
-                                 window)):
+    # auto: the dispatch is the stated predicate and nothing else.  On a
+    # TPU an eligible shape IS the kernel; if Mosaic rejects it, the
+    # enclosing program's compile raises with Mosaic's own message —
+    # a training run must never lose its kernel silently.
+    if _eligible(q, k) and _on_tpu():
         return _pallas_block(q, k, v, q_off, kv_off, causal,
                              interpret=False, window=window)
     return _jnp_block(q, k, v, q_off, kv_off, causal, window)
@@ -824,8 +759,9 @@ def _block_bwd(causal, impl, window, res, cot):
     """Flash-style backward by block recomputation (residuals: out + lse;
     the score matrix is rebuilt — never stored).  Dispatch mirrors the
     forward: the fused Pallas dq/dk/dv kernels on eligible TPU shapes
-    (probe-guarded, like the forward), tiled jnp otherwise — the jnp path
-    is the oracle the kernels are tested against."""
+    (``_bwd_eligible``, no compile probe — a Mosaic failure raises),
+    tiled jnp otherwise — the jnp path is the oracle the kernels are
+    tested against."""
     q, k, v, q_off, kv_off, out, lse = res
     do, dlse = cot
 
@@ -834,11 +770,7 @@ def _block_bwd(causal, impl, window, res, cot):
         use_kernel = _bwd_eligible(q, k)
         interpret = not _on_tpu()
     elif impl == "auto":
-        use_kernel = (
-            _bwd_eligible(q, k) and _on_tpu()
-            and _pallas_bwd_compiles(q.shape[1], k.shape[1], q.shape[3],
-                                     q.dtype, causal, _gqa_groups(q, k),
-                                     window))
+        use_kernel = _bwd_eligible(q, k) and _on_tpu()
     if use_kernel:
         delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                         axis=-1)                          # (b, sq, h)

@@ -44,10 +44,7 @@ _ROW_TILE = 256
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 def ring_salt(round_idx: int, channel: int) -> int:

@@ -59,7 +59,6 @@ from jax import lax
 
 from .. import config as _config
 from .. import constants as C
-from .._compat import optimization_barrier as _opt_barrier
 from ..runtime import (
     BifurcationError,
     CommError,
@@ -1514,7 +1513,7 @@ def _fresh(x):
     """Pass through an optimization barrier to obtain a unique tracer
     object — the handle identity key (the analogue of the reference's
     buffer-pointer hash, csrc/extension.cpp:1100)."""
-    return _opt_barrier(x)
+    return lax.optimization_barrier(x)
 
 
 _SPMD_DESC_LEN = 8
@@ -1533,7 +1532,7 @@ def isend(ctx: SpmdContext, x, dest, tag: int) -> List:
     Returns the raw 3-tensor handle [descriptor, buffer, loopthrough]."""
     perm = _peer_table(ctx, dest, "destination")
     buf = _fresh(x)
-    desc = _opt_barrier(
+    desc = lax.optimization_barrier(
         (jnp.zeros(_SPMD_DESC_LEN, jnp.float32), buf))[0]
     state = _HandleState(kind="send", perm=perm, tag=tag, loop=buf)
     ctx.handles[id(buf)] = state
@@ -1550,7 +1549,7 @@ def irecv(ctx: SpmdContext, x, source, tag: int) -> List:
     src_table = _peer_table(ctx, source, "source")
     send_perm = _invert_perm(src_table)
     buf = _fresh(x)
-    desc = _opt_barrier(
+    desc = lax.optimization_barrier(
         (jnp.zeros(_SPMD_DESC_LEN, jnp.float32), buf))[0]
     state = _HandleState(kind="recv", perm=send_perm, tag=tag)
     ctx.handles[id(buf)] = state
@@ -1590,7 +1589,7 @@ def wait(ctx: SpmdContext, handle: List):
         # arrives; a send that never matches is caught at region close.
         # Tie the returned loop-through to the descriptor chain so
         # JoinDummiesHandle ordering survives into the compiled program.
-        return _opt_barrier((loop, desc))[0]
+        return lax.optimization_barrier((loop, desc))[0]
     if not state.matched:
         raise DeadlockError(
             f"trace-time deadlock: Wait on a receive (tag {state.tag}, "
@@ -1601,7 +1600,7 @@ def wait(ctx: SpmdContext, handle: List):
             "the Isend first (Isend -> Recv -> Wait, as in the reference "
             "examples), or use Irecv and delay the Wait past the send."
         )
-    return _opt_barrier((state.result, desc))[0]
+    return lax.optimization_barrier((state.result, desc))[0]
 
 
 # ---------------------------------------------------------------------------
@@ -1637,7 +1636,7 @@ def _register_coll(ctx: SpmdContext, opname: str, value, complete=None
     WaitHandle layout) and record the completion state keyed by the
     buffer tracer — the same identity scheme as the p2p handles."""
     buf = _fresh(value)
-    desc = _opt_barrier(
+    desc = lax.optimization_barrier(
         (jnp.zeros(_SPMD_DESC_LEN, jnp.float32), buf))[0]
     state = _CollState(opname=opname, complete=complete)
     ctx.coll_handles[id(buf)] = state
@@ -1732,7 +1731,7 @@ def collective_wait(ctx: SpmdContext, handle: List):
     # Tie the phase-1 value to the descriptor chain so JoinDummiesHandle
     # dependencies (and the scheduler's cross-bucket ordering ties)
     # survive into the compiled program — the p2p Wait's discipline.
-    val = _opt_barrier((buf, desc))[0]
+    val = lax.optimization_barrier((buf, desc))[0]
     if state.complete is not None:
         val = state.complete(val)
     return val
@@ -2321,7 +2320,7 @@ def run_spmd(fn, nranks: Optional[int] = None, mesh=None,
     on every MPI rank (SURVEY.md §3.3).
     """
     from jax.sharding import Mesh, PartitionSpec as P
-    from .._compat import shard_map
+    from jax import shard_map
 
     if mesh is None:
         devs = jax.devices()

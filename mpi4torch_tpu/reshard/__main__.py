@@ -62,7 +62,7 @@ def _smoke() -> int:
 
     import mpi4torch_tpu as mpi
     from mpi4torch_tpu import reshard as rs
-    from mpi4torch_tpu._compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     n = len(jax.devices())
@@ -217,4 +217,7 @@ def main(argv) -> int:
 
 
 if __name__ == "__main__":
+    from mpi4torch_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     sys.exit(main(sys.argv[1:]))

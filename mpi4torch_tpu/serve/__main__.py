@@ -74,7 +74,6 @@ def _smoke() -> int:
 
     import mpi4torch_tpu as mpi
     from mpi4torch_tpu import serve
-    from mpi4torch_tpu._compat import lowered_text
     from mpi4torch_tpu.models import transformer as T
 
     ndev = len(jax.devices())
@@ -182,7 +181,7 @@ def _smoke() -> int:
                            spmd=True, nranks=size)
         eng.submit(prompts[0], max_new=3)
         eng.step()
-        txt = lowered_text(eng.lower_step(), debug_info=True)
+        txt = eng.lower_step().as_text(debug_info=True)
         if size > 1:
             if f"Allreduce_start.{rep['algorithm']}" not in txt:
                 print("FAIL: lowered decode step does not carry the "
@@ -316,10 +315,10 @@ def _smoke() -> int:
                        spmd=True, nranks=size)
     eng.submit(prompts[0], max_new=6)
     eng.step()
-    txt1 = lowered_text(eng.lower_step(), debug_info=False)
+    txt1 = eng.lower_step().as_text(debug_info=False)
     eng.submit(prompts[1], max_new=4)   # second slot maps fresh pages
     eng.step()
-    txt2 = lowered_text(eng.lower_step(), debug_info=False)
+    txt2 = eng.lower_step().as_text(debug_info=False)
     if txt1 != txt2:
         print("FAIL: paged decode step retraces across table states")
         return 1
@@ -350,4 +349,7 @@ def main(argv) -> int:
 
 
 if __name__ == "__main__":
+    from mpi4torch_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     sys.exit(main(sys.argv[1:]))

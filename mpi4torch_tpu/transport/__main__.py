@@ -130,7 +130,7 @@ def _smoke_reconcile(failures) -> None:
     import mpi4torch_tpu as mpi
     from mpi4torch_tpu import COMM_WORLD as comm
     from mpi4torch_tpu import obs
-    from mpi4torch_tpu._compat import shard_map
+    from jax import shard_map
 
     x8 = jnp.arange(1024, dtype=jnp.float32)
 
@@ -197,4 +197,7 @@ def main(argv) -> int:
 
 
 if __name__ == "__main__":
+    from mpi4torch_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     sys.exit(main(sys.argv[1:]))

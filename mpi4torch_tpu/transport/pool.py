@@ -13,7 +13,7 @@ Rendezvous is an ``AF_UNIX`` listener in a private temp directory: each
 worker connects back and introduces itself with a ``hello`` frame
 carrying its PID (accept order is arbitrary — the PID is how a socket
 is matched to its ``Popen``).  Workers inherit the parent environment
-with ``JAX_PLATFORMS`` defaulted to ``cpu`` and the repo root on
+with ``JAX_PLATFORMS`` pinned to ``cpu`` and the repo root on
 ``PYTHONPATH``; both ends are the same interpreter on the same
 checkout, which is what lets the wire stay plain pickle (wire.py).
 """
@@ -86,9 +86,11 @@ class WorkerPool:
 
     def _spawn_env(self) -> Dict[str, str]:
         env = dict(os.environ)
-        # Workers are the Mode B host-side runtime: eager jax on CPU
-        # unless the caller explicitly pinned a platform.
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # Workers are the Mode B host-side runtime: eager jax on the
+        # CPU, always.  An accelerator belongs to one process at a
+        # time, so a worker inheriting JAX_PLATFORMS=tpu would ask for
+        # a chip its parent holds and fail or hang.
+        env["JAX_PLATFORMS"] = "cpu"
         repo_root = os.path.dirname(os.path.dirname(
             os.path.dirname(os.path.abspath(__file__))))
         env["PYTHONPATH"] = repo_root + os.pathsep + env.get(

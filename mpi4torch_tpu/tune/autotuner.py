@@ -660,4 +660,7 @@ def _main(argv: Iterable[str]) -> int:
 if __name__ == "__main__":
     import sys
 
+    from mpi4torch_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     sys.exit(_main(sys.argv[1:]))

@@ -28,7 +28,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 import mpi4torch_tpu as mpi
 from mpi4torch_tpu import analyze
-from mpi4torch_tpu._compat import lowered_text, shard_map
+from jax import shard_map
 
 NR = 8
 
@@ -51,7 +51,7 @@ def _lower(body, *args, nr=NR, debug=True):
     comm = mpi.comm_from_mesh(mesh, "w")
     fn = shard_map(lambda *a: body(comm, *a), mesh=mesh, in_specs=P(),
                    out_specs=P(), check_vma=False)
-    return lowered_text(jax.jit(fn).lower(*args), debug_info=debug)
+    return jax.jit(fn).lower(*args).as_text(debug_info=debug)
 
 
 # =========================================================================
@@ -337,7 +337,7 @@ class TestReshardCensusRegression:
     bench runs without x64 (the liveness scan prices i32 index
     constants there, i64 under the x64 test harness — wire bytes are
     invariant but peak live shifts by the constant widths), so the
-    programs lower under ``disable_x64`` to reproduce the recorded
+    programs lower under ``jax.enable_x64(False)`` to reproduce the recorded
     numbers bit-identically."""
 
     @pytest.fixture(scope="class")
@@ -347,7 +347,7 @@ class TestReshardCensusRegression:
         tl = rs.layout((2, 4), 0, 1)
         G = (1024, 256)                          # the bench shapes
         x = jnp.zeros(fl.shard_shape(G), jnp.float32)
-        with jax.experimental.disable_x64():
+        with jax.enable_x64(False):
             return {
                 strategy or "planned": _lower(
                     lambda c, v, s=strategy: c.Reshard(v, fl, tl,
@@ -396,7 +396,7 @@ class TestServeCensusRegression:
         prev = mpi.config.latency_crossover_bytes()
         mpi.config.set_latency_crossover_bytes(1 << 14)
         try:
-            with jax.experimental.disable_x64():
+            with jax.enable_x64(False):
                 params = T.init_transformer(jax.random.PRNGKey(0), cfg,
                                             dtype=jnp.float32)
                 for name, ov in (("overlap", True), ("blocking", False)):
@@ -405,8 +405,8 @@ class TestServeCensusRegression:
                                  spmd=True, nranks=NR)
                     eng.submit(np.array([1, 2, 3, 4, 5]), max_new=3)
                     eng.step()
-                    out[name] = lowered_text(eng.lower_step(),
-                                             debug_info=True)
+                    out[name] = eng.lower_step().as_text(
+                        debug_info=True)
         finally:
             mpi.config.set_latency_crossover_bytes(prev)
         return out

@@ -26,7 +26,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 import mpi4torch_tpu as mpi
 from mpi4torch_tpu import constants as C
 from mpi4torch_tpu import csched
-from mpi4torch_tpu._compat import shard_map
+from jax import shard_map
 from mpi4torch_tpu.ops import eager as op_eager
 from mpi4torch_tpu.ops import spmd as op_spmd
 

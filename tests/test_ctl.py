@@ -469,7 +469,7 @@ class TestOffPath:
         import jax
         import jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
-        from mpi4torch_tpu._compat import shard_map
+        from jax import shard_map
 
         mesh = Mesh(np.asarray(jax.devices()), ("w",))
         cm = mpi.comm_from_mesh(mesh, "w")

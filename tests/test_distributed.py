@@ -98,7 +98,7 @@ _HYBRID_WORKER = textwrap.dedent("""
     import mpi4torch_tpu as mpi
     import jax.numpy as jnp
     import numpy as np
-    from mpi4torch_tpu._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     info = mpi.init_distributed(

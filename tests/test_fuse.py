@@ -28,7 +28,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 import mpi4torch_tpu as mpi
 from mpi4torch_tpu import fuse
-from mpi4torch_tpu._compat import shard_map
+from jax import shard_map
 from mpi4torch_tpu.fuse.bucketing import bucket_layout, flatten_buckets
 
 NR = 4
@@ -124,6 +124,26 @@ class TestFusedCensus:
         assert got == {"all_reduce": 0, "all_gather": 1,
                        "reduce_scatter": 1, "all_to_all": 0,
                        "collective_permute": 0}
+
+    def test_buckets_scatter_flat_never_as_size_by_seg(self):
+        # On the TPU a (size, seg) view of a flat bucket is a relayout
+        # whose kernel took minutes to compile at 128 MiB and crashed
+        # the compiler at 541M parameters on four chips (PR 22): the
+        # pair must reduce-scatter and all-gather rank-1 operands.
+        import re
+
+        mesh = Mesh(np.asarray(jax.devices()[:NR]), ("w",))
+        c = mpi.comm_from_mesh(mesh, "w")
+        fn = shard_map(lambda t: c.Allreduce_tree(t, mpi.MPI_SUM),
+                       mesh=mesh, in_specs=P(), out_specs=P(),
+                       check_vma=False)
+        txt = jax.jit(fn).lower(
+            {"w": jnp.ones((64, 48), jnp.float32)}).as_text()
+        wires = re.findall(
+            r'"stablehlo\.(?:reduce_scatter|all_gather)".*?:\s*'
+            r'\(tensor<([^>]+)>\)', txt, flags=re.S)
+        assert len(wires) == 2
+        assert all(w.count("x") == 1 for w in wires), wires
 
     def test_unfused_baseline_is_per_leaf(self):
         got = census(

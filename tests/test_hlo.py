@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from mpi4torch_tpu._compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 import mpi4torch_tpu as mpi
@@ -355,8 +355,7 @@ class TestCompressedCensus:
             prog = jax.grad(body)
         wrapped = shard_map(prog, mesh=mesh, in_specs=P(), out_specs=P(),
                             check_vma=False)
-        from mpi4torch_tpu._compat import lowered_text
-        return lowered_text(jax.jit(wrapped).lower(*args), debug_info=True)
+        return jax.jit(wrapped).lower(*args).as_text(debug_info=True)
 
     def test_q8_allreduce_ships_int8(self):
         txt = self._lowered(

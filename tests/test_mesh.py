@@ -25,7 +25,7 @@ class TestDeviceMesh:
     def test_collectives_over_helper_mesh(self):
         mesh = mpi.device_mesh({"dp": 2, "tp": 4})
         comm_tp = mpi.comm_from_mesh(mesh, "tp")
-        from mpi4torch_tpu._compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         def body():

@@ -20,7 +20,7 @@ import pytest
 
 import mpi4torch_tpu as mpi
 from mpi4torch_tpu import COMM_WORLD as comm, analyze, config, obs
-from mpi4torch_tpu._compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

@@ -21,8 +21,7 @@ from mpi4torch_tpu import COMM_WORLD as comm
 
 def _lowered_text(fn, *args):
     # debug_info keeps the loc()/name-stack metadata the profiler uses.
-    from mpi4torch_tpu._compat import lowered_text
-    return lowered_text(jax.jit(fn).lower(*args), debug_info=True)
+    return jax.jit(fn).lower(*args).as_text(debug_info=True)
 
 
 class TestNamedScopes:

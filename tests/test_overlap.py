@@ -36,7 +36,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 import mpi4torch_tpu as mpi
 from mpi4torch_tpu import overlap
-from mpi4torch_tpu._compat import shard_map
+from jax import shard_map
 
 NR = 8
 CENSUS_NR = 4
@@ -786,7 +786,6 @@ class TestProfilingSpans:
         # communication per bucket.
         tree = [jnp.ones(256, jnp.float32) for _ in range(2)]
 
-        from mpi4torch_tpu._compat import lowered_text
 
         def body(c, t):
             return c.Allreduce_tree(t, mpi.MPI_SUM, bucket_bytes=1024,
@@ -795,7 +794,7 @@ class TestProfilingSpans:
         mesh, c = _mesh_comm()
         wrapped = shard_map(lambda t: body(c, t), mesh=mesh, in_specs=P(),
                             out_specs=P(), check_vma=False)
-        txt = lowered_text(jax.jit(wrapped).lower(tree), debug_info=True)
+        txt = jax.jit(wrapped).lower(tree).as_text(debug_info=True)
         assert "bucket0of2.start" in txt
         assert "bucket0of2.wait" in txt
 
@@ -837,8 +836,7 @@ class TestScheduledExposure:
         assert windowed["n_exposed"] <= 1
 
     def test_census_accepts_debug_text(self):
-        from mpi4torch_tpu._compat import lowered_text
-        txt = lowered_text(self._tree_lowered(True), debug_info=True)
+        txt = self._tree_lowered(True).as_text(debug_info=True)
         from_text = overlap.scheduled_exposure(txt)
         from_lowered = overlap.scheduled_exposure(self._tree_lowered(True))
         assert from_text == from_lowered

@@ -313,7 +313,7 @@ class TestCensus:
                  grad=False):
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from mpi4torch_tpu._compat import shard_map
+        from jax import shard_map
 
         mesh = Mesh(np.asarray(jax.devices()[:NR]), ("w",))
         c = mpi.comm_from_mesh(mesh, "w")
@@ -383,18 +383,17 @@ class TestCensus:
         assert 0 < planned < gathered
 
     def test_named_scopes_in_lowering(self):
-        from mpi4torch_tpu._compat import lowered_text
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from mpi4torch_tpu._compat import shard_map
+        from jax import shard_map
 
         mesh = Mesh(np.asarray(jax.devices()[:NR]), ("w",))
         c = mpi.comm_from_mesh(mesh, "w")
         fn = shard_map(lambda a: c.Reshard(a, L8, L24), mesh=mesh,
                        in_specs=P(), out_specs=P(), check_vma=False)
-        txt = lowered_text(
-            jax.jit(fn).lower(jnp.zeros(L8.shard_shape(G), jnp.float32)),
-            debug_info=True)
+        txt = jax.jit(fn).lower(
+            jnp.zeros(L8.shard_shape(G), jnp.float32)).as_text(
+                debug_info=True)
         assert "mpi4torch.Reshard" in txt
         assert "mpi4torch.Reshard.alltoall" in txt
 

@@ -373,7 +373,7 @@ class TestModeAGuardCensus:
     def _lowered(self, compression=False):
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from mpi4torch_tpu._compat import shard_map
+        from jax import shard_map
 
         mesh = Mesh(np.asarray(jax.devices()), ("w",))
         cm = mpi.comm_from_mesh(mesh, "w")
@@ -424,7 +424,7 @@ class TestModeAGuardCensus:
     def test_violation_ledger_records_nonfinite(self):
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from mpi4torch_tpu._compat import shard_map
+        from jax import shard_map
 
         mesh = Mesh(np.asarray(jax.devices()), ("w",))
         cm = mpi.comm_from_mesh(mesh, "w")

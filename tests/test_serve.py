@@ -397,7 +397,6 @@ class TestCensusAndLatencyTier:
         assert seen["blocking"]["exposed_fraction"] == 1.0
 
     def test_latency_tier_selection_and_span(self):
-        from mpi4torch_tpu._compat import lowered_text
 
         params = _params(CFG)
         mpi.config.set_latency_crossover_bytes(1 << 14)
@@ -411,7 +410,7 @@ class TestCensusAndLatencyTier:
                            spmd=True, nranks=4)
         eng.submit(PROMPTS[0], max_new=3)
         eng.step()
-        txt = lowered_text(eng.lower_step(), debug_info=True)
+        txt = eng.lower_step().as_text(debug_info=True)
         # Deterministic evidence off the program itself: the resolved
         # split-phase scope carries the latency algorithm, and no
         # bandwidth-tier schedule appears anywhere in the decode step.
@@ -437,8 +436,7 @@ class TestCensusAndLatencyTier:
                     return comm.Wait(comm.Allreduce_start(x, mpi.MPI_SUM))
                 lowered = _jax.jit(mpi.run_spmd(body, nranks=4)).lower(
                     jnp.ones(64, jnp.float32))
-            from mpi4torch_tpu._compat import lowered_text
-            txt = lowered_text(lowered, debug_info=True)
+            txt = lowered.as_text(debug_info=True)
             assert "Allreduce_start.hier" not in txt
             assert "Allreduce_start" in txt
         finally:
@@ -1026,7 +1024,6 @@ class TestPagedPoolAccounting:
 
 class TestPagedNoRetrace:
     def test_lowered_step_identical_across_table_states(self):
-        from mpi4torch_tpu._compat import lowered_text
 
         params = _params(CFG)
         eng = serve.Engine(CFG, params,
@@ -1035,10 +1032,10 @@ class TestPagedNoRetrace:
                            spmd=True, nranks=4)
         eng.submit(PROMPTS[0], max_new=6)
         eng.step()
-        txt1 = lowered_text(eng.lower_step(), debug_info=False)
+        txt1 = eng.lower_step().as_text(debug_info=False)
         eng.submit(PROMPTS[1], max_new=4)
         eng.step()
-        txt2 = lowered_text(eng.lower_step(), debug_info=False)
+        txt2 = eng.lower_step().as_text(debug_info=False)
         assert txt1 == txt2
         assert txt1.count('"stablehlo.gather"') >= 2 * CFG.n_layers
 

@@ -736,7 +736,7 @@ class TestCommFromMesh:
         # src/__init__.py:247-261): use the communicator inside a
         # user-managed shard_map over the user's own axis name.
         from jax.sharding import Mesh, PartitionSpec as P
-        from mpi4torch_tpu._compat import shard_map
+        from jax import shard_map
 
         devs = jax.devices()[:4]
         mesh = Mesh(np.asarray(devs), ("workers",))
@@ -758,7 +758,7 @@ class TestCommFromMesh:
         # fuse into a collective_permute (a fresh context per op call would
         # produce a spurious trace-time DeadlockError).
         from jax.sharding import Mesh, PartitionSpec as P
-        from mpi4torch_tpu._compat import shard_map
+        from jax import shard_map
 
         mesh = Mesh(np.asarray(jax.devices()), ("w",))
         c = mpi.comm_from_mesh(mesh, "w")
@@ -784,7 +784,7 @@ class TestCommFromMesh:
     def test_p2p_scope_matches_and_returns_values(self):
         # Inside an explicit scope the ring still fuses and computes.
         from jax.sharding import Mesh, PartitionSpec as P
-        from mpi4torch_tpu._compat import shard_map
+        from jax import shard_map
 
         mesh = Mesh(np.asarray(jax.devices()), ("w",))
         c = mpi.comm_from_mesh(mesh, "w")
@@ -807,7 +807,7 @@ class TestCommFromMesh:
         # normally only warns from a finalizer; the explicit scope
         # restores run_spmd's hard trace-time DeadlockError.
         from jax.sharding import Mesh, PartitionSpec as P
-        from mpi4torch_tpu._compat import shard_map
+        from jax import shard_map
 
         mesh = Mesh(np.asarray(jax.devices()), ("w",))
         c = mpi.comm_from_mesh(mesh, "w")
@@ -892,7 +892,7 @@ class TestAlltoallCrossModeParity:
         # The (2,4) world: one Alltoall per mesh axis inside a 2D
         # shard_map, each checked against the local transpose oracle.
         from jax.sharding import Mesh, PartitionSpec as P
-        from mpi4torch_tpu._compat import shard_map
+        from jax import shard_map
 
         mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(2, 4),
                     ("a", "b"))

@@ -24,7 +24,7 @@ from mpi4torch_tpu import constants as C
 from mpi4torch_tpu import csched
 from mpi4torch_tpu import obs
 from mpi4torch_tpu import overlap
-from mpi4torch_tpu._compat import shard_map
+from jax import shard_map
 from mpi4torch_tpu.ops import spmd as op_spmd
 
 NR = 8

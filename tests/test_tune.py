@@ -47,7 +47,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 import mpi4torch_tpu as mpi
 from mpi4torch_tpu import tune
-from mpi4torch_tpu._compat import shard_map
+from jax import shard_map
 
 NR = 8
 CENSUS_NR = 4

@@ -363,7 +363,7 @@ class TestZero3:
         # (params, forward) + reduce-scatters (gradient adjoint) — and
         # crucially NO all_reduce (a full gradient allreduce would mean
         # the sharding saved nothing on the wire).
-        from mpi4torch_tpu._compat import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from mpi4torch_tpu.parallel import zero3_init, zero3_step
 
