@@ -1,0 +1,11 @@
+"""The benchmark's own tests: CPU, tiny sizes, no part of tier-1's
+``tests/``.  Run them with ``python -m pytest benchmarks/tests -q``."""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
