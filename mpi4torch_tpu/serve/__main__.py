@@ -64,6 +64,7 @@ MIRRORED_SERVE_COUNTERS = (
     "deadline_expired", "shed",
     "prefix_hits", "prefix_misses", "prefill_tokens", "cow_copies",
     "preempted", "blocks_in_use", "blocks_free", "blocks_cached",
+    "install_writes",
 )
 
 

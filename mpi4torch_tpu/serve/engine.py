@@ -73,6 +73,21 @@ STATUS_OK = "ok"
 STATUS_EXPIRED = "deadline_expired"
 STATUS_SHED = "shed"
 
+# The spans of one :meth:`Engine.step` (``ServeStats.span``; the names
+# are an API — doc/serving.md says what each covers and whether it ends
+# in a device sync).  One span per phase, never one per page, cache
+# leaf or token: six a step plus four per admitted request or chunk.
+SPAN_STEP = _prof.STEP_SPAN
+SPAN_EXPIRE = SPAN_STEP + ".expire"
+SPAN_ADMIT = SPAN_STEP + ".admit"
+SPAN_PLAN = SPAN_ADMIT + ".plan"
+SPAN_PREFILL = SPAN_ADMIT + ".prefill"
+SPAN_INSTALL = SPAN_ADMIT + ".install"
+SPAN_FIRST_TOKEN = SPAN_ADMIT + ".first_token"
+SPAN_DISPATCH = SPAN_STEP + ".decode.dispatch"
+SPAN_FETCH = SPAN_STEP + ".decode.fetch"
+SPAN_SELECT = SPAN_STEP + ".decode.select"
+
 
 class QueueFullError(CommError):
     """Raised by :meth:`Engine.submit` when the engine is at capacity
@@ -387,6 +402,7 @@ class Engine:
                 lambda a: jnp.broadcast_to(a[None], (self._size,)
                                            + a.shape), cache)
         self._cache = cache
+        self._cache_leaves = len(jax.tree.leaves(cache))
         self._tokens = np.zeros((slots,), np.int32)
         self._pos = np.zeros((slots,), np.int32)
         self._slot_req: List[Optional[Request]] = [None] * slots
@@ -592,55 +608,76 @@ class Engine:
         or an immediate EOS) land in ``events`` so the step-event
         surface never drops a token or a completion."""
         chooser = POLICIES[self.serve_cfg.policy]
+        span = self.stats.span
         while self._queue and self._free_slots():
-            req = self._queue[chooser(self._queue)]
+            with span(SPAN_PLAN) as plan:
+                req = self._queue[chooser(self._queue)]
+                plan.rid = req.rid
+                if self._paged:
+                    job = self._plan_paged(req)
+                else:
+                    self._queue.remove(req)
             if self._paged:
-                if not self._admit_paged(req, events):
+                if job is None:
                     # Page pool exhausted even after cache eviction:
                     # defer admission (the request stays queued; decode
                     # keeps draining pages).  Deadline expiry composes
                     # — a deferred request past its deadline leaves
                     # through the next sweep.
                     break
+                self._start_paged(job, events)
                 continue
-            self._queue.remove(req)
-            prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
-            if self._spmd:
-                logits, rows = self._prefill_call(self._shards, prompt)
-                logits_row = np.asarray(logits[0][0])
-            else:
-                cache1 = _kv.init_kv_cache_tp(
-                    self.cfg, 1, self._size, self._dtype, poison=False)
-                logits, rows = _kv.prefill_tp(
-                    self.cfg, self._shards, cache1, prompt, self._comm)
-                logits_row = np.asarray(logits[0])
-            self.stats.mark(req.rid, "admitted")
-            self.stats.count("admitted")
-            tok = self._select(req, logits_row)
-            req.emitted.append(tok)
-            self.stats.mark(req.rid, "first_token")
-            events["admitted"].append(req.rid)
-            events["emitted"].setdefault(req.rid, []).append(tok)
-            if req.finished(self.serve_cfg.eos):
-                # Finished at admission (max_new=1 / immediate EOS):
-                # it never occupied a slot, so no eviction counts —
-                # but the event surface reports it like any other
-                # completion.
-                events["finished"].append(req.rid)
-                self._finish(req)
+            with span(SPAN_PREFILL, req.rid):
+                logits_row, rows = self._prefill_full(req.prompt)
+            with span(SPAN_FIRST_TOKEN, req.rid):
+                self.stats.mark(req.rid, "admitted")
+                self.stats.count("admitted")
+                tok = self._select(req, logits_row)
+                req.emitted.append(tok)
+                self.stats.mark(req.rid, "first_token")
+                events["admitted"].append(req.rid)
+                events["emitted"].setdefault(req.rid, []).append(tok)
+                done = req.finished(self.serve_cfg.eos)
+                if done:
+                    # Finished at admission (max_new=1 / immediate
+                    # EOS): it never occupied a slot, so no eviction
+                    # counts — but the event surface reports it like
+                    # any other completion.
+                    events["finished"].append(req.rid)
+                    self._finish(req)
+            if done:
                 continue
-            j = self._free_slots()[0]
-            self.slot_log.append((req.rid, j))
-            if self._spmd:
-                self._cache = jax.tree.map(
-                    lambda s, r: s.at[:, j].set(r[:, 0]),
-                    self._cache, rows)
-            else:
-                self._cache = jax.tree.map(
-                    lambda s, r: s.at[j].set(r[0]), self._cache, rows)
-            self._slot_req[j] = req
-            self._tokens[j] = tok
-            self._pos[j] = int(req.prompt.size)
+            with span(SPAN_INSTALL, req.rid):
+                # Dispatch only: the writes drain under the next sync.
+                j = self._free_slots()[0]
+                self.slot_log.append((req.rid, j))
+                if self._spmd:
+                    self._cache = jax.tree.map(
+                        lambda s, r: s.at[:, j].set(r[:, 0]),
+                        self._cache, rows)
+                else:
+                    self._cache = jax.tree.map(
+                        lambda s, r: s.at[j].set(r[0]), self._cache,
+                        rows)
+                self.stats.count("install_writes", self._cache_leaves)
+                self._slot_req[j] = req
+                self._tokens[j] = tok
+                self._pos[j] = int(req.prompt.size)
+
+    def _prefill_full(self, prompt):
+        """The whole-prompt prefill — the IDENTICAL dispatch the
+        ``generate()`` oracle uses — and its last-token logits on the
+        host (a device sync: the prefill's device time ends here).
+        Returns ``(logits_row, rows)``."""
+        pj = jnp.asarray(prompt, jnp.int32)[None, :]
+        if self._spmd:
+            logits, rows = self._prefill_call(self._shards, pj)
+            return np.asarray(logits[0][0]), rows
+        cache1 = _kv.init_kv_cache_tp(
+            self.cfg, 1, self._size, self._dtype, poison=False)
+        logits, rows = _kv.prefill_tp(
+            self.cfg, self._shards, cache1, pj, self._comm)
+        return np.asarray(logits[0]), rows
 
     # -------------------------------------------------------------- paged
 
@@ -665,7 +702,10 @@ class Engine:
         plain ``.at[].set`` at CONCRETE page ids — exact bits, and the
         write targets are private pages by the COW rule."""
         bs = self.serve_cfg.block_size
-        for bi in range(lo // bs, -(-hi // bs)):
+        pages = range(lo // bs, -(-hi // bs))
+        self.stats.count("install_writes",
+                         len(pages) * self._cache_leaves)
+        for bi in pages:
             b = int(self._table[j, bi])
             r0, r1 = max(lo, bi * bs), min(hi, (bi + 1) * bs)
             o0 = r0 - bi * bs
@@ -711,15 +751,13 @@ class Engine:
         return [{"k": take(c["k"]), "v": take(c["v"])}
                 for c in self._cache]
 
-    def _admit_paged(self, req: Request, events: dict) -> bool:
-        """Paged admission: prefix-match the prompt against the block
-        index, adopt shared pages (COW-copying a partial tail),
-        allocate private pages for the rest, then prefill only the
-        unmatched suffix — in one shot if it fits
-        ``ServeConfig.prefill_chunk`` (or chunking is off), else as a
-        queued :class:`_PrefillJob` advanced one chunk per step.
-        Returns False (request left queued) when the pool cannot supply
-        the pages."""
+    def _plan_paged(self, req: Request) -> Optional[_PrefillJob]:
+        """Plan a paged admission: prefix-match the prompt against the
+        block index, adopt shared pages (COW-copying a partial tail),
+        allocate private pages for the rest and reserve the slot.  The
+        returned job's ``done`` is the matched prefix; :meth:
+        `_start_paged` prefills the rest.  Returns None (request left
+        queued) when the pool cannot supply the pages."""
         bs = self.serve_cfg.block_size
         prompt = np.asarray(req.prompt)
         p_len = int(prompt.size)
@@ -734,7 +772,7 @@ class Engine:
         n_new = total - (l0 // bs)
         fresh = self._mgr.alloc(n_new)
         if fresh is None:
-            return False
+            return None
         self._mgr.ref(shared)
         j = self._free_slots()[0]
         for bi in range(l0 // bs):
@@ -752,22 +790,23 @@ class Engine:
         self.slot_log.append((req.rid, j))
         self._prefilling[j] = True
         self._pos[j] = l0          # rows installed so far
-        job = _PrefillJob(req=req, slot=j, seq=prompt, done=l0)
+        return _PrefillJob(req=req, slot=j, seq=prompt, done=l0)
+
+    def _start_paged(self, job: _PrefillJob, events: dict) -> None:
+        """Prefill what :meth:`_plan_paged` did not match — in one shot
+        if it fits ``ServeConfig.prefill_chunk`` (or chunking is off),
+        else as a queued :class:`_PrefillJob` advanced one chunk per
+        step."""
+        j, l0, p_len = job.slot, job.done, len(job.seq)
+        rid = job.req.rid
         if l0 == 0 and (self._chunk is None or p_len <= self._chunk):
             # Whole-prompt miss that fits one shot: the ordinary full
             # prefill — the IDENTICAL dispatch the dense engine and the
             # generate() oracle use.
-            pj = jnp.asarray(prompt, jnp.int32)[None, :]
-            if self._spmd:
-                logits, rows = self._prefill_call(self._shards, pj)
-                logits_row = np.asarray(logits[0][0])
-            else:
-                cache1 = _kv.init_kv_cache_tp(
-                    self.cfg, 1, self._size, self._dtype, poison=False)
-                logits, rows = _kv.prefill_tp(
-                    self.cfg, self._shards, cache1, pj, self._comm)
-                logits_row = np.asarray(logits[0])
-            self._install_rows(j, rows, 0, p_len)
+            with self.stats.span(SPAN_PREFILL, rid):
+                logits_row, rows = self._prefill_full(job.seq)
+            with self.stats.span(SPAN_INSTALL, rid):
+                self._install_rows(j, rows, 0, p_len)
             self.stats.count("prefill_tokens", p_len)
             job.done = p_len
             self._complete_admission(job, logits_row, events)
@@ -779,7 +818,6 @@ class Engine:
             # Long suffix: interleave — ONE chunk per step rides along
             # with the resident slots' decode (_prefill_tick).
             self._prefill_jobs.append(job)
-        return True
 
     def _advance_job_chunk(self, job: _PrefillJob, events: dict,
                            cap: Optional[int] = None) -> bool:
@@ -790,17 +828,21 @@ class Engine:
         p_len = len(job.seq)
         c_len = min(cap if cap is not None else self._chunk,
                     p_len - job.done)
-        past = self._gather_past(j, job.done)
-        chunk = jnp.asarray(job.seq[job.done:job.done + c_len],
-                            jnp.int32)[None, :]
-        if self._spmd:
-            logits, rows = self._chunk_call(self._shards, past, chunk)
-            logits_row = np.asarray(logits[0][0])
-        else:
-            logits, rows = _kv.prefill_chunk_tp(
-                self.cfg, self._shards, past, chunk, self._comm)
-            logits_row = np.asarray(logits[0])
-        self._install_rows(j, rows, job.done, job.done + c_len)
+        rid = job.req.rid
+        with self.stats.span(SPAN_PREFILL, rid):
+            past = self._gather_past(j, job.done)
+            chunk = jnp.asarray(job.seq[job.done:job.done + c_len],
+                                jnp.int32)[None, :]
+            if self._spmd:
+                logits, rows = self._chunk_call(self._shards, past,
+                                                chunk)
+                logits_row = np.asarray(logits[0][0])
+            else:
+                logits, rows = _kv.prefill_chunk_tp(
+                    self.cfg, self._shards, past, chunk, self._comm)
+                logits_row = np.asarray(logits[0])
+        with self.stats.span(SPAN_INSTALL, rid):
+            self._install_rows(j, rows, job.done, job.done + c_len)
         self.stats.count("prefill_tokens", c_len)
         job.done += c_len
         self._pos[j] = job.done
@@ -819,25 +861,26 @@ class Engine:
         req, j = job.req, job.slot
         bs = self.serve_cfg.block_size
         p_len = len(job.seq)
-        self.stats.mark(req.rid, "admitted")
-        self.stats.count("admitted")
-        tok = self._select(req, logits_row)
-        req.emitted.append(tok)
-        self.stats.mark(req.rid, "first_token")
-        events["admitted"].append(req.rid)
-        events["emitted"].setdefault(req.rid, []).append(tok)
-        ids = [int(self._table[j, bi]) for bi in range(-(-p_len // bs))]
-        # Content-addressed, so indexing the slot's own (immutable for
-        # its lifetime) prompt pages is safe; the next identical prompt
-        # prefills nothing but its final token.
-        self._mgr.register(job.seq, ids, p_len)
-        self._prefilling[j] = False
-        self._tokens[j] = tok
-        self._pos[j] = p_len
-        if req.finished(self.serve_cfg.eos):
-            events["finished"].append(req.rid)
-            self._release_slots([j])
-            self._finish(req)
+        with self.stats.span(SPAN_FIRST_TOKEN, req.rid):
+            self.stats.mark(req.rid, "admitted")
+            self.stats.count("admitted")
+            tok = self._select(req, logits_row)
+            req.emitted.append(tok)
+            self.stats.mark(req.rid, "first_token")
+            events["admitted"].append(req.rid)
+            events["emitted"].setdefault(req.rid, []).append(tok)
+            ids = [int(self._table[j, bi]) for bi in range(-(-p_len // bs))]
+            # Content-addressed, so indexing the slot's own (immutable for
+            # its lifetime) prompt pages is safe; the next identical prompt
+            # prefills nothing but its final token.
+            self._mgr.register(job.seq, ids, p_len)
+            self._prefilling[j] = False
+            self._tokens[j] = tok
+            self._pos[j] = p_len
+            if req.finished(self.serve_cfg.eos):
+                events["finished"].append(req.rid)
+                self._release_slots([j])
+                self._finish(req)
 
     def _prefill_tick(self, events: dict) -> None:
         """Advance the HEAD chunked-prefill job by exactly one chunk —
@@ -1018,26 +1061,60 @@ class Engine:
         ``deadline_expired`` result status) after the sweep that runs
         BEFORE admission — an expired queued request never burns a
         prefill."""
-        # Between-steps controller consult (mpi4torch_tpu.ctl): a step
-        # boundary is the only safe switch point — no collective is in
-        # flight, so a ratified codec/schedule switch takes effect on
-        # the NEXT step's traffic atomically.  Disabled (the default)
-        # or detached, this is one attribute read.
-        if self._controller is not None:
-            self._controller.poll()
-        events = {"admitted": [], "emitted": {}, "finished": [],
-                  "expired": []}
-        self._expire_sweep(events)
-        self._admit(events)
-        if self._paged:
-            self._prefill_tick(events)
-            self._alloc_tick()
-        active = [j for j, r in enumerate(self._slot_req)
-                  if r is not None and not self._prefilling[j]]
-        if not active:
-            if self._paged:
-                self._pool_levels()
+        span = self.stats.span
+        with span(SPAN_STEP):
+            events = {"admitted": [], "emitted": {}, "finished": [],
+                      "expired": []}
+            with span(SPAN_EXPIRE):
+                # Between-steps controller consult (mpi4torch_tpu.ctl):
+                # a step boundary is the only safe switch point — no
+                # collective is in flight, so a ratified codec/schedule
+                # switch takes effect on the NEXT step's traffic
+                # atomically.  Disabled (the default) or detached, this
+                # is one attribute read.
+                if self._controller is not None:
+                    self._controller.poll()
+                self._expire_sweep(events)
+            with span(SPAN_ADMIT):
+                self._admit(events)
+                if self._paged:
+                    self._prefill_tick(events)
+                    self._alloc_tick()
+            active = [j for j, r in enumerate(self._slot_req)
+                      if r is not None and not self._prefilling[j]]
+            if not active:
+                if self._paged:
+                    self._pool_levels()
+                return events
+            with span(SPAN_DISPATCH):
+                # Ends when the step call has returned, not when the
+                # device has run it.
+                logits = self._dispatch_decode()
+            with span(SPAN_FETCH):
+                # The sync: waits for the step and for every write
+                # queued before it, then copies the logits table.
+                table = np.asarray(logits)
+            with span(SPAN_SELECT):
+                self.stats.tick(len(active), self.serve_cfg.slots)
+                for j in active:
+                    req = self._slot_req[j]
+                    tok = self._select(req, table[j])
+                    req.emitted.append(tok)
+                    events["emitted"].setdefault(req.rid, []).append(tok)
+                    self.stats.count("decode_tokens")
+                    self._pos[j] += 1
+                    self._tokens[j] = tok
+                    if req.finished(self.serve_cfg.eos):
+                        events["finished"].append(req.rid)
+                        self._evict(j)
+                if self._paged:
+                    self._pool_levels()
             return events
+
+    def _dispatch_decode(self):
+        """Queue ONE decode step over the slot table (the new cache
+        replaces the old) and return its ``(slots, vocab)`` logits,
+        still on the device."""
         live = np.asarray([self._slot_req[j] is not None
                            and not self._prefilling[j]
                            for j in range(self.serve_cfg.slots)])
@@ -1053,8 +1130,8 @@ class Engine:
                     self._shards, self._cache,
                     jnp.asarray(self._tokens),
                     jnp.asarray(self._pos), jnp.asarray(live))
-            table = np.asarray(logits[0])
-        elif self._paged:
+            return logits[0]
+        if self._paged:
             logits, self._cache = _kv.decode_step_paged(
                 self.cfg, self._shards, self._cache,
                 jnp.asarray(self._table), jnp.asarray(self._tokens),
@@ -1062,7 +1139,6 @@ class Engine:
                 overlap=self.serve_cfg.overlap,
                 algorithm=self.serve_cfg.algorithm,
                 active=jnp.asarray(live))
-            table = np.asarray(logits)
         else:
             logits, self._cache = _kv.decode_step_tp(
                 self.cfg, self._shards, self._cache,
@@ -1070,22 +1146,7 @@ class Engine:
                 self._comm, overlap=self.serve_cfg.overlap,
                 algorithm=self.serve_cfg.algorithm,
                 active=jnp.asarray(live))
-            table = np.asarray(logits)
-        self.stats.tick(len(active), self.serve_cfg.slots)
-        for j in active:
-            req = self._slot_req[j]
-            tok = self._select(req, table[j])
-            req.emitted.append(tok)
-            events["emitted"].setdefault(req.rid, []).append(tok)
-            self.stats.count("decode_tokens")
-            self._pos[j] += 1
-            self._tokens[j] = tok
-            if req.finished(self.serve_cfg.eos):
-                events["finished"].append(req.rid)
-                self._evict(j)
-        if self._paged:
-            self._pool_levels()
-        return events
+        return logits
 
     def _pool_levels(self) -> None:
         """Mirror the block pool's population into the gauge-semantics

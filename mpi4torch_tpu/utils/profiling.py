@@ -24,11 +24,33 @@ host-side XLA execution (the harness smoke path, tests/test_observability).
 from __future__ import annotations
 
 import contextlib
+import itertools
 import threading
 import time
+from collections import deque
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["profiler_trace", "bucket_scope", "serve_step_scope",
-           "ServeStats", "serve_stats", "reset_serve_stats"]
+           "ServeStats", "serve_stats", "reset_serve_stats",
+           "serve_step_log", "STEP_SPAN", "STEP_LOG_CAP"]
+
+# The span that is one ``Engine.step()`` call; every other serving span
+# (``mpi4torch.serve.step.<phase>``, doc/serving.md) lies inside it.
+STEP_SPAN = "mpi4torch.serve.step"
+STEP_LOG_CAP = 8192
+# The step log: one record per closed STEP_SPAN, newest last, of every
+# engine in the process.  Module-level, so it outlives the engines the
+# way a flight record must; bounded, so it never grows with traffic.
+_STEP_LOG: deque = deque(maxlen=STEP_LOG_CAP)
+_ENGINE_IDS = itertools.count()
+# What a step record says the step did: counter -> the record's key.
+# ``tick()`` adds the active slots once per decode step, so the step's
+# share of ``occupancy_ticks`` is the slots it decoded.
+_STEP_COUNTS = (("admitted", "admitted"),
+                ("prefill_tokens", "prefill_tokens"),
+                ("install_writes", "install_writes"),
+                ("occupancy_ticks", "active"))
 
 
 def bucket_scope(op: str, index: int, total: int, codec=None, phase=None):
@@ -99,7 +121,8 @@ def _labeled_scope(name: str):
 
 
 class ServeStats:
-    """Serving observability: engine counters + per-request spans.
+    """Serving observability: engine counters, per-request marks and
+    the spans of ``Engine.step()``.
 
     Counters (monotonic ints): ``steps`` (decode steps run), ``admitted``
     / ``evicted`` / ``finished`` / ``rejected`` (request lifecycle),
@@ -110,7 +133,12 @@ class ServeStats:
     number.  Spans (per request id): ``submitted`` -> ``admitted`` ->
     ``first_token`` -> ``finished`` wall-clock timestamps, from which
     :meth:`snapshot` derives time-to-first-token and end-to-end
-    latencies.  Thread-safe (Mode B runs one engine per rank thread);
+    latencies.  Step spans (:meth:`span`): the phases of every
+    ``Engine.step()`` call on ``time.perf_counter_ns()``, kept per step
+    on the process-wide step log (:func:`serve_step_log`) and summed
+    per phase under ``snapshot()["phase_s"]``; one engine's spans are
+    written by the one thread that steps it.
+    Thread-safe (Mode B runs one engine per rank thread);
     engines register here so :func:`serve_stats` aggregates
     process-wide.  ``evicted`` counts slots freed — a request finishing
     at admission (max_new=1 / immediate EOS) never occupied one, so
@@ -136,21 +164,70 @@ class ServeStats:
                  # namespace.
                  "prefix_hits", "prefix_misses", "prefill_tokens",
                  "cow_copies", "preempted",
-                 "blocks_in_use", "blocks_free", "blocks_cached")
+                 "blocks_in_use", "blocks_free", "blocks_cached",
+                 # ISSUE 25: device writes dispatched when prefill rows
+                 # are installed into the cache (paged: pages x cache
+                 # leaves; dense: cache leaves).
+                 "install_writes")
     SPAN_CAP = 1024
 
     def __init__(self):
         self._lock = threading.Lock()
         self.counters = {k: 0 for k in self._COUNTERS}
         self.spans = {}
+        self.engine = next(_ENGINE_IDS)   # "engine" of its step records
+        self.phases = {}                  # span name -> [ns, count]
+        self._open = None                 # spans of the step that is open
+        self._open_counts = None
 
     def reset(self) -> None:
-        """Zero the counters and drop the spans (in place, so an
-        engine holding this object keeps counting from zero)."""
+        """Zero the counters and drop the spans and phase totals (in
+        place, so an engine holding this object keeps counting from
+        zero)."""
         with self._lock:
             for k in list(self.counters):
                 self.counters[k] = 0
             self.spans.clear()
+            self.phases.clear()
+
+    def span(self, name: str, rid=None) -> "_Span":
+        """Context manager around one phase of ``Engine.step()``: a
+        ``jax.profiler.TraceAnnotation(name)`` (so that in any xplane
+        capture the span sits on the trace's own clock beside the
+        device lines; inert when no profiler session runs) and one
+        ``(name, t0_ns, t1_ns, rid)`` from ``time.perf_counter_ns()``
+        — the clock :meth:`mark` reads — appended to the record of the
+        step that is open.  :data:`STEP_SPAN` opens that record and,
+        closing, puts it on the process-wide step log
+        (:func:`serve_step_log`).  Nesting gives the parent: a child
+        lies inside its parent's interval.  Always on, like the
+        counters; ``rid`` may be set on the returned object before the
+        block ends."""
+        return _Span(self, name, rid)
+
+    def _open_step(self) -> None:
+        self._open = []
+        with self._lock:
+            self._open_counts = [self.counters[c] for c, _ in _STEP_COUNTS]
+
+    def _span_closed(self, name: str, t0: int, t1: int, rid) -> None:
+        spans = self._open
+        if spans is None:       # no step is open: the annotation only
+            return
+        spans.append((name, t0, t1, rid))
+        if name != STEP_SPAN:
+            return
+        self._open = None
+        record = {"engine": self.engine, "t0_ns": t0, "t1_ns": t1,
+                  "spans": spans}
+        with self._lock:
+            for (c, key), base in zip(_STEP_COUNTS, self._open_counts):
+                record[key] = self.counters[c] - base
+            for n, a, b, _ in spans:
+                tot = self.phases.setdefault(n, [0, 0])
+                tot[0] += b - a
+                tot[1] += 1
+        _STEP_LOG.append(record)
 
     def count(self, name: str, n: int = 1) -> None:
         with self._lock:
@@ -196,6 +273,7 @@ class ServeStats:
         with self._lock:
             counters = dict(self.counters)
             spans = {rid: dict(s) for rid, s in self.spans.items()}
+            phases = {n: tuple(t) for n, t in self.phases.items()}
         ttft = [s["first_token"] - s["submitted"] for s in spans.values()
                 if "first_token" in s and "submitted" in s]
         e2e = [s["finished"] - s["submitted"] for s in spans.values()
@@ -205,6 +283,10 @@ class ServeStats:
             round(counters["occupancy_ticks"] / counters["slot_ticks"], 4)
             if counters["slot_ticks"] else None)
         out["n_requests_tracked"] = len(spans)
+        # Per-phase totals of the step spans (ISSUE 25): what the step
+        # log holds of this engine, summed, and never capped.
+        out["phase_s"] = {n: {"seconds": ns / 1e9, "count": c}
+                          for n, (ns, c) in phases.items()}
         if ttft:
             out["ttft_s"] = {"mean": sum(ttft) / len(ttft),
                              "max": max(ttft),
@@ -215,6 +297,44 @@ class ServeStats:
                             "p50": percentile(e2e, 0.50),
                             "p99": percentile(e2e, 0.99)}
         return out
+
+
+class _Span:
+    """One :meth:`ServeStats.span`.  A class, not a generator-based
+    context manager: the span runs a dozen times an engine step."""
+
+    __slots__ = ("rid", "_stats", "_name", "_ann", "_t0")
+
+    def __init__(self, stats: ServeStats, name: str, rid):
+        self.rid = rid
+        self._stats = stats
+        self._name = name
+        self._ann = TraceAnnotation(name)
+
+    def __enter__(self):
+        if self._name == STEP_SPAN:
+            self._stats._open_step()
+        self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
+        self._stats._span_closed(self._name, self._t0, t1, self.rid)
+        return False
+
+
+def serve_step_log() -> list:
+    """A copy of the process-wide step log, oldest first: one record
+    per ``Engine.step()`` call of every engine, ``{"engine", "t0_ns",
+    "t1_ns", "spans": [(name, t0_ns, t1_ns, rid), ...], "admitted",
+    "prefill_tokens", "install_writes", "active"}`` on the
+    ``time.perf_counter_ns()`` clock, the last :data:`STEP_LOG_CAP`
+    steps.  ``engine`` is the ``ServeStats.engine`` serial of the
+    engine that stepped; the counts are what that step added to the
+    counters of the same name (``active``: the slots it decoded)."""
+    return list(_STEP_LOG)
 
 
 # Weak references: an engine holds the only strong reference to its
@@ -250,10 +370,16 @@ def serve_stats() -> dict:
     really did run every step)."""
     engines = _live_serve_stats()
     agg = {k: 0 for k in ServeStats._COUNTERS}
+    phase_s = {}
     snaps = [e.snapshot() for e in engines]
     for snap in snaps:
         for k in agg:
             agg[k] += snap.get(k, 0)
+        for name, tot in snap["phase_s"].items():
+            into = phase_s.setdefault(name, {"seconds": 0.0, "count": 0})
+            into["seconds"] += tot["seconds"]
+            into["count"] += tot["count"]
+    agg["phase_s"] = phase_s
     agg["n_engines"] = len(engines)
     agg["occupancy"] = (round(agg["occupancy_ticks"] / agg["slot_ticks"], 4)
                         if agg["slot_ticks"] else None)
@@ -261,15 +387,16 @@ def serve_stats() -> dict:
 
 
 def reset_serve_stats() -> None:
-    """Zero every live engine's counters/spans IN PLACE and empty the
-    registry (test/bench isolation).  Engines constructed before the
-    reset keep counting on their own (now zeroed) ``stats`` object but
-    drop out of the process aggregate — a reset mid-flight is a
-    bookkeeping cut, not an engine restart."""
+    """Zero every live engine's counters/spans IN PLACE, empty the
+    registry and the step log (test/bench isolation).  Engines
+    constructed before the reset keep counting on their own (now
+    zeroed) ``stats`` object but drop out of the process aggregate — a
+    reset mid-flight is a bookkeeping cut, not an engine restart."""
     from ..obs.metrics import sources
 
     for e in sources().clear(_SERVE_GROUP):
         e.reset()
+    _STEP_LOG.clear()
 
 
 # Serving counters in the unified metrics namespace: a snapshot-time

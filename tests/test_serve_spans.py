@@ -1,0 +1,341 @@
+"""The spans and the step log inside ``Engine.step()`` (ISSUE 25).
+
+``ServeStats.span`` lays the phases of every step on
+``time.perf_counter_ns()`` and, through ``jax.profiler.
+TraceAnnotation``, on any profiler capture; a closed step goes onto the
+process-wide step log.  Held here, on tiny paged and dense engines in
+both execution modes: the shape of a step's spans (nesting, which
+phases an admitting and a decode-only step have, the request id they
+carry, one span per phase however many pages a prompt has), the
+``install_writes`` census, the log's lifetime (capped, outlives its
+engine, emptied by ``reset_serve_stats()``), ``snapshot()["phase_s"]``
+against the log, the annotation in a CPU profiler capture, and that
+the served tokens are still ``generate()``'s.
+"""
+
+import gc
+import glob
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mpi4torch_tpu import serve
+from mpi4torch_tpu.models import transformer as T
+from mpi4torch_tpu.serve import engine as E
+from mpi4torch_tpu.utils import profiling as P
+
+CFG = T.TransformerConfig(vocab=37, d_model=16, n_heads=4, n_layers=2,
+                          d_ff=32, max_seq=40)
+BLOCK = 4
+LEAVES = 2 * CFG.n_layers            # a K and a V leaf per layer
+
+ADMIT_PHASES = [E.SPAN_PLAN, E.SPAN_PREFILL, E.SPAN_INSTALL,
+                E.SPAN_FIRST_TOKEN]
+DECODE_PHASES = [E.SPAN_DISPATCH, E.SPAN_FETCH, E.SPAN_SELECT]
+
+# (paged, spmd): every engine the spans must read the same on.
+ENGINES = [pytest.param(False, False, id="dense-eager"),
+           pytest.param(False, True, id="dense-spmd"),
+           pytest.param(True, False, id="paged-eager"),
+           pytest.param(True, True, id="paged-spmd")]
+
+
+@pytest.fixture(scope="module")
+def params():
+    return T.init_transformer(jax.random.PRNGKey(0), CFG,
+                              dtype=jnp.float64)
+
+
+@pytest.fixture(autouse=True)
+def _clean_log():
+    P.reset_serve_stats()
+    yield
+    P.reset_serve_stats()
+
+
+def make_engine(params, paged, spmd, **kw):
+    if paged:
+        kw.setdefault("block_size", BLOCK)
+    return serve.Engine(CFG, params,
+                        serve.ServeConfig(slots=2, max_new=3, **kw),
+                        spmd=spmd, nranks=2 if spmd else None)
+
+
+def names(record):
+    return [s[0] for s in record["spans"]]
+
+
+def log_of(eng):
+    return [r for r in P.serve_step_log()
+            if r["engine"] == eng.stats.engine]
+
+
+@pytest.mark.parametrize("paged,spmd", ENGINES)
+class TestStepSpans:
+    def test_children_nest_inside_the_step(self, params, paged, spmd):
+        eng = make_engine(params, paged, spmd)
+        eng.submit(np.arange(1, 6))
+        eng.submit(np.arange(7, 10))
+        eng.run()
+        log = log_of(eng)
+        assert len(log) >= 2
+        for rec in log:
+            *children, step = rec["spans"]
+            assert step[0] == P.STEP_SPAN
+            assert (step[1], step[2]) == (rec["t0_ns"], rec["t1_ns"])
+            for name, t0, t1, _ in children:
+                assert name.startswith(P.STEP_SPAN + ".")
+                assert step[1] <= t0 <= t1 <= step[2]
+            # Any two spans are disjoint or nested: siblings never
+            # overlap, and a child lies inside its parent.
+            for i, (_, a0, a1, _) in enumerate(children):
+                for _, b0, b1, _ in children[i + 1:]:
+                    assert (a1 <= b0 or b1 <= a0
+                            or (a0 <= b0 and b1 <= a1)
+                            or (b0 <= a0 and a1 <= b1))
+            # The admission phases lie inside the admit span.
+            (admit,) = [s for s in children if s[0] == E.SPAN_ADMIT]
+            for name, t0, t1, _ in children:
+                if name.startswith(E.SPAN_ADMIT + "."):
+                    assert admit[1] <= t0 <= t1 <= admit[2]
+
+    def test_admitting_step_carries_the_rid(self, params, paged, spmd):
+        eng = make_engine(params, paged, spmd)
+        rid = eng.submit(np.arange(1, 6), rid="r-17")
+        eng.step()
+        (rec,) = log_of(eng)
+        for phase in ADMIT_PHASES:
+            (span,) = [s for s in rec["spans"] if s[0] == phase]
+            assert span[3] == rid
+        assert names(rec).count(E.SPAN_EXPIRE) == 1
+        for phase in DECODE_PHASES:     # admitted, then decoded
+            (span,) = [s for s in rec["spans"] if s[0] == phase]
+            assert span[3] is None
+        assert rec["admitted"] == 1 and rec["active"] == 1
+        assert rec["prefill_tokens"] == (5 if paged else 0)
+
+    def test_decode_only_step(self, params, paged, spmd):
+        eng = make_engine(params, paged, spmd)
+        eng.submit(np.arange(1, 6))
+        eng.step()
+        eng.step()
+        rec = log_of(eng)[-1]
+        assert names(rec) == [E.SPAN_EXPIRE, E.SPAN_ADMIT,
+                              *DECODE_PHASES, P.STEP_SPAN]
+        assert (rec["admitted"], rec["prefill_tokens"],
+                rec["install_writes"], rec["active"]) == (0, 0, 0, 1)
+
+    def test_idle_step_has_no_decode_span(self, params, paged, spmd):
+        eng = make_engine(params, paged, spmd)
+        eng.step()
+        (rec,) = log_of(eng)
+        assert names(rec) == [E.SPAN_EXPIRE, E.SPAN_ADMIT, P.STEP_SPAN]
+        assert rec["active"] == 0
+
+    def test_span_count_does_not_grow_with_pages(self, params, paged,
+                                                 spmd):
+        counts, writes = [], []
+        for n_tokens in (BLOCK, 8 * BLOCK):       # 1 page, 8 pages
+            eng = make_engine(params, paged, spmd)
+            eng.submit(np.arange(n_tokens) % CFG.vocab)
+            eng.step()
+            (rec,) = log_of(eng)
+            counts.append(len(rec["spans"]))
+            writes.append(rec["install_writes"])
+        assert counts[0] == counts[1] == 6 + 4
+        # install_writes = pages x cache leaves (dense: one write a leaf).
+        assert writes == ([LEAVES, 8 * LEAVES] if paged
+                          else [LEAVES, LEAVES])
+
+    def test_phase_totals_sum_the_log(self, params, paged, spmd):
+        eng = make_engine(params, paged, spmd)
+        for n in (5, 3, 6):
+            eng.submit(np.arange(1, 1 + n))
+        eng.run()
+        want = {}
+        for rec in log_of(eng):
+            for name, t0, t1, _ in rec["spans"]:
+                ns, count = want.get(name, (0, 0))
+                want[name] = (ns + t1 - t0, count + 1)
+        got = eng.stats.snapshot()["phase_s"]
+        assert set(got) == set(want)
+        for name, (ns, count) in want.items():
+            assert got[name]["count"] == count
+            assert got[name]["seconds"] == pytest.approx(ns / 1e9)
+        assert got[P.STEP_SPAN]["count"] == len(log_of(eng))
+        agg = serve.stats()["phase_s"]
+        assert agg[P.STEP_SPAN] == got[P.STEP_SPAN]
+
+    def test_tokens_still_match_generate(self, params, paged, spmd):
+        eng = make_engine(params, paged, spmd)
+        prompts = [np.arange(1, 6), np.arange(7, 10), np.arange(20, 29)]
+        rids = [eng.submit(p) for p in prompts]
+        out = eng.run()
+        for p, rid in zip(prompts, rids):
+            ref = T.generate(CFG, params,
+                             jnp.asarray(p, jnp.int32)[None, :], 3,
+                             dtype=jnp.float64)
+            np.testing.assert_array_equal(out[rid], np.asarray(ref[0]))
+
+
+@pytest.mark.parametrize("spmd", [False, True], ids=["eager", "spmd"])
+def test_chunked_prefill_spans_one_set_per_chunk(params, spmd):
+    """A prompt prefilled chunk by chunk: every step that ran a chunk
+    has one prefill and one install span with the request's id, the
+    first token comes with the last chunk, and ``install_writes``
+    counts the pages each chunk touched."""
+    eng = make_engine(params, True, spmd, prefill_chunk=BLOCK)
+    rid = eng.submit(np.arange(3 * BLOCK) % CFG.vocab)
+    for _ in range(3):
+        eng.step()
+    log = log_of(eng)
+    assert [names(r).count(E.SPAN_PLAN) for r in log] == [1, 0, 0]
+    for rec in log:
+        for phase in (E.SPAN_PREFILL, E.SPAN_INSTALL):
+            (span,) = [s for s in rec["spans"] if s[0] == phase]
+            assert span[3] == rid
+        assert rec["prefill_tokens"] == BLOCK
+        assert rec["install_writes"] == LEAVES
+    assert [names(r).count(E.SPAN_FIRST_TOKEN) for r in log] == [0, 0, 1]
+    assert [r["admitted"] for r in log] == [0, 0, 1]
+    assert [r["active"] for r in log] == [0, 0, 1]
+
+
+def test_deferred_admission_still_plans(params):
+    """A request the pool cannot hold yet is planned (and left queued)
+    every step: a plan span with its id and no prefill."""
+    eng = make_engine(params, True, False, num_blocks=3)
+    eng.submit(np.arange(1, 9), rid="a", max_new=4)     # 2 pages + 1
+    eng.submit(np.arange(11, 19), rid="b", max_new=4)
+    eng.step()
+    (rec,) = log_of(eng)
+    plans = [s for s in rec["spans"] if s[0] == E.SPAN_PLAN]
+    assert [s[3] for s in plans] == ["a", "b"]
+    assert names(rec).count(E.SPAN_PREFILL) == 1
+    assert rec["admitted"] == 1
+
+
+class TestStepLog:
+    def test_capped(self):
+        assert P._STEP_LOG.maxlen == P.STEP_LOG_CAP == 8192
+        stats = P.ServeStats()
+        extra = 5
+        for _ in range(P.STEP_LOG_CAP + extra):
+            with stats.span(P.STEP_SPAN):
+                pass
+        log = P.serve_step_log()
+        assert len(log) == P.STEP_LOG_CAP
+        assert [r["t0_ns"] for r in log] == sorted(r["t0_ns"] for r in log)
+        # The phase totals are never capped.
+        assert stats.snapshot()["phase_s"][P.STEP_SPAN]["count"] \
+            == P.STEP_LOG_CAP + extra
+
+    def test_outlives_its_engine_and_is_reset(self, params):
+        eng = make_engine(params, True, False)
+        eng.submit(np.arange(1, 6))
+        eng.run()
+        serial, steps = eng.stats.engine, len(log_of(eng))
+        del eng
+        gc.collect()
+        assert serve.stats()["n_engines"] == 0
+        log = P.serve_step_log()
+        assert len([r for r in log if r["engine"] == serial]) == steps > 0
+        log.clear()                       # a copy: the ring is untouched
+        assert len(P.serve_step_log()) == steps
+        P.reset_serve_stats()
+        assert P.serve_step_log() == []
+
+    def test_reset_clears_the_phase_totals(self, params):
+        eng = make_engine(params, False, False)
+        eng.submit(np.arange(1, 6))
+        eng.run()
+        assert eng.stats.snapshot()["phase_s"]
+        eng.stats.reset()
+        assert eng.stats.snapshot()["phase_s"] == {}
+        assert eng.stats.counters["install_writes"] == 0
+
+    def test_engines_have_their_own_serials(self, params):
+        a = make_engine(params, False, False)
+        b = make_engine(params, False, False)
+        assert a.stats.engine != b.stats.engine
+        a.step()
+        b.step()
+        b.step()
+        assert (len(log_of(a)), len(log_of(b))) == (1, 2)
+
+    def test_span_outside_a_step_is_not_recorded(self):
+        stats = P.ServeStats()
+        with stats.span(E.SPAN_FETCH):
+            pass
+        assert P.serve_step_log() == []
+        assert stats.snapshot()["phase_s"] == {}
+
+    def test_a_step_that_raises_is_still_logged(self):
+        stats = P.ServeStats()
+        with pytest.raises(RuntimeError):
+            with stats.span(P.STEP_SPAN):
+                with stats.span(E.SPAN_FETCH):
+                    raise RuntimeError("lost the device")
+        (rec,) = P.serve_step_log()
+        assert names(rec) == [E.SPAN_FETCH, P.STEP_SPAN]
+
+    def test_rid_may_be_set_inside_the_block(self):
+        stats = P.ServeStats()
+        with stats.span(P.STEP_SPAN):
+            with stats.span(E.SPAN_PLAN) as plan:
+                plan.rid = "late"
+        (rec,) = P.serve_step_log()
+        assert rec["spans"][0][3] == "late"
+
+
+def test_spans_cost_microseconds():
+    """The always-on promise: an empty span costs microseconds, not a
+    step's worth (the device number is in PERF.md; this holds the order
+    of magnitude wherever the tests run)."""
+    import time
+
+    stats = P.ServeStats()
+    n = 20_000
+    with stats.span(P.STEP_SPAN):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            with stats.span(E.SPAN_FETCH):
+                pass
+        per_span = (time.perf_counter() - t0) / n
+    assert per_span < 50e-6
+
+
+def test_spans_are_in_a_profiler_capture(params, tmp_path):
+    """Under a profiler session the spans are TraceAnnotations on the
+    capture's host plane, beside whatever device lines it has."""
+    eng = make_engine(params, True, True)
+    eng.submit(np.arange(1, 6))
+    eng.step()                            # compile outside the capture
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        eng.step()
+    (pb,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(pb)
+    seen = {e.name for plane in data.planes if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events
+            if e.name.startswith(P.STEP_SPAN)}
+    assert {E.SPAN_FETCH, E.SPAN_DISPATCH, E.SPAN_SELECT,
+            P.STEP_SPAN} <= seen
+
+
+def test_new_counter_is_mirrored(params):
+    """``install_writes`` rides the counters: in ``serve.stats()`` and
+    the registry guard's mirrored set."""
+    from mpi4torch_tpu.analyze import registry
+
+    eng = make_engine(params, True, False)
+    eng.submit(np.arange(1, 6))
+    eng.run()
+    assert serve.stats()["install_writes"] == 2 * LEAVES
+    assert registry.serve_paging_problems() == []
