@@ -51,6 +51,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
 from ..comm import COMM_WORLD
 from ..models.transformer import TransformerConfig, select_token
@@ -394,14 +395,32 @@ class Engine:
             self._mgr = None
             self._table = None
             self._chunk = None
-        if self._spmd:
-            # Stacked per-rank state: leading (size,) axis — exactly the
-            # rank-major layout run_spmd's outputs carry, so the state
-            # round-trips step to step unchanged.
-            cache = jax.tree.map(
-                lambda a: jnp.broadcast_to(a[None], (self._size,)
-                                           + a.shape), cache)
+        # Stacked per-rank state under SPMD: leading (size,) axis —
+        # exactly the rank-major layout run_spmd's outputs carry, and
+        # laid out as they are (read off the shards it just produced),
+        # so the state round-trips step to step unchanged and the paged
+        # install can reuse its buffers from the first call.  A paged
+        # pool also gets a buffer of its own per leaf (the template
+        # shares one): the install donates the whole pool, and one
+        # buffer cannot be donated twice in a call.  All of it leaf by
+        # leaf: a second whole pool, even for a moment, would be the
+        # peak of the process.
+        state = jax.tree.leaves(self._shards)[0].sharding \
+            if self._spmd else None
+
+        def own(a):
+            if self._spmd:
+                a = jax.device_put(
+                    jnp.broadcast_to(a[None], (self._size,) + a.shape),
+                    state)
+            return jnp.copy(a) if self._paged else a
+
+        cache = jax.tree.map(own, cache)
         self._cache = cache
+        # Built here, first called in step(): the engine may be
+        # constructed with jit disabled.
+        self._install_call = self._build_install() if self._paged \
+            else None
         self._cache_leaves = len(jax.tree.leaves(cache))
         self._tokens = np.zeros((slots,), np.int32)
         self._pos = np.zeros((slots,), np.int32)
@@ -693,34 +712,51 @@ class Engine:
                 lambda s: s.at[dst].set(s[src]), self._cache)
         self.stats.count("cow_copies")
 
+    def _build_install(self):
+        """:func:`~mpi4torch_tpu.serve.kv.install_rows_paged` compiled
+        with the pool donated.  Rank-local, no collective: a plain
+        ``jax.jit``; under SPMD each rank writes its own slice of the
+        stacked state in place, and the output keeps the state's
+        layout."""
+        if not self._spmd:
+            return jax.jit(_kv.install_rows_paged, donate_argnums=0)
+        state = jax.tree.leaves(self._cache)[0].sharding
+
+        def per_rank(pool, rows, index):
+            (pool, rows) = jax.tree.map(lambda a: a[0], (pool, rows))
+            return jax.tree.map(
+                lambda a: a[None],
+                _kv.install_rows_paged(pool, rows, index))
+
+        return jax.jit(
+            jax.shard_map(per_rank, mesh=state.mesh,
+                          in_specs=(state.spec, state.spec,
+                                    PartitionSpec()),
+                          out_specs=state.spec, check_vma=False),
+            donate_argnums=0)
+
     def _install_rows(self, j: int, rows, lo: int, hi: int) -> None:
         """Write prefill K/V rows covering positions ``lo..hi-1`` of
         slot ``j`` into its pages.  ``rows`` is the per-layer
         ``[{"k","v"}]`` prefill output with the row axis starting at
         ``lo`` (a full-prompt prefill passes ``lo=0`` and may carry
-        trailing rows beyond ``hi``; they are ignored).  Installs are
-        plain ``.at[].set`` at CONCRETE page ids — exact bits, and the
-        write targets are private pages by the COW rule."""
+        trailing rows beyond ``hi``; they are ignored).  ONE dispatch
+        of the compiled install whatever the range: page ids, offset
+        and length are data, the pool is donated and written in place
+        — exact bits, and the write targets are private pages by the
+        COW rule.  Whoever held ``self._cache``'s old leaves holds
+        deleted arrays afterwards."""
         bs = self.serve_cfg.block_size
-        pages = range(lo // bs, -(-hi // bs))
-        self.stats.count("install_writes",
-                         len(pages) * self._cache_leaves)
-        for bi in pages:
-            b = int(self._table[j, bi])
-            r0, r1 = max(lo, bi * bs), min(hi, (bi + 1) * bs)
-            o0 = r0 - bi * bs
-            if self._spmd:
-                self._cache = jax.tree.map(
-                    lambda s, r, b=b, o0=o0, r0=r0, r1=r1:
-                    s.at[:, b, o0:o0 + (r1 - r0)].set(
-                        r[:, 0, r0 - lo:r1 - lo].astype(s.dtype)),
-                    self._cache, rows)
-            else:
-                self._cache = jax.tree.map(
-                    lambda s, r, b=b, o0=o0, r0=r0, r1=r1:
-                    s.at[b, o0:o0 + (r1 - r0)].set(
-                        r[0, r0 - lo:r1 - lo].astype(s.dtype)),
-                    self._cache, rows)
+        first = lo // bs
+        touched = -(-hi // bs) - first
+        n_pages = _kv.install_page_count(rows[0]["k"].shape[-3], bs)
+        index = np.empty(2 + n_pages, np.int32)
+        index[0], index[1] = lo % bs, hi - lo
+        # Beyond the touched pages: ids outside the pool, dropped.
+        index[2:] = self._mgr.num_blocks + np.arange(n_pages)
+        index[2:2 + touched] = self._table[j, first:first + touched]
+        self._cache = self._install_call(self._cache, rows, index)
+        self.stats.count("install_writes")
 
     def _gather_past(self, j: int, n: int):
         """Exact-length past K/V (positions ``0..n-1``) for slot ``j``,

@@ -62,6 +62,8 @@ __all__ = [
     "shard_params_tp",
     "init_kv_cache_tp",
     "init_kv_pool_tp",
+    "install_page_count",
+    "install_rows_paged",
     "prefill_tp",
     "prefill_chunk_tp",
     "decode_step_tp",
@@ -212,6 +214,53 @@ def init_kv_pool_tp(cfg: TransformerConfig, num_blocks: int,
     shape = (num_blocks, block_size, cfg.kv_heads // size, hd)
     buf = jnp.zeros(shape, dtype)
     return [{"k": buf, "v": buf} for _ in range(cfg.n_layers)]
+
+
+def install_page_count(n_rows: int, block_size: int) -> int:
+    """Pages that ``n_rows`` consecutive positions can touch when the
+    first may sit anywhere inside a page: the static page count of
+    :func:`install_rows_paged` for rows of that length."""
+    return (n_rows + 2 * block_size - 2) // block_size
+
+
+def install_rows_paged(pool, rows, index):
+    """Write prefill K/V rows into one rank's page pool: the engine
+    compiles this once per ``rows`` shape and calls it with ``pool``
+    donated, so the pages are written in place.
+
+    ``pool`` is :func:`init_kv_pool_tp`'s tree; ``rows`` the same tree
+    with ``(1, R, kv_heads/size, head_dim)`` leaves, row 0 being the
+    first position written.  Everything that changes from install to
+    install is in ``index``, an int32 vector ``[off, n, id_0, ...,
+    id_{P-1}]`` with ``P = install_page_count(R, block_size)``: rows
+    ``0..n-1`` land at offsets ``off, off+1, ...`` of page ``id_0``
+    and run on through ``id_1, ...``; rows from ``n`` on are ignored.
+    Ids beyond the pages those rows touch must lie outside the pool
+    (and differ from each other): the scatter drops them.
+
+    Exact bits: rows are cast to the pool's dtype and copied; a first
+    or last page written in part keeps its other rows (the page is
+    read, merged, and written back whole).  The device work is a few
+    passes over ``R`` rows per leaf, never over the pool."""
+    off, n, ids = index[0], index[1], index[2:]
+
+    def leaf(p, r):
+        bs = p.shape[1]
+        n_pages = install_page_count(r.shape[1], bs)
+        if ids.shape[0] != n_pages:
+            raise ValueError(
+                f"install index names {ids.shape[0]} pages; rows of "
+                f"{r.shape[1]} positions in pages of {bs} need {n_pages}")
+        at = jnp.arange(n_pages * bs, dtype=jnp.int32)
+        written = ((at >= off) & (at < off + n)).reshape(n_pages, bs, 1, 1)
+        new = jax.lax.dynamic_update_slice_in_dim(
+            jnp.zeros((n_pages * bs,) + p.shape[2:], p.dtype),
+            r[0].astype(p.dtype), off, 0)
+        old = jnp.take(p, ids, axis=0, mode="clip")
+        pages = jnp.where(written, new.reshape(old.shape), old)
+        return p.at[ids].set(pages, mode="drop", unique_indices=True)
+
+    return jax.tree.map(leaf, pool, rows)
 
 
 def _tp_size(cfg: TransformerConfig, shards) -> int:

@@ -1040,6 +1040,195 @@ class TestPagedNoRetrace:
         assert txt1.count('"stablehlo.gather"') >= 2 * CFG.n_layers
 
 
+def _loop_install(eng, pool, rows, j, lo, hi):
+    """The per-page, per-leaf install the engine had before ISSUE 26,
+    kept here as the reference: one plain ``.at[].set`` at concrete
+    page ids for every page and cache leaf.  Returns the new pool."""
+    bs = eng.serve_cfg.block_size
+    for bi in range(lo // bs, -(-hi // bs)):
+        b = int(eng._table[j, bi])
+        r0, r1 = max(lo, bi * bs), min(hi, (bi + 1) * bs)
+        o0 = r0 - bi * bs
+        if eng._spmd:
+            pool = jax.tree.map(
+                lambda s, r: s.at[:, b, o0:o0 + (r1 - r0)].set(
+                    r[:, 0, r0 - lo:r1 - lo].astype(s.dtype)), pool, rows)
+        else:
+            pool = jax.tree.map(
+                lambda s, r: s.at[b, o0:o0 + (r1 - r0)].set(
+                    r[0, r0 - lo:r1 - lo].astype(s.dtype)), pool, rows)
+    return pool
+
+
+def _seeded_pool(eng, seed):
+    """The engine's pool refilled with seeded random bits, laid out
+    as it was."""
+    rng = np.random.default_rng(seed)
+    return jax.tree.map(
+        lambda a: jax.device_put(
+            jnp.asarray(rng.standard_normal(a.shape), a.dtype),
+            a.sharding), eng._cache)
+
+
+def _seeded_rows(eng, seed, n_rows, dtype=None):
+    """Seeded prefill rows of ``n_rows`` positions for the engine's
+    pool: ``(1, n_rows, kvh, hd)`` leaves, stacked under SPMD."""
+    rng = np.random.default_rng(seed)
+    return jax.tree.map(
+        lambda a: jnp.asarray(
+            rng.standard_normal(a.shape[:-4] + (1, n_rows) + a.shape[-2:]),
+            dtype or a.dtype), eng._cache)
+
+
+def _positions(eng, j, lo, hi):
+    """``(page id, offset)`` of positions ``lo..hi-1`` of slot ``j``."""
+    bs = eng.serve_cfg.block_size
+    return [(int(eng._table[j, t // bs]), t % bs) for t in range(lo, hi)]
+
+
+class TestCompiledInstall:
+    """ISSUE 26: ``Engine._install_rows`` is one compiled, donated call
+    whatever the range; page ids, offset and length are data."""
+
+    BS, SLOTS = 4, 3
+    # (lo, hi, row positions); None = whole-prompt rows (max_seq).
+    RANGES = [
+        pytest.param(0, 8, None, id="whole-pages-from-0"),
+        pytest.param(0, 10, None, id="hi-inside-a-page"),
+        pytest.param(6, 12, 6, id="lo-inside-a-page"),
+        pytest.param(5, 7, 2, id="lo-and-hi-in-one-page"),
+        pytest.param(3, 10, 7, id="chunk-over-three-pages"),
+        pytest.param(9, 10, 1, id="one-token-suffix"),
+        pytest.param(4, 16, 12, id="whole-pages-from-4"),
+    ]
+
+    def _engine(self, spmd, **kw):
+        return serve.Engine(
+            CFG, _params(CFG),
+            serve.ServeConfig(slots=self.SLOTS, block_size=self.BS, **kw),
+            spmd=spmd, nranks=2 if spmd else None)
+
+    @pytest.mark.parametrize("spmd", [False, True], ids=["eager", "spmd"])
+    @pytest.mark.parametrize("lo,hi,n_rows", RANGES)
+    def test_bitwise_vs_per_page_loop(self, spmd, lo, hi, n_rows):
+        eng = self._engine(spmd)
+        j = 1
+        # Scattered, non-monotone page ids: nothing may lean on order.
+        eng._table[j, :] = [7, 2, 11, 5, 16, 0]
+        eng._cache = _seeded_pool(eng, 26)
+        before = jax.tree.map(np.asarray, eng._cache)
+        rows = _seeded_rows(eng, 27, n_rows or CFG.max_seq)
+        want = jax.tree.map(
+            np.asarray, _loop_install(eng, eng._cache, rows, j, lo, hi))
+        writes = eng.stats.counters.get("install_writes", 0)
+        eng._install_rows(j, rows, lo, hi)
+        assert eng.stats.counters["install_writes"] == writes + 1
+        got = jax.tree.map(np.asarray, eng._cache)
+        written = np.zeros(before[0]["k"].shape[-4:-2], bool)
+        for b, o in _positions(eng, j, lo, hi):
+            written[b, o] = True
+        for g, w, b, r in zip(*map(jax.tree.leaves,
+                                   (got, want, before, rows))):
+            np.testing.assert_array_equal(g, w)
+            # Every row outside lo..hi-1 keeps its bits; the rows
+            # inside are the prefill's.
+            np.testing.assert_array_equal(g[..., ~written, :, :],
+                                          b[..., ~written, :, :])
+            np.testing.assert_array_equal(
+                np.stack([g[..., b, o, :, :]
+                          for b, o in _positions(eng, j, lo, hi)], -3),
+                np.asarray(r)[..., 0, :hi - lo, :, :])
+        if spmd:
+            # The state keeps the layout run_spmd's outputs carry.
+            assert jax.tree.leaves(eng._cache)[0].sharding \
+                == jax.tree.leaves(eng._shards)[0].sharding
+
+    @pytest.mark.parametrize("spmd", [False, True], ids=["eager", "spmd"])
+    def test_rows_are_cast_to_the_pool_dtype(self, spmd):
+        eng = self._engine(spmd, cache_dtype=jnp.float32)
+        eng._table[0, :3] = [4, 9, 1]
+        eng._cache = _seeded_pool(eng, 28)
+        rows = _seeded_rows(eng, 29, 7, jnp.float64)
+        want = jax.tree.map(
+            np.asarray, _loop_install(eng, eng._cache, rows, 0, 2, 9))
+        eng._install_rows(0, rows, 2, 9)
+        for g, w in zip(jax.tree.leaves(eng._cache),
+                        jax.tree.leaves(want)):
+            assert g.dtype == jnp.float32
+            np.testing.assert_array_equal(np.asarray(g), w)
+
+    @pytest.mark.parametrize("spmd", [False, True], ids=["eager", "spmd"])
+    def test_one_program_for_every_whole_prompt_install(self, spmd):
+        """Different page ids, slots and prompt lengths, with decode
+        steps (page churn, the state's round trip through the step) in
+        between: the second install compiles nothing."""
+        events = []
+
+        def on(event, _secs, **_):
+            if event == "/jax/core/compile/backend_compile_duration":
+                events.append(event)
+
+        eng = self._engine(spmd)
+        ra = eng.submit(np.arange(1, 4), max_new=6)       # 1 page
+        eng.step()
+        eng.step()
+        # (Eager engines share one jit, and with it its programs.)
+        programs = eng._install_call._cache_size()
+        # Rows as the prefill hands them over (its own program
+        # compiles here, outside the count).
+        _, rows = eng._prefill_full(np.arange(5, 18))
+        eng._table[2, :4] = [15, 3, 12, 8]
+        jax.monitoring.register_event_duration_secs_listener(on)
+        try:
+            eng._install_rows(2, rows, 0, 13)              # 4 pages
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on)
+        assert events == []
+        eng._table[2, :] = -1
+        rb = eng.submit(np.arange(5, 16), max_new=4)      # 3 pages
+        res = eng.run()
+        assert eng._install_call._cache_size() == programs
+        assert eng.stats.counters["install_writes"] == 3
+        np.testing.assert_array_equal(
+            res[ra], oracle_tokens(CFG, _params(CFG), np.arange(1, 4), 6))
+        np.testing.assert_array_equal(
+            res[rb], oracle_tokens(CFG, _params(CFG), np.arange(5, 16), 4))
+
+    @pytest.mark.parametrize("spmd", [False, True],
+                             ids=["eager", "spmd-built-with-jit-off"])
+    def test_first_install_of_a_fresh_engine_donates(self, spmd):
+        """A fresh pool's leaves must be buffers of their own (the
+        templates of ``kv`` share one): one buffer cannot be donated
+        twice in a call.  The SPMD engine is built as the benchmark
+        builds it, with jit disabled, where the install must not be
+        called."""
+        if spmd:
+            with jax.disable_jit():
+                eng = self._engine(True)
+        else:
+            eng = self._engine(False)
+        leaves = jax.tree.leaves(eng._cache)
+        assert len({s.data.unsafe_buffer_pointer() for a in leaves
+                    for s in a.addressable_shards}) \
+            == len(leaves) * (2 if spmd else 1)
+        eng.submit(PROMPTS[1], max_new=4)
+        eng.step()
+        # Donated: the old leaves are gone, the engine holds new ones.
+        assert all(a.is_deleted() for a in leaves)
+        assert not any(a.is_deleted()
+                       for a in jax.tree.leaves(eng._cache))
+        np.testing.assert_array_equal(
+            eng.run()[0], oracle_tokens(CFG, _params(CFG), PROMPTS[1], 4))
+
+    def test_index_must_name_the_static_page_count(self):
+        pool = kv.init_kv_pool_tp(CFG, 6, 4, 1, jnp.float64)
+        rows = jax.tree.map(lambda a: a[None, :1, 0].repeat(5, 1), pool)
+        assert kv.install_page_count(5, 4) == 2
+        with pytest.raises(ValueError, match="need 2"):
+            kv.install_rows_paged(pool, rows,
+                                  np.array([0, 5, 1, 2, 3], np.int32))
+
+
 class TestPagedDrainReadmit:
     def test_tickets_carry_pages_and_readmit_prefix_hits(self):
         # Satellite 6: a drained paged request's ticket carries its

@@ -146,9 +146,9 @@ class TestStepSpans:
             counts.append(len(rec["spans"]))
             writes.append(rec["install_writes"])
         assert counts[0] == counts[1] == 6 + 4
-        # install_writes = pages x cache leaves (dense: one write a leaf).
-        assert writes == ([LEAVES, 8 * LEAVES] if paged
-                          else [LEAVES, LEAVES])
+        # install_writes: one dispatch an install, however many pages
+        # (dense: one eager write a cache leaf).
+        assert writes == ([1, 1] if paged else [LEAVES, LEAVES])
 
     def test_phase_totals_sum_the_log(self, params, paged, spmd):
         eng = make_engine(params, paged, spmd)
@@ -186,7 +186,7 @@ def test_chunked_prefill_spans_one_set_per_chunk(params, spmd):
     """A prompt prefilled chunk by chunk: every step that ran a chunk
     has one prefill and one install span with the request's id, the
     first token comes with the last chunk, and ``install_writes``
-    counts the pages each chunk touched."""
+    counts one dispatch a chunk."""
     eng = make_engine(params, True, spmd, prefill_chunk=BLOCK)
     rid = eng.submit(np.arange(3 * BLOCK) % CFG.vocab)
     for _ in range(3):
@@ -198,7 +198,7 @@ def test_chunked_prefill_spans_one_set_per_chunk(params, spmd):
             (span,) = [s for s in rec["spans"] if s[0] == phase]
             assert span[3] == rid
         assert rec["prefill_tokens"] == BLOCK
-        assert rec["install_writes"] == LEAVES
+        assert rec["install_writes"] == 1
     assert [names(r).count(E.SPAN_FIRST_TOKEN) for r in log] == [0, 0, 1]
     assert [r["admitted"] for r in log] == [0, 0, 1]
     assert [r["active"] for r in log] == [0, 0, 1]
@@ -337,5 +337,5 @@ def test_new_counter_is_mirrored(params):
     eng = make_engine(params, True, False)
     eng.submit(np.arange(1, 6))
     eng.run()
-    assert serve.stats()["install_writes"] == 2 * LEAVES
+    assert serve.stats()["install_writes"] == 1
     assert registry.serve_paging_problems() == []
