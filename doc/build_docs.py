@@ -24,7 +24,8 @@ from pathlib import Path
 
 DOC = Path(__file__).resolve().parent
 OUT = DOC / "html"
-PAGES = ["index", "basic_usage", "examples", "parallelism", "serving",
+PAGES = ["index", "basic_usage", "examples", "parallelism", "layer_spec",
+         "serving",
          "compression", "fusion", "algorithms", "schedule_ir", "overlap",
          "resilience", "reshard", "elasticity", "transport", "analysis",
          "observability", "self_tuning", "api_reference",

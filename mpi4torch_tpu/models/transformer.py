@@ -10,6 +10,11 @@ movement is an ``MPI_Communicator`` op, so the same model runs on the eager
 thread-SPMD runtime, inside ``run_spmd``, or in a user-managed 2D
 ``shard_map`` via ``comm_from_mesh`` (the intended TPU deployment).
 
+A configuration may state its stack as data (``TransformerConfig.layers``,
+one :class:`LayerSpec` a layer): Kimi Delta Attention or latent attention
+as the mixer, the held share of a top-k expert layer as the FFN
+(doc/layer_spec.md).  Training path only.
+
 TPU-first shapes: all compute is batched matmul/einsum (MXU), parameters
 and activations stay in the caller's dtype (bfloat16-ready), and the
 sequence axis per rank is static so XLA tiles cleanly.
@@ -17,20 +22,67 @@ sequence axis per rank is static so XLA tiles cleanly.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..constants import MPI_SUM
-from ..ops.flash import flash_attention, flash_block_attention
+from ..ops.flash import flash_attention, flash_block_attention, \
+    merge_partials
+from ..ops.kda import kda_chunked
 from ..parallel.attention import ring_attention, \
     ulysses_attention, zigzag_ring_attention
 from ..parallel.dp import all_average_tree
-from ..parallel.moe import init_moe, moe_ffn, moe_ffn_dense
+from ..parallel.moe import Experts, held_experts_ffn, init_experts, \
+    init_moe, moe_ffn, moe_ffn_dense
 from ..parallel.zero import zero3_step, zero_step
 from ..parallel.ring import ring_shift
+from ..runtime import CommError
+from ..utils.profiling import layer_scope
+
+
+# What a rematerialised mixer keeps of its forward pass.
+_SAVED_IN_REMAT = jax.checkpoint_policies.save_only_these_names("kda_out")
+
+
+@dataclass(frozen=True)
+class KDA:
+    """Mixer: Kimi Delta Attention (ops/kda.py).  ``n_heads`` heads of
+    ``head_dim`` for keys and values alike, a causal depthwise
+    convolution of ``conv`` taps on q, k and v, decay and output gate
+    each through a low-rank pair of width ``head_dim``."""
+    n_heads: int
+    head_dim: int
+    conv: int = 4
+
+
+@dataclass(frozen=True)
+class MLA:
+    """Mixer: multi-head latent attention without rotation.  Keys and
+    values come up from one latent of ``kv_rank``; a key is ``qk_nope``
+    channels of its own head plus ``qk_rope`` channels shared by all
+    heads (not rotated); a value has ``v_dim`` channels."""
+    n_heads: int
+    kv_rank: int
+    qk_nope: int
+    qk_rope: int
+    v_dim: int
+
+
+@dataclass(frozen=True)
+class LayerSpec:
+    """One layer of a stack whose layers differ.  ``mixer``: ``None`` is
+    the configuration's own attention (``n_heads``, ``n_kv_heads``,
+    ``rope``, ``attn_window``), else a :class:`KDA` or an :class:`MLA`.
+    ``ffn``: ``None`` is the configuration's dense FFN (``ffn``,
+    ``d_ff``), else the :class:`~mpi4torch_tpu.parallel.moe.Experts`
+    share this rank holds."""
+    mixer: Union[None, KDA, MLA] = None
+    ffn: Optional[Experts] = None
 
 
 @dataclass(frozen=True)
@@ -66,8 +118,26 @@ class TransformerConfig:
     capacity: int = 0
     aux_coef: float = 0.01
     remat: bool = False
+    layers: Tuple[LayerSpec, ...] = ()
 
     def __post_init__(self):
+        if self.layers:
+            # The stack stated as data: one LayerSpec a layer.  Without
+            # one every layer is LayerSpec() and nothing changes.
+            if len(self.layers) != self.n_layers:
+                raise ValueError(
+                    f"layers has {len(self.layers)} entries for n_layers="
+                    f"{self.n_layers}")
+            if self.n_experts > 0:
+                raise ValueError(
+                    "a layer spec names its expert layers itself "
+                    "(LayerSpec.ffn); n_experts is the uniform top-1 MoE")
+            if any(s.mixer is None and s.ffn is not None
+                   for s in self.layers):
+                raise ValueError(
+                    "an expert FFN needs a KDA or MLA mixer: the "
+                    "configuration's own attention block carries its own "
+                    "FFN")
         if self.n_experts > 0 and self.capacity <= 0:
             # capacity=0 would silently capacity-drop every token — the
             # model would train with no FFN path at all.
@@ -103,6 +173,10 @@ class TransformerConfig:
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
 
+    @property
+    def layer_specs(self) -> Tuple[LayerSpec, ...]:
+        return self.layers or (LayerSpec(),) * self.n_layers
+
 
 def init_transformer(key, cfg: TransformerConfig,
                      dtype=jnp.float32) -> Dict[str, Any]:
@@ -137,18 +211,26 @@ def init_transformer(key, cfg: TransformerConfig,
             pos_key, (max_seq, d_model), dtype) * 0.02
     params["ln_f"] = norm_p()
     params["unembed"] = dense(next(keys), d_model, vocab)
-    for _ in range(n_layers):
+    for spec in cfg.layer_specs:
         # Fused projection: h q-heads plus 2*h_kv KV heads (= 3*d_model
         # for plain MHA; smaller under GQA).
         hd = d_model // cfg.n_heads
-        blk = {
-            "ln1": norm_p(),
-            "wqkv": dense(next(keys), d_model,
-                          d_model + 2 * cfg.kv_heads * hd),
-            "wo": dense(next(keys), d_model, d_model),
-            "ln2": norm_p(),
-        }
-        if cfg.n_experts > 0:
+        if spec.mixer is None:
+            blk = {
+                "ln1": norm_p(),
+                "wqkv": dense(next(keys), d_model,
+                              d_model + 2 * cfg.kv_heads * hd),
+                "wo": dense(next(keys), d_model, d_model),
+                "ln2": norm_p(),
+            }
+        else:
+            blk = {"ln1": norm_p(), "ln2": norm_p(),
+                   "mixer": _init_mixer(next(keys), spec.mixer, d_model,
+                                        dtype)}
+        if spec.ffn is not None:
+            blk["experts"] = init_experts(next(keys), spec.ffn, d_model,
+                                          dtype)
+        elif cfg.n_experts > 0:
             blk["moe"] = init_moe(next(keys), cfg.n_experts, d_model, d_ff,
                                   dtype)
         elif cfg.ffn == "swiglu":
@@ -160,6 +242,38 @@ def init_transformer(key, cfg: TransformerConfig,
             blk["w2"] = dense(next(keys), d_ff, d_model)
         params["blocks"].append(blk)
     return params
+
+
+def _init_mixer(key, spec, d_model: int, dtype) -> Dict[str, Any]:
+    """Leaves of a :class:`KDA` or :class:`MLA` mixer."""
+    ks = iter(jax.random.split(key, 10))
+
+    def dense(m, n):
+        return jax.random.normal(next(ks), (m, n), dtype) / jnp.sqrt(
+            jnp.asarray(m, dtype))
+
+    if isinstance(spec, MLA):
+        h = spec.n_heads
+        return {"wq": dense(d_model, h * (spec.qk_nope + spec.qk_rope)),
+                "wa": dense(d_model, spec.kv_rank + spec.qk_rope),
+                "kv_norm": {"scale": jnp.ones((spec.kv_rank,), dtype)},
+                "wb": dense(spec.kv_rank, h * (spec.qk_nope + spec.v_dim)),
+                "wo": dense(h * spec.v_dim, d_model)}
+    h, hd = spec.n_heads, spec.head_dim
+    # Decay as the delta-rule models start it: exp(a_log) in [1, 16],
+    # softplus(dt_bias) in [1e-3, 1e-1].
+    dt = jnp.exp(jax.random.uniform(next(ks), (h * hd,), jnp.float32,
+                                    jnp.log(1e-3), jnp.log(1e-1)))
+    return {"wqkv": dense(d_model, 3 * h * hd),
+            "conv": dense(spec.conv, 3 * h * hd),
+            "wf1": dense(d_model, hd), "wf2": dense(hd, h * hd),
+            "dt_bias": jnp.log(jnp.expm1(dt)).astype(dtype),
+            "a_log": jnp.log(jax.random.uniform(
+                next(ks), (h,), jnp.float32, 1.0, 16.0)).astype(dtype),
+            "wg1": dense(d_model, hd), "wg2": dense(hd, h * hd),
+            "wb": dense(d_model, h),
+            "norm": {"scale": jnp.ones((hd,), dtype)},
+            "wo": dense(h * hd, d_model)}
 
 
 def _layer_norm(x, p):
@@ -237,6 +351,133 @@ def _split_qkv(cfg: TransformerConfig, blk, y, positions=None):
         q = _rope_rotate(cfg, q, positions)
         k = _rope_rotate(cfg, k, positions)
     return q, k, v
+
+
+@jax.custom_vjp
+def _causal_conv(x, w):
+    """Depthwise causal convolution over the sequence: ``out_t = sum_j
+    w[j] x_{t - (taps - 1) + j}``, zeros before the sequence's start.
+    ``x`` (b, s, c), ``w`` (taps, c).  Sums in float32; only ``x`` and
+    ``w`` are kept for the way back, which is the same sum run the other
+    way (autodiff would keep a float32 copy of ``x`` per tap)."""
+    return _shifted_sum(x, w, lead=True)
+
+
+def _shifted_sum(x, w, lead: bool):
+    """``sum_j w[j] * (x shifted by taps - 1 - j)``: towards later
+    positions (``lead``, the convolution) or towards earlier ones (its
+    adjoint, with the taps reversed)."""
+    taps, s = w.shape[0], x.shape[1]
+    ct = jnp.promote_types(x.dtype, jnp.float32)
+    pad = (taps - 1, 0) if lead else (0, taps - 1)
+    xp = jnp.pad(x, ((0, 0), pad, (0, 0))).astype(ct)
+    return sum(xp[:, j:j + s] * w[j].astype(ct)
+               for j in range(taps)).astype(x.dtype)
+
+
+def _causal_conv_bwd(res, g):
+    x, w = res
+    taps, s = w.shape[0], x.shape[1]
+    ct = jnp.promote_types(x.dtype, jnp.float32)
+    xp = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    dw = jnp.stack([jnp.sum(xp[:, j:j + s].astype(ct) * g.astype(ct),
+                            axis=(0, 1)) for j in range(taps)])
+    return _shifted_sum(g, w[::-1], lead=False), dw.astype(w.dtype)
+
+
+_causal_conv.defvjp(lambda x, w: (_causal_conv(x, w), (x, w)),
+                    _causal_conv_bwd)
+
+
+def _l2_norm(x):
+    ct = jnp.promote_types(x.dtype, jnp.float32)
+    xf = x.astype(ct)
+    return (xf * jax.lax.rsqrt(jnp.sum(xf * xf, -1, keepdims=True)
+                               + 1e-6)).astype(x.dtype)
+
+
+def _kda_mixer(spec: KDA, p, y):
+    """Kimi Delta Attention on the normed input ``y`` (b, s, d): silu of
+    a short causal convolution on q, k and v, q and k l2-normalised per
+    head, a per-channel log-decay ``-exp(a_log) softplus(low-rank(y) +
+    dt_bias)`` and a write strength ``sigmoid(y wb)`` in float32, the
+    chunked delta rule, then a per-head rmsnorm under a sigmoid gate."""
+    b, s, _ = y.shape
+    h, hd = spec.n_heads, spec.head_dim
+    ct = jnp.promote_types(y.dtype, jnp.float32)
+    heads = lambda t: t.reshape(b, s, h, hd)
+    q, k, v = map(heads, jnp.split(
+        jax.nn.silu(_causal_conv(y @ p["wqkv"], p["conv"])), 3, axis=-1))
+    rate = jax.nn.softplus(((y @ p["wf1"]) @ p["wf2"]).astype(ct)
+                           + p["dt_bias"].astype(ct))
+    g = -jnp.exp(p["a_log"].astype(ct))[:, None] * heads(rate)
+    beta = jax.nn.sigmoid((y @ p["wb"]).astype(ct))
+    # Named so that a rematerialised layer keeps it (_SAVED_IN_REMAT):
+    # the chunked rule already rematerialises itself head group by head
+    # group, and would otherwise run forward a third time.
+    o = checkpoint_name(kda_chunked(_l2_norm(q), _l2_norm(k), v, g, beta),
+                        "kda_out")
+    gate = jax.nn.sigmoid(heads(((y @ p["wg1"]) @ p["wg2"]).astype(ct)))
+    o = (_rms_norm(o.astype(ct), p["norm"]) * gate).astype(y.dtype)
+    return o.reshape(b, s, h * hd) @ p["wo"]
+
+
+_MLA_BLOCK = 2048
+
+
+def _blockwise_causal_attention(q, k, v, block: int):
+    """Causal attention over the whole sequence as a triangle of
+    ``block`` x ``block`` calls of the flash block primitive, the partials
+    of a query block merged exactly (``merge_partials``).  For head sizes
+    and lengths at which one call does not fit the kernels: a 192-wide
+    key is staged 256 wide, and at 8,192 of them Mosaic refuses the
+    forward's staging (16.38 MB of scoped VMEM against 16 MB) although
+    ``flash._eligible`` admits it; with 8,192 queries the backward
+    kernels decline (``flash._bwd_eligible``) and the backward would be
+    the tiled jnp path.  At 2,048 all three kernels run."""
+    s = q.shape[1]
+    if s <= block or s % block:
+        return flash_attention(q, k, v, causal=True)
+    cut = lambda x, i: x[:, i * block:(i + 1) * block]
+    outs = []
+    for i in range(s // block):
+        out = lse = None
+        for j in range(i + 1):
+            o_b, lse_b = flash_block_attention(
+                cut(q, i), cut(k, j), cut(v, j), causal=True,
+                q_offset=i * block, kv_offset=j * block)
+            out, lse = (o_b, lse_b) if out is None else \
+                merge_partials(out, lse, o_b, lse_b)
+        outs.append(out)
+    return jnp.concatenate(outs, axis=1)
+
+
+def _mla_mixer(spec: MLA, p, y):
+    """Latent attention without rotation on the normed input ``y``: the
+    flash kernels at query-key size ``qk_nope + qk_rope``, the value
+    zero-padded up to it (zeros add nothing to the weighted sum)."""
+    b, s, _ = y.shape
+    h, dn, dr, dv = spec.n_heads, spec.qk_nope, spec.qk_rope, spec.v_dim
+    q = (y @ p["wq"]).reshape(b, s, h, dn + dr)
+    latent = y @ p["wa"]
+    c, k_shared = latent[..., :spec.kv_rank], latent[..., spec.kv_rank:]
+    kv = (_rms_norm(c, p["kv_norm"]) @ p["wb"]).reshape(b, s, h, dn + dv)
+    k = jnp.concatenate(
+        [kv[..., :dn],
+         jnp.broadcast_to(k_shared[:, :, None, :], (b, s, h, dr))], axis=-1)
+    v = jnp.pad(kv[..., dn:], ((0, 0),) * 3 + ((0, dn + dr - dv),))
+    o = _blockwise_causal_attention(q, k, v, _MLA_BLOCK)[..., :dv]
+    return o.reshape(b, s, h * dv) @ p["wo"]
+
+
+def refuse_layer_spec(cfg: TransformerConfig, what: str) -> None:
+    """The serving entry points' answer to a configuration with a
+    per-layer spec (also ``serve.validate_tp``'s)."""
+    if cfg.layers:
+        raise CommError(
+            f"{what}: a configuration with a per-layer spec runs on the "
+            "training path only — the serving blocks know one kind of "
+            "layer and keep no latent, recurrent or expert state")
 
 
 def _ffn_residual(cfg: TransformerConfig, blk, x, comm_ep):
@@ -327,7 +568,21 @@ def forward(cfg: TransformerConfig, params, tokens, comm_sp=None,
     (batch, seq_local, d_model) INSTEAD of logits — the unembedding is
     skipped so :func:`lm_loss`'s chunked-vocab path can fold it into the
     online-logsumexp scan without ever materializing the logits.
+
+    With ``cfg.layers`` the loop walks the spec: each layer's mixer and
+    FFN are what its :class:`LayerSpec` names, the new kinds each under
+    its scope (``mpi4torch.kda`` / ``.mla`` / ``.moe``).
     """
+    out, aux, _ = _forward(cfg, params, tokens, comm_sp, attn, comm_ep,
+                           return_hidden)
+    return (out, aux) if return_aux else out
+
+
+def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
+             comm_ep, return_hidden: bool):
+    """:func:`forward` as ``(out, aux, rows)``: the summed load-balancing
+    loss, and the rows each held expert took in every expert layer of a
+    spec, ``(expert layers, held)`` (``None`` without such a layer)."""
     b, s_local = tokens.shape
     h = cfg.n_heads
     if comm_sp is not None and comm_sp.size > 1:
@@ -364,6 +619,12 @@ def forward(cfg: TransformerConfig, params, tokens, comm_sp=None,
     d = x.shape[-1]
     aux_total = jnp.zeros((), x.dtype)
 
+    if cfg.layers and comm_sp is not None and comm_sp.size > 1:
+        raise CommError(
+            "a per-layer spec does not compose with sequence parallelism: "
+            "the KDA state and the MLA keys are not carried across "
+            "sequence shards")
+
     def block_fn(x, blk):
         y = _norm(cfg, x, blk["ln1"])
         q, k, v = _split_qkv(cfg, blk, y, positions)
@@ -372,19 +633,46 @@ def forward(cfg: TransformerConfig, params, tokens, comm_sp=None,
         x, aux = _ffn_residual(cfg, blk, x, comm_ep)
         return x, aux
 
-    if cfg.remat:
-        block_fn = jax.checkpoint(block_fn)
-    for blk in params["blocks"]:
-        x, aux = block_fn(x, blk)
-        aux_total = aux_total + aux
+    def mixer_fn(spec, x, blk):
+        y = _norm(cfg, x, blk["ln1"])
+        if isinstance(spec.mixer, KDA):
+            with layer_scope("kda"):
+                return x + _kda_mixer(spec.mixer, blk["mixer"], y)
+        with layer_scope("mla"):
+            return x + _mla_mixer(spec.mixer, blk["mixer"], y)
+
+    def experts_fn(spec, x, blk):
+        with layer_scope("moe"):
+            y = _norm(cfg, x, blk["ln2"])
+            ff, taken = held_experts_ffn(y.reshape(-1, d), blk["experts"],
+                                         spec.ffn, comm_ep)
+        return x + ff.reshape(x.shape), taken
+
+    # With remat a uniform block is one rematerialised region; a new kind
+    # of mixer and an expert FFN are one each, so that the backward holds
+    # the temporaries of one of them at a time, not of both.
+    remat = functools.partial(jax.checkpoint, policy=_SAVED_IN_REMAT) \
+        if cfg.remat else (lambda f: f)
+    rows = []
+    for spec, blk in zip(cfg.layer_specs, params["blocks"]):
+        if spec.mixer is None and spec.ffn is None:
+            x, aux = (jax.checkpoint(block_fn) if cfg.remat
+                      else block_fn)(x, blk)
+            aux_total = aux_total + aux
+            continue
+        x = remat(functools.partial(mixer_fn, spec))(x, blk)
+        if spec.ffn is None:
+            x, _ = remat(lambda x_, blk_: _ffn_residual(
+                cfg, blk_, x_, comm_ep))(x, blk)
+        else:
+            x, taken = remat(functools.partial(experts_fn, spec))(x, blk)
+            rows.append(taken)
     x = _norm(cfg, x, params["ln_f"])
     if return_hidden:
         out = x
     else:
         out = x @ params["unembed"]
-    if return_aux:
-        return out, aux_total
-    return out
+    return out, aux_total, jnp.stack(rows) if rows else None
 
 
 def init_kv_cache(cfg: TransformerConfig, batch: int, dtype=jnp.float32):
@@ -393,6 +681,7 @@ def init_kv_cache(cfg: TransformerConfig, batch: int, dtype=jnp.float32):
     only the KV heads (the whole point: at ``n_kv_heads = n_heads/8`` the
     decode-time cache is 8x smaller, which is the HBM-resident state that
     bounds TPU batch size during serving)."""
+    refuse_layer_spec(cfg, "init_kv_cache")
     hd = cfg.d_model // cfg.n_heads
     shape = (batch, cfg.max_seq, cfg.kv_heads, hd)
     return [{"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -418,6 +707,7 @@ def decode_step(cfg: TransformerConfig, params, cache, tokens, pos):
     with ``n_experts > 0`` the equivalence holds only while capacity
     does not bind (decode routes ``batch`` tokens per step vs a whole
     batch x seq during training)."""
+    refuse_layer_spec(cfg, "decode_step")
     b = tokens.shape[0]
     try:
         # Concrete positions are checked eagerly: past max_seq the
@@ -487,6 +777,7 @@ def prefill(cfg: TransformerConfig, params, cache, prompt):
     training forward's compute shape — MXU-sized matmuls over the full
     prompt — rather than prompt_len sequential single-token steps) and
     return ``(last_logits (batch, vocab), new_cache)``."""
+    refuse_layer_spec(cfg, "prefill")
     b, p_len = prompt.shape
     x = params["embed"][prompt]
     if not cfg.rope:
@@ -551,6 +842,7 @@ def generate(cfg: TransformerConfig, params, prompt, n_new: int,
     token budget to avoid recompiles).  The cache dtype follows the
     parameters unless ``dtype`` overrides it.  Returns
     (batch, prompt_len + n_new) tokens."""
+    refuse_layer_spec(cfg, "generate")
     b, p_len = prompt.shape
     if p_len + n_new > cfg.max_seq:
         raise ValueError(
@@ -651,6 +943,14 @@ def lm_loss(cfg: TransformerConfig, params, tokens, comm_sp=None,
     SURVEY.md §3.3).  The final global position has no successor and is
     masked out; the sp-summed loss is normalized by the static global token
     count."""
+    return _lm_loss(cfg, params, tokens, comm_sp, attn, seq_global, comm_ep,
+                    vocab_chunk)[0]
+
+
+def _lm_loss(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
+             seq_global, comm_ep, vocab_chunk: int):
+    """:func:`lm_loss` as ``(loss, rows)``, with :func:`_forward`'s row
+    counts."""
     b, s_local = tokens.shape
     sp = comm_sp.size if comm_sp is not None else 1
     s_global = seq_global or sp * s_local
@@ -660,13 +960,9 @@ def lm_loss(cfg: TransformerConfig, params, tokens, comm_sp=None,
             f"vocab_chunk={vocab_chunk} must divide vocab={cfg.vocab}")
 
     want_hidden = bool(vocab_chunk) and vocab_chunk < cfg.vocab
-    if cfg.n_experts > 0:
-        out, aux = forward(cfg, params, tokens, comm_sp, attn,
-                           comm_ep=comm_ep, return_aux=True,
-                           return_hidden=want_hidden)
-    else:
-        out = forward(cfg, params, tokens, comm_sp, attn,
-                      return_hidden=want_hidden)
+    out, aux, rows = _forward(cfg, params, tokens, comm_sp, attn, comm_ep,
+                              want_hidden)
+    if cfg.n_experts == 0:
         aux = None
 
     if sp > 1 and attn == "zigzag":
@@ -719,7 +1015,7 @@ def lm_loss(cfg: TransformerConfig, params, tokens, comm_sp=None,
             # lock-step invariant every collective loss must keep).
             aux = comm_sp.Allreduce(aux, MPI_SUM, compression=False) / sp
         loss = loss + cfg.aux_coef * aux
-    return loss
+    return loss, rows
 
 
 def zero_train_step(cfg: TransformerConfig, params, tokens, opt,
@@ -795,8 +1091,11 @@ def zero3_train_step(cfg: TransformerConfig, p_shards, template, tokens,
 
 def train_step(cfg: TransformerConfig, params, tokens, comm_sp=None,
                comm_dp=None, attn: str = "ring", lr: float = 1e-2,
-               comm_ep=None):
-    """One SGD step; returns (loss, new_params).
+               comm_ep=None, return_stats: bool = False):
+    """One SGD step; returns (loss, new_params), and with
+    ``return_stats`` (loss, new_params, stats): this step's routing
+    counters, ``{"moe_rows": (expert layers, held)}``, the rows each held
+    expert of a per-layer spec took (empty without an expert layer).
 
     DP follows the reference recipe exactly (parameter-averaging Allreduce
     + loss Allreduce over the dp axis) so replicas stay in lock-step.  The
@@ -825,13 +1124,16 @@ def train_step(cfg: TransformerConfig, params, tokens, comm_sp=None,
             p = all_average_tree(comm_sp, p)
         if comm_ep is not None and comm_ep.size > 1:
             p = all_average_tree(comm_ep, p)
-        loss = lm_loss(cfg, p, tokens, comm_sp, attn, comm_ep=comm_ep)
+        loss, rows = _lm_loss(cfg, p, tokens, comm_sp, attn, None, comm_ep, 0)
         if comm_dp is not None and comm_dp.size > 1:
             loss = comm_dp.Allreduce(loss, MPI_SUM, compression=False) / comm_dp.size
         if comm_ep is not None and comm_ep.size > 1:
             loss = comm_ep.Allreduce(loss, MPI_SUM, compression=False) / comm_ep.size
-        return loss
+        return loss, rows
 
-    loss, grads = jax.value_and_grad(global_loss)(params)
+    (loss, rows), grads = jax.value_and_grad(global_loss,
+                                             has_aux=True)(params)
     new_params = jax.tree.map(lambda p, g: p - lr * g, params, grads)
-    return loss, new_params
+    if not return_stats:
+        return loss, new_params
+    return loss, new_params, {} if rows is None else {"moe_rows": rows}

@@ -10,9 +10,12 @@ Two backends implement the same op table (SURVEY.md §2.2):
 :mod:`mpi4torch_tpu.ops.flash` provides the fused (Pallas) block-attention
 kernel that :func:`mpi4torch_tpu.parallel.ring_attention` composes over the
 ring, with a jnp fallback for ineligible shapes/platforms.
+:mod:`mpi4torch_tpu.ops.kda` is the gated delta rule of Kimi Delta
+Attention, chunked (what the model runs) and token by token (its oracle).
 """
 
 from .flash import flash_attention, flash_block_attention, merge_partials
+from .kda import kda_chunked, kda_recurrent
 from .ragged import (block_gather, block_scatter, ragged_allgather,
                      ragged_alltoall, ragged_gather, ragged_scatter,
                      segment_mask)
@@ -21,6 +24,8 @@ __all__ = [
     "flash_attention",
     "flash_block_attention",
     "merge_partials",
+    "kda_chunked",
+    "kda_recurrent",
     "block_gather",
     "block_scatter",
     "ragged_allgather",

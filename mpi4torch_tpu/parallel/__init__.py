@@ -33,8 +33,9 @@ from .tp import (
     tp_attention,
     tp_mlp,
 )
-from .moe import (balanced_assignment, init_moe, moe_ffn,
-                  moe_ffn_dense, rebalance_experts, top1_route)
+from .moe import (Experts, balanced_assignment, held_experts_ffn,
+                  init_experts, init_moe, moe_ffn, moe_ffn_dense,
+                  rebalance_experts, route_topk, top1_route)
 from .zero import (shard_global_norm, zero3_init, zero3_params,
                    zero3_shard_params, zero3_step, zero3_to_tp,
                    zero_init, zero_step)
@@ -72,6 +73,10 @@ __all__ = [
     "shard_axis",
     "tp_attention",
     "tp_mlp",
+    "Experts",
+    "held_experts_ffn",
+    "init_experts",
+    "route_topk",
     "init_moe",
     "moe_ffn",
     "moe_ffn_dense",

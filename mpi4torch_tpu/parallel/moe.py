@@ -19,14 +19,23 @@ Layout (experts rank-major: expert ``e`` lives on rank ``e // epr``):
 Both transports are the one differentiable ``Alltoall`` op, so the entire
 MoE layer is AD-transparent on either backend; gradients to expert weights
 ride the reverse all-to-all automatically.
+
+Beside it, the held-share layer (:func:`held_experts_ffn`): sigmoid
+scores, top-k of ALL the experts with a selection bias, renormalised
+weights, swiglu experts and a shared expert, for a rank that is told
+which experts it holds and computes their part of the result for every
+token routed to them — no capacity, nothing dropped, no exchange yet.
 """
 
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+
+from ..runtime import CommError
 
 
 def top1_route(router_logits, capacity: int):
@@ -199,3 +208,163 @@ def moe_ffn_dense(x, params: Dict[str, Any], capacity: int,
     yout = jnp.einsum("ecf,efd->ecd", h, params["w2"]) + params["b2"][:, None, :]
     y = jnp.einsum("ecd,tec->td", yout, combine)
     return y, aux
+
+
+# ---------------------------------------------------------------------------
+# Held-share top-k layer (sigmoid scores, swiglu experts, shared expert)
+# ---------------------------------------------------------------------------
+
+
+@dataclass(frozen=True)
+class Experts:
+    """A top-k expert FFN as one rank sees it: ``n_experts`` routed
+    experts of width ``d_expert`` in the model, ``top_k`` per token; this
+    rank holds experts ``first_expert`` to ``first_expert + n_held - 1``.
+    The chosen scores are renormalised and scaled by ``scale``;
+    ``n_shared`` shared experts (one swiglu of width ``n_shared *
+    d_expert``) are passed by every token."""
+    n_experts: int
+    top_k: int
+    d_expert: int
+    first_expert: int
+    n_held: int
+    n_shared: int = 0
+    scale: float = 1.0
+
+    def __post_init__(self):
+        if not 0 < self.top_k <= self.n_experts:
+            raise ValueError(
+                f"top_k={self.top_k} must lie in [1, n_experts="
+                f"{self.n_experts}]")
+        last = self.first_expert + self.n_held
+        if self.first_expert < 0 or self.n_held < 1 \
+                or last > self.n_experts:
+            raise ValueError(
+                f"held experts [{self.first_expert}, {last}) must be a "
+                f"non-empty part of [0, {self.n_experts})")
+
+
+def init_experts(key, spec: Experts, d_model: int,
+                 dtype=jnp.float32) -> Dict[str, Any]:
+    """Parameters of one held share: the router at its full width, a
+    selection ``bias`` (zeros; it takes no gradient), the held experts'
+    fused ``w1 = [gate | up]`` and ``w2``, and the shared expert."""
+    kr, k1, k2, k3, k4 = jax.random.split(key, 5)
+    f, e = spec.d_expert, spec.n_held
+
+    def dense(key, *shape):
+        return jax.random.normal(key, shape, dtype) / jnp.sqrt(
+            jnp.asarray(shape[-2], dtype))
+
+    p = {"router": dense(kr, d_model, spec.n_experts),
+         "bias": jnp.zeros((spec.n_experts,), dtype),
+         "w1": dense(k1, e, d_model, 2 * f), "w2": dense(k2, e, f, d_model)}
+    if spec.n_shared:
+        p["shared_w1"] = dense(k3, d_model, 2 * spec.n_shared * f)
+        p["shared_w2"] = dense(k4, spec.n_shared * f, d_model)
+    return p
+
+
+def route_topk(x, router, bias, top_k: int, scale: float):
+    """Sigmoid scores in at least float32 over all experts, the
+    ``top_k`` largest of ``score + bias`` chosen (the bias steers the
+    choice and takes no gradient), weights ``scale * score / sum of the
+    chosen scores``.  Returns ``(chosen (T, k) int32, weights (T, k))``."""
+    ct = jnp.promote_types(x.dtype, jnp.float32)
+    score = jax.nn.sigmoid(jnp.matmul(
+        x.astype(ct), router.astype(ct),
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(
+        score + jax.lax.stop_gradient(bias.astype(ct)), top_k)
+    picked = jnp.take_along_axis(score, chosen, axis=-1)
+    return chosen, scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inverse):
+    """``x[perm]`` for a permutation: the adjoint is the gather by the
+    inverse, not the scatter-add a general gather transposes to."""
+    return x[perm]
+
+
+_permute_rows.defvjp(
+    lambda x, perm, inverse: (x[perm], (perm, inverse)),
+    lambda res, g: (g[res[1]], None, None))
+
+
+@jax.custom_vjp
+def _pair_rows(x, order, inverse, keep):
+    """The (token, choice) pairs of ``x`` ``(T, d)`` in sorted order, as
+    rows: row ``r`` is token ``order[r] // k``, zero where ``keep`` is
+    not set.  No ``(T * k, d)`` copy in token order is made on the way
+    in; on the way back the kept rows' cotangents are unsorted by the
+    inverse permutation and summed over a token's ``k`` pairs."""
+    k = order.shape[0] // x.shape[0]
+    return jnp.where(keep, x[order // k], 0)
+
+
+def _pair_rows_bwd(res, g):
+    order, inverse, keep, T = res
+    g = jnp.where(keep, g, 0)[inverse]
+    return (jnp.sum(g.reshape(T, -1, g.shape[-1]), axis=1, dtype=g.dtype),
+            None, None, None)
+
+
+_pair_rows.defvjp(
+    lambda x, order, inverse, keep: (
+        _pair_rows(x, order, inverse, keep),
+        (order, inverse, keep, x.shape[0])),
+    _pair_rows_bwd)
+
+
+def _swiglu(x, w1, w2, dot):
+    gate, up = jnp.split(dot(x, w1), 2, axis=-1)
+    return dot(jax.nn.silu(gate) * up, w2)
+
+
+def held_experts_ffn(x, params: Dict[str, Any], spec: Experts,
+                     comm_ep=None):
+    """The held experts' part of the layer for ``x`` ``(T, d)``, plus the
+    shared expert: ``sum over chosen and held e of w_e E_e(x) +
+    E_shared(x)``.  The weights are renormalised over all ``top_k``
+    chosen experts, held or not; what the experts held elsewhere would
+    add is left out.
+
+    Every (token, chosen expert) pair is a row; the rows of held experts
+    are sorted by expert to the front and are the groups of two grouped
+    products (``jax.lax.ragged_dot``), so no row routed to a held expert
+    is ever dropped (the buffer has all ``top_k * T`` rows, the worst
+    case); the rows behind them belong to no group, and what the
+    compiler's kernel spends on them is its own affair.
+
+    Returns ``(y, rows)``: ``rows`` ``(n_held,)``, the rows each held
+    expert took, which are the group sizes the products are handed."""
+    if comm_ep is not None and comm_ep.size > 1:
+        raise CommError(
+            "held_experts_ffn computes one rank's share and exchanges "
+            f"nothing; an expert-parallel communicator of size "
+            f"{comm_ep.size} needs the Alltoall exchange of the top-k "
+            "layer, which is not written yet")
+    T, d = x.shape
+    k, held = spec.top_k, spec.n_held
+    chosen, weight = route_topk(x, params["router"], params["bias"], k,
+                                spec.scale)
+    local = chosen.reshape(-1) - spec.first_expert
+    group = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(group, stable=True)
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    rows = jnp.sum(jax.nn.one_hot(group, held, dtype=jnp.int32), axis=0)
+    is_held = (group[order] < held)[:, None]
+
+    xs = _pair_rows(x, order, inverse, is_held)
+    grouped = lambda a, w: jax.lax.ragged_dot(a, w, rows)
+    ys = jnp.where(is_held, _swiglu(xs, params["w1"], params["w2"], grouped),
+                   0)
+    ys = _permute_rows(ys, inverse, order).reshape(T, k, d)
+    y = jnp.sum(ys.astype(weight.dtype) * weight[..., None],
+                axis=1).astype(x.dtype)
+    if spec.n_shared:
+        y = y + _swiglu(x, params["shared_w1"], params["shared_w2"],
+                        jnp.matmul)
+    return y, rows

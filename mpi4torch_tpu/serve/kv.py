@@ -49,7 +49,8 @@ import jax.numpy as jnp
 
 from .. import config as _config
 from ..constants import MPI_SUM
-from ..models.transformer import TransformerConfig, _norm, _rope_rotate
+from ..models.transformer import TransformerConfig, _norm, _rope_rotate, \
+    refuse_layer_spec
 from ..ops.flash import flash_attention, flash_block_attention
 from ..ops.ragged import block_gather, block_scatter, position_onehot
 from ..overlap import overlap_split_allreduce, resolve_overlap
@@ -77,7 +78,9 @@ def validate_tp(cfg: TransformerConfig, size: int) -> None:
     whole q heads, whole KV heads, and an FFN hidden divisible per rank.
     MoE configs are refused — expert-parallel decode routes through
     ``parallel/moe.py``'s Alltoall, a different serving schedule than
-    the dense TP path this subsystem ships."""
+    the dense TP path this subsystem ships.  So is a configuration
+    with a per-layer spec: the serving blocks know one kind of layer."""
+    refuse_layer_spec(cfg, "serve")
     if cfg.n_experts > 0:
         raise CommError(
             "serve: MoE configs (n_experts > 0) are not supported by the "

@@ -32,7 +32,8 @@ from collections import deque
 from jax.profiler import TraceAnnotation
 
 __all__ = ["profiler_trace", "bucket_scope", "serve_step_scope",
-           "ServeStats", "serve_stats", "reset_serve_stats",
+           "layer_scope", "LAYER_SCOPES", "ServeStats", "serve_stats",
+           "reset_serve_stats",
            "serve_step_log", "STEP_SPAN", "STEP_LOG_CAP"]
 
 # The span that is one ``Engine.step()`` call; every other serving span
@@ -105,6 +106,21 @@ def serve_step_scope(what: str = "decode_step"):
     <n>.<phase>/...``), and profiler traces separate prefill spans from
     decode spans per engine step."""
     return _labeled_scope(f"mpi4torch.serve.{what}")
+
+
+# The mechanisms a per-layer spec can name (models/transformer.py), each
+# under its own scope: forward, recomputed and backward instructions of a
+# compiled step all carry the name in their ``op_name``.
+LAYER_SCOPES = {"kda": "mpi4torch.kda", "mla": "mpi4torch.mla",
+                "moe": "mpi4torch.moe"}
+
+
+def layer_scope(kind: str):
+    """Named scope ``mpi4torch.<kind>`` around one mechanism of a layer:
+    ``kda`` (the gated delta-rule mixer, projections included), ``mla``
+    (the latent-attention mixer) or ``moe`` (router, grouped expert
+    products and shared expert)."""
+    return _labeled_scope(LAYER_SCOPES[kind])
 
 
 @contextlib.contextmanager

@@ -1,0 +1,410 @@
+"""A stack whose layers differ: Kimi Delta Attention (the gated delta
+rule, chunked) and latent attention without rotation as mixers, a dense
+swiglu or a held share of sigmoid-routed top-k experts as FFN, stated as
+a per-layer spec on ``TransformerConfig`` — the program against the
+plain float32 reference of ``benchmarks/references/kimi_linear.py`` on
+seeded weights at the configuration's rehearsal sizes (hidden 64, 4
+heads of 16, 8 experts top-2 with 2 held, 5 layers in the published
+pattern).
+
+Tolerances.  Program and reference both compute in float32 here (the
+reference by construction, the program because its parameters are), so
+they differ by summation order alone: 2e-4 of a leaf's norm covers a
+forward, a gradient and one step (the widest seen is 3e-5, the
+embedding's gradient, a scatter-add of 160 rows).  Over three steps the
+differences compound through the routing (a near-tied token's choice
+may flip once the parameters differ in their last bits), so the
+three-step change is held to 2e-3 (seen: 3.5e-4).  A mixer whose KDA
+state is kept in bfloat16, or a layer whose router scores are, misses
+2e-4 by an order of magnitude and more, which the last tests pin down.
+"""
+
+import functools
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+import mpi4torch_tpu as mpi  # noqa: E402
+from benchmarks.families import kimi_linear as family  # noqa: E402
+from benchmarks.references import kimi_linear as ref  # noqa: E402
+from benchmarks.run import merged  # noqa: E402
+from mpi4torch_tpu.models import transformer as T  # noqa: E402
+from mpi4torch_tpu.ops import kda  # noqa: E402
+from mpi4torch_tpu.parallel import moe  # noqa: E402
+from mpi4torch_tpu.serve import kv as serve_kv  # noqa: E402
+
+F32 = jnp.float32
+TOL = 2e-4
+LR = 0.3
+
+
+def _cfg():
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "kimi-linear-48b-a3b.json")) as f:
+        cfg = json.load(f)
+    return merged(cfg, cfg["rehearsal"])
+
+
+CFG = _cfg()
+PLAN = ref.plan(CFG)
+TCFG = family.transformer_config(CFG, remat=True)
+MM = ref.MATMULS["f32"]
+
+
+@functools.lru_cache(maxsize=None)
+def _params(seed=7):
+    return family.make_params(CFG, seed, F32)
+
+
+def _tokens(seed=1, shape=(2, 80)):
+    return jax.random.randint(jax.random.PRNGKey(seed), shape, 0,
+                              CFG["vocab_size"], dtype=jnp.int32)
+
+
+def _x(seed=3, shape=(2, 80)):
+    return jax.random.normal(jax.random.PRNGKey(seed),
+                             shape + (CFG["hidden_size"],), F32)
+
+
+def _close(a, b, tol=TOL):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    scale = max(np.linalg.norm(b), 1e-30)
+    assert np.linalg.norm(a - b) <= tol * scale, \
+        (np.linalg.norm(a - b), scale)
+
+
+def _tree_close(a, b, tol=TOL):
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b), strict=True):
+        _close(x, y, tol)
+
+
+# ------------------------------------------------------------------- KDA
+
+def _kda_inputs(s, dtype=jnp.float64, rate=1.0, seed=0):
+    b, h, dk, dv = 2, 3, 16, 8
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    return (unit(jax.random.normal(ks[0], (b, s, h, dk), dtype)),
+            unit(jax.random.normal(ks[1], (b, s, h, dk), dtype)),
+            jax.random.normal(ks[2], (b, s, h, dv), dtype),
+            -rate * jax.nn.softplus(jax.random.normal(ks[3], (b, s, h, dk),
+                                                      dtype)),
+            jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h), dtype)))
+
+
+def _value_and_grads(fn, n_args):
+    """(value, gradients of sum(sin(value)) in every argument), jitted."""
+    return jax.jit(lambda *a: (fn(*a), jax.grad(
+        lambda *b: jnp.sum(jnp.sin(fn(*b))), argnums=range(n_args))(*a)))
+
+
+@pytest.mark.parametrize("s", [64, 100, 128])
+def test_chunked_kda_equals_the_recurrence(s):
+    """At sequence lengths that are and are not multiples of the chunk,
+    values and every gradient, in float64."""
+    args = _kda_inputs(s)
+    out_c, grads_c = _value_and_grads(kda.kda_chunked, 5)(*args)
+    out_r, grads_r = _value_and_grads(kda.kda_recurrent, 5)(*args)
+    _close(out_c, out_r, 1e-12)
+    for g_c, g_r in zip(grads_c, grads_r):
+        _close(g_c, g_r, 1e-11)
+
+
+def test_chunked_kda_survives_a_fast_decay():
+    """exp(-G) overflows float32 after 30 such tokens; measured from the
+    middle of each block of 16 rows, nothing does."""
+    args = _kda_inputs(128, F32, rate=6.0)
+    out = jax.jit(kda.kda_chunked)(*args)
+    assert bool(jnp.all(jnp.isfinite(out)))
+    _close(out, jax.jit(kda.kda_recurrent)(*args), 1e-5)
+
+
+# ---------------------------------------------------------------- mixers
+
+@pytest.mark.parametrize("layer,mixer,reference", [
+    (0, T._kda_mixer, ref.kda), (3, T._mla_mixer, ref.mla)])
+def test_mixer_matches_the_reference(layer, mixer, reference):
+    spec = TCFG.layers[layer].mixer
+    p, x = _params()["blocks"][layer]["mixer"], _x()
+    out_p, grads_p = _value_and_grads(
+        lambda p_, x_: mixer(spec, p_, x_), 2)(p, x)
+    out_r, grads_r = _value_and_grads(
+        lambda p_, x_: reference(PLAN, p_, x_, MM), 2)(p, x)
+    _close(out_p, out_r)
+    _tree_close(grads_p, grads_r)
+
+
+def test_blockwise_causal_attention_is_one_call_in_blocks():
+    """The triangle of block calls MLA uses where one flash call does not
+    fit the kernels: values and gradients of one call, in float64."""
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q, k, v = (jax.random.normal(kk, (2, 96, 3, 24), jnp.float64)
+               for kk in ks)
+    out_b, grads_b = _value_and_grads(
+        lambda *a: T._blockwise_causal_attention(*a, 32), 3)(q, k, v)
+    out_1, grads_1 = _value_and_grads(
+        lambda *a: T.flash_attention(*a, causal=True), 3)(q, k, v)
+    _close(out_b, out_1, 1e-12)
+    _tree_close(grads_b, grads_1, 1e-11)
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip():
+    """A described (not attached) v5e chip: compile-only."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                       # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_mla_blocks_compile_for_the_v5e_with_all_three_kernels(
+        one_v5e_chip, monkeypatch):
+    """At the published widths (32 heads, keys of 192 staged 256 wide)
+    Mosaic takes the 2,048-token blocks, forward and both backward
+    kernels; one call over 8,192 tokens it refuses."""
+    from mpi4torch_tpu.ops import flash
+    monkeypatch.setattr(flash, "_on_tpu", lambda: True)
+    like = jax.ShapeDtypeStruct((1, 2 * T._MLA_BLOCK, 32, 192), jnp.bfloat16,
+                                sharding=one_v5e_chip)
+    loss = lambda q, k, v: jnp.sum(T._blockwise_causal_attention(
+        q, k, v, T._MLA_BLOCK).astype(F32))
+    with jax.enable_x64(False):      # the kernels are traced without x64
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            like, like, like).compile().as_text()
+    for name in flash.KERNEL_NAMES:
+        assert name in text, name
+
+
+def _flat(y):
+    return y.reshape(-1, y.shape[-1])
+
+
+def test_experts_match_the_reference():
+    spec = TCFG.layers[1].ffn
+    p, x = _params()["blocks"][1]["experts"], _x()
+    out_p, grads_p = _value_and_grads(
+        lambda p_, x_: moe.held_experts_ffn(_flat(x_), p_, spec)[0], 2)(p, x)
+    out_r, grads_r = _value_and_grads(
+        lambda p_, x_: _flat(ref.experts(PLAN, p_, x_, MM)), 2)(p, x)
+    _close(out_p, out_r)
+    _tree_close(grads_p, grads_r)
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """The routed parts of all four shares (2 of 8 experts each), with
+    the shared expert counted once, are the reference's whole layer."""
+    x = _flat(_x())
+    width, held = PLAN.router_width, PLAN.held
+    shares = [family._expert_leaves(jax.random.PRNGKey(c), CFG, F32)
+              for c in range(width // held)]
+    whole = dict(shares[0])
+    for name in ("w1", "w2"):
+        whole[name] = jnp.concatenate([s[name] for s in shares], axis=0)
+    base = TCFG.layers[1].ffn
+    total = ref.swiglu(x, whole["shared_w1"], whole["shared_w2"], MM)
+    for c, share in enumerate(shares):
+        spec = moe.Experts(base.n_experts, base.top_k, base.d_expert,
+                           first_expert=c * held, n_held=held,
+                           n_shared=0, scale=base.scale)
+        # every share routes with the same router and selection bias
+        p = dict(share, router=whole["router"], bias=whole["bias"])
+        total = total + moe.held_experts_ffn(x, p, spec)[0]
+    _close(total, ref.experts(PLAN, whole, x, MM, first=0, held=width))
+
+
+def test_no_row_is_dropped_under_skew():
+    """A selection bias that sends every token to held expert 0, which
+    then takes half of all rows (top-2): the row counts hold every row
+    routed to a held expert and the result is still the reference's."""
+    spec = TCFG.layers[1].ffn
+    p = dict(_params()["blocks"][1]["experts"])
+    x = _flat(_x())
+    p["bias"] = p["bias"].at[0].set(10.0)
+    y, rows = moe.held_experts_ffn(x, p, spec)
+    rows = np.asarray(rows)
+    chosen, _ = moe.route_topk(x, p["router"], p["bias"], spec.top_k,
+                               spec.scale)
+    assert rows[0] == x.shape[0]
+    assert rows.sum() == int(jnp.sum(chosen < spec.n_held))
+    _close(y, ref.experts(PLAN, p, x, MM))
+
+
+def test_a_bfloat16_kda_state_fails_the_comparison(monkeypatch):
+    spec, p, x = TCFG.layers[0].mixer, _params()["blocks"][0]["mixer"], _x()
+    plain = jax.jit(lambda p_, x_: ref.kda(PLAN, p_, x_, MM))(p, x)
+    monkeypatch.setattr(kda, "_state_dtype", lambda q: jnp.bfloat16)
+    out = jax.jit(lambda p_, x_: T._kda_mixer(spec, p_, x_))(p, x)
+    assert np.linalg.norm(out - plain) > 10 * TOL * np.linalg.norm(plain)
+
+
+def test_a_bfloat16_router_fails_the_comparison(monkeypatch):
+    """The layer's output misses the tolerance, and the router's own
+    gradient misses it by far."""
+    spec, p, x = TCFG.layers[1].ffn, _params()["blocks"][1]["experts"], _x()
+    out_r, (grads_r, _) = _value_and_grads(
+        lambda p_, x_: _flat(ref.experts(PLAN, p_, x_, MM)), 2)(p, x)
+    half = lambda a: a.astype(jnp.bfloat16).astype(a.dtype)
+    route = moe.route_topk
+    monkeypatch.setattr(
+        moe, "route_topk", lambda x_, router, bias, k, scale: route(
+            half(x_), half(router), bias, k, scale))
+    out_p, (grads_p, _) = _value_and_grads(
+        lambda p_, x_: moe.held_experts_ffn(_flat(x_), p_, spec)[0], 2)(p, x)
+    rel = lambda a, b: np.linalg.norm(a - b) / np.linalg.norm(b)
+    assert rel(out_p, out_r) > TOL
+    assert rel(grads_p["router"], grads_r["router"]) > 10 * TOL
+
+
+# -------------------------------------------------------------- the step
+
+def test_three_steps_match_the_reference():
+    """Loss, the gradient's norm leaf by leaf (as the benchmark's family
+    takes it), the three-step parameter change, and leaf by leaf the
+    parameters themselves."""
+    step = jax.jit(lambda p, t: T.train_step(TCFG, p, t, lr=LR))
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("mpi",))
+    grad_norms = family.build_grad_norms(TCFG, mesh, 2, dp=False)
+    prog, plain, grads = [_params()], [_params()], []
+    for seed in (1, 2, 3):
+        tokens = _tokens(seed)
+        loss_p, new_p = step(prog[-1], tokens)
+        loss_r, new_r, norms = ref.step(CFG, plain[-1], tokens, LR)
+        assert abs(float(loss_p) - float(loss_r)) <= TOL * float(loss_r)
+        grads.append((np.asarray(grad_norms(prog[-1], tokens)),
+                      np.asarray(jax.tree.leaves(norms))))
+        prog.append(new_p)
+        plain.append(new_r)
+
+    def worst(p, r):
+        """Widest per-leaf gap; a leaf whose reference norm is all but
+        zero (the selection bias) is read against the median leaf."""
+        return float(np.max(np.abs(p - r) / np.maximum(r, np.median(r))))
+
+    def change(trail, k):
+        return np.asarray([float(jnp.linalg.norm(x - y)) for x, y in zip(
+            jax.tree.leaves(trail[0]), jax.tree.leaves(trail[k]),
+            strict=True)])
+
+    assert worst(*grads[0]) <= TOL
+    # the first step's change is lr times that gradient
+    _close(change(prog, 1), LR * grads[0][0], 1e-5)
+    assert worst(change(prog, 3), change(plain, 3)) <= 10 * TOL
+    _tree_close(prog[1], plain[1])
+    _tree_close(prog[3], plain[3], 10 * TOL)
+
+
+@pytest.mark.parametrize("ranks", [1, 2], ids=["one_chip", "dp2"])
+def test_the_benchmarks_gradient_norms_are_the_steps(ranks):
+    """``build_grad_norms``, the program the benchmark compares with the
+    reference, takes the gradient ``train_step`` takes: with float32
+    parameters ``(p0 - p1) / lr`` of the family's step shows it, alone
+    and under the data-parallel average."""
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:ranks]), ("mpi",))
+    dp, tokens = ranks > 1, _tokens(shape=(2 * ranks, 80))
+    norms = np.asarray(family.build_grad_norms(TCFG, mesh, 2, dp)(
+        _params(), tokens))
+    _, new, _ = family.build_train_step(TCFG, mesh, 2, LR, dp)(
+        jax.tree.map(jnp.copy, _params()), tokens)
+    moved = np.asarray([float(jnp.linalg.norm(a - b)) / LR for a, b in zip(
+        jax.tree.leaves(_params()), jax.tree.leaves(new), strict=True)])
+    assert np.max(np.abs(norms - moved)
+                  / np.maximum(moved, np.median(moved))) <= TOL
+
+
+def test_stats_come_out_of_the_step_under_run_spmd():
+    """``train_step`` of a two-layer spec (KDA + experts, MLA + dense,
+    ``init_transformer``'s own leaves) under ``run_spmd`` on two
+    data-parallel ranks: one loss, and each rank's routing counters, one
+    row per expert layer."""
+    experts = moe.Experts(n_experts=8, top_k=2, d_expert=16, first_expert=4,
+                          n_held=2, n_shared=1, scale=2.0)
+    cfg = T.TransformerConfig(
+        vocab=64, d_model=32, n_heads=2, n_layers=2, d_ff=64, max_seq=32,
+        rope=True, norm="rmsnorm", ffn="swiglu", layers=(
+            T.LayerSpec(T.KDA(n_heads=2, head_dim=8), experts),
+            T.LayerSpec(T.MLA(n_heads=2, kv_rank=8, qk_nope=8, qk_rope=4,
+                              v_dim=8))))
+    params = T.init_transformer(jax.random.PRNGKey(0), cfg, F32)
+    tokens = _tokens(shape=(4, 16)) % cfg.vocab
+
+    def body(p, t):
+        comm = mpi.COMM_WORLD
+        local = jax.lax.dynamic_slice_in_dim(t, jnp.asarray(comm.rank) * 2,
+                                             2, 0)
+        return T.train_step(cfg, p, local, comm_dp=comm, lr=LR,
+                            return_stats=True)
+
+    loss, new, stats = mpi.run_spmd(body, nranks=2)(params, tokens)
+    assert float(loss[0]) == float(loss[1]) and np.isfinite(float(loss[0]))
+    assert set(stats) == {"moe_rows"}
+    assert stats["moe_rows"].shape == (2, 1, 2)
+    # top-2 of 8 over 2 x 16 tokens a rank: at most every pair is held
+    assert 0 < int(stats["moe_rows"].sum()) <= 2 * 2 * 2 * 16
+    moved = jax.tree.map(lambda a, b: bool(jnp.any(a != b[0])), params, new)
+    assert moved["blocks"][0]["mixer"]["a_log"] \
+        and moved["blocks"][0]["experts"]["w1"] \
+        and not moved["blocks"][0]["experts"]["bias"]
+
+
+# ------------------------------------------------------- what is refused
+
+def test_a_spec_of_default_layers_lowers_as_no_spec_does():
+    base = dict(vocab=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
+                max_seq=16, n_kv_heads=2, rope=True, norm="rmsnorm",
+                ffn="swiglu", remat=True)
+    texts = []
+    for layers in ((), (T.LayerSpec(),) * 2):
+        cfg = T.TransformerConfig(layers=layers, **base)
+        params = T.init_transformer(jax.random.PRNGKey(0), cfg, F32)
+        tokens = jnp.zeros((2, 16), jnp.int32)
+        texts.append(jax.jit(lambda p, t: T.train_step(cfg, p, t)).lower(
+            params, tokens).as_text())
+    assert texts[0] == texts[1]
+
+
+def test_a_spec_is_checked_when_the_configuration_is_made():
+    base = dict(vocab=64, d_model=32, n_heads=4, d_ff=64, max_seq=16)
+    with pytest.raises(ValueError, match="2 entries for n_layers=3"):
+        T.TransformerConfig(n_layers=3, layers=(T.LayerSpec(),) * 2, **base)
+    with pytest.raises(ValueError, match="needs a KDA or MLA mixer"):
+        T.TransformerConfig(n_layers=1, layers=(
+            T.LayerSpec(None, TCFG.layers[1].ffn),), **base)
+    for first, held in ((7, 2), (0, 0)):
+        with pytest.raises(ValueError, match="held experts"):
+            moe.Experts(n_experts=8, top_k=2, d_expert=4,
+                        first_expert=first, n_held=held)
+
+
+def test_an_expert_parallel_communicator_is_refused():
+    class Two:
+        size = 2
+
+    p = _params()["blocks"][1]["experts"]
+    with pytest.raises(mpi.CommError, match="Alltoall exchange"):
+        moe.held_experts_ffn(_x().reshape(-1, CFG["hidden_size"]), p,
+                             TCFG.layers[1].ffn, comm_ep=Two())
+
+
+@pytest.mark.parametrize("call", [
+    lambda: T.init_kv_cache(TCFG, 1),
+    lambda: T.decode_step(TCFG, {}, [], jnp.zeros((1,), jnp.int32), 0),
+    lambda: T.prefill(TCFG, {}, [], jnp.zeros((1, 4), jnp.int32)),
+    lambda: T.generate(TCFG, {}, jnp.zeros((1, 4), jnp.int32), 2),
+    lambda: serve_kv.validate_tp(TCFG, 1),
+], ids=["init_kv_cache", "decode_step", "prefill", "generate", "validate_tp"])
+def test_serving_refuses_a_layer_spec(call):
+    with pytest.raises(mpi.CommError, match="per-layer spec"):
+        call()
