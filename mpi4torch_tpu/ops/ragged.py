@@ -131,9 +131,9 @@ def block_gather(pool, table):
 
 
 def block_scatter(pool, block_ids, offsets, values, active=None):
-    """One-hot write of one row per writer into a paged pool — the
-    block-granular counterpart of the :func:`position_onehot` slot-table
-    cache write.
+    """Write one row per writer into a paged pool, in place where the
+    caller lets it be — the block-granular counterpart of the
+    :func:`position_onehot` slot-table cache write.
 
     ``pool``: ``(num_blocks, block_size, *feat)``.  ``block_ids`` /
     ``offsets``: ``(writers,)`` int — writer ``w`` targets
@@ -146,14 +146,18 @@ def block_scatter(pool, block_ids, offsets, values, active=None):
     Writers must target DISTINCT (block, offset) cells — the serving
     invariant that live slots own disjoint write positions (shared
     prefix blocks are read-only; writes land in private pages, the
-    copy-on-write rule).  Under that invariant the write is exact: the
-    winning value is routed by integer one-hot masks and a gather
-    (``where`` selects, never sums), so written cells carry ``values``'
-    bits cast to ``pool``'s dtype and untouched cells keep theirs.
-    Static shapes throughout — same compiled program for any table
-    churn.  (This jnp formulation materializes a ``(num_blocks,
-    block_size, *feat)`` routing intermediate; a TPU deployment would
-    drop in a real scatter kernel behind the same contract.)"""
+    copy-on-write rule).  Under that invariant the write is exact:
+    written cells carry ``values``' bits cast to ``pool``'s dtype and
+    every other cell keeps its bits.  Static shapes throughout — same
+    compiled program for any table churn.
+
+    The write is a true scatter of ``writers`` rows: no array of the
+    pool's size is formed beside the result, and a compiled caller that
+    donates ``pool`` (the serving decode step does) gets the rows
+    written into the pool's own buffer.  A writer that must not write
+    is sent to an id past the pool, which the scatter drops — never
+    left at ``-1``, which ``.at[]`` would wrap to the last page (the
+    rule ``serve.kv.install_rows_paged`` puts on its index)."""
     pool = jnp.asarray(pool)
     values = jnp.asarray(values)
     if pool.ndim < 2:
@@ -170,20 +174,11 @@ def block_scatter(pool, block_ids, offsets, values, active=None):
     live = (b >= 0) & (b < nb) & (o >= 0) & (o < bs)
     if active is not None:
         live = live & (jnp.asarray(active).astype(bool))
-    bmask = (jnp.arange(nb, dtype=jnp.int32)[None, :] == b[:, None]) \
-        & live[:, None]                                  # (writers, nb)
-    omask = position_onehot(o, bs) != 0                  # (writers, bs)
-    cell = bmask[:, :, None] & omask[:, None, :]         # (writers, nb, bs)
-    hit = cell.any(axis=0)                               # (nb, bs)
-    # Integer one-hot routing: the writer index owning each hit cell
-    # (exact — at most one contributor under the disjoint-cells
-    # invariant; 0 elsewhere, where `hit` suppresses the write).
-    writer = jnp.einsum("wnb,w->nb", cell.astype(jnp.int32),
-                        jnp.arange(b.shape[0], dtype=jnp.int32))
-    src = jnp.take(values, writer.reshape(-1), axis=0).reshape(
-        (nb, bs) + values.shape[1:])
-    mask = hit.reshape((nb, bs) + (1,) * (pool.ndim - 2))
-    return jnp.where(mask, src.astype(pool.dtype), pool)
+    # Distinct ids past the pool for the dropped writers: the scatter is
+    # promised unique indices.
+    b = jnp.where(live, b, nb + jnp.arange(b.shape[0], dtype=jnp.int32))
+    return pool.at[b, jnp.where(live, o, 0)].set(
+        values.astype(pool.dtype), mode="drop", unique_indices=True)
 
 
 def ragged_alltoall(comm, x, send_counts) -> Tuple:

@@ -2308,7 +2308,8 @@ DEFAULT_AXIS = "mpi"
 
 
 def run_spmd(fn, nranks: Optional[int] = None, mesh=None,
-             axis_name: str = DEFAULT_AXIS, jit: bool = True):
+             axis_name: str = DEFAULT_AXIS, jit: bool = True,
+             donate_argnums=None):
     """Run ``fn`` SPMD over a mesh axis — the traced/compiled counterpart of
     :func:`mpi4torch_tpu.run_ranks`.
 
@@ -2318,6 +2319,12 @@ def run_spmd(fn, nranks: Optional[int] = None, mesh=None,
     Differentiable end-to-end: ``jax.grad`` of (a reduction of) the stacked
     outputs sums cotangents over ranks, exactly like executing ``backward()``
     on every MPI rank (SURVEY.md §3.3).
+
+    ``donate_argnums`` (positions among ``fn``'s arguments; default none)
+    hands those arguments' buffers to the compiled program, as
+    ``jax.jit``'s option of that name does: state that goes in stacked
+    ``(nranks, ...)`` and comes out so can then be updated in place.  The
+    caller's arrays are deleted by the call.  Needs ``jit=True``.
     """
     from jax.sharding import Mesh, PartitionSpec as P
     from jax import shard_map
@@ -2353,8 +2360,14 @@ def run_spmd(fn, nranks: Optional[int] = None, mesh=None,
             mesh=mesh, in_specs=P(), out_specs=P(axis_name),
             check_vma=False)(*args)
 
+    if donate_argnums is not None and not jit:
+        raise ValueError("run_spmd: donate_argnums needs jit=True (the "
+                         "caller's own jit donates when jit=False)")
     if jit:
-        jitted = jax.jit(sm, static_argnums=(0, 1, 2, 3, 4, 5))
+        # The call below puts six static arguments before fn's own.
+        jitted = jax.jit(
+            sm, static_argnums=(0, 1, 2, 3, 4, 5),
+            donate_argnums=tuple(6 + int(i) for i in donate_argnums or ()))
     else:
         jitted = sm
 

@@ -32,7 +32,9 @@ attached (the Makefile's ``serve-smoke`` target runs it on the
    ``obs.prometheus_text()`` as an ``mpi4torch_serve_*`` metric;
 8. **no-retrace census** — the paged decode step lowers to IDENTICAL
    program text across two different block-table states (the table is
-   an argument, not structure), with a stable block-gather op count.
+   an argument, not structure), with one row scatter per K and V per
+   layer and, off the TPU where the read is the gather, a stable
+   block-gather op count.
 
 Exits non-zero on any divergence, so the lane is a real check, not a
 demo.
@@ -64,7 +66,7 @@ MIRRORED_SERVE_COUNTERS = (
     "deadline_expired", "shed",
     "prefix_hits", "prefix_misses", "prefill_tokens", "cow_copies",
     "preempted", "blocks_in_use", "blocks_free", "blocks_cached",
-    "install_writes",
+    "install_writes", "decode_pages_live", "decode_pages_read",
 )
 
 
@@ -323,6 +325,12 @@ def _smoke() -> int:
     if txt1 != txt2:
         print("FAIL: paged decode step retraces across table states")
         return 1
+    n_scatter = txt1.count('"stablehlo.scatter"(')
+    if n_scatter != 2 * cfg.n_layers:
+        print(f"FAIL: paged decode step censuses {n_scatter} scatter "
+              f"ops; expected {2 * cfg.n_layers} (one row write per K "
+              "and V per layer)")
+        return 1
     n_gather = txt1.count('"stablehlo.gather"')
     if n_gather < 2 * cfg.n_layers:
         print(f"FAIL: paged decode step censuses only {n_gather} "
@@ -336,7 +344,8 @@ def _smoke() -> int:
         print("FAIL: no-retrace engine diverges from oracle")
         return 1
     print(f"no-retrace: paged decode step text identical across table "
-          f"states ({n_gather} gather ops censused)")
+          f"states ({n_scatter} scatter, {n_gather} gather ops "
+          "censused)")
 
     print("serve-smoke: OK")
     return 0

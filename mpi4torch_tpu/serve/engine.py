@@ -55,6 +55,7 @@ from jax.sharding import PartitionSpec
 
 from ..comm import COMM_WORLD
 from ..models.transformer import TransformerConfig, select_token
+from ..ops import paged_attention as _paged_attn
 from ..runtime import CommError
 from ..utils import profiling as _prof
 from . import kv as _kv
@@ -347,9 +348,12 @@ class Engine:
             self._shards = run_spmd(
                 lambda: _kv.shard_params_tp(cfg, params, COMM_WORLD),
                 **kw)()
+            # The paged step takes its pool over (argument 1) and
+            # writes the new rows into it; the dense step's one-hot
+            # write builds a new cache and is left as it is.
             self._step_call = run_spmd(
-                self._traced_step_paged if self._paged
-                else self._traced_step, **kw)
+                self._traced_step_paged, donate_argnums=(1,), **kw) \
+                if self._paged else run_spmd(self._traced_step, **kw)
             # One wrapper serves every prompt length: the jit under
             # run_spmd caches per input shape on its own.
             self._prefill_call = run_spmd(self._traced_prefill, **kw)
@@ -369,7 +373,7 @@ class Engine:
             if cfg.max_seq % bs != 0:
                 raise ValueError(
                     f"block_size={bs} must divide max_seq={cfg.max_seq} "
-                    "(the paged gather reconstructs the dense attention "
+                    "(a slot's table row covers the dense attention "
                     "extent — see serve.kv.init_kv_pool_tp)")
             self._blocks_per_seq = cfg.max_seq // bs
             nb = (self.serve_cfg.num_blocks
@@ -389,6 +393,14 @@ class Engine:
             self._slot_seq = [0] * slots
             self._chunk = (self.serve_cfg.prefill_chunk
                            if self._exact_kv else None)
+            # Which read the decode step compiles, asked of the
+            # function that decides it: what decode_pages_read counts.
+            hd = cfg.d_model // cfg.n_heads
+            self._kernel_read = _paged_attn.uses_kernel(
+                jax.ShapeDtypeStruct(
+                    (slots, cfg.n_heads // self._size, hd),
+                    params["embed"].dtype),
+                cache[0]["k"])
         else:
             cache = _kv.init_kv_cache_tp(cfg, slots, self._size,
                                          self._dtype, poison=True)
@@ -758,6 +770,18 @@ class Engine:
         self._cache = self._install_call(self._cache, rows, index)
         self.stats.count("install_writes")
 
+    def _count_pages(self, active: List[int]) -> None:
+        """The step's two page counts: the pages its live slots hold up
+        to their frontier, and the pages its attention visits by the
+        read the engine compiled (the same pages through the kernel;
+        every slot's whole table row through the gather)."""
+        bs = self.serve_cfg.block_size
+        held = sum(int(self._pos[j]) // bs + 1 for j in active)
+        self.stats.count("decode_pages_live", held)
+        self.stats.count("decode_pages_read",
+                         held if self._kernel_read
+                         else len(active) * self._blocks_per_seq)
+
     def _gather_past(self, j: int, n: int):
         """Exact-length past K/V (positions ``0..n-1``) for slot ``j``,
         host-gathered from the pool at the slot's concrete page ids —
@@ -1040,9 +1064,9 @@ class Engine:
                 self._tokens[j] = 0
                 self._pos[j] = 0
             # No NaN poison: free pages are simply unmapped (-1 table
-            # entries); block_gather masks them to zero and the causal
-            # frontier keeps stale mapped rows inert — same invariant,
-            # enforced by masking instead of poison.
+            # entries), which read as zeros or are not read at all, and
+            # the causal frontier keeps stale mapped rows inert — same
+            # invariant, enforced by masking instead of poison.
             return
         for j in idxs:
             self._slot_req[j] = None
@@ -1132,6 +1156,8 @@ class Engine:
                 table = np.asarray(logits)
             with span(SPAN_SELECT):
                 self.stats.tick(len(active), self.serve_cfg.slots)
+                if self._paged:
+                    self._count_pages(active)
                 for j in active:
                     req = self._slot_req[j]
                     tok = self._select(req, table[j])
@@ -1150,7 +1176,9 @@ class Engine:
     def _dispatch_decode(self):
         """Queue ONE decode step over the slot table (the new cache
         replaces the old) and return its ``(slots, vocab)`` logits,
-        still on the device."""
+        still on the device.  A paged step takes the pool over and
+        writes into it: whoever held ``self._cache``'s old leaves holds
+        deleted arrays afterwards, as after an install."""
         live = np.asarray([self._slot_req[j] is not None
                            and not self._prefilling[j]
                            for j in range(self.serve_cfg.slots)])
@@ -1174,7 +1202,7 @@ class Engine:
                 jnp.asarray(self._pos), self._comm,
                 overlap=self.serve_cfg.overlap,
                 algorithm=self.serve_cfg.algorithm,
-                active=jnp.asarray(live))
+                active=jnp.asarray(live), donate=True)
         else:
             logits, self._cache = _kv.decode_step_tp(
                 self.cfg, self._shards, self._cache,

@@ -52,7 +52,8 @@ from ..constants import MPI_SUM
 from ..models.transformer import TransformerConfig, _norm, _rope_rotate, \
     refuse_layer_spec
 from ..ops.flash import flash_attention, flash_block_attention
-from ..ops.ragged import block_gather, block_scatter, position_onehot
+from ..ops.paged_attention import paged_decode_attention
+from ..ops.ragged import block_scatter, position_onehot
 from ..overlap import overlap_split_allreduce, resolve_overlap
 from ..parallel.tp import shard_axis, shard_heads
 from ..runtime import CommError
@@ -196,22 +197,24 @@ def init_kv_pool_tp(cfg: TransformerConfig, num_blocks: int,
     the paged counterpart of :func:`init_kv_cache_tp`, addressed
     through a per-slot block table instead of a dense per-slot row.
     One block-id space serves every layer (block ``i`` of each layer is
-    the same logical page, so one table drives all layers' gathers).
+    the same logical page, so one table drives all layers' reads).
 
-    ``block_size`` must divide ``cfg.max_seq``: the decode step gathers
-    each slot's pages back into a full ``max_seq`` extent, so the paged
-    attention sees exactly the dense buffer shape (unmapped pages as
-    inert zero rows behind the causal frontier) — that extent equality
-    is part of the bitwise-parity contract with the dense path.
+    ``block_size`` must divide ``cfg.max_seq``: a slot's table row names
+    ``max_seq / block_size`` pages, and where the decode step reads by
+    gather (:func:`~mpi4torch_tpu.ops.paged_attention.
+    paged_decode_attention`'s jnp path: every backend but a TPU) it
+    lays them out as a full ``max_seq`` extent, exactly the dense
+    buffer's shape (unmapped pages as inert zero rows behind the causal
+    frontier) — that extent equality is part of the bitwise-parity
+    contract with the dense path there.
 
     No poison fill: free state is expressed by table entries (``-1``),
-    and :func:`~mpi4torch_tpu.ops.ragged.block_gather` zeroes unmapped
-    pages — a stale page's bits are unreachable without a table entry
-    pointing at it."""
+    which read as zero pages on either path — a stale page's bits are
+    unreachable without a table entry pointing at it."""
     if block_size < 1 or cfg.max_seq % block_size != 0:
         raise CommError(
             f"serve: block_size={block_size} must be >= 1 and divide "
-            f"max_seq={cfg.max_seq} (the paged gather reconstructs the "
+            f"max_seq={cfg.max_seq} (a slot's table row covers the "
             "dense attention extent)")
     hd = cfg.d_model // cfg.n_heads
     shape = (num_blocks, block_size, cfg.kv_heads // size, hd)
@@ -515,35 +518,59 @@ def decode_step_tp(cfg: TransformerConfig, shards, cache, tokens, pos,
         return x @ shards["unembed"], new_cache
 
 
+# The eager engine's write: one jitted scatter per pool leaf with the
+# leaf donated, so that off ``run_spmd`` too the row lands in the pool's
+# own buffer instead of in a copy of it.
+_block_scatter_donated = jax.jit(block_scatter, donate_argnums=0)
+
+
 def decode_step_paged(cfg: TransformerConfig, shards, pool, table,
                       tokens, pos, comm=None, *, overlap=None,
-                      algorithm: Optional[str] = None, active=None):
+                      algorithm: Optional[str] = None, active=None,
+                      donate: bool = False):
     """One continuous-batching decode step over a PAGED slot table:
-    :func:`decode_step_tp`'s exact math with the dense per-slot cache
+    :func:`decode_step_tp`'s math with the dense per-slot cache
     replaced by ``pool`` (per-layer ``(num_blocks, block_size,
     kv_heads/size, head_dim)`` pages, :func:`init_kv_pool_tp`) plus a
     ``(slots, max_seq/block_size)`` block ``table`` (``-1`` =
     unmapped).  Returns ``(logits, new_pool)``.
 
-    Per layer: the new K/V row lands by
-    :func:`~mpi4torch_tpu.ops.ragged.block_scatter` one-hot write into
-    the slot's current page (``table[s, pos[s]//bs]`` at offset
-    ``pos[s] % bs``), then :func:`~mpi4torch_tpu.ops.ragged.
-    block_gather` reconstructs each slot's full ``max_seq`` extent —
-    written rows bit-identical to the dense cache's, unmapped pages as
-    zeros behind the per-row causal frontier — and attention proceeds
-    exactly as the dense step.  The table rides as DATA: one compiled
-    program for every alloc/free/COW/prefix-sharing state of the pool,
-    the same no-retrace contract the dense slot table holds, now
-    holding under page churn too.
+    The step reaches the pool only through the table, in both
+    directions, and forms no array of the pool's size.  Per layer:
+
+    * the new K/V row of each live slot lands by
+      :func:`~mpi4torch_tpu.ops.ragged.block_scatter` — a scatter of
+      ``slots`` rows — in the slot's current page (``table[s,
+      pos[s]//bs]`` at offset ``pos[s] % bs``): exact bits on every
+      backend, and written into the pool's own buffers when the pool
+      is donated.  A compiled caller donates through its own jit (the
+      SPMD engine: ``run_spmd(..., donate_argnums=...)``); an eager
+      caller passes ``donate=True``, and each leaf then goes through a
+      jitted scatter that takes it over.  Either way the caller's old
+      pool leaves are deleted by the call;
+    * attention reads the pages through the table
+      (:func:`~mpi4torch_tpu.ops.paged_attention.
+      paged_decode_attention`).  On a TPU, for eligible shapes, that is
+      a kernel that fetches each slot's pages up to its frontier and
+      no others, with an online softmax: equal to the dense step to
+      rounding.  Everywhere else it gathers each slot's full
+      ``max_seq`` extent — written rows bit-identical to the dense
+      cache's, unmapped pages as zeros behind the per-row causal
+      frontier — and attends exactly as the dense step does: bitwise
+      equal to it.  Backend and shapes decide; there is no option.
+
+    The table rides as DATA: one compiled program for every
+    alloc/free/COW/prefix-sharing state of the pool, the same
+    no-retrace contract the dense slot table holds, now holding under
+    page churn too.
 
     The caller (the engine's host-side
     :class:`~mpi4torch_tpu.serve.paging.BlockManager`) guarantees live
     slots' write cells are distinct private pages — the copy-on-write
     discipline — which is ``block_scatter``'s exactness invariant.
     Free slots carry ``-1`` write pages and an ``active=False`` mask:
-    no write, zero gathered rows, payload rows zeroed before the wire
-    (same ``guard_rows`` rule as the dense step)."""
+    no write, no page read, zero attention rows, payload rows zeroed
+    before the wire (same ``guard_rows`` rule as the dense step)."""
     slots = tokens.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
     table = jnp.asarray(table, jnp.int32)
@@ -555,6 +582,7 @@ def decode_step_paged(cfg: TransformerConfig, shards, pool, table,
     live_vec = None if active is None \
         else jnp.asarray(active).astype(bool)
     live = None if live_vec is None else live_vec[:, None]
+    write = _block_scatter_donated if donate else block_scatter
 
     def guard_rows(payload):
         if live is None:
@@ -577,16 +605,12 @@ def decode_step_paged(cfg: TransformerConfig, shards, pool, table,
             y = _norm(cfg, x, blk["ln1"])
             q, k_new, v_new = _split_qkv_local(
                 cfg, blk, y[:, None, :], pos[:, None], size)
-            pk = block_scatter(c["k"], wb, off, k_new[:, 0],
-                               active=live_vec)
-            pv = block_scatter(c["v"], wb, off, v_new[:, 0],
-                               active=live_vec)
+            pk = write(c["k"], wb, off, k_new[:, 0], live_vec)
+            pv = write(c["v"], wb, off, v_new[:, 0], live_vec)
             new_pool.append({"k": pk, "v": pv})
-            ck = block_gather(pk, table)
-            cv = block_gather(pv, table)
-            o, _ = flash_block_attention(
-                q, ck, cv, causal=True, q_offset=pos, kv_offset=0,
-                window=cfg.attn_window, impl="jnp")
+            o = paged_decode_attention(
+                q[:, 0], pk, pv, table, pos, window=cfg.attn_window,
+                active=live_vec)
             o_part = o.reshape(slots, -1).astype(x.dtype) @ blk["wo"]
             attn = _decode_allreduce(comm, guard_rows(o_part), site=site,
                                      nsites=nsites, overlap=ov,

@@ -1,9 +1,10 @@
 """Host-side block accounting for the paged KV cache.
 
-The device side of paging is two static-shape primitives
-(:func:`mpi4torch_tpu.ops.ragged.block_gather` /
-:func:`~mpi4torch_tpu.ops.ragged.block_scatter`) driven by a per-slot
-block table that is DATA to the compiled decode step.  Everything else
+The device side of paging is a static-shape write and read
+(:func:`mpi4torch_tpu.ops.ragged.block_scatter`, one row per live slot;
+:func:`mpi4torch_tpu.ops.paged_attention.paged_decode_attention`, the
+pages through the table) driven by a per-slot block table that is DATA
+to the compiled decode step.  Everything else
 — which physical page holds which logical positions, who may write
 where, what can be shared and what must be copied — is plain host
 bookkeeping, and it lives here so the engine stays a scheduler.

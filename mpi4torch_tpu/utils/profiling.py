@@ -51,6 +51,8 @@ _ENGINE_IDS = itertools.count()
 _STEP_COUNTS = (("admitted", "admitted"),
                 ("prefill_tokens", "prefill_tokens"),
                 ("install_writes", "install_writes"),
+                ("decode_pages_live", "decode_pages_live"),
+                ("decode_pages_read", "decode_pages_read"),
                 ("occupancy_ticks", "active"))
 
 
@@ -184,7 +186,14 @@ class ServeStats:
                  # ISSUE 25: device writes dispatched when prefill rows
                  # are installed into the cache (paged: pages x cache
                  # leaves; dense: cache leaves).
-                 "install_writes")
+                 "install_writes",
+                 # ISSUE 29: pages the paged decode steps' live slots
+                 # held up to their frontiers (sum of pos // block_size
+                 # + 1), and pages those steps' attention visited by
+                 # the read the engine compiled: the same pages through
+                 # the kernel, slots x max_seq / block_size through the
+                 # gather.  Their ratio says which read ran.
+                 "decode_pages_live", "decode_pages_read")
     SPAN_CAP = 1024
 
     def __init__(self):
@@ -345,7 +354,8 @@ def serve_step_log() -> list:
     """A copy of the process-wide step log, oldest first: one record
     per ``Engine.step()`` call of every engine, ``{"engine", "t0_ns",
     "t1_ns", "spans": [(name, t0_ns, t1_ns, rid), ...], "admitted",
-    "prefill_tokens", "install_writes", "active"}`` on the
+    "prefill_tokens", "install_writes", "decode_pages_live",
+    "decode_pages_read", "active"}`` on the
     ``time.perf_counter_ns()`` clock, the last :data:`STEP_LOG_CAP`
     steps.  ``engine`` is the ``ServeStats.engine`` serial of the
     engine that stepped; the counts are what that step added to the

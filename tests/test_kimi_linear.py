@@ -157,37 +157,6 @@ def test_blockwise_causal_attention_is_one_call_in_blocks():
     _tree_close(grads_b, grads_1, 1e-11)
 
 
-@pytest.fixture(scope="module")
-def one_v5e_chip():
-    """A described (not attached) v5e chip: compile-only."""
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:                       # no TPU compiler here
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
-
-
-def test_mla_blocks_compile_for_the_v5e_with_all_three_kernels(
-        one_v5e_chip, monkeypatch):
-    """At the published widths (32 heads, keys of 192 staged 256 wide)
-    Mosaic takes the 2,048-token blocks, forward and both backward
-    kernels; one call over 8,192 tokens it refuses."""
-    from mpi4torch_tpu.ops import flash
-    monkeypatch.setattr(flash, "_on_tpu", lambda: True)
-    like = jax.ShapeDtypeStruct((1, 2 * T._MLA_BLOCK, 32, 192), jnp.bfloat16,
-                                sharding=one_v5e_chip)
-    loss = lambda q, k, v: jnp.sum(T._blockwise_causal_attention(
-        q, k, v, T._MLA_BLOCK).astype(F32))
-    with jax.enable_x64(False):      # the kernels are traced without x64
-        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-            like, like, like).compile().as_text()
-    for name in flash.KERNEL_NAMES:
-        assert name in text, name
-
-
 def _flat(y):
     return y.reshape(-1, y.shape[-1])
 

@@ -1,0 +1,266 @@
+"""Paged decode attention (ISSUE 29): the Pallas kernel, interpreted on
+the CPU host, against the gather path (``block_gather`` + the jnp block
+attention) it replaces on a TPU.
+
+The kernel's softmax is online, page by page, so it equals the gather
+path to rounding; everything else is exact and is held exactly: pages
+beyond a slot's frontier and pages of other slots never reach the
+result (they are filled with NaN here), a free slot reads nothing and
+returns zeros, an unmapped page inside the frontier reads as zeros, and
+off a TPU ``impl="auto"`` IS the gather path, bit for bit."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mpi4torch_tpu.ops import paged_attention as pa
+from mpi4torch_tpu.ops.flash import flash_block_attention
+from mpi4torch_tpu.ops.ragged import block_gather
+
+HD, KVH, N_BLK = 128, 2, 4
+TOL = {jnp.float32: 2e-6, jnp.bfloat16: 2e-2}
+
+
+def block_size(dtype):
+    """The smallest page the kernel takes: the dtype's sublane tile."""
+    return 8 if dtype == jnp.float32 else 16
+
+
+def a_case(dtype, g, seed=0):
+    """Six slots over a pool of scattered pages.  Positions 0,
+    ``bs - 1``, ``bs``, ``max_seq - 1`` and one mid-page; slot 4 shares
+    slot 3's first two pages (a read-only prefix); slot 5 is free (row
+    all ``-1``).  Entries beyond each frontier are ``-1`` (unmapped)."""
+    bs = block_size(dtype)
+    rng = np.random.default_rng(seed)
+    nb = 5 * N_BLK
+    pos = np.array([0, bs - 1, bs, N_BLK * bs - 1, 2 * bs + 3, 0], np.int32)
+    active = np.array([1, 1, 1, 1, 1, 0], bool)
+    ids = [int(i) for i in rng.permutation(nb)]
+    table = np.full((6, N_BLK), -1, np.int32)
+    for s in range(5):
+        for j in range(pos[s] // bs + 1):
+            table[s, j] = ids.pop()
+    table[4, :2] = table[3, :2]
+    mk = lambda shape: jnp.asarray(rng.standard_normal(shape), dtype)
+    q = mk((6, KVH * g, HD))
+    pk, pv = mk((nb, bs, KVH, HD)), mk((nb, bs, KVH, HD))
+    return q, pk, pv, table, pos, active
+
+
+def gather_path(q, pk, pv, table, pos, window=0):
+    """What the decode step did before the kernel, spelt out."""
+    o, _ = flash_block_attention(
+        q[:, None], block_gather(pk, table), block_gather(pv, table),
+        causal=True, q_offset=jnp.asarray(pos), kv_offset=0, window=window,
+        impl="jnp")
+    return o[:, 0]
+
+
+def f32(x):
+    return np.asarray(x.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("g", [1, 2, 4])
+@pytest.mark.parametrize("window", [0, 11])
+def test_kernel_equals_the_gather_path(dtype, g, window):
+    q, pk, pv, table, pos, active = a_case(dtype, g)
+    got = pa.paged_decode_attention(q, pk, pv, table, pos, window=window,
+                                    active=active, impl="pallas")
+    want = gather_path(q, pk, pv, table, pos, window)
+    assert got.dtype == q.dtype and got.shape == q.shape
+    np.testing.assert_allclose(f32(got)[active], f32(want)[active],
+                               atol=TOL[dtype], rtol=TOL[dtype])
+    # the free slot read nothing
+    assert not f32(got)[~active].any()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("window", [0, 11])
+def test_pages_beyond_the_frontier_and_of_other_slots_are_never_read(
+        dtype, window):
+    """NaN in every page that no live slot holds up to its frontier, and
+    slot by slot in every page of the OTHER slots: the output is finite
+    and bit for bit what the clean pool gave."""
+    q, pk, pv, table, pos, active = a_case(dtype, 2, seed=1)
+    bs = pk.shape[1]
+    clean = f32(pa.paged_decode_attention(
+        q, pk, pv, table, pos, window=window, active=active, impl="pallas"))
+
+    def poisoned(keep):
+        mask = np.ones(pk.shape[0], bool)
+        mask[sorted(keep)] = False
+        poison = lambda a: jnp.where(mask[:, None, None, None],
+                                     jnp.asarray(jnp.nan, a.dtype), a)
+        return poison(pk), poison(pv)
+
+    held = {s: set(table[s, :pos[s] // bs + 1].tolist())
+            for s in range(6) if active[s]}
+    # a free slot's stale table row is not followed either
+    table = table.copy()
+    table[5] = table[3]
+    pkn, pvn = poisoned(set().union(*held.values()))
+    got = f32(pa.paged_decode_attention(
+        q, pkn, pvn, table, pos, window=window, active=active,
+        impl="pallas"))
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, clean)
+    for s, mine in held.items():
+        pkn, pvn = poisoned(mine)
+        got = f32(pa.paged_decode_attention(
+            q, pkn, pvn, table, pos, window=window, active=active,
+            impl="pallas"))
+        assert np.isfinite(got[s]).all()
+        np.testing.assert_array_equal(got[s], clean[s])
+
+
+def test_pages_wholly_behind_the_window_are_never_read():
+    q, pk, pv, table, pos, active = a_case(jnp.float32, 2, seed=2)
+    bs, window = pk.shape[1], 5          # slot 3 sees its last page only
+    clean = f32(pa.paged_decode_attention(
+        q, pk, pv, table, pos, window=window, active=active, impl="pallas"))
+    behind = table[3, :N_BLK - 1]
+    pkn = pk.at[behind].set(jnp.nan)
+    pvn = pv.at[behind].set(jnp.nan)
+    got = f32(pa.paged_decode_attention(
+        q, pkn, pvn, table, pos, window=window, active=active,
+        impl="pallas"))
+    # slot 4 shares two of those pages and sees one of them: its row may
+    # be NaN, slot 3's may not.
+    assert (pos[3] - window + 1) // bs == N_BLK - 1
+    np.testing.assert_array_equal(got[3], clean[3])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_unmapped_page_inside_the_frontier_reads_as_zeros(dtype):
+    """``block_gather`` hands an unmapped page over as zeros wherever it
+    sits; so does the kernel, whatever page its clamped index fetched
+    (page 0 here, filled with NaN)."""
+    q, pk, pv, table, pos, active = a_case(dtype, 2, seed=3)
+    table = table.copy()
+    table[3, 1] = -1
+    free = [i for i in range(pk.shape[0]) if i not in set(table.ravel())]
+    pk = pk.at[0].set(pk[free[0]]).at[free[0]].set(jnp.nan)
+    pv = pv.at[0].set(pv[free[0]]).at[free[0]].set(jnp.nan)
+    table = np.where(table == 0, free[0], table).astype(np.int32)
+    pk, pv = pk.at[0].set(jnp.nan), pv.at[0].set(jnp.nan)
+    got = pa.paged_decode_attention(q, pk, pv, table, pos, active=active,
+                                    impl="pallas")
+    want = gather_path(q, pk, pv, table, pos)
+    np.testing.assert_allclose(f32(got)[active], f32(want)[active],
+                               atol=TOL[dtype], rtol=TOL[dtype])
+
+
+def test_without_an_active_mask_every_slot_reads():
+    q, pk, pv, table, pos, _ = a_case(jnp.float32, 2, seed=4)
+    table = table.copy()
+    table[5, 0] = table[0, 0]
+    got = pa.paged_decode_attention(q, pk, pv, table, pos, impl="pallas")
+    want = gather_path(q, pk, pv, table, pos)
+    np.testing.assert_allclose(f32(got), f32(want), atol=2e-6, rtol=2e-6)
+    assert f32(got)[5].any()
+
+
+def test_a_position_past_the_tables_extent_is_held_to_the_table():
+    """The index maps read the table at the live pages: a position
+    beyond ``max_seq`` (the engine never sends one) reads the slot's
+    whole row and nothing past it, as the gather path does."""
+    q, pk, pv, table, pos, active = a_case(jnp.float32, 2, seed=7)
+    pos = pos.copy()
+    pos[3] = N_BLK * pk.shape[1] + 5
+    got = pa.paged_decode_attention(q, pk, pv, table, pos, active=active,
+                                    impl="pallas")
+    want = gather_path(q, pk, pv, table, pos)
+    np.testing.assert_allclose(f32(got)[active], f32(want)[active],
+                               atol=2e-6, rtol=2e-6)
+
+
+@pytest.mark.parametrize("active", [None, "mask"])
+def test_off_the_tpu_auto_is_the_gather_path_bitwise(active):
+    q, pk, pv, table, pos, mask = a_case(jnp.float32, 2, seed=5)
+    assert not pa.uses_kernel(q, pk)
+    got = pa.paged_decode_attention(
+        q, pk, pv, table, pos, window=7,
+        active=mask if active else None)
+    want = np.array(gather_path(q, pk, pv, table, pos, 7))
+    if active:
+        want[~mask] = 0.0
+    np.testing.assert_array_equal(np.asarray(got), want)
+    # float64 (the suite's serving dtype) has no kernel at all
+    q64, pk64, pv64 = (a.astype(jnp.float64) for a in (q, pk, pv))
+    np.testing.assert_array_equal(
+        np.asarray(pa.paged_decode_attention(q64, pk64, pv64, table, pos)),
+        np.asarray(gather_path(q64, pk64, pv64, table, pos)))
+
+
+def test_one_program_for_every_table_and_position():
+    q, pk, pv, table, pos, active = a_case(jnp.float32, 2, seed=6)
+    f = jax.jit(lambda *a: pa.paged_decode_attention(
+        *a[:5], active=a[5], impl="pallas"))
+    a = f(q, pk, pv, table, pos, active)
+    t2 = np.roll(table, 1, axis=0)
+    b = f(q, pk, pv, t2, np.roll(pos, 1), np.roll(active, 1))
+    assert f._cache_size() == 1
+    np.testing.assert_allclose(
+        f32(b), f32(pa.paged_decode_attention(
+            q, pk, pv, t2, np.roll(pos, 1), active=np.roll(active, 1),
+            impl="jnp")), atol=2e-6, rtol=2e-6)
+    assert np.abs(f32(a) - f32(b)).max() > 0
+
+
+class TestEligibility:
+    def q(self, hd=128, dtype=jnp.bfloat16, heads=16):
+        return jax.ShapeDtypeStruct((16, heads, hd), dtype)
+
+    def pool(self, bs=128, kvh=8, hd=128, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((320, bs, kvh, hd), dtype)
+
+    def test_the_serving_cells_shapes_are_eligible(self):
+        assert pa._eligible(self.q(), self.pool())
+        assert pa._eligible(self.q(dtype=jnp.float32),
+                            self.pool(dtype=jnp.float32))
+        assert pa._eligible(self.q(), self.pool(bs=16))
+        assert pa._eligible(self.q(256), self.pool(hd=256))
+
+    @pytest.mark.parametrize("why,q,pool", [
+        ("head of 64", dict(hd=64), dict(hd=64)),
+        ("head of 192", dict(hd=192), dict(hd=192)),
+        ("bf16 page of 8", {}, dict(bs=8)),
+        ("f32 page of 4", dict(dtype=jnp.float32),
+         dict(bs=4, dtype=jnp.float32)),
+        ("down-cast cache", dict(dtype=jnp.float32), {}),
+        ("one-byte pool", dict(dtype=jnp.int8), dict(dtype=jnp.int8)),
+        ("float64", dict(dtype=jnp.float64), dict(dtype=jnp.float64)),
+        ("pages past the VMEM budget", {}, dict(bs=1024, kvh=32)),
+    ])
+    def test_ineligible(self, why, q, pool):
+        assert not pa._eligible(self.q(**q), self.pool(**pool)), why
+
+    def test_forced_kernel_refuses_ineligible_operands(self):
+        q, pk, pv, table, pos, _ = a_case(jnp.float32, 2)
+        with pytest.raises(ValueError, match="kernel-eligible"):
+            pa.paged_decode_attention(q[..., :64], pk[..., :64],
+                                      pv[..., :64], table, pos,
+                                      impl="pallas")
+
+
+@pytest.mark.parametrize("bad,match", [
+    (dict(impl="mosaic"), "unknown impl"),
+    (dict(window=-1), "window"),
+    (dict(q=jnp.zeros((6, 3, HD))), "multiple of KV heads"),
+    (dict(q=jnp.zeros((6, 2, 64))), "head_dim"),
+    (dict(pos=np.zeros((5,), np.int32)), r"\(slots,\)"),
+    (dict(table=np.zeros((5, N_BLK), np.int32)), "n_blk"),
+])
+def test_validation(bad, match):
+    q, pk, pv, table, pos, _ = a_case(jnp.float32, 2)
+    kw = dict(q=q, table=table, pos=pos, window=0, impl="auto")
+    kw.update(bad)
+    with pytest.raises(ValueError, match=match):
+        pa.paged_decode_attention(kw["q"], pk, pv, kw["table"], kw["pos"],
+                                  window=kw["window"], impl=kw["impl"])

@@ -16,6 +16,7 @@ from mpi4torch_tpu import COMM_WORLD as comm
 from mpi4torch_tpu.ops import (block_gather, block_scatter,
                                ragged_allgather, ragged_alltoall,
                                ragged_gather, ragged_scatter, segment_mask)
+from mpi4torch_tpu.ops.ragged import position_onehot
 
 NR = 4
 CAP = 5
@@ -505,3 +506,119 @@ class TestBlockScatter:
         g = np.asarray(block_gather(out, table))
         np.testing.assert_array_equal(g[0, 3], vals[0])
         np.testing.assert_array_equal(g[1, 0], vals[1])
+
+
+def _onehot_scatter(pool, block_ids, offsets, values, active=None):
+    """``block_scatter`` as it was before ISSUE 29, kept here as the
+    reference: integer one-hot routing of each writer to its cell and a
+    ``where`` over the whole pool."""
+    pool, values = jnp.asarray(pool), jnp.asarray(values)
+    nb, bs = pool.shape[0], pool.shape[1]
+    b = jnp.asarray(block_ids, jnp.int32)
+    o = jnp.asarray(offsets, jnp.int32)
+    live = (b >= 0) & (b < nb) & (o >= 0) & (o < bs)
+    if active is not None:
+        live = live & (jnp.asarray(active).astype(bool))
+    bmask = (jnp.arange(nb, dtype=jnp.int32)[None, :] == b[:, None]) \
+        & live[:, None]
+    omask = position_onehot(o, bs) != 0
+    cell = bmask[:, :, None] & omask[:, None, :]
+    hit = cell.any(axis=0)
+    writer = jnp.einsum("wnb,w->nb", cell.astype(jnp.int32),
+                        jnp.arange(b.shape[0], dtype=jnp.int32))
+    src = jnp.take(values, writer.reshape(-1), axis=0).reshape(
+        (nb, bs) + values.shape[1:])
+    mask = hit.reshape((nb, bs) + (1,) * (pool.ndim - 2))
+    return jnp.where(mask, src.astype(pool.dtype), pool)
+
+
+class TestBlockScatterInPlace:
+    """ISSUE 29: the write is a true scatter of ``writers`` rows, bit
+    for bit what the one-hot formulation wrote, and in the pool's own
+    buffer when the pool is donated."""
+
+    # (block ids, offsets, active); pool of 6 pages of 4 rows.
+    WRITERS = [
+        pytest.param([3, 1, 5], [0, 2, 3], None, id="plain"),
+        pytest.param([3, -1, 5], [0, 2, 3], None, id="free-slot-id"),
+        pytest.param([-1, -1, -1], [0, 0, 0], None, id="all-free"),
+        pytest.param([5, -1, -1], [3, 3, 3], None,
+                     id="free-ids-beside-the-last-page"),
+        pytest.param([3, 6, 600], [0, 2, 3], None, id="ids-past-the-pool"),
+        pytest.param([3, 1, 5], [-1, 4, 3], None, id="offsets-out-of-range"),
+        pytest.param([3, 1, 5], [0, 2, 3], [True, False, True],
+                     id="inactive-writer"),
+        pytest.param([3, 1, 5], [0, 2, 3], [0, 0, 0], id="all-inactive"),
+        pytest.param([0, 0, 0], [0, 1, 2], None, id="one-page-three-rows"),
+    ]
+
+    @pytest.mark.parametrize("pool_dtype,value_dtype", [
+        (np.float32, np.float32), (np.float32, np.float64),
+        (jnp.bfloat16, np.float32), (np.int32, np.int32)],
+        ids=["f32", "f64-into-f32", "f32-into-bf16", "i32"])
+    @pytest.mark.parametrize("ids,offs,active", WRITERS)
+    def test_bitwise_vs_one_hot_formulation(self, ids, offs, active,
+                                            pool_dtype, value_dtype):
+        rng = np.random.default_rng(29)
+        pool = jnp.asarray(rng.standard_normal((6, 4, 2, 8)) * 9, pool_dtype)
+        pool = pool.at[2, 1].set(jnp.asarray(-0.0, pool.dtype))
+        vals = jnp.asarray(rng.standard_normal((3, 2, 8)) * 9, value_dtype)
+        act = None if active is None else np.asarray(active)
+        got = block_scatter(pool, np.array(ids), np.array(offs), vals, act)
+        want = _onehot_scatter(pool, np.array(ids), np.array(offs), vals, act)
+        assert got.dtype == pool.dtype
+        np.testing.assert_array_equal(
+            np.asarray(got).view(np.uint8), np.asarray(want).view(np.uint8))
+
+    def test_nan_cells_elsewhere_keep_their_bits(self):
+        pool = _pool(nb=6, bs=4)
+        pool[5, 3] = np.nan
+        got = np.asarray(block_scatter(
+            pool, np.array([-1, 2]), np.array([3, 0]),
+            np.ones((2, 2), np.float32)))
+        want = np.asarray(_onehot_scatter(
+            pool, np.array([-1, 2]), np.array([3, 0]),
+            np.ones((2, 2), np.float32)))
+        np.testing.assert_array_equal(got.view(np.uint32),
+                                      want.view(np.uint32))
+        assert np.isnan(got[5, 3]).all()          # -1 did not wrap to it
+
+    def test_one_program_for_every_writer_state(self):
+        f = jax.jit(block_scatter)
+        pool = _pool(nb=6, bs=4)
+        vals = np.ones((2, 2), np.float32)
+        for ids, offs, act in (([1, 2], [0, 3], [True, True]),
+                               ([-1, 5], [0, 0], [True, True]),
+                               ([4, 4], [1, 2], [False, True])):
+            np.testing.assert_array_equal(
+                np.asarray(f(pool, np.array(ids), np.array(offs), vals,
+                             np.array(act))),
+                np.asarray(_onehot_scatter(pool, np.array(ids),
+                                           np.array(offs), vals,
+                                           np.array(act))))
+        assert f._cache_size() == 1
+
+    def test_lowers_to_a_scatter_and_nothing_of_the_pools_shape_beside(self):
+        pool = jnp.zeros((6, 4, 2, 8), jnp.float32)
+        text = jax.jit(block_scatter).lower(
+            pool, np.array([1, -1]), np.array([0, 0]),
+            jnp.ones((2, 2, 8)), np.array([True, True])).as_text()
+        assert text.count('"stablehlo.scatter"(') == 1
+        # No other instruction's result has the pool's type (the
+        # scatter's own closes on a line without an op name).
+        import re
+        made = [m.group(1) for m in re.finditer(
+            r"= \"?stablehlo\.(\w+)[^\n]*tensor<6x4x2x8xf32>\n", text)]
+        assert made == [], made
+
+    def test_a_donated_pool_is_written_in_its_own_buffer(self):
+        f = jax.jit(block_scatter, donate_argnums=0)
+        pool = jnp.asarray(_pool(nb=6, bs=4))
+        want = np.asarray(_onehot_scatter(
+            pool, np.array([3]), np.array([1]), np.ones((1, 2), np.float32)))
+        where = pool.unsafe_buffer_pointer()
+        out = f(pool, np.array([3]), np.array([1]),
+                np.ones((1, 2), np.float32))
+        assert pool.is_deleted()
+        assert out.unsafe_buffer_pointer() == where
+        np.testing.assert_array_equal(np.asarray(out), want)

@@ -1229,6 +1229,152 @@ class TestCompiledInstall:
                                   np.array([0, 5, 1, 2, 3], np.int32))
 
 
+class TestPagedStepInPlace:
+    """ISSUE 29: the paged decode step takes its pool over and writes
+    one row per live slot into it; nothing of a pool leaf's shape is
+    selected or broadcast; two counters say how many pages the step's
+    attention visited beside how many its live slots hold."""
+
+    BS, SLOTS = 4, 3
+
+    def _engine(self, spmd, **kw):
+        return serve.Engine(
+            CFG, _params(CFG),
+            serve.ServeConfig(slots=self.SLOTS, block_size=self.BS, **kw),
+            spmd=spmd, nranks=2 if spmd else None)
+
+    @pytest.mark.parametrize("spmd", [False, True],
+                             ids=["eager", "spmd-built-with-jit-off"])
+    def test_first_decode_step_of_a_fresh_engine_donates(self, spmd):
+        """The step after the admitting one runs no install: what takes
+        the pool over there is the decode step itself."""
+        if spmd:
+            with jax.disable_jit():
+                eng = self._engine(True)
+        else:
+            eng = self._engine(False)
+        eng.submit(PROMPTS[1], max_new=4)
+        eng.step()                       # install + first decode step
+        layout = jax.tree.leaves(eng._cache)[0].sharding
+        leaves = jax.tree.leaves(eng._cache)
+        before = eng.stats.counters["install_writes"]
+        eng.step()                       # a decode step and nothing else
+        assert eng.stats.counters["install_writes"] == before
+        assert all(a.is_deleted() for a in leaves)
+        now = jax.tree.leaves(eng._cache)
+        assert not any(a.is_deleted() for a in now)
+        # the state keeps its layout through the donated step
+        assert all(a.sharding == layout for a in now)
+        np.testing.assert_array_equal(
+            eng.run()[0], oracle_tokens(CFG, _params(CFG), PROMPTS[1], 4))
+
+    @pytest.mark.parametrize("spmd", [False, True], ids=["eager", "spmd"])
+    def test_only_the_written_cells_change(self, spmd):
+        """One decode step over a seeded pool: every cell but the live
+        slots' ``(page, offset)`` keeps its bits (free slots and their
+        ``-1`` ids write nothing, and not into the last page)."""
+        eng = self._engine(spmd)
+        eng.submit(PROMPTS[0], max_new=6)       # 3 tokens: page 0
+        eng.submit(PROMPTS[1], max_new=4)       # 5 tokens: pages 0-1
+        eng.step()
+        eng._cache = _seeded_pool(eng, 29)
+        before = jax.tree.map(np.asarray, eng._cache)
+        eng.step()
+        # The step wrote at the position each slot has just left (the
+        # first slot's row opened a new page, mapped inside the step).
+        cells = [(int(eng._table[j, (eng._pos[j] - 1) // self.BS]),
+                  int((eng._pos[j] - 1) % self.BS)) for j in (0, 1)]
+        assert cells[0][1] == 0
+        written = np.zeros(before[0]["k"].shape[-4:-2], bool)
+        for b, o in cells:
+            written[b, o] = True
+        for got, old in zip(jax.tree.leaves(eng._cache),
+                            jax.tree.leaves(before)):
+            got = np.asarray(got)
+            np.testing.assert_array_equal(got[..., ~written, :, :],
+                                          old[..., ~written, :, :])
+            assert (got[..., written, :, :]
+                    != old[..., written, :, :]).all()
+
+    def test_lowered_step_selects_and_broadcasts_nothing_pool_shaped(self):
+        import re
+
+        eng = serve.Engine(
+            CFG, _params(CFG),
+            serve.ServeConfig(slots=self.SLOTS, block_size=self.BS,
+                              num_blocks=7), spmd=True, nranks=2)
+        eng.submit(PROMPTS[0], max_new=6)
+        eng.step()
+        text = eng.lower_step().as_text(debug_info=False)
+        hd = CFG.d_model // CFG.n_heads
+        leaf = f"{7}x{self.BS}x{CFG.n_heads // 2}x{hd}xf64>"
+        # run_spmd stacks a rank's new leaf under a leading axis of 1 (a
+        # broadcast_in_dim that moves nothing): not an instruction that
+        # fills a leaf, and told apart by its operand.
+        stacking = f"(tensor<{leaf}) -> tensor<1x{leaf}"
+        made = [m.group(1) for m in re.finditer(
+            r"= \"?stablehlo\.(\w+)([^\n]*)tensor<(?:\d+x)?"
+            + re.escape(leaf) + r"\n", text)
+            if stacking not in m.group(0)]
+        # a leaf is sliced off the stacked state and written; nothing is
+        # selected into one or broadcast over one
+        assert sorted(set(made)) == ["dynamic_slice", "reshape"], made
+        assert text.count('"stablehlo.scatter"(') == 2 * CFG.n_layers
+
+    def test_page_counters_against_hand_counted_pages(self, monkeypatch):
+        eng = self._engine(False)
+        n_blk = CFG.max_seq // self.BS
+        eng.submit(np.arange(1, 4), max_new=4)      # 3 tokens
+        eng.submit(np.arange(1, 10), max_new=3)     # 9 tokens
+        from mpi4torch_tpu.utils import profiling
+        log0 = len(profiling.serve_step_log())
+        eng.run()
+        recs = profiling.serve_step_log()[log0:]
+        # Positions each decode step attends from: the first request
+        # decodes at 3, 4, 5 (its 4th token needs no write), the second
+        # at 9, 10.
+        want_live = [(3 // 4 + 1) + (9 // 4 + 1),
+                     (4 // 4 + 1) + (10 // 4 + 1),
+                     (5 // 4 + 1)]
+        assert [r["decode_pages_live"] for r in recs] == want_live
+        # off the TPU the read is the gather: every slot's whole row
+        assert [r["decode_pages_read"] for r in recs] \
+            == [2 * n_blk, 2 * n_blk, 1 * n_blk]
+        assert eng.stats.counters["decode_pages_live"] == sum(want_live)
+        assert eng.stats.counters["decode_pages_read"] == 5 * n_blk
+        # an engine whose step compiled the kernel visits what is live
+        eng2 = self._engine(False)
+        monkeypatch.setattr(eng2, "_kernel_read", True)
+        eng2.submit(np.arange(1, 4), max_new=4)
+        eng2.submit(np.arange(1, 10), max_new=3)
+        eng2.run()
+        assert eng2.stats.counters["decode_pages_read"] \
+            == eng2.stats.counters["decode_pages_live"] == sum(want_live)
+
+    def test_dense_engine_counts_no_pages(self):
+        eng = serve.Engine(CFG, _params(CFG), serve.ServeConfig(slots=2))
+        eng.submit(PROMPTS[0], max_new=3)
+        eng.run()
+        assert eng.stats.counters["decode_pages_live"] == 0
+        assert eng.stats.counters["decode_pages_read"] == 0
+
+    def test_engine_asks_the_kernels_own_predicate(self, monkeypatch):
+        from mpi4torch_tpu.ops import paged_attention as pa
+        asked = []
+
+        def uses_kernel(q, pool_k):
+            asked.append((q.shape, str(q.dtype), pool_k.shape))
+            return False
+
+        monkeypatch.setattr(pa, "uses_kernel", uses_kernel)
+        eng = self._engine(False)
+        hd = CFG.d_model // CFG.n_heads
+        assert asked == [((self.SLOTS, CFG.n_heads, hd), "float64",
+                          (self.SLOTS * CFG.max_seq // self.BS, self.BS,
+                           CFG.n_heads, hd))]
+        assert eng._kernel_read is False
+
+
 class TestPagedDrainReadmit:
     def test_tickets_carry_pages_and_readmit_prefix_hits(self):
         # Satellite 6: a drained paged request's ticket carries its

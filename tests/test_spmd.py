@@ -940,3 +940,42 @@ def test_no_private_jax_imports():
         str(p) for p in pkg.rglob("*.py") if "jax._src" in p.read_text()
     ]
     assert offenders == []
+
+
+class TestDonation:
+    """``run_spmd(..., donate_argnums=...)`` (ISSUE 29): stacked state
+    that goes in and comes out in one layout is handed to the program."""
+
+    @staticmethod
+    def bump(state, x):
+        # state arrives stacked (NR, 3); each rank returns its own row.
+        mine = jax.lax.dynamic_index_in_dim(
+            state, jnp.asarray(comm.rank), 0, keepdims=False)
+        return mine + comm.Allreduce(x, mpi.MPI_SUM)
+
+    def test_donated_argument_is_taken_and_the_result_is_right(self):
+        f = run(self.bump, donate_argnums=(0,))
+        state, x = f(jnp.zeros((NR, 3)), jnp.ones(3)), jnp.ones(3)
+        old = state
+        state = f(state, x)
+        assert old.is_deleted() and not x.is_deleted()
+        np.testing.assert_array_equal(np.asarray(state),
+                                      np.full((NR, 3), 2.0 * NR))
+
+    def test_default_takes_nothing(self):
+        f = run(self.bump)
+        state = jnp.zeros((NR, 3))
+        f(state, jnp.ones(3))
+        assert not state.is_deleted()
+
+    def test_donation_does_not_change_the_lowering(self):
+        # The option is jit's: the traced program is the same text.
+        args = (jnp.zeros((NR, 3)), jnp.ones(3))
+        plain = jax.jit(run(self.bump)).lower(*args).as_text()
+        taken = jax.jit(run(self.bump, donate_argnums=(0,))) \
+            .lower(*args).as_text()
+        assert plain == taken
+
+    def test_needs_jit(self):
+        with pytest.raises(ValueError, match="jit=True"):
+            run(self.bump, jit=False, donate_argnums=(0,))

@@ -1,0 +1,145 @@
+"""Compiles for a TPU v5e that is described, not attached: what Mosaic
+and the TPU's XLA accept or refuse at real widths, found here at no
+chip time.  Nothing runs; no result, no time.
+
+Every test that loads the TPU's compiler lives in THIS file: one process
+at a time may hold the library, the tier-1 run hands whole files to its
+workers, and a second such file could land on a worker whose fixture
+then skips it in silence.  The topology is described inside a fixture,
+never while a module is imported."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mpi4torch_tpu.models import transformer as T
+from mpi4torch_tpu.ops import flash
+from mpi4torch_tpu.ops import paged_attention as pa
+
+F32 = jnp.float32
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip():
+    """A described (not attached) v5e chip: compile-only."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                       # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """The host platform is the CPU; the dispatch predicates are told
+    otherwise, here in the test and by no option of the program."""
+    monkeypatch.setattr(flash, "_on_tpu", lambda: True)
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+
+
+def test_mla_blocks_compile_for_the_v5e_with_all_three_kernels(
+        one_v5e_chip, as_on_tpu):
+    """At the published widths (32 heads, keys of 192 staged 256 wide)
+    Mosaic takes the 2,048-token blocks, forward and both backward
+    kernels; one call over 8,192 tokens it refuses."""
+    like = jax.ShapeDtypeStruct((1, 2 * T._MLA_BLOCK, 32, 192), jnp.bfloat16,
+                                sharding=one_v5e_chip)
+    loss = lambda q, k, v: jnp.sum(T._blockwise_causal_attention(
+        q, k, v, T._MLA_BLOCK).astype(F32))
+    with jax.enable_x64(False):      # the kernels are traced without x64
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            like, like, like).compile().as_text()
+    for name in flash.KERNEL_NAMES:
+        assert name in text, name
+
+
+def _made_with_shape(text: str, dims: str) -> list:
+    """Names of the compiled instructions whose result has that shape."""
+    return [m.group(1) for m in re.finditer(
+        r"= \w+\[" + re.escape(dims) + r"\]\S* ([\w\-]+)\(", text)]
+
+
+@pytest.mark.parametrize("dtype,window", [
+    (jnp.bfloat16, 0), (jnp.bfloat16, 300), (jnp.float32, 0)],
+    ids=["bf16", "bf16-window", "f32"])
+def test_paged_attention_compiles_at_the_serving_cells_shapes(
+        one_v5e_chip, as_on_tpu, dtype, window):
+    """InternLM2-1.8B's decode step in `serve_chat`: 16 slots, 16 query
+    heads over 8 KV heads of 128, a pool of 320 pages of 128 positions.
+    Mosaic takes the kernel, and the kernel's view of a pool leaf
+    (``block_size * kv_heads`` rows) is the leaf's own bytes: a bitcast,
+    no copy of the pool."""
+    like = lambda shape, dt=dtype: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_v5e_chip)
+    q, pool = like((16, 16, 128)), like((320, 128, 8, 128))
+    assert pa.uses_kernel(q, pool)
+    with jax.enable_x64(False):
+        text = jax.jit(lambda *a: pa.paged_decode_attention(
+            *a, window=window)).lower(
+                q, pool, pool, like((16, 20), jnp.int32),
+                like((16,), jnp.int32)).compile().as_text()
+    assert pa.KERNEL_NAMES[0] in text and "tpu_custom_call" in text
+    assert set(_made_with_shape(text, "320,128,8,128")) == {"parameter"}
+    assert set(_made_with_shape(text, "320,1024,128")) == {"bitcast"}
+
+
+def test_paged_decode_step_compiles_in_place(one_v5e_chip, as_on_tpu):
+    """The engine's own traced step under ``run_spmd`` with the pool
+    donated, compiled for one v5e chip at head size 128: every pool leaf
+    is aliased to its output, the stacked ``(1, ...)`` axis and the rank
+    slice cost no copy, the write is a scatter into the leaf, the read
+    is the kernel, and no other instruction produces anything of a pool
+    leaf's shape."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from mpi4torch_tpu import serve
+    from mpi4torch_tpu.ops.spmd import run_spmd
+
+    cfg = T.TransformerConfig(vocab=512, d_model=512, n_heads=4,
+                              n_kv_heads=2, n_layers=2, d_ff=1024,
+                              max_seq=512, rope=True, norm="rmsnorm",
+                              ffn="swiglu")
+    # 16 MB a leaf: a pool small enough for the chip's fast memory is
+    # prefetched into it, which reads as a copy of the leaf.
+    slots, bs = 64, 128
+    with jax.enable_x64(False):
+        eng = serve.Engine(
+            cfg, T.init_transformer(jax.random.PRNGKey(0), cfg,
+                                    dtype=jnp.bfloat16),
+            serve.ServeConfig(slots=slots, block_size=bs), spmd=True,
+            nranks=1)
+        assert eng._kernel_read
+        mesh = Mesh(np.array([next(iter(one_v5e_chip.device_set))]),
+                    ("mpi",))
+        state, rep = NamedSharding(mesh, P("mpi")), NamedSharding(mesh, P())
+        like = lambda tree, sh: jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            tree)
+        step = run_spmd(eng._traced_step_paged, mesh=mesh, axis_name="mpi",
+                        donate_argnums=(1,))
+        args = (like(eng._shards, state), like(eng._cache, state),
+                like(jnp.asarray(eng._table), rep),
+                like(jnp.asarray(eng._tokens), rep),
+                like(jnp.asarray(eng._pos), rep),
+                like(jnp.zeros((slots,), bool), rep))
+        compiled = jax.jit(step, donate_argnums=(1,)).lower(*args).compile()
+    text = compiled.as_text()
+    leaves = 2 * cfg.n_layers
+    leaf_bytes = slots * cfg.max_seq * 2 * 128 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == leaves * leaf_bytes
+    assert mem.temp_size_in_bytes < leaf_bytes
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+    assert alias and alias.group(1).count("may-alias") \
+        + alias.group(1).count("must-alias") == leaves
+    assert pa.KERNEL_NAMES[0] in text
+    dims = f"{slots * cfg.max_seq // bs},{bs},2,128"
+    made = _made_with_shape(text, dims) + _made_with_shape(text, "1," + dims)
+    assert made.count("scatter") == leaves
+    assert set(made) <= {"parameter", "bitcast", "scatter", "fusion"}, made
