@@ -44,8 +44,8 @@ tune-smoke:
 # prefetch, each checked BITWISE against its blocking form on the
 # 8-virtual-device mesh; exits non-zero on any divergence.  Wall-clock
 # numbers are informational here (the CPU collective runtime is
-# synchronous); bench.py's overlap_zero stanza records the real
-# exposed-comm fractions on hardware.
+# synchronous); what the collectives cost on the chip is the
+# train_dp4 cell of BENCHMARK.json (PERF.md).
 overlap-smoke:
 	env JAX_PLATFORMS=cpu \
 		XLA_FLAGS="--xla_force_host_platform_device_count=8" \
@@ -244,9 +244,9 @@ ctl-smoke:
 		XLA_FLAGS="--xla_force_host_platform_device_count=8" \
 		python -m mpi4torch_tpu.ctl --smoke
 
-# Fast bench lane: ONLY the per-algorithm allreduce size sweep (the
-# sizes × algorithms GB/s table + measured latency/bandwidth
-# crossovers), no model benches.  Runs on whatever platform JAX
+# The autotuner's per-algorithm allreduce size sweep (a sizes ×
+# algorithms GB/s table + measured latency/bandwidth crossovers) and
+# nothing else.  Runs on whatever platform JAX
 # resolves; always re-measures (winners persist, so it doubles as a
 # tuning run).  Smoke variant on the 8-virtual-device CPU mesh (the
 # device-count flag matters: a 1-device world can only run `ring`):

@@ -39,7 +39,7 @@ from mpi4torch_tpu.models import transformer as T
 from mpi4torch_tpu.ops import flash
 from mpi4torch_tpu.utils.compile_cache import use_compile_cache
 
-# The widths the repo calls its flagship (bench.py train_step stanza):
+# The widths the repo calls its flagship:
 # 541,134,848 parameters, bf16.  Depth and widths are never cut here.
 FLAGSHIP = T.TransformerConfig(vocab=32768, d_model=2048, n_heads=16,
                                n_layers=8, d_ff=8192, max_seq=2048)

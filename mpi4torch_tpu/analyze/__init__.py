@@ -12,7 +12,7 @@ transform).  This package is that pass, in four layers:
   ``replica_groups``, ``source_target_pairs``, channel, payload
   dtype/bytes, and the named-scope label recovered from the debug-info
   loc table — replacing the regex censuses that had grown in
-  overlap/census.py, reshard/census.py, bench.py, and tests/.
+  overlap/census.py, reshard/census.py and tests/.
 * **soundness lints** (:mod:`.lints`): permute tables form valid
   partial permutations, replica groups exactly partition the
   participating axis, split-phase start→wait spans pair up per bucket

@@ -5,10 +5,10 @@ the lowering (ROADMAP: HLO op counts, wire bytes, scheduled exposure,
 peak liveness — the regression currency while wall-clock evidence is
 CPU-smoke only).  This module re-expresses all three text-census
 accountings as passes over :func:`mpi4torch_tpu.analyze.parse_program`;
-the historical entry points (``bench._hlo_wire_bytes_per_device``,
-``reshard.peak_live_bytes``, ``overlap.scheduled_exposure``) delegate
-here, and their recorded BENCH/smoke numbers are regression-pinned
-bit-identical in tests/test_analyze.py (q8-bidir 7280 B, the
+the historical entry points (``reshard.peak_live_bytes``,
+``overlap.scheduled_exposure``) delegate here, and the counts recorded
+before the move are regression-pinned bit-identical in
+tests/test_analyze.py (q8-bidir 7280 B, the
 (8,)->(2,4) reshard migration 98304 B vs the 917504 B gather, the serve
 decode step's per-token wire bytes and exposure fractions).
 
@@ -98,9 +98,8 @@ def wire_contribution(kind: str, payload_bytes: float,
 def wire_bytes_per_device(lowered_or_text) -> Tuple[int, Dict[str, int]]:
     """Deterministic per-device bytes-on-wire of a lowered program
     (see module docstring for the per-kind accountings).  Returns
-    ``(total_bytes, per-op-kind counts)`` — the
-    ``bench._hlo_wire_bytes_per_device`` contract, now a pass over the
-    shared parse."""
+    ``(total_bytes, per-op-kind counts)``, a pass over the shared
+    parse."""
     parsed = _parsed(lowered_or_text)
     wire = 0.0
     counts: Dict[str, int] = {}

@@ -5,8 +5,8 @@ itself a collective, with handle machinery encoding cross-rank ordering
 the per-rank DAG cannot see — is a *structural* property of the lowered
 program, and the repo grew four independent regex readers of that
 structure: the scheduled-exposure census (overlap/census.py), the
-peak-liveness scan (reshard/census.py), the wire-bytes accounting
-(bench.py), and ~45 ad-hoc matchers in tests/test_hlo.py.  This module
+peak-liveness scan (reshard/census.py), a wire-bytes accounting, and
+~45 ad-hoc matchers in tests/test_hlo.py.  This module
 replaces the *parsing* layer under all of them with one pass:
 
 :func:`parse_program` turns any lowered program (a ``jax.stages.

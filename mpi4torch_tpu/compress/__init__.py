@@ -98,10 +98,9 @@ def int8_rotation_census(lowered: str, nranks: int):
     the forward/backward full-ring tables for ``nranks`` (all
     whitespace-normalized, so ``fwd in seen and bwd in seen`` is the
     tentpole's census criterion).  ONE matcher shared by the test census
-    matrix (tests/test_tune.py), the ``make quant-smoke`` lane
-    (compress/__main__.py), and the bench verdict (bench.py) — the
-    StableHLO pattern cannot drift between CI, the smoke lane, and the
-    persisted wire table."""
+    matrix (tests/test_tune.py) and the ``make quant-smoke`` lane
+    (compress/__main__.py) — the StableHLO pattern cannot drift between
+    CI and the smoke lane."""
     import re
 
     seen = set()

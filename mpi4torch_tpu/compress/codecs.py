@@ -130,7 +130,7 @@ class Codec:
 
     def wire_bytes(self, shape, dtype) -> int:
         """Bytes a tensor of ``shape``/``dtype`` occupies on the wire once
-        encoded (the sum of the payload leaves' sizes) — the bench's
+        encoded (the sum of the payload leaves' sizes) — the
         bytes-on-wire accounting, computed from real encoded buffers so
         the number cannot drift from the implementation."""
         x = jnp.zeros(shape, dtype)

@@ -672,8 +672,8 @@ def set_comm_backoff(seconds) -> None:
 
 
 # Non-finite payload guard of the collective layer: "off" (default —
-# the lowering is bit-identical to a guard-less build, HLO-censused in
-# bench.py _bench_guard_overhead), "warn" (IntegrityWarning naming the
+# the lowering is bit-identical to a guard-less build, held by
+# tests/test_resilience.py), "warn" (IntegrityWarning naming the
 # offending rank(s) on the eager backend), or "raise" (IntegrityError).
 _GUARD_MODES = ("off", "warn", "raise")
 _comm_finite_guard = "off"
@@ -918,13 +918,13 @@ def thresholds_fingerprint():
     instead of silently reusing the old lowering."""
     # _comm_wire_checksum is deliberately NOT here: it is a Mode B
     # (rendezvous wire) leg only and provably never moves the Mode A
-    # lowering (censused in bench.py _bench_guard_overhead and
-    # tests/test_resilience.py) — keying it in would force a full
+    # lowering (censused in tests/test_resilience.py) — keying it in
+    # would force a full
     # retrace/recompile for zero semantic effect.
     # The obs tracer keys in only as "does Mode A get the step-event
     # callback": a Mode B-only tracer (mode_a=False, the default) never
     # moves the lowering, so it must not force a retrace either —
-    # censused in bench.py _bench_obs_overhead, like _comm_wire_checksum.
+    # censused in tests/test_obs.py, like _comm_wire_checksum.
     # The ctl knobs ride along even though they never move a lowering
     # directly: the controller's thresholds decide which winners get
     # INSTALLED (tune.record bumps the selection generation), so a
@@ -979,7 +979,7 @@ def compression_scope(codec):
 
 # Master switch: False (default) keeps SelfTuningController.poll to ONE
 # knob read and guarantees the controller changes nothing — the
-# fault-plan/obs off-path discipline, censused in bench.py _bench_ctl.
+# fault-plan/obs off-path discipline, censused in tests/test_ctl.py.
 _ctl_enabled = False
 # EWMA half-life of the bandwidth estimates, in SAMPLES (after this
 # many events a value's weight has decayed to 1/2) — a deterministic

@@ -424,8 +424,8 @@ def reduce_q8_hop(values, *, block: int = 256, algorithm="ring",
 
 
 # Below this element count the N-1 jnp folds beat the host round-trip of
-# the native kernel.  Measured (bench_tradeoffs.py native_reduce_crossover,
-# 8 f32 buffers, round-5 single-core host): native/jnp seconds were
+# the native kernel.  Measured on a host's CPU (8 f32 buffers, round-5
+# single-core host, before any chip run): native/jnp seconds were
 # 3.5e-4/2.4e-4 at 64Ki elements, 7.5e-4/1.04e-3 at 256Ki, 2.3e-3/3.7e-3
 # at 1Mi — the blocked one-pass C fold wins ~1.4-1.6x above the ~128Ki
 # crossover, loses to dispatch overhead below it.

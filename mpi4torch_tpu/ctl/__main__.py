@@ -93,7 +93,7 @@ def closed_loop_episode(*, n: int = 8, tiers=(2, 2, 2),
     :class:`~mpi4torch_tpu.ctl.ledger.Decision` records, the fired
     brownout evidence split by phase, the stale-fence outcome, and the
     final config deltas.  The caller asserts; this driver only
-    collects — so the smoke lane, tests and bench read ONE flow.
+    collects — so the smoke lane and the tests read ONE flow.
     """
     import numpy as np
 

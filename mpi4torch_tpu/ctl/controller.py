@@ -27,7 +27,7 @@ against ``DEGRADE_POLICIES`` and the ledger's trigger vocabulary
 Off path: ``config.ctl_enabled()`` is False by default and ``poll``
 is one knob read — a controller constructed but disabled changes
 NOTHING (bit-identical lowering, untouched config; censused in
-bench.py ``_bench_ctl`` and tests/test_ctl.py).
+tests/test_ctl.py).
 """
 
 from __future__ import annotations

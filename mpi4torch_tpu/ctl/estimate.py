@@ -11,8 +11,7 @@ estimates.
   GOODPUT — *logical* bytes per second: an event on a compressed wire
   censuses its encoded bytes (the same bytes the brownout throttle
   reads), which :func:`goodput_bytes` scales back up by the codec's
-  wire ratio (``compress.get_codec(...).wire_bytes``, the bench's own
-  accounting).  Goodput is codec-INVARIANT, which the control loop
+  wire ratio (``compress.get_codec(...).wire_bytes``).  Goodput is codec-INVARIANT, which the control loop
   needs on both sides: a healthy link reads the same estimate whether
   the wire is exact or q8 (so an escalated episode can *recover* —
   the ratio climbs back above the high watermark once the fault

@@ -333,14 +333,18 @@ def _compute_dtype_rope(x):
     return jnp.promote_types(x.dtype, jnp.float32)
 
 
-def _split_qkv(cfg: TransformerConfig, blk, y, positions=None):
+def _split_qkv(cfg: TransformerConfig, blk, y, positions=None,
+               size: int = 1):
     """Project ``y`` (b, s, d) through the fused qkv matrix and split into
     ``q (b, s, h, hd)`` and ``k``/``v (b, s, kv_heads, hd)`` — the ONE
-    place the asymmetric GQA projection layout lives (forward, prefill
-    and decode all slice through here, so they cannot drift apart)."""
+    place the asymmetric GQA projection layout lives (forward, prefill,
+    decode and the serving walker all slice through here, so they cannot
+    drift apart).  ``size`` is the tensor-parallel world a serving shard
+    ``[q_r | k_r | v_r]`` was cut for: the head counts are then this
+    rank's, ``n_heads // size`` and ``kv_heads // size``."""
     b, s = y.shape[0], y.shape[1]
-    h, h_kv = cfg.n_heads, cfg.kv_heads
-    hd = cfg.d_model // h
+    h, h_kv = cfg.n_heads // size, cfg.kv_heads // size
+    hd = cfg.d_model // cfg.n_heads
     qkv = y @ blk["wqkv"]
     q = qkv[..., :h * hd].reshape(b, s, h, hd)
     k = qkv[..., h * hd:(h + h_kv) * hd].reshape(b, s, h_kv, hd)
@@ -480,6 +484,17 @@ def refuse_layer_spec(cfg: TransformerConfig, what: str) -> None:
             "layer and keep no latent, recurrent or expert state")
 
 
+def _ffn_dense(cfg: TransformerConfig, blk, y):
+    """The dense FFN product of the normed input ``y``, before the
+    residual; with a serving shard's ``w1``/``w2`` it is this rank's
+    partial sum."""
+    if cfg.ffn == "swiglu":
+        gate_up = y @ blk["w1"]
+        gate, up = jnp.split(gate_up, 2, axis=-1)
+        return (jax.nn.silu(gate) * up) @ blk["w2"]
+    return jax.nn.gelu(y @ blk["w1"]) @ blk["w2"]
+
+
 def _ffn_residual(cfg: TransformerConfig, blk, x, comm_ep):
     """Post-attention FFN (dense or MoE) with pre-LN and residual; shared
     by the training forward and the decode path.  Returns ``(x, aux)``.
@@ -500,13 +515,7 @@ def _ffn_residual(cfg: TransformerConfig, blk, x, comm_ep):
         else:
             ff, aux = moe_ffn_dense(flat, blk["moe"], cfg.capacity)
         return x + ff.reshape(*b_s, d), aux
-    if cfg.ffn == "swiglu":
-        gate_up = y @ blk["w1"]
-        gate, up = jnp.split(gate_up, 2, axis=-1)
-        return x + (jax.nn.silu(gate) * up) @ blk["w2"], \
-            jnp.zeros((), x.dtype)
-    return x + jax.nn.gelu(y @ blk["w1"]) @ blk["w2"], \
-        jnp.zeros((), x.dtype)
+    return x + _ffn_dense(cfg, blk, y), jnp.zeros((), x.dtype)
 
 
 def _zigzag_positions(comm_sp, s_local: int):
@@ -889,7 +898,7 @@ def _chunked_ce(x, unembed, labels, vocab_chunk: int):
     vocab chunks under ``lax.scan``: the full (batch, seq, vocab) logits
     array never materializes — each step computes one (batch, seq,
     chunk) slab, folds it into a running online logsumexp, and picks the
-    label logit if it falls in the chunk.  At the flagship bench config
+    label logit if it falls in the chunk.  At the flagship config
     (vocab 32768, bf16) the dense logits alone are ~1 GiB of HBM per
     step; chunking caps the transient at chunk/vocab of that, and the
     backward rebuilds each slab from the O(d) residuals (XLA transposes

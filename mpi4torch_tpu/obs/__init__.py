@@ -13,8 +13,8 @@ This package is that layer, in five pieces:
   mailboxes: fuse/compress/overlap/reshard/serve traffic traced with
   zero per-subsystem hooks), plus Mode A step events via the
   named-scope/host-callback hook.  Off path: one attribute read per
-  rendezvous, lowering bit-identical to an obs-less build (censused in
-  ``bench._bench_obs_overhead``).
+  rendezvous, lowering bit-identical to an obs-less build (held by
+  tests/test_obs.py).
 * a **metrics registry** (:mod:`.metrics`) — thread-safe counters/
   gauges/histograms with JSON snapshot and Prometheus text export,
   absorbing the ad-hoc surfaces (retry events, integrity violations,
@@ -42,7 +42,7 @@ bit-identity census.  See doc/observability.md.
 
 # Module alias first: the `trace` attribute below is the context
 # manager, which shadows the submodule on the package — `obs.tracing`
-# is the patchable module handle (bench's obs-less-build census
+# is the patchable module handle (an obs-less-build census
 # monkeypatches `tracing.spmd_collective_event`).
 from . import trace as tracing  # noqa: F401  (module alias)
 from .events import CommEvent, annotate_signature, payload_nbytes

@@ -24,10 +24,9 @@ Three pieces:
   utils/profiling.py, re-homed here as the single implementation (a
   discarded engine drops out of the aggregate and out of memory).
 
-:func:`percentile` is the one percentile rule ``ServeStats.snapshot``
-and ``bench.py`` share (nearest-rank floor: index ``min(int(q*n),
-n-1)`` of the sorted sample — bench's historical rule, so recorded
-BENCH numbers are unchanged).
+:func:`percentile` is the one percentile rule of ``ServeStats.snapshot``
+(nearest-rank floor: index ``min(int(q*n), n-1)`` of the sorted
+sample).
 """
 
 from __future__ import annotations
@@ -62,9 +61,8 @@ DEFAULT_BUCKETS = (1e-5, 1e-4, 1e-3, 1e-2, 1e-1, 1.0, 10.0)
 def percentile(values: Sequence[float], q: float) -> Optional[float]:
     """Nearest-rank-floor percentile of ``values`` (sorted internally):
     element ``min(int(q * n), n - 1)``.  Returns None on an empty
-    sample.  THE shared rule — ``ServeStats.snapshot`` p50/p99 and the
-    bench.py serve stanza both call this, so there is exactly one
-    definition of "p99" in the repo."""
+    sample.  THE shared rule — ``ServeStats.snapshot`` p50/p99 call
+    this, so there is exactly one definition of "p99" in the package."""
     vals = sorted(values)
     if not vals:
         return None

@@ -11,8 +11,8 @@ observation instead of perturbation).
 
 Off path: one attribute read per chokepoint (``config.comm_tracer()``
 returning None), the same zero-overhead contract as the fault plan and
-the integrity guards; ``bench._bench_obs_overhead`` censuses that the
-obs-off Mode A lowering is bit-identical to an obs-less build.
+the integrity guards; tests/test_obs.py holds that the obs-off Mode A
+lowering is bit-identical to an obs-less build.
 
 Mode A coverage: :func:`spmd_collective_event` is a trace-time hook
 (the ``spmd_finite_value`` precedent) at the SPMD collective entries —
@@ -440,7 +440,7 @@ def spmd_collective_event(x, where: str):
     called at trace time on a collective entry's input value.  With no
     tracer installed — or ``mode_a=False`` (default) — returns ``x``
     untouched: ZERO ops added, the lowering is bit-identical to an
-    obs-less build (censused in ``bench._bench_obs_overhead``).  With
+    obs-less build (tests/test_obs.py holds it).  With
     ``mode_a=True``, attaches a host callback that records one
     step-level event per execution, carrying the statically-known
     payload bytes."""

@@ -406,8 +406,8 @@ def _stat_tile(x, width: int):
     """Resize a (rows, _STAT_LANES) lane-broadcast statistic to (rows,
     width) without relayout.  Every lane holds the same value, so
     narrower widths are a leading-lane slice and wider widths (KV tiles
-    above 128 — the tunable `_KV_TILE`, swept by bench_tradeoffs.py
-    flash_tiling) are a relayout-free lane-tiling concat of the
+    above 128 — the tunable `_KV_TILE`) are a relayout-free
+    lane-tiling concat of the
     already-broadcast slab."""
     if width == _STAT_LANES:
         return x

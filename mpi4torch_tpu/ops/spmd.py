@@ -368,8 +368,7 @@ def _identity_perm(n: int) -> Tuple[int, ...]:
 # reduction, config.ordered_ring_chunk_bytes() sets the ring-fold
 # pipeline granularity, config.bcast_tree_max_bytes() the Bcast_ tree/
 # psum dispatch.  All three are validated setters that the tune
-# autotuner can override from measurement (bench_tradeoffs.py measures
-# the real crossovers on attached hardware).
+# autotuner can override from measurement.
 
 
 def _gather_fold_allreduce(ctx: SpmdContext, x, op: int):
@@ -1069,14 +1068,14 @@ def allreduce(ctx: SpmdContext, x, op: int, algorithm=None,
     # Finite guard (mpi4torch_tpu.resilience): trace-time hook — with
     # config.comm_finite_guard off (default) this returns x untouched
     # and the lowering is bit-identical to a guard-less build
-    # (HLO-censused in bench.py _bench_guard_overhead); "warn"/"raise"
+    # (tests/test_resilience.py holds it); "warn"/"raise"
     # add an is_finite reduce + host callback.  The mode rides the
     # thresholds fingerprint, so toggling retraces.
     from ..resilience import guards as _guards
     x = _guards.spmd_finite_value(x, "Allreduce")
     # Mode A step-event hook (mpi4torch_tpu.obs): same trace-time
     # discipline as the finite guard — no tracer (or mode_a off) means
-    # zero ops added (censused in bench.py _bench_obs_overhead); a
+    # zero ops added (tests/test_obs.py holds it); a
     # mode_a tracer adds one host callback per collective entry, and
     # the flag rides the thresholds fingerprint so toggling retraces.
     from ..obs.trace import spmd_collective_event
@@ -1133,13 +1132,9 @@ def _mask_to_root(ctx: SpmdContext, x, root: int):
 # the conservative static switch (shapes are static under jit, so the
 # choice is per-callsite and compiles to exactly one strategy).  The
 # threshold lives in config.py (config.bcast_tree_max_bytes, validated
-# setter; the tune autotuner can override it from measurement) and
-# bench_tradeoffs.py sweeps both lowerings head-to-head across it on
-# whatever hardware is attached.  Calibration NEEDS n > 1 devices: on a
-# single chip both lowerings degenerate to identity (a 1-rank Bcast has
-# no wire), so the one-chip environment available through round 5 can
-# never measure this crossover — the sweep is armed for the first
-# multi-chip run.
+# setter; the tune autotuner can override it from measurement).
+# Calibration NEEDS n > 1 devices: on a single chip both lowerings
+# degenerate to identity (a 1-rank Bcast has no wire).
 
 
 def _tree_bcast_value(ctx: SpmdContext, x, root: int):
@@ -1346,8 +1341,6 @@ def gather(ctx: SpmdContext, x, gatheraxis: int, root: int):
     relay to the root serializes N-1 hops; under SPMD's static shapes the
     all-gather (then mask) is the efficient compiled form — and the root,
     the rank that matters, receives exactly its optimal S*(N-1)/N.
-    bench_tradeoffs.py times Gather vs plain Allgather to quantify the
-    masking overhead on the attached hardware.
     """
     _check_root(ctx, root)
     ax = _norm_axis(gatheraxis, jnp.ndim(x))

@@ -10,7 +10,7 @@ runs it on the 8-virtual-device CPU mesh):
    parameter all-gather prefetch) vs the blocking step, bitwise;
 3. a wall-clock probe of both schedules with the exposed-comm fraction
    of each (informational on CPU — the synchronous host collective
-   runtime cannot hide wire time; see bench._bench_overlap_zero).
+   runtime cannot hide wire time).
 
 Exits non-zero on any parity mismatch, so the lane is a real check,
 not a demo.
@@ -101,8 +101,7 @@ def _smoke() -> int:
           "wire time)")
 
     # The deterministic story: census both step schedules
-    # (overlap.scheduled_exposure — what bench._bench_overlap_zero
-    # records as the smoke-path exposed-comm fraction).
+    # (overlap.scheduled_exposure).
     from . import scheduled_exposure
 
     def lowered(ov):

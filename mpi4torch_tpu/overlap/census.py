@@ -4,8 +4,8 @@ of its bucket communication the schedule leaves *exposed*.
 Wall-clock exposed-comm measurements need hardware whose collective
 runtime is actually asynchronous; on the CPU smoke mesh the in-process
 rendezvous executes synchronously on the device threads, so blocking
-and split-phase programs time within scheduler noise of each other
-(bench._bench_overlap_zero documents this).  What IS deterministic on
+and split-phase programs time within scheduler noise of each other.
+What IS deterministic on
 every platform is the *schedule itself*: the lowered program either
 gives the runtime something to hide a transfer behind, or it does not.
 
@@ -29,10 +29,8 @@ The census is exact about the program, conservative about the runtime:
 it never claims wall-clock hiding, only that the schedule keeps >= 2
 transfers in flight (the same invariant tests/test_overlap.py's
 ordering censuses assert op-by-op, folded down to one fraction).
-``bench._bench_overlap_zero`` records it as the smoke-path
-exposed-comm fraction — blocking programs census to 1.0, windowed
-split-phase programs strictly lower — next to the wall-clock fractions
-that become meaningful on real multi-chip hardware.
+Blocking programs census to 1.0, windowed split-phase programs
+strictly lower (tests/test_overlap.py ``TestScheduledExposure``).
 
 Since the static verifier landed (:mod:`mpi4torch_tpu.analyze`), the
 parsing and the window classification live there as a pass over the

@@ -14,8 +14,9 @@ materializes an ``N x shard`` gather carries an N-times-shard tensor
 through its liveness range no matter how it is scheduled, while the
 planned exchange never defines one.  Planned-vs-gather comparisons run
 both programs through the same scan, so systematic bias cancels; the
-``peak_memory_bounded`` verdict (bench.py ``_bench_reshard``, `make
-reshard-smoke`) is the strict inequality between the two.
+``peak_memory_bounded`` verdict (`make reshard-smoke`,
+tests/test_analyze.py ``TestReshardCensusRegression``) is the strict
+inequality between the two.
 
 Since the static verifier landed (:mod:`mpi4torch_tpu.analyze`), the
 scan itself lives there as a pass over the shared StableHLO parse

@@ -302,8 +302,7 @@ class ReduceScatterStep:
 class ReshardPlan:
     """A compiled transition: the step program plus its static
     metadata.  ``wire_bytes``/``peak_bytes`` are the deterministic
-    per-device estimates the strategy ranking (and the bench stanza's
-    verdict) use."""
+    per-device estimates the strategy ranking uses."""
     steps: Tuple
     strategy: str
     size: int
@@ -689,8 +688,8 @@ def _build_gather(routes: _Routes):
 
 def _estimates(steps, in_shape, out_shape, itemsize, size):
     """Deterministic per-device (wire_bytes, peak_bytes) of a step
-    program — the ranking currency (and the bench stanza's headline).
-    Wire follows the bench.py ring accountings; peak counts the shard
+    program — the ranking currency.  Wire follows the standard ring
+    accountings (:mod:`mpi4torch_tpu.analyze`); peak counts the shard
     buffers plus each step's own live buffers."""
     nbytes = lambda shape: int(math.prod(shape)) * itemsize  # noqa: E731
     in_b, out_b = nbytes(in_shape), nbytes(out_shape)
@@ -739,8 +738,8 @@ def _candidates(src_lay, dst_lay, global_shape, routes,
     preference order (cheapest peak memory first; ``gather`` last and
     never auto-picked).  ``with_gather`` overrides the historical
     src_lay-presence gate (resize routes have no source Layout but DO
-    want the gather baseline — it is the full-restart oracle the bench
-    compares the live replan against)."""
+    want the gather baseline — it is the full-restart oracle the live
+    replan is compared against)."""
     if with_gather is None:
         with_gather = src_lay is not None
     out = []
@@ -900,8 +899,8 @@ def plan_permutation(lay: Layout, axis: int, perm, global_shape, dtype,
 # uniform-chunk _Routes the existing strategy builders and BOTH
 # executors already serve, so a resize plan is an ordinary ReshardPlan:
 # permute/alltoall/rounds candidates, the gather baseline (= the
-# full-restart restore every rank re-materializes — the bench's
-# comparison), adjoint() = the reverse (grow-back) plan, and the
+# full-restart restore every rank re-materializes — what the live
+# replan is compared against), adjoint() = the reverse (grow-back) plan, and the
 # custom_vjp discipline via executor.apply_plan.
 
 

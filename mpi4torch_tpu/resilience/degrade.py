@@ -20,7 +20,7 @@ statically):
   **per-rank wire census** (:func:`rank_wire_bytes`) and pin the one
   that moves the fewest bytes through the slow rank
   (``config.set_default_algorithm``).  The census is deterministic
-  (the bench stanza's regression currency): e.g. the binomial ``tree``
+  (tests/test_gray.py pins it): e.g. the binomial ``tree``
   rooted AWAY from the slow rank routes ``2B`` through it where
   ``ring`` routes ``4B(N-1)/N`` — the slow leaf sends its contribution
   once and receives the result once, full stop.
@@ -136,7 +136,7 @@ def rank_wire_bytes(algorithm: str, nranks: int, nbytes: int, *,
         return [int(round(v)) for v in out]
     raise DegradeError(
         f"no per-rank wire model for algorithm {algorithm!r} — extend "
-        "rank_wire_bytes (and the chaos/bench censuses) to admit it as "
+        "rank_wire_bytes (and the chaos censuses) to admit it as "
         "a failover candidate")
 
 

@@ -1,8 +1,8 @@
 """Integrity guards: non-finite payload checks + compressed-wire checksums.
 
 Two guard families, both **off by default with a zero-overhead off
-path** (bench.py ``_bench_guard_overhead`` proves the Mode A lowering
-is bit-identical to a guard-less build when off):
+path** (tests/test_resilience.py holds that the Mode A lowering is
+bit-identical to a guard-less build when off):
 
 * ``config.comm_finite_guard`` ∈ {"off", "warn", "raise"} — non-finite
   (NaN/Inf) payload checks.  On the eager backend

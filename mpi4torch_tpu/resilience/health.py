@@ -204,9 +204,7 @@ class GrayFailureDetector:
 
     Zero overhead off path by construction: the detector only READS
     events a tracer already recorded; with no tracer installed there is
-    nothing to read and nothing was added to the comm path (the
-    ``bench._bench_degraded_mode`` off-path census pins that the Mode A
-    lowering is bit-identical with and without the detector)."""
+    nothing to read and nothing was added to the comm path."""
 
     def __init__(self, tracer=None, *,
                  threshold: float = DEFAULT_THRESHOLD,

@@ -1007,8 +1007,7 @@ class Engine:
         of cache RESERVED for request state right now — the dense
         engine holds every occupied slot's full ``max_seq`` rows, the
         paged engine only its in-use pages (a shared prefix counted
-        once).  The bench occupancy stanza's headline integrates this
-        per step; it is a census, not a timer, so it regresses
+        once).  It is a census, not a timer, so it regresses
         deterministically on CPU smoke."""
         hd = self.cfg.d_model // self.cfg.n_heads
         row = 2 * (self.cfg.kv_heads // self._size) * hd \
@@ -1334,7 +1333,7 @@ class Engine:
         slot-table state — the deterministic census surface:
         ``overlap.scheduled_exposure(engine.lower_step())`` and the
         latency-tier span assertions read it (``make serve-smoke``,
-        ``bench._bench_serve``)."""
+        tests/test_serve.py)."""
         if not self._spmd:
             raise CommError(
                 "lower_step censuses the compiled SPMD decode program; "

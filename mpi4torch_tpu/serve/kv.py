@@ -49,8 +49,8 @@ import jax.numpy as jnp
 
 from .. import config as _config
 from ..constants import MPI_SUM
-from ..models.transformer import TransformerConfig, _norm, _rope_rotate, \
-    refuse_layer_spec
+from ..models.transformer import TransformerConfig, _ffn_dense, _norm, \
+    _split_qkv, refuse_layer_spec
 from ..ops.flash import flash_attention, flash_block_attention
 from ..ops.paged_attention import paged_decode_attention
 from ..ops.ragged import block_scatter, position_onehot
@@ -279,27 +279,6 @@ def _tp_size(cfg: TransformerConfig, shards) -> int:
     return cfg.n_heads // h_local
 
 
-def _split_qkv_local(cfg: TransformerConfig, blk, y, positions, size):
-    """This rank's q/k/v head slabs from its ``[q_r | k_r | v_r]`` fused
-    projection shard — the TP-local mirror of
-    ``models/transformer._split_qkv`` (same fused-matmul shape, local
-    head counts).  ``positions`` may be ``(s,)`` or ``(b, s)``
-    (per-slot decode positions; the batched rope branch)."""
-    b, s = y.shape[0], y.shape[1]
-    h_loc = cfg.n_heads // size
-    hkv_loc = cfg.kv_heads // size
-    hd = cfg.d_model // cfg.n_heads
-    qkv = y @ blk["wqkv"]
-    q = qkv[..., :h_loc * hd].reshape(b, s, h_loc, hd)
-    k = qkv[..., h_loc * hd:(h_loc + hkv_loc) * hd].reshape(
-        b, s, hkv_loc, hd)
-    v = qkv[..., (h_loc + hkv_loc) * hd:].reshape(b, s, hkv_loc, hd)
-    if cfg.rope:
-        q = _rope_rotate(cfg, q, positions)
-        k = _rope_rotate(cfg, k, positions)
-    return q, k, v
-
-
 def _decode_allreduce(comm, x, *, site: int, nsites: int, overlap,
                       algorithm=None):
     """One decode collective site: the row-parallel partial-sum
@@ -324,13 +303,83 @@ def _decode_allreduce(comm, x, *, site: int, nsites: int, overlap,
                               algorithm=algorithm)
 
 
-def _ffn_local(cfg: TransformerConfig, blk, y):
-    """The TP-local FFN partial product (pre-Allreduce)."""
-    if cfg.ffn == "swiglu":
-        gate_up = y @ blk["w1"]
-        gate, up = jnp.split(gate_up, 2, axis=-1)
-        return (jax.nn.silu(gate) * up) @ blk["w2"]
-    return jax.nn.gelu(y @ blk["w1"]) @ blk["w2"]
+def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
+                 reduce, what: str):
+    """The serving transformer block, once, for every serving program:
+    per layer ``ln1`` → q/k/v → ``attend`` → ``wo`` → ``reduce`` →
+    residual → ``ln2`` → FFN partial → ``reduce`` → residual; then
+    ``ln_f``.  Returns ``(x, entries)``: the normed hidden rows and what
+    ``attend`` handed back for each layer.
+
+    ``x`` is the embedded input, ``(b, s, d)`` with ``positions`` ``(s,)``
+    (a prefill) or ``(slots, d)`` with one position a slot (a decode
+    step, split as sequences of one).  What differs between the four
+    programs is in the two callables:
+
+    * ``attend(layer, q, k, v) -> (o, entry)`` is the cache VIEW: it
+      stores this pass's K/V rows of layer ``layer`` its own way, attends
+      ``q`` over what that layer may see, and returns the attention
+      output (any shape that flattens to ``x``'s rows) and the layer's
+      new cache entry (or the rows to install);
+    * ``reduce(partial, site, nsites)`` sums a row-parallel partial
+      product over the TP ranks; the sites are counted here, two a layer.
+
+    A second kind of layer (an expert FFN, a latent or recurrent cache
+    entry) is taught here and nowhere else on the serving path; until
+    then a configuration with a per-layer spec is refused, under the
+    name ``what`` of the entry point that was called."""
+    refuse_layer_spec(cfg, what)
+    size = _tp_size(cfg, shards)
+    nsites = 2 * len(shards["blocks"])
+    # The qkv split takes sequences: a decode step's rows are of one.
+    seq = (lambda a: a[:, None]) if x.ndim == 2 else (lambda a: a)
+    entries = []
+    for layer, blk in enumerate(shards["blocks"]):
+        y = _norm(cfg, x, blk["ln1"])
+        q, k, v = _split_qkv(cfg, blk, seq(y), seq(positions), size)
+        o, entry = attend(layer, q, k, v)
+        entries.append(entry)
+        o_part = o.reshape(*x.shape[:-1], -1).astype(x.dtype) @ blk["wo"]
+        x = x + reduce(o_part, 2 * layer, nsites).astype(x.dtype)
+        ff = _ffn_dense(cfg, blk, _norm(cfg, x, blk["ln2"]))
+        x = x + reduce(ff, 2 * layer + 1, nsites).astype(x.dtype)
+    return _norm(cfg, x, shards["ln_f"]), entries
+
+
+def _prefill_reduce(comm):
+    """The prefills' reduction: one blocking Allreduce per row-parallel
+    half (prefill is the compute-bound phase, so its collectives stay
+    out of the decode exposure census)."""
+    def reduce(partial, site, nsites):
+        if comm is None:
+            return partial
+        return comm.Allreduce(partial, MPI_SUM, compression=False)
+
+    return reduce
+
+
+def _live_rows(active):
+    """A decode step's ``active`` argument as a ``(slots,)`` bool mask."""
+    return None if active is None else jnp.asarray(active).astype(bool)
+
+
+def _decode_reduce(comm, live, overlap, algorithm):
+    """The decode steps' reduction: free slots' rows zeroed (a poisoned
+    row never reaches the wire; ``where`` selects, so live rows pass
+    bit for bit), then :func:`_decode_allreduce` at the walker's
+    site."""
+    ov = resolve_overlap(overlap)
+    if live is not None:
+        live = live[:, None]
+
+    def reduce(partial, site, nsites):
+        if live is not None:
+            partial = jnp.where(live, partial,
+                                jnp.zeros((), partial.dtype))
+        return _decode_allreduce(comm, partial, site=site, nsites=nsites,
+                                 overlap=ov, algorithm=algorithm)
+
+    return reduce
 
 
 def prefill_tp(cfg: TransformerConfig, shards, cache, prompt, comm=None):
@@ -340,34 +389,26 @@ def prefill_tp(cfg: TransformerConfig, shards, cache, prompt, comm=None):
     sequence per rank; one blocking Allreduce per row-parallel half —
     prefill is the compute-bound phase, so its collectives stay on the
     blocking path and out of the decode exposure census)."""
-    b, p_len = prompt.shape
-    size = _tp_size(cfg, shards)
+    p_len = prompt.shape[1]
     x = shards["embed"][prompt]
     if not cfg.rope:
         x = x + shards["pos"][None, :p_len]
     positions = jnp.arange(p_len, dtype=jnp.int32)
-    new_cache = []
+
+    def attend(layer, q, k, v):
+        # Rows written at 0; attention over this pass's own K/V, so a
+        # lower-precision cache does not touch the prompt's logits.
+        c = cache[layer]
+        ck = jax.lax.dynamic_update_slice_in_dim(
+            c["k"], k.astype(c["k"].dtype), 0, 1)
+        cv = jax.lax.dynamic_update_slice_in_dim(
+            c["v"], v.astype(c["v"].dtype), 0, 1)
+        o = flash_attention(q, k, v, causal=True, window=cfg.attn_window)
+        return o, {"k": ck, "v": cv}
+
     with serve_step_scope("prefill"):
-        for blk, c in zip(shards["blocks"], cache):
-            y = _norm(cfg, x, blk["ln1"])
-            q, k, v = _split_qkv_local(cfg, blk, y, positions, size)
-            ck = jax.lax.dynamic_update_slice_in_dim(
-                c["k"], k.astype(c["k"].dtype), 0, 1)
-            cv = jax.lax.dynamic_update_slice_in_dim(
-                c["v"], v.astype(c["v"].dtype), 0, 1)
-            new_cache.append({"k": ck, "v": cv})
-            o = flash_attention(q, k, v, causal=True,
-                                window=cfg.attn_window)
-            o_part = o.reshape(b, p_len, -1) @ blk["wo"]
-            if comm is not None:
-                o_part = comm.Allreduce(o_part, MPI_SUM,
-                                        compression=False)
-            x = x + o_part.astype(x.dtype)
-            ff = _ffn_local(cfg, blk, _norm(cfg, x, blk["ln2"]))
-            if comm is not None:
-                ff = comm.Allreduce(ff, MPI_SUM, compression=False)
-            x = x + ff.astype(x.dtype)
-        x = _norm(cfg, x, shards["ln_f"])
+        x, new_cache = _walk_layers(cfg, shards, x, positions, attend,
+                                    _prefill_reduce(comm), "prefill_tp")
         return x[:, -1] @ shards["unembed"], new_cache
 
 
@@ -397,35 +438,28 @@ def prefill_chunk_tp(cfg: TransformerConfig, shards, past, chunk,
 
     Collectives are the blocking prefill path (compute-bound phase,
     outside the decode exposure census), one per row-parallel half."""
-    b, c_len = chunk.shape
+    c_len = chunk.shape[1]
     p_len = int(past[0]["k"].shape[1])
-    size = _tp_size(cfg, shards)
     x = shards["embed"][chunk]
     if not cfg.rope:
         x = x + shards["pos"][None, p_len:p_len + c_len]
     positions = jnp.arange(p_len, p_len + c_len, dtype=jnp.int32)
-    rows = []
+
+    def attend(layer, q, k, v):
+        # The chunk's rows go back to be installed; they attend
+        # past ++ chunk from their global offset.
+        p = past[layer]
+        rows = {"k": k.astype(p["k"].dtype), "v": v.astype(p["v"].dtype)}
+        kf = jnp.concatenate([p["k"].astype(k.dtype), k], axis=1)
+        vf = jnp.concatenate([p["v"].astype(v.dtype), v], axis=1)
+        o, _ = flash_block_attention(
+            q, kf, vf, causal=True, q_offset=p_len, kv_offset=0,
+            window=cfg.attn_window, impl="jnp")
+        return o, rows
+
     with serve_step_scope("prefill"):
-        for blk, p in zip(shards["blocks"], past):
-            y = _norm(cfg, x, blk["ln1"])
-            q, k, v = _split_qkv_local(cfg, blk, y, positions, size)
-            rows.append({"k": k.astype(p["k"].dtype),
-                         "v": v.astype(p["v"].dtype)})
-            kf = jnp.concatenate([p["k"].astype(k.dtype), k], axis=1)
-            vf = jnp.concatenate([p["v"].astype(v.dtype), v], axis=1)
-            o, _ = flash_block_attention(
-                q, kf, vf, causal=True, q_offset=p_len, kv_offset=0,
-                window=cfg.attn_window, impl="jnp")
-            o_part = o.reshape(b, c_len, -1) @ blk["wo"]
-            if comm is not None:
-                o_part = comm.Allreduce(o_part, MPI_SUM,
-                                        compression=False)
-            x = x + o_part.astype(x.dtype)
-            ff = _ffn_local(cfg, blk, _norm(cfg, x, blk["ln2"]))
-            if comm is not None:
-                ff = comm.Allreduce(ff, MPI_SUM, compression=False)
-            x = x + ff.astype(x.dtype)
-        x = _norm(cfg, x, shards["ln_f"])
+        x, rows = _walk_layers(cfg, shards, x, positions, attend,
+                               _prefill_reduce(comm), "prefill_chunk_tp")
         return x[:, -1] @ shards["unembed"], rows
 
 
@@ -470,51 +504,27 @@ def decode_step_tp(cfg: TransformerConfig, shards, cache, tokens, pos,
     (the position-tracking bucket slice of ``decode_step`` is a
     single-sequence optimization; per-slot gathers would re-shuffle the
     cache every step for a smoke-scale win)."""
-    slots = tokens.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
-    size = _tp_size(cfg, shards)
-    ov = resolve_overlap(overlap)
-    nsites = 2 * len(shards["blocks"])
-    live = None if active is None \
-        else jnp.asarray(active).astype(bool)[:, None]
+    reduce = _decode_reduce(comm, _live_rows(active), overlap, algorithm)
 
-    def guard_rows(payload):
-        # Free-slot rows never reach the wire carrying poison.
-        if live is None:
-            return payload
-        return jnp.where(live, payload, jnp.zeros((), payload.dtype))
+    def attend(layer, q, k, v):
+        # One-hot ``where`` write of each slot's row; attention over
+        # the whole max_seq buffer behind per-row frontiers.
+        c = cache[layer]
+        wmask = (position_onehot(pos, cfg.max_seq) != 0)[:, :, None, None]
+        ck = jnp.where(wmask, k.astype(c["k"].dtype), c["k"])
+        cv = jnp.where(wmask, v.astype(c["v"].dtype), c["v"])
+        o, _ = flash_block_attention(
+            q, ck, cv, causal=True, q_offset=pos, kv_offset=0,
+            window=cfg.attn_window, impl="jnp")
+        return o, {"k": ck, "v": cv}
 
     with serve_step_scope("decode_step"):
         x = shards["embed"][tokens]
         if not cfg.rope:
             x = x + jnp.take(shards["pos"], pos, axis=0)
-        site = 0
-        new_cache = []
-        for blk, c in zip(shards["blocks"], cache):
-            y = _norm(cfg, x, blk["ln1"])
-            q, k_new, v_new = _split_qkv_local(
-                cfg, blk, y[:, None, :], pos[:, None], size)
-            write = position_onehot(pos, cfg.max_seq) != 0
-            wmask = write[:, :, None, None]
-            ck = jnp.where(wmask, k_new.astype(c["k"].dtype), c["k"])
-            cv = jnp.where(wmask, v_new.astype(c["v"].dtype), c["v"])
-            new_cache.append({"k": ck, "v": cv})
-            o, _ = flash_block_attention(
-                q, ck, cv, causal=True, q_offset=pos, kv_offset=0,
-                window=cfg.attn_window, impl="jnp")
-            o_part = o.reshape(slots, -1).astype(x.dtype) @ blk["wo"]
-            attn = _decode_allreduce(comm, guard_rows(o_part), site=site,
-                                     nsites=nsites, overlap=ov,
-                                     algorithm=algorithm)
-            site += 1
-            x = x + attn.astype(x.dtype)
-            ff = _ffn_local(cfg, blk, _norm(cfg, x, blk["ln2"]))
-            ff = _decode_allreduce(comm, guard_rows(ff), site=site,
-                                   nsites=nsites,
-                                   overlap=ov, algorithm=algorithm)
-            site += 1
-            x = x + ff.astype(x.dtype)
-        x = _norm(cfg, x, shards["ln_f"])
+        x, new_cache = _walk_layers(cfg, shards, x, pos, attend, reduce,
+                                    "decode_step_tp")
         return x @ shards["unembed"], new_cache
 
 
@@ -570,60 +580,38 @@ def decode_step_paged(cfg: TransformerConfig, shards, pool, table,
     discipline — which is ``block_scatter``'s exactness invariant.
     Free slots carry ``-1`` write pages and an ``active=False`` mask:
     no write, no page read, zero attention rows, payload rows zeroed
-    before the wire (same ``guard_rows`` rule as the dense step)."""
-    slots = tokens.shape[0]
+    before the wire (the dense step's rule)."""
     pos = jnp.asarray(pos, jnp.int32)
     table = jnp.asarray(table, jnp.int32)
-    size = _tp_size(cfg, shards)
-    ov = resolve_overlap(overlap)
-    nsites = 2 * len(shards["blocks"])
     bs = pool[0]["k"].shape[1]
-    n_blk = table.shape[1]
-    live_vec = None if active is None \
-        else jnp.asarray(active).astype(bool)
-    live = None if live_vec is None else live_vec[:, None]
+    live = _live_rows(active)
+    reduce = _decode_reduce(comm, live, overlap, algorithm)
     write = _block_scatter_donated if donate else block_scatter
-
-    def guard_rows(payload):
-        if live is None:
-            return payload
-        return jnp.where(live, payload, jnp.zeros((), payload.dtype))
 
     # The slot's current write page and in-page offset; a free slot's
     # all--1 table row yields -1, which block_scatter drops.
     wb = jnp.take_along_axis(
-        table, jnp.clip(pos // bs, 0, n_blk - 1)[:, None], axis=1)[:, 0]
+        table, jnp.clip(pos // bs, 0, table.shape[1] - 1)[:, None],
+        axis=1)[:, 0]
     off = pos % bs
+
+    def attend(layer, q, k, v):
+        # One row a live slot scattered into its page; attention reads
+        # the pages through the table.
+        c = pool[layer]
+        pk = write(c["k"], wb, off, k[:, 0], live)
+        pv = write(c["v"], wb, off, v[:, 0], live)
+        o = paged_decode_attention(
+            q[:, 0], pk, pv, table, pos, window=cfg.attn_window,
+            active=live)
+        return o, {"k": pk, "v": pv}
 
     with serve_step_scope("decode_step"):
         x = shards["embed"][tokens]
         if not cfg.rope:
             x = x + jnp.take(shards["pos"], pos, axis=0)
-        site = 0
-        new_pool = []
-        for blk, c in zip(shards["blocks"], pool):
-            y = _norm(cfg, x, blk["ln1"])
-            q, k_new, v_new = _split_qkv_local(
-                cfg, blk, y[:, None, :], pos[:, None], size)
-            pk = write(c["k"], wb, off, k_new[:, 0], live_vec)
-            pv = write(c["v"], wb, off, v_new[:, 0], live_vec)
-            new_pool.append({"k": pk, "v": pv})
-            o = paged_decode_attention(
-                q[:, 0], pk, pv, table, pos, window=cfg.attn_window,
-                active=live_vec)
-            o_part = o.reshape(slots, -1).astype(x.dtype) @ blk["wo"]
-            attn = _decode_allreduce(comm, guard_rows(o_part), site=site,
-                                     nsites=nsites, overlap=ov,
-                                     algorithm=algorithm)
-            site += 1
-            x = x + attn.astype(x.dtype)
-            ff = _ffn_local(cfg, blk, _norm(cfg, x, blk["ln2"]))
-            ff = _decode_allreduce(comm, guard_rows(ff), site=site,
-                                   nsites=nsites,
-                                   overlap=ov, algorithm=algorithm)
-            site += 1
-            x = x + ff.astype(x.dtype)
-        x = _norm(cfg, x, shards["ln_f"])
+        x, new_pool = _walk_layers(cfg, shards, x, pos, attend, reduce,
+                                   "decode_step_paged")
         return x @ shards["unembed"], new_pool
 
 

@@ -197,7 +197,7 @@ def _save() -> None:
     """Atomic, concurrency-safe, best-effort persist of the in-process
     table.
 
-    Two rules make simultaneous tuners (multi-host jobs, a bench next
+    Two rules make simultaneous tuners (multi-host jobs, a sweep next
     to a training run) safe:
 
     * the payload is written to a UNIQUE tempfile in the cache
@@ -312,7 +312,7 @@ def entry_from_disk(collective: str, dtype, nbytes: int, nranks: int,
                     platform: Optional[str] = None, codec=None,
                     tiers=None) -> bool:
     """True when this key's entry was loaded from the persisted file
-    (rather than measured in this process) — the bench's
+    (rather than measured in this process) — the
     ``tuned_from_cache`` evidence."""
     _load()
     return make_key(collective, dtype, nbytes, nranks,
@@ -404,8 +404,8 @@ def _candidates(nranks: int, collective: str = "allreduce") -> List[str]:
 
 def _time_step(step, x, iters: int) -> float:
     """MIN-of-k seconds/step with a host fetch per iteration (the only
-    completion barrier remote runtimes honor — see bench.py ``_force``;
-    ``np.asarray`` of one output leaf is the cheap equivalent here).
+    completion barrier remote runtimes honor; ``np.asarray`` of one
+    output leaf is the cheap form of it).
 
     Min, not median/mean: timing noise on shared or preemptible
     capacity is strictly one-sided — a preempted slice, a GC pause, or
@@ -454,7 +454,7 @@ def autotune_allreduce(sizes: Optional[Sequence[int]] = None,
     measurements hijacking exact traffic's winners.  The crossover
     derivation reads only the exact (``None``) sweep.
 
-    Returns the report dict (also the bench's JSON stanza):
+    Returns the report dict:
     per-size per-algorithm seconds and GB/s, the winner table, the
     crossover, and ``tuned_from_cache: False`` (a report served
     without measuring — :func:`ensure_tuned_allreduce` — says True,

@@ -2,7 +2,7 @@
 
 The layer stack is unrolled in Python, so a cold compile of the flagship
 step is a large part of a short run; every entry point that compiles
-(``chip_smoke.py``, ``bench.py``, ``bench_tradeoffs.py``, the
+(``chip_smoke.py``, ``benchmarks/run.py``, the
 ``python -m mpi4torch_tpu.*`` lanes) calls :func:`use_compile_cache`
 before its first jit so that a second process finds the first one's
 programs.  The directory is part of the cache key, so it is a fixed path:

@@ -77,8 +77,7 @@ def bucket_scope(op: str, index: int, total: int, codec=None, phase=None):
     and ``.wait``) from *exposed* communication (activity that the
     timeline shows under the ``.wait`` span itself, where the program
     had nothing else to run).  The blocking path's unsuffixed bucket
-    spans are 100% exposed by construction, which is what
-    ``bench._bench_overlap_zero`` quantifies wall-clock-side.
+    spans are 100% exposed by construction.
 
     With a comm tracer installed (mpi4torch_tpu.obs) the scope name is
     additionally pushed onto the tracer's thread-local label stack, so
@@ -290,9 +289,8 @@ class ServeStats:
     def snapshot(self) -> dict:
         """Counters + derived occupancy and latency aggregates.  The
         latency dicts carry mean/max plus p50/p99 via the ONE shared
-        percentile rule (:func:`mpi4torch_tpu.obs.percentile` — the
-        same nearest-rank-floor rule bench.py's serve stanza uses, so
-        "p99" means one thing repo-wide)."""
+        percentile rule (:func:`mpi4torch_tpu.obs.percentile`,
+        nearest-rank floor, so "p99" means one thing package-wide)."""
         from ..obs.metrics import percentile
 
         with self._lock:

@@ -11,11 +11,11 @@ Four layers of evidence:
   (every defect caught BY ITS NAMED LINT, the ledger complete);
 * **accounting** — the migrated ``wire_bytes_per_device`` /
   ``peak_live_bytes`` / ``scheduled_exposure`` passes regression-pinned
-  BIT-IDENTICAL to the recorded PR 6/8/9 bench numbers (q8-bidir
+  BIT-IDENTICAL to the counts recorded at PR 6/8/9 (q8-bidir
   7280 B, the (8,)->(2,4) reshard migration 98304 B planned vs
   917504 B gather, the serve decode step's 14336 B / 3584.0 B-per-token
   wire and its exposure fractions), with the historical entry points
-  (bench, overlap.census, reshard.census) verified to delegate;
+  (overlap.census, reshard.census) verified to delegate;
 * **sweep** — the full registry-wide lint sweep lints clean on the
   (1,), (3,), (8,) and (2,4) worlds.
 """
@@ -301,7 +301,7 @@ class TestWireBytesRegression:
 
     @pytest.fixture(scope="class")
     def multipath(self):
-        x = jnp.ones((1 << 12,), jnp.float32)   # the bench payload
+        x = jnp.ones((1 << 12,), jnp.float32)   # the recorded payload
         out = {}
         for label, codec, algo in (("fp32-bidir", False, "bidir"),
                                    ("q8-bidir", "q8", "bidir")):
@@ -314,7 +314,7 @@ class TestWireBytesRegression:
     def test_q8_bidir_wire_bytes_pinned(self, multipath):
         wire, counts = analyze.wire_bytes_per_device(
             multipath["q8-bidir"])
-        assert wire == 7280                      # BENCH r05 recorded
+        assert wire == 7280
         assert counts == {"collective_permute": 28, "all_gather": 4}
 
     def test_fp32_bidir_wire_bytes_pinned(self, multipath):
@@ -325,16 +325,11 @@ class TestWireBytesRegression:
         # the recorded 3.938x >= 3.5 wire-advantage verdict
         assert round(28672 / 7280, 3) == 3.938
 
-    def test_bench_entry_point_delegates(self, multipath):
-        import bench
-        assert bench._hlo_wire_bytes_per_device(multipath["q8-bidir"]) \
-            == analyze.wire_bytes_per_device(multipath["q8-bidir"])
-
 
 class TestReshardCensusRegression:
     """The PR 8 (8,)->(2,4) migration census: wire bytes AND peak live
-    bytes, planned vs gather, pinned to the recorded values.  The
-    bench runs without x64 (the liveness scan prices i32 index
+    bytes, planned vs gather, pinned to the recorded values.  They
+    were recorded without x64 (the liveness scan prices i32 index
     constants there, i64 under the x64 test harness — wire bytes are
     invariant but peak live shifts by the constant widths), so the
     programs lower under ``jax.enable_x64(False)`` to reproduce the recorded
@@ -345,7 +340,7 @@ class TestReshardCensusRegression:
         from mpi4torch_tpu import reshard as rs
         fl = rs.layout((NR,), 0, None)
         tl = rs.layout((2, 4), 0, 1)
-        G = (1024, 256)                          # the bench shapes
+        G = (1024, 256)                          # the recorded shapes
         x = jnp.zeros(fl.shard_shape(G), jnp.float32)
         with jax.enable_x64(False):
             return {
@@ -377,7 +372,7 @@ class TestReshardCensusRegression:
 
 class TestServeCensusRegression:
     """The PR 9 serve decode-step census: per-step/per-token wire bytes
-    and the scheduled-exposure fractions, pinned to the recorded bench
+    and the scheduled-exposure fractions, pinned to the recorded
     values (slots=4 on the 8-rank TP world)."""
 
     @pytest.fixture(scope="class")
@@ -388,11 +383,10 @@ class TestServeCensusRegression:
         cfg = T.TransformerConfig(vocab=256, d_model=64, n_heads=8,
                                   n_layers=4, d_ff=128, max_seq=64)
         out = {}
-        # The bench environment runs without x64 (see the reshard
-        # regression class) and under the stand-in latency crossover
-        # bench._serve_census installs (decode chunks land in the
-        # latency tier, which picks the wire schedule the recorded
-        # exposure fractions census).
+        # The recorded fractions were taken without x64 (see the
+        # reshard regression class) and under a stand-in latency
+        # crossover of 16 KiB (decode chunks land in the latency tier,
+        # which picks the wire schedule the fractions census).
         prev = mpi.config.latency_crossover_bytes()
         mpi.config.set_latency_crossover_bytes(1 << 14)
         try:

@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-import bench
 import chip_smoke
 from mpi4torch_tpu.models import transformer as T
 from mpi4torch_tpu.ops import flash
@@ -114,9 +113,3 @@ def test_flash_raises_when_the_kernel_does_not_lower(monkeypatch):
     with pytest.raises(Exception, match="(?i)interpret|mosaic|tpu"):
         jax.block_until_ready(
             flash.flash_attention(q, q, q, causal=True, impl="auto"))
-
-
-def test_bench_refuses_an_unknown_device_kind():
-    assert bench._chip_specs("TPU v5 lite") == (197e12, 819.0)
-    with pytest.raises(ValueError, match="not in bench.py's peaks table"):
-        bench._chip_specs("unknown")

@@ -203,8 +203,8 @@ class TestPallasBackwardKernel:
 
 
 class TestTunableTiles:
-    """Non-default _Q_TILE/_KV_TILE configurations (the knobs
-    bench_tradeoffs.py flash_tiling sweeps on chip) must stay
+    """Non-default _Q_TILE/_KV_TILE configurations (the tunable tile
+    sizes) must stay
     oracle-correct, forward AND backward — KV tiles wider than the
     128-lane stat slab exercise _stat_tile's lane-tiling branch."""
 
@@ -377,7 +377,7 @@ class TestCompiledKernelOnTPU:
                                    rtol=1e-4, atol=1e-5)
 
     def test_compiled_bench_shape_bf16(self):
-        # The bench.py flash sub-bench shape.
+        # The flagship attention shape: 4096 tokens, head size 128.
         q, k, v = qkv((4, 4096, 8, 128), dtype=jnp.bfloat16, seed=7)
         a, _ = flash.flash_block_attention(q, k, v, causal=True,
                                            impl="pallas")

@@ -1,11 +1,9 @@
 """Driver entry-point contract tests: entry() compiles single-chip,
 dryrun_multichip() compiles+executes the full distributed step on the
-virtual 8-device CPU mesh, bench.py emits the one-line JSON."""
+virtual 8-device CPU mesh."""
 
 import pytest
-import json
 import os
-import subprocess
 import sys
 
 import jax
@@ -33,22 +31,3 @@ def test_dryrun_multichip_8():
 def test_dryrun_multichip_4():
     # Non-multiple-of-8: the 2D dp x sp dense-FFN fallback.
     graft.dryrun_multichip(4)
-
-
-@pytest.mark.slow  # heavyweight compile/run; TPU-manual lane (tier-1 budget); runs full bench.py
-def test_bench_json_line():
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=8")
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    res = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py")],
-        capture_output=True, text=True, env=env, cwd=repo, timeout=300,
-    )
-    assert res.returncode == 0, res.stderr
-    line = res.stdout.strip().splitlines()[-1]
-    data = json.loads(line)
-    for key in ("metric", "value", "unit", "vs_baseline"):
-        assert key in data
-    assert data["value"] > 0

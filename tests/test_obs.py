@@ -9,8 +9,8 @@ postmortems — tested through the fault matrix's rank_death cell,
 alongside the existing attribution cells), Chrome-trace export, and
 the static-vs-runtime reconciliation (measured Mode B wire == analyze
 predictions EXACTLY).  The off-path contract — obs disabled lowers
-bit-identical to an obs-less build — is censused here and in
-bench._bench_obs_overhead; `make obs-smoke` runs the full lane.
+bit-identical to an obs-less build — is censused here; `make
+obs-smoke` runs the full lane.
 """
 
 import jax
@@ -241,7 +241,7 @@ class TestMetricsRegistry:
 
     def test_percentile_matches_bench_rule(self):
         vals = [5.0, 1.0, 3.0, 2.0, 4.0]
-        # bench's historical rule: sorted[min(int(q*n), n-1)]
+        # the nearest-rank floor rule: sorted[min(int(q*n), n-1)]
         s = sorted(vals)
         for q in (0.0, 0.5, 0.9, 0.99, 1.0):
             assert obs.percentile(vals, q) \

@@ -801,8 +801,8 @@ class TestProfilingSpans:
 
 # ---------------------------------------------------------------------------
 # Scheduled-exposure census (overlap.census): the quantitative fold of
-# the ordering censuses above — bench._bench_overlap_zero's smoke-path
-# exposed-comm fraction.
+# the ordering censuses above — the exposed-comm fraction of a lowered
+# program.
 # ---------------------------------------------------------------------------
 
 
@@ -852,7 +852,7 @@ class TestScheduledExposure:
         assert out["exposed_fraction"] is None
 
     def test_zero_step_census_matches_bench_claim(self):
-        # The bench stanza's acceptance bar, in miniature: the blocking
+        # The overlap scheduler's acceptance bar, in miniature: the blocking
         # ZeRO step censuses fully exposed, the windowed split-phase
         # step strictly lower, on the same model.
         from mpi4torch_tpu.parallel import zero as Z

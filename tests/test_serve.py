@@ -483,6 +483,41 @@ class TestCrossModeBitwise:
                                           np.asarray(outs[r]))
 
 
+class TestOneServingBlock:
+    @pytest.mark.parametrize("entry", [
+        "prefill_tp", "prefill_chunk_tp", "decode_step_tp",
+        "decode_step_paged"])
+    def test_entry_points_refuse_a_layer_spec(self, entry):
+        # Called directly (validate_tp is the engine's gate, not
+        # theirs), each serving program answers a per-layer spec with
+        # the walker's CommError under its own name, before it reads a
+        # block.
+        mla = T.LayerSpec(T.MLA(n_heads=2, kv_rank=8, qk_nope=8,
+                                qk_rope=4, v_dim=8))
+        cfg = dataclasses.replace(CFG_ROPE, layers=(mla,) * 2)
+        params = T.init_transformer(jax.random.PRNGKey(0), cfg,
+                                    dtype=jnp.float32)
+        tokens = jnp.asarray([3, 5], jnp.int32)
+        pos = jnp.asarray([0, 1], jnp.int32)
+        prompt = jnp.asarray(PROMPTS[0], jnp.int32)[None, :]
+        calls = {
+            "prefill_tp": lambda: kv.prefill_tp(
+                cfg, params, kv.init_kv_cache_tp(cfg, 1, 1), prompt),
+            "prefill_chunk_tp": lambda: kv.prefill_chunk_tp(
+                cfg, params,
+                jax.tree.map(lambda a: a[:, :0],
+                             kv.init_kv_cache_tp(cfg, 1, 1)), prompt),
+            "decode_step_tp": lambda: kv.decode_step_tp(
+                cfg, params, kv.init_kv_cache_tp(cfg, 2, 1), tokens, pos),
+            "decode_step_paged": lambda: kv.decode_step_paged(
+                cfg, params, kv.init_kv_pool_tp(cfg, 12, 4, 1),
+                jnp.arange(12, dtype=jnp.int32).reshape(2, 6), tokens,
+                pos),
+        }
+        with pytest.raises(mpi.CommError, match=entry + ": .*per-layer"):
+            calls[entry]()
+
+
 class TestZero3Admission:
     def test_admit_zero3_matches_gather_then_slice(self):
         params = _params(CFG)
@@ -929,6 +964,36 @@ class TestChunkedPrefill:
             serve.ServeConfig(slots=2, block_size=4,
                               prefill_chunk=chunk))
         assert_matches_oracle(CFG, params, drive(eng))
+
+    @pytest.mark.parametrize("nranks", [1, 4])
+    def test_chunk_from_empty_past_is_the_whole_prefill(self, nranks):
+        # The two prefill views meet at an empty prefix: the chunk's
+        # rows and logits are bitwise what prefill_tp writes at 0.
+        params = _params(CFG_ROPE)
+        prompt = jnp.asarray(PROMPTS[1], jnp.int32)[None, :]
+        hd = CFG_ROPE.d_model // CFG_ROPE.n_heads
+
+        def both(prompt):
+            comm = mpi.COMM_WORLD
+            sh = kv.shard_params_tp(CFG_ROPE, params, comm)
+            cache = kv.init_kv_cache_tp(CFG_ROPE, 1, nranks, jnp.float64)
+            empty = jnp.zeros(
+                (1, 0, CFG_ROPE.kv_heads // nranks, hd), jnp.float64)
+            past = [{"k": empty, "v": empty}] * CFG_ROPE.n_layers
+            whole = kv.prefill_tp(CFG_ROPE, sh, cache, prompt, comm)
+            chunk = kv.prefill_chunk_tp(CFG_ROPE, sh, past, prompt, comm)
+            return whole, chunk
+
+        (l_whole, cache), (l_chunk, rows) = \
+            mpi.run_spmd(both, nranks=nranks)(prompt)
+        np.testing.assert_array_equal(np.asarray(l_whole),
+                                      np.asarray(l_chunk))
+        n = prompt.shape[1]
+        for c, r in zip(cache, rows):
+            for leaf in ("k", "v"):
+                assert r[leaf].shape[2] == n
+                np.testing.assert_array_equal(
+                    np.asarray(c[leaf][:, :, :n]), np.asarray(r[leaf]))
 
     def test_long_prompt_never_stalls_resident_decode(self):
         # THE TTFT-bound regression: while a long prompt lands chunk by

@@ -913,7 +913,7 @@ class TestAutotunerMeasurement:
             assert ent["winner"] in ALGOS
             assert set(ent["algorithms"]) >= {"ring", "tree"}
         # The persisted winners serve a second (fresh-table) run with
-        # zero measurement — the bench's tuned_from_cache evidence.
+        # zero measurement — the tuned_from_cache evidence.
         tune.clear()
         rep2 = tune.ensure_tuned_allreduce(sizes=sizes, nranks=4, iters=1)
         assert rep2["tuned_from_cache"] is True
@@ -1361,8 +1361,8 @@ class TestConfigKnobs:
 
     def test_autotuner_can_override_promoted_thresholds(self):
         # The promoted thresholds accept measured overrides (the
-        # autotuner writes latency_crossover; bench_tradeoffs feeds the
-        # other three) — the setters are the override surface.
+        # autotuner writes latency_crossover) — the setters are the
+        # override surface for the other three.
         saved = (mpi.config.ordered_fold_gather_max_bytes(),
                  mpi.config.ordered_ring_chunk_bytes())
         try:
