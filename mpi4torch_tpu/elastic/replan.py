@@ -219,9 +219,9 @@ def drain_tickets(engine, *, snapshot: bool = False
     """Drain (or, with ``snapshot=True``, observe without evicting) an
     engine's in-flight requests as :class:`ServeTicket`\\ s, plus the
     results already finished.  Every Mode B rank's engine holds the
-    identical host-side request state (tokens are selected host-side,
-    deterministically, on every rank), so any SURVIVOR's drain is the
-    authoritative one — which is exactly what rank-death recovery
+    identical host-side request state (every rank's step chooses the
+    same tokens from rank-identical logits), so any SURVIVOR's drain is
+    the authoritative one — which is exactly what rank-death recovery
     needs."""
     reqs = engine.snapshot_inflight() if snapshot \
         else engine.drain()

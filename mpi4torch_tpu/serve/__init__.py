@@ -50,7 +50,7 @@ from ..utils.profiling import (ServeStats, reset_serve_stats,
                                serve_stats)
 from .engine import (Engine, POLICIES, SHED_POLICIES, STATUS_EXPIRED,
                      STATUS_OK, STATUS_SHED, QueueFullError, Request,
-                     ServeConfig)
+                     ServeConfig, select_rows)
 from .kv import (admit_zero3, decode_step_paged, decode_step_tp,
                  init_kv_cache_tp, init_kv_pool_tp, prefill_chunk_tp,
                  prefill_tp, shard_params_tp, validate_tp)
@@ -66,6 +66,7 @@ __all__ = [
     "STATUS_EXPIRED",
     "STATUS_SHED",
     "QueueFullError",
+    "select_rows",
     "decode_step_tp",
     "decode_step_paged",
     "prefill_tp",

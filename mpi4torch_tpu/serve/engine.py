@@ -14,10 +14,12 @@ sequences), one compiled decode-step program, a host-driven loop:
 * **decode** — one :func:`~mpi4torch_tpu.serve.decode_step_tp` call
   over the whole slot table per step: static shapes, per-slot
   positions, free slots riding along as NaN-poisoned inert rows
-  (ops/ragged masks; see kv.py).  Sampling runs host-side with
-  ``models/transformer.select_token`` under the exact per-request key
-  discipline of ``generate()`` — engine tokens equal per-request
-  ``generate()`` tokens by construction.
+  (ops/ragged masks; see kv.py).  The step ends in
+  :func:`select_rows`: every slot's token is chosen where the logits
+  are, with ``models/transformer.select_token`` under the exact
+  per-request key discipline of ``generate()`` — engine tokens equal
+  per-request ``generate()`` tokens by construction — and the host
+  fetches ``(slots,)`` tokens, never the ``(slots, vocab)`` table.
 * **eviction** — a slot finishes on EOS or its token budget; its cache
   rows are re-poisoned and the slot returns to the free pool, ready
   for the next admission in the SAME step loop — no batch barrier,
@@ -62,7 +64,7 @@ from . import kv as _kv
 from . import paging as _paging
 
 __all__ = ["ServeConfig", "Request", "Engine", "POLICIES",
-           "SHED_POLICIES", "QueueFullError",
+           "SHED_POLICIES", "QueueFullError", "select_rows",
            "STATUS_OK", "STATUS_EXPIRED", "STATUS_SHED"]
 
 # Typed result statuses (ISSUE 15): every finished rid carries one.
@@ -89,6 +91,36 @@ SPAN_FIRST_TOKEN = SPAN_ADMIT + ".first_token"
 SPAN_DISPATCH = SPAN_STEP + ".decode.dispatch"
 SPAN_FETCH = SPAN_STEP + ".decode.fetch"
 SPAN_SELECT = SPAN_STEP + ".decode.select"
+
+
+def select_rows(logits, keys, temperature: float, top_k: int):
+    """Every slot's decoding choice from the ``(slots, vocab)`` logits
+    of one decode step, inside the program that computed them (or in
+    one call behind an eager step): to each row exactly what
+    :meth:`Engine._select` applies to one — split the slot's key,
+    ``select_token(row[None, :], sub, temperature, top_k, int32)`` —
+    under ``jax.vmap`` over the ``(slots, ...)`` per-slot ``keys``.
+    Returns ``(tokens (slots,) int32, new_keys)``, bit for bit what the
+    per-slot calls give (first maximum on ties).  ``keys=None`` is the
+    greedy engine's form: greedy reads no key, so none goes in and
+    ``None`` comes back.
+
+    The table is held as it was computed, rounded to its dtype, before
+    anything is chosen from it (``optimization_barrier``): fused into
+    the unembedding product a TPU compares the product's unrounded
+    accumulators, and the near-ties of bfloat16 logits then fall
+    otherwise than for ``_select`` on the fetched table."""
+    logits = jax.lax.optimization_barrier(logits)
+    if keys is None:
+        return select_token(logits, None, temperature, top_k,
+                            jnp.int32), None
+
+    def one(row, key):
+        key, sub = jax.random.split(key)
+        return select_token(row[None, :], sub, temperature, top_k,
+                            jnp.int32)[0], key
+
+    return jax.vmap(one)(logits, keys)
 
 
 class QueueFullError(CommError):
@@ -436,6 +468,7 @@ class Engine:
         self._cache_leaves = len(jax.tree.leaves(cache))
         self._tokens = np.zeros((slots,), np.int32)
         self._pos = np.zeros((slots,), np.int32)
+        self._select_syncs = 0            # device round trips of _select
         self._slot_req: List[Optional[Request]] = [None] * slots
         # True while a slot's chunked prefill is in flight: the slot is
         # reserved (occupancy counts it) but NOT in the decode active
@@ -463,16 +496,24 @@ class Engine:
                                                    keepdims=False),
             stacked)
 
-    def _traced_step(self, shards, cache, tokens, pos, active):
+    def _select_rows(self, logits, keys):
+        """:func:`select_rows` under this engine's decoding rule."""
+        return select_rows(logits, keys, self.serve_cfg.temperature,
+                           self.serve_cfg.top_k)
+
+    def _traced_step(self, shards, cache, tokens, pos, active, keys):
         """Mode A decode step: slice this rank's shard/cache state off
-        the stacked leading axis, decode, return (logits, local cache)
-        — run_spmd re-stacks the per-rank outputs into the state
-        layout."""
-        return _kv.decode_step_tp(
+        the stacked leading axis, decode, choose every slot's token,
+        return (tokens, new keys, local cache) — run_spmd re-stacks the
+        per-rank outputs into the state layout.  The logits are
+        replicated over the ranks (kv.shard_params_tp), so every rank
+        chooses the same tokens and the choice adds no collective."""
+        logits, cache = _kv.decode_step_tp(
             self.cfg, self._rank_slice(shards),
             self._rank_slice(cache), tokens, pos, COMM_WORLD,
             overlap=self.serve_cfg.overlap,
             algorithm=self.serve_cfg.algorithm, active=active)
+        return (*self._select_rows(logits, keys), cache)
 
     def _traced_prefill(self, shards, prompt):
         comm = COMM_WORLD
@@ -482,15 +523,17 @@ class Engine:
                               prompt, comm)
 
     def _traced_step_paged(self, shards, pool, table, tokens, pos,
-                           active):
+                           active, keys):
         """Mode A paged decode step: shard/pool state stacked per rank,
         the block table riding replicated as DATA — one compiled
-        program for every table state (no retrace as pages churn)."""
-        return _kv.decode_step_paged(
+        program for every table state (no retrace as pages churn).
+        Ends in the choice of tokens like :meth:`_traced_step`."""
+        logits, pool = _kv.decode_step_paged(
             self.cfg, self._rank_slice(shards),
             self._rank_slice(pool), table, tokens, pos, COMM_WORLD,
             overlap=self.serve_cfg.overlap,
             algorithm=self.serve_cfg.algorithm, active=active)
+        return (*self._select_rows(logits, keys), pool)
 
     def _traced_prefill_chunk(self, shards, past, chunk):
         """Mode A chunk/suffix prefill: ``past`` is the stacked
@@ -542,6 +585,10 @@ class Engine:
                     "shrink the request")
         if self.serve_cfg.temperature > 0 and key is None:
             raise ValueError("temperature > 0 requires a PRNG `key`")
+        if key is not None and jnp.issubdtype(key.dtype,
+                                              jax.dtypes.prng_key):
+            # The decode step carries the streams as raw key bits.
+            key = jax.random.key_data(key)
         if deadline_s is not None and deadline_s <= 0:
             raise ValueError(
                 f"deadline_s must be > 0 seconds, got {deadline_s}")
@@ -621,14 +668,30 @@ class Engine:
 
     # ---------------------------------------------------------- lifecycle
 
-    def _select(self, req: Request, logits_row) -> int:
-        """One decoding choice for one request — ``generate()``'s exact
-        key discipline: split, then select with the subkey (greedy
-        ignores the key but the stream advances identically)."""
+    def _select(self, req: Request, choice) -> int:
+        """The one hand-over of a token to a request; every emitted
+        token passes through it.  Two inputs:
+
+        * a ``(vocab,)`` row of logits (the prefill's first token): one
+          decoding choice by ``generate()``'s exact key discipline —
+          split, then select with the subkey (greedy ignores the key but
+          the stream advances identically) — which is one round trip to
+          the device, counted in ``_select_syncs``;
+        * the token the decode step already chose for the request's
+          slot (a scalar out of :func:`select_rows`): handed back as it
+          is, with no device call and the key untouched (``step()`` has
+          set the key that came back with it).
+
+        An instance may wrap it (``eng._select = ...``): the
+        benchmark's broken-path control adds one to what it returns,
+        and so makes every served token wrong, decode tokens too."""
+        if np.ndim(choice) == 0:
+            return int(choice)
+        self._select_syncs += 1
         if req.key is None:
             req.key = jax.random.PRNGKey(0)   # unused on greedy path
         req.key, sub = jax.random.split(req.key)
-        tok = select_token(jnp.asarray(logits_row)[None, :], sub,
+        tok = select_token(jnp.asarray(choice)[None, :], sub,
                            self.serve_cfg.temperature,
                            self.serve_cfg.top_k, jnp.int32)
         return int(np.asarray(tok)[0])
@@ -1148,18 +1211,25 @@ class Engine:
             with span(SPAN_DISPATCH):
                 # Ends when the step call has returned, not when the
                 # device has run it.
-                logits = self._dispatch_decode()
+                toks, keys = self._dispatch_decode()
             with span(SPAN_FETCH):
-                # The sync: waits for the step and for every write
-                # queued before it, then copies the logits table.
-                table = np.asarray(logits)
+                # The one sync: waits for the step and for every write
+                # queued before it, then copies (slots,) tokens (and
+                # the keys of a sampling engine).
+                toks = self._fetch(toks)
+                if keys is not None:
+                    keys = self._fetch(keys)
             with span(SPAN_SELECT):
+                # The host's bookkeeping; no device call.
                 self.stats.tick(len(active), self.serve_cfg.slots)
                 if self._paged:
                     self._count_pages(active)
+                syncs = self._select_syncs
                 for j in active:
                     req = self._slot_req[j]
-                    tok = self._select(req, table[j])
+                    if keys is not None:
+                        req.key = keys[j]
+                    tok = self._select(req, toks[j])
                     req.emitted.append(tok)
                     events["emitted"].setdefault(req.rid, []).append(tok)
                     self.stats.count("decode_tokens")
@@ -1168,48 +1238,63 @@ class Engine:
                     if req.finished(self.serve_cfg.eos):
                         events["finished"].append(req.rid)
                         self._evict(j)
+                self.stats.count("decode_select_syncs",
+                                 self._select_syncs - syncs)
                 if self._paged:
                     self._pool_levels()
             return events
 
-    def _dispatch_decode(self):
-        """Queue ONE decode step over the slot table (the new cache
-        replaces the old) and return its ``(slots, vocab)`` logits,
-        still on the device.  A paged step takes the pool over and
-        writes into it: whoever held ``self._cache``'s old leaves holds
-        deleted arrays afterwards, as after an install."""
+    def _fetch(self, out) -> np.ndarray:
+        """The host's copy of what a step chose; under SPMD rank 0's
+        row of the stacked result (every rank chose the same)."""
+        out = np.asarray(out)
+        return out[0] if self._spmd else out
+
+    def _step_inputs(self) -> tuple:
+        """What a decode step takes besides the shards and the cache,
+        uploaded: ``([table,] tokens, pos, live, keys)``.  ``keys`` are
+        the live slots' requests' keys as ``(slots, ...)`` raw key
+        bits (zeros in the other rows), or None in a greedy engine,
+        which moves no key."""
+        slots = self.serve_cfg.slots
         live = np.asarray([self._slot_req[j] is not None
                            and not self._prefilling[j]
-                           for j in range(self.serve_cfg.slots)])
+                           for j in range(slots)])
+        keys = None
+        if self.serve_cfg.temperature > 0:
+            rows = {j: np.asarray(self._slot_req[j].key)
+                    for j in np.flatnonzero(live)}
+            blank = np.zeros_like(next(iter(rows.values()),
+                                       np.zeros(2, np.uint32)))
+            keys = jnp.asarray(np.stack(
+                [rows.get(j, blank) for j in range(slots)]))
+        table = (jnp.asarray(self._table),) if self._paged else ()
+        return (*table, jnp.asarray(self._tokens), jnp.asarray(self._pos),
+                jnp.asarray(live), keys)
+
+    def _dispatch_decode(self):
+        """Queue ONE decode step over the slot table (the new cache
+        replaces the old) and return what it chose, still on the
+        device: ``(slots,)`` tokens and the keys that go with them
+        (None from a greedy engine), under SPMD stacked per rank.  The
+        ``(slots, vocab)`` logits stay where they were computed.  A
+        paged step takes the pool over and writes into it: whoever held
+        ``self._cache``'s old leaves holds deleted arrays afterwards,
+        as after an install."""
+        args = self._step_inputs()
         if self._spmd:
-            if self._paged:
-                logits, self._cache = self._step_call(
-                    self._shards, self._cache,
-                    jnp.asarray(self._table),
-                    jnp.asarray(self._tokens),
-                    jnp.asarray(self._pos), jnp.asarray(live))
-            else:
-                logits, self._cache = self._step_call(
-                    self._shards, self._cache,
-                    jnp.asarray(self._tokens),
-                    jnp.asarray(self._pos), jnp.asarray(live))
-            return logits[0]
-        if self._paged:
-            logits, self._cache = _kv.decode_step_paged(
-                self.cfg, self._shards, self._cache,
-                jnp.asarray(self._table), jnp.asarray(self._tokens),
-                jnp.asarray(self._pos), self._comm,
-                overlap=self.serve_cfg.overlap,
-                algorithm=self.serve_cfg.algorithm,
-                active=jnp.asarray(live), donate=True)
-        else:
-            logits, self._cache = _kv.decode_step_tp(
-                self.cfg, self._shards, self._cache,
-                jnp.asarray(self._tokens), jnp.asarray(self._pos),
-                self._comm, overlap=self.serve_cfg.overlap,
-                algorithm=self.serve_cfg.algorithm,
-                active=jnp.asarray(live))
-        return logits
+            toks, keys, self._cache = self._step_call(
+                self._shards, self._cache, *args)
+            return toks, keys
+        *inputs, live, keys = args
+        decode = _kv.decode_step_paged if self._paged \
+            else _kv.decode_step_tp
+        extra = {"donate": True} if self._paged else {}
+        logits, self._cache = decode(
+            self.cfg, self._shards, self._cache, *inputs, self._comm,
+            overlap=self.serve_cfg.overlap,
+            algorithm=self.serve_cfg.algorithm, active=live, **extra)
+        return self._select_rows(logits, keys)
 
     def _pool_levels(self) -> None:
         """Mirror the block pool's population into the gauge-semantics
@@ -1306,7 +1391,7 @@ class Engine:
         engine untouched.  An elastic driver snapshots after each step
         so that a rank death mid-step still leaves a survivor-held
         ledger to re-admit from (host request state is identical on
-        every rank — tokens are selected host-side, deterministically)."""
+        every rank — every rank's step chooses the same tokens)."""
         return self._inflight_records()
 
     def drain(self) -> List[dict]:
@@ -1338,15 +1423,8 @@ class Engine:
             raise CommError(
                 "lower_step censuses the compiled SPMD decode program; "
                 "construct the engine with spmd=True")
-        live = jnp.asarray(
-            [r is not None for r in self._slot_req])
-        if self._paged:
-            # The block table is an ARGUMENT: two different table
-            # states lower to the identical program text (the no-retrace
-            # census in `make serve-smoke` holds exactly this).
-            return jax.jit(self._step_call).lower(
-                self._shards, self._cache, jnp.asarray(self._table),
-                jnp.asarray(self._tokens), jnp.asarray(self._pos), live)
+        # The block table is an ARGUMENT: two different table states
+        # lower to the identical program text (the no-retrace census in
+        # `make serve-smoke` holds exactly this).
         return jax.jit(self._step_call).lower(
-            self._shards, self._cache, jnp.asarray(self._tokens),
-            jnp.asarray(self._pos), live)
+            self._shards, self._cache, *self._step_inputs())

@@ -141,7 +141,8 @@ def shard_params_tp(cfg: TransformerConfig, params, comm):
     * row-sharded (decode collective site 1);
     * embeddings, norms, positional table, unembedding — replicated
       (logits are computed fully on every rank: rank-identical logits
-      are what make the host-side sampling loop SPMD-consistent).
+      are what let every rank choose the same tokens at the end of the
+      step, ``engine.select_rows``, with no collective).
 
     At ``size == 1`` every shard is the full matrix — the local serving
     path is the same code with identity collectives."""

@@ -53,6 +53,7 @@ _STEP_COUNTS = (("admitted", "admitted"),
                 ("install_writes", "install_writes"),
                 ("decode_pages_live", "decode_pages_live"),
                 ("decode_pages_read", "decode_pages_read"),
+                ("decode_select_syncs", "decode_select_syncs"),
                 ("occupancy_ticks", "active"))
 
 
@@ -192,7 +193,12 @@ class ServeStats:
                  # the read the engine compiled: the same pages through
                  # the kernel, slots x max_seq / block_size through the
                  # gather.  Their ratio says which read ran.
-                 "decode_pages_live", "decode_pages_read")
+                 "decode_pages_live", "decode_pages_read",
+                 # ISSUE 31: round trips to the device that the decode
+                 # steps' ``decode.select`` spent choosing tokens (one
+                 # per live slot while the host chose them from the
+                 # logits table; 0 since the step chooses them itself).
+                 "decode_select_syncs")
     SPAN_CAP = 1024
 
     def __init__(self):
@@ -353,7 +359,7 @@ def serve_step_log() -> list:
     per ``Engine.step()`` call of every engine, ``{"engine", "t0_ns",
     "t1_ns", "spans": [(name, t0_ns, t1_ns, rid), ...], "admitted",
     "prefill_tokens", "install_writes", "decode_pages_live",
-    "decode_pages_read", "active"}`` on the
+    "decode_pages_read", "decode_select_syncs", "active"}`` on the
     ``time.perf_counter_ns()`` clock, the last :data:`STEP_LOG_CAP`
     steps.  ``engine`` is the ``ServeStats.engine`` serial of the
     engine that stepped; the counts are what that step added to the

@@ -48,6 +48,11 @@ PROMPTS = [np.array([1, 2, 3]), np.array([4, 5, 6, 7, 8]),
            np.array([9, 10]), np.array([11, 12, 13, 14])]
 BUDGETS = [6, 4, 5, 3]
 
+# Engine(**spmd): the eager engine of the local world, and the compiled
+# step over two ranks.
+SPMD = [{}, {"spmd": True, "nranks": 2}]
+SPMD_IDS = ["eager", "spmd"]
+
 
 def _params(cfg, seed=0):
     return T.init_transformer(jax.random.PRNGKey(seed), cfg,
@@ -148,15 +153,28 @@ class TestEngineOracleParity:
                 np.testing.assert_array_equal(outs[r][i], outs[0][i])
         assert_matches_oracle(CFG, params, outs[0])
 
-    def test_sampled_parity_local(self):
+    @pytest.mark.parametrize("spmd", SPMD, ids=SPMD_IDS)
+    def test_sampled_parity_local(self, spmd):
+        # Four requests through two slots: the slots are admitted in
+        # different steps, so one step splits keys of different ages.
         params = _params(CFG)
         keys = [jax.random.PRNGKey(100 + i) for i in range(len(PROMPTS))]
         eng = serve.Engine(
             CFG, params,
-            serve.ServeConfig(slots=2, temperature=0.9, top_k=7))
+            serve.ServeConfig(slots=2, temperature=0.9, top_k=7), **spmd)
         res = drive(eng, keys=keys)
         assert_matches_oracle(CFG, params, res, temperature=0.9,
                               top_k=7, keys=keys)
+
+    def test_typed_key_is_served_as_its_raw_bits(self):
+        params = _params(CFG)
+        eng = serve.Engine(CFG, params,
+                           serve.ServeConfig(slots=2, temperature=0.9))
+        eng.submit(PROMPTS[0], max_new=4, key=jax.random.key(7))
+        np.testing.assert_array_equal(
+            eng.run()[0],
+            oracle_tokens(CFG, params, PROMPTS[0], 4, temperature=0.9,
+                          key=jax.random.PRNGKey(7)))
 
     def test_eos_truncates_and_evicts_early(self):
         params = _params(CFG)
@@ -174,6 +192,115 @@ class TestEngineOracleParity:
         assert res[0][-1] == eos
         assert len(res[0]) < len(PROMPTS[0]) + BUDGETS[0] + 1
         assert eng.stats.snapshot()["finished"] == len(PROMPTS)
+
+
+class TestSelectRows:
+    """``select_rows`` is ``Engine._select``'s rule applied to every
+    row at once: tokens and key streams bit for bit."""
+
+    SLOTS = 16
+
+    def _table(self):
+        rng = np.random.default_rng(3)
+        table = rng.standard_normal((self.SLOTS, CFG.vocab))
+        table[5, 30] = table[5, 11] = 7.5      # two maxima in one row
+        table[9] = 0.25                        # and a row of nothing else
+        return table
+
+    @pytest.mark.parametrize("temperature,top_k",
+                             [(0.0, 0), (0.9, 0), (0.9, 7)],
+                             ids=["greedy", "t0.9", "t0.9-top7"])
+    def test_bitwise_vs_sixteen_selects(self, temperature, top_k):
+        table = self._table()
+        keys = np.stack([np.asarray(jax.random.PRNGKey(40 + j))
+                         for j in range(self.SLOTS)])
+        eng = serve.Engine(CFG, _params(CFG), serve.ServeConfig(
+            slots=1, temperature=temperature, top_k=top_k))
+        want_tok, want_key = [], []
+        for j in range(self.SLOTS):
+            req = serve.Request(rid=j, prompt=PROMPTS[0], max_new=1,
+                                key=jnp.asarray(keys[j]))
+            want_tok.append(eng._select(req, table[j]))
+            want_key.append(np.asarray(req.key))
+        args = (jnp.asarray(table), jnp.asarray(keys))
+        compiled = jax.jit(lambda t, k: serve.select_rows(
+            t, k, temperature, top_k))
+        for toks, new in (serve.select_rows(*args, temperature, top_k),
+                          compiled(*args)):
+            assert toks.dtype == jnp.int32 and toks.shape == (self.SLOTS,)
+            np.testing.assert_array_equal(np.asarray(toks), want_tok)
+            np.testing.assert_array_equal(np.asarray(new), want_key)
+        if temperature == 0.0:
+            # First maximum on ties; and the greedy engine's form,
+            # which moves no key, chooses the same.
+            assert (want_tok[5], want_tok[9]) == (11, 0)
+            toks, new = serve.select_rows(args[0], None, 0.0, top_k)
+            assert new is None
+            np.testing.assert_array_equal(np.asarray(toks), want_tok)
+
+
+ENGINE_KINDS = [pytest.param(paged, spmd, id=f"{p}-{i}")
+                for paged, p in (({}, "dense"),
+                                 ({"block_size": 4}, "paged"))
+                for spmd, i in zip(SPMD, SPMD_IDS)]
+
+
+@pytest.mark.parametrize("paged,spmd", ENGINE_KINDS)
+class TestTokenHandOver:
+    def test_instance_select_changes_every_emitted_token(self, paged,
+                                                         spmd):
+        """Every emitted token, the decode steps' too, passes through
+        ``eng._select``: what the benchmark's broken-path control
+        (``--break wrong_token``) rests on."""
+        eng = serve.Engine(CFG, _params(CFG),
+                           serve.ServeConfig(slots=2, **paged), **spmd)
+        select, chosen = eng._select, []
+
+        def wrong(req, choice):
+            chosen.append(select(req, choice))
+            return (chosen[-1] + 1) % CFG.vocab
+
+        eng._select = wrong
+        res = drive(eng)
+        emitted = np.concatenate([np.asarray(res[i])[len(p):]
+                                  for i, p in enumerate(PROMPTS)])
+        assert len(chosen) == len(emitted) == sum(BUDGETS)
+        # Requests interleave; compare as multisets of (chosen + 1).
+        assert sorted(emitted) == sorted((np.asarray(chosen) + 1)
+                                         % CFG.vocab)
+        assert eng.stats.counters["decode_tokens"] \
+            == sum(BUDGETS) - len(PROMPTS)
+        assert eng.stats.counters["decode_select_syncs"] == 0
+
+    def test_drained_key_is_the_oracles_stream(self, paged, spmd):
+        """``req.key`` after n tokens is the oracle's key after n
+        splits, so a drained sampled request re-admitted elsewhere
+        continues bitwise."""
+        from mpi4torch_tpu.elastic import replan as E
+
+        params = _params(CFG)
+        scfg = serve.ServeConfig(slots=2, temperature=0.9, top_k=7,
+                                 **paged)
+        keys = [jax.random.PRNGKey(100 + i) for i in range(2)]
+        eng = serve.Engine(CFG, params, scfg, **spmd)
+        for i in range(2):
+            eng.submit(PROMPTS[i], max_new=6, key=keys[i])
+        eng.step(); eng.step(); eng.step()
+        tickets, _ = E.drain_tickets(eng)
+        assert [len(t.emitted) for t in tickets] == [4, 4]
+        for t, key in zip(tickets, keys):
+            for _ in t.emitted:
+                key = jax.random.split(key)[0]
+            np.testing.assert_array_equal(np.asarray(t.key),
+                                          np.asarray(key))
+        eng2 = serve.Engine(CFG, params, scfg, **spmd)
+        E.readmit(eng2, tickets)
+        stitched = E.stitched_results(eng2.run(), tickets)
+        for i in range(2):
+            np.testing.assert_array_equal(
+                stitched[i],
+                oracle_tokens(CFG, params, PROMPTS[i], 6,
+                              temperature=0.9, top_k=7, key=keys[i]))
 
 
 class TestSlotTable:
@@ -441,6 +568,53 @@ class TestCensusAndLatencyTier:
             assert "Allreduce_start" in txt
         finally:
             mpi.config.set_hier_group_size(None)
+
+    @pytest.mark.parametrize("paged", [{}, {"block_size": 4}],
+                             ids=["dense", "paged"])
+    @pytest.mark.parametrize("temperature", [0.0, 0.9],
+                             ids=["greedy", "sampled"])
+    def test_lowered_step_hands_back_tokens_not_the_table(self, paged,
+                                                          temperature):
+        """The compiled step's results are ``(slots,)`` tokens (and
+        keys when sampling) and the cache, nothing of the logits
+        table's shape; choosing adds no collective to the step that
+        ends in the logits."""
+        from mpi4torch_tpu import analyze
+        from mpi4torch_tpu.ops.spmd import run_spmd
+
+        slots, size = 3, 4
+        eng = serve.Engine(
+            CFG, _params(CFG),
+            serve.ServeConfig(slots=slots, temperature=temperature,
+                              **paged), spmd=True, nranks=size)
+        eng.submit(PROMPTS[0], max_new=3, key=jax.random.PRNGKey(1))
+        eng.step()
+        lowered = eng.lower_step()
+        toks, keys, cache = lowered.out_info
+        assert (toks.shape, toks.dtype) == ((size, slots), jnp.int32)
+        assert (keys is None) == (temperature == 0.0)
+        if keys is not None:
+            assert (keys.shape, keys.dtype) == ((size, slots, 2),
+                                                jnp.uint32)
+        assert [leaf.shape for leaf in jax.tree.leaves(cache)] \
+            == [leaf.shape for leaf in jax.tree.leaves(eng._cache)]
+        for leaf in jax.tree.leaves(lowered.out_info):
+            assert leaf.shape[-2:] != (slots, CFG.vocab)
+
+        def logits_step(shards, cache, *inputs):
+            *inputs, active, _ = inputs
+            decode = kv.decode_step_paged if paged else kv.decode_step_tp
+            return decode(CFG, eng._rank_slice(shards),
+                          eng._rank_slice(cache), *inputs,
+                          mpi.COMM_WORLD, overlap=eng.serve_cfg.overlap,
+                          active=active)
+
+        parent = jax.jit(run_spmd(logits_step, nranks=size)).lower(
+            eng._shards, eng._cache, *eng._step_inputs())
+        assert parent.out_info[0].shape == (size, slots, CFG.vocab)
+        census = analyze.parse_program(lowered).census()
+        assert census == analyze.parse_program(parent).census()
+        assert sum(census.values()) > 0
 
     def test_decode_message_bytes(self):
         scfg = serve.ServeConfig(slots=2)
@@ -803,12 +977,14 @@ class TestPagedOracleParity:
                 np.testing.assert_array_equal(outs[r][i], outs[0][i])
         assert_matches_oracle(CFG, params, outs[0])
 
-    def test_sampled_parity_local(self):
+    @pytest.mark.parametrize("spmd", SPMD, ids=SPMD_IDS)
+    def test_sampled_parity_local(self, spmd):
         params = _params(CFG)
         keys = [jax.random.PRNGKey(100 + i) for i in range(len(PROMPTS))]
         eng = serve.Engine(
             CFG, params,
-            serve.ServeConfig(temperature=0.9, top_k=7, **PAGED_TIGHT))
+            serve.ServeConfig(temperature=0.9, top_k=7, **PAGED_TIGHT),
+            **spmd)
         res = drive(eng, keys=keys)
         assert_matches_oracle(CFG, params, res, temperature=0.9,
                               top_k=7, keys=keys)

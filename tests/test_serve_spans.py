@@ -128,6 +128,33 @@ class TestStepSpans:
         assert (rec["admitted"], rec["prefill_tokens"],
                 rec["install_writes"], rec["active"]) == (0, 0, 0, 1)
 
+    def test_no_select_sync_in_any_decode_step(self, params, paged, spmd):
+        """Every step that decoded holds its dispatch, fetch and select
+        spans, and ``decode.select`` made no round trip to the device:
+        the step chose the tokens."""
+        eng = make_engine(params, paged, spmd)
+        for n in (5, 3, 6):
+            eng.submit(np.arange(1, 1 + n))
+        eng.run()
+        decoded = [r for r in log_of(eng) if r["active"]]
+        assert len(decoded) >= 3
+        for rec in decoded:
+            assert rec["decode_select_syncs"] == 0
+            for phase in DECODE_PHASES:
+                assert names(rec).count(phase) == 1
+        assert eng.stats.counters["decode_select_syncs"] == 0
+        assert eng.stats.counters["decode_tokens"] \
+            == sum(r["active"] for r in decoded)
+        # A row of logits handed to _select inside decode.select is a
+        # round trip, and is counted as one.
+        eng.submit(np.arange(1, 6))
+        eng.step()
+        select = eng._select
+        eng._select = lambda req, tok: select(
+            req, np.eye(CFG.vocab)[int(tok)])
+        eng.step()
+        assert log_of(eng)[-1]["decode_select_syncs"] == 1
+
     def test_idle_step_has_no_decode_span(self, params, paged, spmd):
         eng = make_engine(params, paged, spmd)
         eng.step()
@@ -338,4 +365,5 @@ def test_new_counter_is_mirrored(params):
     eng.submit(np.arange(1, 6))
     eng.run()
     assert serve.stats()["install_writes"] == 1
+    assert serve.stats()["decode_select_syncs"] == 0
     assert registry.serve_paging_problems() == []

@@ -94,14 +94,14 @@ def test_paged_decode_step_compiles_in_place(one_v5e_chip, as_on_tpu):
     donated, compiled for one v5e chip at head size 128: every pool leaf
     is aliased to its output, the stacked ``(1, ...)`` axis and the rank
     slice cost no copy, the write is a scatter into the leaf, the read
-    is the kernel, and no other instruction produces anything of a pool
-    leaf's shape."""
+    is the kernel, no other instruction produces anything of a pool
+    leaf's shape, and the logits are rounded before they are compared."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from mpi4torch_tpu import serve
     from mpi4torch_tpu.ops.spmd import run_spmd
 
-    cfg = T.TransformerConfig(vocab=512, d_model=512, n_heads=4,
+    cfg = T.TransformerConfig(vocab=8192, d_model=512, n_heads=4,
                               n_kv_heads=2, n_layers=2, d_ff=1024,
                               max_seq=512, rope=True, norm="rmsnorm",
                               ffn="swiglu")
@@ -127,7 +127,7 @@ def test_paged_decode_step_compiles_in_place(one_v5e_chip, as_on_tpu):
                 like(jnp.asarray(eng._table), rep),
                 like(jnp.asarray(eng._tokens), rep),
                 like(jnp.asarray(eng._pos), rep),
-                like(jnp.zeros((slots,), bool), rep))
+                like(jnp.zeros((slots,), bool), rep), None)
         compiled = jax.jit(step, donate_argnums=(1,)).lower(*args).compile()
     text = compiled.as_text()
     leaves = 2 * cfg.n_layers
@@ -143,3 +143,8 @@ def test_paged_decode_step_compiles_in_place(one_v5e_chip, as_on_tpu):
     made = _made_with_shape(text, dims) + _made_with_shape(text, "1," + dims)
     assert made.count("scatter") == leaves
     assert set(made) <= {"parameter", "bitcast", "scatter", "fusion"}, made
+    # The logits table is a fusion's own result, rounded to bfloat16
+    # before the step's argmax reads it (select_rows' barrier): fused
+    # into the product the TPU compares unrounded accumulators, and the
+    # served tokens leave the host-selected ones at every near-tie.
+    assert "fusion" in _made_with_shape(text, f"{slots},{cfg.vocab}")
