@@ -277,9 +277,10 @@ def run_server(cfg, params, n: int, log: CompileLog, *, paged: bool,
               f"request {rid}: the prompt did not come back intact")
         check(np.all((out >= 0) & (out < cfg.vocab)),
               f"request {rid}: token outside [0, {cfg.vocab})")
-    # run_spmd programs of one engine: the TP sharding, one prefill per
-    # distinct prompt length, and ONE decode step.
-    want = 1 + len(set(len(p) for p in prompts)) + 1
+    # run_spmd programs of one engine: the TP sharding (the top of the
+    # tree, and one for the uniform layers), one prefill per distinct
+    # prompt length, and ONE decode step.
+    want = 2 + len(set(len(p) for p in prompts)) + 1
     check(programs == want and retraces == 0,
           f"engine compiled {programs} run_spmd programs ({retraces} in "
           f"decode-only steps), want {want}: the decode step must compile "

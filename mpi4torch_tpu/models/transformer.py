@@ -13,7 +13,8 @@ thread-SPMD runtime, inside ``run_spmd``, or in a user-managed 2D
 A configuration may state its stack as data (``TransformerConfig.layers``,
 one :class:`LayerSpec` a layer): Kimi Delta Attention or latent attention
 as the mixer, the held share of a top-k expert layer as the FFN
-(doc/layer_spec.md).  Training path only.
+(doc/layer_spec.md).  KDA runs on the training path only; latent
+attention and the expert share are served too (serve/kv.py).
 
 TPU-first shapes: all compute is batched matmul/einsum (MXU), parameters
 and activations stay in the caller's dtype (bfloat16-ready), and the
@@ -62,15 +63,26 @@ class KDA:
 
 @dataclass(frozen=True)
 class MLA:
-    """Mixer: multi-head latent attention without rotation.  Keys and
-    values come up from one latent of ``kv_rank``; a key is ``qk_nope``
-    channels of its own head plus ``qk_rope`` channels shared by all
-    heads (not rotated); a value has ``v_dim`` channels."""
+    """Mixer: multi-head latent attention.  Keys and values come up from
+    one latent of ``kv_rank``; a key is ``qk_nope`` channels of its own
+    head plus ``qk_rope`` channels shared by all heads; a value has
+    ``v_dim`` channels.  ``q_rank > 0`` brings the query up from a normed
+    latent of that rank as well (0: one projection).  ``rope`` rotates
+    the ``qk_rope`` channels of every query head and of the shared key
+    by their position (``TransformerConfig.rope_theta``); without it no
+    channel knows a position."""
     n_heads: int
     kv_rank: int
     qk_nope: int
     qk_rope: int
     v_dim: int
+    q_rank: int = 0
+    rope: bool = False
+
+    def __post_init__(self):
+        if self.rope and self.qk_rope % 2:
+            raise ValueError(
+                f"rope pairs channels: qk_rope={self.qk_rope} must be even")
 
 
 @dataclass(frozen=True)
@@ -80,9 +92,12 @@ class LayerSpec:
     ``rope``, ``attn_window``), else a :class:`KDA` or an :class:`MLA`.
     ``ffn``: ``None`` is the configuration's dense FFN (``ffn``,
     ``d_ff``), else the :class:`~mpi4torch_tpu.parallel.moe.Experts`
-    share this rank holds."""
+    share this rank holds.  ``post_norm`` puts a second norm on each
+    branch, after the mixer and after the FFN and before the residual
+    sum (a "sandwich"): leaves ``ln1_post`` and ``ln2_post``."""
     mixer: Union[None, KDA, MLA] = None
     ffn: Optional[Experts] = None
+    post_norm: bool = False
 
 
 @dataclass(frozen=True)
@@ -132,12 +147,12 @@ class TransformerConfig:
                 raise ValueError(
                     "a layer spec names its expert layers itself "
                     "(LayerSpec.ffn); n_experts is the uniform top-1 MoE")
-            if any(s.mixer is None and s.ffn is not None
+            if any(s.mixer is None and (s.ffn is not None or s.post_norm)
                    for s in self.layers):
                 raise ValueError(
-                    "an expert FFN needs a KDA or MLA mixer: the "
-                    "configuration's own attention block carries its own "
-                    "FFN")
+                    "an expert FFN or a post-norm needs a KDA or MLA "
+                    "mixer: the configuration's own attention block "
+                    "carries its own FFN and norms")
         if self.n_experts > 0 and self.capacity <= 0:
             # capacity=0 would silently capacity-drop every token — the
             # model would train with no FFN path at all.
@@ -227,6 +242,8 @@ def init_transformer(key, cfg: TransformerConfig,
             blk = {"ln1": norm_p(), "ln2": norm_p(),
                    "mixer": _init_mixer(next(keys), spec.mixer, d_model,
                                         dtype)}
+            if spec.post_norm:
+                blk["ln1_post"], blk["ln2_post"] = norm_p(), norm_p()
         if spec.ffn is not None:
             blk["experts"] = init_experts(next(keys), spec.ffn, d_model,
                                           dtype)
@@ -254,7 +271,12 @@ def _init_mixer(key, spec, d_model: int, dtype) -> Dict[str, Any]:
 
     if isinstance(spec, MLA):
         h = spec.n_heads
-        return {"wq": dense(d_model, h * (spec.qk_nope + spec.qk_rope)),
+        q_in = spec.q_rank or d_model
+        query = {"wq": dense(q_in, h * (spec.qk_nope + spec.qk_rope))}
+        if spec.q_rank:
+            query["wqa"] = dense(d_model, spec.q_rank)
+            query["q_norm"] = {"scale": jnp.ones((spec.q_rank,), dtype)}
+        return {**query,
                 "wa": dense(d_model, spec.kv_rank + spec.qk_rope),
                 "kv_norm": {"scale": jnp.ones((spec.kv_rank,), dtype)},
                 "wb": dense(spec.kv_rank, h * (spec.qk_nope + spec.v_dim)),
@@ -456,32 +478,79 @@ def _blockwise_causal_attention(q, k, v, block: int):
     return jnp.concatenate(outs, axis=1)
 
 
-def _mla_mixer(spec: MLA, p, y):
-    """Latent attention without rotation on the normed input ``y``: the
-    flash kernels at query-key size ``qk_nope + qk_rope``, the value
-    zero-padded up to it (zeros add nothing to the weighted sum)."""
+def mla_project(cfg: TransformerConfig, spec: MLA, p, y, positions):
+    """The projections of latent attention on the normed input ``y``
+    ``(b, s, d)``, the ONE place they live (the training forward and the
+    serving walk both come through here): ``(q, c, k_r)`` with ``q``
+    ``(b, s, h, qk_nope + qk_rope)``, ``c`` ``(b, s, kv_rank)`` the
+    normed latent and ``k_r`` ``(b, s, qk_rope)`` the key channels all
+    heads share.  Under ``spec.rope`` the last ``qk_rope`` channels of
+    ``q`` and ``k_r`` are rotated by ``positions`` (``(s,)`` or ``(b,
+    s)``).  ``[c ; k_r]`` is everything a later query needs of this
+    token: the serving cache's entry."""
     b, s, _ = y.shape
-    h, dn, dr, dv = spec.n_heads, spec.qk_nope, spec.qk_rope, spec.v_dim
-    q = (y @ p["wq"]).reshape(b, s, h, dn + dr)
+    h, dn, dr = spec.n_heads, spec.qk_nope, spec.qk_rope
+    if spec.q_rank:
+        q = _rms_norm(y @ p["wqa"], p["q_norm"]) @ p["wq"]
+    else:
+        q = y @ p["wq"]
+    q = q.reshape(b, s, h, dn + dr)
     latent = y @ p["wa"]
-    c, k_shared = latent[..., :spec.kv_rank], latent[..., spec.kv_rank:]
-    kv = (_rms_norm(c, p["kv_norm"]) @ p["wb"]).reshape(b, s, h, dn + dv)
+    c, k_r = latent[..., :spec.kv_rank], latent[..., spec.kv_rank:]
+    c = _rms_norm(c, p["kv_norm"])
+    if spec.rope:
+        if positions is None:
+            raise ValueError("MLA(rope=True) requires the caller's positions")
+        q = jnp.concatenate(
+            [q[..., :dn], _rope_rotate(cfg, q[..., dn:], positions)], axis=-1)
+        k_r = _rope_rotate(cfg, k_r[:, :, None, :], positions)[:, :, 0]
+    return q, c, k_r
+
+
+def mla_expand(spec: MLA, p, c, k_r):
+    """Keys and values of every head from the normed latent ``c`` and
+    the shared key channels ``k_r``: ``k`` ``(b, s, h, qk_nope +
+    qk_rope)`` and ``v`` zero-padded to the same width (zeros add
+    nothing to the weighted sum), as the flash kernels take them."""
+    b, s, _ = c.shape
+    h, dn, dr, dv = spec.n_heads, spec.qk_nope, spec.qk_rope, spec.v_dim
+    kv = (c @ p["wb"]).reshape(b, s, h, dn + dv)
     k = jnp.concatenate(
         [kv[..., :dn],
-         jnp.broadcast_to(k_shared[:, :, None, :], (b, s, h, dr))], axis=-1)
+         jnp.broadcast_to(k_r[:, :, None, :], (b, s, h, dr))], axis=-1)
     v = jnp.pad(kv[..., dn:], ((0, 0),) * 3 + ((0, dn + dr - dv),))
-    o = _blockwise_causal_attention(q, k, v, _MLA_BLOCK)[..., :dv]
-    return o.reshape(b, s, h * dv) @ p["wo"]
+    return k, v
+
+
+def _mla_mixer(cfg: TransformerConfig, spec: MLA, p, y, positions):
+    """Latent attention on the normed input ``y``: the flash kernels at
+    query-key size ``qk_nope + qk_rope``, the value zero-padded up to
+    it."""
+    b, s, _ = y.shape
+    q, c, k_r = mla_project(cfg, spec, p, y, positions)
+    k, v = mla_expand(spec, p, c, k_r)
+    o = _blockwise_causal_attention(q, k, v, _MLA_BLOCK)[..., :spec.v_dim]
+    return o.reshape(b, s, spec.n_heads * spec.v_dim) @ p["wo"]
 
 
 def refuse_layer_spec(cfg: TransformerConfig, what: str) -> None:
-    """The serving entry points' answer to a configuration with a
-    per-layer spec (also ``serve.validate_tp``'s)."""
+    """The answer of this module's own single-sequence oracle
+    (``init_kv_cache``, ``decode_step``, ``prefill``, ``generate``) to a
+    configuration with a per-layer spec: it knows one kind of layer.
+    The serving engine (``serve/kv.py``) walks the spec itself."""
     if cfg.layers:
         raise CommError(
-            f"{what}: a configuration with a per-layer spec runs on the "
-            "training path only — the serving blocks know one kind of "
-            "layer and keep no latent, recurrent or expert state")
+            f"{what}: the single-sequence oracle knows one kind of layer "
+            "and keeps no latent, recurrent or expert state — serve a "
+            "per-layer spec through mpi4torch_tpu.serve")
+
+
+def branch_norm(cfg: TransformerConfig, spec: LayerSpec, blk, out,
+                which: str):
+    """A branch's output before the residual sum: through the layer's
+    second norm (``ln1_post`` after the mixer, ``ln2_post`` after the
+    FFN) where the spec states one."""
+    return _norm(cfg, out, blk[which]) if spec.post_norm else out
 
 
 def _ffn_dense(cfg: TransformerConfig, blk, y):
@@ -646,16 +715,19 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
         y = _norm(cfg, x, blk["ln1"])
         if isinstance(spec.mixer, KDA):
             with layer_scope("kda"):
-                return x + _kda_mixer(spec.mixer, blk["mixer"], y)
+                return x + branch_norm(cfg, spec, blk, _kda_mixer(
+                    spec.mixer, blk["mixer"], y), "ln1_post")
         with layer_scope("mla"):
-            return x + _mla_mixer(spec.mixer, blk["mixer"], y)
+            return x + branch_norm(cfg, spec, blk, _mla_mixer(
+                cfg, spec.mixer, blk["mixer"], y, positions), "ln1_post")
 
     def experts_fn(spec, x, blk):
         with layer_scope("moe"):
             y = _norm(cfg, x, blk["ln2"])
             ff, taken = held_experts_ffn(y.reshape(-1, d), blk["experts"],
                                          spec.ffn, comm_ep)
-        return x + ff.reshape(x.shape), taken
+            ff = branch_norm(cfg, spec, blk, ff.reshape(x.shape), "ln2_post")
+        return x + ff, taken
 
     # With remat a uniform block is one rematerialised region; a new kind
     # of mixer and an expert FFN are one each, so that the backward holds
@@ -670,7 +742,12 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
             aux_total = aux_total + aux
             continue
         x = remat(functools.partial(mixer_fn, spec))(x, blk)
-        if spec.ffn is None:
+        if spec.ffn is None and spec.post_norm:
+            x = remat(lambda x_, blk_: x_ + branch_norm(
+                cfg, spec, blk_, _ffn_dense(
+                    cfg, blk_, _norm(cfg, x_, blk_["ln2"])),
+                "ln2_post"))(x, blk)
+        elif spec.ffn is None:
             x, _ = remat(lambda x_, blk_: _ffn_residual(
                 cfg, blk_, x_, comm_ep))(x, blk)
         else:

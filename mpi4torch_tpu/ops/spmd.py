@@ -2364,7 +2364,7 @@ def run_spmd(fn, nranks: Optional[int] = None, mesh=None,
     else:
         jitted = sm
 
-    def call(*args):
+    def keyed():
         # The deterministic-reductions flag, the compression default,
         # the fusion bucket size, the algorithm default, the overlap
         # policy, and the schedule thresholds + tune-cache generation
@@ -2374,12 +2374,20 @@ def run_spmd(fn, nranks: Optional[int] = None, mesh=None,
         # the old lowering.
         from .. import tune as _tune
 
-        return jitted(_config.deterministic_reductions(),
-                      _config.default_compression(),
-                      _config.default_bucket_bytes(),
-                      _config.default_algorithm(),
-                      _config.default_overlap(),
-                      (_config.thresholds_fingerprint(),
-                       _tune.generation()), *args)
+        return (_config.deterministic_reductions(),
+                _config.default_compression(),
+                _config.default_bucket_bytes(),
+                _config.default_algorithm(),
+                _config.default_overlap(),
+                (_config.thresholds_fingerprint(), _tune.generation()))
 
+    def call(*args):
+        return jitted(*keyed(), *args)
+
+    if jit:
+        # The very program a call with these arguments runs, lowered:
+        # for a caller that reads its compiled text.  (Not named
+        # ``lower``: ``jax.jit(call)`` copies ``call``'s attributes onto
+        # its own wrapper, whose ``lower`` this would then replace.)
+        call.lower_as_called = lambda *args: jitted.lower(*keyed(), *args)
     return call

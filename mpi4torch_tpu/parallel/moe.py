@@ -323,7 +323,7 @@ def _swiglu(x, w1, w2, dot):
 
 
 def held_experts_ffn(x, params: Dict[str, Any], spec: Experts,
-                     comm_ep=None):
+                     comm_ep=None, live=None):
     """The held experts' part of the layer for ``x`` ``(T, d)``, plus the
     shared expert: ``sum over chosen and held e of w_e E_e(x) +
     E_shared(x)``.  The weights are renormalised over all ``top_k``
@@ -337,8 +337,16 @@ def held_experts_ffn(x, params: Dict[str, Any], spec: Experts,
     case); the rows behind them belong to no group, and what the
     compiler's kernel spends on them is its own affair.
 
+    ``live`` (``(T,)`` bool, optional) marks the tokens that are
+    somebody's: the others' pairs join the rows behind the groups, take
+    no expert's time and are not counted (a serving decode step's free
+    slots; their ``y`` rows are the shared expert's alone).
+
     Returns ``(y, rows)``: ``rows`` ``(n_held,)``, the rows each held
-    expert took, which are the group sizes the products are handed."""
+    expert took, which are the group sizes the products are handed.
+    Inference runs the same code: a compiled prefill or decode step of
+    ``mpi4torch_tpu.serve`` calls it on its rows and hands ``rows`` out
+    with the step's record."""
     if comm_ep is not None and comm_ep.size > 1:
         raise CommError(
             "held_experts_ffn computes one rank's share and exchanges "
@@ -350,7 +358,10 @@ def held_experts_ffn(x, params: Dict[str, Any], spec: Experts,
     chosen, weight = route_topk(x, params["router"], params["bias"], k,
                                 spec.scale)
     local = chosen.reshape(-1) - spec.first_expert
-    group = jnp.where((local >= 0) & (local < held), local, held)
+    here = (local >= 0) & (local < held)
+    if live is not None:
+        here &= jnp.repeat(live, k)
+    group = jnp.where(here, local, held)
     order = jnp.argsort(group, stable=True)
     inverse = jnp.zeros_like(order).at[order].set(
         jnp.arange(order.shape[0], dtype=order.dtype))

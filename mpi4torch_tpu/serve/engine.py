@@ -314,7 +314,15 @@ class Engine:
 
     Construct with full (replicated) parameters; the TP shards, the
     sharded KV cache, and the decode collectives follow from the
-    world (see module docstring).  Drive it with :meth:`submit` +
+    world (see module docstring).  Construction shards ``params`` a
+    layer at a time (the leaves go into the sharding program as
+    arguments, never as constants of it) and lets each layer of the
+    caller's go before it takes the next: ``params["blocks"]`` may be
+    any iterable, walked once, and where it makes its layers as they are
+    asked for, the process holds the shards, the cache and about one
+    layer of the caller's leaves at the fullest moment, not a second
+    copy of the model.  A caller that keeps its whole tree keeps it.
+    Drive it with :meth:`submit` +
     :meth:`step`, or :meth:`run` to drain everything.  Greedy and
     sampled decoding both produce exactly the tokens of a per-request
     ``models/transformer.generate`` call (tests/test_serve.py holds
@@ -353,17 +361,18 @@ class Engine:
         else:
             self._size = self._comm.size
         _kv.validate_tp(cfg, self._size)
+        self._paged = self.serve_cfg.block_size > 0
+        # Exactness gate for prefix sharing and chunked prefill
+        # (_exact_kv, below): both splice CACHE-dtype rows into prefill
+        # attention, which is only bit-identical to the one-shot oracle
+        # when the cache carries the compute dtype.  A down-cast cache
+        # keeps paging (storage) but prefills every prompt in full,
+        # like the dense path.
         self._dtype = (self.serve_cfg.cache_dtype
                        or params["embed"].dtype)
-        self._paged = self.serve_cfg.block_size > 0
-        # Exactness gate for prefix sharing and chunked prefill: both
-        # splice CACHE-dtype rows into prefill attention, which is only
-        # bit-identical to the one-shot oracle when the cache carries
-        # the compute dtype.  A down-cast cache keeps paging (storage)
-        # but prefills every prompt in full, like the dense path.
         self._exact_kv = (jnp.dtype(self._dtype)
                           == jnp.dtype(params["embed"].dtype))
-
+        param_dtype = params["embed"].dtype
         if self._spmd:
             from ..ops.spmd import run_spmd
             kw = {}
@@ -377,9 +386,8 @@ class Engine:
             # compiled step slices one rank's shards instead of
             # re-deriving them from the replicated full parameters
             # every executed step.
-            self._shards = run_spmd(
-                lambda: _kv.shard_params_tp(cfg, params, COMM_WORLD),
-                **kw)()
+            self._shards = self._shard_by_layer(
+                params, lambda fn: run_spmd(fn, **kw))
             # The paged step takes its pool over (argument 1) and
             # writes the new rows into it; the dense step's one-hot
             # write builds a new cache and is left as it is.
@@ -394,10 +402,14 @@ class Engine:
         else:
             # Eager: the rank is concrete here (rank thread or the
             # size-1 world) — shard once.
-            self._shards = _kv.shard_params_tp(cfg, params, self._comm)
+            self._shards = self._shard_by_layer(params, lambda fn: fn)
             self._step_call = None
             self._prefill_call = None
             self._chunk_call = None
+        del params
+        # The input shapes each compiled program has been called with:
+        # what program_texts() lowers again.
+        self._prefilled: set = set()
 
         slots = self.serve_cfg.slots
         if self._paged:
@@ -427,12 +439,7 @@ class Engine:
                            if self._exact_kv else None)
             # Which read the decode step compiles, asked of the
             # function that decides it: what decode_pages_read counts.
-            hd = cfg.d_model // cfg.n_heads
-            self._kernel_read = _paged_attn.uses_kernel(
-                jax.ShapeDtypeStruct(
-                    (slots, cfg.n_heads // self._size, hd),
-                    params["embed"].dtype),
-                cache[0]["k"])
+            self._kernel_read = self._reads_by_kernel(cache, param_dtype)
         else:
             cache = _kv.init_kv_cache_tp(cfg, slots, self._size,
                                          self._dtype, poison=True)
@@ -485,6 +492,59 @@ class Engine:
         # between steps, never during one — see attach_controller.
         self._controller = None
 
+    def _shard_by_layer(self, params, compiled):
+        """:func:`~mpi4torch_tpu.serve.kv.shard_params_tp` a layer at a
+        time: the top of the tree (embedding, head, final norm), then
+        each layer as ``params["blocks"]`` yields it, each through
+        ``compiled(fn)(leaves)`` — ``run_spmd`` for an SPMD engine (the
+        leaves are ARGUMENTS of the sharding program and come back
+        stacked per rank), the function itself for an eager one — and
+        let go before the next is taken."""
+        cfg = self.cfg
+        top = compiled(lambda t: t)
+        by_spec = {}
+        shards = top({k: v for k, v in params.items() if k != "blocks"})
+        shards["blocks"] = []
+        specs = cfg.layer_specs
+        for blk in params["blocks"]:
+            n = len(shards["blocks"])
+            if n == len(specs):
+                raise ValueError(
+                    f"params['blocks'] yields more than n_layers={n} "
+                    "layers")
+            spec = specs[n]
+            if spec not in by_spec:
+                by_spec[spec] = compiled(
+                    lambda b, spec=spec: _kv.shard_block_tp(
+                        cfg, spec, b, self._comm))
+            shards["blocks"].append(by_spec[spec](blk))
+            del blk        # before the iterable makes the next one
+        if len(shards["blocks"]) != len(specs):
+            raise ValueError(
+                f"params['blocks'] has {len(shards['blocks'])} layers for "
+                f"n_layers={len(specs)}")
+        return shards
+
+    def _reads_by_kernel(self, cache, dtype) -> bool:
+        """Whether every layer's decode read of this (unstacked) pool
+        is the paged kernel's, each asked of the dispatch's own
+        predicate with the query that layer will bring."""
+        cfg, slots = self.cfg, self.serve_cfg.slots
+        like = lambda *shape: jax.ShapeDtypeStruct(shape, dtype)
+        asked = {}                    # each kind of layer once
+        for spec, entry in zip(cfg.layer_specs, cache):
+            if spec.mixer in asked:
+                continue
+            if "c" in entry:
+                asked[spec.mixer] = _paged_attn.uses_kernel(
+                    like(slots, spec.mixer.n_heads, entry["c"].shape[-1]),
+                    entry["c"], spec.mixer.kv_rank)
+            else:
+                asked[spec.mixer] = _paged_attn.uses_kernel(
+                    like(slots, cfg.n_heads // self._size,
+                         cfg.d_model // cfg.n_heads), entry["k"])
+        return all(asked.values())
+
     # ------------------------------------------------------------- traced
 
     @staticmethod
@@ -504,23 +564,26 @@ class Engine:
     def _traced_step(self, shards, cache, tokens, pos, active, keys):
         """Mode A decode step: slice this rank's shard/cache state off
         the stacked leading axis, decode, choose every slot's token,
-        return (tokens, new keys, local cache) — run_spmd re-stacks the
-        per-rank outputs into the state layout.  The logits are
+        return (tokens, new keys, local cache, the step's counters) —
+        run_spmd re-stacks the per-rank outputs into the state layout.  The logits are
         replicated over the ranks (kv.shard_params_tp), so every rank
         chooses the same tokens and the choice adds no collective."""
+        stats = {}
         logits, cache = _kv.decode_step_tp(
             self.cfg, self._rank_slice(shards),
             self._rank_slice(cache), tokens, pos, COMM_WORLD,
             overlap=self.serve_cfg.overlap,
-            algorithm=self.serve_cfg.algorithm, active=active)
-        return (*self._select_rows(logits, keys), cache)
+            algorithm=self.serve_cfg.algorithm, active=active,
+            stats=stats)
+        return (*self._select_rows(logits, keys), cache, stats)
 
     def _traced_prefill(self, shards, prompt):
         comm = COMM_WORLD
         cache = _kv.init_kv_cache_tp(self.cfg, 1, comm.size, self._dtype,
                                      poison=False)
-        return _kv.prefill_tp(self.cfg, self._rank_slice(shards), cache,
-                              prompt, comm)
+        stats = {}
+        return (*_kv.prefill_tp(self.cfg, self._rank_slice(shards), cache,
+                                prompt, comm, stats=stats), stats)
 
     def _traced_step_paged(self, shards, pool, table, tokens, pos,
                            active, keys):
@@ -528,21 +591,24 @@ class Engine:
         the block table riding replicated as DATA — one compiled
         program for every table state (no retrace as pages churn).
         Ends in the choice of tokens like :meth:`_traced_step`."""
+        stats = {}
         logits, pool = _kv.decode_step_paged(
             self.cfg, self._rank_slice(shards),
             self._rank_slice(pool), table, tokens, pos, COMM_WORLD,
             overlap=self.serve_cfg.overlap,
-            algorithm=self.serve_cfg.algorithm, active=active)
-        return (*self._select_rows(logits, keys), pool)
+            algorithm=self.serve_cfg.algorithm, active=active,
+            stats=stats)
+        return (*self._select_rows(logits, keys), pool, stats)
 
     def _traced_prefill_chunk(self, shards, past, chunk):
         """Mode A chunk/suffix prefill: ``past`` is the stacked
         exact-length prefix K/V gathered host-side from the pool at
         concrete page ids (compiles per (prefix, chunk) length pair,
         like prefill itself compiles per prompt length)."""
-        return _kv.prefill_chunk_tp(
+        stats = {}
+        return (*_kv.prefill_chunk_tp(
             self.cfg, self._rank_slice(shards), self._rank_slice(past),
-            chunk, COMM_WORLD)
+            chunk, COMM_WORLD, stats=stats), stats)
 
     # -------------------------------------------------------------- public
 
@@ -764,14 +830,30 @@ class Engine:
         host (a device sync: the prefill's device time ends here).
         Returns ``(logits_row, rows)``."""
         pj = jnp.asarray(prompt, jnp.int32)[None, :]
+        stats = {}
         if self._spmd:
-            logits, rows = self._prefill_call(self._shards, pj)
-            return np.asarray(logits[0][0]), rows
-        cache1 = _kv.init_kv_cache_tp(
-            self.cfg, 1, self._size, self._dtype, poison=False)
-        logits, rows = _kv.prefill_tp(
-            self.cfg, self._shards, cache1, pj, self._comm)
-        return np.asarray(logits[0]), rows
+            self._prefilled.add(pj.shape[1])
+            logits, rows, stats = self._prefill_call(self._shards, pj)
+            logits = logits[0]
+        else:
+            cache1 = _kv.init_kv_cache_tp(
+                self.cfg, 1, self._size, self._dtype, poison=False)
+            logits, rows = _kv.prefill_tp(
+                self.cfg, self._shards, cache1, pj, self._comm,
+                stats=stats)
+        logits_row = np.asarray(logits[0])
+        self._note_counters("prefill", stats)
+        return logits_row, rows
+
+    def _note_counters(self, program: str, stats: dict) -> None:
+        """A compiled program's own counters (``kv._hand_out``), copied
+        off the device behind the sync its caller has just made, onto
+        the record of the step that is open: ``moe_rows``, one
+        ``(program, (expert layers, held) rows)`` per call of a program
+        with an expert layer, in the order of the calls."""
+        if "moe_rows" in stats:
+            self.stats.attach(
+                "moe_rows", (program, self._fetch(stats["moe_rows"])))
 
     # -------------------------------------------------------------- paged
 
@@ -824,7 +906,8 @@ class Engine:
         bs = self.serve_cfg.block_size
         first = lo // bs
         touched = -(-hi // bs) - first
-        n_pages = _kv.install_page_count(rows[0]["k"].shape[-3], bs)
+        n_pages = _kv.install_page_count(
+            jax.tree.leaves(rows)[0].shape[-3], bs)
         index = np.empty(2 + n_pages, np.int32)
         index[0], index[1] = lo % bs, hi - lo
         # Beyond the touched pages: ids outside the pool, dropped.
@@ -848,31 +931,22 @@ class Engine:
     def _gather_past(self, j: int, n: int):
         """Exact-length past K/V (positions ``0..n-1``) for slot ``j``,
         host-gathered from the pool at the slot's concrete page ids —
-        the suffix/chunk prefill input.  Stacked ``(size, 1, n, ...)``
+        the suffix/chunk prefill input: the cache's own tree, whatever
+        kind each layer's entry is.  Stacked ``(size, 1, n, ...)``
         leaves under SPMD, ``(1, n, ...)`` eager."""
         bs = self.serve_cfg.block_size
-        hd = self.cfg.d_model // self.cfg.n_heads
-        kvh = self.cfg.kv_heads // self._size
-        if n == 0:
-            shape = ((self._size, 1, 0, kvh, hd) if self._spmd
-                     else (1, 0, kvh, hd))
-            z = jnp.zeros(shape, self._dtype)
-            return [{"k": z, "v": z} for _ in range(self.cfg.n_layers)]
         nblk = -(-n // bs)
         ids = jnp.asarray([int(self._table[j, bi])
                            for bi in range(nblk)], jnp.int32)
+        lead = 1 if self._spmd else 0      # the stacked rank axis
 
         def take(leaf):
-            if self._spmd:
-                g = jnp.take(leaf, ids, axis=1)
-                g = g.reshape((self._size, 1, nblk * bs) + leaf.shape[3:])
-                return g[:, :, :n]
-            g = jnp.take(leaf, ids, axis=0)
-            g = g.reshape((1, nblk * bs) + leaf.shape[2:])
-            return g[:, :n]
+            g = jnp.take(leaf, ids, axis=lead)
+            g = g.reshape(leaf.shape[:lead] + (1, nblk * bs)
+                          + leaf.shape[lead + 2:])
+            return g[(slice(None),) * (lead + 1) + (slice(0, n),)]
 
-        return [{"k": take(c["k"]), "v": take(c["v"])}
-                for c in self._cache]
+        return jax.tree.map(take, self._cache)
 
     def _plan_paged(self, req: Request) -> Optional[_PrefillJob]:
         """Plan a paged admission: prefix-match the prompt against the
@@ -956,14 +1030,17 @@ class Engine:
             past = self._gather_past(j, job.done)
             chunk = jnp.asarray(job.seq[job.done:job.done + c_len],
                                 jnp.int32)[None, :]
+            stats = {}
             if self._spmd:
-                logits, rows = self._chunk_call(self._shards, past,
-                                                chunk)
+                logits, rows, stats = self._chunk_call(self._shards, past,
+                                                       chunk)
                 logits_row = np.asarray(logits[0][0])
             else:
                 logits, rows = _kv.prefill_chunk_tp(
-                    self.cfg, self._shards, past, chunk, self._comm)
+                    self.cfg, self._shards, past, chunk, self._comm,
+                    stats=stats)
                 logits_row = np.asarray(logits[0])
+            self._note_counters("prefill", stats)
         with self.stats.span(SPAN_INSTALL, rid):
             self._install_rows(j, rows, job.done, job.done + c_len)
         self.stats.count("prefill_tokens", c_len)
@@ -1072,9 +1149,11 @@ class Engine:
         paged engine only its in-use pages (a shared prefix counted
         once).  It is a census, not a timer, so it regresses
         deterministically on CPU smoke."""
-        hd = self.cfg.d_model // self.cfg.n_heads
-        row = 2 * (self.cfg.kv_heads // self._size) * hd \
-            * self.cfg.n_layers * jnp.dtype(self._dtype).itemsize
+        # One token's rows over every cache leaf (a leaf is (..., rows
+        # of a slot or a page, *row shape), behind the stacked axis).
+        lead = 3 if self._spmd else 2
+        row = sum(int(np.prod(a.shape[lead:])) * a.dtype.itemsize
+                  for a in jax.tree.leaves(self._cache))
         if self._paged:
             return self._mgr.blocks_in_use \
                 * self.serve_cfg.block_size * row
@@ -1211,14 +1290,16 @@ class Engine:
             with span(SPAN_DISPATCH):
                 # Ends when the step call has returned, not when the
                 # device has run it.
-                toks, keys = self._dispatch_decode()
+                toks, keys, counters = self._dispatch_decode()
             with span(SPAN_FETCH):
                 # The one sync: waits for the step and for every write
                 # queued before it, then copies (slots,) tokens (and
-                # the keys of a sampling engine).
+                # the keys of a sampling engine, and the step's own
+                # counters, which are there once the tokens are).
                 toks = self._fetch(toks)
                 if keys is not None:
                     keys = self._fetch(keys)
+                self._note_counters("decode", counters)
             with span(SPAN_SELECT):
                 # The host's bookkeeping; no device call.
                 self.stats.tick(len(active), self.serve_cfg.slots)
@@ -1275,26 +1356,29 @@ class Engine:
     def _dispatch_decode(self):
         """Queue ONE decode step over the slot table (the new cache
         replaces the old) and return what it chose, still on the
-        device: ``(slots,)`` tokens and the keys that go with them
-        (None from a greedy engine), under SPMD stacked per rank.  The
+        device: ``(slots,)`` tokens, the keys that go with them (None
+        from a greedy engine) and the step's counters (``moe_rows``
+        where a layer has experts), under SPMD stacked per rank.  The
         ``(slots, vocab)`` logits stay where they were computed.  A
         paged step takes the pool over and writes into it: whoever held
         ``self._cache``'s old leaves holds deleted arrays afterwards,
         as after an install."""
         args = self._step_inputs()
         if self._spmd:
-            toks, keys, self._cache = self._step_call(
+            toks, keys, self._cache, counters = self._step_call(
                 self._shards, self._cache, *args)
-            return toks, keys
+            return toks, keys, counters
         *inputs, live, keys = args
         decode = _kv.decode_step_paged if self._paged \
             else _kv.decode_step_tp
         extra = {"donate": True} if self._paged else {}
+        counters = {}
         logits, self._cache = decode(
             self.cfg, self._shards, self._cache, *inputs, self._comm,
             overlap=self.serve_cfg.overlap,
-            algorithm=self.serve_cfg.algorithm, active=live, **extra)
-        return self._select_rows(logits, keys)
+            algorithm=self.serve_cfg.algorithm, active=live,
+            stats=counters, **extra)
+        return (*self._select_rows(logits, keys), counters)
 
     def _pool_levels(self) -> None:
         """Mirror the block pool's population into the gauge-semantics
@@ -1412,6 +1496,29 @@ class Engine:
         return recs
 
     # ------------------------------------------------------------- census
+
+    def program_texts(self) -> Dict[str, str]:
+        """The compiled (Mode A) programs' text, read-only: ``"decode"``,
+        the decode step over the current slot-table state, and
+        ``"prefill.<n>"`` for every prompt length ``n`` this engine has
+        prefilled in one piece.  Each is lowered and compiled again from
+        the shapes it ran with (the persistent compile cache answers
+        where it is on), so the instructions carry the names their
+        events have in a profiler trace and the ``op_name`` of the
+        scope they ran under (``layer_scope``)."""
+        if not self._spmd:
+            raise CommError(
+                "program_texts reads the compiled SPMD programs; "
+                "construct the engine with spmd=True")
+        text = lambda call, *args: call.lower_as_called(
+            *args).compile().as_text()
+        out = {"decode": text(self._step_call, self._shards, self._cache,
+                              *self._step_inputs())}
+        for n in sorted(self._prefilled):
+            out[f"prefill.{n}"] = text(
+                self._prefill_call, self._shards,
+                jax.ShapeDtypeStruct((1, n), jnp.int32))
+        return out
 
     def lower_step(self):
         """The lowered (Mode A) decode-step program over the CURRENT

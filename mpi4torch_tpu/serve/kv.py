@@ -49,15 +49,18 @@ import jax.numpy as jnp
 
 from .. import config as _config
 from ..constants import MPI_SUM
-from ..models.transformer import TransformerConfig, _ffn_dense, _norm, \
-    _split_qkv, refuse_layer_spec
+from ..models.transformer import KDA, MLA, TransformerConfig, _MLA_BLOCK, \
+    _blockwise_causal_attention, _ffn_dense, _norm, _split_qkv, \
+    branch_norm, mla_expand, mla_project
 from ..ops.flash import flash_attention, flash_block_attention
-from ..ops.paged_attention import paged_decode_attention
+from ..ops.paged_attention import latent_rows_attention, \
+    paged_decode_attention, paged_latent_attention
 from ..ops.ragged import block_scatter, position_onehot
 from ..overlap import overlap_split_allreduce, resolve_overlap
+from ..parallel.moe import held_experts_ffn
 from ..parallel.tp import shard_axis, shard_heads
 from ..runtime import CommError
-from ..utils.profiling import bucket_scope, serve_step_scope
+from ..utils.profiling import bucket_scope, layer_scope, serve_step_scope
 
 __all__ = [
     "validate_tp",
@@ -66,6 +69,7 @@ __all__ = [
     "init_kv_pool_tp",
     "install_page_count",
     "install_rows_paged",
+    "latent_width",
     "prefill_tp",
     "prefill_chunk_tp",
     "decode_step_tp",
@@ -77,16 +81,37 @@ __all__ = [
 def validate_tp(cfg: TransformerConfig, size: int) -> None:
     """Serving TP shardability of a model config over ``size`` ranks:
     whole q heads, whole KV heads, and an FFN hidden divisible per rank.
-    MoE configs are refused — expert-parallel decode routes through
-    ``parallel/moe.py``'s Alltoall, a different serving schedule than
-    the dense TP path this subsystem ships.  So is a configuration
-    with a per-layer spec: the serving blocks know one kind of layer."""
-    refuse_layer_spec(cfg, "serve")
+    Uniform top-1 MoE configs (``n_experts > 0``) are refused —
+    expert-parallel decode routes through ``parallel/moe.py``'s
+    Alltoall, a different serving schedule than the dense TP path this
+    subsystem ships.
+
+    A configuration with a per-layer spec is served on ONE rank: latent
+    attention (:class:`~mpi4torch_tpu.models.transformer.MLA`) through a
+    latent cache entry, the held share of an expert layer inside the
+    compiled prefill and decode step.  Refused by name: a KDA mixer (its
+    recurrent state has no cache entry and no snapshot yet), and more
+    than one rank (the heads of a latent layer and the experts' exchange
+    are not sharded yet)."""
     if cfg.n_experts > 0:
         raise CommError(
             "serve: MoE configs (n_experts > 0) are not supported by the "
             "dense TP decode path — expert-parallel serving needs the "
             "Alltoall routing schedule")
+    if cfg.layers:
+        if any(isinstance(sp.mixer, KDA) for sp in cfg.layers):
+            raise CommError(
+                "serve: a KDA mixer keeps a recurrent state, for which "
+                "the serving cache has no entry and no snapshot yet — "
+                "latent attention (MLA) and the held expert share are "
+                "what the serving walk knows of a per-layer spec")
+        if size != 1:
+            raise CommError(
+                f"serve: a configuration with a per-layer spec is served "
+                f"on one rank; {size} ranks would need a latent layer's "
+                "heads sharded and the expert layer's exchange, neither "
+                "of which is written yet")
+        return
     if cfg.n_heads % size != 0 or cfg.kv_heads % size != 0:
         raise CommError(
             f"serve: n_heads={cfg.n_heads} and kv_heads={cfg.kv_heads} "
@@ -95,6 +120,14 @@ def validate_tp(cfg: TransformerConfig, size: int) -> None:
     if cfg.d_ff % size != 0:
         raise CommError(
             f"serve: d_ff={cfg.d_ff} not divisible by world size {size}")
+
+
+def latent_width(spec: MLA) -> int:
+    """Channels of a latent cache row: the normed latent and the shared
+    key channels, ``kv_rank + qk_rope``, stored up to whole lanes of 128
+    (zeros behind them: 512 + 64 -> 640), so that a page is what the
+    paged kernel can fetch as it lies."""
+    return -(-(spec.kv_rank + spec.qk_rope) // 128) * 128
 
 
 def _shard_wqkv(cfg: TransformerConfig, comm, wqkv):
@@ -148,27 +181,27 @@ def shard_params_tp(cfg: TransformerConfig, params, comm):
     path is the same code with identity collectives."""
     size = comm.size
     validate_tp(cfg, size)
+    top = {k: v for k, v in params.items() if k != "blocks"}
+    top["blocks"] = [shard_block_tp(cfg, spec, blk, comm) for spec, blk
+                     in zip(cfg.layer_specs, params["blocks"])]
+    return top
 
-    def block_shard(blk):
-        out = {"ln1": blk["ln1"], "ln2": blk["ln2"],
-               "wqkv": _shard_wqkv(cfg, comm, blk["wqkv"]),
-               "wo": shard_heads(comm, blk["wo"], cfg.n_heads, 0)}
-        if cfg.ffn == "swiglu":
-            out["w1"] = _shard_swiglu_w1(cfg, comm, blk["w1"])
-        else:
-            out["w1"] = shard_axis(comm, blk["w1"], 1)
-        out["w2"] = shard_axis(comm, blk["w2"], 0)
-        return out
 
-    shards = {
-        "embed": params["embed"],
-        "ln_f": params["ln_f"],
-        "unembed": params["unembed"],
-        "blocks": [block_shard(blk) for blk in params["blocks"]],
-    }
-    if "pos" in params:
-        shards["pos"] = params["pos"]
-    return shards
+def shard_block_tp(cfg: TransformerConfig, spec, blk, comm):
+    """One layer of :func:`shard_params_tp` (the engine shards a layer
+    at a time).  A layer a spec names other than the configuration's
+    own is served on one rank and passes whole."""
+    if spec.mixer is not None:
+        return dict(blk)
+    out = {"ln1": blk["ln1"], "ln2": blk["ln2"],
+           "wqkv": _shard_wqkv(cfg, comm, blk["wqkv"]),
+           "wo": shard_heads(comm, blk["wo"], cfg.n_heads, 0)}
+    if cfg.ffn == "swiglu":
+        out["w1"] = _shard_swiglu_w1(cfg, comm, blk["w1"])
+    else:
+        out["w1"] = shard_axis(comm, blk["w1"], 1)
+    out["w2"] = shard_axis(comm, blk["w2"], 0)
+    return out
 
 
 def init_kv_cache_tp(cfg: TransformerConfig, slots: int, size: int,
@@ -183,18 +216,44 @@ def init_kv_cache_tp(cfg: TransformerConfig, slots: int, size: int,
     logits would be caught immediately (all per-slot compute is
     row-local, and tests assert the inertness), while admission
     overwrites the whole slot row so live slots never see the poison."""
-    hd = cfg.d_model // cfg.n_heads
-    shape = (slots, cfg.max_seq, cfg.kv_heads // size, hd)
     fill = jnp.nan if poison and jnp.issubdtype(dtype, jnp.floating) \
         else 0
-    buf = jnp.full(shape, fill, dtype)
-    return [{"k": buf, "v": buf} for _ in range(cfg.n_layers)]
+    return _cache_entries(cfg, (slots, cfg.max_seq), size,
+                          lambda shape: jnp.full(shape, fill, dtype))
+
+
+def _cache_entries(cfg: TransformerConfig, lead: tuple, size: int, make):
+    """One cache entry a layer, each leaf ``make(lead + its row's
+    shape)``: ``{"k", "v"}`` of ``(kv_heads / size, head_dim)`` rows for
+    the configuration's own attention, ``{"c"}`` of ``(1,
+    latent_width)`` rows for a latent layer — one row a token for all
+    heads, the normed latent and the rotated shared key
+    (:func:`latent_width`: 640 channels, 1,280 bytes in bfloat16, where
+    128 heads of keys and values would be 65,536)."""
+    hd = cfg.d_model // cfg.n_heads
+    made = {}                  # one buffer a row shape, shared as a template
+
+    def leaf(*row):
+        if row not in made:
+            made[row] = make(lead + row)
+        return made[row]
+
+    out = []
+    for spec in cfg.layer_specs:
+        if isinstance(spec.mixer, MLA):
+            out.append({"c": leaf(1, latent_width(spec.mixer))})
+        else:
+            kv = leaf(cfg.kv_heads // size, hd)
+            out.append({"k": kv, "v": kv})
+    return out
 
 
 def init_kv_pool_tp(cfg: TransformerConfig, num_blocks: int,
                     block_size: int, size: int, dtype=jnp.float32):
     """Per-layer TP-sharded paged KV pool:
-    ``(num_blocks, block_size, kv_heads / size, head_dim)`` per rank —
+    ``(num_blocks, block_size, kv_heads / size, head_dim)`` per rank
+    (a latent layer: one leaf ``(num_blocks, block_size, 1,
+    latent_width)``, :func:`_cache_entries`) —
     the paged counterpart of :func:`init_kv_cache_tp`, addressed
     through a per-slot block table instead of a dense per-slot row.
     One block-id space serves every layer (block ``i`` of each layer is
@@ -217,10 +276,8 @@ def init_kv_pool_tp(cfg: TransformerConfig, num_blocks: int,
             f"serve: block_size={block_size} must be >= 1 and divide "
             f"max_seq={cfg.max_seq} (a slot's table row covers the "
             "dense attention extent)")
-    hd = cfg.d_model // cfg.n_heads
-    shape = (num_blocks, block_size, cfg.kv_heads // size, hd)
-    buf = jnp.zeros(shape, dtype)
-    return [{"k": buf, "v": buf} for _ in range(cfg.n_layers)]
+    return _cache_entries(cfg, (num_blocks, block_size), size,
+                          lambda shape: jnp.zeros(shape, dtype))
 
 
 def install_page_count(n_rows: int, block_size: int) -> int:
@@ -236,8 +293,9 @@ def install_rows_paged(pool, rows, index):
     donated, so the pages are written in place.
 
     ``pool`` is :func:`init_kv_pool_tp`'s tree; ``rows`` the same tree
-    with ``(1, R, kv_heads/size, head_dim)`` leaves, row 0 being the
-    first position written.  Everything that changes from install to
+    with ``(1, R, kv_heads/size, head_dim)`` leaves (``(1, R, 1,
+    latent_width)`` for a latent layer: the write is the same for every
+    kind of entry), row 0 being the first position written.  Everything that changes from install to
     install is in ``index``, an int32 vector ``[off, n, id_0, ...,
     id_{P-1}]`` with ``P = install_page_count(R, block_size)``: rows
     ``0..n-1`` land at offsets ``off, off+1, ...`` of page ``id_0``
@@ -275,6 +333,8 @@ def _tp_size(cfg: TransformerConfig, shards) -> int:
     output projection's row count (``h_local * head_dim``) — so the
     compute functions need no communicator to agree with their
     shards."""
+    if cfg.layers:
+        return 1                       # validate_tp: one rank
     hd = cfg.d_model // cfg.n_heads
     h_local = shards["blocks"][0]["wo"].shape[0] // hd
     return cfg.n_heads // h_local
@@ -304,47 +364,164 @@ def _decode_allreduce(comm, x, *, site: int, nsites: int, overlap,
                               algorithm=algorithm)
 
 
+class _Latent:
+    """One latent layer's attention, both ways over one cache.  A cache
+    row is ``[c ; k_r ; 0]`` (:func:`latent_width`): the normed latent
+    and the rotated key channels all heads share.
+
+    * :meth:`expanded` (the prefills): the rows go up through ``wb`` to
+      every head's keys and values and the flash path attends at
+      query-key size ``qk_nope + qk_rope`` with the value padded, as the
+      training forward does;
+    * :meth:`absorbed` + :meth:`values` (the decode steps): with ``wb =
+      [Wuk ; Wuv]`` a head, ``q_n . (c Wuk) = (q_n Wuk^T) . c``, so the
+      query goes DOWN to the latent instead (``[q_n Wuk^T ; q_r ; 0]``),
+      every head scores the cached row itself, the weighted sum is taken
+      over the rows' first ``kv_rank`` channels and only that sum goes
+      up through ``Wuv``: keys and values are never formed, and a row
+      is read once for all heads.
+
+    The same mathematics (``tests/test_openpangu_moe.py`` holds the two
+    together on one cache)."""
+
+    def __init__(self, spec: MLA, p):
+        self.spec, self.p = spec, p
+        self.width = latent_width(spec)
+        self.scale = float(spec.qk_nope + spec.qk_rope) ** -0.5
+
+    def rows(self, c, k_r):
+        """``(b, s, 1, width)`` cache rows of a pass's latents."""
+        pad = self.width - c.shape[-1] - k_r.shape[-1]
+        return jnp.concatenate(
+            [c, k_r, jnp.zeros(c.shape[:-1] + (pad,), c.dtype)],
+            axis=-1)[:, :, None, :]
+
+    def expanded(self, q, rows, q_offset=None):
+        """``q`` ``(b, s, h, qk)`` over ``rows`` ``(b, n, 1, width)``:
+        the whole sequence causally (``q_offset`` None: ``n == s``), or
+        from global offset ``q_offset`` over ``rows`` from 0 on (a chunk
+        against cached rows; the jnp path, as the K/V chunk view)."""
+        sp = self.spec
+        rows = rows[:, :, 0].astype(q.dtype)
+        k, v = mla_expand(sp, self.p, rows[..., :sp.kv_rank],
+                          rows[..., sp.kv_rank:sp.kv_rank + sp.qk_rope])
+        if q_offset is None:
+            o = _blockwise_causal_attention(q, k, v, _MLA_BLOCK)
+        else:
+            o, _ = flash_block_attention(
+                q, k, v, causal=True, q_offset=q_offset, kv_offset=0,
+                impl="jnp")
+        return o[..., :sp.v_dim]
+
+    def _up(self):
+        sp = self.spec
+        return self.p["wb"].reshape(sp.kv_rank, sp.n_heads,
+                                    sp.qk_nope + sp.v_dim)
+
+    def absorbed(self, q):
+        """``q`` ``(slots, h, qk)`` -> ``(slots, h, width)``."""
+        sp = self.spec
+        qc = jnp.einsum("shn,rhn->shr", q[..., :sp.qk_nope],
+                        self._up()[..., :sp.qk_nope])
+        pad = self.width - sp.kv_rank - sp.qk_rope
+        return jnp.concatenate(
+            [qc, q[..., sp.qk_nope:],
+             jnp.zeros(q.shape[:-1] + (pad,), q.dtype)], axis=-1)
+
+    def values(self, u):
+        """``u`` ``(slots, h, kv_rank)`` -> ``(slots, h, v_dim)``."""
+        return jnp.einsum("shr,rhv->shv", u,
+                          self._up()[..., self.spec.qk_nope:])
+
+
 def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
-                 reduce, what: str):
+                 attend_latent, reduce, live=None):
     """The serving transformer block, once, for every serving program:
-    per layer ``ln1`` → q/k/v → ``attend`` → ``wo`` → ``reduce`` →
-    residual → ``ln2`` → FFN partial → ``reduce`` → residual; then
-    ``ln_f``.  Returns ``(x, entries)``: the normed hidden rows and what
-    ``attend`` handed back for each layer.
+    per layer ``ln1`` → mixer → ``reduce`` → residual → ``ln2`` → FFN →
+    ``reduce`` → residual; then ``ln_f``.  Returns ``(x, entries,
+    moe_rows)``: the normed hidden rows, what the view handed back for
+    each layer, and the rows each held expert took in every expert
+    layer, ``(expert layers, held)`` (``None`` without such a layer).
 
     ``x`` is the embedded input, ``(b, s, d)`` with ``positions`` ``(s,)``
     (a prefill) or ``(slots, d)`` with one position a slot (a decode
     step, split as sequences of one).  What differs between the four
-    programs is in the two callables:
+    programs is in the callables:
 
-    * ``attend(layer, q, k, v) -> (o, entry)`` is the cache VIEW: it
-      stores this pass's K/V rows of layer ``layer`` its own way, attends
-      ``q`` over what that layer may see, and returns the attention
-      output (any shape that flattens to ``x``'s rows) and the layer's
-      new cache entry (or the rows to install);
+    * ``attend(layer, q, k, v) -> (o, entry)`` is the cache VIEW of a
+      layer of the configuration's own attention: it stores this pass's
+      K/V rows of layer ``layer`` its own way, attends ``q`` over what
+      that layer may see, and returns the attention output (any shape
+      that flattens to ``x``'s rows) and the layer's new cache entry
+      (or the rows to install);
+    * ``attend_latent(layer, q, rows, lat) -> (o, entry)`` is the same
+      view of a latent layer: ``rows`` are this pass's cache rows
+      (``lat.rows``), ``lat`` the layer's :class:`_Latent`, whose
+      ``expanded`` a prefill calls and whose ``absorbed`` / ``values`` a
+      decode step calls; ``o`` holds every head's ``v_dim`` channels;
     * ``reduce(partial, site, nsites)`` sums a row-parallel partial
       product over the TP ranks; the sites are counted here, two a layer.
 
-    A second kind of layer (an expert FFN, a latent or recurrent cache
-    entry) is taught here and nowhere else on the serving path; until
-    then a configuration with a per-layer spec is refused, under the
-    name ``what`` of the entry point that was called."""
-    refuse_layer_spec(cfg, what)
+    A layer is what its :class:`~mpi4torch_tpu.models.transformer.
+    LayerSpec` names (``cfg.layer_specs``; without a spec every layer is
+    the configuration's own): the mixer the configuration's attention or
+    latent attention under ``layer_scope("mla")``, through the
+    projections the training forward uses (``mla_project``); the FFN
+    dense or the held share of an expert layer under
+    ``layer_scope("moe")`` (``live`` ``(rows,)`` keeps a decode step's
+    free slots out of the groups and the counts); a second norm on each
+    branch where the spec states one.  This is the one place on the
+    serving path that knows a kind of layer; what it does not know
+    ``validate_tp`` refuses by name."""
     size = _tp_size(cfg, shards)
+    validate_tp(cfg, size)
     nsites = 2 * len(shards["blocks"])
-    # The qkv split takes sequences: a decode step's rows are of one.
+    # The projections take sequences: a decode step's rows are of one.
     seq = (lambda a: a[:, None]) if x.ndim == 2 else (lambda a: a)
-    entries = []
-    for layer, blk in enumerate(shards["blocks"]):
+    entries, moe_rows = [], []
+    for layer, (spec, blk) in enumerate(zip(cfg.layer_specs,
+                                            shards["blocks"])):
         y = _norm(cfg, x, blk["ln1"])
-        q, k, v = _split_qkv(cfg, blk, seq(y), seq(positions), size)
-        o, entry = attend(layer, q, k, v)
+        if spec.mixer is None:
+            q, k, v = _split_qkv(cfg, blk, seq(y), seq(positions), size)
+            o, entry = attend(layer, q, k, v)
+            o_part = o.reshape(*x.shape[:-1], -1).astype(x.dtype) \
+                @ blk["wo"]
+        else:
+            with layer_scope("mla"):
+                lat = _Latent(spec.mixer, blk["mixer"])
+                q, c, k_r = mla_project(cfg, spec.mixer, blk["mixer"],
+                                        seq(y), seq(positions))
+                o, entry = attend_latent(layer, q, lat.rows(c, k_r), lat)
+                o_part = branch_norm(
+                    cfg, spec, blk,
+                    o.reshape(*x.shape[:-1], -1).astype(x.dtype)
+                    @ blk["mixer"]["wo"], "ln1_post")
         entries.append(entry)
-        o_part = o.reshape(*x.shape[:-1], -1).astype(x.dtype) @ blk["wo"]
         x = x + reduce(o_part, 2 * layer, nsites).astype(x.dtype)
-        ff = _ffn_dense(cfg, blk, _norm(cfg, x, blk["ln2"]))
+        y = _norm(cfg, x, blk["ln2"])
+        if spec.ffn is None:
+            ff = branch_norm(cfg, spec, blk, _ffn_dense(cfg, blk, y),
+                             "ln2_post")
+        else:
+            with layer_scope("moe"):
+                ff, taken = held_experts_ffn(
+                    y.reshape(-1, y.shape[-1]), blk["experts"], spec.ffn,
+                    live=live)
+                ff = branch_norm(cfg, spec, blk, ff.reshape(y.shape),
+                                 "ln2_post")
+            moe_rows.append(taken)
         x = x + reduce(ff, 2 * layer + 1, nsites).astype(x.dtype)
-    return _norm(cfg, x, shards["ln_f"]), entries
+    return (_norm(cfg, x, shards["ln_f"]), entries,
+            jnp.stack(moe_rows) if moe_rows else None)
+
+
+def _hand_out(stats, moe_rows):
+    """A serving program's counters, into the caller's ``stats`` dict
+    (the one route out: the engine's step record takes them from
+    there)."""
+    if stats is not None and moe_rows is not None:
+        stats["moe_rows"] = moe_rows
 
 
 def _prefill_reduce(comm):
@@ -383,13 +560,16 @@ def _decode_reduce(comm, live, overlap, algorithm):
     return reduce
 
 
-def prefill_tp(cfg: TransformerConfig, shards, cache, prompt, comm=None):
+def prefill_tp(cfg: TransformerConfig, shards, cache, prompt, comm=None,
+               stats=None):
     """TP prefill: populate this rank's KV-cache shard rows from a whole
     prompt in one batched pass and return ``(last_logits, new_cache)``
     — the serving mirror of ``models/transformer.prefill`` (same op
     sequence per rank; one blocking Allreduce per row-parallel half —
     prefill is the compute-bound phase, so its collectives stay on the
-    blocking path and out of the decode exposure census)."""
+    blocking path and out of the decode exposure census).  ``stats``, a
+    dict, receives the program's counters (``moe_rows`` where the
+    configuration has an expert layer)."""
     p_len = prompt.shape[1]
     x = shards["embed"][prompt]
     if not cfg.rope:
@@ -407,14 +587,24 @@ def prefill_tp(cfg: TransformerConfig, shards, cache, prompt, comm=None):
         o = flash_attention(q, k, v, causal=True, window=cfg.attn_window)
         return o, {"k": ck, "v": cv}
 
+    def attend_latent(layer, q, rows, lat):
+        # The same for a latent layer: rows written at 0, attention over
+        # this pass's own rows, expanded.
+        c = cache[layer]["c"]
+        cc = jax.lax.dynamic_update_slice_in_dim(
+            c, rows.astype(c.dtype), 0, 1)
+        return lat.expanded(q, rows), {"c": cc}
+
     with serve_step_scope("prefill"):
-        x, new_cache = _walk_layers(cfg, shards, x, positions, attend,
-                                    _prefill_reduce(comm), "prefill_tp")
+        x, new_cache, moe_rows = _walk_layers(
+            cfg, shards, x, positions, attend, attend_latent,
+            _prefill_reduce(comm))
+        _hand_out(stats, moe_rows)
         return x[:, -1] @ shards["unembed"], new_cache
 
 
 def prefill_chunk_tp(cfg: TransformerConfig, shards, past, chunk,
-                     comm=None):
+                     comm=None, stats=None):
     """TP prefill of one prompt CHUNK against already-computed prefix
     K/V: the suffix/chunked half of paged admission.  ``chunk`` is
     ``(1, c_len)`` tokens occupying global positions ``p_len ..
@@ -440,7 +630,7 @@ def prefill_chunk_tp(cfg: TransformerConfig, shards, past, chunk,
     Collectives are the blocking prefill path (compute-bound phase,
     outside the decode exposure census), one per row-parallel half."""
     c_len = chunk.shape[1]
-    p_len = int(past[0]["k"].shape[1])
+    p_len = int(jax.tree.leaves(past[0])[0].shape[1])
     x = shards["embed"][chunk]
     if not cfg.rope:
         x = x + shards["pos"][None, p_len:p_len + c_len]
@@ -458,15 +648,26 @@ def prefill_chunk_tp(cfg: TransformerConfig, shards, past, chunk,
             window=cfg.attn_window, impl="jnp")
         return o, rows
 
+    def attend_latent(layer, q, rows, lat):
+        # A latent layer's chunk: its rows go back to be installed and
+        # attend past ++ chunk, expanded, from their global offset.
+        p = past[layer]["c"]
+        full = jnp.concatenate([p.astype(rows.dtype), rows], axis=1)
+        return (lat.expanded(q, full, q_offset=p_len),
+                {"c": rows.astype(p.dtype)})
+
     with serve_step_scope("prefill"):
-        x, rows = _walk_layers(cfg, shards, x, positions, attend,
-                               _prefill_reduce(comm), "prefill_chunk_tp")
+        x, rows, moe_rows = _walk_layers(
+            cfg, shards, x, positions, attend, attend_latent,
+            _prefill_reduce(comm))
+        _hand_out(stats, moe_rows)
         return x[:, -1] @ shards["unembed"], rows
 
 
 def decode_step_tp(cfg: TransformerConfig, shards, cache, tokens, pos,
                    comm=None, *, overlap=None,
-                   algorithm: Optional[str] = None, active=None):
+                   algorithm: Optional[str] = None, active=None,
+                   stats=None):
     """One continuous-batching decode step over the whole slot table:
     logits for ``tokens`` ``(slots,)``, each slot at its OWN position
     ``pos[slot]`` ``(slots,)``, updating this rank's KV-cache shard.
@@ -506,7 +707,8 @@ def decode_step_tp(cfg: TransformerConfig, shards, cache, tokens, pos,
     single-sequence optimization; per-slot gathers would re-shuffle the
     cache every step for a smoke-scale win)."""
     pos = jnp.asarray(pos, jnp.int32)
-    reduce = _decode_reduce(comm, _live_rows(active), overlap, algorithm)
+    live = _live_rows(active)
+    reduce = _decode_reduce(comm, live, overlap, algorithm)
 
     def attend(layer, q, k, v):
         # One-hot ``where`` write of each slot's row; attention over
@@ -520,12 +722,24 @@ def decode_step_tp(cfg: TransformerConfig, shards, cache, tokens, pos,
             window=cfg.attn_window, impl="jnp")
         return o, {"k": ck, "v": cv}
 
+    def attend_latent(layer, q, rows, lat):
+        # The same write of a latent row; the read is absorbed: every
+        # head scores the slot's rows as they lie.
+        c = cache[layer]["c"]
+        wmask = (position_onehot(pos, cfg.max_seq) != 0)[:, :, None, None]
+        cc = jnp.where(wmask, rows.astype(c.dtype), c)
+        u = latent_rows_attention(
+            lat.absorbed(q[:, 0]), cc[:, :, 0], pos,
+            v_width=lat.spec.kv_rank, scale=lat.scale)
+        return lat.values(u), {"c": cc}
+
     with serve_step_scope("decode_step"):
         x = shards["embed"][tokens]
         if not cfg.rope:
             x = x + jnp.take(shards["pos"], pos, axis=0)
-        x, new_cache = _walk_layers(cfg, shards, x, pos, attend, reduce,
-                                    "decode_step_tp")
+        x, new_cache, moe_rows = _walk_layers(
+            cfg, shards, x, pos, attend, attend_latent, reduce, live)
+        _hand_out(stats, moe_rows)
         return x @ shards["unembed"], new_cache
 
 
@@ -538,7 +752,7 @@ _block_scatter_donated = jax.jit(block_scatter, donate_argnums=0)
 def decode_step_paged(cfg: TransformerConfig, shards, pool, table,
                       tokens, pos, comm=None, *, overlap=None,
                       algorithm: Optional[str] = None, active=None,
-                      donate: bool = False):
+                      donate: bool = False, stats=None):
     """One continuous-batching decode step over a PAGED slot table:
     :func:`decode_step_tp`'s math with the dense per-slot cache
     replaced by ``pool`` (per-layer ``(num_blocks, block_size,
@@ -584,7 +798,7 @@ def decode_step_paged(cfg: TransformerConfig, shards, pool, table,
     before the wire (the dense step's rule)."""
     pos = jnp.asarray(pos, jnp.int32)
     table = jnp.asarray(table, jnp.int32)
-    bs = pool[0]["k"].shape[1]
+    bs = jax.tree.leaves(pool[0])[0].shape[1]
     live = _live_rows(active)
     reduce = _decode_reduce(comm, live, overlap, algorithm)
     write = _block_scatter_donated if donate else block_scatter
@@ -607,12 +821,22 @@ def decode_step_paged(cfg: TransformerConfig, shards, pool, table,
             active=live)
         return o, {"k": pk, "v": pv}
 
+    def attend_latent(layer, q, rows, lat):
+        # One latent row a live slot scattered into its page; the read
+        # is absorbed, page by page through the table.
+        pc = write(pool[layer]["c"], wb, off, rows[:, 0], live)
+        u = paged_latent_attention(
+            lat.absorbed(q[:, 0]), pc, table, pos,
+            v_width=lat.spec.kv_rank, scale=lat.scale, active=live)
+        return lat.values(u), {"c": pc}
+
     with serve_step_scope("decode_step"):
         x = shards["embed"][tokens]
         if not cfg.rope:
             x = x + jnp.take(shards["pos"], pos, axis=0)
-        x, new_pool = _walk_layers(cfg, shards, x, pos, attend, reduce,
-                                   "decode_step_paged")
+        x, new_pool, moe_rows = _walk_layers(
+            cfg, shards, x, pos, attend, attend_latent, reduce, live)
+        _hand_out(stats, moe_rows)
         return x @ shards["unembed"], new_pool
 
 
@@ -644,6 +868,11 @@ def admit_zero3(cfg: TransformerConfig, comm, p_shards, template, *,
 
     size = comm.size
     validate_tp(cfg, size)
+    if cfg.layers:
+        raise CommError(
+            "admit_zero3: the ZeRO-3 admission routes the leaves of the "
+            "configuration's own layers; a per-layer spec's leaves have "
+            "no route yet — construct the engine from the gathered tree")
     row = _rs.Layout((size,), ((0,), ()))
     col = _rs.Layout((size,), ((), (0,)))
 

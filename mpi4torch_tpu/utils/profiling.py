@@ -208,6 +208,7 @@ class ServeStats:
         self.engine = next(_ENGINE_IDS)   # "engine" of its step records
         self.phases = {}                  # span name -> [ns, count]
         self._open = None                 # spans of the step that is open
+        self._open_attached = {}          # what attach() put on it
         self._open_counts = None
 
     def reset(self) -> None:
@@ -235,8 +236,19 @@ class ServeStats:
         block ends."""
         return _Span(self, name, rid)
 
+    def attach(self, key: str, value) -> None:
+        """Put ``value`` on the record of the step that is open, under
+        ``key``: a list, in the order attached (nothing where no step is
+        open).  For what a step's compiled programs count themselves:
+        ``moe_rows``, one ``(program, rows)`` per call of a program with
+        an expert layer (``serve.Engine._note_counters``).  A record
+        has the key only where something was attached."""
+        if self._open is not None:
+            self._open_attached.setdefault(key, []).append(value)
+
     def _open_step(self) -> None:
         self._open = []
+        self._open_attached = {}
         with self._lock:
             self._open_counts = [self.counters[c] for c, _ in _STEP_COUNTS]
 
@@ -249,7 +261,7 @@ class ServeStats:
             return
         self._open = None
         record = {"engine": self.engine, "t0_ns": t0, "t1_ns": t1,
-                  "spans": spans}
+                  "spans": spans, **self._open_attached}
         with self._lock:
             for (c, key), base in zip(_STEP_COUNTS, self._open_counts):
                 record[key] = self.counters[c] - base

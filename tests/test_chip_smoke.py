@@ -52,8 +52,9 @@ def test_phases_pass_at_tiny_widths(n, no_x64):
         prompt_lens=(5, 9), block_size=16)
     assert (dense["cache"], paged["cache"]) == ("dense", "paged")
     for res in (dense, paged):
-        # TP shards + two prefill lengths + ONE decode step.
-        assert res["spmd_programs_compiled"] == 4
+        # TP shards (the tree's top, the layers) + two prefill lengths
+        # + ONE decode step.
+        assert res["spmd_programs_compiled"] == 5
         assert res["decode_steps"] > 0
 
 

@@ -131,7 +131,8 @@ def test_chunked_kda_survives_a_fast_decay():
 # ---------------------------------------------------------------- mixers
 
 @pytest.mark.parametrize("layer,mixer,reference", [
-    (0, T._kda_mixer, ref.kda), (3, T._mla_mixer, ref.mla)])
+    (0, T._kda_mixer, ref.kda),
+    (3, lambda spec, p, y: T._mla_mixer(TCFG, spec, p, y, None), ref.mla)])
 def test_mixer_matches_the_reference(layer, mixer, reference):
     spec = TCFG.layers[layer].mixer
     p, x = _params()["blocks"][layer]["mixer"], _x()

@@ -264,3 +264,84 @@ def test_validation(bad, match):
     with pytest.raises(ValueError, match=match):
         pa.paged_decode_attention(kw["q"], pk, pv, kw["table"], kw["pos"],
                                   window=kw["window"], impl=kw["impl"])
+
+
+# ------------------------------------------------------- the latent read
+
+W, V = 256, 128          # a row's stored width, and its value's
+
+
+def a_latent_case(dtype, heads=8, seed=0):
+    """``a_case`` with a latent pool: one row a token for all heads."""
+    q, pk, _, table, pos, active = a_case(dtype, 1, seed)
+    rng = np.random.default_rng(seed + 1)
+    mk = lambda shape: jnp.asarray(rng.standard_normal(shape), dtype)
+    return (mk((6, heads, W)), mk(pk.shape[:2] + (1, W)), table, pos,
+            active)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("heads", [4, 16])
+def test_latent_kernel_equals_its_jnp_path(dtype, heads):
+    q, pool, table, pos, active = a_latent_case(dtype, heads)
+    kw = dict(v_width=V, scale=0.17, active=active)
+    got = pa.paged_latent_attention(q, pool, table, pos, impl="pallas", **kw)
+    want = pa.paged_latent_attention(q, pool, table, pos, impl="jnp", **kw)
+    assert got.dtype == q.dtype and got.shape == (6, heads, V)
+    np.testing.assert_allclose(f32(got)[active], f32(want)[active],
+                               atol=TOL[dtype], rtol=TOL[dtype])
+    assert not f32(got)[~active].any() and not f32(want)[~active].any()
+
+
+def test_latent_read_is_attention_over_the_rows_as_they_lie():
+    """Against the definition written out: every head scores the slot's
+    rows at the stored width and sums their first ``v_width``
+    channels."""
+    q, pool, table, pos, active = a_latent_case(jnp.float32)
+    got = pa.paged_latent_attention(q, pool, table, pos, v_width=V,
+                                    scale=0.17, active=active)
+    bs = pool.shape[1]
+    for s in np.flatnonzero(active):
+        rows = np.concatenate([np.asarray(pool[table[s, j], :, 0])
+                               for j in range(pos[s] // bs + 1)])
+        rows = rows[:pos[s] + 1].astype(np.float64)
+        sc = 0.17 * np.asarray(q[s], np.float64) @ rows.T
+        p = np.exp(sc - sc.max(-1, keepdims=True))
+        want = (p / p.sum(-1, keepdims=True)) @ rows[:, :V]
+        np.testing.assert_allclose(np.asarray(got[s]), want, atol=5e-6)
+
+
+def test_latent_kernel_reads_no_page_behind_a_frontier():
+    """Pages no live slot's frontier reaches are NaN here: neither path
+    lets them through, and a free slot reads nothing."""
+    q, pool, table, pos, active = a_latent_case(jnp.float32)
+    bs = pool.shape[1]
+    reached = {int(table[s, j]) for s in np.flatnonzero(active)
+               for j in range(pos[s] // bs + 1)}
+    poisoned = np.asarray(pool).copy()
+    for page in set(range(pool.shape[0])) - reached:
+        poisoned[page] = np.nan
+    for impl in ("pallas", "jnp"):
+        got = pa.paged_latent_attention(
+            q, jnp.asarray(poisoned), np.where(table >= 0, table, -1), pos,
+            v_width=V, scale=0.17, active=active, impl=impl)
+        want = pa.paged_latent_attention(q, pool, table, pos, v_width=V,
+                                         scale=0.17, active=active,
+                                         impl=impl)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_latent_kernel_wants_whole_lanes():
+    q, pool, table, pos, _ = a_latent_case(jnp.float32)
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+    assert pa._eligible(like(q), like(pool), V)
+    assert not pa._eligible(like(q), like(pool), 96)
+    assert not pa._eligible(like(q[..., :200]), like(pool[..., :200]), V)
+    assert not pa.uses_kernel(like(q), like(pool), V)       # no TPU here
+    with pytest.raises(ValueError, match="kernel-eligible"):
+        pa.paged_latent_attention(q, pool, table, pos, v_width=96,
+                                  scale=1.0, impl="pallas")
+    with pytest.raises(ValueError, match="v_width"):
+        pa.paged_latent_attention(q, pool, table, pos, v_width=W + 1,
+                                  scale=1.0)

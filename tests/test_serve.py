@@ -590,7 +590,8 @@ class TestCensusAndLatencyTier:
         eng.submit(PROMPTS[0], max_new=3, key=jax.random.PRNGKey(1))
         eng.step()
         lowered = eng.lower_step()
-        toks, keys, cache = lowered.out_info
+        toks, keys, cache, counters = lowered.out_info
+        assert counters == {}          # no expert layer, nothing counted
         assert (toks.shape, toks.dtype) == ((size, slots), jnp.int32)
         assert (keys is None) == (temperature == 0.0)
         if keys is not None:
@@ -661,14 +662,14 @@ class TestOneServingBlock:
     @pytest.mark.parametrize("entry", [
         "prefill_tp", "prefill_chunk_tp", "decode_step_tp",
         "decode_step_paged"])
-    def test_entry_points_refuse_a_layer_spec(self, entry):
-        # Called directly (validate_tp is the engine's gate, not
-        # theirs), each serving program answers a per-layer spec with
-        # the walker's CommError under its own name, before it reads a
-        # block.
-        mla = T.LayerSpec(T.MLA(n_heads=2, kv_rank=8, qk_nope=8,
-                                qk_rope=4, v_dim=8))
-        cfg = dataclasses.replace(CFG_ROPE, layers=(mla,) * 2)
+    def test_entry_points_refuse_a_recurrent_mixer(self, entry):
+        # Called directly (validate_tp is the engine's gate too), each
+        # serving program answers a layer the walk does not know, a KDA
+        # mixer, with validate_tp's CommError, by name, before it reads
+        # a block.  (Latent attention and the expert share it serves:
+        # tests/test_openpangu_moe.py.)
+        kda = T.LayerSpec(T.KDA(n_heads=2, head_dim=8))
+        cfg = dataclasses.replace(CFG_ROPE, layers=(kda,) * 2)
         params = T.init_transformer(jax.random.PRNGKey(0), cfg,
                                     dtype=jnp.float32)
         tokens = jnp.asarray([3, 5], jnp.int32)
@@ -688,7 +689,7 @@ class TestOneServingBlock:
                 jnp.arange(12, dtype=jnp.int32).reshape(2, 6), tokens,
                 pos),
         }
-        with pytest.raises(mpi.CommError, match=entry + ": .*per-layer"):
+        with pytest.raises(mpi.CommError, match="KDA mixer.*recurrent"):
             calls[entry]()
 
 

@@ -148,3 +148,91 @@ def test_paged_decode_step_compiles_in_place(one_v5e_chip, as_on_tpu):
     # into the product the TPU compares unrounded accumulators, and the
     # served tokens leave the host-selected ones at every near-tie.
     assert "fusion" in _made_with_shape(text, f"{slots},{cfg.vocab}")
+
+
+def test_latent_read_compiles_at_openpangus_shapes(one_v5e_chip, as_on_tpu):
+    """openPangu-Ultra-MoE's decode read in `serve_latent_4k`: 32 slots,
+    128 query heads on rows of 640 channels whose first 512 are the
+    value, a pool of 2,048 pages of 128 rows.  Mosaic takes the kernel
+    (a page is staged once and serves as key and as value), and its
+    view of the pool leaf is the leaf's own bytes."""
+    like = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_v5e_chip)
+    q, pool = like((32, 128, 640)), like((2048, 128, 1, 640))
+    assert pa.uses_kernel(q, pool, 512)
+    with jax.enable_x64(False):
+        text = jax.jit(lambda *a: pa.paged_latent_attention(
+            *a, v_width=512, scale=192 ** -0.5)).lower(
+                q, pool, like((32, 64), jnp.int32),
+                like((32,), jnp.int32)).compile().as_text()
+    assert pa.KERNEL_NAMES[1] in text and "tpu_custom_call" in text
+    assert set(_made_with_shape(text, "2048,128,1,640")) == {"parameter"}
+    assert set(_made_with_shape(text, "2048,128,640")) == {"bitcast"}
+    assert _made_with_shape(text, "32,128,512") != []
+
+
+def test_openpangus_decode_step_compiles_in_place_at_its_real_size(
+        one_v5e_chip, as_on_tpu):
+    """The serving walk's paged decode step at the configuration's own
+    widths (4.92 B parameters, five layers, the 1.68 GB latent pool),
+    from shapes alone: every pool leaf is aliased to its output, each
+    latent layer reads through the kernel, each expert layer makes its
+    two grouped products, and the step's temporaries stay small beside
+    the 11.5 GB it is handed."""
+    import json
+    import os
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import mpi4torch_tpu as mpi
+    from benchmarks.families import openpangu_moe as fam
+    from mpi4torch_tpu.ops.spmd import run_spmd
+    from mpi4torch_tpu.serve import kv
+    from mpi4torch_tpu.serve.engine import select_rows
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "openpangu-ultra-moe-718b.json")) as f:
+        cfg = json.load(f)
+    tcfg = fam.transformer_config(cfg)
+    mesh = Mesh(np.array([next(iter(one_v5e_chip.device_set))]), ("mpi",))
+    state, rep = NamedSharding(mesh, P("mpi")), NamedSharding(mesh, P())
+    key, dt = jax.random.PRNGKey(0), jnp.bfloat16
+    params = jax.eval_shape(lambda: dict(
+        fam.make_top(key, cfg, dt),
+        blocks=[fam.make_layer(key, cfg, i, dt)
+                for i in range(cfg["num_hidden_layers"])]))
+    assert sum(int(np.prod(a.shape))
+               for a in jax.tree.leaves(params)) == 4_919_140_864
+    slots, bs, nb = 32, 128, 2048
+    pool = jax.eval_shape(lambda: kv.init_kv_pool_tp(tcfg, nb, bs, 1, dt))
+    stacked = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((1,) + a.shape, a.dtype,
+                                       sharding=state), tree)
+    like = lambda shape, d: jax.ShapeDtypeStruct(shape, d, sharding=rep)
+    mine = lambda tree: jax.tree.map(lambda a: a[0], tree)
+
+    def step(shards, pool, table, tokens, pos, active):
+        stats = {}
+        logits, pool = kv.decode_step_paged(
+            tcfg, mine(shards), mine(pool), table, tokens, pos,
+            mpi.COMM_WORLD, active=active, stats=stats)
+        return (*select_rows(logits, None, 0.0, 0), pool, stats)
+
+    with jax.enable_x64(False):
+        compiled = run_spmd(
+            step, mesh=mesh, axis_name="mpi",
+            donate_argnums=(1,)).lower_as_called(
+                stacked(params), stacked(pool),
+                like((slots, nb // slots), jnp.int32),
+                like((slots,), jnp.int32), like((slots,), jnp.int32),
+                like((slots,), bool)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    layers = cfg["num_hidden_layers"]
+    pool_bytes = layers * nb * bs * 640 * 2
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert len(set(re.findall(r"%(mpi4torch_paged_latent_attn[\w.]*) = ",
+                              text))) == layers
+    assert len(set(re.findall(r"%(ragged-dot-none[\w.]*) = ", text))) \
+        == 2 * (layers - cfg["first_k_dense_replace"])
