@@ -270,7 +270,7 @@ chip-smoke:
 # EXECUTE rather than skip; x64 stays off, as everywhere on the chip.
 tpu-test:
 	MPI4TORCH_TPU_REAL_DEVICES=1 python -m pytest tests/test_flash.py -q -rs \
-		-k "Compiled or Pallas or LanePadding"
+		-k "Compiled or Pallas or LanePadding or ExplicitPlans or InteriorEdge"
 
 native:
 	$(MAKE) -C mpi4torch_tpu/_native

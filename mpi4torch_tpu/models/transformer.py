@@ -454,13 +454,14 @@ _MLA_BLOCK = 2048
 def _blockwise_causal_attention(q, k, v, block: int):
     """Causal attention over the whole sequence as a triangle of
     ``block`` x ``block`` calls of the flash block primitive, the partials
-    of a query block merged exactly (``merge_partials``).  For head sizes
-    and lengths at which one call does not fit the kernels: a 192-wide
-    key is staged 256 wide, and at 8,192 of them Mosaic refuses the
-    forward's staging (16.38 MB of scoped VMEM against 16 MB) although
-    ``flash._eligible`` admits it; with 8,192 queries the backward
-    kernels decline (``flash._bwd_eligible``) and the backward would be
-    the tiled jnp path.  At 2,048 all three kernels run."""
+    of a query block merged exactly (``merge_partials``).  Written for
+    head sizes and lengths at which one call did not fit the kernels: a
+    192-wide key is staged 256 wide, and at 8,192 of them Mosaic refused
+    the forward's staging (16.38 MB of scoped VMEM against its default
+    16) while the backward kernels declined 8,192 queries.  At 2,048 all
+    three kernels run.  Since ``flash.tile_plan`` asks Mosaic for what
+    the kernels stage the one call compiles as well; the triangle stays
+    until that call has been measured inside the step."""
     s = q.shape[1]
     if s <= block or s % block:
         return flash_attention(q, k, v, causal=True)

@@ -7,7 +7,12 @@ module provides that block primitive two ways behind one signature:
 * a Pallas TPU kernel (`pltpu`): q tiles stream through VMEM, the KV loop
   runs fused in-core (scores, masking, online softmax, PV accumulation all
   without materializing the (q, k) score matrix in HBM), MXU matmuls in
-  f32 accumulation;
+  f32 accumulation.  The tiles are not constants: :func:`tile_plan`
+  chooses each kernel's q and KV tile from the call's shape (sequence
+  lengths, head size, dtype), wide enough to keep the MXU fed, and counts
+  the VMEM the kernel stages with them; the loops skip the tiles no query
+  attends and mask only those the causal diagonal or the window's edge
+  crosses (:func:`masked_tile_share` counts both without a trace);
 * a pure-jnp fallback with identical semantics for ineligible shapes and
   non-TPU platforms (XLA still fuses it well on CPU; it is the oracle the
   kernel is tested against, tests/test_flash.py).
@@ -33,29 +38,44 @@ and is shared by both forward paths.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 NEG_BIG = -1e30
-_Q_TILE = 128
-_KV_TILE = 128
-# Per-row statistics (lse, and the backward's delta/dlse) cross the
+# The floor of every tile: one MXU pass and one lane tile wide.  The
+# tiles a call actually gets come from :func:`tile_plan`.
+_MIN_TILE = 128
+# Per-row statistics (lse, and the dq kernel's delta - dlse) cross the
 # kernel boundary broadcast along a full lane tile: a (qt,) vector in
 # sublane orientation cannot be stored to / loaded from a lane-oriented
 # row without a relayout Mosaic may reject, so the stats ride as
 # (rows, 128) with the value replicated across lanes — the layout jax's
 # own TPU flash kernel uses (MIN_BLOCK_SIZE in
-# jax/experimental/pallas/ops/tpu/flash_attention.py).
+# jax/experimental/pallas/ops/tpu/flash_attention.py).  The dk/dv kernel
+# works on transposed score tiles and takes the same statistics as lane-
+# oriented rows, replicated over one sublane tile instead.
 _STAT_LANES = 128
+_SUBLANES = 8
 
-
-# The kernel stages the whole KV block in VMEM per grid step (the KV loop
-# runs in-core); cap the staged bytes well under the ~16 MB/core VMEM so
-# q tiles, outputs and accumulators still fit.  Longer local blocks fall
-# back to the jnp path (ring attention keeps per-rank blocks short anyway).
+# The kernels stage one head's whole KV block (forward, dq) or whole
+# q + dO block (dk/dv) in VMEM per grid step: the loop over it runs
+# in-core.  One copy of such a pair may take this much; longer local
+# blocks are chunked (``flash_attention``) or fall back to the jnp path
+# (ring attention keeps per-rank blocks short anyway).
 _KV_VMEM_BUDGET = 8 * 1024 * 1024
+# What Mosaic grants a kernel that asks for nothing, and the most a plan
+# may ask for (the smallest VMEM of a supported chip is 64 MiB; the v5e
+# has 128).  A plan over the default sets ``vmem_limit_bytes`` to its
+# own estimate.
+_DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024
+_VMEM_CAP = 48 * 1024 * 1024
+# Room for what Mosaic allocates for itself beside a kernel's blocks and
+# values: its internal scratch, and a share that grows with the staged
+# blocks (the plain count below was up to 1.4 MiB of 21 short), so a
+# sixteenth on top and this much.
+_MOSAIC_SCRATCH = 1024 * 1024
 
 # Stable names of the three Mosaic kernels (forward, backward dq,
 # backward dk/dv): what a lowered program's ``kernel_name`` attributes
@@ -69,22 +89,148 @@ def _lane_pad(d: int) -> int:
     return 128 * ((d + 127) // 128)
 
 
+# ---------------------------------------------------------------------------
+# The tile plan: which tiles a call gets, and what they cost in VMEM
+# ---------------------------------------------------------------------------
+
+
+class KernelTiles(NamedTuple):
+    """One kernel's tiles and the VMEM bytes it stages with them (blocks
+    double-buffered, the in-core loop's temporaries included)."""
+    q_tile: int
+    kv_tile: int
+    vmem_bytes: int
+
+
+class TilePlan(NamedTuple):
+    """What :func:`tile_plan` decides for one call shape.  ``fwd`` is
+    ``None`` where the forward kernel cannot be launched, ``dq`` / ``dkv``
+    where the backward kernels cannot."""
+    fwd: Optional[KernelTiles]
+    dq: Optional[KernelTiles]
+    dkv: Optional[KernelTiles]
+
+
+def _vmem_bytes(kernel: str, qt: int, kt: int, sq: int, sk: int, dp: int,
+                isz: int) -> int:
+    """VMEM one grid step of ``kernel`` stages at tiles ``(qt, kt)``:
+    every BlockSpec operand twice (Pallas double-buffers them) plus the
+    loop body's float32 temporaries.  The temporaries are counted as
+    whole ``(qt, kt)`` slabs (scores, ``p``, its copy in the operand
+    dtype, the select; backward also ``dP`` and ``ds``), which is what
+    Mosaic materializes for tiles wider than the register file; a
+    float32 operand of a product is split into three bfloat16 pieces
+    under ``HIGHEST``, one and a half slabs more for each score-sized
+    operand (``p``; ``ds``; both in dk/dv).  Held against Mosaic's own
+    count by compiling every planned launch for a described v5e
+    (bfloat16 and float32, head sizes 64 to 256, 512 to 16,384 tokens:
+    the count is never under it, and over it by a few MiB)."""
+    f32 = 4
+    tile = qt * kt * f32
+    split = 3 * tile // 2 if isz == 4 else 0
+    if kernel == "fwd":
+        blocks = (2 * qt * dp * isz + qt * _STAT_LANES * f32   # q, out, lse
+                  + 2 * sk * dp * isz)                         # K, V whole
+        temps = 4 * tile + split + 3 * qt * dp * f32
+    elif kernel == "dq":
+        blocks = (3 * qt * dp * isz + 2 * qt * _STAT_LANES * f32
+                  + 2 * sk * dp * isz)
+        temps = 6 * tile + split + 2 * qt * dp * f32
+    else:
+        # dk/dv leave as float32 partials under GQA: counted so always.
+        blocks = (2 * kt * dp * isz + 2 * kt * dp * f32
+                  + 2 * sq * dp * isz                          # q, dO whole
+                  + 2 * _SUBLANES * sq * f32)                  # lse, dd rows
+        temps = 6 * tile + 2 * split + 4 * kt * dp * f32
+    return (2 * blocks + temps) * 17 // 16 + _MOSAIC_SCRATCH
+
+
+def kernel_tiles(kernel: str, qt: int, kt: int, sq: int, sk: int,
+                 head_dim: int, dtype) -> KernelTiles:
+    """``kernel``'s tiles ``(qt, kt)`` with the VMEM count that goes with
+    them for a call of this shape: what :func:`tile_plan` hands out, and
+    how a test or the chip probe spells an explicit plan."""
+    return KernelTiles(qt, kt, _vmem_bytes(
+        kernel, qt, kt, sq, sk, _lane_pad(head_dim),
+        jnp.dtype(dtype).itemsize))
+
+
+def _tile_candidates(s: int):
+    """Tile lengths a sequence of ``s`` can be cut into, widest first:
+    powers of two from 1,024 down to the floor that divide it, or the
+    whole of a sequence shorter than the floor."""
+    if s <= _MIN_TILE:
+        return (s,)
+    return tuple(t for t in (1024, 512, 256, _MIN_TILE) if s % t == 0)
+
+
+# The widest tiles each kernel is given, (q tile, KV tile): where every
+# shape the benchmark's cells send ran fastest, or within 3% of it, in
+# the chip sweep of all sixteen pairs of 128..1,024 (PERF.md section 6,
+# PR 33; v5e, bf16, head sizes 128 and 192 staged 256, 256 to 16,384
+# tokens).  Wider KV tiles amortize the lane reductions of the online
+# softmax and the carry's rescale; wider q tiles stream more rows
+# through each staged MXU weight tile; past 512 the diagonal's waste
+# (visited over attended pairs is (n + 1) / n for n tiles a side) and
+# the float32 score slab's trips through VMEM cost more than they save.
+# The forward, with its (q tile, head) accumulator rescaled every KV
+# tile, is best at a narrower q tile than the backward kernels.
+_WIDEST_TILES = {"fwd": (256, 512), "dq": (512, 512), "dkv": (512, 512)}
+# float32 operands contract in several MXU passes under ``HIGHEST``: the
+# products dominate, wide tiles gain less (1.7x over the floor in the
+# forward, 1.1x in the backward) and 256 x 256 was the fastest pair of
+# the sixteen in all three kernels (same sweep, float32, head sizes 128
+# and 192).
+_WIDEST_TILES_F32 = (256, 256)
+
+
+def tile_plan(sq: int, sk: int, head_dim: int, dtype, causal: bool = False,
+              window: int = 0) -> TilePlan:
+    """The q tile, the KV tile and the staged VMEM bytes of each of the
+    three kernels for a call of ``sq`` queries against ``sk`` keys, from
+    nothing but the call's shape: pure integer arithmetic, once a trace.
+    Each kernel gets the widest tiles up to ``_WIDEST_TILES`` (float32:
+    ``_WIDEST_TILES_F32``) that divide the sequence (a 256-token prefill
+    gets 256 x 256, a 192-wide head staged 256 wide the same tiles with
+    twice the bytes); a kernel whose
+    whole-sequence operands pass ``_KV_VMEM_BUDGET``, or whose staging
+    would pass ``_VMEM_CAP``, gets none.  ``_eligible``, ``_bwd_eligible``,
+    ``_kv_chunk_for`` and both launches ask it, so the predicate and the
+    launch cannot disagree."""
+    del causal, window      # they narrow a loop's span, not its tiles
+    dp, isz = _lane_pad(head_dim), jnp.dtype(dtype).itemsize
+    q_c, k_c = _tile_candidates(sq), _tile_candidates(sk)
+    if head_dim < 64 or not q_c or not k_c:
+        return TilePlan(None, None, None)
+
+    def pick(kernel: str):
+        if 2 * (sq if kernel == "dkv" else sk) * dp * isz > _KV_VMEM_BUDGET:
+            return None
+        widest = _WIDEST_TILES_F32 if isz == 4 else _WIDEST_TILES[kernel]
+        qt, kt = (next((t for t in cands if t <= most), cands[-1])
+                  for cands, most in zip((q_c, k_c), widest))
+        tiles = kernel_tiles(kernel, qt, kt, sq, sk, head_dim, dtype)
+        return tiles if tiles.vmem_bytes <= _VMEM_CAP else None
+
+    fwd = pick("fwd")
+    if fwd is None:
+        return TilePlan(None, None, None)
+    dq, dkv = pick("dq"), pick("dkv")
+    if dq is None or dkv is None:
+        dq = dkv = None
+    return TilePlan(fwd, dq, dkv)
+
+
 def _eligible(q, k) -> bool:
-    """Shapes the TPU kernel handles: sequence lengths divisible by their
-    tile and the staged KV within the VMEM budget.  head_dim need not be
-    a lane multiple — the kernel zero-pads it to the next multiple of 128
-    (d=64/96 pay ≤2x staged bytes, still far cheaper than the jnp path's
-    HBM score matrix).  d < 64 would waste >2x MXU/VMEM on padding, so
-    those shapes take the jnp fallback (XLA fuses them fine)."""
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    if d < 64:
-        return False
-    if 2 * sk * _lane_pad(d) * jnp.dtype(k.dtype).itemsize > _KV_VMEM_BUDGET:
-        return False
-    qt = min(_Q_TILE, sq)
-    kt = min(_KV_TILE, sk)
-    return sq % qt == 0 and sk % kt == 0
+    """Shapes the TPU kernel handles: those :func:`tile_plan` has forward
+    tiles for — sequence lengths a tile divides and one head's staged KV
+    within the budget.  head_dim need not be a lane multiple — the
+    kernel zero-pads it to the next multiple of 128 (d=64/96 pay ≤2x
+    staged bytes, still far cheaper than the jnp path's HBM score
+    matrix).  d < 64 would waste >2x MXU/VMEM on padding, so those
+    shapes take the jnp fallback (XLA fuses them fine)."""
+    return tile_plan(q.shape[1], k.shape[1], q.shape[3], k.dtype).fwd \
+        is not None
 
 
 def _on_tpu() -> bool:
@@ -208,100 +354,196 @@ def _jnp_block(q, k, v, q_off, kv_off, causal: bool, window: int = 0):
 # ---------------------------------------------------------------------------
 
 
-def _causal_n_live(qoff, kvoff, qi, qt: int, kv_tile: int, n_tiles: int):
-    """Number of leading KV tiles that can contain unmasked positions for
-    q tile ``qi``: tiles whose first position <= this q tile's LAST
-    position (q_hi).  Skipped tiles are exactly neutral in both the
-    online-softmax carry and the gradients (p is where-masked to zero),
-    so cutting the loop at the diagonal halves causal work without
-    changing any output bit.  Traced-scalar offsets (rank-symbolic under
-    SPMD) are fine: the bound feeds a dynamic fori_loop."""
-    q_hi = qoff + (qi + 1) * qt - 1
-    return jnp.clip((q_hi - kvoff) // kv_tile + 1, 0, n_tiles)
+def _clip(x, lo, hi):
+    """``clip`` on Python ints (the plan's own arithmetic, no trace) or
+    on traced scalars (inside a kernel)."""
+    if all(isinstance(t, int) for t in (x, lo, hi)):
+        return min(max(x, lo), hi)
+    return jnp.minimum(jnp.maximum(x, lo), hi)
 
 
-def _window_start_tile(qoff, kvoff, qi, qt: int, kv_tile: int,
-                       window: int, n_tiles: int):
-    """First KV tile that can contain in-window positions for q tile
-    ``qi`` under a sliding window: the tile holding position
-    ``q_lo - window + 1`` (this q tile's FIRST query's earliest visible
-    key).  Earlier tiles are fully below every query's window — skipping
-    them makes windowed attention cost O(window), not O(seq), per query
-    tile.  Same exact-neutrality argument as :func:`_causal_n_live`."""
-    q_lo = qoff + qi * qt
-    return jnp.clip((q_lo - window + 1 - kvoff) // kv_tile, 0, n_tiles)
+def _kv_loop_bounds(q_lo, kv_off, qt: int, kt: int, n_kv: int,
+                    causal: bool, window: int):
+    """KV tiles the forward and dq kernels visit for the q tile whose
+    first query sits at ``q_lo``, as ``(a, b, c, d)``: tiles ``[a, d)``
+    can hold an attended pair, tiles ``[b, c)`` hold nothing else.
+
+    * ``d``: tiles whose first key <= the tile's LAST query (the causal
+      diagonal's outer bound); ``a``: the tile holding the FIRST query's
+      earliest visible key ``q_lo - window + 1`` (the window frontier's
+      outer bound).  Tiles outside ``[a, d)`` are exactly neutral in the
+      online-softmax carry and in the gradients (p is where-masked to
+      zero), so skipping them changes no output bit and makes windowed
+      attention cost O(window), not O(seq), per q tile.
+    * ``c``: tiles whose LAST key <= the FIRST query, ``b``: tiles whose
+      first key >= the LAST query's window start — the same arithmetic
+      on the tile's other corner.  On ``[b, c)`` every pair is attended:
+      the mask is all true, a ``where`` on it the identity, so those
+      tiles run without iota, compare or select.
+
+    Offsets may be traced scalars (rank-symbolic under SPMD): the bounds
+    feed dynamic fori_loops.  On Python ints this is plain arithmetic,
+    which is how :func:`masked_tile_share` counts without a trace."""
+    if not causal:
+        return 0, 0, n_kv, n_kv
+    q_hi = q_lo + qt - 1
+    d = _clip((q_hi - kv_off) // kt + 1, 0, n_kv)
+    c = (q_lo - kv_off + 1) // kt
+    if window:
+        a = _clip((q_lo - window + 1 - kv_off) // kt, 0, n_kv)
+        b = _clip(-((kv_off - (q_hi - window + 1)) // kt), a, d)
+    else:
+        a = b = 0
+    return a, b, _clip(c, b, d), d
 
 
-def _parallel_grid_params():
-    """Shared CompilerParams for all three kernels: both grid dims are
+def _q_loop_bounds(kv_lo, q_off, qt: int, kt: int, n_q: int,
+                   causal: bool, window: int):
+    """The mirror cuts of the dk/dv kernel: q tiles ``[a, d)`` can hold
+    a pair attended by the KV tile whose first key sits at ``kv_lo``,
+    q tiles ``[b, c)`` hold nothing else.
+
+    * ``a``: the first q tile whose last query reaches ``kv_lo`` (the
+      diagonal); ``d``: one past the tile of the farthest query still
+      inside any of this KV tile's windows, ``kv_hi + window - 1``.
+    * ``b``: the first q tile whose FIRST query >= the tile's LAST key;
+      ``c``: q tiles whose LAST query's window still starts at or before
+      the tile's FIRST key."""
+    if not causal:
+        return 0, 0, n_q, n_q
+    kv_hi = kv_lo + kt - 1
+    a = _clip((kv_lo - q_off) // qt, 0, n_q)
+    if window:
+        d = _clip((kv_hi + window - 1 - q_off) // qt + 1, 0, n_q)
+        c = (kv_lo + window - q_off) // qt
+    else:
+        c = d = n_q
+    b = _clip(-((q_off - kv_hi) // qt), a, d)
+    return a, b, _clip(c, b, d), d
+
+
+def masked_tile_share(sq: int, sk: int, tiles: KernelTiles, causal: bool,
+                      window: int = 0, q_offset: int = 0,
+                      kv_offset: int = 0, over: str = "kv"):
+    """``(visited, masked)``: the tile pairs a kernel's loops visit for
+    a call at these (integer) offsets, and how many of them take the
+    mask — the kernels' own bounds on Python ints.  ``over="kv"`` is the
+    forward and dq kernels' loop (a q tile walks KV tiles), ``"q"`` the
+    dk/dv kernel's."""
+    qt, kt = tiles.q_tile, tiles.kv_tile
+    n_q, n_kv = sq // qt, sk // kt
+    visited = masked = 0
+    for t in range(n_q if over == "kv" else n_kv):
+        if over == "kv":
+            a, b, c, d = _kv_loop_bounds(q_offset + t * qt, kv_offset, qt,
+                                         kt, n_kv, causal, window)
+        else:
+            a, b, c, d = _q_loop_bounds(kv_offset + t * kt, q_offset, qt,
+                                        kt, n_q, causal, window)
+        visited += d - a
+        masked += (b - a) + (d - c)
+    return visited, masked
+
+
+def _split_loop(bounds, body, carry):
+    """Run ``body(j, carry, masked)`` over the tiles ``[a, d)`` of
+    ``bounds``: the interior ``[b, c)`` unmasked in one loop, the two
+    edges ``[a, b)`` and ``[c, d)`` masked in another (one traced copy of
+    each body).  Every tile is visited once; the order differs from a
+    single sweep only in the order of float32 sums."""
+    a, b, c, d = bounds
+    carry = jax.lax.fori_loop(b, c, lambda j, x: body(j, x, False), carry)
+    low, n_edge = b - a, (b - a) + (d - c)
+    if isinstance(n_edge, int) and n_edge == 0:
+        return carry                    # no mask at all (not causal)
+
+    def edge(t, x):
+        return body(jnp.where(t < low, a + t, c + (t - low)), x, True)
+
+    return jax.lax.fori_loop(0, n_edge, edge, carry)
+
+
+def _compiler_params(vmem_bytes: int):
+    """CompilerParams shared by all three kernels: both grid dims are
     fully independent (each step writes a distinct output block; all
     reduction lives in in-core fori_loops), so Mosaic may pipeline the
-    grid and split it across cores on megacore parts."""
+    grid and split it across cores on megacore parts.  A plan that needs
+    more than Mosaic's default scoped VMEM asks for its own estimate."""
     from jax.experimental.pallas import tpu as pltpu
 
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel"))
+        dimension_semantics=("parallel", "parallel"),
+        vmem_limit_bytes=(vmem_bytes if vmem_bytes > _DEFAULT_SCOPED_VMEM
+                          else None))
+
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T: contract the minor dims
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+
+
+def _tile_mask(q_lo, kv_lo, qt: int, kt: int, window: int,
+               transposed: bool = False):
+    """Causal (and window) mask of the tile whose first query and key
+    sit at global positions ``q_lo`` / ``kv_lo``: ``(qt, kt)``, or
+    ``(kt, qt)`` for the dk/dv kernel's transposed tiles."""
+    i32 = jnp.int32
+    q_shape, kv_shape = ((1, qt), (kt, 1)) if transposed \
+        else ((qt, 1), (1, kt))
+    q_pos = q_lo + jax.lax.broadcasted_iota(i32, q_shape, int(transposed))
+    kv_pos = kv_lo + jax.lax.broadcasted_iota(i32, kv_shape,
+                                              int(not transposed))
+    mask = q_pos >= kv_pos
+    if window:
+        mask &= (q_pos - kv_pos) < window
+    return mask
 
 
 def _fwd_kernel(qoff_ref, kvoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                *, causal: bool, kv_tile: int, true_d: int,
-                window: int = 0):
+                *, causal: bool, kv_tile: int, window: int = 0):
     from jax.experimental import pallas as pl
 
     f32 = jnp.float32
-    i32 = jnp.int32
     qt, d = q_ref.shape[1], q_ref.shape[2]
-    sk = k_ref.shape[1]
-    n_kv = sk // kv_tile
-    # d is the lane-padded staging width; the softmax scale is the model's
-    # true head_dim (padded columns are zero and change no dot product).
-    scale = 1.0 / jnp.sqrt(jnp.asarray(true_d, f32))
+    n_kv = k_ref.shape[1] // kv_tile
 
     # Operands stay in their input dtype for the MXU dots (bf16 inputs
     # run at the MXU's bf16 rate; an up-front astype(f32) would force
     # f32-rate multiplies) — accumulation is f32 via
-    # preferred_element_type, and the scale is applied to the f32 scores.
-    # f32 operands pin the f32-exact contract (see dot_precision).
+    # preferred_element_type.  q arrives already multiplied by the
+    # softmax scale (once a call, in the layout change outside), so no
+    # score tile pays for it.  f32 operands pin the f32-exact contract
+    # (see dot_precision).
     prec = dot_precision(q_ref.dtype)
     qb = q_ref[0]                                           # (QT, D)
-    qi = pl.program_id(1)
-    q_pos = (qoff_ref[0, 0] + qi * qt
-             + jax.lax.broadcasted_iota(i32, (qt, 1), 0))    # (QT, 1)
+    q_lo = qoff_ref[0, 0] + pl.program_id(1) * qt
+    kv_off = kvoff_ref[0, 0]
 
-    def body(j, carry):
+    def body(j, carry, masked: bool):
         m, l, acc = carry
-        kb = k_ref[0, pl.ds(j * kv_tile, kv_tile), :]
-        vb = v_ref[0, pl.ds(j * kv_tile, kv_tile), :]
-        s = jax.lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=f32, precision=prec) * scale  # (QT, KT)
-        if causal:
-            kv_pos = (kvoff_ref[0, 0] + j * kv_tile
-                      + jax.lax.broadcasted_iota(i32, (1, kv_tile), 1))
-            mask = q_pos >= kv_pos                           # (QT, KT)
-            if window:
-                mask &= (q_pos - kv_pos) < window
+        rows = pl.ds(pl.multiple_of(j * kv_tile, kv_tile), kv_tile)
+        kb = k_ref[0, rows, :]
+        vb = v_ref[0, rows, :]
+        s = jax.lax.dot_general(qb, kb, _NT, preferred_element_type=f32,
+                                precision=prec)             # (QT, KT)
+        if masked:
+            mask = _tile_mask(q_lo, kv_off + j * kv_tile, qt, kv_tile,
+                              window)
             s = jnp.where(mask, s, NEG_BIG)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
-        if causal:
+        if masked:
             p = jnp.where(mask, p, 0.0)
         corr = jnp.exp(m - m_new)
         l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc = acc * corr + jax.lax.dot_general(
-            p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=f32, precision=prec)
+            p.astype(vb.dtype), vb, _NN, preferred_element_type=f32,
+            precision=prec)
         return m_new, l, acc
 
-    m0 = jnp.full((qt, 1), NEG_BIG, f32)
-    l0 = jnp.zeros((qt, 1), f32)
-    acc0 = jnp.zeros((qt, d), f32)
-    n_live = (_causal_n_live(qoff_ref[0, 0], kvoff_ref[0, 0], qi, qt,
-                             kv_tile, n_kv) if causal else n_kv)
-    j0 = (_window_start_tile(qoff_ref[0, 0], kvoff_ref[0, 0], qi, qt,
-                             kv_tile, window, n_kv)
-          if (causal and window) else 0)
-    m, l, acc = jax.lax.fori_loop(j0, n_live, body, (m0, l0, acc0))
+    m, l, acc = _split_loop(
+        _kv_loop_bounds(q_lo, kv_off, qt, kv_tile, n_kv, causal, window),
+        body, (jnp.full((qt, 1), NEG_BIG, f32), jnp.zeros((qt, 1), f32),
+               jnp.zeros((qt, d), f32)))
 
     nonzero = l > 0
     safe_l = jnp.where(nonzero, l, 1.0)
@@ -317,41 +559,59 @@ def _fwd_kernel(qoff_ref, kvoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                                           (0, 1))
 
 
+def _to_bh(x, dp: int, scale=None):
+    """(b, s, nh, d) -> (b*nh, s, dp): heads to the front, head_dim
+    zero-padded to the lane width (zeros leave every dot product
+    unchanged, so only an output slice is needed to undo it), and for q
+    the softmax scale folded in (float32 product, rounded once to the
+    operand dtype) — all one layout-changing copy."""
+    b, s, nh, d = x.shape
+    if scale is not None:
+        x = (x.astype(jnp.float32) * scale).astype(x.dtype)
+    x = x.transpose(0, 2, 1, 3).reshape(b * nh, s, d)
+    if dp != d:
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, dp - d)))
+    return x
+
+
+def _from_bh(x, b: int, d: int):
+    """(b*nh, s, dp) -> (b, s, nh, d)."""
+    bh, s, _ = x.shape
+    return x[:, :, :d].reshape(b, bh // b, s, d).transpose(0, 2, 1, 3)
+
+
+def _offsets(q_off, kv_off):
+    return (jnp.asarray(q_off, jnp.int32).reshape(1, 1),
+            jnp.asarray(kv_off, jnp.int32).reshape(1, 1))
+
+
+def _softmax_scale(d: int) -> float:
+    """1/sqrt(head_dim) of the model's TRUE head_dim (padded columns are
+    zero and change no dot product)."""
+    return float(d) ** -0.5
+
+
 def _pallas_block(q, k, v, q_off, kv_off, causal: bool, interpret: bool,
-                  window: int = 0):
+                  window: int = 0, tiles: Optional[KernelTiles] = None):
+    """The forward launch.  ``tiles`` overrides the plan's (tests and the
+    chip probe sweep explicit plans; no caller of the library does)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, sq, h, d = q.shape
     sk, h_kv = k.shape[1], k.shape[2]
-    g = _gqa_groups(q, k)
     bh = b * h
-    qt = min(_Q_TILE, sq)
-    kt = min(_KV_TILE, sk)
     dp = _lane_pad(d)
+    qt, kt, vmem_bytes = tiles or tile_plan(sq, sk, d, q.dtype, causal,
+                                            window).fwd
+    kv_row = functools.partial(_kv_row, h=h, h_kv=h_kv, g=_gqa_groups(q, k))
 
-    def to_bh(x, s, nh):
-        x = x.transpose(0, 2, 1, 3).reshape(b * nh, s, d)
-        if dp != d:
-            # Zero-pad head_dim to the lane width.  Zeros leave every dot
-            # product unchanged (scores and PV columns), so only the
-            # output slice below is needed to undo it.
-            x = jnp.pad(x, ((0, 0), (0, 0), (0, dp - d)))
-        return x
-
-    kv_row = functools.partial(_kv_row, h=h, h_kv=h_kv, g=g)
-
-    qb = to_bh(q, sq, h)
-    kb, vb = to_bh(k, sk, h_kv), to_bh(v, sk, h_kv)
-    qoff = jnp.asarray(q_off, jnp.int32).reshape(1, 1)
-    kvoff = jnp.asarray(kv_off, jnp.int32).reshape(1, 1)
-
-    grid = (bh, sq // qt)
+    qoff, kvoff = _offsets(q_off, kv_off)
     smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
     vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, kv_tile=kt,
-                          true_d=d, window=window),
+                          window=window),
         out_shape=(
             jax.ShapeDtypeStruct((bh, sq, dp), q.dtype),
             # lse rides lane-broadcast as (bh, sq, _STAT_LANES): Mosaic
@@ -365,7 +625,7 @@ def _pallas_block(q, k, v, q_off, kv_off, causal: bool, interpret: bool,
             # stats vector (see _STAT_LANES).
             jax.ShapeDtypeStruct((bh, sq, _STAT_LANES), jnp.float32),
         ),
-        grid=grid,
+        grid=(bh, sq // qt),
         in_specs=[
             smem((1, 1), lambda i, j: (0, 0)),
             smem((1, 1), lambda i, j: (0, 0)),
@@ -377,16 +637,14 @@ def _pallas_block(q, k, v, q_off, kv_off, causal: bool, interpret: bool,
             vmem((1, qt, dp), lambda i, j: (i, j, 0)),
             vmem((1, qt, _STAT_LANES), lambda i, j: (i, j, 0)),
         ),
-        compiler_params=_parallel_grid_params(),
+        compiler_params=_compiler_params(vmem_bytes),
         interpret=interpret,
         name=KERNEL_NAMES[0],
-    )(qoff, kvoff, qb, kb, vb)
+    )(qoff, kvoff, _to_bh(q, dp, _softmax_scale(d)), _to_bh(k, dp),
+      _to_bh(v, dp))
 
-    if dp != d:
-        out = out[:, :, :d]
-    out = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
     lse = lse[:, :, 0].reshape(b, h, sq).transpose(0, 2, 1)
-    return out, lse
+    return _from_bh(out, b, d), lse
 
 
 # ---------------------------------------------------------------------------
@@ -400,160 +658,125 @@ def _pallas_block(q, k, v, q_off, kv_off, causal: bool, interpret: bool,
 # accumulates dk/dv).  Both recompute p = exp(s - lse) from the forward
 # residuals — scores never hit HBM in either direction.  The jnp
 # backward below stays as the oracle (tests/test_flash.py).
-
-
-def _stat_tile(x, width: int):
-    """Resize a (rows, _STAT_LANES) lane-broadcast statistic to (rows,
-    width) without relayout.  Every lane holds the same value, so
-    narrower widths are a leading-lane slice and wider widths (KV tiles
-    above 128 — the tunable `_KV_TILE`) are a relayout-free
-    lane-tiling concat of the
-    already-broadcast slab."""
-    if width == _STAT_LANES:
-        return x
-    if width < _STAT_LANES:
-        return x[:, :width]
-    reps = -(-width // _STAT_LANES)
-    return jnp.concatenate([x] * reps, axis=1)[:, :width]
-
-
-def _bwd_p_ds(q_t, k_t, v_t, do_t, lse_t, dd_t, q_pos, kv_pos,
-              causal: bool, scale, window, prec):
-    """Recompute p and ds for one (q-tile, kv-tile) pair, in-kernel.
-
-    ``lse`` and ``dd = delta - dlse`` arrive as (QT, KT) lane-broadcast
-    tiles (see _STAT_LANES); fusing delta and dlse into one stat array
-    saves a third of the staged stat VMEM (they only ever appear as this
-    difference: ds = p*(dp - delta + dlse)).  The dlse term is live under
-    ring attention, whose merge consumes lse.  Fully-masked rows have
-    lse = NEG_BIG, making the raw exp() garbage; the mask ``where``
-    zeroes those entries (same order of operations as the jnp oracle)."""
-    f32 = jnp.float32
-    # Native-dtype MXU operands, f32 accumulation (see _fwd_kernel).
-    s = jax.lax.dot_general(q_t, k_t, (((1,), (1,)), ((), ())),
-                            preferred_element_type=f32,
-                            precision=prec) * scale               # (QT, KT)
-    p = jnp.exp(s - lse_t)
-    if causal:
-        mask = q_pos >= kv_pos                                    # (QT, KT)
-        if window:
-            mask &= (q_pos - kv_pos) < window
-        p = jnp.where(mask, p, 0.0)
-    dp_ = jax.lax.dot_general(do_t, v_t, (((1,), (1,)), ((), ())),
-                              preferred_element_type=f32, precision=prec)
-    ds = p * (dp_ - dd_t)
-    return p, ds
+#
+# ``lse`` and ``dd = delta - dlse`` are the only row statistics: fusing
+# delta and dlse into one array saves a third of the staged stat VMEM
+# (they only ever appear as this difference: ds = p*(dp - delta + dlse)).
+# The dlse term is live under ring attention, whose merge consumes lse.
+# Fully-masked rows have lse = NEG_BIG, making the raw exp() garbage;
+# the mask ``where`` zeroes those entries (same order of operations as
+# the jnp oracle) — and such rows lie on edge tiles only: an interior
+# tile attends every pair, so each of its rows has a finite lse.
 
 
 def _bwd_dq_kernel(qoff_ref, kvoff_ref, q_ref, k_ref, v_ref, do_ref,
                    lse_ref, dd_ref, dq_ref,
-                   *, causal: bool, kv_tile: int, true_d: int,
+                   *, causal: bool, kv_tile: int, scale: float,
                    window: int = 0):
     from jax.experimental import pallas as pl
 
-    f32, i32 = jnp.float32, jnp.int32
+    f32 = jnp.float32
     qt, d = q_ref.shape[1], q_ref.shape[2]
-    sk = k_ref.shape[1]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(true_d, f32))
+    n_kv = k_ref.shape[1] // kv_tile
     prec = dot_precision(q_ref.dtype)
 
-    qb = q_ref[0]
+    qb = q_ref[0]                    # already times the softmax scale
     dob = do_ref[0]
-    lse_t = _stat_tile(lse_ref[0], kv_tile)
-    dd_t = _stat_tile(dd_ref[0], kv_tile)
-    qi = pl.program_id(1)
-    q_pos = (qoff_ref[0, 0] + qi * qt
-             + jax.lax.broadcasted_iota(i32, (qt, 1), 0))
+    # One lane of the broadcast statistics, as a (QT, 1) column: the
+    # subtractions below broadcast it along lanes, whatever the KV tile.
+    lse_c = lse_ref[0][:, :1]
+    dd_c = dd_ref[0][:, :1]
+    q_lo = qoff_ref[0, 0] + pl.program_id(1) * qt
+    kv_off = kvoff_ref[0, 0]
 
-    def body(j, dq):
-        kb = k_ref[0, pl.ds(j * kv_tile, kv_tile), :]
-        vb = v_ref[0, pl.ds(j * kv_tile, kv_tile), :]
-        kv_pos = (kvoff_ref[0, 0] + j * kv_tile
-                  + jax.lax.broadcasted_iota(i32, (1, kv_tile), 1))
-        _, ds = _bwd_p_ds(qb, kb, vb, dob, lse_t, dd_t,
-                          q_pos, kv_pos, causal, scale, window, prec)
+    def body(j, dq, masked: bool):
+        rows = pl.ds(pl.multiple_of(j * kv_tile, kv_tile), kv_tile)
+        kb = k_ref[0, rows, :]
+        vb = v_ref[0, rows, :]
+        # Native-dtype MXU operands, f32 accumulation (see _fwd_kernel).
+        s = jax.lax.dot_general(qb, kb, _NT, preferred_element_type=f32,
+                                precision=prec)             # (QT, KT)
+        p = jnp.exp(s - lse_c)
+        if masked:
+            p = jnp.where(_tile_mask(q_lo, kv_off + j * kv_tile, qt,
+                                     kv_tile, window), p, 0.0)
+        dp_ = jax.lax.dot_general(dob, vb, _NT, preferred_element_type=f32,
+                                  precision=prec)
+        ds = p * (dp_ - dd_c)
         return dq + jax.lax.dot_general(
-            ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
-            preferred_element_type=f32, precision=prec) * scale
+            ds.astype(kb.dtype), kb, _NN, preferred_element_type=f32,
+            precision=prec)
 
-    n_kv = sk // kv_tile
-    n_live = (_causal_n_live(qoff_ref[0, 0], kvoff_ref[0, 0], qi, qt,
-                             kv_tile, n_kv) if causal else n_kv)
-    j0 = (_window_start_tile(qoff_ref[0, 0], kvoff_ref[0, 0], qi, qt,
-                             kv_tile, window, n_kv)
-          if (causal and window) else 0)
-    dq = jax.lax.fori_loop(j0, n_live, body, jnp.zeros((qt, d), f32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    dq = _split_loop(
+        _kv_loop_bounds(q_lo, kv_off, qt, kv_tile, n_kv, causal, window),
+        body, jnp.zeros((qt, d), f32))
+    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(qoff_ref, kvoff_ref, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, dd_ref, dk_ref, dv_ref,
-                    *, causal: bool, q_tile: int, true_d: int,
-                    window: int = 0):
+                    *, causal: bool, q_tile: int, window: int = 0):
+    """dk/dv of one KV tile, on TRANSPOSED score tiles ``(KT, QT)``:
+    ``k q^T`` and ``v dO^T`` contract minor dims like the forward's
+    ``q k^T``, and ``p^T dO`` / ``ds^T q`` are then plain products — no
+    tile is transposed on the way to the MXU.  The row statistics arrive
+    lane-oriented, ``(q tiles, 8, QT)``, one q tile's row replicated
+    over a sublane tile, and broadcast along sublanes."""
     from jax.experimental import pallas as pl
 
-    f32, i32 = jnp.float32, jnp.int32
+    f32 = jnp.float32
     kt, d = k_ref.shape[1], k_ref.shape[2]
-    sq = q_ref.shape[1]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(true_d, f32))
+    n_q = q_ref.shape[1] // q_tile
     prec = dot_precision(q_ref.dtype)
 
     kb = k_ref[0]
     vb = v_ref[0]
-    ki = pl.program_id(1)
-    kv_pos = (kvoff_ref[0, 0] + ki * kt
-              + jax.lax.broadcasted_iota(i32, (1, kt), 1))
+    kv_lo = kvoff_ref[0, 0] + pl.program_id(1) * kt
+    q_off = qoff_ref[0, 0]
 
-    def body(i, carry):
+    def body(i, carry, masked: bool):
         dk, dv = carry
-        qs = pl.ds(i * q_tile, q_tile)
-        q_t = q_ref[0, qs, :]
-        do_t = do_ref[0, qs, :]
-        lse_t = _stat_tile(lse_ref[0, qs, :], kt)
-        dd_t = _stat_tile(dd_ref[0, qs, :], kt)
-        q_pos = (qoff_ref[0, 0] + i * q_tile
-                 + jax.lax.broadcasted_iota(i32, (q_tile, 1), 0))
-        p, ds = _bwd_p_ds(q_t, kb, vb, do_t, lse_t, dd_t,
-                          q_pos, kv_pos, causal, scale, window, prec)
+        rows = pl.ds(pl.multiple_of(i * q_tile, q_tile), q_tile)
+        q_t = q_ref[0, rows, :]      # already times the softmax scale
+        do_t = do_ref[0, rows, :]
+        s_t = jax.lax.dot_general(kb, q_t, _NT, preferred_element_type=f32,
+                                  precision=prec)           # (KT, QT)
+        p_t = jnp.exp(s_t - lse_ref[0, i][:1])
+        if masked:
+            p_t = jnp.where(_tile_mask(q_off + i * q_tile, kv_lo, q_tile,
+                                       kt, window, transposed=True),
+                            p_t, 0.0)
         dv = dv + jax.lax.dot_general(
-            p.astype(do_t.dtype), do_t, (((0,), (0,)), ((), ())),
-            preferred_element_type=f32, precision=prec)    # (KT, D)
+            p_t.astype(do_t.dtype), do_t, _NN, preferred_element_type=f32,
+            precision=prec)                                 # (KT, D)
+        dp_t = jax.lax.dot_general(vb, do_t, _NT,
+                                   preferred_element_type=f32,
+                                   precision=prec)
+        ds_t = p_t * (dp_t - dd_ref[0, i][:1])
+        # q carries the softmax scale, so this is dk whole.
         dk = dk + jax.lax.dot_general(
-            ds.astype(q_t.dtype), q_t, (((0,), (0,)), ((), ())),
-            preferred_element_type=f32, precision=prec) * scale
+            ds_t.astype(q_t.dtype), q_t, _NN, preferred_element_type=f32,
+            precision=prec)
         return dk, dv
 
-    dk0 = jnp.zeros((kt, d), f32)
-    n_q = sq // q_tile
-    if causal:
-        # Mirror cut: q tile i contributes iff its last position reaches
-        # this KV block's first position — start the loop at the
-        # diagonal.  i_min = floor((kv_lo - qoff) / q_tile) (clipped), the
-        # first tile whose max q_pos >= kv_lo.
-        kv_lo = kvoff_ref[0, 0] + ki * kt
-        i_start = jnp.clip((kv_lo - qoff_ref[0, 0]) // q_tile, 0, n_q)
-    else:
-        i_start = 0
-    if causal and window:
-        # Window mirror cut: the farthest query still inside any of this
-        # KV tile's windows sits at kv_hi + window - 1 — stop after its
-        # tile.
-        kv_hi = kvoff_ref[0, 0] + (ki + 1) * kt - 1
-        i_end = jnp.clip((kv_hi + window - 1 - qoff_ref[0, 0]) // q_tile
-                         + 1, 0, n_q)
-    else:
-        i_end = n_q
-    dk, dv = jax.lax.fori_loop(i_start, i_end, body, (dk0, dk0))
+    zero = jnp.zeros((kt, d), f32)
+    dk, dv = _split_loop(
+        _q_loop_bounds(kv_lo, q_off, q_tile, kt, n_q, causal, window),
+        body, (zero, zero))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
 def _pallas_bwd(q, k, v, do, lse, dd, q_off, kv_off,
-                causal: bool, interpret: bool, window: int = 0):
-    """Fused dq/dk/dv.  Layout/staging mirrors ``_pallas_block``; the row
-    statistics (lse, delta, dlse) ride lane-broadcast as
-    (bh, sq, _STAT_LANES) f32 — the same Mosaic-proven scheme as the
-    forward's lse output."""
+                causal: bool, interpret: bool, window: int = 0,
+                tiles_dq: Optional[KernelTiles] = None,
+                tiles_dkv: Optional[KernelTiles] = None):
+    """Fused dq/dk/dv, two launches.  Layout/staging mirrors
+    ``_pallas_block``; the row statistics (lse, delta - dlse) ride
+    lane-broadcast as (bh, sq, _STAT_LANES) f32 into the dq kernel — the
+    same Mosaic-proven scheme as the forward's lse output — and as
+    lane-oriented rows into the dk/dv kernel.  ``tiles_*`` override the
+    plan's, for tests and the chip probe."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -561,34 +784,34 @@ def _pallas_bwd(q, k, v, do, lse, dd, q_off, kv_off,
     sk, h_kv = k.shape[1], k.shape[2]
     g = _gqa_groups(q, k)
     bh = b * h
-    qt = min(_Q_TILE, sq)
-    kt = min(_KV_TILE, sk)
     dp = _lane_pad(d)
-
-    def to_bh(x, s, nh):
-        x = x.transpose(0, 2, 1, 3).reshape(b * nh, s, d)
-        if dp != d:
-            x = jnp.pad(x, ((0, 0), (0, 0), (0, dp - d)))
-        return x
-
+    scale = _softmax_scale(d)
+    plan = tile_plan(sq, sk, d, q.dtype, causal, window)
+    qt, kt, vmem_dq = tiles_dq or plan.dq
+    qt2, kt2, vmem_dkv = tiles_dkv or plan.dkv
     kv_row = functools.partial(_kv_row, h=h, h_kv=h_kv, g=g)
 
-    def rows(x):  # (b, sq, h) -> (bh, sq, _STAT_LANES) f32, lane-broadcast
-        x = x.astype(jnp.float32).transpose(0, 2, 1).reshape(bh, sq)
+    def stats(x):                       # (b, sq, h) -> (bh, sq) f32
+        return x.astype(jnp.float32).transpose(0, 2, 1).reshape(bh, sq)
+
+    def lanes(x):     # -> (bh, sq, _STAT_LANES), lane-broadcast
         return jnp.broadcast_to(x[..., None], (bh, sq, _STAT_LANES))
 
-    qb, dob = to_bh(q, sq, h), to_bh(do, sq, h)
-    kb, vb = to_bh(k, sk, h_kv), to_bh(v, sk, h_kv)
-    lse_r, dd_r = rows(lse), rows(dd)
-    qoff = jnp.asarray(q_off, jnp.int32).reshape(1, 1)
-    kvoff = jnp.asarray(kv_off, jnp.int32).reshape(1, 1)
+    def rows(x):      # -> (bh, q tiles, 8, QT), sublane-broadcast
+        return jnp.broadcast_to(x.reshape(bh, sq // qt2, 1, qt2),
+                                (bh, sq // qt2, _SUBLANES, qt2))
+
+    qb, dob = _to_bh(q, dp, scale), _to_bh(do, dp)
+    kb, vb = _to_bh(k, dp), _to_bh(v, dp)
+    lse_s, dd_s = stats(lse), stats(dd)
+    qoff, kvoff = _offsets(q_off, kv_off)
 
     smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
     vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal=causal, kv_tile=kt,
-                          true_d=d, window=window),
+                          scale=scale, window=window),
         out_shape=jax.ShapeDtypeStruct((bh, sq, dp), q.dtype),
         grid=(bh, sq // qt),
         in_specs=[
@@ -602,10 +825,10 @@ def _pallas_bwd(q, k, v, do, lse, dd, q_off, kv_off,
             vmem((1, qt, _STAT_LANES), lambda i, j: (i, j, 0)),
         ],
         out_specs=vmem((1, qt, dp), lambda i, j: (i, j, 0)),
-        compiler_params=_parallel_grid_params(),
+        compiler_params=_compiler_params(vmem_dq),
         interpret=interpret,
         name=KERNEL_NAMES[1],
-    )(qoff, kvoff, qb, kb, vb, dob, lse_r, dd_r)
+    )(qoff, kvoff, qb, kb, vb, dob, lanes(lse_s), lanes(dd_s))
 
     # Under GQA (g > 1) the dkv grid still walks q heads: each grid row
     # reads its shared KV head (kv_row) and writes a PER-Q-HEAD partial;
@@ -613,34 +836,35 @@ def _pallas_bwd(q, k, v, do, lse, dd, q_off, kv_off,
     # are f32 so the cross-group sum accumulates at the same precision as
     # the in-kernel fori_loop (transient cost: g x f32 dk/dv, freed by
     # the sum — KV itself is still never duplicated).
+    n_q2 = sq // qt2
     dk_p, dv_p = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, causal=causal, q_tile=qt,
-                          true_d=d, window=window),
+        functools.partial(_bwd_dkv_kernel, causal=causal, q_tile=qt2,
+                          window=window),
         out_shape=(
             jax.ShapeDtypeStruct((bh, sk, dp),
                                  k.dtype if g == 1 else jnp.float32),
             jax.ShapeDtypeStruct((bh, sk, dp),
                                  v.dtype if g == 1 else jnp.float32),
         ),
-        grid=(bh, sk // kt),
+        grid=(bh, sk // kt2),
         in_specs=[
             smem((1, 1), lambda i, j: (0, 0)),
             smem((1, 1), lambda i, j: (0, 0)),
             vmem((1, sq, dp), lambda i, j: (i, 0, 0)),
-            vmem((1, kt, dp), lambda i, j: (kv_row(i), j, 0)),
-            vmem((1, kt, dp), lambda i, j: (kv_row(i), j, 0)),
+            vmem((1, kt2, dp), lambda i, j: (kv_row(i), j, 0)),
+            vmem((1, kt2, dp), lambda i, j: (kv_row(i), j, 0)),
             vmem((1, sq, dp), lambda i, j: (i, 0, 0)),
-            vmem((1, sq, _STAT_LANES), lambda i, j: (i, 0, 0)),
-            vmem((1, sq, _STAT_LANES), lambda i, j: (i, 0, 0)),
+            vmem((1, n_q2, _SUBLANES, qt2), lambda i, j: (i, 0, 0, 0)),
+            vmem((1, n_q2, _SUBLANES, qt2), lambda i, j: (i, 0, 0, 0)),
         ],
         out_specs=(
-            vmem((1, kt, dp), lambda i, j: (i, j, 0)),
-            vmem((1, kt, dp), lambda i, j: (i, j, 0)),
+            vmem((1, kt2, dp), lambda i, j: (i, j, 0)),
+            vmem((1, kt2, dp), lambda i, j: (i, j, 0)),
         ),
-        compiler_params=_parallel_grid_params(),
+        compiler_params=_compiler_params(vmem_dkv),
         interpret=interpret,
         name=KERNEL_NAMES[2],
-    )(qoff, kvoff, qb, kb, vb, dob, lse_r, dd_r)
+    )(qoff, kvoff, qb, kb, vb, dob, rows(lse_s), rows(dd_s))
     if g == 1:
         dk, dv = dk_p, dv_p
     else:
@@ -649,31 +873,19 @@ def _pallas_bwd(q, k, v, do, lse, dd, q_off, kv_off,
             return p.reshape(b * h_kv, sk, dp).astype(dtype)
         dk, dv = gsum(dk_p, k.dtype), gsum(dv_p, v.dtype)
 
-    def from_bh(x, s, nh):
-        if dp != d:
-            x = x[:, :, :d]
-        return x.reshape(b, nh, s, d).transpose(0, 2, 1, 3)
-
-    return (from_bh(dq, sq, h), from_bh(dk, sk, h_kv),
-            from_bh(dv, sk, h_kv))
+    return _from_bh(dq, b, d), _from_bh(dk, b, d), _from_bh(dv, b, d)
 
 
 def _bwd_eligible(q, k) -> bool:
     """The bwd kernels additionally stage, per grid step of the dkv
-    kernel, full-length q+do plus the two (sq, _STAT_LANES) f32 row-stat
-    arrays (lse, dd) — all of which must fit the budget together (the
-    stats alone are 2x the q+do bytes at bf16/d=128, so ignoring them
-    would pass shapes that blow VMEM).  f64 (the x64 CPU oracle suite)
-    never takes the kernel."""
+    kernel, full-length q+do plus the two row-statistic arrays (lse, dd)
+    — :func:`tile_plan` counts them and has ``dq`` / ``dkv`` tiles only
+    where they fit.  f64 (the x64 CPU oracle suite) never takes the
+    kernel."""
     if q.dtype not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
         return False
-    if not _eligible(q, k):
-        return False
-    sq = q.shape[1]
-    d_stage = _lane_pad(q.shape[3])
-    staged = (2 * sq * d_stage * jnp.dtype(q.dtype).itemsize
-              + 2 * sq * _STAT_LANES * 4)
-    return staged <= _KV_VMEM_BUDGET
+    return tile_plan(q.shape[1], k.shape[1], q.shape[3], k.dtype).dkv \
+        is not None
 
 
 # ---------------------------------------------------------------------------
@@ -718,7 +930,7 @@ def _block_fwd(q, k, v, q_off, kv_off, causal, impl, window=0):
 
 
 # Backward recomputation is KV-tiled beyond this many keys so the rebuilt
-# score slab stays (b, sq, h, _KV_TILE) instead of (b, sq, h, sk) — the
+# score slab stays (b, sq, h, _MIN_TILE) instead of (b, sq, h, sk) — the
 # memory the fused forward saves must not reappear transiently in HBM on
 # the way back.  Small blocks keep the one-shot einsum (fewer reassociated
 # sums: the x64 oracle tests compare at 1e-12).
@@ -796,7 +1008,7 @@ def _block_bwd(causal, impl, window, res, cot):
     q_pos = q_off + jnp.arange(sq, dtype=jnp.int32)
     kv_pos = kv_off + jnp.arange(sk, dtype=jnp.int32)
 
-    kt = _KV_TILE
+    kt = _MIN_TILE
     prec = dot_precision(q.dtype)
     if sk <= _BWD_TILE_ABOVE or sk % kt != 0:
         dq, dk, dv = _bwd_tile_math(qf, kf, vf, do, lse, delta, dlse,
@@ -925,14 +1137,16 @@ def _kv_chunk_for(q, k) -> int:
     quadratic forward memory either way."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    qt = min(_Q_TILE, sq)
-    if d < 64 or sq % qt != 0 or sk % _KV_TILE != 0:
+    # What does not depend on the chunk (head size, a q tile that
+    # divides) the plan answers for the narrowest one.
+    if sk % _MIN_TILE != 0 \
+            or tile_plan(sq, _MIN_TILE, d, k.dtype).fwd is None:
         return 0
     per_token = 2 * _lane_pad(d) * jnp.dtype(k.dtype).itemsize
-    chunk = min((_KV_VMEM_BUDGET // per_token) // _KV_TILE * _KV_TILE, sk)
-    while chunk >= _KV_TILE and sk % chunk != 0:
-        chunk -= _KV_TILE
-    return chunk if chunk >= _KV_TILE else 0
+    chunk = min((_KV_VMEM_BUDGET // per_token) // _MIN_TILE * _MIN_TILE, sk)
+    while sk % chunk != 0:
+        chunk -= _MIN_TILE
+    return chunk
 
 
 def flash_attention(q, k, v, *, causal: bool = False, impl: str = "auto",
@@ -950,7 +1164,7 @@ def flash_attention(q, k, v, *, causal: bool = False, impl: str = "auto",
     ``merge_partials`` ring attention uses), so memory stays
     O(seq + chunks x q) instead of the jnp fallback's quadratic score
     matrix.  ``kv_chunk`` forces a chunk length (must divide the KV
-    length and be a multiple of the 128 KV tile); 0 picks the largest
+    length and be a multiple of the 128-key tile floor); 0 picks the largest
     eligible chunk automatically, and shapes with no eligible chunk take
     the ordinary single-call dispatch."""
     sk = k.shape[1]
@@ -958,10 +1172,10 @@ def flash_attention(q, k, v, *, causal: bool = False, impl: str = "auto",
         # The kernel path needs whole KV tiles per chunk; the jnp path
         # merges any divisor (useful for testing the merge math).
         if kv_chunk < 0 or sk % kv_chunk != 0 or (
-                impl != "jnp" and kv_chunk % _KV_TILE != 0):
+                impl != "jnp" and kv_chunk % _MIN_TILE != 0):
             raise ValueError(
                 f"kv_chunk={kv_chunk} must divide the KV length {sk} and "
-                f"(for kernel paths) be a multiple of {_KV_TILE}")
+                f"(for kernel paths) be a multiple of {_MIN_TILE}")
         chunk = kv_chunk
     elif impl != "jnp" and not _eligible(q, k):
         chunk = _kv_chunk_for(q, k)

@@ -226,3 +226,47 @@ def test_compressed_data_parallel():
     assert abs(st - fp32) <= 0.02 * fp32
     for r in results[1:]:
         assert r == results[0]   # rank-identical training trajectories
+
+
+class TestFlashTileProbe:
+    """examples/flash_tile_probe.py is a chip script; its control flow
+    runs here at tiny shapes, interpreted (its times mean nothing)."""
+
+    def test_rehearsal_writes_the_plan_and_a_check(self, tmp_path,
+                                                   monkeypatch, capsys):
+        import json
+
+        mod = _load("flash_tile_probe")
+        monkeypatch.chdir(tmp_path)
+        assert mod.main(["--rehearse", "--shapes", "tiny_off", "--iters",
+                         "1", "--check"]) == 0
+        rows = [json.loads(line) for line in open(
+            tmp_path / "chiprun_out" / "flash_tile_probe.jsonl")]
+        check = next(r for r in rows if "check" in r)
+        assert max(check[k] for k in ("out", "lse", "dq", "dk", "dv")) < 1e-4
+        plan = next(r for r in rows if "plan" in r)
+        assert plan["plan"]["fwd"][:2] == [256, 256]      # float32 here
+        assert plan["visited_masked"]["fwd"] == [2, 1]
+        assert json.loads(capsys.readouterr().out.splitlines()[-1])["ok"]
+
+    def test_refuses_to_time_off_the_tpu(self, capsys):
+        mod = _load("flash_tile_probe")
+        assert mod.main(["--shapes", "prefill_256"]) == 1
+        assert "not on a TPU" in capsys.readouterr().out
+
+    def test_table_names_baseline_floor_plan_and_best(self):
+        mod = _load("flash_tile_probe")
+        key = mod.flash.KERNEL_NAMES[0] + "_ms"
+        rows = [dict(shape="s", label="plan", tiles=[256, 512], **{key: 3.0}),
+                dict(shape="s", label="tiles", tiles=[128, 128], **{key: 9.0}),
+                dict(shape="s", label="tiles", tiles=[512, 512], **{key: 2.5}),
+                dict(shape="s", label="baseline", tiles=[128, 128],
+                     **{key: 10.0}),
+                dict(shape="s", label="tiles", tiles=[1024, 1024],
+                     error="refused")]
+        line = mod.table(rows).splitlines()[1].split()
+        assert line == ["s", mod.flash.KERNEL_NAMES[0], "10.000", "9.000",
+                        "3.000", "[256,512]", "2.500", "[512,512]"]
+
+    def test_no_trace_no_durations(self, tmp_path):
+        assert _load("flash_tile_probe").kernel_durations(str(tmp_path)) == {}

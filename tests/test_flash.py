@@ -202,48 +202,340 @@ class TestPallasBackwardKernel:
             np.testing.assert_array_equal(np.asarray(a), 0.0)
 
 
-class TestTunableTiles:
-    """Non-default _Q_TILE/_KV_TILE configurations (the tunable tile
-    sizes) must stay
-    oracle-correct, forward AND backward — KV tiles wider than the
-    128-lane stat slab exercise _stat_tile's lane-tiling branch."""
+def _tiles(kernel, qt, kt, q, k):
+    """Explicit tiles for one kernel, with the plan's own VMEM count."""
+    return flash.kernel_tiles(kernel, qt, kt, q.shape[1], k.shape[1],
+                              q.shape[3], q.dtype)
+
+
+def _kernels_vs_oracle(q, k, v, *, fwd, dq=None, dkv=None, causal=True,
+                       window=0, q_off=0, kv_off=0, traced=False, seed=0,
+                       rtol=1e-4, atol=1e-4):
+    """All three kernels (interpreted here, compiled under ``make
+    tpu-test``) at explicit ``(q_tile, kv_tile)`` pairs against the jnp
+    oracle: out, lse, and dq / dk / dv for a random cotangent on BOTH
+    outputs (dlse is live under ring attention)."""
+    dq, dkv = dq or fwd, dkv or fwd
+    interpret = not flash._on_tpu()
+    rng = np.random.default_rng(seed + 1000)
+    do = jnp.asarray(rng.standard_normal(q.shape), q.dtype)
+    dlse = jnp.asarray(rng.standard_normal(q.shape[:3]), jnp.float32)
+
+    def kernels(qo, ko):
+        out, lse = flash._pallas_block(
+            q, k, v, qo, ko, causal, interpret, window,
+            tiles=_tiles("fwd", *fwd, q, k))
+        dd = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                     axis=-1) - dlse
+        return (out, lse) + flash._pallas_bwd(
+            q, k, v, do, lse, dd, qo, ko, causal, interpret, window,
+            tiles_dq=_tiles("dq", *dq, q, k),
+            tiles_dkv=_tiles("dkv", *dkv, q, k))
+
+    offs = (jnp.int32(q_off), jnp.int32(kv_off))
+    got = jax.jit(kernels)(*offs) if traced else kernels(*offs)
+    (out_j, lse_j), vjp = jax.vjp(
+        lambda q, k, v: flash.flash_block_attention(
+            q, k, v, causal=causal, q_offset=q_off, kv_offset=kv_off,
+            window=window, impl="jnp"), q, k, v)
+    want = (out_j, lse_j) + vjp((do, dlse.astype(lse_j.dtype)))
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32),
+            rtol=rtol, atol=atol, err_msg=name)
+    return got
+
+
+class TestExplicitPlans:
+    """Tiles other than the floor must stay oracle-correct, forward AND
+    backward — what :func:`flash.tile_plan` may hand a call.  The
+    launches take explicit tiles here (tests and the chip probe only;
+    the library always asks the plan)."""
 
     @pytest.mark.parametrize("qt,kt", [(256, 128), (256, 256),
                                        (512, 512), (128, 256)])
-    def test_tiles_match_jnp_fwd_bwd(self, qt, kt, monkeypatch):
-        monkeypatch.setattr(flash, "_Q_TILE", qt)
-        monkeypatch.setattr(flash, "_KV_TILE", kt)
+    def test_tiles_match_jnp_fwd_bwd(self, qt, kt):
         q, k, v = qkv((1, 512, 2, 64), dtype=jnp.float32, seed=5)
+        _kernels_vs_oracle(q, k, v, fwd=(qt, kt), rtol=1e-4, atol=1e-4)
 
-        def loss(impl):
-            return lambda q, k, v: jnp.sum(flash.flash_attention(
-                q, k, v, causal=True, impl=impl) ** 2)
-
-        out_p = flash.flash_attention(q, k, v, causal=True, impl="pallas")
-        out_j = flash.flash_attention(q, k, v, causal=True, impl="jnp")
-        np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_j),
-                                   rtol=1e-5, atol=1e-6)
-        gp = jax.grad(loss("pallas"), argnums=(0, 1, 2))(q, k, v)
-        gj = jax.grad(loss("jnp"), argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(gp, gj):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-4, atol=1e-4)
-
-    def test_windowed_gqa_at_wide_tiles(self, monkeypatch):
-        monkeypatch.setattr(flash, "_Q_TILE", 256)
-        monkeypatch.setattr(flash, "_KV_TILE", 256)
+    def test_windowed_gqa_at_wide_tiles(self):
         q, _, _ = qkv((1, 512, 4, 64), dtype=jnp.float32, seed=7)
         _, k, v = qkv((1, 512, 2, 64), dtype=jnp.float32, seed=8)
+        _kernels_vs_oracle(q, k, v, fwd=(256, 256), window=100)
 
-        def loss(impl):
-            return lambda q, k, v: jnp.sum(flash.flash_attention(
-                q, k, v, causal=True, window=100, impl=impl) ** 2)
+    def test_each_kernel_may_have_its_own_tiles(self):
+        q, k, v = qkv((1, 512, 2, 64), dtype=jnp.float32, seed=9)
+        _kernels_vs_oracle(q, k, v, fwd=(256, 512), dq=(128, 256),
+                           dkv=(256, 128), window=200)
 
-        gp = jax.grad(loss("pallas"), argnums=(0, 1, 2))(q, k, v)
-        gj = jax.grad(loss("jnp"), argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(gp, gj):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-4, atol=1e-4)
+
+class TestInteriorEdgeSplit:
+    """Each kernel runs the tiles that lie wholly under the diagonal and
+    wholly inside every query's window without a mask, and only the
+    tiles the diagonal or the window's edge crosses with one.  Shapes of
+    several tiles a side, so that all three kinds of tile occur."""
+
+    @pytest.mark.parametrize("q_off,kv_off", [
+        (512, 512),          # a diagonal block of MLA's triangle
+        (1024, 512),         # a block wholly under it: no tile masked
+        (300, 77), (77, 300), (640, 1)])
+    def test_traced_unequal_offsets(self, q_off, kv_off):
+        q, k, v = qkv((1, 512, 1, 64), dtype=jnp.float32,
+                      seed=q_off + kv_off)
+        _kernels_vs_oracle(q, k, v, fwd=(128, 128), q_off=q_off,
+                           kv_off=kv_off, traced=True)
+
+    @pytest.mark.parametrize("window,tiles", [
+        (300, (128, 128)), (300, (256, 128)), (300, (128, 256)),
+        (1024, (256, 256)),          # window == sequence: no window edge
+        (1, (128, 128)), (129, (128, 128))])
+    def test_windows_that_fit_no_tile(self, window, tiles):
+        q, k, v = qkv((1, 1024, 1, 64), dtype=jnp.float32, seed=window)
+        _kernels_vs_oracle(q, k, v, fwd=tiles, window=window)
+
+    def test_window_across_offset_blocks(self):
+        # A ring block two blocks back, window reaching into it partly.
+        q, k, v = qkv((1, 512, 1, 64), dtype=jnp.float32, seed=11)
+        _kernels_vs_oracle(q, k, v, fwd=(128, 128), window=900,
+                           q_off=1024, kv_off=256, traced=True)
+
+    def test_gqa(self):
+        q, _, _ = qkv((1, 512, 4, 64), dtype=jnp.float32, seed=12)
+        _, k, v = qkv((1, 512, 2, 64), dtype=jnp.float32, seed=13)
+        _kernels_vs_oracle(q, k, v, fwd=(128, 256), dkv=(256, 128))
+
+    def test_head_192_staged_256(self):
+        q, k, v = qkv((1, 512, 2, 192), dtype=jnp.float32, seed=14)
+        _kernels_vs_oracle(q, k, v, fwd=(256, 128), q_off=512, kv_off=512,
+                           traced=True, rtol=2e-4, atol=2e-4)
+
+    def test_float32_under_highest(self):
+        # f32 operands keep the f32-exact contract on the TPU
+        # (dot_precision); here the flag is inert, the tiles are not.
+        q, k, v = qkv((1, 512, 2, 128), dtype=jnp.float32, seed=15)
+        assert flash.dot_precision(q.dtype) == jax.lax.Precision.HIGHEST
+        _kernels_vs_oracle(q, k, v, fwd=(256, 256), window=384)
+
+    def test_bfloat16_operands(self):
+        q, k, v = qkv((1, 512, 2, 128), dtype=jnp.bfloat16, seed=16)
+        _kernels_vs_oracle(q, k, v, fwd=(256, 128), rtol=5e-2, atol=5e-2)
+
+    @pytest.mark.parametrize("tiles", [(128, 128), (256, 256)])
+    def test_block_wholly_above_the_diagonal(self, tiles):
+        q, k, v = qkv((1, 512, 2, 64), dtype=jnp.float32, seed=17)
+        out, lse, dq, dk, dv = _kernels_vs_oracle(
+            q, k, v, fwd=tiles, q_off=0, kv_off=512, traced=True)
+        assert np.all(np.asarray(out) == 0.0)
+        assert np.all(np.asarray(lse) == flash.NEG_BIG)
+        for g in (dq, dk, dv):
+            np.testing.assert_array_equal(np.asarray(g), 0.0)
+
+    @pytest.mark.parametrize("q_off", [384, 100, 0])
+    def test_queries_shorter_than_keys(self, q_off):
+        # A chunk of a chunked prefill against the cache so far.
+        q, _, _ = qkv((1, 128, 2, 64), dtype=jnp.float32, seed=18)
+        _, k, v = qkv((1, 512, 2, 64), dtype=jnp.float32, seed=19)
+        _kernels_vs_oracle(q, k, v, fwd=(128, 128), q_off=q_off,
+                           traced=True)
+
+    def test_not_causal_every_tile_is_interior(self):
+        q, k, v = qkv((1, 256, 2, 64), dtype=jnp.float32, seed=20)
+        _kernels_vs_oracle(q, k, v, fwd=(128, 128), causal=False)
+        assert flash.masked_tile_share(
+            256, 256, flash.KernelTiles(128, 128, 0), False) == (4, 0)
+
+
+class TestLoopBounds:
+    """The kernels' loop bounds on Python ints against the mask itself:
+    a tile outside ``[a, d)`` attends nothing (skipping it is exact), a
+    tile in ``[b, c)`` attends every pair (dropping its mask is exact),
+    and both cuts are tight."""
+
+    CASES = [(qt, kt, w, qo, ko)
+             for qt, kt in ((128, 128), (256, 128), (128, 512), (512, 256))
+             for w in (0, 1, 300, 512, 4096)
+             for qo, ko in ((0, 0), (77, 300), (300, 77), (2048, 0),
+                            (0, 2048), (1000, 999))]
+
+    @staticmethod
+    def _mask(sq, sk, w, qo, ko):
+        qp = qo + np.arange(sq)[:, None]
+        kp = ko + np.arange(sk)[None, :]
+        m = qp >= kp
+        return m & (qp - kp < w) if w else m
+
+    @pytest.mark.parametrize("qt,kt,w,qo,ko", CASES)
+    def test_kv_bounds_are_exact_and_tight(self, qt, kt, w, qo, ko):
+        sq = sk = 1024
+        m = self._mask(sq, sk, w, qo, ko)
+        for qi in range(sq // qt):
+            a, b, c, d = flash._kv_loop_bounds(qo + qi * qt, ko, qt, kt,
+                                               sk // kt, True, w)
+            assert 0 <= a <= b <= c <= d <= sk // kt
+            for j in range(sk // kt):
+                t = m[qi * qt:(qi + 1) * qt, j * kt:(j + 1) * kt]
+                assert t.any() == (a <= j < d), (qi, j)
+                assert t.all() == (b <= j < c), (qi, j)
+
+    @pytest.mark.parametrize("qt,kt,w,qo,ko", CASES)
+    def test_q_bounds_are_exact_and_tight(self, qt, kt, w, qo, ko):
+        sq = sk = 1024
+        m = self._mask(sq, sk, w, qo, ko)
+        for ki in range(sk // kt):
+            a, b, c, d = flash._q_loop_bounds(ko + ki * kt, qo, qt, kt,
+                                              sq // qt, True, w)
+            assert 0 <= a <= b <= c <= d <= sq // qt
+            for i in range(sq // qt):
+                t = m[i * qt:(i + 1) * qt, ki * kt:(ki + 1) * kt]
+                assert t.any() == (a <= i < d), (i, ki)
+                assert t.all() == (b <= i < c), (i, ki)
+
+    def test_both_loops_visit_the_same_pairs(self):
+        t = flash.KernelTiles(256, 128, 0)
+        for w, qo, ko in ((0, 0, 0), (300, 77, 0), (4096, 2048, 1024)):
+            assert flash.masked_tile_share(1024, 1024, t, True, w, qo, ko) \
+                == flash.masked_tile_share(1024, 1024, t, True, w, qo, ko,
+                                           over="q")
+
+
+class TestTilePlan:
+    """``flash.tile_plan`` is static per shape, so what a call gets is
+    readable without a chip: the tiles, the VMEM bytes, and the share of
+    visited tiles that take the mask.  Pinned for every flash call the
+    benchmark's five cells send (and `train_long`'s), as PERF.md
+    tabulates them: a change of plan shows here first."""
+
+    # name: (queries, keys, head size, window, q_offset, kv_offset),
+    #       fwd / dq / dkv tiles, (visited, masked) of each kernel's loop
+    CALLS = {
+        "mistral-train-4096": (
+            (4096, 4096, 128, 4096, 0, 0),
+            (256, 512), (512, 512), (512, 512),
+            ((72, 16), (36, 8), (36, 8))),
+        "mistral-train_long-16384-window-4096": (
+            (16384, 16384, 128, 4096, 0, 0),
+            (256, 512), (512, 512), (512, 512),
+            ((504, 112), (252, 56), (252, 56))),
+        "internlm2-prefill-256": (
+            (256, 256, 128, 0, 0, 0),
+            (256, 256), (256, 256), (256, 256),
+            ((1, 1), (1, 1), (1, 1))),
+        "internlm2-prefill-1024": (
+            (1024, 1024, 128, 0, 0, 0),
+            (256, 512), (512, 512), (512, 512),
+            ((6, 4), (3, 2), (3, 2))),
+        "internlm2-prefill-2048": (
+            (2048, 2048, 128, 0, 0, 0),
+            (256, 512), (512, 512), (512, 512),
+            ((20, 8), (10, 4), (10, 4))),
+        "mla-block-2048x192-on-the-diagonal": (
+            (2048, 2048, 192, 0, 2048, 2048),
+            (256, 512), (512, 512), (512, 512),
+            ((20, 8), (10, 4), (10, 4))),
+        "mla-block-2048x192-under-the-diagonal": (
+            (2048, 2048, 192, 0, 4096, 2048),
+            (256, 512), (512, 512), (512, 512),
+            ((32, 0), (16, 0), (16, 0))),
+    }
+
+    @pytest.mark.parametrize("call", list(CALLS))
+    def test_plan_and_masked_share_of_the_cells_calls(self, call):
+        (sq, sk, d, w, qo, ko), fwd, dq, dkv, shares = self.CALLS[call]
+        plan = flash.tile_plan(sq, sk, d, jnp.bfloat16, True, w)
+        assert (plan.fwd[:2], plan.dq[:2], plan.dkv[:2]) == (fwd, dq, dkv)
+        got = tuple(
+            flash.masked_tile_share(sq, sk, t, True, w, qo, ko, over=over)
+            for t, over in ((plan.fwd, "kv"), (plan.dq, "kv"),
+                            (plan.dkv, "q")))
+        assert got == shares
+        for kernel, t in zip(("fwd", "dq", "dkv"), plan):
+            assert t == flash.kernel_tiles(kernel, t.q_tile, t.kv_tile, sq,
+                                           sk, d, jnp.bfloat16)
+            assert t.vmem_bytes <= flash._VMEM_CAP
+
+    @staticmethod
+    def _launches(fn, *args):
+        """(grid, block shapes, vmem limit) of every pallas_call."""
+        found = []
+        for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                gm = e.params["grid_mapping"]
+                found.append((
+                    e.params["name"], gm.grid,
+                    [tuple(getattr(b, "block_size", b)
+                           for b in bm.block_shape)
+                     for bm in gm.block_mappings],
+                    e.params["compiler_params"]["mosaic_tpu"]
+                    .vmem_limit_bytes))
+        return found
+
+    @pytest.mark.parametrize("call", list(CALLS))
+    def test_predicates_agree_with_the_launch(self, call):
+        """Where ``_eligible`` / ``_bwd_eligible`` say yes the launches
+        take the plan's tiles (grid and blocks), and ask Mosaic for more
+        scoped VMEM exactly where the plan's count passes its default."""
+        (sq, sk, d, w, _, _), *_ = self.CALLS[call]
+        like = lambda s: jax.ShapeDtypeStruct((1, s, 2, d), jnp.bfloat16)
+        q, k = like(sq), like(sk)
+        off = jax.ShapeDtypeStruct((), jnp.int32)
+        stat = jax.ShapeDtypeStruct((1, sq, 2), jnp.float32)
+        assert flash._eligible(q, k) and flash._bwd_eligible(q, k)
+        plan = flash.tile_plan(sq, sk, d, jnp.bfloat16, True, w)
+        dp = flash._lane_pad(d)
+        limit = lambda t: (t.vmem_bytes if t.vmem_bytes
+                           > flash._DEFAULT_SCOPED_VMEM else None)
+        (name, grid, blocks, vmem), = self._launches(
+            lambda q, k, v, a, b: flash._pallas_block(
+                q, k, v, a, b, True, True, w), q, k, k, off, off)
+        assert name == flash.KERNEL_NAMES[0]
+        assert grid == (2, sq // plan.fwd.q_tile)
+        assert blocks[2] == (1, plan.fwd.q_tile, dp)
+        assert blocks[3] == (1, sk, dp) and vmem == limit(plan.fwd)
+        dq_l, dkv_l = self._launches(
+            lambda q, k, v, do, lse, dd, a, b: flash._pallas_bwd(
+                q, k, v, do, lse, dd, a, b, True, True, w),
+            q, k, k, q, stat, stat, off, off)
+        assert (dq_l[0], dkv_l[0]) == flash.KERNEL_NAMES[1:]
+        assert dq_l[1] == (2, sq // plan.dq.q_tile)
+        assert dq_l[2][2] == (1, plan.dq.q_tile, dp)
+        assert dq_l[3] == limit(plan.dq)
+        assert dkv_l[1] == (2, sk // plan.dkv.kv_tile)
+        assert dkv_l[2][3] == (1, plan.dkv.kv_tile, dp)
+        assert dkv_l[2][6] == (1, sq // plan.dkv.q_tile, 8,
+                               plan.dkv.q_tile)
+        assert dkv_l[3] == limit(plan.dkv)
+
+    def test_the_long_call_asks_for_its_own_vmem(self):
+        plan = flash.tile_plan(16384, 16384, 128, jnp.bfloat16, True, 4096)
+        assert all(t.vmem_bytes > flash._DEFAULT_SCOPED_VMEM for t in plan)
+
+    @pytest.mark.parametrize("sq,sk,d,dtype,why", [
+        (256, 256, 32, jnp.float32, "head size under 64"),
+        (200, 256, 128, jnp.float32, "queries no tile divides"),
+        (256, 200, 128, jnp.float32, "keys no tile divides"),
+        (128, 16384, 128, jnp.float32, "one head's K and V over the budget"),
+    ])
+    def test_no_plan_no_kernel(self, sq, sk, d, dtype, why):
+        assert flash.tile_plan(sq, sk, d, dtype) == (None, None, None), why
+        q = jax.ShapeDtypeStruct((1, sq, 1, d), dtype)
+        k = jax.ShapeDtypeStruct((1, sk, 1, d), dtype)
+        assert not flash._eligible(q, k) and not flash._bwd_eligible(q, k)
+
+    def test_backward_declines_where_q_and_do_pass_the_budget(self):
+        # 32,768 float32 queries of 128: q + dO are 32 MB a head.  The
+        # forward stages a q tile and still runs.
+        plan = flash.tile_plan(32768, 1024, 128, jnp.float32)
+        assert plan.fwd is not None and plan.dq is None and plan.dkv is None
+
+    def test_short_sequences_are_one_tile(self):
+        plan = flash.tile_plan(100, 64, 64, jnp.float32)
+        assert plan.fwd[:2] == plan.dq[:2] == plan.dkv[:2] == (100, 64)
+
+    def test_float32_takes_narrower_tiles(self):
+        # Several MXU passes a product under HIGHEST: 256 x 256 was the
+        # fastest pair in all three kernels on the chip.
+        plan = flash.tile_plan(2048, 2048, 128, jnp.float32)
+        assert plan.fwd[:2] == plan.dq[:2] == plan.dkv[:2] == (256, 256)
 
 
 class TestLanePadding:
@@ -279,7 +571,7 @@ class TestLanePadding:
 
 
 class TestCausalTileSkip:
-    """The diagonal-cut loop bounds (_causal_n_live and the dkv i_start)
+    """The diagonal-cut loop bounds (_kv_loop_bounds and _q_loop_bounds)
     must be exact at UNALIGNED offsets: a bound off by one tile either
     recomputes masked work (benign) or skips live keys (wrong output).
     Sweep odd offsets through the forced kernel path vs the jnp oracle —

@@ -47,7 +47,7 @@ def test_mla_blocks_compile_for_the_v5e_with_all_three_kernels(
         one_v5e_chip, as_on_tpu):
     """At the published widths (32 heads, keys of 192 staged 256 wide)
     Mosaic takes the 2,048-token blocks, forward and both backward
-    kernels; one call over 8,192 tokens it refuses."""
+    kernels."""
     like = jax.ShapeDtypeStruct((1, 2 * T._MLA_BLOCK, 32, 192), jnp.bfloat16,
                                 sharding=one_v5e_chip)
     loss = lambda q, k, v: jnp.sum(T._blockwise_causal_attention(
@@ -55,6 +55,78 @@ def test_mla_blocks_compile_for_the_v5e_with_all_three_kernels(
     with jax.enable_x64(False):      # the kernels are traced without x64
         text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
             like, like, like).compile().as_text()
+    for name in flash.KERNEL_NAMES:
+        assert name in text, name
+
+
+def _flash_grads_text(chip, b, s, h, h_kv, d, window, dtype=jnp.bfloat16):
+    """The compiled text of all three kernels' launches for one call at
+    traced offsets, as ring blocks and MLA's triangle make it."""
+    like = lambda n: jax.ShapeDtypeStruct((b, s, n, d), dtype,
+                                          sharding=chip)
+    off = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+    loss = lambda q, k, v, qo, ko: jnp.sum(flash.flash_block_attention(
+        q, k, v, causal=True, q_offset=qo, kv_offset=ko,
+        window=window)[0].astype(F32))
+    with jax.enable_x64(False):
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            like(h), like(h_kv), like(h_kv), off, off).compile().as_text()
+
+
+# (batch, tokens, q heads, kv heads, head size, window) of every flash
+# call the benchmark's five cells send, and of `train_long`'s.
+CELL_CALLS = {
+    "mistral-train-2x4096": (2, 4096, 32, 8, 128, 4096),
+    "mistral-train_long-1x16384": (1, 16384, 32, 8, 128, 4096),
+    "internlm2-prefill-256": (1, 256, 16, 8, 128, 0),
+    "internlm2-prefill-1024": (1, 1024, 16, 8, 128, 0),
+    "internlm2-prefill-2048": (1, 2048, 16, 8, 128, 0),
+    "kimi-mla-block-2048x192": (2, 2048, 32, 32, 192, 0),
+    "openpangu-mla-block-2048x192": (1, 2048, 128, 128, 192, 0),
+}
+
+
+@pytest.mark.parametrize("call", list(CELL_CALLS))
+def test_flash_kernels_compile_at_the_cells_shapes_under_the_plan(
+        one_v5e_chip, as_on_tpu, call):
+    """Mosaic takes all three kernels at the tiles ``flash.tile_plan``
+    gives each call the cells send, at its real shape: the plan's VMEM
+    count, and the limit it asks for where it passes Mosaic's default,
+    are enough."""
+    b, s, h, h_kv, d, window = CELL_CALLS[call]
+    plan = flash.tile_plan(s, s, d, jnp.bfloat16, True, window)
+    assert None not in plan
+    text = _flash_grads_text(one_v5e_chip, b, s, h, h_kv, d, window)
+    for name in flash.KERNEL_NAMES:
+        assert name in text, name
+
+
+@pytest.mark.parametrize("s,d", [(2048, 192), (4096, 256), (8192, 128)])
+def test_float32_calls_compile_under_the_plan(one_v5e_chip, as_on_tpu, s, d):
+    """float32 operands contract under ``HIGHEST``: Mosaic splits each
+    into bfloat16 pieces and stages more than the operands' own bytes.
+    The plan's count covers it (a first count did not: 20.47 MB against
+    a limit of 19 at 2,048 x 192, found on the chip), at its narrower
+    float32 tiles."""
+    plan = flash.tile_plan(s, s, d, jnp.float32, True)
+    assert plan.fwd[:2] == plan.dq[:2] == plan.dkv[:2] == (256, 256)
+    text = _flash_grads_text(one_v5e_chip, 2, s, 16, 16, d, 0, jnp.float32)
+    for name in flash.KERNEL_NAMES:
+        assert name in text, name
+
+
+def test_one_call_over_8192_keys_of_192_compiles_since_the_plan(
+        one_v5e_chip, as_on_tpu):
+    """Before ``tile_plan`` Mosaic refused MLA's forward as one call
+    over 8,192 keys staged 256 wide (16.38 MB of scoped VMEM against its
+    default 16) although ``_eligible`` admitted it, and the backward
+    declined 8,192 queries.  The plan counts what the kernels stage and
+    asks Mosaic for that much: the call compiles, all three kernels.
+    (``transformer._MLA_BLOCK`` still cuts it into 2,048 blocks.)"""
+    plan = flash.tile_plan(8192, 8192, 192, jnp.bfloat16, True)
+    assert None not in plan
+    assert plan.fwd.vmem_bytes > flash._DEFAULT_SCOPED_VMEM
+    text = _flash_grads_text(one_v5e_chip, 1, 8192, 32, 32, 192, 0)
     for name in flash.KERNEL_NAMES:
         assert name in text, name
 
