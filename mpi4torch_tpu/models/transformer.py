@@ -70,7 +70,10 @@ class MLA:
     latent of that rank as well (0: one projection).  ``rope`` rotates
     the ``qk_rope`` channels of every query head and of the shared key
     by their position (``TransformerConfig.rope_theta``); without it no
-    channel knows a position."""
+    channel knows a position.  ``q_scale`` and ``kv_scale`` multiply the
+    normed query latent and the normed key-value latent (a model that
+    scales them by ``sqrt(d_model / rank)``); the scaled key-value
+    latent is what a serving cache row holds."""
     n_heads: int
     kv_rank: int
     qk_nope: int
@@ -78,11 +81,16 @@ class MLA:
     v_dim: int
     q_rank: int = 0
     rope: bool = False
+    q_scale: float = 1.0
+    kv_scale: float = 1.0
 
     def __post_init__(self):
         if self.rope and self.qk_rope % 2:
             raise ValueError(
                 f"rope pairs channels: qk_rope={self.qk_rope} must be even")
+        if self.q_scale != 1.0 and not self.q_rank:
+            raise ValueError(
+                "q_scale scales the normed query latent: it needs q_rank > 0")
 
 
 @dataclass(frozen=True)
@@ -94,10 +102,26 @@ class LayerSpec:
     ``d_ff``), else the :class:`~mpi4torch_tpu.parallel.moe.Experts`
     share this rank holds.  ``post_norm`` puts a second norm on each
     branch, after the mixer and after the FFN and before the residual
-    sum (a "sandwich"): leaves ``ln1_post`` and ``ln2_post``."""
+    sum (a "sandwich"): leaves ``ln1_post`` and ``ln2_post``.
+
+    ``branch`` is a shortcut: the :class:`~mpi4torch_tpu.parallel.moe.
+    Experts` share computed on THIS layer's ``ln2`` rows (leaves
+    ``branch``), beside the layer's own FFN, and carried along the stack
+    until a layer with ``join`` adds it to the stream after its own FFN
+    residual: the same layer, or a later one, so that the branch runs
+    beside every mixer and FFN in between.  One branch is open at a
+    time, and every branch is joined once (:class:`TransformerConfig`
+    refuses anything else)."""
     mixer: Union[None, KDA, MLA] = None
     ffn: Optional[Experts] = None
     post_norm: bool = False
+    branch: Optional[Experts] = None
+    join: bool = False
+
+    @property
+    def shortcut(self) -> bool:
+        """Whether the layer carries or joins a branch."""
+        return self.branch is not None or self.join
 
 
 @dataclass(frozen=True)
@@ -147,12 +171,30 @@ class TransformerConfig:
                 raise ValueError(
                     "a layer spec names its expert layers itself "
                     "(LayerSpec.ffn); n_experts is the uniform top-1 MoE")
-            if any(s.mixer is None and (s.ffn is not None or s.post_norm)
+            if any(s.mixer is None and (s.ffn is not None or s.post_norm
+                                        or s.shortcut)
                    for s in self.layers):
                 raise ValueError(
-                    "an expert FFN or a post-norm needs a KDA or MLA "
-                    "mixer: the configuration's own attention block "
-                    "carries its own FFN and norms")
+                    "an expert FFN, a post-norm or a shortcut branch needs "
+                    "a KDA or MLA mixer: the configuration's own attention "
+                    "block carries its own FFN and norms")
+            open_at = None
+            for i, s in enumerate(self.layers):
+                if s.branch is not None:
+                    if open_at is not None:
+                        raise ValueError(
+                            f"layer {i} opens a shortcut branch while layer "
+                            f"{open_at}'s is not joined yet")
+                    open_at = i
+                if s.join:
+                    if open_at is None:
+                        raise ValueError(
+                            f"layer {i} joins a shortcut branch, and none "
+                            "is open: a branch is joined once")
+                    open_at = None
+            if open_at is not None:
+                raise ValueError(
+                    f"layer {open_at}'s shortcut branch is never joined")
         if self.n_experts > 0 and self.capacity <= 0:
             # capacity=0 would silently capacity-drop every token — the
             # model would train with no FFN path at all.
@@ -244,6 +286,9 @@ def init_transformer(key, cfg: TransformerConfig,
                                         dtype)}
             if spec.post_norm:
                 blk["ln1_post"], blk["ln2_post"] = norm_p(), norm_p()
+        if spec.branch is not None:
+            blk["branch"] = init_experts(next(keys), spec.branch, d_model,
+                                         dtype)
         if spec.ffn is not None:
             blk["experts"] = init_experts(next(keys), spec.ffn, d_model,
                                           dtype)
@@ -488,17 +533,25 @@ def mla_project(cfg: TransformerConfig, spec: MLA, p, y, positions):
     heads share.  Under ``spec.rope`` the last ``qk_rope`` channels of
     ``q`` and ``k_r`` are rotated by ``positions`` (``(s,)`` or ``(b,
     s)``).  ``[c ; k_r]`` is everything a later query needs of this
-    token: the serving cache's entry."""
+    token: the serving cache's entry.  ``spec.q_scale`` and
+    ``spec.kv_scale`` are applied here, once, to the normed latents: the
+    ``c`` handed back (and cached) is the scaled one."""
     b, s, _ = y.shape
     h, dn, dr = spec.n_heads, spec.qk_nope, spec.qk_rope
+    # The product in at least float32, rounded once: sqrt(12) is no
+    # bfloat16 number.
+    scaled = lambda t, by: t if by == 1.0 else (
+        t.astype(jnp.promote_types(t.dtype, jnp.float32)) * by
+    ).astype(t.dtype)
     if spec.q_rank:
-        q = _rms_norm(y @ p["wqa"], p["q_norm"]) @ p["wq"]
+        q = scaled(_rms_norm(y @ p["wqa"], p["q_norm"]),
+                   spec.q_scale) @ p["wq"]
     else:
         q = y @ p["wq"]
     q = q.reshape(b, s, h, dn + dr)
     latent = y @ p["wa"]
     c, k_r = latent[..., :spec.kv_rank], latent[..., spec.kv_rank:]
-    c = _rms_norm(c, p["kv_norm"])
+    c = scaled(_rms_norm(c, p["kv_norm"]), spec.kv_scale)
     if spec.rope:
         if positions is None:
             raise ValueError("MLA(rope=True) requires the caller's positions")
@@ -563,6 +616,31 @@ def _ffn_dense(cfg: TransformerConfig, blk, y):
         gate, up = jnp.split(gate_up, 2, axis=-1)
         return (jax.nn.silu(gate) * up) @ blk["w2"]
     return jax.nn.gelu(y @ blk["w1"]) @ blk["w2"]
+
+
+def dense_ffn(cfg: TransformerConfig, spec: LayerSpec, blk, y):
+    """:func:`_ffn_dense` as a spec'd layer runs it: under
+    ``layer_scope("ffn")`` where the layer carries or joins a shortcut
+    branch (there the dense path is what the branch runs beside, and a
+    trace tells the two apart), as it is everywhere else."""
+    if not spec.shortcut:
+        return _ffn_dense(cfg, blk, y)
+    with layer_scope("ffn"):
+        return _ffn_dense(cfg, blk, y)
+
+
+def shortcut_branch(spec: LayerSpec, blk, y, comm_ep=None, live=None):
+    """The shortcut branch of a layer that carries one, on the layer's
+    ``ln2`` rows ``y``: ``(s, rows, zero_pairs)`` with ``s`` of ``y``'s
+    shape, to be added to the stream by the layer whose spec says
+    ``join``, and :func:`~mpi4torch_tpu.parallel.moe.held_experts_ffn`'s
+    two counts.  The training forward and the serving walk both come
+    through here."""
+    with layer_scope("moe"):
+        s, rows, zero_pairs = held_experts_ffn(
+            y.reshape(-1, y.shape[-1]), blk["branch"], spec.branch,
+            comm_ep, live=live)
+    return s.reshape(y.shape), rows, zero_pairs
 
 
 def _ffn_residual(cfg: TransformerConfig, blk, x, comm_ep):
@@ -725,17 +803,39 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
     def experts_fn(spec, x, blk):
         with layer_scope("moe"):
             y = _norm(cfg, x, blk["ln2"])
-            ff, taken = held_experts_ffn(y.reshape(-1, d), blk["experts"],
-                                         spec.ffn, comm_ep)
+            ff, taken, _ = held_experts_ffn(
+                y.reshape(-1, d), blk["experts"], spec.ffn, comm_ep)
             ff = branch_norm(cfg, spec, blk, ff.reshape(x.shape), "ln2_post")
         return x + ff, taken
+
+    def shortcut_fn(spec, x, blk, carried):
+        # A layer that carries or joins a shortcut branch: the branch
+        # reads the rows the layer's own FFN reads, and joins the stream
+        # behind the FFN residual of the layer that says so.
+        y = _norm(cfg, x, blk["ln2"])
+        taken = []
+        if spec.branch is not None:
+            carried, rows, _ = shortcut_branch(spec, blk, y, comm_ep)
+            taken.append(rows)
+        if spec.ffn is None:
+            ff = dense_ffn(cfg, spec, blk, y)
+        else:
+            with layer_scope("moe"):
+                ff, rows, _ = held_experts_ffn(
+                    y.reshape(-1, d), blk["experts"], spec.ffn, comm_ep)
+                ff = ff.reshape(x.shape)
+            taken.append(rows)
+        x = x + branch_norm(cfg, spec, blk, ff, "ln2_post")
+        if spec.join:
+            x, carried = x + carried, None
+        return x, carried, taken
 
     # With remat a uniform block is one rematerialised region; a new kind
     # of mixer and an expert FFN are one each, so that the backward holds
     # the temporaries of one of them at a time, not of both.
     remat = functools.partial(jax.checkpoint, policy=_SAVED_IN_REMAT) \
         if cfg.remat else (lambda f: f)
-    rows = []
+    rows, carried = [], None
     for spec, blk in zip(cfg.layer_specs, params["blocks"]):
         if spec.mixer is None and spec.ffn is None:
             x, aux = (jax.checkpoint(block_fn) if cfg.remat
@@ -743,7 +843,11 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
             aux_total = aux_total + aux
             continue
         x = remat(functools.partial(mixer_fn, spec))(x, blk)
-        if spec.ffn is None and spec.post_norm:
+        if spec.shortcut:
+            x, carried, taken = remat(functools.partial(shortcut_fn, spec))(
+                x, blk, carried)
+            rows += taken
+        elif spec.ffn is None and spec.post_norm:
             x = remat(lambda x_, blk_: x_ + branch_norm(
                 cfg, spec, blk_, _ffn_dense(
                     cfg, blk_, _norm(cfg, x_, blk_["ln2"])),

@@ -20,15 +20,17 @@ Both transports are the one differentiable ``Alltoall`` op, so the entire
 MoE layer is AD-transparent on either backend; gradients to expert weights
 ride the reverse all-to-all automatically.
 
-Beside it, the held-share layer (:func:`held_experts_ffn`): sigmoid
-scores, top-k of ALL the experts with a selection bias, renormalised
-weights, swiglu experts and a shared expert, for a rank that is told
+Beside it, the held-share layer (:func:`held_experts_ffn`): sigmoid or
+softmax scores, top-k of ALL the experts with a selection bias, weights
+renormalised or not, swiglu experts, a shared expert and zero-compute
+(identity) experts behind the routed ones, for a rank that is told
 which experts it holds and computes their part of the result for every
 token routed to them — no capacity, nothing dropped, no exchange yet.
 """
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict
 
@@ -220,9 +222,15 @@ class Experts:
     """A top-k expert FFN as one rank sees it: ``n_experts`` routed
     experts of width ``d_expert`` in the model, ``top_k`` per token; this
     rank holds experts ``first_expert`` to ``first_expert + n_held - 1``.
-    The chosen scores are renormalised and scaled by ``scale``;
-    ``n_shared`` shared experts (one swiglu of width ``n_shared *
-    d_expert``) are passed by every token."""
+    The scores are ``score`` (``sigmoid`` | ``softmax``) of the router's
+    outputs; the chosen ones are renormalised over the ``top_k`` chosen
+    where ``renorm`` says so, and scaled by ``scale``; ``n_shared``
+    shared experts (one swiglu of width ``n_shared * d_expert``) are
+    passed by every token.  ``n_zero`` zero-compute experts stand behind
+    the routed ones (router outputs ``n_experts`` to ``n_experts + n_zero
+    - 1``): each returns its input, so a token that chooses some of them
+    adds ``(sum of their weights) * x`` and computes that many fewer
+    experts.  No rank holds them: a token's own rank adds their part."""
     n_experts: int
     top_k: int
     d_expert: int
@@ -230,12 +238,24 @@ class Experts:
     n_held: int
     n_shared: int = 0
     scale: float = 1.0
+    score: str = "sigmoid"
+    renorm: bool = True
+    n_zero: int = 0
+
+    @property
+    def width(self) -> int:
+        """The router's outputs: routed and zero-compute experts."""
+        return self.n_experts + self.n_zero
 
     def __post_init__(self):
-        if not 0 < self.top_k <= self.n_experts:
+        if self.score not in ("sigmoid", "softmax"):
+            raise ValueError(f"unknown score function {self.score!r}")
+        if self.n_zero < 0:
+            raise ValueError(f"n_zero={self.n_zero} must be >= 0")
+        if not 0 < self.top_k <= self.width:
             raise ValueError(
-                f"top_k={self.top_k} must lie in [1, n_experts="
-                f"{self.n_experts}]")
+                f"top_k={self.top_k} must lie in [1, n_experts + n_zero="
+                f"{self.width}]")
         last = self.first_expert + self.n_held
         if self.first_expert < 0 or self.n_held < 1 \
                 or last > self.n_experts:
@@ -246,9 +266,11 @@ class Experts:
 
 def init_experts(key, spec: Experts, d_model: int,
                  dtype=jnp.float32) -> Dict[str, Any]:
-    """Parameters of one held share: the router at its full width, a
-    selection ``bias`` (zeros; it takes no gradient), the held experts'
-    fused ``w1 = [gate | up]`` and ``w2``, and the shared expert."""
+    """Parameters of one held share: the router at its full width
+    (``spec.width``: zero-compute experts have an output each and no
+    other leaf), a selection ``bias`` (zeros; it takes no gradient), the
+    held experts' fused ``w1 = [gate | up]`` and ``w2``, and the shared
+    expert."""
     kr, k1, k2, k3, k4 = jax.random.split(key, 5)
     f, e = spec.d_expert, spec.n_held
 
@@ -256,8 +278,8 @@ def init_experts(key, spec: Experts, d_model: int,
         return jax.random.normal(key, shape, dtype) / jnp.sqrt(
             jnp.asarray(shape[-2], dtype))
 
-    p = {"router": dense(kr, d_model, spec.n_experts),
-         "bias": jnp.zeros((spec.n_experts,), dtype),
+    p = {"router": dense(kr, d_model, spec.width),
+         "bias": jnp.zeros((spec.width,), dtype),
          "w1": dense(k1, e, d_model, 2 * f), "w2": dense(k2, e, f, d_model)}
     if spec.n_shared:
         p["shared_w1"] = dense(k3, d_model, 2 * spec.n_shared * f)
@@ -265,18 +287,25 @@ def init_experts(key, spec: Experts, d_model: int,
     return p
 
 
-def route_topk(x, router, bias, top_k: int, scale: float):
-    """Sigmoid scores in at least float32 over all experts, the
-    ``top_k`` largest of ``score + bias`` chosen (the bias steers the
-    choice and takes no gradient), weights ``scale * score / sum of the
-    chosen scores``.  Returns ``(chosen (T, k) int32, weights (T, k))``."""
+def route_topk(x, router, bias, top_k: int, scale: float, *,
+               score: str = "sigmoid", renorm: bool = True):
+    """Scores in at least float32 over all the router's outputs
+    (``score``: each output's ``sigmoid``, or the ``softmax`` over them),
+    the ``top_k`` largest of ``score + bias`` chosen (the bias steers the
+    choice and takes no gradient), weights ``scale * score``, over the
+    sum of the chosen scores where ``renorm``.  Returns ``(chosen (T, k)
+    int32, weights (T, k))``."""
     ct = jnp.promote_types(x.dtype, jnp.float32)
-    score = jax.nn.sigmoid(jnp.matmul(
+    squash = jax.nn.sigmoid if score == "sigmoid" else \
+        functools.partial(jax.nn.softmax, axis=-1)
+    score = squash(jnp.matmul(
         x.astype(ct), router.astype(ct),
         precision=jax.lax.Precision.HIGHEST))
     _, chosen = jax.lax.top_k(
         score + jax.lax.stop_gradient(bias.astype(ct)), top_k)
     picked = jnp.take_along_axis(score, chosen, axis=-1)
+    if not renorm:
+        return chosen, scale * picked
     return chosen, scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
 
 
@@ -325,10 +354,12 @@ def _swiglu(x, w1, w2, dot):
 def held_experts_ffn(x, params: Dict[str, Any], spec: Experts,
                      comm_ep=None, live=None):
     """The held experts' part of the layer for ``x`` ``(T, d)``, plus the
-    shared expert: ``sum over chosen and held e of w_e E_e(x) +
-    E_shared(x)``.  The weights are renormalised over all ``top_k``
-    chosen experts, held or not; what the experts held elsewhere would
-    add is left out.
+    shared expert and the zero-compute experts: ``sum over chosen and
+    held e of w_e E_e(x) + E_shared(x) + (sum over chosen zero-compute z
+    of w_z) x``.  The weights are taken (and, where the spec says so,
+    renormalised) over all ``top_k`` chosen experts, held or not; what
+    the experts held elsewhere would add is left out.  The zero-compute
+    part is whole: every token of ``x`` is at home here.
 
     Every (token, chosen expert) pair is a row; the rows of held experts
     are sorted by expert to the front and are the groups of two grouped
@@ -342,11 +373,13 @@ def held_experts_ffn(x, params: Dict[str, Any], spec: Experts,
     no expert's time and are not counted (a serving decode step's free
     slots; their ``y`` rows are the shared expert's alone).
 
-    Returns ``(y, rows)``: ``rows`` ``(n_held,)``, the rows each held
-    expert took, which are the group sizes the products are handed.
+    Returns ``(y, rows, zero_pairs)``: ``rows`` ``(n_held,)``, the rows
+    each held expert took, which are the group sizes the products are
+    handed; ``zero_pairs``, the live (token, choice) pairs that chose a
+    zero-compute expert (int32; the number 0 where the spec has none).
     Inference runs the same code: a compiled prefill or decode step of
-    ``mpi4torch_tpu.serve`` calls it on its rows and hands ``rows`` out
-    with the step's record."""
+    ``mpi4torch_tpu.serve`` calls it on its rows and hands the counts
+    out with the step's record."""
     if comm_ep is not None and comm_ep.size > 1:
         raise CommError(
             "held_experts_ffn computes one rank's share and exchanges "
@@ -356,7 +389,8 @@ def held_experts_ffn(x, params: Dict[str, Any], spec: Experts,
     T, d = x.shape
     k, held = spec.top_k, spec.n_held
     chosen, weight = route_topk(x, params["router"], params["bias"], k,
-                                spec.scale)
+                                spec.scale, score=spec.score,
+                                renorm=spec.renorm)
     local = chosen.reshape(-1) - spec.first_expert
     here = (local >= 0) & (local < held)
     if live is not None:
@@ -373,9 +407,17 @@ def held_experts_ffn(x, params: Dict[str, Any], spec: Experts,
     ys = jnp.where(is_held, _swiglu(xs, params["w1"], params["w2"], grouped),
                    0)
     ys = _permute_rows(ys, inverse, order).reshape(T, k, d)
-    y = jnp.sum(ys.astype(weight.dtype) * weight[..., None],
-                axis=1).astype(x.dtype)
+    y = jnp.sum(ys.astype(weight.dtype) * weight[..., None], axis=1)
+    zero_pairs = 0
+    if spec.n_zero:
+        is_zero = chosen >= spec.n_experts
+        y = y + jnp.sum(jnp.where(is_zero, weight, 0), axis=1,
+                        keepdims=True) * x.astype(weight.dtype)
+        if live is not None:
+            is_zero &= live[:, None]
+        zero_pairs = jnp.sum(is_zero, dtype=jnp.int32)
+    y = y.astype(x.dtype)
     if spec.n_shared:
         y = y + _swiglu(x, params["shared_w1"], params["shared_w2"],
                         jnp.matmul)
-    return y, rows
+    return y, rows, zero_pairs

@@ -850,10 +850,16 @@ class Engine:
         off the device behind the sync its caller has just made, onto
         the record of the step that is open: ``moe_rows``, one
         ``(program, (expert layers, held) rows)`` per call of a program
-        with an expert layer, in the order of the calls."""
+        with an expert layer, in the order of the calls; and, from a
+        program whose expert layers have zero-compute experts, the two
+        counters ``moe_zero_pairs`` and ``moe_live_pairs``, which the
+        step record carries by name like every counter."""
+        stats = {k: self._fetch(v) for k, v in jax.device_get(stats).items()}
         if "moe_rows" in stats:
-            self.stats.attach(
-                "moe_rows", (program, self._fetch(stats["moe_rows"])))
+            self.stats.attach("moe_rows", (program, stats["moe_rows"]))
+        for name in ("moe_zero_pairs", "moe_live_pairs"):
+            if name in stats:
+                self.stats.count(name, int(stats[name]))
 
     # -------------------------------------------------------------- paged
 

@@ -50,8 +50,8 @@ import jax.numpy as jnp
 from .. import config as _config
 from ..constants import MPI_SUM
 from ..models.transformer import KDA, MLA, TransformerConfig, _MLA_BLOCK, \
-    _blockwise_causal_attention, _ffn_dense, _norm, _split_qkv, \
-    branch_norm, mla_expand, mla_project
+    _blockwise_causal_attention, _norm, _split_qkv, branch_norm, \
+    dense_ffn, mla_expand, mla_project, shortcut_branch
 from ..ops.flash import flash_attention, flash_block_attention
 from ..ops.paged_attention import latent_rows_attention, \
     paged_decode_attention, paged_latent_attention
@@ -92,7 +92,9 @@ def validate_tp(cfg: TransformerConfig, size: int) -> None:
     compiled prefill and decode step.  Refused by name: a KDA mixer (its
     recurrent state has no cache entry and no snapshot yet), and more
     than one rank (the heads of a latent layer and the experts' exchange
-    are not sharded yet)."""
+    are not sharded yet; a shortcut branch is named where the spec has
+    one, since it is the branch's exchange that its dense path would
+    hide)."""
     if cfg.n_experts > 0:
         raise CommError(
             "serve: MoE configs (n_experts > 0) are not supported by the "
@@ -105,6 +107,12 @@ def validate_tp(cfg: TransformerConfig, size: int) -> None:
                 "the serving cache has no entry and no snapshot yet — "
                 "latent attention (MLA) and the held expert share are "
                 "what the serving walk knows of a per-layer spec")
+        if size != 1 and any(sp.shortcut for sp in cfg.layers):
+            raise CommError(
+                f"serve: a shortcut branch (LayerSpec.branch / .join) is "
+                f"served on one rank; {size} ranks would need the "
+                "branch's expert exchange overlapped with the mixer and "
+                "the dense FFNs it runs beside, which is not written yet")
         if size != 1:
             raise CommError(
                 f"serve: a configuration with a per-layer spec is served "
@@ -439,9 +447,13 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
     """The serving transformer block, once, for every serving program:
     per layer ``ln1`` → mixer → ``reduce`` → residual → ``ln2`` → FFN →
     ``reduce`` → residual; then ``ln_f``.  Returns ``(x, entries,
-    moe_rows)``: the normed hidden rows, what the view handed back for
-    each layer, and the rows each held expert took in every expert
-    layer, ``(expert layers, held)`` (``None`` without such a layer).
+    counts)``: the normed hidden rows, what the view handed back for
+    each layer, and what the expert layers counted (``{}`` without
+    one): ``moe_rows``, the rows each held expert took in every expert
+    layer, ``(expert layers, held)``, and, where a layer has
+    zero-compute experts, ``moe_zero_pairs`` and ``moe_live_pairs``, the
+    live (token, choice) pairs that chose one and all of them, summed
+    over those layers.
 
     ``x`` is the embedded input, ``(b, s, d)`` with ``positions`` ``(s,)``
     (a prefill) or ``(slots, d)`` with one position a slot (a decode
@@ -470,7 +482,12 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
     dense or the held share of an expert layer under
     ``layer_scope("moe")`` (``live`` ``(rows,)`` keeps a decode step's
     free slots out of the groups and the counts); a second norm on each
-    branch where the spec states one.  This is the one place on the
+    branch where the spec states one; a shortcut branch
+    (``LayerSpec.branch``) computed on its layer's ``ln2`` rows, carried
+    across the layers it runs beside and added behind the FFN residual
+    of the layer that joins it, with the dense FFN of those layers under
+    ``layer_scope("ffn")`` (``shortcut_branch``, ``dense_ffn``: the
+    training forward's helpers).  This is the one place on the
     serving path that knows a kind of layer; what it does not know
     ``validate_tp`` refuses by name."""
     size = _tp_size(cfg, shards)
@@ -478,7 +495,17 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
     nsites = 2 * len(shards["blocks"])
     # The projections take sequences: a decode step's rows are of one.
     seq = (lambda a: a[:, None]) if x.ndim == 2 else (lambda a: a)
-    entries, moe_rows = [], []
+    entries, moe_rows, zero_pairs, live_pairs = [], [], [], []
+    carried = None
+
+    def counted(spec, taken, zero):
+        moe_rows.append(taken)
+        if spec.n_zero:
+            zero_pairs.append(zero)
+            live_pairs.append(spec.top_k * (
+                x.size // x.shape[-1] if live is None
+                else jnp.sum(live, dtype=jnp.int32)))
+
     for layer, (spec, blk) in enumerate(zip(cfg.layer_specs,
                                             shards["blocks"])):
         y = _norm(cfg, x, blk["ln1"])
@@ -500,28 +527,38 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
         entries.append(entry)
         x = x + reduce(o_part, 2 * layer, nsites).astype(x.dtype)
         y = _norm(cfg, x, blk["ln2"])
+        if spec.branch is not None:
+            carried, taken, zero = shortcut_branch(spec, blk, y, live=live)
+            counted(spec.branch, taken, zero)
         if spec.ffn is None:
-            ff = branch_norm(cfg, spec, blk, _ffn_dense(cfg, blk, y),
+            ff = branch_norm(cfg, spec, blk, dense_ffn(cfg, spec, blk, y),
                              "ln2_post")
         else:
             with layer_scope("moe"):
-                ff, taken = held_experts_ffn(
+                ff, taken, zero = held_experts_ffn(
                     y.reshape(-1, y.shape[-1]), blk["experts"], spec.ffn,
                     live=live)
                 ff = branch_norm(cfg, spec, blk, ff.reshape(y.shape),
                                  "ln2_post")
-            moe_rows.append(taken)
+            counted(spec.ffn, taken, zero)
         x = x + reduce(ff, 2 * layer + 1, nsites).astype(x.dtype)
-    return (_norm(cfg, x, shards["ln_f"]), entries,
-            jnp.stack(moe_rows) if moe_rows else None)
+        if spec.join:
+            x, carried = x + carried.astype(x.dtype), None
+    x, counts = _norm(cfg, x, shards["ln_f"]), {}
+    if moe_rows:
+        counts["moe_rows"] = jnp.stack(moe_rows)
+    if zero_pairs:
+        counts["moe_zero_pairs"] = sum(zero_pairs)
+        counts["moe_live_pairs"] = jnp.asarray(sum(live_pairs), jnp.int32)
+    return x, entries, counts
 
 
-def _hand_out(stats, moe_rows):
-    """A serving program's counters, into the caller's ``stats`` dict
-    (the one route out: the engine's step record takes them from
-    there)."""
-    if stats is not None and moe_rows is not None:
-        stats["moe_rows"] = moe_rows
+def _hand_out(stats, counts):
+    """A serving program's counters (:func:`_walk_layers`' ``counts``),
+    into the caller's ``stats`` dict (the one route out: the engine's
+    step record takes them from there)."""
+    if stats is not None:
+        stats.update(counts)
 
 
 def _prefill_reduce(comm):
@@ -596,10 +633,10 @@ def prefill_tp(cfg: TransformerConfig, shards, cache, prompt, comm=None,
         return lat.expanded(q, rows), {"c": cc}
 
     with serve_step_scope("prefill"):
-        x, new_cache, moe_rows = _walk_layers(
+        x, new_cache, counts = _walk_layers(
             cfg, shards, x, positions, attend, attend_latent,
             _prefill_reduce(comm))
-        _hand_out(stats, moe_rows)
+        _hand_out(stats, counts)
         return x[:, -1] @ shards["unembed"], new_cache
 
 
@@ -657,10 +694,10 @@ def prefill_chunk_tp(cfg: TransformerConfig, shards, past, chunk,
                 {"c": rows.astype(p.dtype)})
 
     with serve_step_scope("prefill"):
-        x, rows, moe_rows = _walk_layers(
+        x, rows, counts = _walk_layers(
             cfg, shards, x, positions, attend, attend_latent,
             _prefill_reduce(comm))
-        _hand_out(stats, moe_rows)
+        _hand_out(stats, counts)
         return x[:, -1] @ shards["unembed"], rows
 
 
@@ -737,9 +774,9 @@ def decode_step_tp(cfg: TransformerConfig, shards, cache, tokens, pos,
         x = shards["embed"][tokens]
         if not cfg.rope:
             x = x + jnp.take(shards["pos"], pos, axis=0)
-        x, new_cache, moe_rows = _walk_layers(
+        x, new_cache, counts = _walk_layers(
             cfg, shards, x, pos, attend, attend_latent, reduce, live)
-        _hand_out(stats, moe_rows)
+        _hand_out(stats, counts)
         return x @ shards["unembed"], new_cache
 
 
@@ -834,9 +871,9 @@ def decode_step_paged(cfg: TransformerConfig, shards, pool, table,
         x = shards["embed"][tokens]
         if not cfg.rope:
             x = x + jnp.take(shards["pos"], pos, axis=0)
-        x, new_pool, moe_rows = _walk_layers(
+        x, new_pool, counts = _walk_layers(
             cfg, shards, x, pos, attend, attend_latent, reduce, live)
-        _hand_out(stats, moe_rows)
+        _hand_out(stats, counts)
         return x @ shards["unembed"], new_pool
 
 

@@ -54,6 +54,8 @@ _STEP_COUNTS = (("admitted", "admitted"),
                 ("decode_pages_live", "decode_pages_live"),
                 ("decode_pages_read", "decode_pages_read"),
                 ("decode_select_syncs", "decode_select_syncs"),
+                ("moe_zero_pairs", "moe_zero_pairs"),
+                ("moe_live_pairs", "moe_live_pairs"),
                 ("occupancy_ticks", "active"))
 
 
@@ -114,14 +116,16 @@ def serve_step_scope(what: str = "decode_step"):
 # under its own scope: forward, recomputed and backward instructions of a
 # compiled step all carry the name in their ``op_name``.
 LAYER_SCOPES = {"kda": "mpi4torch.kda", "mla": "mpi4torch.mla",
-                "moe": "mpi4torch.moe"}
+                "moe": "mpi4torch.moe", "ffn": "mpi4torch.ffn"}
 
 
 def layer_scope(kind: str):
     """Named scope ``mpi4torch.<kind>`` around one mechanism of a layer:
     ``kda`` (the gated delta-rule mixer, projections included), ``mla``
-    (the latent-attention mixer) or ``moe`` (router, grouped expert
-    products and shared expert)."""
+    (the latent-attention mixer), ``moe`` (router, grouped expert
+    products, shared and zero-compute experts) or ``ffn`` (the dense FFN
+    of a layer that carries or joins a shortcut branch, and of no other
+    layer: the path the branch runs beside)."""
     return _labeled_scope(LAYER_SCOPES[kind])
 
 
@@ -198,7 +202,14 @@ class ServeStats:
                  # steps' ``decode.select`` spent choosing tokens (one
                  # per live slot while the host chose them from the
                  # logits table; 0 since the step chooses them itself).
-                 "decode_select_syncs")
+                 "decode_select_syncs",
+                 # ISSUE 34: the live (token, chosen expert) pairs of the
+                 # compiled programs' expert layers that have
+                 # zero-compute experts, and those of them that chose
+                 # one (``kv._hand_out``; summed over a step's prefills
+                 # and decode step, expert layers and live rows).  Their
+                 # ratio is the share of choices that cost no expert.
+                 "moe_zero_pairs", "moe_live_pairs")
     SPAN_CAP = 1024
 
     def __init__(self):
@@ -371,7 +382,8 @@ def serve_step_log() -> list:
     per ``Engine.step()`` call of every engine, ``{"engine", "t0_ns",
     "t1_ns", "spans": [(name, t0_ns, t1_ns, rid), ...], "admitted",
     "prefill_tokens", "install_writes", "decode_pages_live",
-    "decode_pages_read", "decode_select_syncs", "active"}`` on the
+    "decode_pages_read", "decode_select_syncs", "moe_zero_pairs",
+    "moe_live_pairs", "active"}`` on the
     ``time.perf_counter_ns()`` clock, the last :data:`STEP_LOG_CAP`
     steps.  ``engine`` is the ``ServeStats.engine`` serial of the
     engine that stepped; the counts are what that step added to the

@@ -203,7 +203,7 @@ def test_no_row_is_dropped_under_skew():
     p = dict(_params()["blocks"][1]["experts"])
     x = _flat(_x())
     p["bias"] = p["bias"].at[0].set(10.0)
-    y, rows = moe.held_experts_ffn(x, p, spec)
+    y, rows, _ = moe.held_experts_ffn(x, p, spec)
     rows = np.asarray(rows)
     chosen, _ = moe.route_topk(x, p["router"], p["bias"], spec.top_k,
                                spec.scale)
@@ -229,8 +229,8 @@ def test_a_bfloat16_router_fails_the_comparison(monkeypatch):
     half = lambda a: a.astype(jnp.bfloat16).astype(a.dtype)
     route = moe.route_topk
     monkeypatch.setattr(
-        moe, "route_topk", lambda x_, router, bias, k, scale: route(
-            half(x_), half(router), bias, k, scale))
+        moe, "route_topk", lambda x_, router, bias, k, scale, **how: route(
+            half(x_), half(router), bias, k, scale, **how))
     out_p, (grads_p, _) = _value_and_grads(
         lambda p_, x_: moe.held_experts_ffn(_flat(x_), p_, spec)[0], 2)(p, x)
     rel = lambda a, b: np.linalg.norm(a - b) / np.linalg.norm(b)

@@ -238,9 +238,9 @@ def test_a_token_that_is_nobodys_takes_no_experts_time():
     x = jnp.asarray(np.random.default_rng(1).standard_normal(
         (6, CFG["hidden_size"])), F32)
     live = jnp.asarray([True, False, True, True, False, True])
-    y_all, rows_all = moe.held_experts_ffn(x, p, spec)
-    y, rows = moe.held_experts_ffn(x, p, spec, live=live)
-    _, rows_live = moe.held_experts_ffn(x[live], p, spec)
+    y_all, rows_all, _ = moe.held_experts_ffn(x, p, spec)
+    y, rows, _ = moe.held_experts_ffn(x, p, spec, live=live)
+    _, rows_live, _ = moe.held_experts_ffn(x[live], p, spec)
     assert np.array_equal(rows, rows_live) and rows.sum() < rows_all.sum()
     assert _gap(y[live], y_all[live]) < 1e-6
 
@@ -267,7 +267,7 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
                            scale=cfg["routed_scaling_factor"])
         part = dict(blk, w1=blk["w1"][rank:rank + 1],
                     w2=blk["w2"][rank:rank + 1])
-        y, rows = moe.held_experts_ffn(m[0], part, spec)
+        y, rows, _ = moe.held_experts_ffn(m[0], part, spec)
         total = total + y
     assert _gap(total - 15 * shared[0], whole[0]) < TOL
 
@@ -328,13 +328,15 @@ def test_a_layer_spec_is_served_on_one_rank_only():
 # ----------------------- the other configurations' programs did not move
 
 # sha256 of the lowered text of the parent commit's programs (PR 31,
-# 90d8c4d), taken in this suite's environment (x64 on, jax 0.9.0): Kimi's
-# training step and InternLM2's paged decode step at their rehearsal
-# sizes.  A PR that means to change one of these programs replaces its
-# line; one that does not has changed it by accident.
+# 90d8c4d; openPangu's own: PR 33, 8333d4b), taken in this suite's
+# environment (x64 on, jax 0.9.0): Kimi's training step, InternLM2's
+# paged decode step and openPangu's latent paged decode step at their
+# rehearsal sizes.  A PR that means to change one of these programs
+# replaces its line; one that does not has changed it by accident.
 PARENT_TEXTS = {
     "kimi": "f359630dfc06bad6b9b47ab71f11f2cacf8c82bfccc459b7a28af81c9d841366",
     "internlm2": "b22542455bf21f7b5b741190940b59ed6d71cd3aef36eaa38c803462c53d6e1c",
+    "openpangu": "3e458a5c3030085242fdcd60aafff19ea566a4426452705c6789586822b9b8c3",
 }
 
 
@@ -344,7 +346,8 @@ def _lowered_text(which: str) -> str:
     from benchmarks import program, weights
     from benchmarks.families import kimi_linear
 
-    name = {"kimi": "kimi-linear-48b-a3b", "internlm2": "internlm2-1.8b"}
+    name = {"kimi": "kimi-linear-48b-a3b", "internlm2": "internlm2-1.8b",
+            "openpangu": PUBLISHED["name"]}
     with open(os.path.join(ROOT, "benchmarks", "configs",
                            name[which] + ".json")) as f:
         cfg = json.load(f)
@@ -360,9 +363,12 @@ def _lowered_text(which: str) -> str:
             False)
         return step.lower(params, jax.ShapeDtypeStruct(
             (2, 64), jnp.int32, sharding=repl)).as_text()
-    eng = serve.Engine(program.transformer_config(cfg),
-                       weights.make_params(cfg, 1, F32),
-                       serve.ServeConfig(slots=4, block_size=8),
+    if which == "openpangu":
+        tcfg, params = TCFG, fam.make_params(CFG, 1, F32)
+    else:
+        tcfg = program.transformer_config(cfg)
+        params = weights.make_params(cfg, 1, F32)
+    eng = serve.Engine(tcfg, params, serve.ServeConfig(slots=4, block_size=8),
                        spmd=True, nranks=1)
     eng.submit(np.arange(1, 10), max_new=3)
     eng.step()

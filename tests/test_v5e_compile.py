@@ -243,45 +243,44 @@ def test_latent_read_compiles_at_openpangus_shapes(one_v5e_chip, as_on_tpu):
     assert _made_with_shape(text, "32,128,512") != []
 
 
-def test_openpangus_decode_step_compiles_in_place_at_its_real_size(
-        one_v5e_chip, as_on_tpu):
-    """The serving walk's paged decode step at the configuration's own
-    widths (4.92 B parameters, five layers, the 1.68 GB latent pool),
-    from shapes alone: every pool leaf is aliased to its output, each
-    latent layer reads through the kernel, each expert layer makes its
-    two grouped products, and the step's temporaries stay small beside
-    the 11.5 GB it is handed."""
+def _real_size(name: str, family, chip):
+    """A configuration at its own widths as shapes alone: ``(cfg, tcfg,
+    mesh, stacked params, parameter count, stacked, like)``."""
     import json
     import os
 
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    import mpi4torch_tpu as mpi
-    from benchmarks.families import openpangu_moe as fam
-    from mpi4torch_tpu.ops.spmd import run_spmd
-    from mpi4torch_tpu.serve import kv
-    from mpi4torch_tpu.serve.engine import select_rows
-
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "configs",
-                           "openpangu-ultra-moe-718b.json")) as f:
+                           name + ".json")) as f:
         cfg = json.load(f)
-    tcfg = fam.transformer_config(cfg)
-    mesh = Mesh(np.array([next(iter(one_v5e_chip.device_set))]), ("mpi",))
+    tcfg = family.transformer_config(cfg)
+    mesh = Mesh(np.array([next(iter(chip.device_set))]), ("mpi",))
     state, rep = NamedSharding(mesh, P("mpi")), NamedSharding(mesh, P())
     key, dt = jax.random.PRNGKey(0), jnp.bfloat16
     params = jax.eval_shape(lambda: dict(
-        fam.make_top(key, cfg, dt),
-        blocks=[fam.make_layer(key, cfg, i, dt)
+        family.make_top(key, cfg, dt),
+        blocks=[family.make_layer(key, cfg, i, dt)
                 for i in range(cfg["num_hidden_layers"])]))
-    assert sum(int(np.prod(a.shape))
-               for a in jax.tree.leaves(params)) == 4_919_140_864
-    slots, bs, nb = 32, 128, 2048
-    pool = jax.eval_shape(lambda: kv.init_kv_pool_tp(tcfg, nb, bs, 1, dt))
+    count = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params))
     stacked = lambda tree: jax.tree.map(
         lambda a: jax.ShapeDtypeStruct((1,) + a.shape, a.dtype,
                                        sharding=state), tree)
     like = lambda shape, d: jax.ShapeDtypeStruct(shape, d, sharding=rep)
+    return cfg, tcfg, mesh, stacked(params), count, stacked, like
+
+
+def _compiled_decode_step(tcfg, mesh, params, stacked, like, slots, bs, nb):
+    """The serving walk's paged decode step, as the engine traces it
+    (pool donated, tokens chosen inside), compiled from shapes alone."""
+    import mpi4torch_tpu as mpi
+    from mpi4torch_tpu.ops.spmd import run_spmd
+    from mpi4torch_tpu.serve import kv
+    from mpi4torch_tpu.serve.engine import select_rows
+
+    pool = jax.eval_shape(
+        lambda: kv.init_kv_pool_tp(tcfg, nb, bs, 1, jnp.bfloat16))
     mine = lambda tree: jax.tree.map(lambda a: a[0], tree)
 
     def step(shards, pool, table, tokens, pos, active):
@@ -292,19 +291,113 @@ def test_openpangus_decode_step_compiles_in_place_at_its_real_size(
         return (*select_rows(logits, None, 0.0, 0), pool, stats)
 
     with jax.enable_x64(False):
-        compiled = run_spmd(
+        return run_spmd(
             step, mesh=mesh, axis_name="mpi",
             donate_argnums=(1,)).lower_as_called(
-                stacked(params), stacked(pool),
-                like((slots, nb // slots), jnp.int32),
+                params, stacked(pool),
+                like((slots, tcfg.max_seq // bs), jnp.int32),
                 like((slots,), jnp.int32), like((slots,), jnp.int32),
                 like((slots,), bool)).compile()
+
+
+def _compiled_prefill(tcfg, mesh, params, like, n):
+    """The one-piece prefill of ``n`` tokens, as the engine traces it."""
+    import mpi4torch_tpu as mpi
+    from mpi4torch_tpu.ops.spmd import run_spmd
+    from mpi4torch_tpu.serve import kv
+
+    mine = lambda tree: jax.tree.map(lambda a: a[0], tree)
+
+    def prefill(shards, prompt):
+        stats = {}
+        cache = kv.init_kv_cache_tp(tcfg, 1, 1, jnp.bfloat16)
+        return (*kv.prefill_tp(tcfg, mine(shards), cache, prompt,
+                               mpi.COMM_WORLD, stats=stats), stats)
+
+    with jax.enable_x64(False):
+        return run_spmd(prefill, mesh=mesh, axis_name="mpi").lower_as_called(
+            params, like((1, n), jnp.int32)).compile()
+
+
+def _names(text: str, kernel: str) -> set:
+    return set(re.findall(r"%(" + re.escape(kernel) + r"[\w.]*) = ", text))
+
+
+def test_openpangus_decode_step_compiles_in_place_at_its_real_size(
+        one_v5e_chip, as_on_tpu):
+    """The serving walk's paged decode step at the configuration's own
+    widths (4.92 B parameters, five layers, the 1.68 GB latent pool),
+    from shapes alone: every pool leaf is aliased to its output, each
+    latent layer reads through the kernel, each expert layer makes its
+    two grouped products, and the step's temporaries stay small beside
+    the 11.5 GB it is handed."""
+    from benchmarks.families import openpangu_moe as fam
+
+    cfg, tcfg, mesh, params, count, stacked, like = _real_size(
+        "openpangu-ultra-moe-718b", fam, one_v5e_chip)
+    assert count == 4_919_140_864
+    slots, bs, nb = 32, 128, 2048
+    compiled = _compiled_decode_step(tcfg, mesh, params, stacked, like,
+                                     slots, bs, nb)
     text, mem = compiled.as_text(), compiled.memory_analysis()
     layers = cfg["num_hidden_layers"]
+    assert mem.alias_size_in_bytes == layers * nb * bs * 640 * 2
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert len(_names(text, "mpi4torch_paged_latent_attn")) == layers
+    assert len(_names(text, "ragged-dot-none")) \
+        == 2 * (layers - cfg["first_k_dense_replace"])
+
+
+def test_latent_read_compiles_at_longcats_shapes(one_v5e_chip, as_on_tpu):
+    """LongCat-Flash's decode read in `serve_scmoe_1k`: 32 slots, 64
+    query heads on rows of 640 channels, a pool of 1,024 pages."""
+    like = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_v5e_chip)
+    q, pool = like((32, 64, 640)), like((1024, 128, 1, 640))
+    assert pa.uses_kernel(q, pool, 512)
+    with jax.enable_x64(False):
+        text = jax.jit(lambda *a: pa.paged_latent_attention(
+            *a, v_width=512, scale=192 ** -0.5)).lower(
+                q, pool, like((32, 32), jnp.int32),
+                like((32,), jnp.int32)).compile().as_text()
+    assert pa.KERNEL_NAMES[1] in text and "tpu_custom_call" in text
+    assert set(_made_with_shape(text, "1024,128,1,640")) == {"parameter"}
+    assert _made_with_shape(text, "32,64,512") != []
+
+
+def test_longcats_programs_compile_at_their_real_size(
+        one_v5e_chip, as_on_tpu, capsys):
+    """LongCat-Flash-Chat as `serve_scmoe_1k` serves it (5.17 B
+    parameters, eight latent sublayers for four published layers, the
+    1.34 GB latent pool), from shapes alone.  The decode step: every
+    pool leaf aliased to its output, eight latent reads through the
+    kernel, two grouped products for each of the four shortcut
+    branches, small temporaries.  The longest prefill (2,048 tokens)
+    compiles and its temporaries fit beside weights and pool on a 16 GB
+    chip.  Both programs' temporaries are printed."""
+    from benchmarks.families import longcat_flash as fam
+
+    cfg, tcfg, mesh, params, count, stacked, like = _real_size(
+        "longcat-flash-chat", fam, one_v5e_chip)
+    assert count == 5_172_749_312
+    slots, bs, nb = 32, 128, 1024
+    layers = cfg["num_hidden_layers"]
     pool_bytes = layers * nb * bs * 640 * 2
+    compiled = _compiled_decode_step(tcfg, mesh, params, stacked, like,
+                                     slots, bs, nb)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
     assert mem.alias_size_in_bytes == pool_bytes
     assert mem.temp_size_in_bytes < 0.5e9
-    assert len(set(re.findall(r"%(mpi4torch_paged_latent_attn[\w.]*) = ",
-                              text))) == layers
-    assert len(set(re.findall(r"%(ragged-dot-none[\w.]*) = ", text))) \
-        == 2 * (layers - cfg["first_k_dense_replace"])
+    assert len(_names(text, "mpi4torch_paged_latent_attn")) == layers == 8
+    assert len(_names(text, "ragged-dot-none")) == 2 * cfg["num_layers"]
+    for scope in ("mpi4torch.mla", "mpi4torch.moe", "mpi4torch.ffn"):
+        assert scope in text, scope
+    pre = _compiled_prefill(tcfg, mesh, params, like, 2048).memory_analysis()
+    with capsys.disabled():
+        print(f"\nlongcat-flash-chat: {count:,} parameters; decode step "
+              f"temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB; "
+              f"2,048-token prefill temporaries "
+              f"{pre.temp_size_in_bytes / 1e9:.3f} GB, outputs "
+              f"{pre.output_size_in_bytes / 1e9:.3f} GB")
+    held = 2 * count + pool_bytes
+    assert held + pre.temp_size_in_bytes + pre.output_size_in_bytes < 15.7e9
