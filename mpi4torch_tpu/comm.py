@@ -389,11 +389,15 @@ class MPI_Communicator:
                        overlap=None, algorithm=None):
         """Fused bucketed Allreduce over a whole pytree
         (:mod:`mpi4torch_tpu.fuse`): the leaves are flattened into
-        dtype-homogeneous flat buckets of ~``bucket_bytes`` (layout
-        cached per tree structure) and each bucket rides ONE collective
-        — under SPMD, one ring reduce-scatter + all-gather pair —
-        instead of one launch per leaf, with consecutive buckets staged
-        to overlap.  Semantically equivalent to mapping
+        dtype-homogeneous buckets of ~``bucket_bytes`` (layout cached
+        per tree structure) and each bucket rides ONE collective —
+        under SPMD, one ``lax.psum`` forward and one in the adjoint; a
+        bucket of one leaf in the leaf's own shape — instead of one
+        launch per leaf.  Nothing is staged between buckets: the
+        reduce-scatter + all-gather pair and its barrier chain went in
+        PR 35, the chip having shown every collective exposed and the
+        pair a third dearer than the all-reduce.  Semantically
+        equivalent to mapping
         :meth:`Allreduce` over the leaves (and bit-identical to it on
         the eager backend); AD-transparent like every facade op — the
         backward pass is itself fused bucketed communication.
@@ -404,7 +408,8 @@ class MPI_Communicator:
         :attr:`size` once — the DP rank-mean as a single post-fuse
         scale (MPI_SUM only).  ``compression`` follows the
         :meth:`Allreduce` contract, applied per bucket.  ``overlap``
-        picks the scheduler (None = backend default; see
+        picks the scheduler (None = the blocking path, unless an
+        ``overlap_scope`` is active; see
         :func:`mpi4torch_tpu.fuse.fused_allreduce_tree`).
         ``algorithm`` follows the :meth:`Allreduce` contract, applied
         *per bucket*: with auto selection, small tail buckets take the

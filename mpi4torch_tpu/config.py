@@ -161,8 +161,8 @@ def default_overlap():
     active :func:`overlap_scope` on this thread, else the process-wide
     :func:`set_default_overlap` value.
 
-    ``None`` (default) keeps each backend's historical behavior (SPMD:
-    barrier-staged bucket interleave; eager: blocking rendezvous);
+    ``None`` (default) is the blocking schedule on both backends (one
+    whole collective a bucket, nothing staged between buckets);
     ``True`` enables the split-phase overlap scheduler
     (:mod:`mpi4torch_tpu.overlap`) with the default prefetch depth of
     2; an ``int >= 1`` enables it with that many collectives in

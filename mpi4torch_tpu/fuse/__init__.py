@@ -1,4 +1,4 @@
-"""Fused bucketed collectives with compute/communication overlap.
+"""Fused bucketed collectives.
 
 The per-leaf collective pattern (one Allreduce per pytree leaf —
 parallel/dp.py, parallel/zero.py, utils/lbfgs.py) pays per-collective
@@ -7,9 +7,9 @@ tensors.  This package eliminates that overhead the way production
 stacks do ("The Big Send-off", arxiv 2504.18658; GC3 from the compiler
 side): flatten the tree into a few dtype-homogeneous flat **buckets**
 (~``bucket_bytes`` each, layout cached per tree structure) and issue one
-collective — under SPMD, one ring reduce-scatter + all-gather *pair* —
-per bucket, with an overlap scheduler keeping consecutive buckets in
-flight simultaneously.
+collective — under SPMD, one ``lax.psum`` — per bucket; an explicit
+``overlap=`` hands the buckets to the split-phase scheduler
+(:mod:`mpi4torch_tpu.overlap`) to keep several in flight.
 
 Entry points::
 

@@ -118,26 +118,41 @@ def bucket_layout(tree, bucket_bytes: int) -> BucketLayout:
     return _layout(treedef, _leaf_avals(leaves), int(bucket_bytes))
 
 
-def flatten_buckets(tree, bucket_bytes: int):
-    """``tree -> (buckets, layout)``: the list of 1-D dtype-homogeneous
-    flat buckets holding every leaf, plus the layout to undo it."""
+def leaf_buckets(tree, bucket_bytes: int):
+    """``tree -> (buckets, layout)`` with a bucket that holds ONE leaf
+    left in that leaf's own shape; a bucket of several leaves is their
+    1-D concatenation.  A collective is element-wise, so the shape is
+    free to choose, and on the TPU a ``reshape(-1)`` of a tiled matrix
+    is a relayout, a copy of the whole leaf (PERF.md, PR 35)."""
     leaves, treedef = jax.tree.flatten(tree)
     layout = _layout(treedef, _leaf_avals(leaves), int(bucket_bytes))
     parts: List[List[Any]] = [[] for _ in layout.bucket_sizes]
     for leaf, slot in zip(leaves, layout.slots):
-        parts[slot.bucket].append(jnp.asarray(leaf).reshape(-1))
-    buckets = [p[0] if len(p) == 1 else jnp.concatenate(p) for p in parts]
+        parts[slot.bucket].append(jnp.asarray(leaf))
+    buckets = [p[0] if len(p) == 1
+               else jnp.concatenate([x.reshape(-1) for x in p])
+               for p in parts]
     return buckets, layout
 
 
+def flatten_buckets(tree, bucket_bytes: int):
+    """``tree -> (buckets, layout)``: the list of 1-D dtype-homogeneous
+    flat buckets holding every leaf, plus the layout to undo it."""
+    buckets, layout = leaf_buckets(tree, bucket_bytes)
+    return [b.reshape(-1) for b in buckets], layout
+
+
 def unflatten_buckets(buckets: Sequence, layout: BucketLayout):
-    """Inverse of :func:`flatten_buckets` (over possibly-transformed
-    bucket values of the same sizes/dtypes)."""
-    leaves = [
-        jax.lax.slice_in_dim(buckets[s.bucket], s.offset,
-                             s.offset + s.size).reshape(s.shape)
-        for s in layout.slots
-    ]
+    """Inverse of :func:`flatten_buckets` and of :func:`leaf_buckets`
+    (over possibly-transformed bucket values of the same sizes/dtypes):
+    a leaf that fills its bucket is the bucket, reshaped if it came
+    flat; any other is a slice of a flat one."""
+    leaves = []
+    for s in layout.slots:
+        b = buckets[s.bucket]
+        if s.size != layout.bucket_sizes[s.bucket]:
+            b = jax.lax.slice_in_dim(b, s.offset, s.offset + s.size)
+        leaves.append(b.reshape(s.shape))
     return jax.tree.unflatten(layout.treedef, leaves)
 
 

@@ -1,23 +1,34 @@
-"""Fused bucketed tree collectives with compute/communication overlap.
+"""Fused bucketed tree collectives.
 
-One collective (pair) per *bucket* instead of per leaf:
+One collective per *bucket* instead of per leaf:
 
-* :func:`fused_allreduce_tree` — the DP primitive.  Mode A (SPMD mesh)
-  lowers each exact-SUM bucket to a single ring **reduce-scatter +
-  all-gather pair** over the flat buffer (the two halves of a ring
-  allreduce, visible as one ``stablehlo.reduce_scatter`` + one
-  ``stablehlo.all_gather`` per bucket in the lowered program) and stages
-  consecutive buckets through a differentiable ``optimization_barrier``
-  interleave so bucket ``i``'s all-gather is issued only after bucket
-  ``i+1``'s reduce-scatter — at least two collectives in flight while
-  the result of the first is still being consumed.  Mode B (eager
-  thread-SPMD) runs one rendezvous collective per bucket (bit-identical
-  to the per-leaf ascending-rank fold), or — with ``overlap=True`` —
-  the :func:`_pipeline_allreduce` schedule: nonblocking per-bucket
+* :func:`fused_allreduce_tree` — the DP primitive.  Every bucket of the
+  blocking path is ONE whole ``comm.Allreduce``, on both backends.
+  Mode A (SPMD mesh) lowers an exact-SUM bucket to a single
+  ``lax.psum`` — one ``stablehlo.all_reduce`` a bucket forward and one
+  in the adjoint, and no ``reduce_scatter``, ``all_gather`` or
+  ``optimization_barrier`` — and a bucket that holds one leaf (every
+  matrix of a real model) travels in the leaf's own shape, with no
+  flat view before or after.  Mode B (eager thread-SPMD) runs one
+  rendezvous collective per bucket (bit-identical to the per-leaf
+  ascending-rank fold), or — with ``overlap=True`` — the
+  :func:`_pipeline_allreduce` schedule: nonblocking per-bucket
   gather-fold collectives built from the existing ``Isend``/``Irecv``/
   ``WaitHandle`` machinery, issuing bucket ``i+1``'s transfers before
   waiting on bucket ``i`` (``JoinDummiesHandle`` chains the issue
   order; the buffered eager sends make the overlap real).
+
+  Until PR 35 a Mode A bucket was a flat ring reduce-scatter +
+  all-gather pair, bucket ``i``'s all-gather staged behind bucket
+  ``i+1``'s reduce-scatter through ``optimization_barrier`` to keep two
+  collectives in flight.  That was written on a CPU.  On the chip every
+  collective is a synchronous instruction (``dp_collective_exposed_ms``
+  equal to ``dp_collective_ms`` in every check since PR 24), so the
+  staging hid nothing, and the TPU's compiler makes an all-reduce and a
+  slice of a flat ``psum_scatter``: each direction ran an all-reduce and
+  then an all-gather, a third more wire time than the all-reduce alone
+  (PERF.md, PR 35).  Asking for collectives in flight is the explicit
+  ``overlap=`` scheduler's (:mod:`mpi4torch_tpu.overlap`).
 
 * :func:`fused_reduce_scatter_tree` / :func:`fused_allgather_tree` —
   the ZeRO pair: block buckets whose row ``r`` concatenates every member
@@ -29,8 +40,8 @@ AD transparency is compositional: bucketing is differentiable
 reshape/concat/slice glue (fuse/bucketing.py) and every collective here
 is the facade's own ``custom_vjp`` op, so the backward pass of a fused
 bucketed collective is itself fused bucketed communication — the
-adjoint of the reduce-scatter + all-gather pair is the same pair on the
-cotangent buckets, in reverse bucket order.
+adjoint of a bucket's all-reduce is one all-reduce of the cotangent
+bucket, in reverse bucket order.
 
 Compression composes per bucket: ``compression="q8"`` (or an active
 ``compression_scope``) sends each float bucket through the quantized
@@ -55,7 +66,7 @@ from ..resilience import guards as _guards
 from ..runtime import CommError
 from ..utils.profiling import bucket_scope
 from .bucketing import (flatten_buckets, flatten_shard_buckets,
-                        flatten_shard_rows, shard_layout,
+                        flatten_shard_rows, leaf_buckets, shard_layout,
                         unflatten_buckets, unflatten_gathered,
                         unflatten_shard_rows)
 
@@ -229,26 +240,28 @@ def fused_allreduce_tree(comm, tree, op: int = C.MPI_SUM, *,
                          mean: bool = False,
                          overlap: Optional[bool] = None,
                          algorithm=None, tier_window=None):
-    """Allreduce every leaf of ``tree`` through dtype-homogeneous flat
-    buckets — one collective (pair) per bucket instead of per leaf.
+    """Allreduce every leaf of ``tree`` through dtype-homogeneous
+    buckets — one collective per bucket instead of per leaf.
 
     ``bucket_bytes``: target bucket size (None → the ``fusion_scope`` /
     process default, ~4 MiB; 0/False → unfused per-leaf ops).
     ``mean=True`` divides each reduced bucket by ``comm.size`` once —
     the DP rank-mean as a single post-fuse scale per bucket (MPI_SUM
     only).  ``compression`` follows the facade's Allreduce contract,
-    applied per bucket.  ``overlap``: None picks the backend default
-    (SPMD: barrier-staged interleave on; eager: rendezvous collectives);
-    ``True`` under the eager runtime switches to the nonblocking
-    Isend/Irecv pipeline (:func:`_pipeline_allreduce`) — exact MPI_SUM
-    only; requesting it with a codec or another reduction raises rather
-    than silently degrading to the blocking rendezvous.
+    applied per bucket.  ``overlap``: None picks the
+    ``config.overlap_scope`` / process default, which is the blocking
+    path (one whole collective a bucket, nothing staged); a truthy value
+    under the SPMD backend selects the split-phase scheduler
+    (:mod:`mpi4torch_tpu.overlap`) and under the eager runtime the
+    nonblocking Isend/Irecv pipeline (:func:`_pipeline_allreduce`) —
+    exact MPI_SUM only; requesting it with a codec or another reduction
+    raises rather than silently degrading to the blocking rendezvous.
 
     ``algorithm`` follows the facade's Allreduce contract
     (:mod:`mpi4torch_tpu.tune`), applied *per bucket*: an explicit name
     pins every bucket; with auto selection the tune selector picks per
-    bucket size, so the full body buckets keep the ring
-    reduce-scatter/all-gather pair — or, past the measured
+    bucket size, so the full body buckets keep the ring (one
+    ``lax.psum``) — or, past the measured
     ``config.bandwidth_crossover_bytes``, the multipath bandwidth
     algorithm (``bidir``'s counter-rotating dual ring) — while a small
     tail bucket below the measured latency crossover takes the
@@ -363,18 +376,17 @@ def fused_allreduce_tree(comm, tree, op: int = C.MPI_SUM, *,
             out = jax.tree.map(lambda p: p / size, out)
         return out
 
-    buckets, layout = flatten_buckets(tree, bb)
-    nb = layout.num_buckets
+    if overlap:
+        # Both overlap schedulers split and window 1-D buckets.
+        buckets, layout = flatten_buckets(tree, bb)
+        if not sched_ok:
+            from ..overlap import overlap_depth
+            reduced = _pipeline_allreduce(comm, buckets, op,
+                                          depth=overlap_depth(overlap))
+            if mean:
+                reduced = [b / size for b in reduced]
+            return unflatten_buckets(reduced, layout)
 
-    if overlap and not sched_ok:
-        from ..overlap import overlap_depth
-        reduced = _pipeline_allreduce(comm, buckets, op,
-                                      depth=overlap_depth(overlap))
-        if mean:
-            reduced = [b / size for b in reduced]
-        return unflatten_buckets(reduced, layout)
-
-    if overlap and sched_ok:
         # The split-phase overlap scheduler (mpi4torch_tpu.overlap):
         # windowed Allreduce_start/Wait pairs, sharing THIS function's
         # per-bucket codec/algorithm plan so the split-phase and
@@ -395,15 +407,13 @@ def fused_allreduce_tree(comm, tree, op: int = C.MPI_SUM, *,
             tier_window=(tier_window_depth() if tier_window is None
                          else tier_window))
 
-    # Phase 1: issue every bucket's reduction.  Exact-SUM buckets on the
-    # SPMD mesh take the explicit reduce-scatter half of the ring (the
-    # all-gather half is phase 2, so consecutive buckets overlap);
-    # everything else — eager rendezvous, compressed, non-SUM,
-    # deterministic-ordered — is a whole collective through the facade,
-    # one launch per bucket either way.
-    use_pair = (mode_a and op == C.MPI_SUM and size > 1
-                and not _config.deterministic_reductions())
-    stage = []
+    # The blocking path, both backends: every bucket is ONE whole
+    # Allreduce through the facade (on the SPMD mesh one ``lax.psum``,
+    # its adjoint one ``lax.psum``; why no pair, no staging: the module
+    # head), a bucket of one leaf in the leaf's own shape.
+    buckets, layout = leaf_buckets(tree, bb)
+    nb = layout.num_buckets
+    reduced = []
     for i, b in enumerate(buckets):
         # Per-bucket codec/algorithm pick (_plan_bucket, shared with the
         # split-phase scheduler): the facade's dtype degrade, the
@@ -416,56 +426,16 @@ def fused_allreduce_tree(comm, tree, op: int = C.MPI_SUM, *,
             comm, b, op, codec, algo, explicit=explicit,
             algo_explicit=algo_explicit, owns_resolution=owns_resolution,
             size=size, mode_a=mode_a)
-        pair_ok = use_pair and balgo in (None, "ring")
+        # Re-resolution guard: the degrade decision was already made
+        # here, so hand the facade the resolved codec, or False to pin
+        # exact (compression=None would re-read the scope default and
+        # re-apply a codec this bucket — or an explicit
+        # compression=False — just opted out of).
+        arg = bcodec if bcodec is not None else (
+            False if (codec is not None or explicit) else None)
         with bucket_scope("Allreduce_tree", i, nb, codec=bcodec):
-            if bcodec is not None or not pair_ok:
-                # Re-resolution guard: the degrade decision was already
-                # made here, so hand the facade the resolved codec, or
-                # False to pin exact (compression=None would re-read the
-                # scope default and re-apply a codec this bucket — or an
-                # explicit compression=False — just opted out of).
-                arg = bcodec if bcodec is not None else (
-                    False if (codec is not None or explicit) else None)
-                out = comm.Allreduce(b, op, compression=arg,
-                                     algorithm=balgo)
-                stage.append(("whole", i, out, None))
-            else:
-                seg = -(-b.size // size)
-                padded = b
-                if seg * size != b.size:
-                    padded = jnp.concatenate(
-                        [b, jnp.zeros((seg * size - b.size,), b.dtype)])
-                # Scatter the FLAT bucket (rank r keeps elements
-                # [r*seg, (r+1)*seg)), never a (size, seg) view of it:
-                # on the TPU that reshape is a relayout whose kernel
-                # took ~2 minutes to compile per 128 MiB bucket and
-                # brought the compiler down at 541M parameters on four
-                # chips (PERF.md, PR 22).  Same segments, same bits.
-                part = comm.Reduce_scatter(padded, op, 0)
-                stage.append(("part", i, part, b.size))
-
-    # Overlap staging: tie bucket i's scattered part to bucket i+1's
-    # through a differentiable optimization_barrier, so bucket i's
-    # all-gather cannot be issued (or hoisted) before bucket i+1's
-    # reduce-scatter — the staged interleave keeps >= 2 collectives in
-    # flight without adding any wire traffic.
-    part_idx = [k for k, s in enumerate(stage) if s[0] == "part"]
-    if overlap is not False and len(part_idx) > 1:
-        orig = [stage[k][2] for k in part_idx]
-        for j in range(len(part_idx) - 1):
-            k = part_idx[j]
-            kind, i, _, nelem = stage[k]
-            tied = jax.lax.optimization_barrier((orig[j], orig[j + 1]))[0]
-            stage[k] = (kind, i, tied, nelem)
-
-    # Phase 2: complete — all-gather the scattered parts, unpad, scale.
-    reduced = [None] * nb
-    for kind, i, val, nelem in stage:
-        if kind == "part":
-            with bucket_scope("Allreduce_tree", i, nb):
-                full = comm.Allgather(val, 0, compression=False)
-                val = full[:nelem]
-        reduced[i] = val / size if mean else val
+            out = comm.Allreduce(b, op, compression=arg, algorithm=balgo)
+        reduced.append(out / size if mean else out)
     return unflatten_buckets(reduced, layout)
 
 

@@ -24,19 +24,26 @@ def all_average_tree(comm, tree, bucket_bytes=None, overlap=None):
     ranks (reference: doc/examples.rst:46-65).
 
     Rides the fused bucketed path (:mod:`mpi4torch_tpu.fuse`) by
-    default: one collective pair per ~``bucket_bytes`` dtype-homogeneous
-    bucket instead of one Allreduce per leaf, and the ``/ comm.size``
-    mean folded into a single post-fuse scale per bucket instead of one
-    division per leaf.  Results stay bitwise lock-step across ranks
-    (every rank decodes the same gathered bucket), and the eager backend
-    is bit-identical to the historical per-leaf form.  Opt out with
-    ``bucket_bytes=0`` or ``config.fusion_scope(0)``.
+    default: one Allreduce per ~``bucket_bytes`` dtype-homogeneous
+    bucket instead of one per leaf (under SPMD one ``lax.psum`` forward
+    and one in the adjoint; a leaf that fills a bucket, as every matrix
+    of a real model does, goes in its own shape), and the
+    ``/ comm.size`` mean folded into a single post-fuse scale per bucket
+    instead of one division per leaf.  Results stay bitwise lock-step
+    across ranks (every rank gets the same bits from one all-reduce),
+    and the eager backend is bit-identical to the historical per-leaf
+    form.  Opt out with ``bucket_bytes=0`` or
+    ``config.fusion_scope(0)``.  (The SPMD path's reduce-scatter +
+    all-gather pair and its staging went in PR 35: on the chip the pair
+    was an all-reduce and then an all-gather, every one exposed.)
 
     ``overlap`` (None → the :func:`mpi4torch_tpu.config.overlap_scope`
     / process default): truthy selects the split-phase overlap
     scheduler (:mod:`mpi4torch_tpu.overlap`) under the SPMD backend —
     each bucket's reduce-scatter starts while earlier buckets are still
-    completing, up to the window depth in flight — and the nonblocking
+    completing, up to the window depth in flight (its start and wait
+    halves are that same pair, so on the chip it pays the pair's third
+    more) — and the nonblocking
     Isend/Irecv pipeline on the eager backend.  Bit-identical to the
     blocking form either way."""
     return comm.Allreduce_tree(tree, MPI_SUM, bucket_bytes=bucket_bytes,

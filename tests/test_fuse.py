@@ -3,9 +3,11 @@
 Four claims are pinned here:
 
 1. **Launch census** — a 100-leaf fp32 pytree Allreduce lowers to exactly
-   ONE reduce-scatter + all-gather pair under SPMD when it fits one
-   bucket, and to exactly ``ceil(total_bytes / bucket_bytes)`` pairs when
-   it does not (vs one all_reduce per leaf unfused).
+   ONE all-reduce under SPMD when it fits one bucket, and to exactly
+   ``ceil(total_bytes / bucket_bytes)`` of them when it does not (vs one
+   all_reduce per leaf unfused).  Since PR 35 no bucket of the blocking
+   path is a reduce-scatter + all-gather pair: on the chip that was an
+   all-reduce and then an all-gather, with nothing in flight.
 2. **Parity** — the fused path is bit-identical to the per-leaf path on
    the eager backend (same ascending-rank fold, concat changes nothing
    per element), including the Isend/Irecv overlap pipeline, and matches
@@ -19,6 +21,7 @@ Four claims are pinned here:
 """
 
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -38,14 +41,25 @@ COLLECTIVES = ("all_reduce", "all_gather", "reduce_scatter", "all_to_all",
                "collective_permute")
 
 
-def census(fn, *args):
-    """Collective-op census of ``fn`` lowered in a shard_map over a fresh
-    NR-device mesh (the test_hlo.py pattern)."""
+def lowered_text(fn, *args):
+    """``fn`` lowered in a shard_map over a fresh NR-device mesh (the
+    test_hlo.py pattern)."""
     mesh = Mesh(np.asarray(jax.devices()[:NR]), ("w",))
     c = mpi.comm_from_mesh(mesh, "w")
     wrapped = shard_map(lambda *a: fn(c, *a), mesh=mesh, in_specs=P(),
                         out_specs=P(), check_vma=False)
-    txt = jax.jit(wrapped).lower(*args).as_text()
+    return jax.jit(wrapped).lower(*args).as_text()
+
+
+def allreduce_operands(txt):
+    """The operand type of every ``stablehlo.all_reduce`` in ``txt``."""
+    return re.findall(r'"stablehlo\.all_reduce".*?:\s*\(tensor<([^>]+)>\)',
+                      txt, flags=re.S)
+
+
+def census(fn, *args):
+    """Collective-op census of ``fn`` lowered so."""
+    txt = lowered_text(fn, *args)
     return {k: txt.count(f"stablehlo.{k}") for k in COLLECTIVES}
 
 
@@ -116,34 +130,36 @@ class TestBucketing:
 
 
 class TestFusedCensus:
-    def test_100_leaves_one_collective_pair(self):
+    def test_100_leaves_one_collective(self):
         # The ISSUE 2 acceptance bar: <= 4 MiB of fp32 leaves -> exactly
-        # one fused ring reduce-scatter + all-gather pair, nothing else.
+        # one fused collective, nothing else; since PR 35 one all-reduce.
         got = census(lambda c, t: c.Allreduce_tree(t, mpi.MPI_SUM),
                      tree100())
-        assert got == {"all_reduce": 0, "all_gather": 1,
-                       "reduce_scatter": 1, "all_to_all": 0,
+        assert got == {"all_reduce": 1, "all_gather": 0,
+                       "reduce_scatter": 0, "all_to_all": 0,
                        "collective_permute": 0}
 
-    def test_buckets_scatter_flat_never_as_size_by_seg(self):
+    def test_no_bucket_is_scattered_or_viewed_as_size_by_seg(self):
         # On the TPU a (size, seg) view of a flat bucket is a relayout
         # whose kernel took minutes to compile at 128 MiB and crashed
-        # the compiler at 541M parameters on four chips (PR 22): the
-        # pair must reduce-scatter and all-gather rank-1 operands.
-        import re
-
-        mesh = Mesh(np.asarray(jax.devices()[:NR]), ("w",))
-        c = mpi.comm_from_mesh(mesh, "w")
-        fn = shard_map(lambda t: c.Allreduce_tree(t, mpi.MPI_SUM),
-                       mesh=mesh, in_specs=P(), out_specs=P(),
-                       check_vma=False)
-        txt = jax.jit(fn).lower(
-            {"w": jnp.ones((64, 48), jnp.float32)}).as_text()
-        wires = re.findall(
-            r'"stablehlo\.(?:reduce_scatter|all_gather)".*?:\s*'
-            r'\(tensor<([^>]+)>\)', txt, flags=re.S)
-        assert len(wires) == 2
-        assert all(w.count("x") == 1 for w in wires), wires
+        # the compiler at 541M parameters on four chips (PR 22), and a
+        # flat reduce-scatter compiles to an all-reduce and a slice
+        # (PR 35): no bucket of the blocking path, of one leaf or of
+        # several, forward or adjoint, is split over the ranks at all.
+        t = {"w": jnp.ones((64, 48), jnp.float32),
+             "a": jnp.ones((8, 6), jnp.float64),
+             "b": jnp.ones((5,), jnp.float64)}
+        for fn in (lambda c, tt: c.Allreduce_tree(tt, mpi.MPI_SUM),
+                   lambda c, tt: jax.grad(lambda u: sum(
+                       jnp.sum(v * v) for v in jax.tree.leaves(
+                           c.Allreduce_tree(u, mpi.MPI_SUM, mean=True))))(
+                               tt)):
+            txt = lowered_text(fn, t)
+            for gone in ("reduce_scatter", "all_gather", "dynamic_slice"):
+                assert f"stablehlo.{gone}" not in txt, gone
+            made = re.findall(r"stablehlo\.reshape.*?-> tensor<([^>]+)>", txt)
+            assert made and not [m for m in made
+                                 if m.startswith(f"{NR}x")], made
 
     def test_unfused_baseline_is_per_leaf(self):
         got = census(
@@ -154,7 +170,7 @@ class TestFusedCensus:
 
     def test_bucket_count_matches_ceil_bound(self):
         # 100 leaves x 64 B; bucket_bytes=1024 packs exactly 16 leaves
-        # per bucket -> ceil(6400/1024) = 7 pairs.
+        # per bucket -> ceil(6400/1024) = 7 all-reduces.
         t = tree100()
         total = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(t))
         bb = 1024
@@ -162,7 +178,8 @@ class TestFusedCensus:
         got = census(
             lambda c, tt: c.Allreduce_tree(tt, mpi.MPI_SUM,
                                            bucket_bytes=bb), t)
-        assert got["reduce_scatter"] == got["all_gather"] == expect
+        assert got["all_reduce"] == expect
+        assert got["reduce_scatter"] == got["all_gather"] == 0
 
     def test_fusion_scope_zero_disables(self):
         def f(c, t):
@@ -179,14 +196,14 @@ class TestFusedCensus:
                 return c.Allreduce_tree(t, mpi.MPI_SUM)
 
         got = census(f, tree100())
-        assert got["reduce_scatter"] == 7
+        assert got["all_reduce"] == 7
         # and the default is restored outside the scope
         assert mpi.config.default_bucket_bytes() \
             == mpi.config.DEFAULT_BUCKET_BYTES
 
     def test_backward_is_bucketed_too(self):
         # AD transparency at the launch level: fwd+bwd of one fused
-        # bucket is two pairs, not 100 + 100 per-leaf collectives.
+        # bucket is two all-reduces, not 100 + 100 per-leaf collectives.
         def f(c, t):
             def loss(tt):
                 y = c.Allreduce_tree(tt, mpi.MPI_SUM)
@@ -194,9 +211,46 @@ class TestFusedCensus:
             return jax.grad(loss)(t)
 
         got = census(f, tree100())
-        assert got["all_reduce"] == 0
-        assert got["reduce_scatter"] == 2
-        assert got["all_gather"] == 2
+        assert got["all_reduce"] == 2
+        assert got["reduce_scatter"] == got["all_gather"] == 0
+
+    @pytest.mark.parametrize("overlap", [None, False])
+    def test_buckets_are_not_staged_behind_one_another(self, overlap):
+        # Seven buckets, forward and adjoint: the chain of
+        # optimization_barriers that held bucket i's all-gather behind
+        # bucket i+1's reduce-scatter went with the pair (PR 35), and
+        # overlap=False, which used to switch it off, now says nothing.
+        def f(c, t):
+            return jax.grad(lambda tt: sum(
+                jnp.vdot(v, v) for v in jax.tree.leaves(c.Allreduce_tree(
+                    tt, mpi.MPI_SUM, bucket_bytes=1024, mean=True,
+                    overlap=overlap))))(t)
+
+        txt = lowered_text(f, tree100())
+        assert txt.count("stablehlo.all_reduce") == 14
+        assert "optimization_barrier" not in txt
+
+    def test_one_leaf_bucket_travels_in_the_leafs_shape(self):
+        # A leaf alone in its bucket (every matrix of a real model: the
+        # buckets are 4 MiB) is reduced as it is and comes back as it
+        # is.  Flat, the TPU pays a relayout of a tiled array each way
+        # (PERF.md, PR 35); the small leaves beside it still share one
+        # flat bucket.
+        t = {"w": jnp.ones((64, 48), jnp.float32)}
+        avg = lambda c, tt: c.Allreduce_tree(tt, mpi.MPI_SUM, mean=True)
+        grad = lambda c, tt: jax.grad(
+            lambda u: jnp.sum(avg(c, u)["w"] ** 2))(tt)
+        for fn, n in ((avg, 1), (grad, 2)):
+            txt = lowered_text(fn, t)
+            assert "stablehlo.reshape" not in txt
+            assert "stablehlo.slice" not in txt
+            assert allreduce_operands(txt) == ["64x48xf32"] * n
+        mixed = dict(t, b=jnp.ones((48,), jnp.float32),
+                     s=jnp.ones((), jnp.float32))
+        txt = lowered_text(
+            lambda c, tt: c.Allreduce_tree(tt, mpi.MPI_SUM,
+                                           bucket_bytes=4096), mixed)
+        assert sorted(allreduce_operands(txt)) == ["49xf32", "64x48xf32"]
 
     def test_compressed_buckets_ship_int8(self):
         mesh = Mesh(np.asarray(jax.devices()[:NR]), ("w",))
@@ -208,7 +262,6 @@ class TestFusedCensus:
 
         txt = jax.jit(shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
                                 check_vma=False)).lower(t).as_text()
-        import re
         assert re.search(r"collective_permute.*xi8>", txt), \
             "fused q8 bucket did not ride the int8 ring"
         assert txt.count("stablehlo.all_reduce") == 0
@@ -360,6 +413,47 @@ class TestParity:
             lambda a, b: np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=1e-12, atol=1e-12),
             gf, gr)
+
+    @staticmethod
+    def _small_and_large(r):
+        # "w" and "e" overflow a 256 B bucket and travel alone, in their
+        # own shapes; "b", "s" and "g" share flat buckets.
+        rng = np.random.default_rng(5)
+        t = {"w": rng.standard_normal((9, 7)), "b": rng.standard_normal(7),
+             "s": rng.standard_normal(()), "e": rng.standard_normal((3, 4, 5)),
+             "g": rng.standard_normal((2, 3))}
+        return jax.tree.map(lambda x: jnp.asarray(x) * (r + 1.0), t)
+
+    @staticmethod
+    def _fused_and_perleaf(t, mean):
+        """(value, grads) through 256 B buckets and through one
+        ``Allreduce`` a leaf."""
+        div = comm.size if mean else 1
+        vg = lambda reduce: jax.value_and_grad(lambda tt: sum(
+            jnp.vdot(v, v) for v in jax.tree.leaves(reduce(tt))))(t)
+        return (vg(lambda tt: comm.Allreduce_tree(
+                    tt, mpi.MPI_SUM, mean=mean, bucket_bytes=256)),
+                vg(lambda tt: jax.tree.map(
+                    lambda p: comm.Allreduce(p, mpi.MPI_SUM) / div, tt)))
+
+    @pytest.mark.parametrize("mean", [False, True])
+    def test_eager_small_and_large_leaves_bitwise_equal_perleaf(self, mean):
+        def body():
+            return jax.tree.map(np.asarray, self._fused_and_perleaf(
+                self._small_and_large(float(comm.rank)), mean))
+
+        for fused, ref in mpi.run_ranks(body, NR):
+            jax.tree.map(np.testing.assert_array_equal, fused, ref)
+
+    @pytest.mark.parametrize("mean", [False, True])
+    def test_spmd_small_and_large_leaves_match_perleaf(self, mean):
+        fused, ref = mpi.run_spmd(lambda: self._fused_and_perleaf(
+            self._small_and_large(jnp.asarray(comm.rank + 0.0)), mean),
+            nranks=NR)()
+        jax.tree.map(
+            lambda a, b: np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-12, atol=1e-12),
+            fused, ref)
 
     def test_nonsum_op_fused(self):
         def body():

@@ -1228,9 +1228,9 @@ class TestFusePerBucket:
         got, _ = census(
             lambda c, t: c.Allreduce_tree(t, mpi.MPI_SUM,
                                           bucket_bytes=8192), self.TREE)
-        # body bucket: the ring reduce-scatter + all-gather pair; tail
+        # body bucket: one all-reduce (the ring, whole; PR 35); tail
         # bucket (40 B < crossover): the rhd butterfly
-        assert got == only(reduce_scatter=1, all_gather=1,
+        assert got == only(all_reduce=1,
                            collective_permute=2 * logn), got
 
     def test_body_bucket_takes_bidir_past_bandwidth_crossover(self):
@@ -1247,11 +1247,13 @@ class TestFusePerBucket:
         assert got == only(
             collective_permute=4 * (CENSUS_NR - 1) + 2 * logn), got
 
-    def test_without_crossover_all_buckets_keep_ring_pair(self):
+    def test_without_crossover_every_bucket_is_one_ring_allreduce(self):
+        # Since PR 35 a bucket's ring is one whole all-reduce, not a
+        # reduce-scatter + all-gather pair.
         got, _ = census(
             lambda c, t: c.Allreduce_tree(t, mpi.MPI_SUM,
                                           bucket_bytes=8192), self.TREE)
-        assert got == only(reduce_scatter=2, all_gather=2), got
+        assert got == only(all_reduce=2), got
 
     def test_explicit_algorithm_pins_every_bucket(self):
         logn = int(math.ceil(math.log2(CENSUS_NR)))

@@ -23,16 +23,22 @@ F32 = jnp.float32
 
 
 @pytest.fixture(scope="module")
-def one_v5e_chip():
-    """A described (not attached) v5e chip: compile-only."""
+def v5e_2x2():
+    """The four described (not attached) chips of a v5e host:
+    compile-only."""
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:                       # no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 @pytest.fixture
@@ -401,3 +407,42 @@ def test_longcats_programs_compile_at_their_real_size(
               f"{pre.output_size_in_bytes / 1e9:.3f} GB")
     held = 2 * count + pool_bytes
     assert held + pre.temp_size_in_bytes + pre.output_size_in_bytes < 15.7e9
+
+
+def test_dp_step_compiles_to_all_reduces_alone(v5e_2x2):
+    """`train_dp4`'s program at a small size on the four chips, as the
+    benchmark builds it: matrices of 8 MiB that travel alone beside
+    norm scales that share a bucket.  The chip's compiler makes an
+    all-reduce and a slice of a flat reduce-scatter, so the pair that a
+    bucket was until PR 35 ran an all-reduce AND an all-gather a
+    direction; a bucket is one all-reduce now and nothing is gathered.
+    The kernels are not this test's (the dispatch stays the CPU's)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from benchmarks import program, weights
+
+    cfg = {"vocab_size": 2048, "hidden_size": 2048, "intermediate_size": 2048,
+           "num_hidden_layers": 2, "num_attention_heads": 16,
+           "num_key_value_heads": 4, "max_position_embeddings": 128,
+           "sliding_window": 128, "rms_norm_eps": 1e-5, "rope_theta": 1e4}
+    mesh = Mesh(np.asarray(v5e_2x2), ("mpi",))
+    rep = NamedSharding(mesh, P())
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep)
+    params = jax.tree.map(like, jax.eval_shape(
+        lambda: weights.make_params(cfg, 0, jnp.bfloat16)))
+    tokens = jax.ShapeDtypeStruct((4 * 2, 128), jnp.int32, sharding=rep)
+    step = program.build_train_step(
+        program.transformer_config(cfg, remat=True), mesh, 2, 0.3, True)
+    with jax.enable_x64(False):
+        lowered = step.lower(params, tokens)
+        text = lowered.compile().as_text()
+    text = re.sub(r"/\*.*?\*/", "", text)      # a tuple's /*index=5*/
+    instr = lambda op: re.findall(rf"= [^=\n]*\s{op}\(", text)
+    assert instr("all-reduce") and not instr("all-gather")
+    assert not instr("reduce-scatter") and not instr("all-gather-start")
+    low = lowered.as_text()
+    assert "stablehlo.all_gather" not in low
+    assert "stablehlo.reduce_scatter" not in low
+    # the matrices' buckets, forward and adjoint, in their own shapes
+    assert low.count("tensor<2048x2048xbf16>) -> tensor<2048x2048xbf16>") \
+        >= 2 * 2 * 2
