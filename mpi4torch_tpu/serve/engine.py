@@ -80,7 +80,8 @@ STATUS_SHED = "shed"
 # The spans of one :meth:`Engine.step` (``ServeStats.span``; the names
 # are an API — doc/serving.md says what each covers and whether it ends
 # in a device sync).  One span per phase, never one per page, cache
-# leaf or token: six a step plus four per admitted request or chunk.
+# leaf or token: ten a step that decodes (``dispatch`` and ``fetch``
+# each with two children) plus four per admitted request or chunk.
 SPAN_STEP = _prof.STEP_SPAN
 SPAN_EXPIRE = SPAN_STEP + ".expire"
 SPAN_ADMIT = SPAN_STEP + ".admit"
@@ -89,7 +90,11 @@ SPAN_PREFILL = SPAN_ADMIT + ".prefill"
 SPAN_INSTALL = SPAN_ADMIT + ".install"
 SPAN_FIRST_TOKEN = SPAN_ADMIT + ".first_token"
 SPAN_DISPATCH = SPAN_STEP + ".decode.dispatch"
+SPAN_DISPATCH_INPUTS = SPAN_DISPATCH + ".inputs"
+SPAN_DISPATCH_CALL = SPAN_DISPATCH + ".call"
 SPAN_FETCH = SPAN_STEP + ".decode.fetch"
+SPAN_FETCH_TOKENS = SPAN_FETCH + ".tokens"
+SPAN_FETCH_COUNTERS = SPAN_FETCH + ".counters"
 SPAN_SELECT = SPAN_STEP + ".decode.select"
 
 
@@ -853,7 +858,10 @@ class Engine:
         with an expert layer, in the order of the calls; and, from a
         program whose expert layers have zero-compute experts, the two
         counters ``moe_zero_pairs`` and ``moe_live_pairs``, which the
-        step record carries by name like every counter."""
+        step record carries by name like every counter.  A program
+        that counts nothing costs nothing here."""
+        if not stats:
+            return
         stats = {k: self._fetch(v) for k, v in jax.device_get(stats).items()}
         if "moe_rows" in stats:
             self.stats.attach("moe_rows", (program, stats["moe_rows"]))
@@ -1298,14 +1306,17 @@ class Engine:
                 # device has run it.
                 toks, keys, counters = self._dispatch_decode()
             with span(SPAN_FETCH):
-                # The one sync: waits for the step and for every write
-                # queued before it, then copies (slots,) tokens (and
-                # the keys of a sampling engine, and the step's own
-                # counters, which are there once the tokens are).
-                toks = self._fetch(toks)
-                if keys is not None:
-                    keys = self._fetch(keys)
-                self._note_counters("decode", counters)
+                with span(SPAN_FETCH_TOKENS):
+                    # The one sync: waits for the step and for every
+                    # write queued before it, then copies (slots,)
+                    # tokens (and the keys of a sampling engine).
+                    toks = self._fetch(toks)
+                    if keys is not None:
+                        keys = self._fetch(keys)
+                with span(SPAN_FETCH_COUNTERS):
+                    # The step's own counters, which are there once
+                    # the tokens are: a copy, no second wait.
+                    self._note_counters("decode", counters)
             with span(SPAN_SELECT):
                 # The host's bookkeeping; no device call.
                 self.stats.tick(len(active), self.serve_cfg.slots)
@@ -1342,22 +1353,25 @@ class Engine:
         uploaded: ``([table,] tokens, pos, live, keys)``.  ``keys`` are
         the live slots' requests' keys as ``(slots, ...)`` raw key
         bits (zeros in the other rows), or None in a greedy engine,
-        which moves no key."""
+        which moves no key.  Every host-to-device transfer made here
+        is counted in ``decode_uploads``."""
         slots = self.serve_cfg.slots
         live = np.asarray([self._slot_req[j] is not None
                            and not self._prefilling[j]
                            for j in range(slots)])
-        keys = None
-        if self.serve_cfg.temperature > 0:
+        sampling = self.serve_cfg.temperature > 0
+        host = [self._table] if self._paged else []
+        host += [self._tokens, self._pos, live]
+        if sampling:
             rows = {j: np.asarray(self._slot_req[j].key)
                     for j in np.flatnonzero(live)}
             blank = np.zeros_like(next(iter(rows.values()),
                                        np.zeros(2, np.uint32)))
-            keys = jnp.asarray(np.stack(
+            host.append(np.stack(
                 [rows.get(j, blank) for j in range(slots)]))
-        table = (jnp.asarray(self._table),) if self._paged else ()
-        return (*table, jnp.asarray(self._tokens), jnp.asarray(self._pos),
-                jnp.asarray(live), keys)
+        self.stats.count("decode_uploads", len(host))
+        uploaded = tuple(jnp.asarray(a) for a in host)
+        return uploaded if sampling else (*uploaded, None)
 
     def _dispatch_decode(self):
         """Queue ONE decode step over the slot table (the new cache
@@ -1369,22 +1383,27 @@ class Engine:
         paged step takes the pool over and writes into it: whoever held
         ``self._cache``'s old leaves holds deleted arrays afterwards,
         as after an install."""
-        args = self._step_inputs()
-        if self._spmd:
-            toks, keys, self._cache, counters = self._step_call(
-                self._shards, self._cache, *args)
-            return toks, keys, counters
-        *inputs, live, keys = args
-        decode = _kv.decode_step_paged if self._paged \
-            else _kv.decode_step_tp
-        extra = {"donate": True} if self._paged else {}
-        counters = {}
-        logits, self._cache = decode(
-            self.cfg, self._shards, self._cache, *inputs, self._comm,
-            overlap=self.serve_cfg.overlap,
-            algorithm=self.serve_cfg.algorithm, active=live,
-            stats=counters, **extra)
-        return (*self._select_rows(logits, keys), counters)
+        span = self.stats.span
+        with span(SPAN_DISPATCH_INPUTS):
+            args = self._step_inputs()
+        with span(SPAN_DISPATCH_CALL):
+            # Ends when the call has returned: the arguments flattened
+            # and the program queued, not run.
+            if self._spmd:
+                toks, keys, self._cache, counters = self._step_call(
+                    self._shards, self._cache, *args)
+                return toks, keys, counters
+            *inputs, live, keys = args
+            decode = _kv.decode_step_paged if self._paged \
+                else _kv.decode_step_tp
+            extra = {"donate": True} if self._paged else {}
+            counters = {}
+            logits, self._cache = decode(
+                self.cfg, self._shards, self._cache, *inputs, self._comm,
+                overlap=self.serve_cfg.overlap,
+                algorithm=self.serve_cfg.algorithm, active=live,
+                stats=counters, **extra)
+            return (*self._select_rows(logits, keys), counters)
 
     def _pool_levels(self) -> None:
         """Mirror the block pool's population into the gauge-semantics

@@ -29,6 +29,7 @@ import threading
 import time
 from collections import deque
 
+from jax import monitoring as _monitoring
 from jax.profiler import TraceAnnotation
 
 __all__ = ["profiler_trace", "bucket_scope", "serve_step_scope",
@@ -56,7 +57,16 @@ _STEP_COUNTS = (("admitted", "admitted"),
                 ("decode_select_syncs", "decode_select_syncs"),
                 ("moe_zero_pairs", "moe_zero_pairs"),
                 ("moe_live_pairs", "moe_live_pairs"),
+                ("decode_uploads", "decode_uploads"),
+                ("step_compiles", "step_compiles"),
                 ("occupancy_ticks", "active"))
+# JAX's own event when a backend compilation ends (a persistent-cache
+# hit included): the one ``benchmarks`` counts as ``compiles_in_window``.
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# ``.stats``: the ServeStats whose step is open on this thread (one
+# engine's spans are written by the one thread that steps it, and a
+# compilation runs on the thread that called the program).
+_STEPPING = threading.local()
 
 
 def bucket_scope(op: str, index: int, total: int, codec=None, phase=None):
@@ -209,7 +219,14 @@ class ServeStats:
                  # one (``kv._hand_out``; summed over a step's prefills
                  # and decode step, expert layers and live rows).  Their
                  # ratio is the share of choices that cost no expert.
-                 "moe_zero_pairs", "moe_live_pairs")
+                 "moe_zero_pairs", "moe_live_pairs",
+                 # ISSUE 36: host-to-device transfers the decode steps'
+                 # ``decode.dispatch.inputs`` made (table, tokens,
+                 # positions, live mask, and the keys where the engine
+                 # samples), and backend compilations that ended while
+                 # a step was open (each also on that step's record
+                 # under ``compiles``, with the span it ended in).
+                 "decode_uploads", "step_compiles")
     SPAN_CAP = 1024
 
     def __init__(self):
@@ -221,6 +238,7 @@ class ServeStats:
         self._open = None                 # spans of the step that is open
         self._open_attached = {}          # what attach() put on it
         self._open_counts = None
+        self._stack = []                  # the open spans, innermost last
 
     def reset(self) -> None:
         """Zero the counters and drop the spans and phase totals (in
@@ -236,7 +254,7 @@ class ServeStats:
         """Context manager around one phase of ``Engine.step()``: a
         ``jax.profiler.TraceAnnotation(name)`` (so that in any xplane
         capture the span sits on the trace's own clock beside the
-        device lines; inert when no profiler session runs) and one
+        device lines; not made at all while no profiler session records) and one
         ``(name, t0_ns, t1_ns, rid)`` from ``time.perf_counter_ns()``
         — the clock :meth:`mark` reads — appended to the record of the
         step that is open.  :data:`STEP_SPAN` opens that record and,
@@ -252,16 +270,27 @@ class ServeStats:
         ``key``: a list, in the order attached (nothing where no step is
         open).  For what a step's compiled programs count themselves:
         ``moe_rows``, one ``(program, rows)`` per call of a program with
-        an expert layer (``serve.Engine._note_counters``).  A record
-        has the key only where something was attached."""
+        an expert layer (``serve.Engine._note_counters``); and
+        ``compiles``, one ``(span, rid, seconds)`` per backend
+        compilation that ended while the step was open, with the
+        innermost span open then.  A record has the key only where
+        something was attached."""
         if self._open is not None:
             self._open_attached.setdefault(key, []).append(value)
 
     def _open_step(self) -> None:
         self._open = []
         self._open_attached = {}
+        _STEPPING.stats = self
         with self._lock:
             self._open_counts = [self.counters[c] for c, _ in _STEP_COUNTS]
+
+    def _compiled(self, seconds: float) -> None:
+        """A backend compilation ended on the thread of the open step
+        (whose own span is on the stack for as long as it is open)."""
+        inner = self._stack[-1]
+        self.attach("compiles", (inner._name, inner.rid, float(seconds)))
+        self.count("step_compiles")
 
     def _span_closed(self, name: str, t0: int, t1: int, rid) -> None:
         spans = self._open
@@ -271,6 +300,7 @@ class ServeStats:
         if name != STEP_SPAN:
             return
         self._open = None
+        _STEPPING.stats = None
         record = {"engine": self.engine, "t0_ns": t0, "t1_ns": t1,
                   "spans": spans, **self._open_attached}
         with self._lock:
@@ -361,20 +391,39 @@ class _Span:
         self.rid = rid
         self._stats = stats
         self._name = name
-        self._ann = TraceAnnotation(name)
+        # An annotation made while no profiler session records stays
+        # inert when one starts, so none is made then: between two big
+        # calls of a step, with cold caches, it is half a span's cost.
+        self._ann = TraceAnnotation(name) \
+            if TraceAnnotation.is_enabled() else None
 
     def __enter__(self):
         if self._name == STEP_SPAN:
             self._stats._open_step()
-        self._ann.__enter__()
+        self._stats._stack.append(self)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
-        self._ann.__exit__(*exc)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._stats._stack.pop()
         self._stats._span_closed(self._name, self._t0, t1, self.rid)
         return False
+
+
+def _on_compile(event: str, seconds: float, **_) -> None:
+    if event == _COMPILE_EVENT:
+        stats = getattr(_STEPPING, "stats", None)
+        if stats is not None:
+            stats._compiled(seconds)
+
+
+# Once a process; it does nothing until a compilation ends.
+_monitoring.register_event_duration_secs_listener(_on_compile)
 
 
 def serve_step_log() -> list:
@@ -383,9 +432,10 @@ def serve_step_log() -> list:
     "t1_ns", "spans": [(name, t0_ns, t1_ns, rid), ...], "admitted",
     "prefill_tokens", "install_writes", "decode_pages_live",
     "decode_pages_read", "decode_select_syncs", "moe_zero_pairs",
-    "moe_live_pairs", "active"}`` on the
-    ``time.perf_counter_ns()`` clock, the last :data:`STEP_LOG_CAP`
-    steps.  ``engine`` is the ``ServeStats.engine`` serial of the
+    "moe_live_pairs", "decode_uploads", "step_compiles", "active"}`` on
+    the ``time.perf_counter_ns()`` clock, the last :data:`STEP_LOG_CAP`
+    steps (and ``moe_rows`` / ``compiles`` where :meth:`ServeStats.attach`
+    put them).  ``engine`` is the ``ServeStats.engine`` serial of the
     engine that stepped; the counts are what that step added to the
     counters of the same name (``active``: the slots it decoded)."""
     return list(_STEP_LOG)

@@ -35,6 +35,10 @@ LEAVES = 2 * CFG.n_layers            # a K and a V leaf per layer
 ADMIT_PHASES = [E.SPAN_PLAN, E.SPAN_PREFILL, E.SPAN_INSTALL,
                 E.SPAN_FIRST_TOKEN]
 DECODE_PHASES = [E.SPAN_DISPATCH, E.SPAN_FETCH, E.SPAN_SELECT]
+# A decode step's spans in the order they close: children first.
+DECODE_SPANS = [E.SPAN_DISPATCH_INPUTS, E.SPAN_DISPATCH_CALL,
+                E.SPAN_DISPATCH, E.SPAN_FETCH_TOKENS,
+                E.SPAN_FETCH_COUNTERS, E.SPAN_FETCH, E.SPAN_SELECT]
 
 # (paged, spmd): every engine the spans must read the same on.
 ENGINES = [pytest.param(False, False, id="dense-eager"),
@@ -124,9 +128,69 @@ class TestStepSpans:
         eng.step()
         rec = log_of(eng)[-1]
         assert names(rec) == [E.SPAN_EXPIRE, E.SPAN_ADMIT,
-                              *DECODE_PHASES, P.STEP_SPAN]
+                              *DECODE_SPANS, P.STEP_SPAN]
         assert (rec["admitted"], rec["prefill_tokens"],
                 rec["install_writes"], rec["active"]) == (0, 0, 0, 1)
+
+    def test_dispatch_and_fetch_split_where_their_work_happens(
+            self, params, paged, spmd):
+        """``dispatch`` = ``inputs`` then ``call``, ``fetch`` =
+        ``tokens`` then ``counters``: each child inside its parent, in
+        that order, and the two together covering the parent to within
+        the spans' own cost."""
+        eng = make_engine(params, paged, spmd)
+        for n in (5, 3, 6):
+            eng.submit(np.arange(1, 1 + n))
+        eng.run()
+        decoded = [r for r in log_of(eng) if r["active"]]
+        assert len(decoded) >= 3
+        for rec in decoded:
+            at = {s[0]: s for s in rec["spans"]}
+            for parent, first, second in (
+                    (E.SPAN_DISPATCH, E.SPAN_DISPATCH_INPUTS,
+                     E.SPAN_DISPATCH_CALL),
+                    (E.SPAN_FETCH, E.SPAN_FETCH_TOKENS,
+                     E.SPAN_FETCH_COUNTERS)):
+                for name in (parent, first, second):
+                    assert names(rec).count(name) == 1
+                _, p0, p1, _ = at[parent]
+                _, a0, a1, _ = at[first]
+                _, b0, b1, _ = at[second]
+                assert p0 <= a0 <= a1 <= b0 <= b1 <= p1
+                bare = (p1 - p0) - (a1 - a0) - (b1 - b0)
+                assert 0 <= bare < 200_000        # ns: 3 spans' cost
+
+    def test_decode_uploads_counts_the_transfers_made(
+            self, params, paged, spmd, monkeypatch):
+        """``decode_uploads`` on a step's record is the host-to-device
+        transfers ``_step_inputs`` made in it: table (paged), tokens,
+        positions, live mask, and the keys where the engine samples."""
+        for temperature, keys in ((0.0, 0), (0.7, 1)):
+            eng = make_engine(params, paged, spmd, temperature=temperature)
+            eng.submit(np.arange(1, 6),
+                       key=jax.random.PRNGKey(3) if keys else None)
+            eng.step()
+            made = []
+            asarray, inputs = E.jnp.asarray, eng._step_inputs
+
+            def counting(x, *a, **kw):
+                made.append(np.shape(x))
+                return asarray(x, *a, **kw)
+
+            def watched():          # count inside _step_inputs only
+                with monkeypatch.context() as m:
+                    m.setattr(E.jnp, "asarray", counting)
+                    return inputs()
+
+            eng._step_inputs = watched
+            eng.step()
+            rec = log_of(eng)[-1]
+            want = (1 if paged else 0) + 3 + keys
+            assert rec["decode_uploads"] == len(made) == want
+        assert log_of(eng)[0]["decode_uploads"] == want
+        idle = make_engine(params, paged, spmd)
+        idle.step()
+        assert log_of(idle)[-1]["decode_uploads"] == 0
 
     def test_no_select_sync_in_any_decode_step(self, params, paged, spmd):
         """Every step that decoded holds its dispatch, fetch and select
@@ -172,7 +236,7 @@ class TestStepSpans:
             (rec,) = log_of(eng)
             counts.append(len(rec["spans"]))
             writes.append(rec["install_writes"])
-        assert counts[0] == counts[1] == 6 + 4
+        assert counts[0] == counts[1] == 10 + 4
         # install_writes: one dispatch an install, however many pages
         # (dense: one eager write a cache leaf).
         assert writes == ([1, 1] if paged else [LEAVES, LEAVES])
@@ -206,6 +270,115 @@ class TestStepSpans:
                              jnp.asarray(p, jnp.int32)[None, :], 3,
                              dtype=jnp.float64)
             np.testing.assert_array_equal(out[rid], np.asarray(ref[0]))
+
+
+@pytest.mark.parametrize("paged,spmd", ENGINES)
+def test_a_new_prompt_length_names_the_step_that_compiled(params, paged,
+                                                          spmd):
+    """A step that meets a prompt length for the first time compiles
+    inside ``admit.prefill``: its record says so, with the request; the
+    same length again compiles nothing.  (An eager engine compiles op
+    by op and shares JAX's cache with every earlier test, so only a
+    compiled engine is certain to compile here.)"""
+    n = 11                          # a length no other case prefills
+    eng = make_engine(params, paged, spmd)
+    eng.submit(np.arange(n) % CFG.vocab, rid="first")
+    eng.step()
+    (first,) = log_of(eng)
+    if spmd:
+        assert first["step_compiles"] >= 1
+    assert len(first.get("compiles", [])) == first["step_compiles"]
+    for name, rid, seconds in first.get("compiles", []):
+        assert name.startswith(P.STEP_SPAN + ".") and seconds > 0
+        # Only a span of the admission carries the request.
+        assert rid == ("first" if name in ADMIT_PHASES else None)
+    if spmd:
+        assert (E.SPAN_PREFILL, "first") in [
+            c[:2] for c in first["compiles"]]
+    eng.run()
+    eng.submit(np.arange(1, 1 + n) % CFG.vocab, rid="again")
+    eng.step()
+    again = log_of(eng)[-1]
+    assert again["admitted"] == 1 and again["prefill_tokens"] == \
+        (n if paged else 0)
+    assert again["step_compiles"] == 0 and "compiles" not in again
+    assert eng.stats.counters["step_compiles"] \
+        == sum(r["step_compiles"] for r in log_of(eng))
+    assert serve.stats()["step_compiles"] \
+        == eng.stats.counters["step_compiles"]
+    P.reset_serve_stats()
+    assert eng.stats.counters["step_compiles"] == 0
+
+
+class TestCompileListener:
+    """``profiling._on_compile``: a backend compilation that ends while
+    a step is open goes onto that step's record with the innermost span
+    open on the compiling thread, and into ``step_compiles``."""
+
+    @staticmethod
+    def compile_something(k):
+        jax.jit(lambda x: x * k + 1)(np.arange(3.0)).block_until_ready()
+
+    def test_named_by_the_innermost_open_span(self):
+        stats = P.ServeStats()
+        with stats.span(P.STEP_SPAN):
+            with stats.span(E.SPAN_ADMIT):
+                with stats.span(E.SPAN_PREFILL, "r-9"):
+                    self.compile_something(3)
+                self.compile_something(5)
+            with stats.span(E.SPAN_DISPATCH):
+                with stats.span(E.SPAN_DISPATCH_CALL):
+                    self.compile_something(7)
+        (rec,) = P.serve_step_log()
+        assert [c[:2] for c in rec["compiles"]] == [
+            (E.SPAN_PREFILL, "r-9"), (E.SPAN_ADMIT, None),
+            (E.SPAN_DISPATCH_CALL, None)]
+        assert all(c[2] > 0 for c in rec["compiles"])
+        assert rec["step_compiles"] == 3 == stats.counters["step_compiles"]
+
+    def test_a_cached_program_is_no_compilation(self):
+        stats = P.ServeStats()
+        fn = jax.jit(lambda x: x * 11 + 1)
+        for _ in range(2):
+            with stats.span(P.STEP_SPAN):
+                with stats.span(E.SPAN_PREFILL):
+                    fn(np.arange(3.0))
+        first, second = P.serve_step_log()
+        assert first["step_compiles"] == 1
+        assert second["step_compiles"] == 0 and "compiles" not in second
+
+    def test_outside_a_step_it_is_nobodys(self):
+        stats = P.ServeStats()
+        self.compile_something(13)
+        with stats.span(E.SPAN_PREFILL):          # no step is open
+            self.compile_something(17)
+        with stats.span(P.STEP_SPAN):
+            pass
+        self.compile_something(19)                # the step has closed
+        (rec,) = P.serve_step_log()
+        assert rec["step_compiles"] == 0 and "compiles" not in rec
+        assert stats.counters["step_compiles"] == 0
+
+    def test_another_threads_step_is_not_charged(self):
+        import threading
+
+        stats = P.ServeStats()
+        with stats.span(P.STEP_SPAN):
+            t = threading.Thread(target=self.compile_something, args=(23,))
+            t.start()
+            t.join()
+        (rec,) = P.serve_step_log()
+        assert rec["step_compiles"] == 0
+
+    def test_two_engines_on_one_thread(self):
+        a, b = P.ServeStats(), P.ServeStats()
+        with a.span(P.STEP_SPAN):
+            self.compile_something(29)
+        with b.span(P.STEP_SPAN):
+            self.compile_something(31)
+            self.compile_something(37)
+        assert (a.counters["step_compiles"],
+                b.counters["step_compiles"]) == (1, 2)
 
 
 @pytest.mark.parametrize("spmd", [False, True], ids=["eager", "spmd"])
@@ -353,7 +526,10 @@ def test_spans_are_in_a_profiler_capture(params, tmp_path):
             for line in plane.lines for e in line.events
             if e.name.startswith(P.STEP_SPAN)}
     assert {E.SPAN_FETCH, E.SPAN_DISPATCH, E.SPAN_SELECT,
-            P.STEP_SPAN} <= seen
+            P.STEP_SPAN, *DECODE_SPANS} <= seen
+    # Outside a session no annotation is made (one made then would
+    # stay inert in a later session, and costs half a span).
+    assert eng.stats.span(E.SPAN_FETCH)._ann is None
 
 
 def test_new_counter_is_mirrored(params):
@@ -366,4 +542,8 @@ def test_new_counter_is_mirrored(params):
     eng.run()
     assert serve.stats()["install_writes"] == 1
     assert serve.stats()["decode_select_syncs"] == 0
+    # table, tokens, positions, live mask: four a decode step, greedy.
+    assert serve.stats()["decode_uploads"] == 4 * serve.stats()["steps"]
+    assert serve.stats()["step_compiles"] \
+        == sum(r["step_compiles"] for r in log_of(eng))
     assert registry.serve_paging_problems() == []
