@@ -20,6 +20,12 @@ sequences), one compiled decode-step program, a host-driven loop:
   per-request key discipline of ``generate()`` — engine tokens equal
   per-request ``generate()`` tokens by construction — and the host
   fetches ``(slots,)`` tokens, never the ``(slots, vocab)`` table.
+  The slot state the step reads (tokens, positions, live mask, block
+  table, keys) stays on the device and the step hands it back
+  advanced; the host's arrays stay the truth, and a step uploads only
+  the arrays in which they differ from what the device holds
+  (:meth:`Engine._step_inputs`): a decode step behind a decode step
+  sends nothing and fetches one array.
 * **eviction** — a slot finishes on EOS or its token budget; its cache
   rows are re-poisoned and the slot returns to the free pool, ready
   for the next admission in the SAME step loop — no batch barrier,
@@ -34,13 +40,13 @@ Two execution modes behind one engine:
   ``RankFailedError`` on every survivor, never a hang.
 * **SPMD / Mode A** — ``Engine(..., spmd=True, nranks=4)`` (or
   ``mesh=``/``axis_name=``): the decode step is ONE ``run_spmd``
-  program; per-rank KV shards ride between steps as a stacked
-  ``(size, ...)`` leading axis (sliced by rank in-trace, re-stacked by
-  the rank-major output convention — on the CPU harness this means
-  each device holds the full stacked cache; a production deployment
-  would pin the axis sharded, which changes none of the semantics
-  here).  :meth:`Engine.lower_step` exposes the lowered step for the
-  deterministic exposure/latency censuses.
+  program; per-rank KV shards and the slot state ride between steps
+  as a stacked ``(size, ...)`` leading axis (sliced by rank in-trace,
+  re-stacked by the rank-major output convention — on the CPU harness
+  this means each device holds the full stacked cache; a production
+  deployment would pin the axis sharded, which changes none of the
+  semantics here).  :meth:`Engine.lower_step` exposes the lowered step
+  for the deterministic exposure/latency censuses.
 """
 
 from __future__ import annotations
@@ -126,6 +132,23 @@ def select_rows(logits, keys, temperature: float, top_k: int):
                             jnp.int32)[0], key
 
     return jax.vmap(one)(logits, keys)
+
+
+def _advanced(xp, state: dict, toks, keys) -> dict:
+    """The slot state behind a decode step that chose ``toks`` (and
+    ``keys``, None where the engine is greedy): a live slot takes its
+    chosen token, the next position and its new key; a free one keeps
+    what it had; the mask and the table stay as they came.  Written
+    once for both sides of the boundary: the compiled step ends in it
+    (``xp`` is ``jax.numpy``), and the host applies it to its memory of
+    what the device holds (``numpy``)."""
+    live = state["live"]
+    new = dict(state,
+               tokens=xp.where(live, toks, state["tokens"]),
+               pos=state["pos"] + live.astype(state["pos"].dtype))
+    if keys is not None:
+        new["keys"] = xp.where(live[:, None], keys, state["keys"])
+    return new
 
 
 class QueueFullError(CommError):
@@ -395,10 +418,14 @@ class Engine:
                 params, lambda fn: run_spmd(fn, **kw))
             # The paged step takes its pool over (argument 1) and
             # writes the new rows into it; the dense step's one-hot
-            # write builds a new cache and is left as it is.
+            # write builds a new cache and is left as it is.  Every
+            # step takes the slot state over (argument 2) and hands
+            # the next one back in its buffers: nothing is allocated or
+            # freed for it inside the call (0.15-0.27 ms a step on the
+            # chip, PERF.md section 6, PR 38).
             self._step_call = run_spmd(
-                self._traced_step_paged, donate_argnums=(1,), **kw) \
-                if self._paged else run_spmd(self._traced_step, **kw)
+                self._traced_step,
+                donate_argnums=(1, 2) if self._paged else (2,), **kw)
             # One wrapper serves every prompt length: the jit under
             # run_spmd caches per input shape on its own.
             self._prefill_call = run_spmd(self._traced_prefill, **kw)
@@ -461,8 +488,9 @@ class Engine:
         # buffer cannot be donated twice in a call.  All of it leaf by
         # leaf: a second whole pool, even for a moment, would be the
         # peak of the process.
-        state = jax.tree.leaves(self._shards)[0].sharding \
-            if self._spmd else None
+        state = self._state_sharding = \
+            jax.tree.leaves(self._shards)[0].sharding if self._spmd \
+            else None
 
         def own(a):
             if self._spmd:
@@ -480,6 +508,19 @@ class Engine:
         self._cache_leaves = len(jax.tree.leaves(cache))
         self._tokens = np.zeros((slots,), np.int32)
         self._pos = np.zeros((slots,), np.int32)
+        # The slot state on the device, beside the cache and riding as
+        # it does (stacked per rank under SPMD): what a decode step
+        # reads and hands back advanced.  The host's arrays above (with
+        # the table and the requests' keys) stay the truth; _held is the
+        # host's memory of what the device holds, and _step_inputs
+        # uploads the arrays in which the two differ: all of them the
+        # first time, since _held starts empty.
+        self._state: Dict[str, Any] = {}
+        self._held: Dict[str, np.ndarray] = {}
+        # The decode program's counters as it packs them behind the
+        # tokens: ((name, shape, size), ...), static, noted by _advance
+        # where the step is traced.
+        self._counted: tuple = ()
         self._select_syncs = 0            # device round trips of _select
         self._slot_req: List[Optional[Request]] = [None] * slots
         # True while a slot's chunked prefill is in flight: the slot is
@@ -561,26 +602,54 @@ class Engine:
                                                    keepdims=False),
             stacked)
 
-    def _select_rows(self, logits, keys):
-        """:func:`select_rows` under this engine's decoding rule."""
-        return select_rows(logits, keys, self.serve_cfg.temperature,
-                           self.serve_cfg.top_k)
+    def _decode(self, shards, cache, state):
+        """One decode step over the slot ``state`` with this rank's
+        shards and cache, on every step path (compiled dense and paged,
+        eager): decode, then :meth:`_advance`.  Returns ``(chosen,
+        next state, new cache)``."""
+        stats = {}
+        table = (state["table"],) if self._paged else ()
+        decode = _kv.decode_step_paged if self._paged \
+            else _kv.decode_step_tp
+        # Eager, the paged step takes each pool leaf over itself; the
+        # compiled one donates through run_spmd.
+        extra = {"donate": True} if self._paged and not self._spmd else {}
+        logits, cache = decode(
+            self.cfg, shards, cache, *table, state["tokens"],
+            state["pos"], self._comm, overlap=self.serve_cfg.overlap,
+            algorithm=self.serve_cfg.algorithm, active=state["live"],
+            stats=stats, **extra)
+        return (*self._advance(state, logits, stats), cache)
 
-    def _traced_step(self, shards, cache, tokens, pos, active, keys):
-        """Mode A decode step: slice this rank's shard/cache state off
-        the stacked leading axis, decode, choose every slot's token,
-        return (tokens, new keys, local cache, the step's counters) —
-        run_spmd re-stacks the per-rank outputs into the state layout.  The logits are
+    def _advance(self, state, logits, counters):
+        """What every decode step ends in: choose each slot's token
+        (:func:`select_rows`, behind its barrier: nothing here reaches
+        back into the unembedding product), pack the step's counters
+        behind the tokens into the one ``int32`` array the host
+        fetches, and advance the slot state (:func:`_advanced`).
+        Returns ``(chosen (slots + counted,), next state)``."""
+        toks, keys = select_rows(logits, state.get("keys"),
+                                 self.serve_cfg.temperature,
+                                 self.serve_cfg.top_k)
+        self._counted = tuple((name, c.shape, c.size)
+                              for name, c in sorted(counters.items()))
+        chosen = jnp.concatenate(
+            [toks] + [counters[name].astype(jnp.int32).reshape(-1)
+                      for name, _, _ in self._counted])
+        return chosen, _advanced(jnp, state, toks, keys)
+
+    def _traced_step(self, shards, cache, state):
+        """Mode A decode step, dense or paged: slice this rank's
+        shards, cache (or pool) and slot state off the stacked leading
+        axis and :meth:`_decode` — run_spmd re-stacks the per-rank
+        outputs into the state layout, so the state goes into the next
+        step as it came out of this one.  A paged engine's block table
+        is part of the slot state, DATA: one compiled program for every
+        table state (no retrace as pages churn).  The logits are
         replicated over the ranks (kv.shard_params_tp), so every rank
         chooses the same tokens and the choice adds no collective."""
-        stats = {}
-        logits, cache = _kv.decode_step_tp(
-            self.cfg, self._rank_slice(shards),
-            self._rank_slice(cache), tokens, pos, COMM_WORLD,
-            overlap=self.serve_cfg.overlap,
-            algorithm=self.serve_cfg.algorithm, active=active,
-            stats=stats)
-        return (*self._select_rows(logits, keys), cache, stats)
+        return self._decode(*map(self._rank_slice,
+                                 (shards, cache, state)))
 
     def _traced_prefill(self, shards, prompt):
         comm = COMM_WORLD
@@ -589,21 +658,6 @@ class Engine:
         stats = {}
         return (*_kv.prefill_tp(self.cfg, self._rank_slice(shards), cache,
                                 prompt, comm, stats=stats), stats)
-
-    def _traced_step_paged(self, shards, pool, table, tokens, pos,
-                           active, keys):
-        """Mode A paged decode step: shard/pool state stacked per rank,
-        the block table riding replicated as DATA — one compiled
-        program for every table state (no retrace as pages churn).
-        Ends in the choice of tokens like :meth:`_traced_step`."""
-        stats = {}
-        logits, pool = _kv.decode_step_paged(
-            self.cfg, self._rank_slice(shards),
-            self._rank_slice(pool), table, tokens, pos, COMM_WORLD,
-            overlap=self.serve_cfg.overlap,
-            algorithm=self.serve_cfg.algorithm, active=active,
-            stats=stats)
-        return (*self._select_rows(logits, keys), pool, stats)
 
     def _traced_prefill_chunk(self, shards, past, chunk):
         """Mode A chunk/suffix prefill: ``past`` is the stacked
@@ -847,27 +901,33 @@ class Engine:
                 self.cfg, self._shards, cache1, pj, self._comm,
                 stats=stats)
         logits_row = np.asarray(logits[0])
-        self._note_counters("prefill", stats)
+        self._note_counters("prefill", self._prefill_counters(stats))
         return logits_row, rows
 
     def _note_counters(self, program: str, stats: dict) -> None:
-        """A compiled program's own counters (``kv._hand_out``), copied
-        off the device behind the sync its caller has just made, onto
+        """A compiled program's own counters (``kv._hand_out``), as the
+        host has them behind the sync its caller has just made, onto
         the record of the step that is open: ``moe_rows``, one
         ``(program, (expert layers, held) rows)`` per call of a program
         with an expert layer, in the order of the calls; and, from a
         program whose expert layers have zero-compute experts, the two
         counters ``moe_zero_pairs`` and ``moe_live_pairs``, which the
-        step record carries by name like every counter.  A program
-        that counts nothing costs nothing here."""
-        if not stats:
-            return
-        stats = {k: self._fetch(v) for k, v in jax.device_get(stats).items()}
+        step record carries by name like every counter.  No transfer is
+        made here: a decode step's counters came down with its tokens
+        (:meth:`_advance`), a prefill's by :meth:`_prefill_counters`."""
         if "moe_rows" in stats:
             self.stats.attach("moe_rows", (program, stats["moe_rows"]))
         for name in ("moe_zero_pairs", "moe_live_pairs"):
             if name in stats:
                 self.stats.count(name, int(stats[name]))
+
+    def _prefill_counters(self, stats: dict) -> dict:
+        """A prefill program's counters off the device, in one copy
+        behind the sync on its logits (one an admission); a program
+        that counts nothing costs nothing here."""
+        if not stats:
+            return stats
+        return {k: self._fetch(v) for k, v in jax.device_get(stats).items()}
 
     # -------------------------------------------------------------- paged
 
@@ -1054,7 +1114,7 @@ class Engine:
                     self.cfg, self._shards, past, chunk, self._comm,
                     stats=stats)
                 logits_row = np.asarray(logits[0])
-            self._note_counters("prefill", stats)
+            self._note_counters("prefill", self._prefill_counters(stats))
         with self.stats.span(SPAN_INSTALL, rid):
             self._install_rows(j, rows, job.done, job.done + c_len)
         self.stats.count("prefill_tokens", c_len)
@@ -1304,21 +1364,25 @@ class Engine:
             with span(SPAN_DISPATCH):
                 # Ends when the step call has returned, not when the
                 # device has run it.
-                toks, keys, counters = self._dispatch_decode()
+                chosen = self._dispatch_decode()
             with span(SPAN_FETCH):
                 with span(SPAN_FETCH_TOKENS):
-                    # The one sync: waits for the step and for every
-                    # write queued before it, then copies (slots,)
-                    # tokens (and the keys of a sampling engine).
-                    toks = self._fetch(toks)
-                    if keys is not None:
-                        keys = self._fetch(keys)
+                    # The one sync, and the one transfer of a greedy
+                    # step: waits for the step and for every write
+                    # queued before it, then copies the (slots,) tokens
+                    # with the step's counters behind them (and, in a
+                    # copy of their own, the keys of a sampling engine).
+                    chosen = self._fetch(chosen)
+                    keys = self._fetch(self._state["keys"]) \
+                        if "keys" in self._state else None
                 with span(SPAN_FETCH_COUNTERS):
-                    # The step's own counters, which are there once
-                    # the tokens are: a copy, no second wait.
+                    # The host's unpacking: no transfer.
+                    toks, counters = self._unpack(chosen)
                     self._note_counters("decode", counters)
             with span(SPAN_SELECT):
-                # The host's bookkeeping; no device call.
+                # The host's bookkeeping; no device call.  First what
+                # the device holds now, then the host's own arrays.
+                self._held = _advanced(np, self._held, toks, keys)
                 self.stats.tick(len(active), self.serve_cfg.slots)
                 if self._paged:
                     self._count_pages(active)
@@ -1348,62 +1412,82 @@ class Engine:
         out = np.asarray(out)
         return out[0] if self._spmd else out
 
-    def _step_inputs(self) -> tuple:
-        """What a decode step takes besides the shards and the cache,
-        uploaded: ``([table,] tokens, pos, live, keys)``.  ``keys`` are
-        the live slots' requests' keys as ``(slots, ...)`` raw key
-        bits (zeros in the other rows), or None in a greedy engine,
-        which moves no key.  Every host-to-device transfer made here
-        is counted in ``decode_uploads``."""
+    def _unpack(self, chosen: np.ndarray) -> tuple:
+        """What :meth:`_advance` packed, apart again: ``(tokens
+        (slots,), {counter: value in its own shape})``."""
+        slots = self.serve_cfg.slots
+        toks, rest, counters = chosen[:slots], chosen[slots:], {}
+        for name, shape, n in self._counted:
+            counters[name], rest = rest[:n].reshape(shape), rest[n:]
+        return toks, counters
+
+    def _host_state(self) -> Dict[str, np.ndarray]:
+        """The slot state as the host's arrays, the truth, have it:
+        ``tokens``, ``pos``, the ``live`` mask, a paged engine's
+        ``table``, and a sampling engine's ``keys``, the live slots'
+        requests' keys as ``(slots, ...)`` raw key bits (zeros in the
+        other rows; a greedy engine moves no key)."""
         slots = self.serve_cfg.slots
         live = np.asarray([self._slot_req[j] is not None
                            and not self._prefilling[j]
                            for j in range(slots)])
-        sampling = self.serve_cfg.temperature > 0
-        host = [self._table] if self._paged else []
-        host += [self._tokens, self._pos, live]
-        if sampling:
+        host = {"tokens": self._tokens, "pos": self._pos, "live": live}
+        if self._paged:
+            host["table"] = self._table
+        if self.serve_cfg.temperature > 0:
             rows = {j: np.asarray(self._slot_req[j].key)
                     for j in np.flatnonzero(live)}
             blank = np.zeros_like(next(iter(rows.values()),
                                        np.zeros(2, np.uint32)))
-            host.append(np.stack(
-                [rows.get(j, blank) for j in range(slots)]))
-        self.stats.count("decode_uploads", len(host))
-        uploaded = tuple(jnp.asarray(a) for a in host)
-        return uploaded if sampling else (*uploaded, None)
+            host["keys"] = np.stack(
+                [rows.get(j, blank) for j in range(slots)])
+        return host
+
+    def _step_inputs(self) -> Dict[str, Any]:
+        """The slot state a decode step takes besides the shards and
+        the cache, on the device.  Each of the host's arrays is held
+        against the memory of what the device holds; those that differ
+        (what an admission, an eviction, a crossed page or a wrapped
+        ``_select`` wrote since the last step) go up, in one
+        ``jax.device_put`` however many, each counted in
+        ``decode_uploads``.  Where nothing differs, as in a decode-only
+        step behind a decode-only step, nothing moves."""
+        host = self._host_state()
+        differ = [k for k, a in host.items()
+                  if k not in self._held
+                  or not np.array_equal(a, self._held[k])]
+        self.stats.count("decode_uploads", len(differ))
+        if differ:
+            # Copies: the host writes its arrays in place.
+            self._held.update((k, np.array(host[k])) for k in differ)
+            stacked = (self._size,) if self._spmd else ()
+            self._state.update(zip(differ, jax.device_put(
+                [np.broadcast_to(self._held[k],
+                                 stacked + self._held[k].shape)
+                 for k in differ], self._state_sharding)))
+        return self._state
 
     def _dispatch_decode(self):
-        """Queue ONE decode step over the slot table (the new cache
-        replaces the old) and return what it chose, still on the
-        device: ``(slots,)`` tokens, the keys that go with them (None
-        from a greedy engine) and the step's counters (``moe_rows``
-        where a layer has experts), under SPMD stacked per rank.  The
-        ``(slots, vocab)`` logits stay where they were computed.  A
-        paged step takes the pool over and writes into it: whoever held
-        ``self._cache``'s old leaves holds deleted arrays afterwards,
+        """Queue ONE decode step over the slot table (the new cache and
+        the advanced slot state replace the old) and return what it
+        chose, still on the device: ``(slots,)`` tokens with the step's
+        counters packed behind them (``moe_rows`` where a layer has
+        experts), under SPMD stacked per rank.  The ``(slots, vocab)``
+        logits stay where they were computed.  A paged step takes the
+        pool over and writes into it, and every compiled step takes
+        the slot state over: whoever held ``self._cache``'s or
+        ``self._state``'s old leaves holds deleted arrays afterwards,
         as after an install."""
         span = self.stats.span
         with span(SPAN_DISPATCH_INPUTS):
-            args = self._step_inputs()
+            state = self._step_inputs()
         with span(SPAN_DISPATCH_CALL):
             # Ends when the call has returned: the arguments flattened
             # and the program queued, not run.
-            if self._spmd:
-                toks, keys, self._cache, counters = self._step_call(
-                    self._shards, self._cache, *args)
-                return toks, keys, counters
-            *inputs, live, keys = args
-            decode = _kv.decode_step_paged if self._paged \
-                else _kv.decode_step_tp
-            extra = {"donate": True} if self._paged else {}
-            counters = {}
-            logits, self._cache = decode(
-                self.cfg, self._shards, self._cache, *inputs, self._comm,
-                overlap=self.serve_cfg.overlap,
-                algorithm=self.serve_cfg.algorithm, active=live,
-                stats=counters, **extra)
-            return (*self._select_rows(logits, keys), counters)
+            step = self._step_call if self._spmd else self._decode
+            chosen, self._state, self._cache = step(
+                self._shards, self._cache, state)
+            return chosen
 
     def _pool_levels(self) -> None:
         """Mirror the block pool's population into the gauge-semantics
@@ -1538,7 +1622,7 @@ class Engine:
         text = lambda call, *args: call.lower_as_called(
             *args).compile().as_text()
         out = {"decode": text(self._step_call, self._shards, self._cache,
-                              *self._step_inputs())}
+                              self._step_inputs())}
         for n in sorted(self._prefilled):
             out[f"prefill.{n}"] = text(
                 self._prefill_call, self._shards,
@@ -1555,8 +1639,9 @@ class Engine:
             raise CommError(
                 "lower_step censuses the compiled SPMD decode program; "
                 "construct the engine with spmd=True")
-        # The block table is an ARGUMENT: two different table states
-        # lower to the identical program text (the no-retrace census in
-        # `make serve-smoke` holds exactly this).
+        # The block table is an ARGUMENT, a leaf of the slot state: two
+        # different table states lower to the identical program text
+        # (the no-retrace census in `make serve-smoke` holds exactly
+        # this).
         return jax.jit(self._step_call).lower(
-            self._shards, self._cache, *self._step_inputs())
+            self._shards, self._cache, self._step_inputs())

@@ -226,6 +226,75 @@ def test_spmd_engine_counts_and_names_its_scopes():
         == sum(r["moe_live_pairs"] for r in log)
 
 
+@pytest.mark.parametrize("spmd", [False, True], ids=["eager", "spmd"])
+def test_decode_counters_come_down_with_the_tokens(spmd, monkeypatch):
+    """A decode-only step makes exactly one device-to-host transfer, the
+    ``(slots + counted,)`` array that holds the chosen tokens and the
+    step's counters behind them, and its record carries ``moe_rows``,
+    ``moe_zero_pairs`` and ``moe_live_pairs`` as the decode program
+    hands them out when asked directly (the parent's second fetch)."""
+    from mpi4torch_tpu.serve import engine as E
+
+    top, blocks = _weights()
+    serve.reset_stats()
+    eng = serve.Engine(TCFG, dict(top, blocks=iter(blocks)),
+                       serve.ServeConfig(slots=3, block_size=BS,
+                                         max_new=N_NEW),
+                       spmd=spmd, nranks=1 if spmd else None)
+    eng.submit(_tokens()[0, :P_LEN])
+    eng.submit(_tokens(seed=1)[0, :P_LEN - 3])
+    eng.step()
+    eng.step()
+    # What the program counts for the step to come, read off a copy of
+    # the engine's state (the step takes its pool over).
+    rank0 = (lambda t: jax.tree.map(lambda a: a[0], t)) if spmd \
+        else (lambda t: t)
+    host = eng._host_state()
+    want = {}
+    kv.decode_step_paged(
+        TCFG, rank0(eng._shards), jax.tree.map(jnp.copy, rank0(eng._cache)),
+        host["table"], host["tokens"], host["pos"], active=host["live"],
+        stats=want)
+    want = jax.device_get(want)
+    assert set(want) == {"moe_rows", "moe_zero_pairs", "moe_live_pairs"}
+
+    class Watched:
+        """numpy, but for the arrays the engine copies off the device."""
+        down = []
+
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        def asarray(self, x, *a, **kw):
+            if isinstance(x, jax.Array):
+                self.down.append(x.shape)
+            return np.asarray(x, *a, **kw)
+
+        array = asarray
+
+    def no_device_get(tree):
+        raise AssertionError("a second fetch in a decode-only step")
+
+    with monkeypatch.context() as m:
+        m.setattr(E, "np", Watched())
+        m.setattr(jax, "device_get", no_device_get)
+        eng.step()
+    counted = want["moe_rows"].size + 2
+    assert Watched.down == [((1,) if spmd else ())
+                            + (eng.serve_cfg.slots + counted,)]
+    rec = profiling.serve_step_log()[-1]
+    assert rec["admitted"] == 0 and rec["active"] == 2
+    assert rec["decode_uploads"] == 0
+    assert E.SPAN_FETCH_COUNTERS in [s[0] for s in rec["spans"]]
+    ((program, rows),) = rec["moe_rows"]
+    assert program == "decode" and rows.shape == want["moe_rows"].shape
+    np.testing.assert_array_equal(rows, want["moe_rows"])
+    assert rows.sum() > 0
+    assert rec["moe_zero_pairs"] == int(want["moe_zero_pairs"]) > 0
+    assert rec["moe_live_pairs"] == int(want["moe_live_pairs"]) \
+        == 2 * CFG["num_layers"] * CFG["moe_topk"]
+
+
 # ------------------------------------------------------------- training
 
 def _loss(logits, toks):

@@ -328,15 +328,16 @@ def test_a_layer_spec_is_served_on_one_rank_only():
 # ----------------------- the other configurations' programs did not move
 
 # sha256 of the lowered text of the parent commit's programs (PR 31,
-# 90d8c4d; openPangu's own: PR 33, 8333d4b), taken in this suite's
-# environment (x64 on, jax 0.9.0): Kimi's training step, InternLM2's
-# paged decode step and openPangu's latent paged decode step at their
-# rehearsal sizes.  A PR that means to change one of these programs
+# 90d8c4d; the two decode steps: PR 38, whose step takes the slot state
+# stacked and hands it back advanced, the walk between unchanged), taken
+# in this suite's environment (x64 on, jax 0.9.0): Kimi's training step,
+# InternLM2's paged decode step and openPangu's latent paged decode step
+# at their rehearsal sizes.  A PR that means to change one of these programs
 # replaces its line; one that does not has changed it by accident.
 PARENT_TEXTS = {
     "kimi": "f359630dfc06bad6b9b47ab71f11f2cacf8c82bfccc459b7a28af81c9d841366",
-    "internlm2": "b22542455bf21f7b5b741190940b59ed6d71cd3aef36eaa38c803462c53d6e1c",
-    "openpangu": "3e458a5c3030085242fdcd60aafff19ea566a4426452705c6789586822b9b8c3",
+    "internlm2": "ddcbb9f523103a01172891ee29d82bf8839323abf424f1e05587b9142ec5ad65",
+    "openpangu": "5f642f3b450194072169ead73b988dded931711343d47cb074f2e0ed5c69cfc6",
 }
 
 

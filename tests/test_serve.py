@@ -575,10 +575,11 @@ class TestCensusAndLatencyTier:
                              ids=["greedy", "sampled"])
     def test_lowered_step_hands_back_tokens_not_the_table(self, paged,
                                                           temperature):
-        """The compiled step's results are ``(slots,)`` tokens (and
-        keys when sampling) and the cache, nothing of the logits
-        table's shape; choosing adds no collective to the step that
-        ends in the logits."""
+        """The compiled step's results are ``(slots,)`` tokens with
+        nothing counted behind them, the slot state it was given,
+        advanced (the keys in it when sampling), and the cache: nothing
+        of the logits table's shape; choosing and advancing add no
+        collective to the step that ends in the logits."""
         from mpi4torch_tpu import analyze
         from mpi4torch_tpu.ops.spmd import run_spmd
 
@@ -590,28 +591,35 @@ class TestCensusAndLatencyTier:
         eng.submit(PROMPTS[0], max_new=3, key=jax.random.PRNGKey(1))
         eng.step()
         lowered = eng.lower_step()
-        toks, keys, cache, counters = lowered.out_info
-        assert counters == {}          # no expert layer, nothing counted
+        toks, state, cache = lowered.out_info
+        # no expert layer, nothing counted behind the tokens
         assert (toks.shape, toks.dtype) == ((size, slots), jnp.int32)
-        assert (keys is None) == (temperature == 0.0)
-        if keys is not None:
-            assert (keys.shape, keys.dtype) == ((size, slots, 2),
-                                                jnp.uint32)
+        assert set(state) == {"tokens", "pos", "live"} \
+            | ({"table"} if paged else set()) \
+            | ({"keys"} if temperature else set())
+        # the state goes into the next step as it came out of this one
+        assert jax.tree.map(lambda a: (a.shape, a.dtype), state) \
+            == jax.tree.map(lambda a: (a.shape, a.dtype), eng._state)
+        if temperature:
+            assert (state["keys"].shape, state["keys"].dtype) \
+                == ((size, slots, 2), jnp.uint32)
         assert [leaf.shape for leaf in jax.tree.leaves(cache)] \
             == [leaf.shape for leaf in jax.tree.leaves(eng._cache)]
         for leaf in jax.tree.leaves(lowered.out_info):
             assert leaf.shape[-2:] != (slots, CFG.vocab)
 
-        def logits_step(shards, cache, *inputs):
-            *inputs, active, _ = inputs
+        def logits_step(shards, cache, state):
+            state = eng._rank_slice(state)
+            table = (state["table"],) if paged else ()
             decode = kv.decode_step_paged if paged else kv.decode_step_tp
             return decode(CFG, eng._rank_slice(shards),
-                          eng._rank_slice(cache), *inputs,
+                          eng._rank_slice(cache), *table,
+                          state["tokens"], state["pos"],
                           mpi.COMM_WORLD, overlap=eng.serve_cfg.overlap,
-                          active=active)
+                          active=state["live"])
 
         parent = jax.jit(run_spmd(logits_step, nranks=size)).lower(
-            eng._shards, eng._cache, *eng._step_inputs())
+            eng._shards, eng._cache, eng._step_inputs())
         assert parent.out_info[0].shape == (size, slots, CFG.vocab)
         census = analyze.parse_program(lowered).census()
         assert census == analyze.parse_program(parent).census()
@@ -1650,6 +1658,82 @@ class TestPagedDrainReadmit:
         # Each readmission prefilled ONLY its uncovered suffix (1-2
         # tokens past the registered rows), not the whole prompt.
         assert snap["prefill_tokens"] <= 2 * 2
+
+
+class TestSlotStateOnTheDevice:
+    """ISSUE 38: the slot state (tokens, positions, live mask, table)
+    stays on the device between decode steps and the host uploads only
+    the arrays in which its own state differs from what the device
+    holds.  Whatever takes a request out of its slot in the middle of a
+    run writes the host's arrays alone; the comparison has to catch it,
+    or the other slots, and whoever takes the freed slot, decode from a
+    stale state."""
+
+    @staticmethod
+    def _undisturbed(params):
+        """Every request in a slot of its own to the largest budget a
+        case gives it: greedy, so any shorter run is a prefix."""
+        eng = serve.Engine(CFG, params,
+                           serve.ServeConfig(slots=3, block_size=4))
+        rids = [eng.submit(p, max_new=10) for p in PROMPTS[:3]]
+        res = eng.run()
+        return [np.asarray(res[r]) for r in rids]
+
+    @pytest.mark.parametrize("spmd", SPMD, ids=SPMD_IDS)
+    @pytest.mark.parametrize(
+        "how", ["eviction", "deadline", "preemption", "drain"])
+    def test_a_disturbed_slot_leaves_the_others_tokens(self, how, spmd):
+        from mpi4torch_tpu.elastic import replan as E
+        from mpi4torch_tpu.utils import profiling
+
+        params = _params(CFG)
+        want = self._undisturbed(params)
+        t = [0.0]
+        tight = {"num_blocks": 5} if how == "preemption" else {}
+        eng = serve.Engine(
+            CFG, params, serve.ServeConfig(slots=2, block_size=4, **tight),
+            clock=lambda: t[0], **spmd)
+        # Request 1 is the disturbed one; request 0 decodes beside it
+        # throughout and request 2 takes whichever slot comes free.
+        budgets = [10, 3 if how == "eviction" else 10, 6]
+        deadline = 3.5 if how == "deadline" else None
+        rids = [eng.submit(p, max_new=n,
+                           deadline_s=deadline if i == 1 else None)
+                for i, (p, n) in enumerate(zip(PROMPTS[:3], budgets))]
+        tickets = None
+        for step in range(64):
+            if how == "drain" and step == 3:
+                tickets, _ = E.drain_tickets(eng)
+                assert len(tickets) == 3
+                E.readmit(eng, tickets)
+            eng.step()
+            t[0] += 1.0
+            if not eng.pending():
+                break
+        res = eng.results()
+        if tickets is not None:
+            res = E.stitched_results(res, tickets)
+        snap = eng.stats.snapshot()
+        assert snap["evicted"] >= 2
+        if how == "preemption":
+            assert snap["preempted"] >= 1
+        status = eng.statuses()
+        for i, rid in enumerate(rids):
+            got = np.asarray(res[rid])
+            expired = how == "deadline" and i == 1
+            assert status[rid] == (serve.STATUS_EXPIRED if expired
+                                   else serve.STATUS_OK)
+            if expired:
+                assert len(PROMPTS[1]) < len(got) \
+                    < len(PROMPTS[1]) + budgets[1]
+            else:
+                assert len(got) == len(PROMPTS[i]) + budgets[i]
+            np.testing.assert_array_equal(got, want[i][:len(got)])
+        # the mechanism did engage: decode-only steps that sent nothing
+        log = [r for r in profiling.serve_step_log()
+               if r["engine"] == eng.stats.engine and r["active"]]
+        assert sum(r["decode_uploads"] == 0 for r in log) >= 3
+        assert any(r["decode_uploads"] for r in log[1:])
 
 
 class TestBlockManager:

@@ -162,32 +162,57 @@ class TestStepSpans:
 
     def test_decode_uploads_counts_the_transfers_made(
             self, params, paged, spmd, monkeypatch):
-        """``decode_uploads`` on a step's record is the host-to-device
-        transfers ``_step_inputs`` made in it: table (paged), tokens,
-        positions, live mask, and the keys where the engine samples."""
+        """``decode_uploads`` on a step's record is the arrays of the
+        slot state ``_step_inputs`` sent up in it, those in which the
+        host's state differs from what the device holds: after an
+        admission what the admission wrote (tokens, positions, live
+        mask, the table of a paged engine, the keys where the engine
+        samples); in a decode-only step behind a decode-only step
+        nothing, and no transfer is made; where a slot crosses a page,
+        the table alone."""
         for temperature, keys in ((0.0, 0), (0.7, 1)):
             eng = make_engine(params, paged, spmd, temperature=temperature)
-            eng.submit(np.arange(1, 6),
-                       key=jax.random.PRNGKey(3) if keys else None)
-            eng.step()
-            made = []
-            asarray, inputs = E.jnp.asarray, eng._step_inputs
-
-            def counting(x, *a, **kw):
-                made.append(np.shape(x))
-                return asarray(x, *a, **kw)
+            sent, inputs = [], eng._step_inputs
 
             def watched():          # count inside _step_inputs only
+                put, asarray = jax.device_put, jnp.asarray
+
+                def counting(x, *a, **kw):
+                    sent[-1] += [np.shape(leaf)[1 if spmd else 0:]
+                                 for leaf in jax.tree.leaves(x)]
+                    return put(x, *a, **kw)
+
+                sent.append([])
                 with monkeypatch.context() as m:
-                    m.setattr(E.jnp, "asarray", counting)
+                    m.setattr(E.jax, "device_put", counting)
+                    m.setattr(E.jnp, "asarray", lambda x, *a, **kw: (
+                        sent[-1].append(np.shape(x)),
+                        asarray(x, *a, **kw))[1])
                     return inputs()
 
             eng._step_inputs = watched
+            key = jax.random.PRNGKey(3) if keys else None
+            everything = (1 if paged else 0) + 3 + keys
+            # prompt of 5 at pages of 4: the slot holds rows 0..7 and
+            # crosses into its third page when it writes position 8.
+            eng.submit(np.arange(1, 6), max_new=8, key=key)
+            eng.step()                       # admits and decodes: pos 6
+            eng.step()                       # decode-only: pos 7
+            eng.submit(np.arange(1, 4), max_new=8, key=key)
+            eng.step()                       # admits the second: 8 and 4
+            eng.step()                       # both cross a page
             eng.step()
-            rec = log_of(eng)[-1]
-            want = (1 if paged else 0) + 3 + keys
-            assert rec["decode_uploads"] == len(made) == want
-        assert log_of(eng)[0]["decode_uploads"] == want
+            recs = log_of(eng)
+            table = (2, CFG.max_seq // BLOCK)
+            want = [everything, 0, everything, 1 if paged else 0, 0]
+            assert [r["decode_uploads"] for r in recs] == want
+            assert [len(shapes) for shapes in sent] == want
+            assert sent[3] == ([table] if paged else [])
+            # An upload is what the step's own output was, to the
+            # compiled step: neither retraces it.
+            if spmd:
+                assert recs[1]["step_compiles"] == 0
+            assert [r["step_compiles"] for r in recs[3:]] == [0, 0]
         idle = make_engine(params, paged, spmd)
         idle.step()
         assert log_of(idle)[-1]["decode_uploads"] == 0
@@ -270,6 +295,45 @@ class TestStepSpans:
                              jnp.asarray(p, jnp.int32)[None, :], 3,
                              dtype=jnp.float64)
             np.testing.assert_array_equal(out[rid], np.asarray(ref[0]))
+
+
+@pytest.mark.parametrize("paged,spmd", ENGINES)
+def test_a_wrapped_select_feeds_the_next_step(params, paged, spmd):
+    """``_select`` is the one hand-over of every emitted token: where an
+    instance wraps it and hands on another token than the step chose
+    (the benchmark's broken-path control), the host's token differs
+    from the device's and goes up before the next step, which decodes
+    from it as it did when every step uploaded everything."""
+    def served(wrapped, forgetful):
+        eng = make_engine(params, paged, spmd)
+        if wrapped:
+            select = eng._select
+            eng._select = lambda req, choice: (select(req, choice) + 1) \
+                % CFG.vocab
+        rids = [eng.submit(np.arange(1, 6), max_new=6),
+                eng.submit(np.arange(7, 10), max_new=6)]
+        while eng.pending():
+            if forgetful:           # the parent's rule: all of it, every step
+                eng._held.clear()
+            eng.step()
+        out = eng.results()
+        return [out[r] for r in rids], log_of(eng)
+
+    kept, log = served(True, False)
+    all_up, every = served(True, True)
+    plain, _ = served(False, False)
+    for a, b, c in zip(kept, all_up, plain):
+        np.testing.assert_array_equal(a, b)
+        assert not np.array_equal(a, c)
+    everything = (1 if paged else 0) + 3
+    assert [r["decode_uploads"] for r in every] \
+        == [everything] * len(every)
+    # Behind the admitting step the tokens alone go up, and the table
+    # with them in the two steps in which a slot crosses a page (the
+    # short prompt into its second, the long one into its third).
+    later = [r["decode_uploads"] for r in log[1:]]
+    assert len(later) == 4 and set(later) <= {1, 2}
+    assert later.count(2) == (2 if paged else 0)
 
 
 @pytest.mark.parametrize("paged,spmd", ENGINES)
@@ -542,8 +606,10 @@ def test_new_counter_is_mirrored(params):
     eng.run()
     assert serve.stats()["install_writes"] == 1
     assert serve.stats()["decode_select_syncs"] == 0
-    # table, tokens, positions, live mask: four a decode step, greedy.
-    assert serve.stats()["decode_uploads"] == 4 * serve.stats()["steps"]
+    # What the one admission wrote: table, tokens, positions, live
+    # mask; the decode-only step behind it sent nothing up.
+    assert serve.stats()["steps"] == 2
+    assert serve.stats()["decode_uploads"] == 4
     assert serve.stats()["step_compiles"] \
         == sum(r["step_compiles"] for r in log_of(eng))
     assert registry.serve_paging_problems() == []

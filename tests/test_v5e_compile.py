@@ -195,17 +195,16 @@ def test_paged_decode_step_compiles_in_place(one_v5e_chip, as_on_tpu):
         assert eng._kernel_read
         mesh = Mesh(np.array([next(iter(one_v5e_chip.device_set))]),
                     ("mpi",))
-        state, rep = NamedSharding(mesh, P("mpi")), NamedSharding(mesh, P())
+        state = NamedSharding(mesh, P("mpi"))
         like = lambda tree, sh: jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
             tree)
-        step = run_spmd(eng._traced_step_paged, mesh=mesh, axis_name="mpi",
+        step = run_spmd(eng._traced_step, mesh=mesh, axis_name="mpi",
                         donate_argnums=(1,))
+        # The slot state rides stacked per rank, like the pool.
         args = (like(eng._shards, state), like(eng._cache, state),
-                like(jnp.asarray(eng._table), rep),
-                like(jnp.asarray(eng._tokens), rep),
-                like(jnp.asarray(eng._pos), rep),
-                like(jnp.zeros((slots,), bool), rep), None)
+                like({k: a[None] for k, a in eng._host_state().items()},
+                     state))
         compiled = jax.jit(step, donate_argnums=(1,)).lower(*args).compile()
     text = compiled.as_text()
     leaves = 2 * cfg.n_layers
