@@ -35,6 +35,7 @@ from ..constants import MPI_SUM
 from ..ops.flash import flash_attention, flash_block_attention, \
     merge_partials
 from ..ops.kda import kda_chunked
+from ..ops.paged_attention import index_scores
 from ..parallel.attention import ring_attention, \
     ulysses_attention, zigzag_ring_attention
 from ..parallel.dp import all_average_tree
@@ -62,6 +63,29 @@ class KDA:
 
 
 @dataclass(frozen=True)
+class Indexer:
+    """The learned selection of sparse latent attention: ``n_heads``
+    index queries of ``head_dim`` channels a token, up from the layer's
+    normed query latent, against ONE index key a cached token; the first
+    ``rope`` channels of both are rotated by position.  A cached
+    position's score is ``sum_j w_j relu(q_j . k)`` with a learned weight
+    a head and token, and a query attends the ``top_k`` positions of
+    the largest scores (all of them while there are no more)."""
+    n_heads: int
+    head_dim: int
+    rope: int
+    top_k: int
+
+    def __post_init__(self):
+        if self.rope % 2 or not 0 <= self.rope <= self.head_dim:
+            raise ValueError(
+                f"rope pairs channels: Indexer.rope={self.rope} must be "
+                f"even and at most head_dim={self.head_dim}")
+        if self.top_k < 1:
+            raise ValueError(f"Indexer.top_k={self.top_k} must be >= 1")
+
+
+@dataclass(frozen=True)
 class MLA:
     """Mixer: multi-head latent attention.  Keys and values come up from
     one latent of ``kv_rank``; a key is ``qk_nope`` channels of its own
@@ -73,7 +97,14 @@ class MLA:
     channel knows a position.  ``q_scale`` and ``kv_scale`` multiply the
     normed query latent and the normed key-value latent (a model that
     scales them by ``sqrt(d_model / rank)``); the scaled key-value
-    latent is what a serving cache row holds."""
+    latent is what a serving cache row holds.
+
+    ``index`` makes the attention sparse: an :class:`Indexer` on a layer
+    that scores every cached position and attends the ``top_k`` it
+    selects, ``"shared"`` on a layer that attends the selection of the
+    nearest scoring layer below it and has no indexer of its own
+    (:class:`TransformerConfig` refuses one with no such layer).  Served
+    only: the training forward refuses an indexed mixer by name."""
     n_heads: int
     kv_rank: int
     qk_nope: int
@@ -83,6 +114,7 @@ class MLA:
     rope: bool = False
     q_scale: float = 1.0
     kv_scale: float = 1.0
+    index: Union[None, Indexer, str] = None
 
     def __post_init__(self):
         if self.rope and self.qk_rope % 2:
@@ -91,6 +123,20 @@ class MLA:
         if self.q_scale != 1.0 and not self.q_rank:
             raise ValueError(
                 "q_scale scales the normed query latent: it needs q_rank > 0")
+        if not (self.index is None or self.index == "shared"
+                or isinstance(self.index, Indexer)):
+            raise ValueError(
+                f"MLA.index is None, an Indexer or \"shared\", got "
+                f"{self.index!r}")
+        if isinstance(self.index, Indexer) and not self.q_rank:
+            raise ValueError(
+                "an Indexer's queries come up from the normed query latent: "
+                "it needs q_rank > 0")
+
+    @property
+    def scores(self) -> bool:
+        """Whether the layer has an indexer of its own."""
+        return isinstance(self.index, Indexer)
 
 
 @dataclass(frozen=True)
@@ -178,6 +224,16 @@ class TransformerConfig:
                     "an expert FFN, a post-norm or a shortcut branch needs "
                     "a KDA or MLA mixer: the configuration's own attention "
                     "block carries its own FFN and norms")
+            scoring = None
+            for i, s in enumerate(self.layers):
+                index = getattr(s.mixer, "index", None)
+                if isinstance(index, Indexer):
+                    scoring = i
+                elif index == "shared" and scoring is None:
+                    raise ValueError(
+                        f"layer {i} shares a selection (MLA.index="
+                        "\"shared\") and no layer below it has an Indexer "
+                        "to make one")
             open_at = None
             for i, s in enumerate(self.layers):
                 if s.branch is not None:
@@ -321,11 +377,20 @@ def _init_mixer(key, spec, d_model: int, dtype) -> Dict[str, Any]:
         if spec.q_rank:
             query["wqa"] = dense(d_model, spec.q_rank)
             query["q_norm"] = {"scale": jnp.ones((spec.q_rank,), dtype)}
-        return {**query,
-                "wa": dense(d_model, spec.kv_rank + spec.qk_rope),
-                "kv_norm": {"scale": jnp.ones((spec.kv_rank,), dtype)},
-                "wb": dense(spec.kv_rank, h * (spec.qk_nope + spec.v_dim)),
-                "wo": dense(h * spec.v_dim, d_model)}
+        out = {**query,
+               "wa": dense(d_model, spec.kv_rank + spec.qk_rope),
+               "kv_norm": {"scale": jnp.ones((spec.kv_rank,), dtype)},
+               "wb": dense(spec.kv_rank, h * (spec.qk_nope + spec.v_dim)),
+               "wo": dense(h * spec.v_dim, d_model)}
+        if spec.scores:
+            ix = spec.index
+            out["index"] = {
+                "wq": dense(spec.q_rank, ix.n_heads * ix.head_dim),
+                "wk": dense(d_model, ix.head_dim),
+                "k_norm": {"scale": jnp.ones((ix.head_dim,), dtype),
+                           "bias": jnp.zeros((ix.head_dim,), dtype)},
+                "ww": dense(d_model, ix.n_heads)}
+        return out
     h, hd = spec.n_heads, spec.head_dim
     # Decay as the delta-rule models start it: exp(a_log) in [1, 16],
     # softplus(dt_bias) in [1e-3, 1e-1].
@@ -535,7 +600,10 @@ def mla_project(cfg: TransformerConfig, spec: MLA, p, y, positions):
     s)``).  ``[c ; k_r]`` is everything a later query needs of this
     token: the serving cache's entry.  ``spec.q_scale`` and
     ``spec.kv_scale`` are applied here, once, to the normed latents: the
-    ``c`` handed back (and cached) is the scaled one."""
+    ``c`` handed back (and cached) is the scaled one.  Fourth comes
+    ``cq`` ``(b, s, q_rank)``, the normed (and scaled) query latent that
+    an indexer's queries come up from (:func:`index_project`); ``None``
+    without a query rank."""
     b, s, _ = y.shape
     h, dn, dr = spec.n_heads, spec.qk_nope, spec.qk_rope
     # The product in at least float32, rounded once: sqrt(12) is no
@@ -543,9 +611,10 @@ def mla_project(cfg: TransformerConfig, spec: MLA, p, y, positions):
     scaled = lambda t, by: t if by == 1.0 else (
         t.astype(jnp.promote_types(t.dtype, jnp.float32)) * by
     ).astype(t.dtype)
+    cq = None
     if spec.q_rank:
-        q = scaled(_rms_norm(y @ p["wqa"], p["q_norm"]),
-                   spec.q_scale) @ p["wq"]
+        cq = scaled(_rms_norm(y @ p["wqa"], p["q_norm"]), spec.q_scale)
+        q = cq @ p["wq"]
     else:
         q = y @ p["wq"]
     q = q.reshape(b, s, h, dn + dr)
@@ -558,7 +627,125 @@ def mla_project(cfg: TransformerConfig, spec: MLA, p, y, positions):
         q = jnp.concatenate(
             [q[..., :dn], _rope_rotate(cfg, q[..., dn:], positions)], axis=-1)
         k_r = _rope_rotate(cfg, k_r[:, :, None, :], positions)[:, :, 0]
-    return q, c, k_r
+    return q, c, k_r, cq
+
+
+def index_project(cfg: TransformerConfig, ix: Indexer, p, y, cq, positions):
+    """The projections of an :class:`Indexer` (leaves ``p``: ``wq``,
+    ``wk``, ``k_norm``, ``ww``) on the layer's normed input ``y`` ``(b,
+    s, d)`` and normed query latent ``cq`` ``(b, s, q_rank)``, the ONE
+    place they live: ``(q_i, k_i, w)`` with ``q_i`` ``(b, s, n_heads,
+    head_dim)`` the index queries, ``k_i`` ``(b, s, head_dim)`` the
+    token's index key, layer-normed with scale and bias (what the
+    serving cache's index-key entry holds), the first ``ix.rope``
+    channels of both rotated by ``positions``; and ``w`` ``(b, s,
+    n_heads)`` float32, each head's weight in the score with the scale
+    ``n_heads ** -0.5 * head_dim ** -0.5`` in it."""
+    b, s, _ = y.shape
+    q_i = (cq @ p["wq"]).reshape(b, s, ix.n_heads, ix.head_dim)
+    k_i = _layer_norm(y @ p["wk"], p["k_norm"])
+    if ix.rope:
+        r = ix.rope
+        q_i = jnp.concatenate(
+            [_rope_rotate(cfg, q_i[..., :r], positions), q_i[..., r:]], -1)
+        k_i = jnp.concatenate(
+            [_rope_rotate(cfg, k_i[:, :, None, :r], positions)[:, :, 0],
+             k_i[..., r:]], -1)
+    ct = jnp.promote_types(y.dtype, jnp.float32)
+    w = (y @ p["ww"]).astype(ct) * (
+        float(ix.n_heads) ** -0.5 * float(ix.head_dim) ** -0.5)
+    return q_i, k_i, w
+
+
+_LOWEST = float(jnp.finfo(jnp.float32).min)
+
+
+def _sortable(x):
+    """float32 as uint32 keys of the same order (``-0.0 < +0.0``)."""
+    u = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(1 << 31))
+
+
+def select_mask(scores, valid, top_k: int):
+    """Which positions a query attends: ``scores`` ``(rows, n)`` float32
+    and ``valid`` ``(rows, n)`` bool give the ``(rows, n)`` bool mask of
+    each row's ``top_k`` valid positions of the largest scores, all the
+    valid ones where there are no more; equal scores go to the earlier
+    position, as :func:`jax.lax.top_k` breaks them.  Exact, and with no
+    sort: the ``top_k``-th largest key of a row is found a bit at a time
+    (32 counts over the row), and what lies above it is selected; the
+    positions that tie with it are counted off from the left only where
+    a row has such a tie to break.  A score of ``-inf`` counts as the
+    least float32, in both forms of the selection."""
+    key = jnp.where(valid, _sortable(jnp.maximum(scores, _LOWEST)),
+                    jnp.uint32(0))
+    count = lambda m: jnp.sum(m, axis=-1, dtype=jnp.int32)
+
+    def bit(i, t):
+        cand = t | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        return jnp.where(count(key >= cand[:, None]) >= top_k, cand, t)
+
+    # The largest threshold at least top_k keys reach: the top_k-th
+    # largest key; 0, which every key reaches, for a row with fewer.
+    t = jax.lax.fori_loop(0, 32, bit, jnp.zeros(key.shape[:1], jnp.uint32))
+    above = valid & (key > t[:, None])
+    ties = valid & (key == t[:, None])
+    room = top_k - count(above)
+
+    def counted_off(_):
+        return above | (ties & (jnp.cumsum(ties, axis=-1, dtype=jnp.int32)
+                                <= room[:, None]))
+
+    return jax.lax.cond(jnp.any(count(ties) > room), counted_off,
+                        lambda _: above | ties, None)
+
+
+_INDEX_BLOCK = 128
+
+
+def index_select_mask(q_i, k_i, w, top_k: int, q_offset=0):
+    """The selection of a whole pass as a mask: ``q_i`` ``(sq, n_heads,
+    head_dim)`` at positions ``q_offset, q_offset + 1, ...`` against the
+    index keys ``k_i`` ``(sk, head_dim)`` of positions ``0..sk-1`` with
+    ``w`` ``(sq, n_heads)``: ``(sq, sk)`` bool, row ``t`` naming the
+    ``top_k`` positions at or before its own of the largest index scores
+    (``ops.paged_attention.index_scores``, :func:`select_mask`).  A
+    block of queries at a time: the scores exist as ``(_INDEX_BLOCK,
+    sk)`` float32 and never as ``(sq, sk)``; what is kept is the mask, a
+    byte a pair."""
+    sq, sk = q_i.shape[0], k_i.shape[0]
+    blk = min(_INDEX_BLOCK, sq)
+    n = -(-sq // blk)
+    pad = lambda x: jnp.pad(x, ((0, n * blk - sq),) + ((0, 0),) * (x.ndim - 1))
+    q_i, w = pad(q_i), pad(w)
+    kv_pos = jnp.arange(sk, dtype=jnp.int32)
+
+    def block(i):
+        cut = lambda x: jax.lax.dynamic_slice_in_dim(x, i * blk, blk, 0)
+        q_pos = q_offset + i * blk + jnp.arange(blk, dtype=jnp.int32)
+        valid = kv_pos[None, :] <= q_pos[:, None]
+        return select_mask(index_scores(cut(q_i), k_i, cut(w)), valid, top_k)
+
+    mask = jax.lax.map(block, jnp.arange(n, dtype=jnp.int32))
+    return mask.reshape(n * blk, sk)[:sq]
+
+
+def select_rows(scores, valid, top_k: int):
+    """:func:`select_mask`'s selection as positions, for a read that
+    gathers: ``(rows, top_k)`` int32, the selected positions in the
+    order of their scores, ``-1`` behind them where a row has fewer than
+    ``top_k`` valid ones (:func:`jax.lax.top_k` itself)."""
+    # No valid score is -inf (select_mask's rule), so what top_k hands
+    # back at -inf is a position that was not valid: no second look-up
+    # (a gather of top_k scalars a row costs a TPU 0.3 ms).
+    vals, rows = jax.lax.top_k(
+        jnp.where(valid, jnp.maximum(scores.astype(jnp.float32), _LOWEST),
+                  -jnp.inf), min(top_k, scores.shape[-1]))
+    rows = jnp.where(vals > -jnp.inf, rows, -1).astype(jnp.int32)
+    if rows.shape[-1] < top_k:
+        rows = jnp.pad(rows, ((0, 0), (0, top_k - rows.shape[-1])),
+                       constant_values=-1)
+    return rows
 
 
 def mla_expand(spec: MLA, p, c, k_r):
@@ -581,7 +768,13 @@ def _mla_mixer(cfg: TransformerConfig, spec: MLA, p, y, positions):
     query-key size ``qk_nope + qk_rope``, the value zero-padded up to
     it."""
     b, s, _ = y.shape
-    q, c, k_r = mla_project(cfg, spec, p, y, positions)
+    if spec.index is not None:
+        raise CommError(
+            "the training forward does not run an indexed mixer "
+            f"(MLA.index={spec.index!r}): its indexer is trained by a loss "
+            "of its own beside the model's, which is not written; serve "
+            "the configuration through mpi4torch_tpu.serve")
+    q, c, k_r, _ = mla_project(cfg, spec, p, y, positions)
     k, v = mla_expand(spec, p, c, k_r)
     o = _blockwise_causal_attention(q, k, v, _MLA_BLOCK)[..., :spec.v_dim]
     return o.reshape(b, s, spec.n_heads * spec.v_dim) @ p["wo"]

@@ -312,6 +312,7 @@ def _jnp_block(q, k, v, q_off, kv_off, causal: bool, window: int = 0):
     prec = dot_precision(q.dtype)
     s = jnp.einsum("bqhd,bkhd->bqhk", q.astype(ct), k.astype(ct),
                    precision=prec) * scale
+    bmask = None
     if causal:
         batched = q_off.ndim > 0 or kv_off.ndim > 0
         if not batched:
@@ -336,17 +337,86 @@ def _jnp_block(q, k, v, q_off, kv_off, causal: bool, window: int = 0):
                          - kv_pos[..., None, :]) < window
             mask = jnp.broadcast_to(mask, (b, sq, sk))
             bmask = mask[:, :, None, :]
+    out, lse = _jnp_softmax(s, v.astype(ct), bmask, prec)
+    return out.astype(q.dtype), lse
+
+
+def _jnp_softmax(s, v, bmask, prec):
+    """Scores ``s`` ``(b, q, h, k)`` under ``bmask`` (broadcastable to
+    them; ``None``: all of them) into the normalised weighted sum of
+    ``v`` ``(b, k, h, d)`` and the scores' log-sum-exp, in ``s``'s
+    dtype: a row with nothing to attend gives zeros and ``NEG_BIG``."""
+    if bmask is not None:
         s = jnp.where(bmask, s, NEG_BIG)
     m = jnp.max(s, axis=-1)
     p = jnp.exp(s - m[..., None])
-    if causal:
+    if bmask is not None:
         p = jnp.where(bmask, p, 0.0)
     l = jnp.sum(p, axis=-1)
-    acc = jnp.einsum("bqhk,bkhd->bqhd", p, v.astype(ct), precision=prec)
+    acc = jnp.einsum("bqhk,bkhd->bqhd", p, v, precision=prec)
     safe_l = jnp.where(l > 0, l, 1.0)
     out = jnp.where(l[..., None] > 0, acc / safe_l[..., None], 0.0)
     lse = jnp.where(l > 0, m + jnp.log(safe_l), NEG_BIG)
-    return out.astype(q.dtype), lse
+    return out, lse
+
+
+def masked_attention(q, k, v, mask, *, q_offset: int = 0,
+                     block_q: int = 512, block_k: int = 2048):
+    """Attention of every query over the keys ITS row of ``mask`` names,
+    in plain jnp: ``q`` ``(1, sq, h, d)``, ``k`` / ``v`` ``(1, sk, h,
+    d)`` of one head count, ``mask`` ``(sq, sk)`` bool, causal already
+    (query ``i`` sits at position ``q_offset + i`` and names no key
+    beyond it).  The arithmetic of the jnp block path (scores, softmax
+    and sums in float32 at the least); a query block at a time over the
+    key blocks up to its diagonal, the partials merged by the
+    online-softmax rule in float32, so that no array of ``sq x sk``
+    scores is formed.  A row that names nothing gives zeros."""
+    ct = _compute_dtype(q)
+    _, sq, h, d = q.shape
+    sk = k.shape[1]
+    scale = 1.0 / jnp.sqrt(jnp.asarray(d, ct))
+    prec = dot_precision(q.dtype)
+
+    def part(qb, kb, vb, mb):
+        s = jnp.einsum("bqhd,bkhd->bqhk", qb.astype(ct), kb.astype(ct),
+                       precision=prec) * scale
+        return _jnp_softmax(s, vb.astype(ct), mb[None, :, None, :], prec)
+
+    if sq <= block_q and sk <= block_k:
+        return part(q, k, v, mask)[0].astype(q.dtype)
+    nq, nk = -(-sq // block_q), -(-sk // block_k)
+    pad = lambda x, n, axis: jnp.pad(
+        x, [(0, n - x.shape[a]) if a == axis else (0, 0)
+            for a in range(x.ndim)])
+    q = pad(q, nq * block_q, 1)
+    k, v = pad(k, nk * block_k, 1), pad(v, nk * block_k, 1)
+    mask = pad(pad(mask, nq * block_q, 0), nk * block_k, 1)
+    cut = jax.lax.dynamic_slice_in_dim
+
+    def q_block(i):
+        qb = cut(q, i * block_q, block_q, 1)
+        mrows = cut(mask, i * block_q, block_q, 0)
+        # Key blocks beyond the block's last query hold nothing it names.
+        n_live = jnp.minimum(
+            (q_offset + (i + 1) * block_q - 1) // block_k + 1, nk)
+
+        def k_block(j, carry):
+            out, lse = carry
+            o_b, lse_b = part(qb, cut(k, j * block_k, block_k, 1),
+                              cut(v, j * block_k, block_k, 1),
+                              cut(mrows, j * block_k, block_k, 1))
+            new = jnp.logaddexp(lse, lse_b)
+            return (out * jnp.exp(lse - new)[..., None]
+                    + o_b * jnp.exp(lse_b - new)[..., None], new)
+
+        out, _ = jax.lax.fori_loop(
+            0, n_live, k_block,
+            (jnp.zeros((1, block_q, h, v.shape[-1]), ct),
+             jnp.full((1, block_q, h), NEG_BIG, ct)))
+        return out[0].astype(q.dtype)
+
+    out = jax.lax.map(q_block, jnp.arange(nq, dtype=jnp.int32))
+    return out.reshape(1, nq * block_q, h, -1)[:, :sq]
 
 
 # ---------------------------------------------------------------------------

@@ -45,6 +45,14 @@ above with one operand less: a page is fetched once and serves as key
 and as value, and all heads share every row, so nothing is masked by
 head.
 
+Two more reads serve SPARSE latent attention (a latent layer with an
+indexer, ``models/transformer.py``): :func:`paged_index_scores` scores
+every cached position of a slot from a second, narrow pool of index keys
+(``(num_blocks, block_size, 1, head_dim)``, one row a token), page by
+page up to the frontier; and :func:`paged_sparse_latent_attention` reads
+of the latent pool only the rows a selection names, found by page and
+offset through the block table, and attends those.
+
 Inference-only: no VJP.
 """
 
@@ -60,13 +68,23 @@ from .flash import NEG_BIG, _KV_VMEM_BUDGET, _STAT_LANES, _on_tpu, \
 from .ragged import block_gather
 
 __all__ = ["paged_decode_attention", "paged_latent_attention",
-           "latent_rows_attention", "uses_kernel", "KERNEL_NAMES"]
+           "latent_rows_attention", "paged_index_scores",
+           "index_scores", "index_rows_scores",
+           "paged_sparse_latent_attention",
+           "sparse_rows_gather", "uses_kernel", "uses_index_kernel",
+           "KERNEL_NAMES"]
 
 # Stable names of the Mosaic kernels: what a lowered program's
 # ``kernel_name`` attributes and a profiler trace's kernel events are
 # matched against (as ``flash.KERNEL_NAMES``): the K/V read, the latent
-# read.
-KERNEL_NAMES = ("mpi4torch_paged_attn", "mpi4torch_paged_latent_attn")
+# read, the index scoring, the latent read over selected rows.
+KERNEL_NAMES = ("mpi4torch_paged_attn", "mpi4torch_paged_latent_attn",
+                "mpi4torch_paged_index_score",
+                "mpi4torch_paged_sparse_latent_attn")
+# The scope the selected rows' gather runs under, left to XLA: its
+# instructions carry the name in their ``op_name``, and their time is
+# the sparse read's.
+SPARSE_GATHER_SCOPE = "mpi4torch.paged_sparse_gather"
 
 
 def _eligible(q, pool_k, v_width=None) -> bool:
@@ -353,7 +371,7 @@ def _latent_kernel(table_ref, pos_ref, q_ref, c_ref, o_ref, m_ref, l_ref,
 
 
 def _pallas_latent(q, pool_c, table, pos, v_width: int, scale: float,
-                   interpret: bool):
+                   interpret: bool, name: str = KERNEL_NAMES[1]):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -389,7 +407,7 @@ def _pallas_latent(q, pool_c, table, pos, v_width: int, scale: float,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        name=KERNEL_NAMES[1],
+        name=name,
     )(table.reshape(-1), pos, q, pool_c.reshape(nb, bs, w))
 
 
@@ -476,3 +494,267 @@ def paged_latent_attention(q, pool_c, table, pos, *, v_width: int,
     return latent_rows_attention(
         q, block_gather(pool_c, table)[:, :, 0], pos, v_width=v_width,
         scale=scale, active=live)
+
+
+# ---------------------------------------------------------------------------
+# Sparse latent attention: the index scoring and the read of selected rows
+# ---------------------------------------------------------------------------
+
+
+def _index_eligible(q_i, pool_k) -> bool:
+    """Operands the scoring kernel takes: index keys of whole lanes, one
+    row a token, ``block_size`` of the pool dtype's sublane tile and of
+    whole lanes (a page's scores are one row of the result), the queries
+    in the pool's dtype."""
+    hd, bs = q_i.shape[-1], pool_k.shape[1]
+    item = jnp.dtype(pool_k.dtype).itemsize
+    return (hd % 128 == 0 and bs % 128 == 0 and pool_k.shape[2] == 1
+            and q_i.dtype == pool_k.dtype and item in (2, 4))
+
+
+def uses_index_kernel(q_i, pool_k) -> bool:
+    """Whether ``impl="auto"`` of :func:`paged_index_scores` takes the
+    kernel for these operands (shapes and dtypes only)."""
+    return _index_eligible(q_i, pool_k) and _on_tpu()
+
+
+def _pages_a_step(n_blk: int) -> int:
+    """Pages one grid step of the scoring kernel takes: a page is a
+    small product, so several share a step's fixed cost."""
+    return next(g for g in (8, 4, 2, 1) if n_blk % g == 0)
+
+
+def _index_kernel(table_ref, pos_ref, q_ref, w_ref, *refs, bs: int,
+                  n_blk: int, group: int):
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    s, j = pl.program_id(0), pl.program_id(1)
+    k_refs, o_ref = refs[:group], refs[group]
+    _, n_live = _page_span(pos_ref[s], bs, n_blk, 0)
+    q, w = q_ref[0], w_ref[0]              # (heads, hd), (heads, bs) f32
+    prec = dot_precision(q.dtype)
+    for g in range(group):
+        page = j * group + g
+
+        @pl.when(page < n_live)
+        def _page(g=g, page=page):
+            sc = jax.lax.dot_general(
+                q, k_refs[g][0], (((1,), (1,)), ((), ())),
+                preferred_element_type=f32, precision=prec)
+            row = jnp.sum(jnp.maximum(sc, 0.0) * w, axis=0, keepdims=True)
+            # An unmapped page inside the frontier scores as zero rows.
+            mapped = table_ref[s * n_blk + page] >= 0
+            o_ref[0, 0, pl.ds(g, 1), :] = jnp.where(mapped, row, 0.0)
+
+        @pl.when(page >= n_live)
+        def _dead(g=g):
+            o_ref[0, 0, pl.ds(g, 1), :] = jnp.zeros((1, bs), f32)
+
+
+def _pallas_index(q_i, w, pool_k, table, pos, interpret: bool):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    slots, heads, hd = q_i.shape
+    nb, bs = pool_k.shape[0], pool_k.shape[1]
+    n_blk = table.shape[1]
+    group = _pages_a_step(n_blk)
+
+    def page_index(g):
+        def index(s, j, table_ref, pos_ref):
+            # Steps past the frontier repeat the last live page's index:
+            # nothing is fetched for them.
+            _, n_live = _page_span(pos_ref[s], bs, n_blk, 0)
+            jj = jnp.clip(j * group + g, 0, jnp.maximum(n_live - 1, 0))
+            return jnp.maximum(table_ref[s * n_blk + jj], 0), 0, 0
+        return index
+
+    slot_index = lambda s, j, table_ref, pos_ref: (s, 0, 0)
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(slots, n_blk // group),
+        in_specs=[vmem((1, heads, hd), slot_index),
+                  vmem((1, heads, bs), slot_index)]
+        + [vmem((1, bs, hd), page_index(g)) for g in range(group)],
+        out_specs=vmem((1, 1, group, bs),
+                       lambda s, j, table_ref, pos_ref: (s, j, 0, 0)))
+    pages = pool_k.reshape(nb, bs, hd)
+    out = pl.pallas_call(
+        functools.partial(_index_kernel, bs=bs, n_blk=n_blk, group=group),
+        out_shape=jax.ShapeDtypeStruct(
+            (slots, n_blk // group, group, bs), jnp.float32),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name=KERNEL_NAMES[2],
+    )(table.reshape(-1), pos, q_i,
+      jnp.broadcast_to(w.astype(jnp.float32)[:, :, None],
+                       (slots, heads, bs)), *([pages] * group))
+    return out.reshape(slots, n_blk * bs)
+
+
+def index_scores(q_i, k_i, w):
+    """The index score of every (query, cached position) pair in plain
+    jnp, float32 at the least: ``q_i`` ``(..., q, heads, head_dim)``
+    against ``k_i`` ``(..., n, head_dim)`` with ``w`` ``(..., q, heads)``
+    gives ``sum_j w_j relu(q_j . k)``, ``(..., q, n)``.  The ONE place
+    the score is written out: a prefill's block of queries against its
+    pass's keys, and (:func:`index_rows_scores`) a decode step's."""
+    ct = jnp.promote_types(q_i.dtype, jnp.float32)
+    sc = jnp.einsum("...qjc,...nc->...qjn", q_i.astype(ct), k_i.astype(ct),
+                    precision=dot_precision(q_i.dtype))
+    return jnp.einsum("...qjn,...qj->...qn", jnp.maximum(sc, 0), w.astype(ct))
+
+
+def index_rows_scores(q_i, k_rows, w):
+    """:func:`index_scores` over index keys laid out a slot at a time,
+    one query a slot: ``q_i`` ``(slots, heads, head_dim)`` against
+    ``k_rows`` ``(slots, n, head_dim)`` with ``w`` ``(slots, heads)``
+    gives ``(slots, n)``.  The dense slot cache's scoring, and (behind a
+    gather) the paged pool's oracle."""
+    return index_scores(q_i[:, None], k_rows, w[:, None])[:, 0]
+
+
+def paged_index_scores(q_i, w, pool_k, table, pos, *, active=None,
+                       impl: str = "auto"):
+    """The index score of every cached position of every slot, from a
+    pool of index keys: ``q_i`` ``(slots, heads, head_dim)`` one slot's
+    index queries, ``w`` ``(slots, heads)`` their float32 weights,
+    ``pool_k`` ``(num_blocks, block_size, 1, head_dim)``; ``table``,
+    ``pos``, ``active``, ``impl`` as :func:`paged_decode_attention` has
+    them.  Returns ``(slots, n_blk * block_size)`` float32: position
+    ``t`` of slot ``s`` scores ``sum_j w[s, j] relu(q_i[s, j] . key_t)``.
+    Only positions ``0..pos[s]`` of an active slot mean anything (the
+    caller's mask): the kernel fetches no page beyond a frontier and
+    writes zeros there, the jnp path scores whatever the gather found.
+
+    The kernel (on a TPU: ``head_dim`` and ``block_size`` multiples of
+    128, ``q_i`` in the pool's dtype) takes several pages a grid step,
+    each fetched once; everywhere else the pages are gathered into each
+    slot's full extent and scored by :func:`index_rows_scores`, its
+    oracle."""
+    if impl not in ("auto", "pallas", "jnp"):
+        raise ValueError(f"unknown impl {impl!r}")
+    if q_i.ndim != 3 or pool_k.ndim != 4 or pool_k.shape[2] != 1 \
+            or q_i.shape[2] != pool_k.shape[3] \
+            or w.shape != q_i.shape[:2]:
+        raise ValueError(
+            f"q_i{q_i.shape} must be (slots, heads, head_dim), w{w.shape} "
+            f"(slots, heads) and the index-key pool{pool_k.shape} "
+            "(num_blocks, block_size, 1, head_dim) of the same head_dim")
+    table = jnp.asarray(table, jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+    if table.ndim != 2 or table.shape[0] != q_i.shape[0] \
+            or pos.shape != (q_i.shape[0],):
+        raise ValueError(
+            f"table{table.shape} must be (slots, n_blk) and pos{pos.shape} "
+            f"(slots,) for {q_i.shape[0]} slots")
+    if impl == "pallas" and not _index_eligible(q_i, pool_k):
+        raise ValueError(
+            f"impl='pallas' requires kernel-eligible operands (head_dim "
+            f"and block_size multiples of 128, q_i in the pool's dtype); "
+            f"got q_i{q_i.shape} {q_i.dtype} pool{pool_k.shape} "
+            f"{pool_k.dtype}")
+    if impl == "pallas" or (impl == "auto"
+                            and uses_index_kernel(q_i, pool_k)):
+        if active is not None:
+            pos = jnp.where(jnp.asarray(active).astype(bool), pos, -1)
+        return _pallas_index(q_i, w, pool_k, table, pos,
+                             interpret=not _on_tpu())
+    return index_rows_scores(q_i, block_gather(pool_k, table)[:, :, 0], w)
+
+
+def sparse_rows_gather(pool_c, table, rows):
+    """The rows of a latent pool that a selection names, a slot at a
+    time: ``rows`` ``(slots, k)`` int32 positions (negative: none), each
+    found at ``pool_c[table[s, t // block_size], t % block_size]``;
+    ``(slots, k, width)``, whatever row a negative position or an
+    unmapped page falls on (the caller masks by ``rows >= 0``).  Nothing
+    but those ``slots * k`` rows is read."""
+    nb, bs, _, width = pool_c.shape
+    at = jnp.maximum(rows, 0)
+    # The page of each position, by comparison against the table's
+    # columns: a look-up of slots x k scalars as a gather costs a TPU
+    # more than the rows' own gather is worth (0.13 ms for 32,768).
+    column = jnp.arange(table.shape[1], dtype=jnp.int32)
+    page = jnp.sum(jnp.where((at // bs)[:, :, None] == column,
+                             table[:, None, :], 0), axis=-1)
+    flat = jnp.clip(page, 0, nb - 1) * bs + at % bs
+    with jax.named_scope(SPARSE_GATHER_SCOPE):
+        return pool_c.reshape(nb * bs, width).at[flat].get(
+            mode="promise_in_bounds")
+
+
+def _sparse_chunk(q, pool_c, k: int, v_width: int) -> int:
+    """Rows of the gathered selection one grid step of the latent kernel
+    takes (its "page"): the largest that divides ``k`` and that the
+    kernel is eligible for, 0 where there is none."""
+    like = lambda c: jax.ShapeDtypeStruct((1, c, 1, pool_c.shape[3]),
+                                          pool_c.dtype)
+    return next((c for c in (512, 256, 128) if k % c == 0
+                 and _eligible(q, like(c), v_width)), 0)
+
+
+def paged_sparse_latent_attention(q, pool_c, table, rows, *, v_width: int,
+                                  scale: float, impl: str = "auto"):
+    """Attention of one query row per slot over the rows of a LATENT pool
+    that a selection names, and no others.
+
+    ``q``, ``pool_c``, ``table``, ``v_width``, ``scale`` as
+    :func:`paged_latent_attention` has them; ``rows`` ``(slots, k)``
+    int32: the positions slot ``s`` attends, those that mean one (``>=
+    0``) FIRST and ``-1`` behind them, as
+    :func:`~mpi4torch_tpu.models.transformer.select_rows` hands them
+    over (a slot with none returns zeros).  Head ``h`` weighs the named
+    rows by ``softmax(scale * q[s, h] . row)`` and returns the weighted
+    sum of their first ``v_width`` channels, ``(slots, heads,
+    v_width)``.
+
+    The rows are gathered by page and offset through the table
+    (:func:`sparse_rows_gather`: ``slots * k`` rows of the pool are read,
+    whatever the context's length) and attended by the latent kernel
+    over the gathered rows, a chunk of them a grid step (on a TPU, for
+    the shapes :func:`paged_latent_attention`'s kernel takes and ``k`` a
+    multiple of 128), else by :func:`latent_rows_attention`, the
+    oracle."""
+    if impl not in ("auto", "pallas", "jnp"):
+        raise ValueError(f"unknown impl {impl!r}")
+    if q.ndim != 3 or pool_c.ndim != 4 or pool_c.shape[2] != 1 \
+            or q.shape[2] != pool_c.shape[3]:
+        raise ValueError(
+            f"q{q.shape} must be (slots, heads, width) and the latent "
+            f"pool{pool_c.shape} (num_blocks, block_size, 1, width) of "
+            "the same width")
+    table = jnp.asarray(table, jnp.int32)
+    rows = jnp.asarray(rows, jnp.int32)
+    if table.ndim != 2 or table.shape[0] != q.shape[0] or rows.ndim != 2 \
+            or rows.shape[0] != q.shape[0]:
+        raise ValueError(
+            f"table{table.shape} must be (slots, n_blk) and rows"
+            f"{rows.shape} (slots, k) for {q.shape[0]} slots")
+    slots, k = rows.shape
+    got = sparse_rows_gather(pool_c, table, rows)
+    n_named = jnp.sum(rows >= 0, axis=-1, dtype=jnp.int32)
+    chunk = _sparse_chunk(q, pool_c, k, v_width)
+    if impl == "pallas" and not chunk:
+        raise ValueError(
+            f"impl='pallas' requires kernel-eligible operands (width and "
+            f"v_width multiples of 128, k a multiple of 128, q in the "
+            f"pool's dtype); got q{q.shape} {q.dtype} pool{pool_c.shape} "
+            f"{pool_c.dtype} v_width={v_width} k={k}")
+    if impl == "pallas" or (impl == "auto" and chunk and _on_tpu()):
+        # The gathered rows as a pool of their own, a slot's chunks one
+        # after another: the latent kernel reads chunks up to the last
+        # named row.
+        n_chunks = k // chunk
+        return _pallas_latent(
+            q, got.reshape(slots * n_chunks, chunk, 1, -1),
+            jnp.arange(slots * n_chunks, dtype=jnp.int32).reshape(
+                slots, n_chunks),
+            n_named - 1, v_width, float(scale), interpret=not _on_tpu(),
+            name=KERNEL_NAMES[3])
+    return latent_rows_attention(q, got, n_named - 1, v_width=v_width,
+                                 scale=scale)

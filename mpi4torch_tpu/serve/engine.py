@@ -581,7 +581,16 @@ class Engine:
         for spec, entry in zip(cfg.layer_specs, cache):
             if spec.mixer in asked:
                 continue
-            if "c" in entry:
+            index = getattr(spec.mixer, "index", None)
+            if "ik" in entry:
+                # A scoring layer visits pages where it scores (the
+                # read of the selected rows visits rows, not pages).
+                asked[spec.mixer] = _paged_attn.uses_index_kernel(
+                    like(slots, index.n_heads, index.head_dim),
+                    entry["ik"])
+            elif index is not None:
+                continue
+            elif "c" in entry:
                 asked[spec.mixer] = _paged_attn.uses_kernel(
                     like(slots, spec.mixer.n_heads, entry["c"].shape[-1]),
                     entry["c"], spec.mixer.kv_rank)
@@ -909,15 +918,25 @@ class Engine:
         host has them behind the sync its caller has just made, onto
         the record of the step that is open: ``moe_rows``, one
         ``(program, (expert layers, held) rows)`` per call of a program
-        with an expert layer, in the order of the calls; and, from a
+        with an expert layer (one a piece where a long prompt's expert
+        layers ran in pieces), in the order of the calls; and, from a
         program whose expert layers have zero-compute experts, the two
-        counters ``moe_zero_pairs`` and ``moe_live_pairs``, which the
+        counters ``moe_zero_pairs`` and ``moe_live_pairs``, and from a
+        decode step with indexed latent layers ``dsa_rows_live``,
+        ``dsa_rows_read`` and ``dsa_rows_scored`` (the latent rows under
+        the live slots' frontiers, the rows the selections named, the
+        index keys scored, each summed over its layers), which the
         step record carries by name like every counter.  No transfer is
         made here: a decode step's counters came down with its tokens
         (:meth:`_advance`), a prefill's by :meth:`_prefill_counters`."""
         if "moe_rows" in stats:
-            self.stats.attach("moe_rows", (program, stats["moe_rows"]))
-        for name in ("moe_zero_pairs", "moe_live_pairs"):
+            # One entry a call of the grouped products: a long prompt's
+            # expert layers ran in pieces, (pieces, expert layers, held).
+            rows = stats["moe_rows"]
+            for piece in rows.reshape((-1,) + rows.shape[-2:]):
+                self.stats.attach("moe_rows", (program, piece))
+        for name in ("moe_zero_pairs", "moe_live_pairs", "dsa_rows_live",
+                     "dsa_rows_read", "dsa_rows_scored"):
             if name in stats:
                 self.stats.count(name, int(stats[name]))
 

@@ -42,6 +42,7 @@ inside ``run_ranks`` rank threads (Mode B) and traced under ``run_spmd``
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional
 
 import jax
@@ -51,10 +52,13 @@ from .. import config as _config
 from ..constants import MPI_SUM
 from ..models.transformer import KDA, MLA, TransformerConfig, _MLA_BLOCK, \
     _blockwise_causal_attention, _norm, _split_qkv, branch_norm, \
-    dense_ffn, mla_expand, mla_project, shortcut_branch
-from ..ops.flash import flash_attention, flash_block_attention
-from ..ops.paged_attention import latent_rows_attention, \
-    paged_decode_attention, paged_latent_attention
+    dense_ffn, index_project, index_select_mask, mla_expand, mla_project, \
+    select_rows, shortcut_branch
+from ..ops.flash import flash_attention, flash_block_attention, \
+    masked_attention
+from ..ops.paged_attention import index_rows_scores, \
+    latent_rows_attention, paged_decode_attention, paged_index_scores, \
+    paged_latent_attention, paged_sparse_latent_attention
 from ..ops.ragged import block_scatter, position_onehot
 from ..overlap import overlap_split_allreduce, resolve_overlap
 from ..parallel.moe import held_experts_ffn
@@ -107,6 +111,13 @@ def validate_tp(cfg: TransformerConfig, size: int) -> None:
                 "the serving cache has no entry and no snapshot yet — "
                 "latent attention (MLA) and the held expert share are "
                 "what the serving walk knows of a per-layer spec")
+        if size != 1 and any(getattr(sp.mixer, "index", None) is not None
+                             for sp in cfg.layers):
+            raise CommError(
+                f"serve: sparse latent attention (MLA.index) is served on "
+                f"one rank; {size} ranks would need the index keys and the "
+                "selection shared between the ranks that hold a layer's "
+                "heads, which is not written yet")
         if size != 1 and any(sp.shortcut for sp in cfg.layers):
             raise CommError(
                 f"serve: a shortcut branch (LayerSpec.branch / .join) is "
@@ -237,7 +248,12 @@ def _cache_entries(cfg: TransformerConfig, lead: tuple, size: int, make):
     latent_width)`` rows for a latent layer — one row a token for all
     heads, the normed latent and the rotated shared key
     (:func:`latent_width`: 640 channels, 1,280 bytes in bfloat16, where
-    128 heads of keys and values would be 65,536)."""
+    128 heads of keys and values would be 65,536).  A latent layer that
+    SCORES (``MLA.index`` an ``Indexer``) has a second leaf beside it,
+    ``"ik"`` of ``(1, head_dim)`` rows: the token's index key, what a
+    later query's indexer scores this position by (128 channels, 256
+    bytes).  Both leaves of a layer live in the same pages: one block
+    table, one install, one copy on write."""
     hd = cfg.d_model // cfg.n_heads
     made = {}                  # one buffer a row shape, shared as a template
 
@@ -250,6 +266,8 @@ def _cache_entries(cfg: TransformerConfig, lead: tuple, size: int, make):
     for spec in cfg.layer_specs:
         if isinstance(spec.mixer, MLA):
             out.append({"c": leaf(1, latent_width(spec.mixer))})
+            if spec.mixer.scores:
+                out[-1]["ik"] = leaf(1, spec.mixer.index.head_dim)
         else:
             kv = leaf(cfg.kv_heads // size, hd)
             out.append({"k": kv, "v": kv})
@@ -404,13 +422,18 @@ class _Latent:
             [c, k_r, jnp.zeros(c.shape[:-1] + (pad,), c.dtype)],
             axis=-1)[:, :, None, :]
 
-    def expanded(self, q, rows, q_offset=None):
+    def expanded(self, q, rows, q_offset=None, selected=None):
         """``q`` ``(b, s, h, qk)`` over ``rows`` ``(b, n, 1, width)``:
         the whole sequence causally (``q_offset`` None: ``n == s``), or
         from global offset ``q_offset`` over ``rows`` from 0 on (a chunk
-        against cached rows; the jnp path, as the K/V chunk view)."""
+        against cached rows; the jnp path, as the K/V chunk view).
+        ``selected`` ``(s, n)`` bool, of an indexed layer: each query
+        over the rows its own row of the mask names and no others
+        (``ops.flash.masked_attention``, one sequence)."""
         sp = self.spec
         rows = rows[:, :, 0].astype(q.dtype)
+        if selected is not None:
+            return self._expanded_selected(q, rows, q_offset or 0, selected)
         k, v = mla_expand(sp, self.p, rows[..., :sp.kv_rank],
                           rows[..., sp.kv_rank:sp.kv_rank + sp.qk_rope])
         if q_offset is None:
@@ -420,6 +443,31 @@ class _Latent:
                 q, k, v, causal=True, q_offset=q_offset, kv_offset=0,
                 impl="jnp")
         return o[..., :sp.v_dim]
+
+    def _expanded_selected(self, q, rows, q_offset, selected):
+        """:meth:`expanded` under a selection, a group of heads at a
+        time, one after the other: the keys and values of 16 heads of a
+        16,384-token prompt are 0.27 GB where all 64 heads' are 1.1 GB
+        beside the 0.94 GB product they are cut from, and the one-piece
+        prefill has to fit beside the weights and the pool."""
+        sp = self.spec
+        c = rows[..., :sp.kv_rank]
+        k_r = rows[..., sp.kv_rank:sp.kv_rank + sp.qk_rope]
+        group = next(g for g in (16, 8, 4, 2, 1) if sp.n_heads % g == 0)
+        n = sp.n_heads // group
+        part = dataclasses.replace(sp, n_heads=group)
+
+        def heads(args):
+            q_g, wb_g = args
+            k, v = mla_expand(part, {"wb": wb_g}, c, k_r)
+            return masked_attention(q_g, k, v, selected, q_offset=q_offset)
+
+        b, s, _, d = q.shape
+        o = jax.lax.map(heads, (
+            jnp.moveaxis(q.reshape(b, s, n, group, d), 2, 0),
+            jnp.moveaxis(self.p["wb"].reshape(sp.kv_rank, n, -1), 1, 0)))
+        return jnp.moveaxis(o, 0, 2).reshape(b, s, sp.n_heads, -1)[
+            ..., :sp.v_dim]
 
     def _up(self):
         sp = self.spec
@@ -443,17 +491,23 @@ class _Latent:
 
 
 def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
-                 attend_latent, reduce, live=None):
+                 attend_latent, reduce, live=None, select=None):
     """The serving transformer block, once, for every serving program:
     per layer ``ln1`` → mixer → ``reduce`` → residual → ``ln2`` → FFN →
     ``reduce`` → residual; then ``ln_f``.  Returns ``(x, entries,
     counts)``: the normed hidden rows, what the view handed back for
     each layer, and what the expert layers counted (``{}`` without
     one): ``moe_rows``, the rows each held expert took in every expert
-    layer, ``(expert layers, held)``, and, where a layer has
+    layer, ``(expert layers, held)`` (``(pieces, expert layers, held)``
+    from a prompt long enough for its expert layers to run in pieces,
+    :func:`_held_experts_in_pieces`), and, where a layer has
     zero-compute experts, ``moe_zero_pairs`` and ``moe_live_pairs``, the
     live (token, choice) pairs that chose one and all of them, summed
-    over those layers.
+    over those layers; and from a DECODE step with indexed layers
+    ``dsa_rows_live``, the latent rows under the live slots' frontiers
+    summed over the indexed layers, ``dsa_rows_read``, the rows their
+    selections name (what attention reads of them), and
+    ``dsa_rows_scored``, the index keys the scoring layers scored.
 
     ``x`` is the embedded input, ``(b, s, d)`` with ``positions`` ``(s,)``
     (a prefill) or ``(slots, d)`` with one position a slot (a decode
@@ -466,11 +520,23 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
       that layer may see, and returns the attention output (any shape
       that flattens to ``x``'s rows) and the layer's new cache entry
       (or the rows to install);
-    * ``attend_latent(layer, q, rows, lat) -> (o, entry)`` is the same
-      view of a latent layer: ``rows`` are this pass's cache rows
-      (``lat.rows``), ``lat`` the layer's :class:`_Latent`, whose
+    * ``attend_latent(layer, q, rows, lat, selected) -> (o, entry)`` is
+      the same view of a latent layer: ``rows`` are this pass's cache
+      rows (``lat.rows``), ``lat`` the layer's :class:`_Latent`, whose
       ``expanded`` a prefill calls and whose ``absorbed`` / ``values`` a
       decode step calls; ``o`` holds every head's ``v_dim`` channels;
+      ``selected`` is ``None``, or on an indexed layer (``MLA.index``)
+      the selection the layer attends and nothing beside it;
+    * ``select(layer, q_i, k_i, w, ix) -> (selected, entry)`` is the view
+      of a SCORING layer's index keys: it stores this pass's keys ``k_i``
+      ``(b, s, 1, head_dim)`` its own way, scores what the layer may see
+      with the index queries ``q_i`` and weights ``w``
+      (``transformer.index_project``) and hands back the selection in
+      the form its ``attend_latent`` takes (a prefill: a ``(s, n)``
+      mask; a decode step: ``(slots, top_k)`` positions) and the index
+      keys' new cache entry.  The selection is CARRIED along the walk:
+      the layers above with ``MLA.index="shared"`` attend it as it is,
+      until the next scoring layer replaces it;
     * ``reduce(partial, site, nsites)`` sums a row-parallel partial
       product over the TP ranks; the sites are counted here, two a layer.
 
@@ -478,7 +544,9 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
     LayerSpec` names (``cfg.layer_specs``; without a spec every layer is
     the configuration's own): the mixer the configuration's attention or
     latent attention under ``layer_scope("mla")``, through the
-    projections the training forward uses (``mla_project``); the FFN
+    projections the training forward uses (``mla_project``), a scoring
+    layer's indexer (projections, cache write, scoring and top-k) under
+    ``layer_scope("dsa")`` beside it; the FFN
     dense or the held share of an expert layer under
     ``layer_scope("moe")`` (``live`` ``(rows,)`` keeps a decode step's
     free slots out of the groups and the counts); a second norm on each
@@ -496,7 +564,20 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
     # The projections take sequences: a decode step's rows are of one.
     seq = (lambda a: a[:, None]) if x.ndim == 2 else (lambda a: a)
     entries, moe_rows, zero_pairs, live_pairs = [], [], [], []
-    carried = None
+    carried = selected = None
+    dsa = {}
+
+    def dsa_counted(spec, selected):
+        # A decode step's rows: held under the frontiers, named by the
+        # selection (free slots name none), scored by this layer.
+        held = jnp.sum(positions + 1 if live is None
+                       else jnp.where(live, positions + 1, 0),
+                       dtype=jnp.int32)
+        for name, rows in (
+                ("dsa_rows_live", held),
+                ("dsa_rows_read", jnp.sum(selected >= 0, dtype=jnp.int32)),
+                ("dsa_rows_scored", held if spec.scores else 0)):
+            dsa[name] = dsa.get(name, 0) + rows
 
     def counted(spec, taken, zero):
         moe_rows.append(taken)
@@ -517,13 +598,28 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
         else:
             with layer_scope("mla"):
                 lat = _Latent(spec.mixer, blk["mixer"])
-                q, c, k_r = mla_project(cfg, spec.mixer, blk["mixer"],
-                                        seq(y), seq(positions))
-                o, entry = attend_latent(layer, q, lat.rows(c, k_r), lat)
+                q, c, k_r, cq = mla_project(cfg, spec.mixer, blk["mixer"],
+                                            seq(y), seq(positions))
+            scored = {}
+            if spec.mixer.scores:
+                with layer_scope("dsa"):
+                    ix = spec.mixer.index
+                    q_i, k_i, w = index_project(
+                        cfg, ix, blk["mixer"]["index"], seq(y), cq,
+                        seq(positions))
+                    selected, scored["ik"] = select(
+                        layer, q_i, k_i[:, :, None, :], w, ix)
+            indexed = spec.mixer.index is not None
+            with layer_scope("mla"):
+                o, entry = attend_latent(layer, q, lat.rows(c, k_r), lat,
+                                         selected if indexed else None)
+                entry = {**entry, **scored}
                 o_part = branch_norm(
                     cfg, spec, blk,
                     o.reshape(*x.shape[:-1], -1).astype(x.dtype)
                     @ blk["mixer"]["wo"], "ln1_post")
+            if indexed and x.ndim == 2:
+                dsa_counted(spec.mixer, selected)
         entries.append(entry)
         x = x + reduce(o_part, 2 * layer, nsites).astype(x.dtype)
         y = _norm(cfg, x, blk["ln2"])
@@ -535,22 +631,52 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
                              "ln2_post")
         else:
             with layer_scope("moe"):
-                ff, taken, zero = held_experts_ffn(
+                ff, taken, zero = _held_experts_in_pieces(
                     y.reshape(-1, y.shape[-1]), blk["experts"], spec.ffn,
-                    live=live)
+                    live)
                 ff = branch_norm(cfg, spec, blk, ff.reshape(y.shape),
                                  "ln2_post")
             counted(spec.ffn, taken, zero)
         x = x + reduce(ff, 2 * layer + 1, nsites).astype(x.dtype)
         if spec.join:
             x, carried = x + carried.astype(x.dtype), None
-    x, counts = _norm(cfg, x, shards["ln_f"]), {}
+    x, counts = _norm(cfg, x, shards["ln_f"]), {
+        k: jnp.asarray(v, jnp.int32) for k, v in dsa.items()}
     if moe_rows:
-        counts["moe_rows"] = jnp.stack(moe_rows)
+        # (expert layers, held); of a prompt whose expert layers ran in
+        # pieces, (pieces, expert layers, held).
+        counts["moe_rows"] = jnp.moveaxis(jnp.stack(moe_rows), 0, -2)
     if zero_pairs:
         counts["moe_zero_pairs"] = sum(zero_pairs)
         counts["moe_live_pairs"] = jnp.asarray(sum(live_pairs), jnp.int32)
     return x, entries, counts
+
+
+# Rows of one call of the expert layer: the layer keeps a buffer of
+# every (token, choice) pair at the stream's width and again at the
+# experts', 1.5 GB in all for 4,096 tokens choosing 8 of 6,144 channels.
+_EXPERT_ROWS = 4096
+
+
+def _held_experts_in_pieces(x, params, spec, live):
+    """``held_experts_ffn`` on ``x`` ``(T, d)``, ``_EXPERT_ROWS`` tokens
+    at a time where there are more (a long prompt's one-piece prefill):
+    routing is a token's own affair, so the pieces' outputs are the
+    whole's, and the layer's buffers are a piece's.  The rows the held
+    experts took come back a piece at a time then, ``(pieces, held)``:
+    each piece is a call of the grouped products with group sizes of its
+    own, and is counted as one."""
+    T, d = x.shape
+    if T <= _EXPERT_ROWS:
+        return held_experts_ffn(x, params, spec, live=live)
+    # Written out piece by piece: as the operands of a loop the experts'
+    # matrices would be copied into its state, 1.2 GB a layer.
+    pieces = [held_experts_ffn(
+        x[at:at + _EXPERT_ROWS], params, spec,
+        live=None if live is None else live[at:at + _EXPERT_ROWS])
+        for at in range(0, T, _EXPERT_ROWS)]
+    y, taken, zero = zip(*pieces)
+    return jnp.concatenate(y), jnp.stack(taken), sum(zero)
 
 
 def _hand_out(stats, counts):
@@ -576,6 +702,13 @@ def _prefill_reduce(comm):
 def _live_rows(active):
     """A decode step's ``active`` argument as a ``(slots,)`` bool mask."""
     return None if active is None else jnp.asarray(active).astype(bool)
+
+
+def _frontier(pos, extent: int, live):
+    """``(slots, extent)`` bool: the positions a decode step's slot may
+    score, ``0..pos``, none for a free slot."""
+    seen = jnp.arange(extent, dtype=jnp.int32)[None, :] <= pos[:, None]
+    return seen if live is None else seen & live[:, None]
 
 
 def _decode_reduce(comm, live, overlap, algorithm):
@@ -624,18 +757,27 @@ def prefill_tp(cfg: TransformerConfig, shards, cache, prompt, comm=None,
         o = flash_attention(q, k, v, causal=True, window=cfg.attn_window)
         return o, {"k": ck, "v": cv}
 
-    def attend_latent(layer, q, rows, lat):
+    def attend_latent(layer, q, rows, lat, selected):
         # The same for a latent layer: rows written at 0, attention over
-        # this pass's own rows, expanded.
+        # this pass's own rows, expanded; under a selection, each query
+        # over the rows it names.
         c = cache[layer]["c"]
         cc = jax.lax.dynamic_update_slice_in_dim(
             c, rows.astype(c.dtype), 0, 1)
-        return lat.expanded(q, rows), {"c": cc}
+        return lat.expanded(q, rows, selected=selected), {"c": cc}
+
+    def select(layer, q_i, k_i, w, ix):
+        # Index keys written at 0; every query scores this pass's own
+        # keys up to its position.
+        c = cache[layer]["ik"]
+        return (index_select_mask(q_i[0], k_i[0, :, 0], w[0], ix.top_k),
+                jax.lax.dynamic_update_slice_in_dim(
+                    c, k_i.astype(c.dtype), 0, 1))
 
     with serve_step_scope("prefill"):
         x, new_cache, counts = _walk_layers(
             cfg, shards, x, positions, attend, attend_latent,
-            _prefill_reduce(comm))
+            _prefill_reduce(comm), select=select)
         _hand_out(stats, counts)
         return x[:, -1] @ shards["unembed"], new_cache
 
@@ -685,18 +827,26 @@ def prefill_chunk_tp(cfg: TransformerConfig, shards, past, chunk,
             window=cfg.attn_window, impl="jnp")
         return o, rows
 
-    def attend_latent(layer, q, rows, lat):
+    def attend_latent(layer, q, rows, lat, selected):
         # A latent layer's chunk: its rows go back to be installed and
         # attend past ++ chunk, expanded, from their global offset.
         p = past[layer]["c"]
         full = jnp.concatenate([p.astype(rows.dtype), rows], axis=1)
-        return (lat.expanded(q, full, q_offset=p_len),
+        return (lat.expanded(q, full, q_offset=p_len, selected=selected),
                 {"c": rows.astype(p.dtype)})
+
+    def select(layer, q_i, k_i, w, ix):
+        # The chunk's index keys go back to be installed; its queries
+        # score past ++ chunk from their global offset.
+        p = past[layer]["ik"]
+        full = jnp.concatenate([p.astype(k_i.dtype), k_i], axis=1)
+        return (index_select_mask(q_i[0], full[0, :, 0], w[0], ix.top_k,
+                                  q_offset=p_len), k_i.astype(p.dtype))
 
     with serve_step_scope("prefill"):
         x, rows, counts = _walk_layers(
             cfg, shards, x, positions, attend, attend_latent,
-            _prefill_reduce(comm))
+            _prefill_reduce(comm), select=select)
         _hand_out(stats, counts)
         return x[:, -1] @ shards["unembed"], rows
 
@@ -759,23 +909,40 @@ def decode_step_tp(cfg: TransformerConfig, shards, cache, tokens, pos,
             window=cfg.attn_window, impl="jnp")
         return o, {"k": ck, "v": cv}
 
-    def attend_latent(layer, q, rows, lat):
+    def attend_latent(layer, q, rows, lat, selected):
         # The same write of a latent row; the read is absorbed: every
-        # head scores the slot's rows as they lie.
+        # head scores the slot's rows as they lie, or, under a
+        # selection, the rows it names, taken out of the slot's extent.
         c = cache[layer]["c"]
         wmask = (position_onehot(pos, cfg.max_seq) != 0)[:, :, None, None]
         cc = jnp.where(wmask, rows.astype(c.dtype), c)
+        seen, upto = cc[:, :, 0], pos
+        if selected is not None:
+            seen = jnp.take_along_axis(
+                seen, jnp.maximum(selected, 0)[:, :, None], axis=1)
+            upto = jnp.sum(selected >= 0, axis=-1, dtype=jnp.int32) - 1
         u = latent_rows_attention(
-            lat.absorbed(q[:, 0]), cc[:, :, 0], pos,
+            lat.absorbed(q[:, 0]), seen, upto,
             v_width=lat.spec.kv_rank, scale=lat.scale)
         return lat.values(u), {"c": cc}
+
+    def select(layer, q_i, k_i, w, ix):
+        # The same write of an index key; every slot scores its extent
+        # and keeps the top_k positions up to its frontier.
+        c = cache[layer]["ik"]
+        wmask = (position_onehot(pos, cfg.max_seq) != 0)[:, :, None, None]
+        cc = jnp.where(wmask, k_i.astype(c.dtype), c)
+        scores = index_rows_scores(q_i[:, 0], cc[:, :, 0], w[:, 0])
+        return select_rows(scores, _frontier(pos, cfg.max_seq, live),
+                           ix.top_k), cc
 
     with serve_step_scope("decode_step"):
         x = shards["embed"][tokens]
         if not cfg.rope:
             x = x + jnp.take(shards["pos"], pos, axis=0)
         x, new_cache, counts = _walk_layers(
-            cfg, shards, x, pos, attend, attend_latent, reduce, live)
+            cfg, shards, x, pos, attend, attend_latent, reduce, live,
+            select)
         _hand_out(stats, counts)
         return x @ shards["unembed"], new_cache
 
@@ -858,21 +1025,38 @@ def decode_step_paged(cfg: TransformerConfig, shards, pool, table,
             active=live)
         return o, {"k": pk, "v": pv}
 
-    def attend_latent(layer, q, rows, lat):
+    def attend_latent(layer, q, rows, lat, selected):
         # One latent row a live slot scattered into its page; the read
-        # is absorbed, page by page through the table.
+        # is absorbed, page by page through the table, or, under a
+        # selection, of the rows it names and no others.
         pc = write(pool[layer]["c"], wb, off, rows[:, 0], live)
-        u = paged_latent_attention(
-            lat.absorbed(q[:, 0]), pc, table, pos,
-            v_width=lat.spec.kv_rank, scale=lat.scale, active=live)
+        if selected is None:
+            u = paged_latent_attention(
+                lat.absorbed(q[:, 0]), pc, table, pos,
+                v_width=lat.spec.kv_rank, scale=lat.scale, active=live)
+        else:
+            u = paged_sparse_latent_attention(
+                lat.absorbed(q[:, 0]), pc, table, selected,
+                v_width=lat.spec.kv_rank, scale=lat.scale)
         return lat.values(u), {"c": pc}
+
+    def select(layer, q_i, k_i, w, ix):
+        # One index key a live slot scattered into its page; every slot
+        # scores its pages up to its frontier and keeps the top_k
+        # positions.
+        pk = write(pool[layer]["ik"], wb, off, k_i[:, 0], live)
+        scores = paged_index_scores(q_i[:, 0], w[:, 0], pk, table, pos,
+                                    active=live)
+        return select_rows(scores, _frontier(pos, scores.shape[1], live),
+                           ix.top_k), pk
 
     with serve_step_scope("decode_step"):
         x = shards["embed"][tokens]
         if not cfg.rope:
             x = x + jnp.take(shards["pos"], pos, axis=0)
         x, new_pool, counts = _walk_layers(
-            cfg, shards, x, pos, attend, attend_latent, reduce, live)
+            cfg, shards, x, pos, attend, attend_latent, reduce, live,
+            select)
         _hand_out(stats, counts)
         return x @ shards["unembed"], new_pool
 

@@ -57,6 +57,9 @@ _STEP_COUNTS = (("admitted", "admitted"),
                 ("decode_select_syncs", "decode_select_syncs"),
                 ("moe_zero_pairs", "moe_zero_pairs"),
                 ("moe_live_pairs", "moe_live_pairs"),
+                ("dsa_rows_live", "dsa_rows_live"),
+                ("dsa_rows_read", "dsa_rows_read"),
+                ("dsa_rows_scored", "dsa_rows_scored"),
                 ("decode_uploads", "decode_uploads"),
                 ("step_compiles", "step_compiles"),
                 ("occupancy_ticks", "active"))
@@ -126,16 +129,20 @@ def serve_step_scope(what: str = "decode_step"):
 # under its own scope: forward, recomputed and backward instructions of a
 # compiled step all carry the name in their ``op_name``.
 LAYER_SCOPES = {"kda": "mpi4torch.kda", "mla": "mpi4torch.mla",
-                "moe": "mpi4torch.moe", "ffn": "mpi4torch.ffn"}
+                "moe": "mpi4torch.moe", "ffn": "mpi4torch.ffn",
+                "dsa": "mpi4torch.dsa"}
 
 
 def layer_scope(kind: str):
     """Named scope ``mpi4torch.<kind>`` around one mechanism of a layer:
     ``kda`` (the gated delta-rule mixer, projections included), ``mla``
     (the latent-attention mixer), ``moe`` (router, grouped expert
-    products, shared and zero-compute experts) or ``ffn`` (the dense FFN
+    products, shared and zero-compute experts), ``ffn`` (the dense FFN
     of a layer that carries or joins a shortcut branch, and of no other
-    layer: the path the branch runs beside)."""
+    layer: the path the branch runs beside) or ``dsa`` (a scoring
+    layer's indexer: its projections, the index-key cache write, the
+    scoring and the top-k; the read of the selected rows is the latent
+    read and stays under ``mla``)."""
     return _labeled_scope(LAYER_SCOPES[kind])
 
 
@@ -220,6 +227,13 @@ class ServeStats:
                  # and decode step, expert layers and live rows).  Their
                  # ratio is the share of choices that cost no expert.
                  "moe_zero_pairs", "moe_live_pairs",
+                 # ISSUE 39: sparse latent attention's decode steps
+                 # (``kv._hand_out``): the latent rows under the live
+                 # slots' frontiers summed over the indexed layers, the
+                 # rows their selections named (what attention read of
+                 # them: min(pos + 1, top_k) a slot and layer), and the
+                 # index keys the scoring layers scored.
+                 "dsa_rows_live", "dsa_rows_read", "dsa_rows_scored",
                  # ISSUE 36: host-to-device transfers the decode steps'
                  # ``decode.dispatch.inputs`` made (table, tokens,
                  # positions, live mask, and the keys where the engine
@@ -432,7 +446,8 @@ def serve_step_log() -> list:
     "t1_ns", "spans": [(name, t0_ns, t1_ns, rid), ...], "admitted",
     "prefill_tokens", "install_writes", "decode_pages_live",
     "decode_pages_read", "decode_select_syncs", "moe_zero_pairs",
-    "moe_live_pairs", "decode_uploads", "step_compiles", "active"}`` on
+    "moe_live_pairs", "dsa_rows_live", "dsa_rows_read", "dsa_rows_scored",
+    "decode_uploads", "step_compiles", "active"}`` on
     the ``time.perf_counter_ns()`` clock, the last :data:`STEP_LOG_CAP`
     steps (and ``moe_rows`` / ``compiles`` where :meth:`ServeStats.attach`
     put them).  ``engine`` is the ``ServeStats.engine`` serial of the

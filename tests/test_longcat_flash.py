@@ -147,8 +147,8 @@ def test_the_cached_row_holds_the_scaled_latent():
     bare = dataclasses.replace(spec, q_scale=1.0, kv_scale=1.0)
     y = jnp.asarray(np.random.default_rng(3).standard_normal(
         (1, 11, CFG["hidden_size"])), F32)
-    q, c, k_r = T.mla_project(TCFG, spec, p, y, jnp.arange(11))
-    q0, c0, k_r0 = T.mla_project(TCFG, bare, p, y, jnp.arange(11))
+    q, c, k_r, _ = T.mla_project(TCFG, spec, p, y, jnp.arange(11))
+    q0, c0, k_r0, _ = T.mla_project(TCFG, bare, p, y, jnp.arange(11))
     assert _gap(c, spec.kv_scale * c0) < 1e-6 and _gap(k_r, k_r0) == 0
     lat = kv._Latent(spec, p)
     rows = lat.rows(c, k_r)

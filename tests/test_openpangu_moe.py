@@ -136,7 +136,7 @@ def test_absorbed_decode_equals_expanded_attention_on_one_cache():
     rng = np.random.default_rng(3)
     n, h = 21, spec.n_heads
     y = jnp.asarray(rng.standard_normal((1, n, CFG["hidden_size"])), F32)
-    q, c, k_r = T.mla_project(TCFG, spec, blk["mixer"], y, jnp.arange(n))
+    q, c, k_r, _ = T.mla_project(TCFG, spec, blk["mixer"], y, jnp.arange(n))
     rows = lat.rows(c, k_r)                              # (1, n, 1, width)
     expanded = lat.expanded(q, rows)[0, -1]              # (h, dv) at n - 1
     u = kv.latent_rows_attention(

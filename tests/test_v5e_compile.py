@@ -408,6 +408,83 @@ def test_longcats_programs_compile_at_their_real_size(
     assert held + pre.temp_size_in_bytes + pre.output_size_in_bytes < 15.7e9
 
 
+def test_glms_two_reads_compile_at_their_cells_shapes(one_v5e_chip,
+                                                      as_on_tpu):
+    """GLM-5.2's decode reads in `serve_dsa_16k`: 16 slots over 136
+    pages of 128 positions each.  The scoring (32 index queries of 128
+    channels a slot against index-key pages) and the read of 2,048
+    selected rows a slot (64 heads on rows of 640 channels, gathered by
+    page and offset, a chunk of 512 a grid step): Mosaic takes both
+    kernels, and each pool leaf goes in as it lies."""
+    like = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_v5e_chip)
+    slots, nb, n_blk = 16, 2176, 136
+    q_i, keys = like((slots, 32, 128)), like((nb, 128, 1, 128))
+    assert pa.uses_index_kernel(q_i, keys)
+    table, pos = like((slots, n_blk), jnp.int32), like((slots,), jnp.int32)
+    with jax.enable_x64(False):
+        text = jax.jit(pa.paged_index_scores).lower(
+            q_i, like((slots, 32), F32), keys, table, pos
+        ).compile().as_text()
+    assert pa.KERNEL_NAMES[2] in text and "tpu_custom_call" in text
+    assert set(_made_with_shape(text, "2176,128,1,128")) == {"parameter"}
+    q, pool = like((slots, 64, 640)), like((nb, 128, 1, 640))
+    assert pa._sparse_chunk(q, pool, 2048, 512) == 512
+    with jax.enable_x64(False):
+        text = jax.jit(lambda *a: pa.paged_sparse_latent_attention(
+            *a, v_width=512, scale=256 ** -0.5)).lower(
+                q, pool, table, like((slots, 2048), jnp.int32)
+            ).compile().as_text()
+    assert pa.KERNEL_NAMES[3] in text and pa.SPARSE_GATHER_SCOPE in text
+    assert set(_made_with_shape(text, "2176,128,1,640")) == {"parameter"}
+    # The selected rows exist once, as gathered: 16 x 2,048 of them.
+    assert "fusion" in _made_with_shape(text, "32768,640")
+
+
+def test_glms_programs_compile_at_their_real_size(
+        one_v5e_chip, as_on_tpu, capsys):
+    """GLM-5.2 as `serve_dsa_16k` serves it (3.88 B parameters, five
+    latent layers of which two score, the 1.93 GB pool of latent rows
+    and index keys), from shapes alone.  The decode step: every pool
+    leaf aliased to its output, two scorings and five reads of selected
+    rows through their kernels, two grouped products for each of the
+    four expert layers, small temporaries.  The longest prefill (16,384
+    tokens in one piece) compiles and its temporaries fit beside weights
+    and pool on a 16 GB chip.  Both programs' temporaries are printed."""
+    from benchmarks.families import glm_dsa as fam
+
+    cfg, tcfg, mesh, params, count, stacked, like = _real_size(
+        "glm-5.2", fam, one_v5e_chip)
+    assert count == 3_881_517_056
+    slots, bs, nb = 16, 128, 2176
+    layers = cfg["num_hidden_layers"]
+    scoring = sum(kind == "full" for kind in cfg["indexer_types"])
+    pool_bytes = nb * bs * (layers * 640 + scoring * 128) * 2
+    assert pool_bytes == slots * cfg["max_position_embeddings"] * 6912
+    compiled = _compiled_decode_step(tcfg, mesh, params, stacked, like,
+                                     slots, bs, nb)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert len(_names(text, pa.KERNEL_NAMES[2])) == scoring == 2
+    assert len(_names(text, pa.KERNEL_NAMES[3])) == layers == 5
+    assert not _names(text, pa.KERNEL_NAMES[1] + ".") \
+        and (pa.KERNEL_NAMES[1] + " ") not in text
+    assert len(_names(text, "ragged-dot-none")) \
+        == 2 * (layers - cfg["first_k_dense_replace"])
+    for scope in ("mpi4torch.mla", "mpi4torch.dsa", "mpi4torch.moe",
+                  pa.SPARSE_GATHER_SCOPE):
+        assert scope in text, scope
+    pre = _compiled_prefill(tcfg, mesh, params, like, 16384).memory_analysis()
+    with capsys.disabled():
+        print(f"\nglm-5.2: {count:,} parameters; decode step temporaries "
+              f"{mem.temp_size_in_bytes / 1e9:.3f} GB; 16,384-token prefill "
+              f"temporaries {pre.temp_size_in_bytes / 1e9:.3f} GB, outputs "
+              f"{pre.output_size_in_bytes / 1e9:.3f} GB")
+    held = 2 * count + pool_bytes
+    assert held + pre.temp_size_in_bytes + pre.output_size_in_bytes < 15.7e9
+
+
 def test_dp_step_compiles_to_all_reduces_alone(v5e_2x2):
     """`train_dp4`'s program at a small size on the four chips, as the
     benchmark builds it: matrices of 8 MiB that travel alone beside
