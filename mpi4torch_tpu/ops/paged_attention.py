@@ -5,14 +5,20 @@ One query row per slot (the continuous-batching decode step of
 block table.  Two realizations behind one signature, chosen like
 :mod:`~mpi4torch_tpu.ops.flash` chooses — by backend and shapes alone:
 
-* a Pallas TPU kernel (:data:`KERNEL_NAMES`): the table and the
-  positions ride as scalar-prefetch arguments, the grid walks (slot,
-  page), and the K/V index maps look the page up in the table — so a
-  page travels HBM -> VMEM exactly once, and only if it lies between
-  the slot's window start and its frontier.  Grid steps past the
-  frontier repeat the last live page's index (the pipeline fetches
-  nothing for an unchanged index) and compute nothing.  No array of
-  the pool's or of a slot's ``max_seq`` extent is ever formed;
+* a Pallas TPU kernel (:data:`KERNEL_NAMES`): the pages' ids (the
+  table, resolved once a call in plain XLA) and the positions ride as
+  scalar-prefetch arguments, the grid walks (slot, group of pages), and
+  the K/V index maps look each page of the group up there — so a page
+  travels HBM -> VMEM exactly once, and only if it lies between the
+  slot's window start and its frontier.
+  A grid step takes several pages (:func:`read_grid`: as many as
+  divide the table's width and fit the staging budget), each through an
+  operand of its own, and folds them into the running softmax in ONE
+  update.  An operand whose page lies outside the span repeats the
+  nearest live page OF ITS OWN (the pipeline fetches nothing for an
+  unchanged index), and a step none of whose pages is live computes
+  nothing.  No array of the pool's or of a slot's ``max_seq`` extent is
+  ever formed;
 * the jnp path for every other platform and shape: the pages gathered
   into each slot's full extent by
   :func:`~mpi4torch_tpu.ops.ragged.block_gather` and attended by
@@ -33,8 +39,9 @@ by the few query rows, so the masked-out products are free.
 
 Arithmetic as :func:`~mpi4torch_tpu.ops.flash._jnp_block` has it:
 K and V read in the pool's dtype, scores, running max/sum and the P.V
-accumulation in float32.  The softmax is online (page by page), so
-against the jnp path the result is equal to rounding, not bitwise.
+accumulation in float32.  The softmax is online (a grid step's pages
+at a time), so against the jnp path the result is equal to rounding,
+not bitwise.
 
 A **latent** pool (:func:`paged_latent_attention`) holds one row a
 token for all heads, ``(num_blocks, block_size, 1, width)``: the row is
@@ -59,6 +66,7 @@ Inference-only: no VJP.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -72,7 +80,7 @@ __all__ = ["paged_decode_attention", "paged_latent_attention",
            "index_scores", "index_rows_scores",
            "paged_sparse_latent_attention",
            "sparse_rows_gather", "uses_kernel", "uses_index_kernel",
-           "KERNEL_NAMES"]
+           "read_grid", "KERNEL_NAMES"]
 
 # Stable names of the Mosaic kernels: what a lowered program's
 # ``kernel_name`` attributes and a profiler trace's kernel events are
@@ -124,80 +132,214 @@ def uses_kernel(q, pool_k, v_width=None) -> bool:
 
 
 def _page_span(pos, bs: int, n_blk: int, window: int):
-    """First and one-past-last page a query at ``pos`` attends (scalar
-    int32 arithmetic, non-negative operands only so truncating division
-    is floor).  ``pos < 0`` marks a slot that reads nothing; a position
-    past the table's extent is held to the table (the index maps read
-    the table at these pages)."""
+    """First and one-past-last page a query at ``pos`` attends (int32
+    arithmetic on a kernel's scalar or on every slot's at once,
+    non-negative operands only so truncating division is floor).
+    ``pos < 0`` marks a slot that reads nothing; a position past the
+    table's extent is held to the table."""
     i32 = jnp.int32
     n_live = jnp.where(
         pos >= 0,
-        jnp.minimum(jax.lax.div(jnp.maximum(pos, i32(0)), i32(bs)) + 1,
-                    i32(n_blk)), i32(0))
+        jnp.minimum(jnp.maximum(pos, i32(0)) // i32(bs) + 1, i32(n_blk)),
+        i32(0))
     if not window:
         return jnp.zeros_like(n_live), n_live
-    first = jax.lax.div(jnp.maximum(pos - (window - 1), i32(0)), i32(bs))
-    return first, n_live
+    return jnp.maximum(pos - (window - 1), i32(0)) // i32(bs), n_live
 
 
-def _kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, bs: int, kvh: int, g: int,
-            n_blk: int, window: int):
-    from jax.experimental import pallas as pl
+def _pages_a_step(n_blk: int, page_bytes: int) -> int:
+    """Pages one grid step of a paged read takes, each through an
+    operand of its own, so that they share the step's fixed cost: the
+    largest of 8, 4, 2, 1 that divides the table's width and keeps that
+    many double-buffered pages (``page_bytes`` each: a K page and a V
+    page where the read stages both) within the budget ``_eligible``
+    holds a single page to."""
+    return next((g for g in (8, 4, 2) if n_blk % g == 0
+                 and 2 * g * page_bytes <= _KV_VMEM_BUDGET), 1)
 
-    f32, i32 = jnp.float32, jnp.int32
-    s, j = pl.program_id(0), pl.program_id(1)
-    pos = pos_ref[s]
+
+def read_grid(slots: int, n_blk: int, *pools) -> tuple:
+    """The grid a paged read's kernel walks over a table of ``(slots,
+    n_blk)``: ``(slots, n_blk // pages a step)``.  ``pools``: the pool
+    leaves the read stages a page of (K and V; the one latent pool; the
+    index keys); only shapes and dtypes are read.  What the kernels give
+    as ``grid=``, and what a caller counts grid steps by."""
+    page_bytes = sum(math.prod(p.shape[1:]) * jnp.dtype(p.dtype).itemsize
+                     for p in pools)
+    return slots, n_blk // _pages_a_step(n_blk, page_bytes)
+
+
+def _page_ids(table, pos, bs: int, window: int, group: int):
+    """The pool page each operand of each grid step fetches, ``(slots *
+    n_blk,)`` int32 for the kernels' scalar prefetch: entry ``s * n_blk
+    + j * group + g`` is what operand ``g`` holds at grid step ``j`` of
+    slot ``s``.  Inside the slot's span that is the table's own entry
+    (``-1``, an unmapped page, stays: the index map holds it to page 0
+    and the kernel reads zeros).  Outside it the operand repeats the
+    nearest live page OF ITS OWN (the pages ``g`` modulo ``group``), so
+    every page it names is one it folds and nothing is fetched for a
+    dead step; where the span holds none of its own, the pool's page 0,
+    which it keeps until a slot gives it one.  Worked out here, in
+    plain XLA and once for all of a decode step's layers (they share
+    the table and the positions), and not by the index maps: a grid
+    step pays the scalar core for every operand's look-up, live or
+    dead."""
+    i32 = jnp.int32
+    slots, n_blk = table.shape
     first, n_live = _page_span(pos, bs, n_blk, window)
+    by_step = table.reshape(slots, n_blk // group, group)
+    step = jnp.arange(n_blk // group, dtype=i32)[None, :, None]
+    g = jnp.arange(group, dtype=i32)[None, :]
+    # The grid step of an operand's first page at or behind `first`,
+    # and of its last below `n_live` (none: lo > hi).
+    lo = (first[:, None] + (group - 1 - g)) // group
+    hi = (n_live[:, None] + (group - 1 - g)) // group - 1
+    # Its entry at a step, by comparison against the steps: a gather of
+    # a few scalars costs a TPU more than the whole row's comparison.
+    at = lambda k: jnp.sum(jnp.where(step == k[:, None, :], by_step, 0),
+                           axis=1, keepdims=True)
+    ids = jnp.where(step > hi[:, None, :], at(hi), by_step)
+    if window:
+        ids = jnp.where(step < lo[:, None, :], at(lo), ids)
+    return jnp.where((lo <= hi)[:, None, :], ids, 0).reshape(-1)
+
+
+def _page_indices(group: int, n_blk: int):
+    """The index maps of a grid step's ``group`` page operands over
+    :func:`_page_ids`."""
+    def index_of(g: int):
+        def index(s, j, ids_ref, pos_ref):
+            return jnp.maximum(ids_ref[s * n_blk + j * group + g], 0), 0, 0
+        return index
+
+    return [index_of(g) for g in range(group)]
+
+
+def _slot_index(s, j, ids_ref, pos_ref):
+    return s, 0, 0
+
+
+def _start(j, m_ref, l_ref, acc_ref):
+    from jax.experimental import pallas as pl
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full(m_ref.shape, NEG_BIG, f32)
-        l_ref[...] = jnp.zeros(l_ref.shape, f32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+        m_ref[...] = jnp.full(m_ref.shape, NEG_BIG, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    @pl.when((j >= first) & (j < n_live))
-    def _page():
-        q = q_ref[0]                                   # (heads, hd)
-        k, v = k_ref[0], v_ref[0]                      # (bs * kvh, hd)
-        heads, rows = q.shape[0], k.shape[0]
-        prec = dot_precision(q.dtype)
-        scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[1], f32))
-        sc = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=f32, precision=prec) * scale
-        # Row r of the page is position r // kvh of KV head r % kvh.
-        col = jax.lax.broadcasted_iota(i32, (heads, rows), 1)
-        row = jax.lax.broadcasted_iota(i32, (heads, rows), 0)
-        kv_pos = j * bs + jax.lax.div(col, i32(kvh))
-        mask = (jax.lax.rem(col, i32(kvh)) == jax.lax.div(row, i32(g))) \
-            & (kv_pos <= pos)
-        if window:
-            mask &= (pos - kv_pos) < window
-        # An unmapped page inside the frontier reads as zeros, as
-        # block_gather hands it over (whatever page the clamped index
-        # fetched is discarded, NaN and all).
-        mapped = table_ref[s * n_blk + j] >= 0
-        sc = jnp.where(mapped, sc, 0.0)
-        sc = jnp.where(mask, sc, NEG_BIG)
-        m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(sc - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=f32, precision=prec)
-        acc_ref[...] = acc_ref[...] * corr + jnp.where(mapped, pv, 0.0)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(j == n_blk - 1)
-    def _finish():
+def _fold_live_pages(fold, page0, group: int, first, n_live):
+    """Run ``fold(n)``, the update over a grid step's first ``n`` pages,
+    where the step (pages ``page0 .. page0 + group``) holds a live page:
+    over all of them, or over the first half where no live page lies
+    behind it (a frontier just past a step's start, as behind a prompt
+    of whole steps, would otherwise pay for a whole step of products)."""
+    from jax.experimental import pallas as pl
+
+    live = (page0 + group > first) & (page0 < n_live)
+    half = group // 2
+    if not half:
+        pl.when(live)(lambda: fold(group))
+        return
+    pl.when(live & (page0 + half >= n_live))(lambda: fold(half))
+    pl.when(live & (page0 + half < n_live))(lambda: fold(group))
+
+
+def _as_read(sc, v, live, mapped):
+    """A page's scores and values as the fold takes them: an unmapped
+    page inside the frontier reads as zeros, as block_gather hands it
+    over, and a page outside the span (its scores are masked, its
+    operand holds whatever it last fetched) brings zero values, so that
+    nothing it holds, NaN and all, meets a weight."""
+    return (jnp.where(mapped, sc, 0.0),
+            jnp.where(live & mapped, v, jnp.zeros((), v.dtype)))
+
+
+def _side_by_side(scs, vals):
+    """A grid step's pages as ONE block of scores and one of values, in
+    page order: they enter the running softmax in one update.  A page
+    folded alone pays the whole chain product - maximum - exponential -
+    product - rescale before the next may start, and that chain, not
+    the page's bytes or the grid step, is most of what a live page
+    costs (0.76 us for a page whose bytes need 0.20, PERF.md)."""
+    if len(scs) == 1:
+        return scs[0], vals[0]
+    return jnp.concatenate(scs, axis=1), jnp.concatenate(vals, axis=0)
+
+
+def _fold(sc, mask, v, m_ref, l_ref, acc_ref, prec):
+    """Scores ``sc`` (float32, ``mask`` their attended pairs) and the
+    values ``v`` of their rows into the running max, sum and
+    accumulator."""
+    sc = jnp.where(mask, sc, NEG_BIG)
+    m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+    p = jnp.where(mask, jnp.exp(sc - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=prec)
+    acc_ref[...] = acc_ref[...] * corr + pv
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+
+def _finish(j, steps: int, o_ref, l_ref, acc_ref):
+    from jax.experimental import pallas as pl
+
+    @pl.when(j == steps - 1)
+    def _out():
         l = l_ref[:, :1]
         safe = jnp.where(l > 0, l, 1.0)
         o_ref[0] = jnp.where(l > 0, acc_ref[...] / safe,
                              0.0).astype(o_ref.dtype)
+
+
+def _kernel(ids_ref, pos_ref, q_ref, *refs, bs: int, kvh: int, g: int,
+            n_blk: int, window: int, group: int):
+    from jax.experimental import pallas as pl
+
+    f32, i32 = jnp.float32, jnp.int32
+    s, j = pl.program_id(0), pl.program_id(1)
+    k_refs, v_refs = refs[:group], refs[group:2 * group]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * group:]
+    pos = pos_ref[s]
+    first, n_live = _page_span(pos, bs, n_blk, window)
+    page0 = j * group
+    _start(j, m_ref, l_ref, acc_ref)
+
+    def fold(pages: int):
+        q = q_ref[0]                                       # (heads, hd)
+        prec = dot_precision(q.dtype)
+        scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[1], f32))
+        scs, vals = [], []
+        for n in range(pages):
+            sc = jax.lax.dot_general(
+                q, k_refs[n][0], (((1,), (1,)), ((), ())),  # (bs * kvh, hd)
+                preferred_element_type=f32, precision=prec) * scale
+            sc, v = _as_read(
+                sc, v_refs[n][0],
+                (page0 + n >= first) & (page0 + n < n_live),
+                ids_ref[s * n_blk + page0 + n] >= 0)
+            scs.append(sc)
+            vals.append(v)
+        sc, v = _side_by_side(scs, vals)
+        # Column c is row c % (bs * kvh) of page page0 + c // (bs * kvh),
+        # and row r of a page is position r // kvh of KV head r % kvh
+        # (bs * kvh is a multiple of kvh).
+        col = jax.lax.broadcasted_iota(i32, sc.shape, 1)
+        row = jax.lax.broadcasted_iota(i32, sc.shape, 0)
+        kv_pos = page0 * bs + jax.lax.div(col, i32(kvh))
+        mask = (jax.lax.rem(col, i32(kvh)) == jax.lax.div(row, i32(g))) \
+            & (kv_pos <= pos)
+        if window:
+            mask &= (pos - kv_pos) < window
+        _fold(sc, mask, v, m_ref, l_ref, acc_ref, prec)
+
+    _fold_live_pages(fold, page0, group, first, n_live)
+    _finish(j, n_blk // group, o_ref, l_ref, acc_ref)
 
 
 def _pallas_paged(q, pool_k, pool_v, table, pos, window: int,
@@ -208,41 +350,31 @@ def _pallas_paged(q, pool_k, pool_v, table, pos, window: int,
     slots, heads, hd = q.shape
     nb, bs, kvh, _ = pool_k.shape
     n_blk = table.shape[1]
-    g = heads // kvh
-
-    def page_index(s, j, table_ref, pos_ref):
-        # The page the table names for the nearest live step: steps
-        # before the window and past the frontier repeat a live page's
-        # index, so nothing is fetched for them.
-        first, n_live = _page_span(pos_ref[s], bs, n_blk, window)
-        jj = jnp.clip(j, first, jnp.maximum(n_live - 1, 0))
-        return jnp.maximum(table_ref[s * n_blk + jj], 0), 0, 0
-
-    def slot_index(s, j, table_ref, pos_ref):
-        return s, 0, 0
-
+    grid = read_grid(slots, n_blk, pool_k, pool_v)
+    group = n_blk // grid[1]
     vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    pages = [vmem((1, bs * kvh, hd), index)
+             for index in _page_indices(group, n_blk)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(slots, n_blk),
-        in_specs=[vmem((1, heads, hd), slot_index),
-                  vmem((1, bs * kvh, hd), page_index),
-                  vmem((1, bs * kvh, hd), page_index)],
-        out_specs=vmem((1, heads, hd), slot_index),
+        grid=grid,
+        in_specs=[vmem((1, heads, hd), _slot_index)] + pages + pages,
+        out_specs=vmem((1, heads, hd), _slot_index),
         scratch_shapes=[pltpu.VMEM((heads, _STAT_LANES), jnp.float32),
                         pltpu.VMEM((heads, _STAT_LANES), jnp.float32),
                         pltpu.VMEM((heads, hd), jnp.float32)])
     return pl.pallas_call(
-        functools.partial(_kernel, bs=bs, kvh=kvh, g=g, n_blk=n_blk,
-                          window=window),
+        functools.partial(_kernel, bs=bs, kvh=kvh, g=heads // kvh,
+                          n_blk=n_blk, window=window, group=group),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=KERNEL_NAMES[0],
-    )(table.reshape(-1), pos, q,
-      pool_k.reshape(nb, bs * kvh, hd), pool_v.reshape(nb, bs * kvh, hd))
+    )(_page_ids(table, pos, bs, window, group), pos, q,
+      *[pool_k.reshape(nb, bs * kvh, hd)] * group,
+      *[pool_v.reshape(nb, bs * kvh, hd)] * group)
 
 
 def paged_decode_attention(q, pool_k, pool_v, table, pos, *,
@@ -319,55 +451,39 @@ def paged_decode_attention(q, pool_k, pool_v, table, pos, *,
 # ---------------------------------------------------------------------------
 
 
-def _latent_kernel(table_ref, pos_ref, q_ref, c_ref, o_ref, m_ref, l_ref,
-                   acc_ref, *, bs: int, n_blk: int, v_width: int,
-                   scale: float):
+def _latent_kernel(ids_ref, pos_ref, q_ref, *refs, bs: int, n_blk: int,
+                   v_width: int, scale: float, group: int):
     from jax.experimental import pallas as pl
 
     f32, i32 = jnp.float32, jnp.int32
     s, j = pl.program_id(0), pl.program_id(1)
+    c_refs = refs[:group]
+    o_ref, m_ref, l_ref, acc_ref = refs[group:]
     pos = pos_ref[s]
     _, n_live = _page_span(pos, bs, n_blk, 0)
+    page0 = j * group
+    _start(j, m_ref, l_ref, acc_ref)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full(m_ref.shape, NEG_BIG, f32)
-        l_ref[...] = jnp.zeros(l_ref.shape, f32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
-
-    @pl.when(j < n_live)
-    def _page():
-        q, c = q_ref[0], c_ref[0]                # (heads, w), (bs, w)
+    def fold(pages: int):
+        q = q_ref[0]                                       # (heads, w)
         prec = dot_precision(q.dtype)
-        sc = jax.lax.dot_general(
-            q, c, (((1,), (1,)), ((), ())),
-            preferred_element_type=f32, precision=prec) * scale
-        kv_pos = j * bs + jax.lax.broadcasted_iota(i32, sc.shape, 1)
-        mask = kv_pos <= pos
-        # An unmapped page inside the frontier reads as zeros, as the
-        # K/V kernel has it.
-        mapped = table_ref[s * n_blk + j] >= 0
-        sc = jnp.where(mapped, sc, 0.0)
-        sc = jnp.where(mask, sc, NEG_BIG)
-        m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(sc - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        # The value of a row is the head of the row itself.
-        pv = jax.lax.dot_general(
-            p.astype(c.dtype), c[:, :v_width], (((1,), (0,)), ((), ())),
-            preferred_element_type=f32, precision=prec)
-        acc_ref[...] = acc_ref[...] * corr + jnp.where(mapped, pv, 0.0)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        scs, vals = [], []
+        for n in range(pages):
+            c = c_refs[n][0]                               # (bs, w)
+            sc = jax.lax.dot_general(
+                q, c, (((1,), (1,)), ((), ())),
+                preferred_element_type=f32, precision=prec) * scale
+            # The value of a row is the head of the row itself.
+            sc, v = _as_read(sc, c[:, :v_width], page0 + n < n_live,
+                             ids_ref[s * n_blk + page0 + n] >= 0)
+            scs.append(sc)
+            vals.append(v)
+        sc, v = _side_by_side(scs, vals)
+        kv_pos = page0 * bs + jax.lax.broadcasted_iota(i32, sc.shape, 1)
+        _fold(sc, kv_pos <= pos, v, m_ref, l_ref, acc_ref, prec)
 
-    @pl.when(j == n_blk - 1)
-    def _finish():
-        l = l_ref[:, :1]
-        safe = jnp.where(l > 0, l, 1.0)
-        o_ref[0] = jnp.where(l > 0, acc_ref[...] / safe,
-                             0.0).astype(o_ref.dtype)
+    _fold_live_pages(fold, page0, group, 0, n_live)
+    _finish(j, n_blk // group, o_ref, l_ref, acc_ref)
 
 
 def _pallas_latent(q, pool_c, table, pos, v_width: int, scale: float,
@@ -378,37 +494,30 @@ def _pallas_latent(q, pool_c, table, pos, v_width: int, scale: float,
     slots, heads, w = q.shape
     nb, bs = pool_c.shape[0], pool_c.shape[1]
     n_blk = table.shape[1]
-
-    def page_index(s, j, table_ref, pos_ref):
-        # Steps past the frontier repeat the last live page's index:
-        # nothing is fetched for them.
-        _, n_live = _page_span(pos_ref[s], bs, n_blk, 0)
-        jj = jnp.clip(j, 0, jnp.maximum(n_live - 1, 0))
-        return jnp.maximum(table_ref[s * n_blk + jj], 0), 0, 0
-
-    def slot_index(s, j, table_ref, pos_ref):
-        return s, 0, 0
-
+    grid = read_grid(slots, n_blk, pool_c)
+    group = n_blk // grid[1]
     vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(slots, n_blk),
-        in_specs=[vmem((1, heads, w), slot_index),
-                  vmem((1, bs, w), page_index)],
-        out_specs=vmem((1, heads, v_width), slot_index),
+        grid=grid,
+        in_specs=[vmem((1, heads, w), _slot_index)]
+        + [vmem((1, bs, w), index)
+           for index in _page_indices(group, n_blk)],
+        out_specs=vmem((1, heads, v_width), _slot_index),
         scratch_shapes=[pltpu.VMEM((heads, _STAT_LANES), jnp.float32),
                         pltpu.VMEM((heads, _STAT_LANES), jnp.float32),
                         pltpu.VMEM((heads, v_width), jnp.float32)])
     return pl.pallas_call(
         functools.partial(_latent_kernel, bs=bs, n_blk=n_blk,
-                          v_width=v_width, scale=scale),
+                          v_width=v_width, scale=scale, group=group),
         out_shape=jax.ShapeDtypeStruct((slots, heads, v_width), q.dtype),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=name,
-    )(table.reshape(-1), pos, q, pool_c.reshape(nb, bs, w))
+    )(_page_ids(table, pos, bs, 0, group), pos, q,
+      *[pool_c.reshape(nb, bs, w)] * group)
 
 
 def latent_rows_attention(q, rows, pos, *, v_width: int, scale: float,
@@ -518,13 +627,7 @@ def uses_index_kernel(q_i, pool_k) -> bool:
     return _index_eligible(q_i, pool_k) and _on_tpu()
 
 
-def _pages_a_step(n_blk: int) -> int:
-    """Pages one grid step of the scoring kernel takes: a page is a
-    small product, so several share a step's fixed cost."""
-    return next(g for g in (8, 4, 2, 1) if n_blk % g == 0)
-
-
-def _index_kernel(table_ref, pos_ref, q_ref, w_ref, *refs, bs: int,
+def _index_kernel(ids_ref, pos_ref, q_ref, w_ref, *refs, bs: int,
                   n_blk: int, group: int):
     from jax.experimental import pallas as pl
 
@@ -544,7 +647,7 @@ def _index_kernel(table_ref, pos_ref, q_ref, w_ref, *refs, bs: int,
                 preferred_element_type=f32, precision=prec)
             row = jnp.sum(jnp.maximum(sc, 0.0) * w, axis=0, keepdims=True)
             # An unmapped page inside the frontier scores as zero rows.
-            mapped = table_ref[s * n_blk + page] >= 0
+            mapped = ids_ref[s * n_blk + page] >= 0
             o_ref[0, 0, pl.ds(g, 1), :] = jnp.where(mapped, row, 0.0)
 
         @pl.when(page >= n_live)
@@ -559,27 +662,18 @@ def _pallas_index(q_i, w, pool_k, table, pos, interpret: bool):
     slots, heads, hd = q_i.shape
     nb, bs = pool_k.shape[0], pool_k.shape[1]
     n_blk = table.shape[1]
-    group = _pages_a_step(n_blk)
-
-    def page_index(g):
-        def index(s, j, table_ref, pos_ref):
-            # Steps past the frontier repeat the last live page's index:
-            # nothing is fetched for them.
-            _, n_live = _page_span(pos_ref[s], bs, n_blk, 0)
-            jj = jnp.clip(j * group + g, 0, jnp.maximum(n_live - 1, 0))
-            return jnp.maximum(table_ref[s * n_blk + jj], 0), 0, 0
-        return index
-
-    slot_index = lambda s, j, table_ref, pos_ref: (s, 0, 0)
+    grid = read_grid(slots, n_blk, pool_k)
+    group = n_blk // grid[1]
     vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(slots, n_blk // group),
-        in_specs=[vmem((1, heads, hd), slot_index),
-                  vmem((1, heads, bs), slot_index)]
-        + [vmem((1, bs, hd), page_index(g)) for g in range(group)],
+        grid=grid,
+        in_specs=[vmem((1, heads, hd), _slot_index),
+                  vmem((1, heads, bs), _slot_index)]
+        + [vmem((1, bs, hd), index)
+           for index in _page_indices(group, n_blk)],
         out_specs=vmem((1, 1, group, bs),
-                       lambda s, j, table_ref, pos_ref: (s, j, 0, 0)))
+                       lambda s, j, ids_ref, pos_ref: (s, j, 0, 0)))
     pages = pool_k.reshape(nb, bs, hd)
     out = pl.pallas_call(
         functools.partial(_index_kernel, bs=bs, n_blk=n_blk, group=group),
@@ -590,7 +684,7 @@ def _pallas_index(q_i, w, pool_k, table, pos, interpret: bool):
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=KERNEL_NAMES[2],
-    )(table.reshape(-1), pos, q_i,
+    )(_page_ids(table, pos, bs, 0, group), pos, q_i,
       jnp.broadcast_to(w.astype(jnp.float32)[:, :, None],
                        (slots, heads, bs)), *([pages] * group))
     return out.reshape(slots, n_blk * bs)

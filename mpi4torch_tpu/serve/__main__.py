@@ -67,7 +67,7 @@ MIRRORED_SERVE_COUNTERS = (
     "prefix_hits", "prefix_misses", "prefill_tokens", "cow_copies",
     "preempted", "blocks_in_use", "blocks_free", "blocks_cached",
     "install_writes", "decode_pages_live", "decode_pages_read",
-    "decode_select_syncs", "moe_zero_pairs", "moe_live_pairs",
+    "decode_grid_steps", "decode_select_syncs", "moe_zero_pairs", "moe_live_pairs",
     "dsa_rows_live", "dsa_rows_read", "dsa_rows_scored",
     "decode_uploads", "step_compiles",
 )

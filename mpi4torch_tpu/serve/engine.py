@@ -51,6 +51,7 @@ Two execution modes behind one engine:
 
 from __future__ import annotations
 
+import math
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -470,8 +471,10 @@ class Engine:
             self._chunk = (self.serve_cfg.prefill_chunk
                            if self._exact_kv else None)
             # Which read the decode step compiles, asked of the
-            # function that decides it: what decode_pages_read counts.
-            self._kernel_read = self._reads_by_kernel(cache, param_dtype)
+            # functions that decide it: what decode_pages_read and
+            # decode_grid_steps count.
+            self._grid_steps = self._kernel_grid_steps(cache, param_dtype)
+            self._kernel_read = self._grid_steps > 0
         else:
             cache = _kv.init_kv_cache_tp(cfg, slots, self._size,
                                          self._dtype, poison=True)
@@ -571,34 +574,42 @@ class Engine:
                 f"n_layers={len(specs)}")
         return shards
 
-    def _reads_by_kernel(self, cache, dtype) -> bool:
-        """Whether every layer's decode read of this (unstacked) pool
-        is the paged kernel's, each asked of the dispatch's own
-        predicate with the query that layer will bring."""
+    def _kernel_grid_steps(self, cache, dtype) -> int:
+        """The grid steps one call of the decode step's paged read walks
+        over this (unstacked) pool (``read_grid``, the kernels' own
+        ``grid=``; the most of the kinds of layer), 0 unless every
+        layer's read is the paged kernel's: each kind of layer asked
+        once, of the dispatch's own predicate with the query that layer
+        will bring."""
         cfg, slots = self.cfg, self.serve_cfg.slots
         like = lambda *shape: jax.ShapeDtypeStruct(shape, dtype)
-        asked = {}                    # each kind of layer once
+        steps = {}                    # each kind of layer once
         for spec, entry in zip(cfg.layer_specs, cache):
-            if spec.mixer in asked:
+            if spec.mixer in steps:
                 continue
             index = getattr(spec.mixer, "index", None)
             if "ik" in entry:
                 # A scoring layer visits pages where it scores (the
                 # read of the selected rows visits rows, not pages).
-                asked[spec.mixer] = _paged_attn.uses_index_kernel(
+                staged = (entry["ik"],)
+                by_kernel = _paged_attn.uses_index_kernel(
                     like(slots, index.n_heads, index.head_dim),
                     entry["ik"])
             elif index is not None:
                 continue
             elif "c" in entry:
-                asked[spec.mixer] = _paged_attn.uses_kernel(
+                staged = (entry["c"],)
+                by_kernel = _paged_attn.uses_kernel(
                     like(slots, spec.mixer.n_heads, entry["c"].shape[-1]),
                     entry["c"], spec.mixer.kv_rank)
             else:
-                asked[spec.mixer] = _paged_attn.uses_kernel(
+                staged = (entry["k"], entry["v"])
+                by_kernel = _paged_attn.uses_kernel(
                     like(slots, cfg.n_heads // self._size,
                          cfg.d_model // cfg.n_heads), entry["k"])
-        return all(asked.values())
+            steps[spec.mixer] = by_kernel and math.prod(
+                _paged_attn.read_grid(slots, self._blocks_per_seq, *staged))
+        return max(steps.values(), default=0) if all(steps.values()) else 0
 
     # ------------------------------------------------------------- traced
 
@@ -1010,16 +1021,19 @@ class Engine:
         self.stats.count("install_writes")
 
     def _count_pages(self, active: List[int]) -> None:
-        """The step's two page counts: the pages its live slots hold up
-        to their frontier, and the pages its attention visits by the
-        read the engine compiled (the same pages through the kernel;
-        every slot's whole table row through the gather)."""
+        """The step's page counts: the pages its live slots hold up to
+        their frontier, the pages its attention visits by the read the
+        engine compiled (the same pages through the kernel; every
+        slot's whole table row through the gather), and the grid steps
+        one call of that kernel walks for them (every slot's, live or
+        free: the grid is the program's)."""
         bs = self.serve_cfg.block_size
         held = sum(int(self._pos[j]) // bs + 1 for j in active)
         self.stats.count("decode_pages_live", held)
         self.stats.count("decode_pages_read",
                          held if self._kernel_read
                          else len(active) * self._blocks_per_seq)
+        self.stats.count("decode_grid_steps", self._grid_steps)
 
     def _gather_past(self, j: int, n: int):
         """Exact-length past K/V (positions ``0..n-1``) for slot ``j``,

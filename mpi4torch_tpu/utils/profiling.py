@@ -54,6 +54,7 @@ _STEP_COUNTS = (("admitted", "admitted"),
                 ("install_writes", "install_writes"),
                 ("decode_pages_live", "decode_pages_live"),
                 ("decode_pages_read", "decode_pages_read"),
+                ("decode_grid_steps", "decode_grid_steps"),
                 ("decode_select_syncs", "decode_select_syncs"),
                 ("moe_zero_pairs", "moe_zero_pairs"),
                 ("moe_live_pairs", "moe_live_pairs"),
@@ -215,6 +216,12 @@ class ServeStats:
                  # the kernel, slots x max_seq / block_size through the
                  # gather.  Their ratio says which read ran.
                  "decode_pages_live", "decode_pages_read",
+                 # ISSUE 40: grid steps one call of those steps' paged
+                 # read walked (``ops.paged_attention.read_grid``: slots
+                 # x pages of a table row / pages a grid step; 0 through
+                 # the gather).  Over ``decode_pages_live``: what a live
+                 # page pays of the grid's fixed cost.
+                 "decode_grid_steps",
                  # ISSUE 31: round trips to the device that the decode
                  # steps' ``decode.select`` spent choosing tokens (one
                  # per live slot while the host chose them from the
@@ -445,7 +452,8 @@ def serve_step_log() -> list:
     per ``Engine.step()`` call of every engine, ``{"engine", "t0_ns",
     "t1_ns", "spans": [(name, t0_ns, t1_ns, rid), ...], "admitted",
     "prefill_tokens", "install_writes", "decode_pages_live",
-    "decode_pages_read", "decode_select_syncs", "moe_zero_pairs",
+    "decode_pages_read", "decode_grid_steps", "decode_select_syncs",
+    "moe_zero_pairs",
     "moe_live_pairs", "dsa_rows_live", "dsa_rows_read", "dsa_rows_scored",
     "decode_uploads", "step_compiles", "active"}`` on
     the ``time.perf_counter_ns()`` clock, the last :data:`STEP_LOG_CAP`

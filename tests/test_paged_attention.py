@@ -345,3 +345,260 @@ def test_latent_kernel_wants_whole_lanes():
     with pytest.raises(ValueError, match="v_width"):
         pa.paged_latent_attention(q, pool, table, pos, v_width=W + 1,
                                   scale=1.0)
+
+
+# ------------------------------------- several pages a grid step (ISSUE 40)
+
+# pages a grid step -> a table width that the rule gives it at these
+# page sizes (the widths are small: the staging budget never binds)
+WIDTHS = {1: 5, 2: 6, 4: 12, 8: 16}
+# A row of 16 pages sums four times the rows of ``a_case``'s.
+WIDE_TOL = 1e-5
+
+
+def grouped_case(read, group, dtype=jnp.float32, seed=0, window=0):
+    """Eight slots over a table of ``WIDTHS[group]`` pages, frontiers at
+    0, 1, ``group - 1``, ``group``, ``group + 1`` pages, at the table's
+    whole width, mid-table, and one inactive slot (``pos = -1``): groups
+    wholly dead, partly live and wholly live.  ``call(table, pos, pools)``
+    runs the read's kernel; ``oracle(...)`` its jnp path."""
+    bs, n_blk = block_size(dtype), WIDTHS[group]
+    rng = np.random.default_rng(seed)
+    pages = sorted({1, max(group - 1, 1), group, min(group + 1, n_blk),
+                    n_blk, (n_blk + 1) // 2})
+    pos = [0] + [p * bs - 1 - int(rng.integers(0, bs - 1)) for p in pages]
+    pos = np.array((pos + [n_blk * bs - 1] * 8)[:7] + [-1], np.int32)
+    slots, nb = len(pos), len(pos) * n_blk + 1
+    ids = [int(i) for i in rng.permutation(np.arange(1, nb))]
+    table = np.full((slots, n_blk), -1, np.int32)
+    for s in range(slots):
+        for j in range(pos[s] // bs + 1 if pos[s] >= 0 else 0):
+            table[s, j] = ids.pop()
+    mk = lambda *shape: jnp.asarray(rng.standard_normal(shape), dtype)
+    if read == "kv":
+        q, pools = mk(slots, 2 * KVH, HD), (mk(nb, bs, KVH, HD),
+                                            mk(nb, bs, KVH, HD))
+        run = lambda impl: lambda table, pos, pools=pools: \
+            pa.paged_decode_attention(q, *pools, table, pos, window=window,
+                                      impl=impl)
+    else:
+        q, pools = mk(slots, 8, W), (mk(nb, bs, 1, W),)
+        run = lambda impl: lambda table, pos, pools=pools: \
+            pa.paged_latent_attention(q, *pools, table, pos, v_width=V,
+                                      scale=0.17, impl=impl)
+    assert pa.read_grid(slots, n_blk, *pools) == (slots, n_blk // group)
+    return run("pallas"), run("jnp"), table, pos, pools
+
+
+def same_fold(got, want, tol=WIDE_TOL):
+    """A step's pages enter the softmax in one update where the one-page
+    grid makes one update a page: the same sums in another order."""
+    np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
+
+
+def one_page_fold(call, table, pos, pools=None):
+    """The same read through a grid of ONE page a step: a table row one
+    unmapped column wider is odd, so the rule gives it one page."""
+    wide = np.concatenate(
+        [table, np.full((table.shape[0], 1 + table.shape[1] % 2), -1,
+                        np.int32)], axis=1)
+    assert wide.shape[1] % 2 == 1
+    return call(wide, pos) if pools is None else call(wide, pos, pools)
+
+
+@pytest.mark.parametrize("group", [1, 2, 4, 8])
+@pytest.mark.parametrize("read", ["kv", "latent"])
+class TestSeveralPagesAGridStep:
+    def test_equals_the_one_page_fold_and_the_oracle(self, read, group):
+        """(a), (b): whatever part of a group is live, the step's one
+        update gives what a page at a time gives, to rounding (and,
+        where the rule gives one page a step, bit for bit: the one-page
+        fold is this kernel)."""
+        call, oracle, table, pos, _ = grouped_case(read, group)
+        got = f32(call(table, pos))
+        same_fold(got, f32(one_page_fold(call, table, pos)),
+                  WIDE_TOL if group > 1 else 0)
+        np.testing.assert_allclose(got, f32(oracle(table, pos)),
+                                   atol=WIDE_TOL, rtol=WIDE_TOL)
+        assert not got[-1].any()                  # the inactive slot
+
+    def test_bfloat16_too(self, read, group):
+        call, oracle, table, pos, _ = grouped_case(read, group,
+                                                   jnp.bfloat16, seed=1)
+        got = f32(call(table, pos))
+        same_fold(got, f32(one_page_fold(call, table, pos)), 2e-2)
+        np.testing.assert_allclose(got, f32(oracle(table, pos)),
+                                   atol=2e-2, rtol=2e-2)
+
+    def test_an_unmapped_page_inside_a_live_group_reads_as_zeros(
+            self, read, group):
+        """(c): whatever the clamped look-up fetched (the pool's page 0,
+        NaN here) is discarded."""
+        call, oracle, table, pos, pools = grouped_case(read, group, seed=2)
+        bs = pools[0].shape[1]
+        table = table.copy()
+        for s in np.flatnonzero(pos // bs >= 1):
+            table[s, (pos[s] // bs) // 2] = -1   # one hole a slot
+        pools = tuple(p.at[0].set(jnp.nan) for p in pools)
+        got = f32(call(table, pos, pools))
+        assert np.isfinite(got).all()
+        same_fold(got, f32(one_page_fold(call, table, pos, pools)))
+        np.testing.assert_allclose(got, f32(oracle(table, pos, pools)),
+                                   atol=WIDE_TOL, rtol=WIDE_TOL)
+
+    def test_no_operand_reads_a_dead_or_a_foreign_page(self, read, group):
+        """(e): NaN in every page past a frontier (the table names real
+        pages there too) and, slot by slot, in every page of the other
+        slots: each operand of a group either folds a live page of its
+        own slot or folds nothing."""
+        call, _, table, pos, pools = grouped_case(read, group, seed=3)
+        bs, n_blk = pools[0].shape[1], table.shape[1]
+        clean = f32(call(table, pos))
+        # Past the frontiers the table names pages of their own.
+        grow = lambda p: jnp.concatenate(
+            [p, jnp.full((table.size,) + p.shape[1:], jnp.nan, p.dtype)])
+        dead = iter(range(pools[0].shape[0],
+                          pools[0].shape[0] + table.size))
+        full = table.copy()
+        for s in range(table.shape[0]):
+            for j in range(n_blk):
+                if full[s, j] < 0:
+                    full[s, j] = next(dead)
+        got = f32(call(full, pos, tuple(grow(p) for p in pools)))
+        np.testing.assert_array_equal(got, clean)
+        held = {s: table[s, :pos[s] // bs + 1] for s in range(len(pos))
+                if pos[s] >= 0}
+        for s, mine in held.items():
+            keep = np.zeros(pools[0].shape[0], bool)
+            keep[mine] = True
+            alone = tuple(jnp.where(keep[:, None, None, None], p, jnp.nan)
+                          for p in pools)
+            np.testing.assert_array_equal(
+                f32(call(table, pos, alone))[s], clean[s])
+
+
+@pytest.mark.parametrize("group", [1, 2, 4, 8])
+def test_a_window_that_opens_inside_a_group(group):
+    """(d): the first attended page of a slot lies in the middle of a
+    group, pages before it are dead (NaN here) and the rest of the
+    group is live: equal to the one-page fold and to the gather path."""
+    bs, window = block_size(jnp.float32), 2 * block_size(jnp.float32) + 3
+    call, oracle, table, pos, pools = grouped_case("kv", group, seed=4,
+                                                   window=window)
+    got = f32(call(table, pos))
+    same_fold(got, f32(one_page_fold(call, table, pos)))
+    np.testing.assert_allclose(got, f32(oracle(table, pos)),
+                               atol=WIDE_TOL, rtol=WIDE_TOL)
+    behind = {int(table[s, j]) for s in range(len(pos)) if pos[s] >= 0
+              for j in range((pos[s] - window + 1) // bs)}
+    ahead = {int(table[s, j]) for s in range(len(pos)) if pos[s] >= 0
+             for j in range(max(pos[s] - window + 1, 0) // bs,
+                            pos[s] // bs + 1)}
+    if group > 1:
+        firsts = {(max(p - window + 1, 0) // bs) % group
+                  for p in pos if p >= 0}
+        assert firsts - {0}, "no window opens inside a group"
+    poison = np.zeros(pools[0].shape[0], bool)
+    poison[sorted(behind - ahead)] = True
+    assert poison.any()
+    nan = tuple(jnp.where(poison[:, None, None, None], jnp.nan, p)
+                for p in pools)
+    np.testing.assert_array_equal(f32(call(table, pos, nan)), got)
+
+
+@pytest.mark.parametrize("n_named", [0, 1, 513, 2048])
+def test_sparse_read_over_four_chunks_equals_its_oracle(n_named):
+    """(f): the read of the selected rows hands the latent kernel four
+    chunks of 512 gathered rows a slot, ``n_named - 1`` as the position;
+    the rule gives it all four in one grid step."""
+    rng = np.random.default_rng(5)
+    slots, k, bs, n_blk = 2, 2048, 128, 20
+    mk = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    q, pool = mk(slots, 4, W), mk(slots * n_blk, bs, 1, W)
+    table = rng.permutation(slots * n_blk).astype(np.int32).reshape(
+        slots, n_blk)
+    rows = np.full((slots, k), -1, np.int32)
+    for s in range(slots):
+        rows[s, :n_named] = rng.permutation(n_blk * bs)[:n_named]
+    chunk = pa._sparse_chunk(q, pool, k, V)
+    assert chunk == 512 and pa.read_grid(
+        slots, k // chunk,
+        jax.ShapeDtypeStruct((1, chunk, 1, W), q.dtype)) == (slots, 1)
+    got = pa.paged_sparse_latent_attention(q, pool, table, rows, v_width=V,
+                                           scale=0.17, impl="pallas")
+    want = pa.latent_rows_attention(
+        q, pa.sparse_rows_gather(pool, table, rows),
+        jnp.full((slots,), n_named - 1, jnp.int32), v_width=V, scale=0.17)
+    np.testing.assert_allclose(f32(got), f32(want), atol=WIDE_TOL, rtol=WIDE_TOL)
+    if not n_named:
+        assert not f32(got).any()
+
+
+class TestTheRuleForPagesAStep:
+    """``read_grid`` at the four serving cells' shapes: the pages a grid
+    step takes, and that what it stages (each page double-buffered)
+    stays within the budget a single page is held to."""
+    like = staticmethod(lambda *shape: jax.ShapeDtypeStruct(shape,
+                                                            jnp.bfloat16))
+
+    @pytest.mark.parametrize("cell,slots,n_blk,pools,group", [
+        ("serve_latent_4k", 32, 64, [(2048, 128, 1, 640)], 8),
+        ("serve_scmoe_1k", 32, 32, [(1024, 128, 1, 640)], 8),
+        ("serve_chat", 16, 20, [(320, 128, 8, 128)] * 2, 4),
+        ("serve_dsa_16k scoring", 16, 136, [(2176, 128, 1, 128)], 8),
+        ("serve_dsa_16k selected rows", 16, 4, [(64, 512, 1, 640)], 4),
+    ])
+    def test_the_cells_shapes(self, cell, slots, n_blk, pools, group):
+        from mpi4torch_tpu.ops.flash import _KV_VMEM_BUDGET
+        pools = [self.like(*p) for p in pools]
+        assert pa.read_grid(slots, n_blk, *pools) \
+            == (slots, n_blk // group), cell
+        page = sum(int(np.prod(p.shape[1:])) * 2 for p in pools)
+        assert 2 * group * page <= _KV_VMEM_BUDGET
+
+    def test_the_budget_binds_before_the_divisors_do(self):
+        # a page pair of 2 MB: two fit double-buffered, four do not
+        pool = self.like(64, 512, 8, 128)
+        assert pa.read_grid(4, 8, pool, pool) == (4, 4)
+        # one alone is what _eligible already admits
+        big = self.like(64, 1024, 8, 128)
+        assert pa._eligible(self.like(4, 16, 128), big)
+        assert pa.read_grid(4, 8, big, big) == (4, 8)
+
+    @pytest.mark.parametrize("n_blk,group", [(1, 1), (7, 1), (6, 2),
+                                             (12, 4), (20, 4), (24, 8)])
+    def test_the_largest_divisor_of_the_tables_width(self, n_blk, group):
+        assert pa.read_grid(3, n_blk, self.like(9, 16, 1, 128)) \
+            == (3, n_blk // group)
+
+
+@pytest.mark.parametrize("window", [0, 21])
+@pytest.mark.parametrize("group", [1, 2, 4, 8])
+def test_an_operand_outside_the_span_repeats_a_live_page_of_its_own(
+        group, window):
+    """What each operand of each grid step fetches (``_page_ids``): its
+    own table entry inside the slot's span (an unmapped ``-1`` stays);
+    outside it the nearest live page of the same operand, so that a
+    dead step fetches nothing it will not fold; the pool's page 0 where
+    the span holds no page of that operand."""
+    bs, n_blk, rng = 8, WIDTHS[group], np.random.default_rng(group)
+    pos = np.array([-1, 0, bs - 1, bs, n_blk * bs - 1, n_blk * bs + 9]
+                   + list(rng.integers(0, n_blk * bs, 6)), np.int32)
+    table = rng.permutation(np.arange(1, 1 + len(pos) * n_blk)).astype(
+        np.int32).reshape(len(pos), n_blk)
+    table[3, 0] = -1                               # a hole inside a span
+    ids = np.asarray(pa._page_ids(jnp.asarray(table), jnp.asarray(pos), bs,
+                                  window, group)).reshape(table.shape)
+    for s, p in enumerate(pos):
+        n_live = min(p // bs + 1, n_blk) if p >= 0 else 0
+        first = max(p - window + 1, 0) // bs if window and p >= 0 else 0
+        for page in range(n_blk):
+            own = [q for q in range(first, n_live)
+                   if q % group == page % group]
+            if first <= page < n_live:
+                want = table[s, page]
+            elif own:
+                want = table[s, min(own, key=lambda q: abs(q - page))]
+            else:
+                want = 0
+            assert ids[s, page] == want, (s, p, page)

@@ -1592,6 +1592,8 @@ class TestPagedStepInPlace:
             == [2 * n_blk, 2 * n_blk, 1 * n_blk]
         assert eng.stats.counters["decode_pages_live"] == sum(want_live)
         assert eng.stats.counters["decode_pages_read"] == 5 * n_blk
+        # and no kernel walks a grid
+        assert [r["decode_grid_steps"] for r in recs] == [0, 0, 0]
         # an engine whose step compiled the kernel visits what is live
         eng2 = self._engine(False)
         monkeypatch.setattr(eng2, "_kernel_read", True)
@@ -1601,12 +1603,36 @@ class TestPagedStepInPlace:
         assert eng2.stats.counters["decode_pages_read"] \
             == eng2.stats.counters["decode_pages_live"] == sum(want_live)
 
+    def test_grid_steps_are_the_kernels_own_grid(self, monkeypatch):
+        """An engine whose read is the kernel's counts, a decode step,
+        the steps of the grid that kernel walks (every slot's, live or
+        free), asked of ``read_grid`` with the pool it made."""
+        from mpi4torch_tpu.ops import paged_attention as pa
+        from mpi4torch_tpu.utils import profiling
+        monkeypatch.setattr(pa, "uses_kernel", lambda q, pool_k: True)
+        eng = self._engine(False)
+        monkeypatch.undo()            # count as the kernel, read as here
+        n_blk = CFG.max_seq // self.BS
+        entry = eng._cache[0]
+        grid = pa.read_grid(self.SLOTS, n_blk, entry["k"], entry["v"])
+        assert grid[0] == self.SLOTS and n_blk % grid[1] == 0
+        assert eng._kernel_read and eng._grid_steps == grid[0] * grid[1]
+        eng.submit(np.arange(1, 4), max_new=4)
+        log0 = len(profiling.serve_step_log())
+        eng.run()
+        recs = profiling.serve_step_log()[log0:]
+        assert [r["decode_grid_steps"] for r in recs] \
+            == [eng._grid_steps] * 3
+        assert eng.stats.counters["decode_grid_steps"] \
+            == 3 * eng._grid_steps
+
     def test_dense_engine_counts_no_pages(self):
         eng = serve.Engine(CFG, _params(CFG), serve.ServeConfig(slots=2))
         eng.submit(PROMPTS[0], max_new=3)
         eng.run()
         assert eng.stats.counters["decode_pages_live"] == 0
         assert eng.stats.counters["decode_pages_read"] == 0
+        assert eng.stats.counters["decode_grid_steps"] == 0
 
     def test_engine_asks_the_kernels_own_predicate(self, monkeypatch):
         from mpi4torch_tpu.ops import paged_attention as pa

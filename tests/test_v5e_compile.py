@@ -143,6 +143,25 @@ def _made_with_shape(text: str, dims: str) -> list:
         r"= \w+\[" + re.escape(dims) + r"\]\S* ([\w\-]+)\(", text)]
 
 
+def _kernel_grids(fn, *args) -> dict:
+    """``{kernel name: (its grid, its operands)}`` of the Pallas calls
+    ``fn`` makes of these arguments (traced, not compiled)."""
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found[eqn.params["name"]] = (
+                    tuple(eqn.params["grid_mapping"].grid), len(eqn.invars))
+            for sub in eqn.params.values():
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
 @pytest.mark.parametrize("dtype,window", [
     (jnp.bfloat16, 0), (jnp.bfloat16, 300), (jnp.float32, 0)],
     ids=["bf16", "bf16-window", "f32"])
@@ -157,12 +176,16 @@ def test_paged_attention_compiles_at_the_serving_cells_shapes(
         shape, dt, sharding=one_v5e_chip)
     q, pool = like((16, 16, 128)), like((320, 128, 8, 128))
     assert pa.uses_kernel(q, pool)
+    read = lambda *a: pa.paged_decode_attention(*a, window=window)
+    args = (q, pool, pool, like((16, 20), jnp.int32), like((16,), jnp.int32))
     with jax.enable_x64(False):
-        text = jax.jit(lambda *a: pa.paged_decode_attention(
-            *a, window=window)).lower(
-                q, pool, pool, like((16, 20), jnp.int32),
-                like((16,), jnp.int32)).compile().as_text()
+        text = jax.jit(read).lower(*args).compile().as_text()
+        grids = _kernel_grids(read, *args)
     assert pa.KERNEL_NAMES[0] in text and "tpu_custom_call" in text
+    # Four page pairs a grid step (in float32, 1 MB a pair, they fill
+    # the staging budget to the byte), each an operand of its own beside
+    # the page ids, the positions and the query.
+    assert grids == {pa.KERNEL_NAMES[0]: ((16, 20 // 4), 3 + 2 * 4)}
     assert set(_made_with_shape(text, "320,128,8,128")) == {"parameter"}
     assert set(_made_with_shape(text, "320,1024,128")) == {"bitcast"}
 
@@ -206,6 +229,11 @@ def test_paged_decode_step_compiles_in_place(one_v5e_chip, as_on_tpu):
                 like({k: a[None] for k, a in eng._host_state().items()},
                      state))
         compiled = jax.jit(step, donate_argnums=(1,)).lower(*args).compile()
+        grids = _kernel_grids(step, *args)
+    # A row of 4 pages of 64 KB pairs: all four in one grid step, and
+    # the engine counts the steps of that grid.
+    assert grids == {pa.KERNEL_NAMES[0]: ((slots, 1), 3 + 2 * 4)}
+    assert eng._grid_steps == slots
     text = compiled.as_text()
     leaves = 2 * cfg.n_layers
     leaf_bytes = slots * cfg.max_seq * 2 * 128 * 2
@@ -237,12 +265,15 @@ def test_latent_read_compiles_at_openpangus_shapes(one_v5e_chip, as_on_tpu):
         shape, dt, sharding=one_v5e_chip)
     q, pool = like((32, 128, 640)), like((2048, 128, 1, 640))
     assert pa.uses_kernel(q, pool, 512)
+    read = lambda *a: pa.paged_latent_attention(*a, v_width=512,
+                                                scale=192 ** -0.5)
+    args = (q, pool, like((32, 64), jnp.int32), like((32,), jnp.int32))
     with jax.enable_x64(False):
-        text = jax.jit(lambda *a: pa.paged_latent_attention(
-            *a, v_width=512, scale=192 ** -0.5)).lower(
-                q, pool, like((32, 64), jnp.int32),
-                like((32,), jnp.int32)).compile().as_text()
+        text = jax.jit(read).lower(*args).compile().as_text()
+        grids = _kernel_grids(read, *args)
     assert pa.KERNEL_NAMES[1] in text and "tpu_custom_call" in text
+    # Eight pages a grid step, under the kernel's old name.
+    assert grids == {pa.KERNEL_NAMES[1]: ((32, 64 // 8), 3 + 8)}
     assert set(_made_with_shape(text, "2048,128,1,640")) == {"parameter"}
     assert set(_made_with_shape(text, "2048,128,640")) == {"bitcast"}
     assert _made_with_shape(text, "32,128,512") != []
@@ -360,12 +391,14 @@ def test_latent_read_compiles_at_longcats_shapes(one_v5e_chip, as_on_tpu):
         shape, dt, sharding=one_v5e_chip)
     q, pool = like((32, 64, 640)), like((1024, 128, 1, 640))
     assert pa.uses_kernel(q, pool, 512)
+    read = lambda *a: pa.paged_latent_attention(*a, v_width=512,
+                                                scale=192 ** -0.5)
+    args = (q, pool, like((32, 32), jnp.int32), like((32,), jnp.int32))
     with jax.enable_x64(False):
-        text = jax.jit(lambda *a: pa.paged_latent_attention(
-            *a, v_width=512, scale=192 ** -0.5)).lower(
-                q, pool, like((32, 32), jnp.int32),
-                like((32,), jnp.int32)).compile().as_text()
+        text = jax.jit(read).lower(*args).compile().as_text()
+        grids = _kernel_grids(read, *args)
     assert pa.KERNEL_NAMES[1] in text and "tpu_custom_call" in text
+    assert grids == {pa.KERNEL_NAMES[1]: ((32, 32 // 8), 3 + 8)}
     assert set(_made_with_shape(text, "1024,128,1,640")) == {"parameter"}
     assert _made_with_shape(text, "32,64,512") != []
 
@@ -426,16 +459,23 @@ def test_glms_two_reads_compile_at_their_cells_shapes(one_v5e_chip,
         text = jax.jit(pa.paged_index_scores).lower(
             q_i, like((slots, 32), F32), keys, table, pos
         ).compile().as_text()
+        grids = _kernel_grids(pa.paged_index_scores, q_i,
+                              like((slots, 32), F32), keys, table, pos)
     assert pa.KERNEL_NAMES[2] in text and "tpu_custom_call" in text
+    # Eight pages a grid step, as before the rule was shared.
+    assert grids == {pa.KERNEL_NAMES[2]: ((slots, n_blk // 8), 4 + 8)}
     assert set(_made_with_shape(text, "2176,128,1,128")) == {"parameter"}
     q, pool = like((slots, 64, 640)), like((nb, 128, 1, 640))
     assert pa._sparse_chunk(q, pool, 2048, 512) == 512
+    read = lambda *a: pa.paged_sparse_latent_attention(
+        *a, v_width=512, scale=256 ** -0.5)
+    args = (q, pool, table, like((slots, 2048), jnp.int32))
     with jax.enable_x64(False):
-        text = jax.jit(lambda *a: pa.paged_sparse_latent_attention(
-            *a, v_width=512, scale=256 ** -0.5)).lower(
-                q, pool, table, like((slots, 2048), jnp.int32)
-            ).compile().as_text()
+        text = jax.jit(read).lower(*args).compile().as_text()
+        grids = _kernel_grids(read, *args)
     assert pa.KERNEL_NAMES[3] in text and pa.SPARSE_GATHER_SCOPE in text
+    # A slot's four chunks of 512 gathered rows in ONE grid step.
+    assert grids == {pa.KERNEL_NAMES[3]: ((slots, 1), 3 + 4)}
     assert set(_made_with_shape(text, "2176,128,1,640")) == {"parameter"}
     # The selected rows exist once, as gathered: 16 x 2,048 of them.
     assert "fusion" in _made_with_shape(text, "32768,640")
