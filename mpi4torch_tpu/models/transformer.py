@@ -11,10 +11,12 @@ thread-SPMD runtime, inside ``run_spmd``, or in a user-managed 2D
 ``shard_map`` via ``comm_from_mesh`` (the intended TPU deployment).
 
 A configuration may state its stack as data (``TransformerConfig.layers``,
-one :class:`LayerSpec` a layer): Kimi Delta Attention or latent attention
-as the mixer, the held share of a top-k expert layer as the FFN
-(doc/layer_spec.md).  KDA runs on the training path only; latent
-attention and the expert share are served too (serve/kv.py).
+one :class:`LayerSpec` a layer): Kimi Delta Attention, latent attention
+or a Mamba-2 state-space mixer as the mixer, the held share of a top-k
+expert layer as the FFN, or a layer of one of the two parts alone
+(doc/layer_spec.md).  KDA runs on the training path only and Mamba-2 on
+the serving path only; latent attention and the expert share run on both
+(serve/kv.py).
 
 TPU-first shapes: all compute is batched matmul/einsum (MXU), parameters
 and activations stay in the caller's dtype (bfloat16-ready), and the
@@ -35,6 +37,7 @@ from ..constants import MPI_SUM
 from ..ops.flash import flash_attention, flash_block_attention, \
     merge_partials
 from ..ops.kda import kda_chunked
+from ..ops.ssd import CHUNK as _SSD_CHUNK, ssd_chunked, ssd_step
 from ..ops.paged_attention import index_scores
 from ..parallel.attention import ring_attention, \
     ulysses_attention, zigzag_ring_attention
@@ -60,6 +63,44 @@ class KDA:
     n_heads: int
     head_dim: int
     conv: int = 4
+
+
+@dataclass(frozen=True)
+class Mamba2:
+    """Mixer: a Mamba-2 state-space layer (ops/ssd.py).  ``n_heads``
+    heads of ``head_dim`` channels (``d_inner = n_heads * head_dim``),
+    each with a state of ``head_dim x d_state`` under one scalar decay;
+    ``n_groups`` groups of heads share their ``B`` and ``C``; a causal
+    depthwise convolution of ``conv`` taps with a bias on ``[x | B |
+    C]``; a sigmoid-linear gate and an rmsnorm over each group's
+    ``d_inner / n_groups`` channels before the output projection.
+    Between tokens a sequence keeps the state and the convolution's last
+    ``conv - 1`` inputs and nothing else.  Served only: the training
+    forward refuses the mixer by name (the scan's backward is not
+    written)."""
+    n_heads: int
+    head_dim: int
+    d_state: int
+    n_groups: int
+    conv: int = 4
+    chunk: int = _SSD_CHUNK
+
+    def __post_init__(self):
+        if self.n_groups < 1 or self.n_heads % self.n_groups:
+            raise ValueError(
+                f"n_heads={self.n_heads} must be a positive multiple of "
+                f"n_groups={self.n_groups}")
+        if self.conv < 2:
+            raise ValueError(f"Mamba2.conv={self.conv} must be >= 2")
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the convolution runs over: ``[x | B | C]``."""
+        return self.d_inner + 2 * self.n_groups * self.d_state
 
 
 @dataclass(frozen=True)
@@ -143,7 +184,8 @@ class MLA:
 class LayerSpec:
     """One layer of a stack whose layers differ.  ``mixer``: ``None`` is
     the configuration's own attention (``n_heads``, ``n_kv_heads``,
-    ``rope``, ``attn_window``), else a :class:`KDA` or an :class:`MLA`.
+    ``rope``, ``attn_window``), else a :class:`KDA`, an :class:`MLA` or
+    a :class:`Mamba2`.
     ``ffn``: ``None`` is the configuration's dense FFN (``ffn``,
     ``d_ff``), else the :class:`~mpi4torch_tpu.parallel.moe.Experts`
     share this rank holds.  ``post_norm`` puts a second norm on each
@@ -157,12 +199,20 @@ class LayerSpec:
     residual: the same layer, or a later one, so that the branch runs
     beside every mixer and FFN in between.  One branch is open at a
     time, and every branch is joined once (:class:`TransformerConfig`
-    refuses anything else)."""
-    mixer: Union[None, KDA, MLA] = None
+    refuses anything else).
+
+    ``only`` makes the layer ONE part: ``"mixer"`` is ``x + Mixer(N(x))``
+    and nothing else (leaves ``ln1`` and the mixer's; no ``ln2``, no
+    FFN), ``"ffn"`` is ``x + FFN(N(x))`` (leaves ``ln2`` and the FFN's;
+    no ``ln1``, no mixer: ``mixer`` stays ``None`` and names nothing).
+    One norm and one residual sum a layer; no second norm and no
+    shortcut."""
+    mixer: Union[None, KDA, MLA, Mamba2] = None
     ffn: Optional[Experts] = None
     post_norm: bool = False
     branch: Optional[Experts] = None
     join: bool = False
+    only: str = ""
 
     @property
     def shortcut(self) -> bool:
@@ -186,7 +236,12 @@ class TransformerConfig:
     Collectives inside a rematted block re-execute during backward, which
     is SPMD-symmetric (every rank reruns the same sequence, so no
     deadlock); it requires the traced (SPMD/jit) path — the eager
-    thread-SPMD backend's ops execute imperatively and refuse tracing."""
+    thread-SPMD backend's ops execute imperatively and refuse tracing.
+
+    ``nope`` gives the configuration's own attention no position signal
+    at all: no rotation (``rope`` must be off) and no learned table (no
+    ``pos`` leaf), for a stack whose other layers carry the order (the
+    attention layers of a state-space hybrid)."""
     vocab: int
     d_model: int
     n_heads: int
@@ -197,6 +252,7 @@ class TransformerConfig:
     attn_window: int = 0
     rope: bool = False
     rope_theta: float = 10000.0
+    nope: bool = False
     norm: str = "layernorm"
     ffn: str = "gelu"
     n_experts: int = 0
@@ -217,8 +273,19 @@ class TransformerConfig:
                 raise ValueError(
                     "a layer spec names its expert layers itself "
                     "(LayerSpec.ffn); n_experts is the uniform top-1 MoE")
-            if any(s.mixer is None and (s.ffn is not None or s.post_norm
-                                        or s.shortcut)
+            for i, s in enumerate(self.layers):
+                if s.only not in ("", "mixer", "ffn"):
+                    raise ValueError(
+                        f"layer {i}: LayerSpec.only is \"\", \"mixer\" or "
+                        f"\"ffn\", got {s.only!r}")
+                if s.only and (s.post_norm or s.shortcut or (
+                        s.ffn if s.only == "mixer" else s.mixer) is not None):
+                    raise ValueError(
+                        f"layer {i} is its {s.only} alone (LayerSpec.only): "
+                        "it has one norm and one residual sum, and names no "
+                        "other part, second norm or shortcut")
+            if any(s.mixer is None and not s.only
+                   and (s.ffn is not None or s.post_norm or s.shortcut)
                    for s in self.layers):
                 raise ValueError(
                     "an expert FFN, a post-norm or a shortcut branch needs "
@@ -269,6 +336,10 @@ class TransformerConfig:
             raise ValueError(
                 f"attn_window must be >= 0 (0 = full causal attention), "
                 f"got {self.attn_window}")
+        if self.nope and self.rope:
+            raise ValueError(
+                "nope is attention with no position signal; rope rotates "
+                "by position")
         if self.rope and (self.d_model // self.n_heads) % 2 != 0:
             raise ValueError(
                 f"rope requires an even head_dim, got "
@@ -285,6 +356,12 @@ class TransformerConfig:
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def pos_table(self) -> bool:
+        """Whether the model adds a learned position table (``pos``) to
+        its embedding: under neither ``rope`` nor ``nope``."""
+        return not (self.rope or self.nope)
 
     @property
     def layer_specs(self) -> Tuple[LayerSpec, ...]:
@@ -316,7 +393,7 @@ def init_transformer(key, cfg: TransformerConfig,
     # would shift every later key and silently change all existing
     # non-rope initializations for the same seed.
     pos_key = next(keys)
-    if not cfg.rope:
+    if cfg.pos_table:
         # Learned absolute positions; under rope the encoding is applied
         # rotationally to q/k instead (no table, no max_seq cap on the
         # encoding itself).
@@ -328,20 +405,25 @@ def init_transformer(key, cfg: TransformerConfig,
         # Fused projection: h q-heads plus 2*h_kv KV heads (= 3*d_model
         # for plain MHA; smaller under GQA).
         hd = d_model // cfg.n_heads
-        if spec.mixer is None:
+        if spec.only == "ffn":
+            blk = {}
+        elif spec.mixer is None:
             blk = {
                 "ln1": norm_p(),
                 "wqkv": dense(next(keys), d_model,
                               d_model + 2 * cfg.kv_heads * hd),
                 "wo": dense(next(keys), d_model, d_model),
-                "ln2": norm_p(),
             }
         else:
-            blk = {"ln1": norm_p(), "ln2": norm_p(),
+            blk = {"ln1": norm_p(),
                    "mixer": _init_mixer(next(keys), spec.mixer, d_model,
                                         dtype)}
             if spec.post_norm:
                 blk["ln1_post"], blk["ln2_post"] = norm_p(), norm_p()
+        if spec.only == "mixer":
+            params["blocks"].append(blk)
+            continue
+        blk["ln2"] = norm_p()
         if spec.branch is not None:
             blk["branch"] = init_experts(next(keys), spec.branch, d_model,
                                          dtype)
@@ -363,7 +445,8 @@ def init_transformer(key, cfg: TransformerConfig,
 
 
 def _init_mixer(key, spec, d_model: int, dtype) -> Dict[str, Any]:
-    """Leaves of a :class:`KDA` or :class:`MLA` mixer."""
+    """Leaves of a :class:`KDA`, :class:`MLA` or :class:`Mamba2`
+    mixer."""
     ks = iter(jax.random.split(key, 10))
 
     def dense(m, n):
@@ -391,6 +474,21 @@ def _init_mixer(key, spec, d_model: int, dtype) -> Dict[str, Any]:
                            "bias": jnp.zeros((ix.head_dim,), dtype)},
                 "ww": dense(d_model, ix.n_heads)}
         return out
+    if isinstance(spec, Mamba2):
+        h = spec.n_heads
+        # As the state-space models start them: exp(a_log) in [1, 16],
+        # softplus(dt_bias) in [1e-3, 1e-1], D ones.
+        dt = jnp.exp(jax.random.uniform(next(ks), (h,), jnp.float32,
+                                        jnp.log(1e-3), jnp.log(1e-1)))
+        return {"in_proj": dense(d_model, spec.d_inner + spec.conv_dim + h),
+                "conv": dense(spec.conv, spec.conv_dim),
+                "conv_bias": jnp.zeros((spec.conv_dim,), dtype),
+                "dt_bias": jnp.log(jnp.expm1(dt)).astype(dtype),
+                "a_log": jnp.log(jax.random.uniform(
+                    next(ks), (h,), jnp.float32, 1.0, 16.0)).astype(dtype),
+                "d": jnp.ones((h,), dtype),
+                "norm": {"scale": jnp.ones((spec.d_inner,), dtype)},
+                "out_proj": dense(spec.d_inner, d_model)}
     h, hd = spec.n_heads, spec.head_dim
     # Decay as the delta-rule models start it: exp(a_log) in [1, 16],
     # softplus(dt_bias) in [1e-3, 1e-1].
@@ -556,6 +654,72 @@ def _kda_mixer(spec: KDA, p, y):
     gate = jax.nn.sigmoid(heads(((y @ p["wg1"]) @ p["wg2"]).astype(ct)))
     o = (_rms_norm(o.astype(ct), p["norm"]) * gate).astype(y.dtype)
     return o.reshape(b, s, h * hd) @ p["wo"]
+
+
+def mamba2_project(spec: Mamba2, p, y):
+    """The input projection of a Mamba-2 mixer on the normed input ``y``
+    ``(b, s, d)``, one fused product cut as ``[z | xBC | dt]``: the gate
+    ``z`` ``(b, s, d_inner)``, the convolution's input ``xBC`` ``(b, s,
+    conv_dim)`` and the step sizes before their bias and softplus ``dt``
+    ``(b, s, n_heads)``."""
+    proj = y @ p["in_proj"]
+    di, dc = spec.d_inner, spec.conv_dim
+    return proj[..., :di], proj[..., di:di + dc], proj[..., di + dc:]
+
+
+def mamba2_scan(spec: Mamba2, p, xBC, dt, entry=None):
+    """The convolution and the recurrence of a Mamba-2 mixer, from what
+    a sequence kept: ``entry`` is ``{"h": (b, n_heads, head_dim,
+    d_state) float32, "conv": (b, conv - 1, conv_dim)}``, the state and
+    the convolution's last inputs before this pass (``None``: a sequence
+    that starts here, zeros both).  ``xBC`` ``(b, s, conv_dim)`` and
+    ``dt`` ``(b, s, n_heads)`` are :func:`mamba2_project`'s.  Returns
+    ``(y (b, s, d_inner), entry)`` with the entry as the pass leaves it:
+    the state after the last token and the last ``conv - 1`` inputs (the
+    old ones still among them where the pass is shorter).
+
+    A pass of one token with an entry is a decode step and runs
+    :func:`~mpi4torch_tpu.ops.ssd.ssd_step`, every sequence's state read
+    once and written once; every other pass runs the chunked form from
+    the entry's state."""
+    b, s, _ = xBC.shape
+    taps, ct = spec.conv, jnp.promote_types(xBC.dtype, jnp.float32)
+    tail = jnp.zeros((b, taps - 1, spec.conv_dim), xBC.dtype) \
+        if entry is None else entry["conv"].astype(xBC.dtype)
+    seen = jnp.concatenate([tail, xBC], axis=1)
+    conv = sum(seen[:, j:j + s].astype(ct) * p["conv"][j].astype(ct)
+               for j in range(taps)) + p["conv_bias"].astype(ct)
+    conv = jax.nn.silu(conv).astype(xBC.dtype)
+    g, n = spec.n_groups, spec.d_state
+    x = conv[..., :spec.d_inner].reshape(b, s, spec.n_heads, spec.head_dim)
+    B = conv[..., spec.d_inner:spec.d_inner + g * n].reshape(b, s, g, n)
+    C = conv[..., spec.d_inner + g * n:].reshape(b, s, g, n)
+    delta = jax.nn.softplus(dt.astype(ct) + p["dt_bias"].astype(ct))
+    A = -jnp.exp(p["a_log"].astype(ct))
+    if entry is not None and s == 1:
+        y, h = ssd_step(entry["h"], x[:, 0], delta[:, 0], A, B[:, 0],
+                        C[:, 0], p["d"])
+        y = y[:, None]
+    else:
+        y, h = ssd_chunked(x, delta, A, B, C, p["d"],
+                           None if entry is None else entry["h"],
+                           chunk=spec.chunk)
+    return y.reshape(b, s, spec.d_inner), \
+        {"h": h, "conv": seen[:, -(taps - 1):]}
+
+
+def mamba2_out(spec: Mamba2, p, y, z):
+    """What follows the recurrence: ``y`` under the gate ``silu(z)``,
+    an rmsnorm over each group's ``d_inner / n_groups`` channels with
+    one scale of ``d_inner`` (gate first, then norm), the output
+    projection.  ``y`` and ``z`` ``(b, s, d_inner)``."""
+    ct = jnp.promote_types(y.dtype, jnp.float32)
+    gated = y.astype(ct) * jax.nn.silu(z.astype(ct))
+    groups = gated.reshape(*gated.shape[:-1], spec.n_groups, -1)
+    ms = jnp.mean(jnp.square(groups), axis=-1, keepdims=True)
+    normed = (groups * jax.lax.rsqrt(ms + 1e-5)).reshape(gated.shape) \
+        * p["norm"]["scale"].astype(ct)
+    return normed.astype(y.dtype) @ p["out_proj"]
 
 
 _MLA_BLOCK = 2048
@@ -936,7 +1100,7 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
     b, s_local = tokens.shape
     h = cfg.n_heads
     if comm_sp is not None and comm_sp.size > 1:
-        if not cfg.rope and comm_sp.size * s_local > cfg.max_seq:
+        if cfg.pos_table and comm_sp.size * s_local > cfg.max_seq:
             # Without this, the positional-table dynamic_slice would
             # clamp the high ranks' start offsets and silently reuse the
             # last positional block.  Under rope there is no table and
@@ -960,7 +1124,7 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
     else:
         positions = offset + jnp.arange(s_local, dtype=jnp.int32)
     x = params["embed"][tokens]
-    if not cfg.rope:
+    if cfg.pos_table:
         if zigzag_sharded:
             x = x + jnp.take(params["pos"], positions, axis=0)[None]
         else:
@@ -983,7 +1147,21 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
         x, aux = _ffn_residual(cfg, blk, x, comm_ep)
         return x, aux
 
+    def attention_fn(x, blk):
+        # The configuration's own attention as a layer of one part.
+        q, k, v = _split_qkv(cfg, blk, _norm(cfg, x, blk["ln1"]), positions)
+        o = _attention(q, k, v, comm_sp, attn, cfg.attn_window)
+        return x + o.reshape(b, s_local, d) @ blk["wo"]
+
     def mixer_fn(spec, x, blk):
+        if isinstance(spec.mixer, Mamba2):
+            raise CommError(
+                "the training forward does not run a Mamba2 mixer: the "
+                "chunked scan's backward is not written (ops/ssd.py is "
+                "inference only); serve the configuration through "
+                "mpi4torch_tpu.serve")
+        if spec.mixer is None:
+            return attention_fn(x, blk)
         y = _norm(cfg, x, blk["ln1"])
         if isinstance(spec.mixer, KDA):
             with layer_scope("kda"):
@@ -1030,12 +1208,15 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
         if cfg.remat else (lambda f: f)
     rows, carried = [], None
     for spec, blk in zip(cfg.layer_specs, params["blocks"]):
-        if spec.mixer is None and spec.ffn is None:
+        if spec.mixer is None and spec.ffn is None and not spec.only:
             x, aux = (jax.checkpoint(block_fn) if cfg.remat
                       else block_fn)(x, blk)
             aux_total = aux_total + aux
             continue
-        x = remat(functools.partial(mixer_fn, spec))(x, blk)
+        if spec.only != "ffn":
+            x = remat(functools.partial(mixer_fn, spec))(x, blk)
+        if spec.only == "mixer":
+            continue
         if spec.shortcut:
             x, carried, taken = remat(functools.partial(shortcut_fn, spec))(
                 x, blk, carried)
@@ -1109,7 +1290,7 @@ def decode_step(cfg: TransformerConfig, params, cache, tokens, pos):
     pos = jnp.asarray(pos, jnp.int32)
 
     x = params["embed"][tokens]
-    if not cfg.rope:
+    if cfg.pos_table:
         x = x + jax.lax.dynamic_slice_in_dim(params["pos"], pos, 1, 0)[0]
 
     # Sliding-window serving win: with attn_window set, the query only
@@ -1164,7 +1345,7 @@ def prefill(cfg: TransformerConfig, params, cache, prompt):
     refuse_layer_spec(cfg, "prefill")
     b, p_len = prompt.shape
     x = params["embed"][prompt]
-    if not cfg.rope:
+    if cfg.pos_table:
         x = x + params["pos"][None, :p_len]
     new_cache = []
     for blk, c in zip(params["blocks"], cache):

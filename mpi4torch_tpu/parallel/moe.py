@@ -22,8 +22,9 @@ ride the reverse all-to-all automatically.
 
 Beside it, the held-share layer (:func:`held_experts_ffn`): sigmoid or
 softmax scores, top-k of ALL the experts with a selection bias, weights
-renormalised or not, swiglu experts, a shared expert and zero-compute
-(identity) experts behind the routed ones, for a rank that is told
+renormalised or not, swiglu or relu² experts at the stream's width or in
+a latent behind one shared down- and up-projection, a shared expert and
+zero-compute (identity) experts behind the routed ones, for a rank that is told
 which experts it holds and computes their part of the result for every
 token routed to them — no capacity, nothing dropped, no exchange yet.
 """
@@ -230,7 +231,18 @@ class Experts:
     the routed ones (router outputs ``n_experts`` to ``n_experts + n_zero
     - 1``): each returns its input, so a token that chooses some of them
     adds ``(sum of their weights) * x`` and computes that many fewer
-    experts.  No rank holds them: a token's own rank adds their part."""
+    experts.  No rank holds them: a token's own rank adds their part.
+
+    ``latent > 0`` puts the routed experts in a latent of that width:
+    one down-projection ``(d, latent)`` before them and one
+    up-projection ``(latent, d)`` behind them, shared by all of them;
+    an expert's matrices are ``latent`` wide on the stream's side and
+    the weighted sum over a token's experts is taken in the latent.  The
+    router and the shared expert read the stream itself.  ``act`` is an
+    expert's form, the shared one's too: ``"swiglu"`` (``W2 (silu(Wg x)
+    * Wu x)``, ``w1 = [gate | up]``) or ``"relu2"`` (``W2 relu(W1 x)^2``,
+    no gate).  ``d_shared`` is the shared expert's width where it is not
+    ``n_shared * d_expert``."""
     n_experts: int
     top_k: int
     d_expert: int
@@ -241,17 +253,34 @@ class Experts:
     score: str = "sigmoid"
     renorm: bool = True
     n_zero: int = 0
+    latent: int = 0
+    act: str = "swiglu"
+    d_shared: int = 0
 
     @property
     def width(self) -> int:
         """The router's outputs: routed and zero-compute experts."""
         return self.n_experts + self.n_zero
 
+    @property
+    def shared_width(self) -> int:
+        return self.d_shared or self.n_shared * self.d_expert
+
     def __post_init__(self):
         if self.score not in ("sigmoid", "softmax"):
             raise ValueError(f"unknown score function {self.score!r}")
+        if self.act not in ("swiglu", "relu2"):
+            raise ValueError(f"unknown expert activation {self.act!r}")
         if self.n_zero < 0:
             raise ValueError(f"n_zero={self.n_zero} must be >= 0")
+        if self.latent < 0 or (self.latent and self.n_zero):
+            raise ValueError(
+                f"latent={self.latent} must be >= 0, and a zero-compute "
+                "expert returns its input, which a latent layer's experts "
+                "never see at the stream's width")
+        if self.d_shared and not self.n_shared:
+            raise ValueError("d_shared is the width of a shared expert: "
+                             "it needs n_shared > 0")
         if not 0 < self.top_k <= self.width:
             raise ValueError(
                 f"top_k={self.top_k} must lie in [1, n_experts + n_zero="
@@ -269,10 +298,12 @@ def init_experts(key, spec: Experts, d_model: int,
     """Parameters of one held share: the router at its full width
     (``spec.width``: zero-compute experts have an output each and no
     other leaf), a selection ``bias`` (zeros; it takes no gradient), the
-    held experts' fused ``w1 = [gate | up]`` and ``w2``, and the shared
-    expert."""
+    held experts' ``w1`` (fused ``[gate | up]`` for swiglu) and ``w2``,
+    the shared expert, and a latent layer's ``down`` and ``up``."""
     kr, k1, k2, k3, k4 = jax.random.split(key, 5)
     f, e = spec.d_expert, spec.n_held
+    fan = 2 if spec.act == "swiglu" else 1
+    d_in = spec.latent or d_model
 
     def dense(key, *shape):
         return jax.random.normal(key, shape, dtype) / jnp.sqrt(
@@ -280,10 +311,15 @@ def init_experts(key, spec: Experts, d_model: int,
 
     p = {"router": dense(kr, d_model, spec.width),
          "bias": jnp.zeros((spec.width,), dtype),
-         "w1": dense(k1, e, d_model, 2 * f), "w2": dense(k2, e, f, d_model)}
+         "w1": dense(k1, e, d_in, fan * f), "w2": dense(k2, e, f, d_in)}
     if spec.n_shared:
-        p["shared_w1"] = dense(k3, d_model, 2 * spec.n_shared * f)
-        p["shared_w2"] = dense(k4, spec.n_shared * f, d_model)
+        p["shared_w1"] = dense(k3, d_model, fan * spec.shared_width)
+        p["shared_w2"] = dense(k4, spec.shared_width, d_model)
+    if spec.latent:
+        # Keys of their own: the five above stay what they were.
+        k5, k6 = jax.random.split(jax.random.fold_in(key, 5))
+        p["down"] = dense(k5, d_model, spec.latent)
+        p["up"] = dense(k6, spec.latent, d_model)
     return p
 
 
@@ -351,12 +387,21 @@ def _swiglu(x, w1, w2, dot):
     return dot(jax.nn.silu(gate) * up, w2)
 
 
+def _relu2(x, w1, w2, dot):
+    return dot(jnp.square(jax.nn.relu(dot(x, w1))), w2)
+
+
+_EXPERT = {"swiglu": _swiglu, "relu2": _relu2}
+
+
 def held_experts_ffn(x, params: Dict[str, Any], spec: Experts,
                      comm_ep=None, live=None):
     """The held experts' part of the layer for ``x`` ``(T, d)``, plus the
     shared expert and the zero-compute experts: ``sum over chosen and
     held e of w_e E_e(x) + E_shared(x) + (sum over chosen zero-compute z
-    of w_z) x``.  The weights are taken (and, where the spec says so,
+    of w_z) x``; of a latent layer (``spec.latent``) ``(sum over chosen
+    and held e of w_e E_e(x W_down)) W_up + E_shared(x)``, the sum taken
+    in the latent.  The weights are taken (and, where the spec says so,
     renormalised) over all ``top_k`` chosen experts, held or not; what
     the experts held elsewhere would add is left out.  The zero-compute
     part is whole: every token of ``x`` is at home here.
@@ -402,11 +447,13 @@ def held_experts_ffn(x, params: Dict[str, Any], spec: Experts,
     rows = jnp.sum(jax.nn.one_hot(group, held, dtype=jnp.int32), axis=0)
     is_held = (group[order] < held)[:, None]
 
-    xs = _pair_rows(x, order, inverse, is_held)
+    expert = _EXPERT[spec.act]
+    xs = _pair_rows(x @ params["down"] if spec.latent else x, order,
+                    inverse, is_held)
     grouped = lambda a, w: jax.lax.ragged_dot(a, w, rows)
-    ys = jnp.where(is_held, _swiglu(xs, params["w1"], params["w2"], grouped),
+    ys = jnp.where(is_held, expert(xs, params["w1"], params["w2"], grouped),
                    0)
-    ys = _permute_rows(ys, inverse, order).reshape(T, k, d)
+    ys = _permute_rows(ys, inverse, order).reshape(T, k, xs.shape[-1])
     y = jnp.sum(ys.astype(weight.dtype) * weight[..., None], axis=1)
     zero_pairs = 0
     if spec.n_zero:
@@ -417,7 +464,9 @@ def held_experts_ffn(x, params: Dict[str, Any], spec: Experts,
             is_zero &= live[:, None]
         zero_pairs = jnp.sum(is_zero, dtype=jnp.int32)
     y = y.astype(x.dtype)
+    if spec.latent:
+        y = y @ params["up"]
     if spec.n_shared:
-        y = y + _swiglu(x, params["shared_w1"], params["shared_w2"],
-                        jnp.matmul)
+        y = y + expert(x, params["shared_w1"], params["shared_w2"],
+                       jnp.matmul)
     return y, rows, zero_pairs

@@ -69,6 +69,7 @@ MIRRORED_SERVE_COUNTERS = (
     "install_writes", "decode_pages_live", "decode_pages_read",
     "decode_grid_steps", "decode_select_syncs", "moe_zero_pairs", "moe_live_pairs",
     "dsa_rows_live", "dsa_rows_read", "dsa_rows_scored",
+    "ssm_states_live", "ssm_states_touched",
     "decode_uploads", "step_compiles",
 )
 

@@ -389,8 +389,15 @@ class Engine:
                 self._size = int(nranks or len(jax.devices()))
         else:
             self._size = self._comm.size
-        _kv.validate_tp(cfg, self._size)
         self._paged = self.serve_cfg.block_size > 0
+        _kv.validate_tp(
+            cfg, self._size,
+            prefix_cache=self._paged and self.serve_cfg.prefix_cache,
+            prefill_chunk=self.serve_cfg.prefill_chunk)
+        # A layer that keeps a per-slot state beside the cache
+        # (kv.STATE_LEAVES): made with the cache, donated and handed
+        # back with it, written whole by the install.
+        self._stateful = _kv.has_state(cfg)
         # Exactness gate for prefix sharing and chunked prefill
         # (_exact_kv, below): both splice CACHE-dtype rows into prefill
         # attention, which is only bit-identical to the one-shot oracle
@@ -457,7 +464,7 @@ class Engine:
                   if self.serve_cfg.num_blocks is not None
                   else slots * self._blocks_per_seq)
             cache = _kv.init_kv_pool_tp(cfg, nb, bs, self._size,
-                                        self._dtype)
+                                        self._dtype, slots=slots)
             self._mgr = _paging.BlockManager(
                 nb, bs,
                 prefix_cache=(self.serve_cfg.prefix_cache
@@ -585,6 +592,8 @@ class Engine:
         like = lambda *shape: jax.ShapeDtypeStruct(shape, dtype)
         steps = {}                    # each kind of layer once
         for spec, entry in zip(cfg.layer_specs, cache):
+            if all(k in _kv.STATE_LEAVES for k in entry):
+                continue              # no pages: an FFN or a state alone
             if spec.mixer in steps:
                 continue
             index = getattr(spec.mixer, "index", None)
@@ -936,7 +945,10 @@ class Engine:
         decode step with indexed latent layers ``dsa_rows_live``,
         ``dsa_rows_read`` and ``dsa_rows_scored`` (the latent rows under
         the live slots' frontiers, the rows the selections named, the
-        index keys scored, each summed over its layers), which the
+        index keys scored, each summed over its layers), and from one
+        with Mamba-2 layers ``ssm_states_live`` and
+        ``ssm_states_touched`` (the live slots' kept states it had to
+        advance, and those it read and wrote), which the
         step record carries by name like every counter.  No transfer is
         made here: a decode step's counters came down with its tokens
         (:meth:`_advance`), a prefill's by :meth:`_prefill_counters`."""
@@ -947,7 +959,8 @@ class Engine:
             for piece in rows.reshape((-1,) + rows.shape[-2:]):
                 self.stats.attach("moe_rows", (program, piece))
         for name in ("moe_zero_pairs", "moe_live_pairs", "dsa_rows_live",
-                     "dsa_rows_read", "dsa_rows_scored"):
+                     "dsa_rows_read", "dsa_rows_scored", "ssm_states_live",
+                     "ssm_states_touched"):
             if name in stats:
                 self.stats.count(name, int(stats[name]))
 
@@ -983,16 +996,17 @@ class Engine:
             return jax.jit(_kv.install_rows_paged, donate_argnums=0)
         state = jax.tree.leaves(self._cache)[0].sharding
 
-        def per_rank(pool, rows, index):
+        def per_rank(pool, rows, *index):
             (pool, rows) = jax.tree.map(lambda a: a[0], (pool, rows))
             return jax.tree.map(
                 lambda a: a[None],
-                _kv.install_rows_paged(pool, rows, index))
+                _kv.install_rows_paged(pool, rows, *index))
 
+        # The page index, and the slot where a layer keeps a state.
+        index = (PartitionSpec(),) * (1 + self._stateful)
         return jax.jit(
             jax.shard_map(per_rank, mesh=state.mesh,
-                          in_specs=(state.spec, state.spec,
-                                    PartitionSpec()),
+                          in_specs=(state.spec, state.spec) + index,
                           out_specs=state.spec, check_vma=False),
             donate_argnums=0)
 
@@ -1011,13 +1025,14 @@ class Engine:
         first = lo // bs
         touched = -(-hi // bs) - first
         n_pages = _kv.install_page_count(
-            jax.tree.leaves(rows)[0].shape[-3], bs)
+            _kv.first_paged_leaf(rows).shape[-3], bs)
         index = np.empty(2 + n_pages, np.int32)
         index[0], index[1] = lo % bs, hi - lo
         # Beyond the touched pages: ids outside the pool, dropped.
         index[2:] = self._mgr.num_blocks + np.arange(n_pages)
         index[2:2 + touched] = self._table[j, first:first + touched]
-        self._cache = self._install_call(self._cache, rows, index)
+        slot = (np.int32(j),) if self._stateful else ()
+        self._cache = self._install_call(self._cache, rows, index, *slot)
         self.stats.count("install_writes")
 
     def _count_pages(self, active: List[int]) -> None:
@@ -1257,14 +1272,23 @@ class Engine:
         once).  It is a census, not a timer, so it regresses
         deterministically on CPU smoke."""
         # One token's rows over every cache leaf (a leaf is (..., rows
-        # of a slot or a page, *row shape), behind the stacked axis).
+        # of a slot or a page, *row shape), behind the stacked axis),
+        # and what an occupied slot keeps whatever its length (a
+        # per-slot state: (..., slots, *its shape)).
         lead = 3 if self._spmd else 2
-        row = sum(int(np.prod(a.shape[lead:])) * a.dtype.itemsize
-                  for a in jax.tree.leaves(self._cache))
+        size = lambda a, lead: int(np.prod(a.shape[lead:])) \
+            * a.dtype.itemsize
+        row = kept = 0
+        for entry in self._cache:
+            for k, a in entry.items():
+                if k in _kv.STATE_LEAVES:
+                    kept += size(a, lead - 1)
+                else:
+                    row += size(a, lead)
         if self._paged:
             return self._mgr.blocks_in_use \
-                * self.serve_cfg.block_size * row
-        return self.occupancy() * self.cfg.max_seq * row
+                * self.serve_cfg.block_size * row + self.occupancy() * kept
+        return self.occupancy() * (self.cfg.max_seq * row + kept)
 
     def _finish(self, req: Request, status: str = STATUS_OK) -> None:
         self._results[req.rid] = np.concatenate(

@@ -50,10 +50,11 @@ import jax.numpy as jnp
 
 from .. import config as _config
 from ..constants import MPI_SUM
-from ..models.transformer import KDA, MLA, TransformerConfig, _MLA_BLOCK, \
-    _blockwise_causal_attention, _norm, _split_qkv, branch_norm, \
-    dense_ffn, index_project, index_select_mask, mla_expand, mla_project, \
-    select_rows, shortcut_branch
+from ..models.transformer import KDA, MLA, Mamba2, TransformerConfig, \
+    _MLA_BLOCK, _blockwise_causal_attention, _norm, _split_qkv, \
+    branch_norm, dense_ffn, index_project, index_select_mask, mamba2_out, \
+    mamba2_project, mamba2_scan, mla_expand, mla_project, select_rows, \
+    shortcut_branch
 from ..ops.flash import flash_attention, flash_block_attention, \
     masked_attention
 from ..ops.paged_attention import index_rows_scores, \
@@ -82,7 +83,25 @@ __all__ = [
 ]
 
 
-def validate_tp(cfg: TransformerConfig, size: int) -> None:
+# The leaves of a cache entry that are a slot's own and not paged: a
+# Mamba-2 layer's state and its convolution's last inputs.
+STATE_LEAVES = ("h", "conv")
+
+
+def has_state(cfg: TransformerConfig) -> bool:
+    """Whether a layer of the configuration keeps a per-slot state."""
+    return any(isinstance(sp.mixer, Mamba2) for sp in cfg.layer_specs)
+
+
+def first_paged_leaf(entries):
+    """The first leaf of a cache tree (or of prefill rows) that lies in
+    pages: what a page's size or a pass's length is read off."""
+    return next(leaf for entry in entries for k, leaf in entry.items()
+                if k not in STATE_LEAVES)
+
+
+def validate_tp(cfg: TransformerConfig, size: int, *,
+                prefix_cache: bool = False, prefill_chunk=None) -> None:
     """Serving TP shardability of a model config over ``size`` ranks:
     whole q heads, whole KV heads, and an FFN hidden divisible per rank.
     Uniform top-1 MoE configs (``n_experts > 0``) are refused —
@@ -92,13 +111,18 @@ def validate_tp(cfg: TransformerConfig, size: int) -> None:
 
     A configuration with a per-layer spec is served on ONE rank: latent
     attention (:class:`~mpi4torch_tpu.models.transformer.MLA`) through a
-    latent cache entry, the held share of an expert layer inside the
+    latent cache entry, a Mamba-2 mixer through a per-slot state entry
+    beside the cache, the held share of an expert layer inside the
     compiled prefill and decode step.  Refused by name: a KDA mixer (its
-    recurrent state has no cache entry and no snapshot yet), and more
-    than one rank (the heads of a latent layer and the experts' exchange
-    are not sharded yet; a shortcut branch is named where the spec has
-    one, since it is the branch's exchange that its dense path would
-    hide)."""
+    state entry is not written yet), and more
+    than one rank (the heads of a latent or a state-space layer and the
+    experts' exchange are not sharded yet; a shortcut branch is named
+    where the spec has one, since it is the branch's exchange that its
+    dense path would hide).  With a state layer, ``prefix_cache`` and
+    ``prefill_chunk`` (the engine's options) are refused too: a prompt
+    that starts from shared pages, or from its own earlier chunk, needs
+    the state as it stood at that page's edge, and no snapshot of it is
+    kept."""
     if cfg.n_experts > 0:
         raise CommError(
             "serve: MoE configs (n_experts > 0) are not supported by the "
@@ -107,10 +131,33 @@ def validate_tp(cfg: TransformerConfig, size: int) -> None:
     if cfg.layers:
         if any(isinstance(sp.mixer, KDA) for sp in cfg.layers):
             raise CommError(
-                "serve: a KDA mixer keeps a recurrent state, for which "
-                "the serving cache has no entry and no snapshot yet — "
-                "latent attention (MLA) and the held expert share are "
-                "what the serving walk knows of a per-layer spec")
+                "serve: a KDA mixer keeps a recurrent state of its own "
+                "kind (a matrix a head under a per-channel decay and the "
+                "convolutions' inputs of q, k and v), for which the "
+                "per-slot state entry is not written yet — latent "
+                "attention (MLA), a Mamba2 mixer and the held expert "
+                "share are what the serving walk knows of a per-layer "
+                "spec")
+        state = has_state(cfg)
+        if size != 1 and state:
+            raise CommError(
+                f"serve: a Mamba2 mixer is served on one rank; {size} "
+                "ranks would need its heads, their states and the "
+                "grouped norm sharded over the ranks, which is not "
+                "written yet")
+        if state and prefix_cache:
+            raise CommError(
+                "serve: prefix sharing (ServeConfig.prefix_cache) with a "
+                "Mamba2 layer: a prompt that adopts shared pages would "
+                "need the recurrent state as it stood at that page's "
+                "edge, and no snapshot of it is kept — pass "
+                "prefix_cache=False")
+        if state and prefill_chunk is not None:
+            raise CommError(
+                "serve: chunked prefill (ServeConfig.prefill_chunk) with "
+                "a Mamba2 layer: the chunk view carries K/V rows between "
+                "chunks and no recurrent state yet — prefill in one "
+                "piece")
         if size != 1 and any(getattr(sp.mixer, "index", None) is not None
                              for sp in cfg.layers):
             raise CommError(
@@ -209,8 +256,9 @@ def shard_params_tp(cfg: TransformerConfig, params, comm):
 def shard_block_tp(cfg: TransformerConfig, spec, blk, comm):
     """One layer of :func:`shard_params_tp` (the engine shards a layer
     at a time).  A layer a spec names other than the configuration's
-    own is served on one rank and passes whole."""
-    if spec.mixer is not None:
+    own, or as one part alone, is served on one rank and passes
+    whole."""
+    if spec.mixer is not None or spec.only:
         return dict(blk)
     out = {"ln1": blk["ln1"], "ln2": blk["ln2"],
            "wqkv": _shard_wqkv(cfg, comm, blk["wqkv"]),
@@ -237,14 +285,19 @@ def init_kv_cache_tp(cfg: TransformerConfig, slots: int, size: int,
     overwrites the whole slot row so live slots never see the poison."""
     fill = jnp.nan if poison and jnp.issubdtype(dtype, jnp.floating) \
         else 0
-    return _cache_entries(cfg, (slots, cfg.max_seq), size,
-                          lambda shape: jnp.full(shape, fill, dtype))
+    return _cache_entries(
+        cfg, (slots, cfg.max_seq), size,
+        lambda shape, dt=dtype: jnp.full(shape, fill, dt), slots,
+        jnp.promote_types(dtype, jnp.float32))
 
 
-def _cache_entries(cfg: TransformerConfig, lead: tuple, size: int, make):
+def _cache_entries(cfg: TransformerConfig, lead: tuple, size: int, make,
+                   slots: int = 0, state_dtype=jnp.float32):
     """One cache entry a layer, each leaf ``make(lead + its row's
-    shape)``: ``{"k", "v"}`` of ``(kv_heads / size, head_dim)`` rows for
-    the configuration's own attention, ``{"c"}`` of ``(1,
+    shape)`` (``make(shape, state_dtype)`` for the one leaf that is not
+    of the cache's type, a recurrent state): ``{"k", "v"}`` of
+    ``(kv_heads / size, head_dim)`` rows for the configuration's own
+    attention, ``{"c"}`` of ``(1,
     latent_width)`` rows for a latent layer — one row a token for all
     heads, the normed latent and the rotated shared key
     (:func:`latent_width`: 640 channels, 1,280 bytes in bfloat16, where
@@ -253,18 +306,37 @@ def _cache_entries(cfg: TransformerConfig, lead: tuple, size: int, make):
     ``"ik"`` of ``(1, head_dim)`` rows: the token's index key, what a
     later query's indexer scores this position by (128 channels, 256
     bytes).  Both leaves of a layer live in the same pages: one block
-    table, one install, one copy on write."""
-    hd = cfg.d_model // cfg.n_heads
-    made = {}                  # one buffer a row shape, shared as a template
+    table, one install, one copy on write.
 
-    def leaf(*row):
-        if row not in made:
-            made[row] = make(lead + row)
-        return made[row]
+    Two kinds of layer keep no rows.  A layer that is its FFN alone
+    (``LayerSpec.only == "ffn"``) has an EMPTY entry.  A Mamba-2 layer
+    keeps what does not grow with the sequence, a SLOT's own and under
+    no ``lead``: ``{"h": (slots, n_heads, head_dim, d_state)`` in at
+    least float32 whatever the cache's type, ``"conv": (slots, conv - 1,
+    conv_dim)}`` in the cache's (:data:`STATE_LEAVES`): the recurrent
+    state after the slot's last token and the convolution's last
+    inputs.  No table addresses them, no page holds them, and a slot
+    that is taken again has them overwritten whole by its prompt's."""
+    hd = cfg.d_model // cfg.n_heads
+    made = {}                  # one buffer a shape, shared as a template
+
+    def leaf(*row, lead=lead, state=False):
+        if (lead, row) not in made:
+            made[lead, row] = make(lead + row, state_dtype) if state \
+                else make(lead + row)
+        return made[lead, row]
 
     out = []
     for spec in cfg.layer_specs:
-        if isinstance(spec.mixer, MLA):
+        if spec.only == "ffn":
+            out.append({})
+        elif isinstance(spec.mixer, Mamba2):
+            m = spec.mixer
+            out.append({
+                "h": leaf(m.n_heads, m.head_dim, m.d_state, lead=(slots,),
+                          state=True),
+                "conv": leaf(m.conv - 1, m.conv_dim, lead=(slots,))})
+        elif isinstance(spec.mixer, MLA):
             out.append({"c": leaf(1, latent_width(spec.mixer))})
             if spec.mixer.scores:
                 out[-1]["ik"] = leaf(1, spec.mixer.index.head_dim)
@@ -275,7 +347,8 @@ def _cache_entries(cfg: TransformerConfig, lead: tuple, size: int, make):
 
 
 def init_kv_pool_tp(cfg: TransformerConfig, num_blocks: int,
-                    block_size: int, size: int, dtype=jnp.float32):
+                    block_size: int, size: int, dtype=jnp.float32,
+                    slots: int = 0):
     """Per-layer TP-sharded paged KV pool:
     ``(num_blocks, block_size, kv_heads / size, head_dim)`` per rank
     (a latent layer: one leaf ``(num_blocks, block_size, 1,
@@ -284,6 +357,10 @@ def init_kv_pool_tp(cfg: TransformerConfig, num_blocks: int,
     through a per-slot block table instead of a dense per-slot row.
     One block-id space serves every layer (block ``i`` of each layer is
     the same logical page, so one table drives all layers' reads).
+    A Mamba-2 layer's entry is not paged: its per-slot state and
+    convolution inputs are made here, beside the pool, for ``slots``
+    slots (:func:`_cache_entries`; required where the configuration has
+    such a layer).
 
     ``block_size`` must divide ``cfg.max_seq``: a slot's table row names
     ``max_seq / block_size`` pages, and where the decode step reads by
@@ -302,8 +379,14 @@ def init_kv_pool_tp(cfg: TransformerConfig, num_blocks: int,
             f"serve: block_size={block_size} must be >= 1 and divide "
             f"max_seq={cfg.max_seq} (a slot's table row covers the "
             "dense attention extent)")
-    return _cache_entries(cfg, (num_blocks, block_size), size,
-                          lambda shape: jnp.zeros(shape, dtype))
+    if has_state(cfg) and slots < 1:
+        raise CommError(
+            "serve: a Mamba2 layer keeps a state a slot beside the pool: "
+            "init_kv_pool_tp needs slots >= 1")
+    return _cache_entries(
+        cfg, (num_blocks, block_size), size,
+        lambda shape, dt=dtype: jnp.zeros(shape, dt), slots,
+        jnp.promote_types(dtype, jnp.float32))
 
 
 def install_page_count(n_rows: int, block_size: int) -> int:
@@ -313,10 +396,14 @@ def install_page_count(n_rows: int, block_size: int) -> int:
     return (n_rows + 2 * block_size - 2) // block_size
 
 
-def install_rows_paged(pool, rows, index):
+def install_rows_paged(pool, rows, index, slot=None):
     """Write prefill K/V rows into one rank's page pool: the engine
     compiles this once per ``rows`` shape and calls it with ``pool``
-    donated, so the pages are written in place.
+    donated, so the pages are written in place.  Where a layer keeps a
+    per-slot state (:data:`STATE_LEAVES`), the same call writes the
+    prompt's, ``(1, ...)`` in ``rows``, over row ``slot`` (an int32
+    scalar) of the pool's: whole, so that nothing of the slot's last
+    request is left.
 
     ``pool`` is :func:`init_kv_pool_tp`'s tree; ``rows`` the same tree
     with ``(1, R, kv_heads/size, head_dim)`` leaves (``(1, R, 1,
@@ -351,7 +438,12 @@ def install_rows_paged(pool, rows, index):
         pages = jnp.where(written, new.reshape(old.shape), old)
         return p.at[ids].set(pages, mode="drop", unique_indices=True)
 
-    return jax.tree.map(leaf, pool, rows)
+    def state(p, r):
+        return jax.lax.dynamic_update_index_in_dim(
+            p, r[0].astype(p.dtype), slot, 0)
+
+    return [{k: (state if k in STATE_LEAVES else leaf)(p[k], r[k])
+             for k in sorted(p)} for p, r in zip(pool, rows)]
 
 
 def _tp_size(cfg: TransformerConfig, shards) -> int:
@@ -491,10 +583,12 @@ class _Latent:
 
 
 def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
-                 attend_latent, reduce, live=None, select=None):
+                 attend_latent, reduce, live=None, select=None, scan=None):
     """The serving transformer block, once, for every serving program:
     per layer ``ln1`` → mixer → ``reduce`` → residual → ``ln2`` → FFN →
-    ``reduce`` → residual; then ``ln_f``.  Returns ``(x, entries,
+    ``reduce`` → residual (a layer of one part, ``LayerSpec.only``: its
+    own norm, part, ``reduce`` and residual, and nothing of the other);
+    then ``ln_f``.  Returns ``(x, entries,
     counts)``: the normed hidden rows, what the view handed back for
     each layer, and what the expert layers counted (``{}`` without
     one): ``moe_rows``, the rows each held expert took in every expert
@@ -507,7 +601,11 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
     ``dsa_rows_live``, the latent rows under the live slots' frontiers
     summed over the indexed layers, ``dsa_rows_read``, the rows their
     selections name (what attention reads of them), and
-    ``dsa_rows_scored``, the index keys the scoring layers scored.
+    ``dsa_rows_scored``, the index keys the scoring layers scored; and
+    from a decode step with Mamba-2 layers ``ssm_states_live``, the
+    (live slot, layer) pairs whose kept state the step had to advance,
+    and ``ssm_states_touched``, those it read and wrote (every slot's:
+    the update runs over the whole slot table).
 
     ``x`` is the embedded input, ``(b, s, d)`` with ``positions`` ``(s,)``
     (a prefill) or ``(slots, d)`` with one position a slot (a decode
@@ -537,12 +635,22 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
       keys' new cache entry.  The selection is CARRIED along the walk:
       the layers above with ``MLA.index="shared"`` attend it as it is,
       until the next scoring layer replaces it;
+    * ``scan(layer, spec, p, xBC, dt) -> (y, entry)`` is the view of a
+      Mamba-2 layer's kept state: it runs the convolution and the
+      recurrence (``transformer.mamba2_scan``) from where the layer's
+      sequences stand — a prefill from nothing, over the whole prompt,
+      under ``layer_scope("ssm_scan")``; a decode step from each slot's
+      entry, one token on, under ``layer_scope("ssm_update")`` — and
+      hands back the recurrence's output and the entry as the pass
+      leaves it (a prefill's: what the install writes over the slot's);
     * ``reduce(partial, site, nsites)`` sums a row-parallel partial
       product over the TP ranks; the sites are counted here, two a layer.
 
     A layer is what its :class:`~mpi4torch_tpu.models.transformer.
     LayerSpec` names (``cfg.layer_specs``; without a spec every layer is
-    the configuration's own): the mixer the configuration's attention or
+    the configuration's own): the mixer the configuration's attention, a
+    Mamba-2 mixer whole under ``layer_scope("ssm")`` (``mamba2_project``,
+    the view's ``scan``, ``mamba2_out``) or
     latent attention under ``layer_scope("mla")``, through the
     projections the training forward uses (``mla_project``), a scoring
     layer's indexer (projections, cache write, scoring and top-k) under
@@ -565,7 +673,7 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
     seq = (lambda a: a[:, None]) if x.ndim == 2 else (lambda a: a)
     entries, moe_rows, zero_pairs, live_pairs = [], [], [], []
     carried = selected = None
-    dsa = {}
+    dsa, ssm = {}, {}
 
     def dsa_counted(spec, selected):
         # A decode step's rows: held under the frontiers, named by the
@@ -587,14 +695,47 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
                 x.size // x.shape[-1] if live is None
                 else jnp.sum(live, dtype=jnp.int32)))
 
+    def ffn_part(spec, blk, y):
+        # The FFN of a layer on its ln2 rows ``y``, before the reduction
+        # and the residual.
+        if spec.ffn is None:
+            return branch_norm(cfg, spec, blk,
+                               dense_ffn(cfg, spec, blk, y), "ln2_post")
+        with layer_scope("moe"):
+            ff, taken, zero = _held_experts_in_pieces(
+                y.reshape(-1, y.shape[-1]), blk["experts"], spec.ffn,
+                live)
+            ff = branch_norm(cfg, spec, blk, ff.reshape(y.shape),
+                             "ln2_post")
+        counted(spec.ffn, taken, zero)
+        return ff
+
     for layer, (spec, blk) in enumerate(zip(cfg.layer_specs,
                                             shards["blocks"])):
+        if spec.only == "ffn":
+            entries.append({})
+            x = x + reduce(ffn_part(spec, blk, _norm(cfg, x, blk["ln2"])),
+                           2 * layer + 1, nsites).astype(x.dtype)
+            continue
         y = _norm(cfg, x, blk["ln1"])
         if spec.mixer is None:
             q, k, v = _split_qkv(cfg, blk, seq(y), seq(positions), size)
             o, entry = attend(layer, q, k, v)
             o_part = o.reshape(*x.shape[:-1], -1).astype(x.dtype) \
                 @ blk["wo"]
+        elif isinstance(spec.mixer, Mamba2):
+            with layer_scope("ssm"):
+                z, xBC, dt = mamba2_project(spec.mixer, blk["mixer"], seq(y))
+                o, entry = scan(layer, spec.mixer, blk["mixer"], xBC, dt)
+                o_part = mamba2_out(spec.mixer, blk["mixer"], o,
+                                    z).reshape(x.shape)
+            if x.ndim == 2:
+                slots = x.shape[0]
+                ssm["ssm_states_live"] = ssm.get("ssm_states_live", 0) + (
+                    slots if live is None
+                    else jnp.sum(live, dtype=jnp.int32))
+                ssm["ssm_states_touched"] = ssm.get(
+                    "ssm_states_touched", 0) + slots
         else:
             with layer_scope("mla"):
                 lat = _Latent(spec.mixer, blk["mixer"])
@@ -622,26 +763,18 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
                 dsa_counted(spec.mixer, selected)
         entries.append(entry)
         x = x + reduce(o_part, 2 * layer, nsites).astype(x.dtype)
+        if spec.only == "mixer":
+            continue
         y = _norm(cfg, x, blk["ln2"])
         if spec.branch is not None:
             carried, taken, zero = shortcut_branch(spec, blk, y, live=live)
             counted(spec.branch, taken, zero)
-        if spec.ffn is None:
-            ff = branch_norm(cfg, spec, blk, dense_ffn(cfg, spec, blk, y),
-                             "ln2_post")
-        else:
-            with layer_scope("moe"):
-                ff, taken, zero = _held_experts_in_pieces(
-                    y.reshape(-1, y.shape[-1]), blk["experts"], spec.ffn,
-                    live)
-                ff = branch_norm(cfg, spec, blk, ff.reshape(y.shape),
-                                 "ln2_post")
-            counted(spec.ffn, taken, zero)
-        x = x + reduce(ff, 2 * layer + 1, nsites).astype(x.dtype)
+        x = x + reduce(ffn_part(spec, blk, y), 2 * layer + 1,
+                       nsites).astype(x.dtype)
         if spec.join:
             x, carried = x + carried.astype(x.dtype), None
     x, counts = _norm(cfg, x, shards["ln_f"]), {
-        k: jnp.asarray(v, jnp.int32) for k, v in dsa.items()}
+        k: jnp.asarray(v, jnp.int32) for k, v in {**dsa, **ssm}.items()}
     if moe_rows:
         # (expert layers, held); of a prompt whose expert layers ran in
         # pieces, (pieces, expert layers, held).
@@ -677,6 +810,22 @@ def _held_experts_in_pieces(x, params, spec, live):
         for at in range(0, T, _EXPERT_ROWS)]
     y, taken, zero = zip(*pieces)
     return jnp.concatenate(y), jnp.stack(taken), sum(zero)
+
+
+def _state_view(cache, scope: str, carried: bool):
+    """The ``scan`` callable of a view over the Mamba-2 layers' entries
+    of ``cache``, under ``layer_scope(scope)``: from each sequence's
+    kept entry where ``carried`` (a decode step: one token on), from
+    nothing otherwise (a prefill: the whole prompt).  The entry handed
+    back has the cache's types."""
+    def scan(layer, spec, p, xBC, dt):
+        kept = cache[layer]
+        with layer_scope(scope):
+            y, entry = mamba2_scan(spec, p, xBC, dt,
+                                   kept if carried else None)
+        return y, {k: entry[k].astype(kept[k].dtype) for k in kept}
+
+    return scan
 
 
 def _hand_out(stats, counts):
@@ -742,7 +891,7 @@ def prefill_tp(cfg: TransformerConfig, shards, cache, prompt, comm=None,
     configuration has an expert layer)."""
     p_len = prompt.shape[1]
     x = shards["embed"][prompt]
-    if not cfg.rope:
+    if cfg.pos_table:
         x = x + shards["pos"][None, :p_len]
     positions = jnp.arange(p_len, dtype=jnp.int32)
 
@@ -777,7 +926,8 @@ def prefill_tp(cfg: TransformerConfig, shards, cache, prompt, comm=None,
     with serve_step_scope("prefill"):
         x, new_cache, counts = _walk_layers(
             cfg, shards, x, positions, attend, attend_latent,
-            _prefill_reduce(comm), select=select)
+            _prefill_reduce(comm), select=select,
+            scan=_state_view(cache, "ssm_scan", carried=False))
         _hand_out(stats, counts)
         return x[:, -1] @ shards["unembed"], new_cache
 
@@ -808,10 +958,11 @@ def prefill_chunk_tp(cfg: TransformerConfig, shards, past, chunk,
 
     Collectives are the blocking prefill path (compute-bound phase,
     outside the decode exposure census), one per row-parallel half."""
+    validate_tp(cfg, _tp_size(cfg, shards), prefill_chunk=chunk.shape[1])
     c_len = chunk.shape[1]
     p_len = int(jax.tree.leaves(past[0])[0].shape[1])
     x = shards["embed"][chunk]
-    if not cfg.rope:
+    if cfg.pos_table:
         x = x + shards["pos"][None, p_len:p_len + c_len]
     positions = jnp.arange(p_len, p_len + c_len, dtype=jnp.int32)
 
@@ -938,11 +1089,11 @@ def decode_step_tp(cfg: TransformerConfig, shards, cache, tokens, pos,
 
     with serve_step_scope("decode_step"):
         x = shards["embed"][tokens]
-        if not cfg.rope:
+        if cfg.pos_table:
             x = x + jnp.take(shards["pos"], pos, axis=0)
         x, new_cache, counts = _walk_layers(
             cfg, shards, x, pos, attend, attend_latent, reduce, live,
-            select)
+            select, _state_view(cache, "ssm_update", carried=True))
         _hand_out(stats, counts)
         return x @ shards["unembed"], new_cache
 
@@ -1002,7 +1153,7 @@ def decode_step_paged(cfg: TransformerConfig, shards, pool, table,
     before the wire (the dense step's rule)."""
     pos = jnp.asarray(pos, jnp.int32)
     table = jnp.asarray(table, jnp.int32)
-    bs = jax.tree.leaves(pool[0])[0].shape[1]
+    bs = first_paged_leaf(pool).shape[1]
     live = _live_rows(active)
     reduce = _decode_reduce(comm, live, overlap, algorithm)
     write = _block_scatter_donated if donate else block_scatter
@@ -1052,11 +1203,11 @@ def decode_step_paged(cfg: TransformerConfig, shards, pool, table,
 
     with serve_step_scope("decode_step"):
         x = shards["embed"][tokens]
-        if not cfg.rope:
+        if cfg.pos_table:
             x = x + jnp.take(shards["pos"], pos, axis=0)
         x, new_pool, counts = _walk_layers(
             cfg, shards, x, pos, attend, attend_latent, reduce, live,
-            select)
+            select, _state_view(pool, "ssm_update", carried=True))
         _hand_out(stats, counts)
         return x @ shards["unembed"], new_pool
 

@@ -61,6 +61,8 @@ _STEP_COUNTS = (("admitted", "admitted"),
                 ("dsa_rows_live", "dsa_rows_live"),
                 ("dsa_rows_read", "dsa_rows_read"),
                 ("dsa_rows_scored", "dsa_rows_scored"),
+                ("ssm_states_live", "ssm_states_live"),
+                ("ssm_states_touched", "ssm_states_touched"),
                 ("decode_uploads", "decode_uploads"),
                 ("step_compiles", "step_compiles"),
                 ("occupancy_ticks", "active"))
@@ -131,7 +133,9 @@ def serve_step_scope(what: str = "decode_step"):
 # compiled step all carry the name in their ``op_name``.
 LAYER_SCOPES = {"kda": "mpi4torch.kda", "mla": "mpi4torch.mla",
                 "moe": "mpi4torch.moe", "ffn": "mpi4torch.ffn",
-                "dsa": "mpi4torch.dsa"}
+                "dsa": "mpi4torch.dsa", "ssm": "mpi4torch.ssm",
+                "ssm_scan": "mpi4torch.ssm_scan",
+                "ssm_update": "mpi4torch.ssm_update"}
 
 
 def layer_scope(kind: str):
@@ -140,10 +144,15 @@ def layer_scope(kind: str):
     (the latent-attention mixer), ``moe`` (router, grouped expert
     products, shared and zero-compute experts), ``ffn`` (the dense FFN
     of a layer that carries or joins a shortcut branch, and of no other
-    layer: the path the branch runs beside) or ``dsa`` (a scoring
+    layer: the path the branch runs beside), ``dsa`` (a scoring
     layer's indexer: its projections, the index-key cache write, the
     scoring and the top-k; the read of the selected rows is the latent
-    read and stays under ``mla``)."""
+    read and stays under ``mla``) or ``ssm`` (a Mamba-2 mixer, whole:
+    projections, convolution, recurrence, gated norm), with one of two
+    more INSIDE it around the convolution and the recurrence:
+    ``ssm_scan`` where a prefill runs them over a prompt (the chunked
+    scan), ``ssm_update`` where a decode step advances every slot's
+    kept state by one token."""
     return _labeled_scope(LAYER_SCOPES[kind])
 
 
@@ -241,6 +250,10 @@ class ServeStats:
                  # them: min(pos + 1, top_k) a slot and layer), and the
                  # index keys the scoring layers scored.
                  "dsa_rows_live", "dsa_rows_read", "dsa_rows_scored",
+                 # ISSUE 41: per decode step with Mamba-2 layers, the
+                 # (live slot, layer) pairs whose kept state the step had
+                 # to advance, and those it read and wrote.
+                 "ssm_states_live", "ssm_states_touched",
                  # ISSUE 36: host-to-device transfers the decode steps'
                  # ``decode.dispatch.inputs`` made (table, tokens,
                  # positions, live mask, and the keys where the engine
@@ -455,7 +468,7 @@ def serve_step_log() -> list:
     "decode_pages_read", "decode_grid_steps", "decode_select_syncs",
     "moe_zero_pairs",
     "moe_live_pairs", "dsa_rows_live", "dsa_rows_read", "dsa_rows_scored",
-    "decode_uploads", "step_compiles", "active"}`` on
+    "ssm_states_live", "ssm_states_touched", "decode_uploads", "step_compiles", "active"}`` on
     the ``time.perf_counter_ns()`` clock, the last :data:`STEP_LOG_CAP`
     steps (and ``moe_rows`` / ``compiles`` where :meth:`ServeStats.attach`
     put them).  ``engine`` is the ``ServeStats.engine`` serial of the

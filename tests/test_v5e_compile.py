@@ -18,6 +18,7 @@ import pytest
 from mpi4torch_tpu.models import transformer as T
 from mpi4torch_tpu.ops import flash
 from mpi4torch_tpu.ops import paged_attention as pa
+from mpi4torch_tpu.ops import ssd
 
 F32 = jnp.float32
 
@@ -47,6 +48,7 @@ def as_on_tpu(monkeypatch):
     otherwise, here in the test and by no option of the program."""
     monkeypatch.setattr(flash, "_on_tpu", lambda: True)
     monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ssd, "_on_tpu", lambda: True)
 
 
 def test_mla_blocks_compile_for_the_v5e_with_all_three_kernels(
@@ -316,7 +318,8 @@ def _compiled_decode_step(tcfg, mesh, params, stacked, like, slots, bs, nb):
     from mpi4torch_tpu.serve.engine import select_rows
 
     pool = jax.eval_shape(
-        lambda: kv.init_kv_pool_tp(tcfg, nb, bs, 1, jnp.bfloat16))
+        lambda: kv.init_kv_pool_tp(tcfg, nb, bs, 1, jnp.bfloat16,
+                                   slots=slots))
     mine = lambda tree: jax.tree.map(lambda a: a[0], tree)
 
     def step(shards, pool, table, tokens, pos, active):
@@ -523,6 +526,99 @@ def test_glms_programs_compile_at_their_real_size(
               f"{pre.output_size_in_bytes / 1e9:.3f} GB")
     held = 2 * count + pool_bytes
     assert held + pre.temp_size_in_bytes + pre.output_size_in_bytes < 15.7e9
+
+
+def _compiled_install(tcfg, mesh, stacked, like, slots, bs, nb, n):
+    """The install of an ``n``-token prompt's rows (and, where a layer
+    keeps one, its state into the slot's row), pool donated, as the
+    engine compiles it."""
+    from jax.sharding import PartitionSpec as P
+
+    from mpi4torch_tpu.serve import kv
+
+    pool = jax.eval_shape(lambda: kv.init_kv_pool_tp(
+        tcfg, nb, bs, 1, jnp.bfloat16, slots=slots))
+    rows = jax.eval_shape(
+        lambda: kv.init_kv_cache_tp(tcfg, 1, 1, jnp.bfloat16))
+    rows = [{k: a if k in kv.STATE_LEAVES else jax.ShapeDtypeStruct(
+        (1, n) + a.shape[2:], a.dtype) for k, a in e.items()} for e in rows]
+
+    def per_rank(pool, rows, index, slot):
+        pool, rows = jax.tree.map(lambda a: a[0], (pool, rows))
+        return jax.tree.map(lambda a: a[None],
+                            kv.install_rows_paged(pool, rows, index, slot))
+
+    with jax.enable_x64(False):
+        return jax.jit(
+            jax.shard_map(per_rank, mesh=mesh,
+                          in_specs=(P("mpi"), P("mpi"), P(), P()),
+                          out_specs=P("mpi"), check_vma=False),
+            donate_argnums=0).lower(
+                stacked(pool), stacked(rows),
+                like((2 + kv.install_page_count(n, bs),), jnp.int32),
+                like((), jnp.int32)).compile()
+
+
+def test_nemotrons_programs_compile_at_their_real_size(
+        one_v5e_chip, as_on_tpu, capsys):
+    """Nemotron-3-Super as `serve_ssm_chat` serves it (4.65 B parameters:
+    five Mamba-2, five latent-expert layers and one attention layer; 128
+    slots' 2.68 GB of float32 state and 39 MB of convolution inputs
+    beside a 0.27 GB K/V pool), from shapes alone.  The decode step:
+    every leaf of pool and state aliased to its output (a second copy of
+    the state would not fit), the attention layer's read through the
+    paged kernel, two grouped products for each expert layer, the three
+    scopes in the text.  The 256-, 512- and 1,024-token prefills and
+    the install compile, the install with the state written in place,
+    and the largest temporaries fit beside weights, state and pool on a
+    16 GB chip.
+    The programs' temporaries are printed."""
+    from benchmarks.families import nemotron_h as fam
+
+    cfg, tcfg, mesh, params, count, stacked, like = _real_size(
+        "nemotron-3-super-120b-a12b", fam, one_v5e_chip)
+    assert count == 4_648_163_712
+    slots, bs, nb = 128, 128, 2048
+    n_m = cfg["hybrid_override_pattern"].count("M")
+    n_e = cfg["hybrid_override_pattern"].count("E")
+    state = slots * n_m * 128 * 64 * 128 * 4
+    tails = slots * n_m * 3 * 10240 * 2
+    pool = nb * bs * 2 * 2 * 128 * 2
+    assert (state, tails, pool) == (2_684_354_560, 39_321_600, 268_435_456)
+    compiled = _compiled_decode_step(tcfg, mesh, params, stacked, like,
+                                     slots, bs, nb)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == state + tails + pool
+    assert mem.temp_size_in_bytes < 1.0e9
+    assert len(_names(text, pa.KERNEL_NAMES[0])) == 1
+    assert len(_names(text, "ragged-dot-none")) == 2 * n_e
+    for scope in ("mpi4torch.ssm/", "mpi4torch.ssm_update", "mpi4torch.moe"):
+        assert scope in text, scope
+    assert "mpi4torch.ssm_scan" not in text
+    held = 2 * count + state + tails + pool
+    pre = {}
+    for n in (256, 512, 1024):
+        c = _compiled_prefill(tcfg, mesh, params, like, n)
+        assert "mpi4torch.ssm_scan" in c.as_text()
+        pre[n] = c.memory_analysis()
+        assert held + pre[n].temp_size_in_bytes \
+            + pre[n].output_size_in_bytes < 15.7e9
+    inst = _compiled_install(tcfg, mesh, stacked, like, slots, bs, nb,
+                             1024).memory_analysis()
+    assert inst.alias_size_in_bytes == state + tails + pool
+    # The state is written in place.  A K/V pool of 2 KV heads is copied
+    # (the scatter wants another tiling of its pages than it arrives
+    # in; one of 8 KV heads is not: PERF.md section 6, PR 41).
+    assert inst.temp_size_in_bytes < pool + 0.01e9
+    with capsys.disabled():
+        print(f"\nnemotron-3-super: {count:,} parameters, "
+              f"{held / 1e9:.2f} GB held; decode step temporaries "
+              f"{mem.temp_size_in_bytes / 1e9:.3f} GB; prefill "
+              "temporaries + outputs " + ", ".join(
+                  f"{n}: {(m.temp_size_in_bytes + m.output_size_in_bytes) / 1e9:.3f} GB"
+                  for n, m in pre.items())
+              + f"; install temporaries {inst.temp_size_in_bytes / 1e9:.3f}"
+              " GB")
 
 
 def test_dp_step_compiles_to_all_reduces_alone(v5e_2x2):
