@@ -988,16 +988,16 @@ def dense_ffn(cfg: TransformerConfig, spec: LayerSpec, blk, y):
 
 def shortcut_branch(spec: LayerSpec, blk, y, comm_ep=None, live=None):
     """The shortcut branch of a layer that carries one, on the layer's
-    ``ln2`` rows ``y``: ``(s, rows, zero_pairs)`` with ``s`` of ``y``'s
-    shape, to be added to the stream by the layer whose spec says
+    ``ln2`` rows ``y``: ``(s, rows, zero_pairs, overflow)`` with ``s`` of
+    ``y``'s shape, to be added to the stream by the layer whose spec says
     ``join``, and :func:`~mpi4torch_tpu.parallel.moe.held_experts_ffn`'s
-    two counts.  The training forward and the serving walk both come
+    three counts.  The training forward and the serving walk both come
     through here."""
     with layer_scope("moe"):
-        s, rows, zero_pairs = held_experts_ffn(
+        s, *counts = held_experts_ffn(
             y.reshape(-1, y.shape[-1]), blk["branch"], spec.branch,
             comm_ep, live=live)
-    return s.reshape(y.shape), rows, zero_pairs
+    return (s.reshape(y.shape), *counts)
 
 
 def _ffn_residual(cfg: TransformerConfig, blk, x, comm_ep):
@@ -1094,9 +1094,12 @@ def forward(cfg: TransformerConfig, params, tokens, comm_sp=None,
 
 def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
              comm_ep, return_hidden: bool):
-    """:func:`forward` as ``(out, aux, rows)``: the summed load-balancing
-    loss, and the rows each held expert took in every expert layer of a
-    spec, ``(expert layers, held)`` (``None`` without such a layer)."""
+    """:func:`forward` as ``(out, aux, stats)``: the summed load-balancing
+    loss, and what the expert layers of a spec counted (``None`` without
+    such a layer): ``moe_rows``, the rows each held expert took in every
+    expert layer, ``(expert layers, held)``, and ``moe_overflow_calls``,
+    how many of those layers had held rows behind their prefix
+    (:func:`~mpi4torch_tpu.parallel.moe.held_experts_ffn`)."""
     b, s_local = tokens.shape
     h = cfg.n_heads
     if comm_sp is not None and comm_sp.size > 1:
@@ -1174,10 +1177,10 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
     def experts_fn(spec, x, blk):
         with layer_scope("moe"):
             y = _norm(cfg, x, blk["ln2"])
-            ff, taken, _ = held_experts_ffn(
+            ff, taken, _, over = held_experts_ffn(
                 y.reshape(-1, d), blk["experts"], spec.ffn, comm_ep)
             ff = branch_norm(cfg, spec, blk, ff.reshape(x.shape), "ln2_post")
-        return x + ff, taken
+        return x + ff, [(taken, over)]
 
     def shortcut_fn(spec, x, blk, carried):
         # A layer that carries or joins a shortcut branch: the branch
@@ -1186,16 +1189,16 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
         y = _norm(cfg, x, blk["ln2"])
         taken = []
         if spec.branch is not None:
-            carried, rows, _ = shortcut_branch(spec, blk, y, comm_ep)
-            taken.append(rows)
+            carried, rows, _, over = shortcut_branch(spec, blk, y, comm_ep)
+            taken.append((rows, over))
         if spec.ffn is None:
             ff = dense_ffn(cfg, spec, blk, y)
         else:
             with layer_scope("moe"):
-                ff, rows, _ = held_experts_ffn(
+                ff, rows, _, over = held_experts_ffn(
                     y.reshape(-1, d), blk["experts"], spec.ffn, comm_ep)
                 ff = ff.reshape(x.shape)
-            taken.append(rows)
+            taken.append((rows, over))
         x = x + branch_norm(cfg, spec, blk, ff, "ln2_post")
         if spec.join:
             x, carried = x + carried, None
@@ -1206,7 +1209,7 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
     # the temporaries of one of them at a time, not of both.
     remat = functools.partial(jax.checkpoint, policy=_SAVED_IN_REMAT) \
         if cfg.remat else (lambda f: f)
-    rows, carried = [], None
+    counted, carried = [], None
     for spec, blk in zip(cfg.layer_specs, params["blocks"]):
         if spec.mixer is None and spec.ffn is None and not spec.only:
             x, aux = (jax.checkpoint(block_fn) if cfg.remat
@@ -1220,7 +1223,7 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
         if spec.shortcut:
             x, carried, taken = remat(functools.partial(shortcut_fn, spec))(
                 x, blk, carried)
-            rows += taken
+            counted += taken
         elif spec.ffn is None and spec.post_norm:
             x = remat(lambda x_, blk_: x_ + branch_norm(
                 cfg, spec, blk_, _ffn_dense(
@@ -1231,13 +1234,17 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
                 cfg, blk_, x_, comm_ep))(x, blk)
         else:
             x, taken = remat(functools.partial(experts_fn, spec))(x, blk)
-            rows.append(taken)
+            counted += taken
     x = _norm(cfg, x, params["ln_f"])
     if return_hidden:
         out = x
     else:
         out = x @ params["unembed"]
-    return out, aux_total, jnp.stack(rows) if rows else None
+    if not counted:
+        return out, aux_total, None
+    rows, over = zip(*counted)
+    return out, aux_total, {"moe_rows": jnp.stack(rows),
+                            "moe_overflow_calls": sum(over)}
 
 
 def init_kv_cache(cfg: TransformerConfig, batch: int, dtype=jnp.float32):
@@ -1514,7 +1521,7 @@ def lm_loss(cfg: TransformerConfig, params, tokens, comm_sp=None,
 
 def _lm_loss(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
              seq_global, comm_ep, vocab_chunk: int):
-    """:func:`lm_loss` as ``(loss, rows)``, with :func:`_forward`'s row
+    """:func:`lm_loss` as ``(loss, stats)``, with :func:`_forward`'s
     counts."""
     b, s_local = tokens.shape
     sp = comm_sp.size if comm_sp is not None else 1
@@ -1525,8 +1532,8 @@ def _lm_loss(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
             f"vocab_chunk={vocab_chunk} must divide vocab={cfg.vocab}")
 
     want_hidden = bool(vocab_chunk) and vocab_chunk < cfg.vocab
-    out, aux, rows = _forward(cfg, params, tokens, comm_sp, attn, comm_ep,
-                              want_hidden)
+    out, aux, stats = _forward(cfg, params, tokens, comm_sp, attn, comm_ep,
+                               want_hidden)
     if cfg.n_experts == 0:
         aux = None
 
@@ -1580,7 +1587,7 @@ def _lm_loss(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
             # lock-step invariant every collective loss must keep).
             aux = comm_sp.Allreduce(aux, MPI_SUM, compression=False) / sp
         loss = loss + cfg.aux_coef * aux
-    return loss, rows
+    return loss, stats
 
 
 def zero_train_step(cfg: TransformerConfig, params, tokens, opt,
@@ -1659,8 +1666,11 @@ def train_step(cfg: TransformerConfig, params, tokens, comm_sp=None,
                comm_ep=None, return_stats: bool = False):
     """One SGD step; returns (loss, new_params), and with
     ``return_stats`` (loss, new_params, stats): this step's routing
-    counters, ``{"moe_rows": (expert layers, held)}``, the rows each held
-    expert of a per-layer spec took (empty without an expert layer).
+    counters, ``{"moe_rows": (expert layers, held), "moe_overflow_calls":
+    int32}``, the rows each held expert of a per-layer spec took and how
+    many of the expert layers had held rows behind their prefix
+    (:func:`~mpi4torch_tpu.parallel.moe.held_experts_ffn`; empty without
+    an expert layer).
 
     DP follows the reference recipe exactly (parameter-averaging Allreduce
     + loss Allreduce over the dp axis) so replicas stay in lock-step.  The
@@ -1689,16 +1699,17 @@ def train_step(cfg: TransformerConfig, params, tokens, comm_sp=None,
             p = all_average_tree(comm_sp, p)
         if comm_ep is not None and comm_ep.size > 1:
             p = all_average_tree(comm_ep, p)
-        loss, rows = _lm_loss(cfg, p, tokens, comm_sp, attn, None, comm_ep, 0)
+        loss, stats = _lm_loss(cfg, p, tokens, comm_sp, attn, None, comm_ep,
+                               0)
         if comm_dp is not None and comm_dp.size > 1:
             loss = comm_dp.Allreduce(loss, MPI_SUM, compression=False) / comm_dp.size
         if comm_ep is not None and comm_ep.size > 1:
             loss = comm_ep.Allreduce(loss, MPI_SUM, compression=False) / comm_ep.size
-        return loss, rows
+        return loss, stats
 
-    (loss, rows), grads = jax.value_and_grad(global_loss,
-                                             has_aux=True)(params)
+    (loss, stats), grads = jax.value_and_grad(global_loss,
+                                              has_aux=True)(params)
     new_params = jax.tree.map(lambda p, g: p - lr * g, params, grads)
     if not return_stats:
         return loss, new_params
-    return loss, new_params, {} if rows is None else {"moe_rows": rows}
+    return loss, new_params, stats or {}

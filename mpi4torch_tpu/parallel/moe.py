@@ -345,6 +345,24 @@ def route_topk(x, router, bias, top_k: int, scale: float, *,
     return chosen, scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
 
 
+# A prefix of the sorted pairs is whole row tiles of the grouped product;
+# below _MIN_PAIRS pairs (a decode step's 128-2,816) the layer keeps its
+# one buffer of all of them: nothing there is worth a second body.
+_ROW_TILE = 512
+_MIN_PAIRS = 4096
+
+
+def _prefix_rows(pairs: int, spec: Experts) -> int:
+    """``B``: the rows of the sorted pairs that the layer works on before
+    it asks whether any are left: twice the held experts' even share of
+    ``pairs`` in whole row tiles, ``pairs`` itself where that is no less
+    or where ``pairs`` is under :data:`_MIN_PAIRS`."""
+    if pairs < _MIN_PAIRS:
+        return pairs
+    even = -(-2 * pairs * spec.n_held // spec.n_experts)
+    return min(pairs, -(-even // _ROW_TILE) * _ROW_TILE)
+
+
 @jax.custom_vjp
 def _permute_rows(x, perm, inverse):
     """``x[perm]`` for a permutation: the adjoint is the gather by the
@@ -382,6 +400,88 @@ _pair_rows.defvjp(
     _pair_rows_bwd)
 
 
+# The way back reads its table in column pieces of at most this many
+# bytes.  XLA's row gather on a v5e turns five times slower a row once
+# its table passes some 120 MB (131,072 rows of 2,304 bf16 from a table
+# of 113 MB: 1.0 ms, of 132 MB: 5.0 ms), and a prefill's 60 MiB table
+# read 15% faster in two pieces than whole; pieces of 16 MiB read slower
+# again (PERF.md section 6, PR 42).
+_GATHER_PIECE_BYTES = 48 * 2 ** 20
+
+
+def _column_pieces(table):
+    """``table`` ``(rows, d)`` as column slices of whole lane tiles, each
+    of at most :data:`_GATHER_PIECE_BYTES` (itself where it is no more)."""
+    rows, d = table.shape
+    pieces = -(-rows * d * table.dtype.itemsize // _GATHER_PIECE_BYTES)
+    step = -(-d // (pieces * 128)) * 128
+    return [table] if step >= d else [
+        table[:, at:at + step] for at in range(0, d, step)]
+
+
+def _sum_by_choice(table, at, weight=None):
+    """``(T, d)``: ``sum over j of table[at[t, j]]`` (``* weight[t, j]``,
+    in ``weight``'s type), a pair whose ``at`` ``(T, k)`` names no row of
+    the table adding nothing.  The rows are gathered choice-major,
+    ``(k, T, d)``, so that the sum runs down the major axis where ``(T,
+    k, d)`` would first be laid out anew (eight rows to a tile of
+    sixteen)."""
+    rows, (T, k) = table.shape[0], at.shape
+    at = at.T.reshape(-1)
+    inside = ((at >= 0) & (at < rows))[:, None]
+    at = jnp.clip(at, 0, rows - 1)
+    sums = []
+    for piece in _column_pieces(table):
+        got = jnp.where(inside, piece[at], 0).reshape(k, T, -1)
+        sums.append(
+            jnp.sum(got, axis=0, dtype=table.dtype) if weight is None else
+            jnp.sum(got.astype(weight.dtype) * weight.T[..., None], axis=0))
+    return jnp.concatenate(sums, axis=-1)
+
+
+@jax.custom_vjp
+def _part_rows(x, order, at, keep):
+    """:func:`_pair_rows` for a part of the sorted pairs: ``order``, the
+    part's pairs; ``at`` ``(T, k)``, each pair's row of the part (outside
+    ``[0, len(order))`` for a pair of another part).  The adjoint reads
+    the part's cotangents as a table at ``at``: as many rows as the part
+    has, not ``T * k``."""
+    return jnp.where(keep, x[order // at.shape[1]], 0)
+
+
+_part_rows.defvjp(
+    lambda x, order, at, keep: (_part_rows(x, order, at, keep), (at, keep)),
+    lambda res, g: (_sum_by_choice(jnp.where(res[1], g, 0), res[0]),
+                    None, None, None))
+
+
+@jax.custom_vjp
+def _weighted_back(ys, weight, order, at):
+    """A part's rows ``ys`` back at their tokens: ``(T, d)`` in
+    ``weight``'s type, ``sum over j of weight[t, j] * ys[at[t, j]]``
+    over the pairs that lie in the part.  The adjoint gathers
+    ``len(order)`` rows of the cotangent (a row's token is ``order[r] //
+    k``) and never makes the ``(T * k, d)`` cotangent of the rows."""
+    return _sum_by_choice(ys, at, weight)
+
+
+def _weighted_back_bwd(res, g):
+    ys, weight, order, at = res
+    g_rows = g[order // weight.shape[1]]
+    g_ys = (g_rows * weight.reshape(-1)[order][:, None]).astype(ys.dtype)
+    g_weight = jnp.sum(g_rows * ys.astype(g.dtype), axis=-1)
+    inside = (at >= 0) & (at < ys.shape[0])
+    g_weight = jnp.where(inside, g_weight[jnp.clip(at, 0, ys.shape[0] - 1)],
+                         0)
+    return g_ys, g_weight.astype(weight.dtype), None, None
+
+
+_weighted_back.defvjp(
+    lambda ys, weight, order, at: (_weighted_back(ys, weight, order, at),
+                                   (ys, weight, order, at)),
+    _weighted_back_bwd)
+
+
 def _swiglu(x, w1, w2, dot):
     gate, up = jnp.split(dot(x, w1), 2, axis=-1)
     return dot(jax.nn.silu(gate) * up, w2)
@@ -392,6 +492,85 @@ def _relu2(x, w1, w2, dot):
 
 
 _EXPERT = {"swiglu": _swiglu, "relu2": _relu2}
+
+
+def _sorted_part(act, lo, n, x, w1, w2, weight, order, inverse, kept, rows):
+    """The ``n`` sorted pairs from row ``lo`` on (``lo`` may be traced)
+    through the held experts and back at their tokens: ``(T, d)`` in
+    ``weight``'s type, ``sum over a token's pairs in this part of weight
+    * E(x)``.  ``rows``: the held experts' group sizes over all the
+    sorted pairs, of which this part's products take what lies in it;
+    ``kept`` ``(pairs, 1)`` marks the sorted rows that are a held
+    expert's.  Every buffer has ``n`` rows."""
+    ends = jnp.cumsum(rows)
+    sizes = jnp.clip(ends, lo, lo + n) - jnp.clip(ends - rows, lo, lo + n)
+    order = jax.lax.dynamic_slice_in_dim(order, lo, n)
+    keep = jax.lax.dynamic_slice_in_dim(kept, lo, n)
+    at = inverse.reshape(weight.shape) - lo
+    xs = _part_rows(x, order, at, keep)
+    grouped = lambda a, w: jax.lax.ragged_dot(a, w, sizes)
+    ys = jnp.where(keep, _EXPERT[act](xs, w1, w2, grouped), 0)
+    return _weighted_back(ys, weight, order, at)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _prefix_then_rest(act, prefix, diff, *where):
+    """:func:`_sorted_part` of all the sorted pairs, ``diff = (x, w1,
+    w2, weight)`` and ``where = (order, inverse, kept, rows)``, the
+    first ``prefix`` rows always and the rows behind them only where a
+    held expert's row lies there.
+
+    Where nothing is differentiated (a serving program) that is ONE body
+    in a loop, ``prefix`` rows at a time while a held row is left: one
+    turn unless the held rows overflow the prefix, and a program with
+    many calls (a long prompt's pieces) holds no second pair of grouped
+    products a call, whose code a start-up would load.  Under a gradient
+    the prefix is taken by itself and the rest whole under one
+    ``jax.lax.cond``, forward and backward, so that the backward takes
+    the prefix's products from what the forward kept and the branch not
+    taken costs nothing: the rest's cotangents are added to the prefix's
+    inside the condition, not handed out as arrays of zeros to be added
+    to them."""
+    order, inverse, kept, rows = where
+    pad = -order.shape[0] % prefix
+    padded = (jnp.pad(order, (0, pad)), inverse,
+              jnp.pad(kept, ((0, pad), (0, 0))), rows)
+    held = jnp.sum(rows)
+
+    def turn(i, y):
+        return jax.lax.cond(
+            i * prefix < held, lambda y: y + _sorted_part(
+                act, i * prefix, prefix, *diff, *padded), lambda y: y, y)
+
+    x, _, _, weight = diff
+    return jax.lax.fori_loop(
+        0, (order.shape[0] + pad) // prefix, turn,
+        jnp.zeros(x.shape, weight.dtype))
+
+
+def _prefix_then_rest_fwd(act, prefix, diff, *where):
+    y, pull = jax.vjp(
+        lambda *d: _sorted_part(act, 0, prefix, *d, *where), *diff)
+    rest = where[0].shape[0] - prefix
+    y = jax.lax.cond(
+        jnp.sum(where[3]) > prefix,
+        lambda y, *d: y + _sorted_part(act, prefix, rest, *d, *where),
+        lambda y, *d: y, y, *diff)
+    return y, (pull, diff, where)
+
+
+def _prefix_then_rest_bwd(act, prefix, res, g):
+    pull, diff, where = res
+    rest = lambda *d: _sorted_part(
+        act, prefix, where[0].shape[0] - prefix, *d, *where)
+    grads = jax.lax.cond(
+        jnp.sum(where[3]) > prefix, lambda grads, *d: jax.tree.map(
+            jnp.add, grads, jax.vjp(rest, *d)[1](g)),
+        lambda grads, *d: grads, pull(g), *diff)
+    return (grads,) + (None,) * len(where)
+
+
+_prefix_then_rest.defvjp(_prefix_then_rest_fwd, _prefix_then_rest_bwd)
 
 
 def held_experts_ffn(x, params: Dict[str, Any], spec: Experts,
@@ -408,20 +587,39 @@ def held_experts_ffn(x, params: Dict[str, Any], spec: Experts,
 
     Every (token, chosen expert) pair is a row; the rows of held experts
     are sorted by expert to the front and are the groups of two grouped
-    products (``jax.lax.ragged_dot``), so no row routed to a held expert
-    is ever dropped (the buffer has all ``top_k * T`` rows, the worst
-    case); the rows behind them belong to no group, and what the
-    compiler's kernel spends on them is its own affair.
+    products (``jax.lax.ragged_dot``).  The layer works on a prefix of
+    the sorted pairs, ``B`` rows (:func:`_prefix_rows`: twice the held
+    experts' even share, ``2 * top_k * T * n_held / n_experts``, in
+    whole tiles of :data:`_ROW_TILE` rows; a Python int from shapes and
+    the spec alone): the rows gathered in, both products' row operands
+    and the mask have ``B`` rows, and the weighted sum back at the
+    tokens reads that table at all ``top_k * T`` pairs.  No row routed
+    to a held expert is ever dropped: where the held rows are more than
+    ``B`` (a router that collapsed onto the held experts) the rows
+    behind the prefix go through the same two products with the group
+    sizes less what the prefix took, under a ``jax.lax.cond`` on
+    ``sum(rows) > B`` whose other branch runs nothing, forward and
+    backward; a program that takes no gradient holds one body and runs
+    it a prefix's rows at a time while a held row is left
+    (:func:`_prefix_then_rest`).  Where ``B`` comes to ``top_k
+    * T`` (half or more of the experts held) or the pairs are fewer
+    than :data:`_MIN_PAIRS` (a decode step) there is one buffer of all
+    the pairs and no condition; the rows behind the groups belong to no
+    group and take none of the products' time (the kernel walks the
+    groups' tiles).
 
     ``live`` (``(T,)`` bool, optional) marks the tokens that are
     somebody's: the others' pairs join the rows behind the groups, take
     no expert's time and are not counted (a serving decode step's free
     slots; their ``y`` rows are the shared expert's alone).
 
-    Returns ``(y, rows, zero_pairs)``: ``rows`` ``(n_held,)``, the rows
-    each held expert took, which are the group sizes the products are
-    handed; ``zero_pairs``, the live (token, choice) pairs that chose a
-    zero-compute expert (int32; the number 0 where the spec has none).
+    Returns ``(y, rows, zero_pairs, overflow)``: ``rows`` ``(n_held,)``,
+    the rows each held expert took, which are the group sizes the
+    products are handed (cut at ``B`` between the prefix and the rest);
+    ``zero_pairs``, the live (token, choice) pairs that chose a
+    zero-compute expert (int32; the number 0 where the spec has none);
+    ``overflow``, int32, 1 where this call took the branch for the rows
+    behind the prefix (the counter ``moe_overflow_calls`` sums it).
     Inference runs the same code: a compiled prefill or decode step of
     ``mpi4torch_tpu.serve`` calls it on its rows and hands the counts
     out with the step's record."""
@@ -431,7 +629,6 @@ def held_experts_ffn(x, params: Dict[str, Any], spec: Experts,
             f"nothing; an expert-parallel communicator of size "
             f"{comm_ep.size} needs the Alltoall exchange of the top-k "
             "layer, which is not written yet")
-    T, d = x.shape
     k, held = spec.top_k, spec.n_held
     chosen, weight = route_topk(x, params["router"], params["bias"], k,
                                 spec.scale, score=spec.score,
@@ -445,16 +642,26 @@ def held_experts_ffn(x, params: Dict[str, Any], spec: Experts,
     inverse = jnp.zeros_like(order).at[order].set(
         jnp.arange(order.shape[0], dtype=order.dtype))
     rows = jnp.sum(jax.nn.one_hot(group, held, dtype=jnp.int32), axis=0)
-    is_held = (group[order] < held)[:, None]
+    kept = (group[order] < held)[:, None]
 
     expert = _EXPERT[spec.act]
-    xs = _pair_rows(x @ params["down"] if spec.latent else x, order,
-                    inverse, is_held)
-    grouped = lambda a, w: jax.lax.ragged_dot(a, w, rows)
-    ys = jnp.where(is_held, expert(xs, params["w1"], params["w2"], grouped),
-                   0)
-    ys = _permute_rows(ys, inverse, order).reshape(T, k, xs.shape[-1])
-    y = jnp.sum(ys.astype(weight.dtype) * weight[..., None], axis=1)
+    xin = x @ params["down"] if spec.latent else x
+    pairs = order.shape[0]
+    prefix = _prefix_rows(pairs, spec)
+    if prefix == pairs:
+        # One buffer of all the pairs, as before there was a prefix.
+        xs = _pair_rows(xin, order, inverse, kept)
+        grouped = lambda a, w: jax.lax.ragged_dot(a, w, rows)
+        ys = jnp.where(kept, expert(xs, params["w1"], params["w2"], grouped),
+                       0)
+        ys = _permute_rows(ys, inverse, order).reshape(-1, k, xs.shape[-1])
+        y = jnp.sum(ys.astype(weight.dtype) * weight[..., None], axis=1)
+        overflow = jnp.zeros((), jnp.int32)
+    else:
+        y = _prefix_then_rest(
+            spec.act, prefix, (xin, params["w1"], params["w2"], weight),
+            order, inverse, kept, rows)
+        overflow = (jnp.sum(rows) > prefix).astype(jnp.int32)
     zero_pairs = 0
     if spec.n_zero:
         is_zero = chosen >= spec.n_experts
@@ -469,4 +676,4 @@ def held_experts_ffn(x, params: Dict[str, Any], spec: Experts,
     if spec.n_shared:
         y = y + expert(x, params["shared_w1"], params["shared_w2"],
                        jnp.matmul)
-    return y, rows, zero_pairs
+    return y, rows, zero_pairs, overflow

@@ -68,6 +68,7 @@ MIRRORED_SERVE_COUNTERS = (
     "preempted", "blocks_in_use", "blocks_free", "blocks_cached",
     "install_writes", "decode_pages_live", "decode_pages_read",
     "decode_grid_steps", "decode_select_syncs", "moe_zero_pairs", "moe_live_pairs",
+    "moe_overflow_calls",
     "dsa_rows_live", "dsa_rows_read", "dsa_rows_scored",
     "ssm_states_live", "ssm_states_touched",
     "decode_uploads", "step_compiles",

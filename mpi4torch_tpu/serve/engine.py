@@ -939,7 +939,9 @@ class Engine:
         the record of the step that is open: ``moe_rows``, one
         ``(program, (expert layers, held) rows)`` per call of a program
         with an expert layer (one a piece where a long prompt's expert
-        layers ran in pieces), in the order of the calls; and, from a
+        layers ran in pieces), in the order of the calls, with the
+        counter ``moe_overflow_calls`` (the calls of an expert layer
+        that had held rows behind their prefix); and, from a
         program whose expert layers have zero-compute experts, the two
         counters ``moe_zero_pairs`` and ``moe_live_pairs``, and from a
         decode step with indexed latent layers ``dsa_rows_live``,
@@ -958,7 +960,8 @@ class Engine:
             rows = stats["moe_rows"]
             for piece in rows.reshape((-1,) + rows.shape[-2:]):
                 self.stats.attach("moe_rows", (program, piece))
-        for name in ("moe_zero_pairs", "moe_live_pairs", "dsa_rows_live",
+        for name in ("moe_overflow_calls", "moe_zero_pairs",
+                     "moe_live_pairs", "dsa_rows_live",
                      "dsa_rows_read", "dsa_rows_scored", "ssm_states_live",
                      "ssm_states_touched"):
             if name in stats:

@@ -594,7 +594,9 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
     one): ``moe_rows``, the rows each held expert took in every expert
     layer, ``(expert layers, held)`` (``(pieces, expert layers, held)``
     from a prompt long enough for its expert layers to run in pieces,
-    :func:`_held_experts_in_pieces`), and, where a layer has
+    :func:`_held_experts_in_pieces`), ``moe_overflow_calls``, how many
+    calls of an expert layer (a piece is one) had held rows behind
+    their prefix (``parallel.moe.held_experts_ffn``), and, where a layer has
     zero-compute experts, ``moe_zero_pairs`` and ``moe_live_pairs``, the
     live (token, choice) pairs that chose one and all of them, summed
     over those layers; and from a DECODE step with indexed layers
@@ -671,7 +673,7 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
     nsites = 2 * len(shards["blocks"])
     # The projections take sequences: a decode step's rows are of one.
     seq = (lambda a: a[:, None]) if x.ndim == 2 else (lambda a: a)
-    entries, moe_rows, zero_pairs, live_pairs = [], [], [], []
+    entries, moe_rows, zero_pairs, live_pairs, overflows = [], [], [], [], []
     carried = selected = None
     dsa, ssm = {}, {}
 
@@ -687,8 +689,9 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
                 ("dsa_rows_scored", held if spec.scores else 0)):
             dsa[name] = dsa.get(name, 0) + rows
 
-    def counted(spec, taken, zero):
+    def counted(spec, taken, zero, overflow):
         moe_rows.append(taken)
+        overflows.append(overflow)
         if spec.n_zero:
             zero_pairs.append(zero)
             live_pairs.append(spec.top_k * (
@@ -702,12 +705,12 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
             return branch_norm(cfg, spec, blk,
                                dense_ffn(cfg, spec, blk, y), "ln2_post")
         with layer_scope("moe"):
-            ff, taken, zero = _held_experts_in_pieces(
+            ff, *counts = _held_experts_in_pieces(
                 y.reshape(-1, y.shape[-1]), blk["experts"], spec.ffn,
                 live)
             ff = branch_norm(cfg, spec, blk, ff.reshape(y.shape),
                              "ln2_post")
-        counted(spec.ffn, taken, zero)
+        counted(spec.ffn, *counts)
         return ff
 
     for layer, (spec, blk) in enumerate(zip(cfg.layer_specs,
@@ -767,8 +770,8 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
             continue
         y = _norm(cfg, x, blk["ln2"])
         if spec.branch is not None:
-            carried, taken, zero = shortcut_branch(spec, blk, y, live=live)
-            counted(spec.branch, taken, zero)
+            carried, *counts = shortcut_branch(spec, blk, y, live=live)
+            counted(spec.branch, *counts)
         x = x + reduce(ffn_part(spec, blk, y), 2 * layer + 1,
                        nsites).astype(x.dtype)
         if spec.join:
@@ -779,6 +782,7 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
         # (expert layers, held); of a prompt whose expert layers ran in
         # pieces, (pieces, expert layers, held).
         counts["moe_rows"] = jnp.moveaxis(jnp.stack(moe_rows), 0, -2)
+        counts["moe_overflow_calls"] = sum(overflows)
     if zero_pairs:
         counts["moe_zero_pairs"] = sum(zero_pairs)
         counts["moe_live_pairs"] = jnp.asarray(sum(live_pairs), jnp.int32)
@@ -798,7 +802,7 @@ def _held_experts_in_pieces(x, params, spec, live):
     whole's, and the layer's buffers are a piece's.  The rows the held
     experts took come back a piece at a time then, ``(pieces, held)``:
     each piece is a call of the grouped products with group sizes of its
-    own, and is counted as one."""
+    own, and is counted as one; the other two counts are summed."""
     T, d = x.shape
     if T <= _EXPERT_ROWS:
         return held_experts_ffn(x, params, spec, live=live)
@@ -808,8 +812,8 @@ def _held_experts_in_pieces(x, params, spec, live):
         x[at:at + _EXPERT_ROWS], params, spec,
         live=None if live is None else live[at:at + _EXPERT_ROWS])
         for at in range(0, T, _EXPERT_ROWS)]
-    y, taken, zero = zip(*pieces)
-    return jnp.concatenate(y), jnp.stack(taken), sum(zero)
+    y, taken, zero, overflow = zip(*pieces)
+    return jnp.concatenate(y), jnp.stack(taken), sum(zero), sum(overflow)
 
 
 def _state_view(cache, scope: str, carried: bool):

@@ -56,6 +56,7 @@ _STEP_COUNTS = (("admitted", "admitted"),
                 ("decode_pages_read", "decode_pages_read"),
                 ("decode_grid_steps", "decode_grid_steps"),
                 ("decode_select_syncs", "decode_select_syncs"),
+                ("moe_overflow_calls", "moe_overflow_calls"),
                 ("moe_zero_pairs", "moe_zero_pairs"),
                 ("moe_live_pairs", "moe_live_pairs"),
                 ("dsa_rows_live", "dsa_rows_live"),
@@ -243,6 +244,12 @@ class ServeStats:
                  # and decode step, expert layers and live rows).  Their
                  # ratio is the share of choices that cost no expert.
                  "moe_zero_pairs", "moe_live_pairs",
+                 # ISSUE 42: calls of an expert layer (a prefill's piece
+                 # is one) whose held rows did not fit the prefix of the
+                 # sorted pairs the layer works on and took the branch
+                 # for the rows behind it
+                 # (``parallel.moe.held_experts_ffn``).
+                 "moe_overflow_calls",
                  # ISSUE 39: sparse latent attention's decode steps
                  # (``kv._hand_out``): the latent rows under the live
                  # slots' frontiers summed over the indexed layers, the
@@ -467,7 +474,8 @@ def serve_step_log() -> list:
     "prefill_tokens", "install_writes", "decode_pages_live",
     "decode_pages_read", "decode_grid_steps", "decode_select_syncs",
     "moe_zero_pairs",
-    "moe_live_pairs", "dsa_rows_live", "dsa_rows_read", "dsa_rows_scored",
+    "moe_live_pairs", "moe_overflow_calls", "dsa_rows_live",
+    "dsa_rows_read", "dsa_rows_scored",
     "ssm_states_live", "ssm_states_touched", "decode_uploads", "step_compiles", "active"}`` on
     the ``time.perf_counter_ns()`` clock, the last :data:`STEP_LOG_CAP`
     steps (and ``moe_rows`` / ``compiles`` where :meth:`ServeStats.attach`

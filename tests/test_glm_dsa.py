@@ -487,11 +487,12 @@ def test_a_long_prompts_expert_layer_runs_in_pieces_and_adds_up(monkeypatch):
         (23, CFG["hidden_size"])), F32)
     whole = moe.held_experts_ffn(x, p, spec)
     monkeypatch.setattr(kv, "_EXPERT_ROWS", 8)
-    y, rows, zero = kv._held_experts_in_pieces(x, p, spec, None)
+    y, rows, zero, over = kv._held_experts_in_pieces(x, p, spec, None)
     assert _gap(y, whole[0]) < 1e-6
     assert rows.shape == (3, spec.n_held)
     assert np.array_equal(rows.sum(0), whole[1])
     assert int(zero) == int(whole[2])
+    assert int(over) == int(whole[3]) == 0
 
 
 @pytest.mark.parametrize("spmd", [False, True])
@@ -544,7 +545,7 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
                            scale=cfg["routed_scaling_factor"])
         part = dict(blk, w1=blk["w1"][rank:rank + 1],
                     w2=blk["w2"][rank:rank + 1])
-        y, rows, _ = moe.held_experts_ffn(m[0], part, spec)
+        y, rows, *_ = moe.held_experts_ffn(m[0], part, spec)
         total = total + y
     assert _gap(total - 15 * shared[0], whole[0]) < TOL
 

@@ -195,21 +195,70 @@ def test_the_shares_add_up_to_the_uncut_layer():
     _close(total, ref.experts(PLAN, whole, x, MM, first=0, held=width))
 
 
-def test_no_row_is_dropped_under_skew():
+@pytest.mark.parametrize("buffer", ["whole", "prefix"])
+def test_no_row_is_dropped_under_skew(buffer, monkeypatch):
     """A selection bias that sends every token to held expert 0, which
     then takes half of all rows (top-2): the row counts hold every row
-    routed to a held expert and the result is still the reference's."""
+    routed to a held expert and the result is still the reference's.
+    With a prefix shorter than the buffer (the module's two constants
+    lowered: 2 of 8 experts held, so half the pairs) and both held
+    experts steered to, every pair is a held row, twice the prefix: the
+    rest goes through the condition, is counted, and outputs and
+    gradients are the reference's all the same."""
     spec = TCFG.layers[1].ffn
     p = dict(_params()["blocks"][1]["experts"])
     x = _flat(_x())
+    pairs = spec.top_k * x.shape[0]
     p["bias"] = p["bias"].at[0].set(10.0)
-    y, rows, _ = moe.held_experts_ffn(x, p, spec)
+    if buffer == "prefix":
+        monkeypatch.setattr(moe, "_MIN_PAIRS", 0)
+        monkeypatch.setattr(moe, "_ROW_TILE", 8)
+        p["bias"] = p["bias"].at[1].set(10.0)
+        assert moe._prefix_rows(pairs, spec) == pairs // 2
+    else:
+        assert moe._prefix_rows(pairs, spec) == pairs
+    y, rows, _, overflow = moe.held_experts_ffn(x, p, spec)
     rows = np.asarray(rows)
     chosen, _ = moe.route_topk(x, p["router"], p["bias"], spec.top_k,
                                spec.scale)
     assert rows[0] == x.shape[0]
     assert rows.sum() == int(jnp.sum(chosen < spec.n_held))
+    assert int(overflow) == (buffer == "prefix")
+    if buffer == "prefix":
+        assert rows.sum() == pairs
     _close(y, ref.experts(PLAN, p, x, MM))
+    out_p, grads_p = _value_and_grads(
+        lambda p_, x_: moe.held_experts_ffn(x_, p_, spec)[0], 2)(p, x)
+    out_r, grads_r = _value_and_grads(
+        lambda p_, x_: ref.experts(PLAN, p_, x_, MM), 2)(p, x)
+    _close(out_p, out_r)
+    _tree_close(grads_p, grads_r)
+    for name in ("w1", "w2", "router"):
+        assert float(jnp.linalg.norm(grads_p[0][name])) > 0
+
+
+def test_a_step_counts_the_layers_that_overflowed(monkeypatch):
+    """``train_step(return_stats=True)`` hands out ``moe_overflow_calls``
+    beside ``moe_rows``: every expert layer of a stack whose routers are
+    all steered to their held experts, none of the stack as seeded."""
+    monkeypatch.setattr(moe, "_MIN_PAIRS", 0)
+    monkeypatch.setattr(moe, "_ROW_TILE", 8)
+    step = jax.jit(lambda p, t: T.train_step(TCFG, p, t, lr=LR,
+                                             return_stats=True))
+    params = _params()
+    _, _, stats = step(params, _tokens())
+    layers = stats["moe_rows"].shape[0]
+    pairs = TCFG.layers[1].ffn.top_k * _tokens().size
+    assert layers > 0 and int(stats["moe_overflow_calls"]) == 0
+    assert int(stats["moe_rows"].sum(axis=1).max()) <= pairs // 2
+    steered = dict(params, blocks=[
+        dict(blk, experts=dict(blk["experts"], bias=blk["experts"][
+            "bias"].at[:2].set(10.0))) if "experts" in blk else blk
+        for blk in params["blocks"]])
+    loss, _, stats = step(steered, _tokens())
+    assert np.isfinite(float(loss))
+    assert int(stats["moe_overflow_calls"]) == layers
+    assert stats["moe_rows"].sum(axis=1).tolist() == [pairs] * layers
 
 
 def test_a_bfloat16_kda_state_fails_the_comparison(monkeypatch):
@@ -319,8 +368,9 @@ def test_stats_come_out_of_the_step_under_run_spmd():
 
     loss, new, stats = mpi.run_spmd(body, nranks=2)(params, tokens)
     assert float(loss[0]) == float(loss[1]) and np.isfinite(float(loss[0]))
-    assert set(stats) == {"moe_rows"}
+    assert set(stats) == {"moe_rows", "moe_overflow_calls"}
     assert stats["moe_rows"].shape == (2, 1, 2)
+    assert stats["moe_overflow_calls"].tolist() == [0, 0]
     # top-2 of 8 over 2 x 16 tokens a rank: at most every pair is held
     assert 0 < int(stats["moe_rows"].sum()) <= 2 * 2 * 2 * 16
     moved = jax.tree.map(lambda a, b: bool(jnp.any(a != b[0])), params, new)

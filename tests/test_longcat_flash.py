@@ -256,7 +256,8 @@ def test_decode_counters_come_down_with_the_tokens(spmd, monkeypatch):
         host["table"], host["tokens"], host["pos"], active=host["live"],
         stats=want)
     want = jax.device_get(want)
-    assert set(want) == {"moe_rows", "moe_zero_pairs", "moe_live_pairs"}
+    assert set(want) == {"moe_rows", "moe_zero_pairs", "moe_live_pairs",
+                         "moe_overflow_calls"}
 
     class Watched:
         """numpy, but for the arrays the engine copies off the device."""
@@ -279,7 +280,7 @@ def test_decode_counters_come_down_with_the_tokens(spmd, monkeypatch):
         m.setattr(E, "np", Watched())
         m.setattr(jax, "device_get", no_device_get)
         eng.step()
-    counted = want["moe_rows"].size + 2
+    counted = want["moe_rows"].size + 3
     assert Watched.down == [((1,) if spmd else ())
                             + (eng.serve_cfg.slots + counted,)]
     rec = profiling.serve_step_log()[-1]
@@ -291,6 +292,7 @@ def test_decode_counters_come_down_with_the_tokens(spmd, monkeypatch):
     np.testing.assert_array_equal(rows, want["moe_rows"])
     assert rows.sum() > 0
     assert rec["moe_zero_pairs"] == int(want["moe_zero_pairs"]) > 0
+    assert rec["moe_overflow_calls"] == int(want["moe_overflow_calls"]) == 0
     assert rec["moe_live_pairs"] == int(want["moe_live_pairs"]) \
         == 2 * CFG["num_layers"] * CFG["moe_topk"]
 
@@ -360,7 +362,7 @@ def test_the_32_shares_and_the_zero_part_add_up_to_the_uncut_layer():
             score="softmax", renorm=False, n_zero=zeros)
         part = dict(blk, w1=blk["w1"][rank:rank + 1],
                     w2=blk["w2"][rank:rank + 1])
-        y, rows, zero = moe.held_experts_ffn(m[0], part, spec)
+        y, rows, zero, _ = moe.held_experts_ffn(m[0], part, spec)
         total = total + (y - zero_part[0])       # the rank's held part
         zero_pairs.append(int(zero))
     assert _gap(total, whole[0]) < TOL
@@ -375,9 +377,9 @@ def test_a_token_that_is_nobodys_takes_no_time_and_is_not_counted():
     x = jnp.asarray(np.random.default_rng(1).standard_normal(
         (24, CFG["hidden_size"])), F32)
     live = jnp.asarray([True, False, True, True, False, True] * 4)
-    y_all, rows_all, zero_all = moe.held_experts_ffn(x, p, spec)
-    y, rows, zero = moe.held_experts_ffn(x, p, spec, live=live)
-    _, rows_live, zero_live = moe.held_experts_ffn(x[live], p, spec)
+    y_all, rows_all, zero_all, _ = moe.held_experts_ffn(x, p, spec)
+    y, rows, zero, _ = moe.held_experts_ffn(x, p, spec, live=live)
+    _, rows_live, zero_live, _ = moe.held_experts_ffn(x[live], p, spec)
     assert np.array_equal(rows, rows_live) and rows.sum() < rows_all.sum()
     assert int(zero) == int(zero_live) < int(zero_all)
     assert _gap(y[live], y_all[live]) < 1e-6
@@ -405,7 +407,7 @@ def test_default_fields_are_todays_layer():
     assert (m.q_scale, m.kv_scale) == (1.0, 1.0)
     p = moe.init_experts(jax.random.PRNGKey(0), e, 64)
     x = jnp.ones((3, 64), F32)
-    _, _, zero = moe.held_experts_ffn(x, p, e)
+    _, _, zero, _ = moe.held_experts_ffn(x, p, e)
     assert zero == 0 and not isinstance(zero, jax.Array)
     chosen, w = moe.route_topk(x, p["router"], p["bias"], 2, 1.0)
     assert _gap(jnp.sum(w, axis=-1), 1.0) < 1e-6
@@ -441,12 +443,12 @@ def _zero_experts_dropped(top, blocks):
     real = moe.held_experts_ffn
 
     def without(x, params, spec, comm_ep=None, live=None):
-        y, rows, zero = real(x, params, spec, comm_ep, live=live)
+        y, *counts = real(x, params, spec, comm_ep, live=live)
         chosen, w = moe.route_topk(
             x, params["router"], params["bias"], spec.top_k, spec.scale,
             score=spec.score, renorm=spec.renorm)
         lost = jnp.sum(jnp.where(chosen >= n, w, 0), axis=1, keepdims=True)
-        return y - lost * x, rows, zero
+        return (y - lost * x, *counts)
 
     return TCFG, dict(top, blocks=blocks), without
 

@@ -278,7 +278,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
             d_shared=cfg["moe_shared_expert_intermediate_size"])
         part = dict(blk, w1=blk["w1"][4 * rank:4 * rank + 4],
                     w2=blk["w2"][4 * rank:4 * rank + 4])
-        y, rows, _ = moe.held_experts_ffn(m, part, spec)
+        y, rows, *_ = moe.held_experts_ffn(m, part, spec)
         total, taken = total + y, taken + int(rows.sum())
     assert taken == 40 * cfg["num_experts_per_tok"]
     assert _gap(total - 3 * shared, whole) < TOL

@@ -238,9 +238,9 @@ def test_a_token_that_is_nobodys_takes_no_experts_time():
     x = jnp.asarray(np.random.default_rng(1).standard_normal(
         (6, CFG["hidden_size"])), F32)
     live = jnp.asarray([True, False, True, True, False, True])
-    y_all, rows_all, _ = moe.held_experts_ffn(x, p, spec)
-    y, rows, _ = moe.held_experts_ffn(x, p, spec, live=live)
-    _, rows_live, _ = moe.held_experts_ffn(x[live], p, spec)
+    y_all, rows_all, *_ = moe.held_experts_ffn(x, p, spec)
+    y, rows, *_ = moe.held_experts_ffn(x, p, spec, live=live)
+    _, rows_live, *_ = moe.held_experts_ffn(x[live], p, spec)
     assert np.array_equal(rows, rows_live) and rows.sum() < rows_all.sum()
     assert _gap(y[live], y_all[live]) < 1e-6
 
@@ -267,7 +267,7 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
                            scale=cfg["routed_scaling_factor"])
         part = dict(blk, w1=blk["w1"][rank:rank + 1],
                     w2=blk["w2"][rank:rank + 1])
-        y, rows, _ = moe.held_experts_ffn(m[0], part, spec)
+        y, rows, *_ = moe.held_experts_ffn(m[0], part, spec)
         total = total + y
     assert _gap(total - 15 * shared[0], whole[0]) < TOL
 
@@ -333,11 +333,14 @@ def test_a_layer_spec_is_served_on_one_rank_only():
 # in this suite's environment (x64 on, jax 0.9.0): Kimi's training step,
 # InternLM2's paged decode step and openPangu's latent paged decode step
 # at their rehearsal sizes.  A PR that means to change one of these programs
-# replaces its line; one that does not has changed it by accident.
+# replaces its line; one that does not has changed it by accident.  PR 42
+# replaced Kimi's and openPangu's: both hand out one more counter,
+# ``moe_overflow_calls``; their expert layers, under the row constant at
+# these sizes, are the parent's (``tests/test_moe_prefix.py``, bit for bit).
 PARENT_TEXTS = {
-    "kimi": "f359630dfc06bad6b9b47ab71f11f2cacf8c82bfccc459b7a28af81c9d841366",
+    "kimi": "1a658da468a6c3461c38340fcbc6465ccd350c53a405299a82821bb6de3f158f",
     "internlm2": "ddcbb9f523103a01172891ee29d82bf8839323abf424f1e05587b9142ec5ad65",
-    "openpangu": "5f642f3b450194072169ead73b988dded931711343d47cb074f2e0ed5c69cfc6",
+    "openpangu": "cd48ae9b73a5afa03dceab1ab8e7f1069b6759f10c550555e566776f4e353fc7",
 }
 
 

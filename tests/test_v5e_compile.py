@@ -658,3 +658,82 @@ def test_dp_step_compiles_to_all_reduces_alone(v5e_2x2):
     # the matrices' buckets, forward and adjoint, in their own shapes
     assert low.count("tensor<2048x2048xbf16>) -> tensor<2048x2048xbf16>") \
         >= 2 * 2 * 2
+
+
+def _ragged_dots(text: str):
+    """``(on the path every call takes, in a branch of a condition)``:
+    the grouped products (``ragged-dot-none`` custom calls) of a compiled
+    program, by the computation that holds them."""
+    branches = set(re.findall(r"%([\w.-]+)", " ".join(
+        re.findall(r"branch_computations=\{([^}]*)\}", text))))
+    always = conditional = 0
+    for block in re.split(r"\n(?=(?:ENTRY )?%[\w.-]+ \()", text):
+        name = re.match(r"(?:ENTRY )?%([\w.-]+) \(", block)
+        n = len(re.findall(r"ragged-dot-none[\w.]* = ", block))
+        if name is not None and name.group(1) in branches:
+            conditional += n
+        else:
+            always += n
+    return always, conditional
+
+
+@pytest.mark.parametrize("cell", ["train_kda_8k", "serve_ssm_chat"])
+def test_the_expert_layer_compiles_on_a_prefix_at_the_cells_shapes(
+        one_v5e_chip, cell):
+    """``held_experts_ffn`` at the real shapes of the two cells whose
+    held share is largest and smallest in rows: `train_kda_8k`'s step
+    (16,384 tokens, 8 of 256 with 32 held: a prefix of 32,768 of 131,072
+    pairs) under ``jax.checkpoint`` and ``jax.grad``, and
+    `serve_ssm_chat`'s longest prefill (1,024 tokens, 22 of 512 with 128
+    held, latent 1,024, relu2: 11,264 of 22,528).  Under the gradient
+    each product of the prefix is one grouped product on the path every
+    step takes (eight a training step and layer: what
+    ``moe_grouped_dot_roofline`` counts), the rest of the rows are a
+    second body behind a condition, and the backward's branch not taken
+    hands its operands through.  The serving call holds ONE pair of
+    grouped products, in a loop that takes a prefix's rows a turn (two
+    events a call, and no second body's code to load at start-up)."""
+    from mpi4torch_tpu.parallel import moe
+
+    if cell == "train_kda_8k":
+        spec = moe.Experts(256, 8, 1024, 0, 32, n_shared=1, scale=2.446)
+        tokens, d, always, conditional = 16384, 2304, 8, 8
+    else:
+        spec = moe.Experts(512, 22, 2688, 0, 128, n_shared=1, scale=5.0,
+                           latent=1024, act="relu2", d_shared=5376)
+        tokens, d, always, conditional = 1024, 4096, 0, 2
+    pairs = spec.top_k * tokens
+    assert moe._prefix_rows(pairs, spec) == {
+        "train_kda_8k": 32768, "serve_ssm_chat": 11264}[cell] < pairs
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                          sharding=one_v5e_chip)
+    p = jax.tree.map(like, jax.eval_shape(lambda: moe.init_experts(
+        jax.random.PRNGKey(0), spec, d, jnp.bfloat16)))
+    x = jax.ShapeDtypeStruct((tokens, d), jnp.bfloat16,
+                             sharding=one_v5e_chip)
+
+    def loss(p, x):
+        y, *counts = moe.held_experts_ffn(x, p, spec)
+        return jnp.sum(y.astype(F32)), counts
+
+    fn = jax.value_and_grad(jax.checkpoint(loss), argnums=(0, 1),
+                            has_aux=True) if cell == "train_kda_8k" \
+        else (lambda p, x: moe.held_experts_ffn(x, p, spec))
+    with jax.enable_x64(False):
+        compiled = jax.jit(fn).lower(p, x).compile()
+    text = re.sub(r"/\*.*?\*/", "", compiled.as_text())
+    assert _ragged_dots(text) == (always, conditional)
+    # the buffers are the prefix's: no product over all the pairs
+    assert not re.search(rf"ragged-dot-none[\w.]* = \w+\[{pairs},", text)
+    if cell == "serve_ssm_chat":
+        assert len(re.findall(r" while\(", text)) == 1
+    else:
+        # forward and backward: one condition each; the backward's other
+        # branch is its parameter, nothing is copied or zeroed
+        assert len(re.findall(r" conditional\(", text)) == 2
+        assert re.search(r"\n%[\w.-]+ \([^\n]*\{\n\s*ROOT %[\w.-]+ = "
+                         r"\([^\n]*\) parameter\(0\)\n\}", text)
+        # both bodies' temporaries are counted, the rest's of 98,304 rows
+        # too (the parent's one body: 2.72 GB; this layer is not the
+        # step's peak, which the delta rule's layers set: 7.7-7.8 GB)
+        assert compiled.memory_analysis().temp_size_in_bytes < 3.6e9
