@@ -270,3 +270,32 @@ class TestFlashTileProbe:
 
     def test_no_trace_no_durations(self, tmp_path):
         assert _load("flash_tile_probe").kernel_durations(str(tmp_path)) == {}
+
+
+class TestKdaProbe:
+    """examples/kda_probe.py is a chip script; its control flow runs
+    here at a tiny shape, the kernel interpreted (its times mean
+    nothing)."""
+
+    def test_rehearsal_times_both_paths_and_a_baseline(self, tmp_path,
+                                                       monkeypatch):
+        import json
+
+        mod = _load("kda_probe")
+        monkeypatch.chdir(tmp_path)
+        assert mod.main(["--rehearse", "--iters", "1", "--check",
+                         "--baseline", mod.kda.__file__]) == 0
+        rows = [json.loads(line) for line in open(
+            tmp_path / "chiprun_out" / "kda_probe.jsonl")]
+        assert [(r["module"], r["impl"]) for r in rows] == [
+            ("this", "jnp"), ("this", "pallas"),
+            ("baseline", "jnp"), ("baseline", "pallas")]
+        for row in rows:
+            assert row["fwd_ms"] > 0 and row["grad_ms"] > 0
+            # bfloat16 operands: the two paths round differently on the
+            # way into each product; the gradients are one arithmetic
+            assert row["out_gap"] < 2e-3 and row["grad_gap"] < 1e-5
+
+    def test_refuses_to_time_off_the_tpu(self, capsys):
+        assert _load("kda_probe").main([]) == 3
+        assert "no TPU here" in capsys.readouterr().err

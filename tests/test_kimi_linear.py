@@ -119,13 +119,176 @@ def test_chunked_kda_equals_the_recurrence(s):
         _close(g_c, g_r, 1e-11)
 
 
-def test_chunked_kda_survives_a_fast_decay():
+def _kernel_inputs(s, dtype=F32, rate=1.0, seed=0, h=3):
+    """Heads the kernel takes (128 channels), in ``dtype``; the decay
+    and the write strength in float32, as the mixer hands them."""
+    b, d = 2, 128
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    normal = lambda key, *shape: jax.random.normal(key, shape, F32)
+    return (unit(normal(ks[0], b, s, h, d)).astype(dtype),
+            unit(normal(ks[1], b, s, h, d)).astype(dtype),
+            normal(ks[2], b, s, h, d).astype(dtype),
+            -rate * jax.nn.softplus(normal(ks[3], b, s, h, d)),
+            jax.nn.sigmoid(normal(ks[4], b, s, h)))
+
+
+def _gap(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return np.linalg.norm(a - b) / np.linalg.norm(b)
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+def test_chunked_kda_survives_a_fast_decay(impl):
     """exp(-G) overflows float32 after 30 such tokens; measured from the
-    middle of each block of 16 rows, nothing does."""
-    args = _kda_inputs(128, F32, rate=6.0)
-    out = jax.jit(kda.kda_chunked)(*args)
+    middle of each block of 16 rows, nothing does, on the plain path (4
+    heads of 16) and through the kernel (heads of 128, where the plain
+    path itself stands 2e-5 off the float32 recurrence: the kernel is
+    held to the plain path, and to no more of a gap than it has)."""
+    if impl == "jnp":
+        args = _kda_inputs(128, F32, rate=6.0)
+        out = jax.jit(kda.kda_chunked)(*args)
+        assert bool(jnp.all(jnp.isfinite(out)))
+        _close(out, jax.jit(kda.kda_recurrent)(*args), 1e-5)
+        return
+    args = _kernel_inputs(128, rate=6.0)
+    out, plain = (jax.jit(functools.partial(kda.kda_chunked, impl=i))(*args)
+                  for i in ("pallas", "jnp"))
     assert bool(jnp.all(jnp.isfinite(out)))
-    _close(out, jax.jit(kda.kda_recurrent)(*args), 1e-5)
+    _close(out, plain, 1e-5)
+    exact = jax.jit(kda.kda_recurrent)(*args)
+    assert _gap(out, exact) <= 1.1 * _gap(plain, exact) + 1e-6
+
+
+# Widest gap seen to the float32 recurrence: 9.5e-7 from the kernel and
+# 6.9e-7 from the plain path in float32, 3.8e-3 and 3.4e-3 in bfloat16
+# (the operands' rounding on the way into each product).
+KERNEL_TOL = {F32: 5e-6, jnp.bfloat16: 6e-3}
+
+
+@pytest.mark.parametrize("dtype", [F32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("s,block", [(64, None), (100, None), (128, None),
+                                     (320, None), (320, 128)])
+def test_the_forward_kernel_equals_both_oracles(s, block, dtype):
+    """The kernel, interpreted, against the recurrence and the plain
+    path: whole chunks and a tail, one token block and three (the last
+    of them half past the sequence's end)."""
+    args = _kernel_inputs(s, dtype)
+    if block is None:
+        run = functools.partial(kda.kda_chunked, impl="pallas")
+    else:
+        run = lambda *a: kda._pallas_forward(
+            *a, 128 ** -0.5, kda.CHUNK, interpret=True, block=block)
+    out = jax.jit(run)(*args)
+    out_j = jax.jit(functools.partial(kda.kda_chunked, impl="jnp"))(*args)
+    exact = jax.jit(kda.kda_recurrent)(*(a.astype(F32) for a in args))
+    assert out.dtype == out_j.dtype and out.shape == out_j.shape
+    _close(out, exact, KERNEL_TOL[dtype])
+    _close(out, out_j, KERNEL_TOL[dtype])
+
+
+@pytest.mark.parametrize("chunk", [32, 128])
+def test_the_kernel_takes_other_chunks(chunk):
+    """Four chunks of 32 side by side along the lanes, or one of 128:
+    the same rule."""
+    args = _kernel_inputs(200)
+    out = jax.jit(functools.partial(kda.kda_chunked, chunk=chunk,
+                                    impl="pallas"))(*args)
+    _close(out, jax.jit(kda.kda_recurrent)(*args), KERNEL_TOL[F32])
+
+
+def test_the_kernels_inverse_holds_where_keys_are_alike():
+    """One key all chunk long, written at full strength with no decay:
+    ``I + A`` is ones below the diagonal, its inverse two diagonals.
+    Block forward substitution keeps float32's digits there (4e-7, as
+    ``solve_triangular`` on the plain path); the sum of powers the
+    inverse could also be built from read 2e-4."""
+    q, k, v, g, beta = _kernel_inputs(128, h=2)
+    k = jnp.broadcast_to(k[:, :1], k.shape)
+    args = (k, k, v, jnp.zeros_like(g), jnp.ones_like(beta))
+    out = jax.jit(functools.partial(kda.kda_chunked, impl="pallas"))(*args)
+    _close(out, jax.jit(kda.kda_recurrent)(*args), KERNEL_TOL[F32])
+
+
+@pytest.mark.parametrize("dtype", [F32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("s,heads", [(64, 16), (100, 16), (100, 3)])
+def test_the_kernels_gradients_are_the_plain_paths(s, heads, dtype):
+    """Through the ``custom_vjp`` every gradient for the same cotangent
+    is the plain path's own: to the bit where the heads come in groups
+    (the model's 32: both run ``_chunked_heads`` again a group at a
+    time and transpose it); below a group the plain path keeps its
+    residuals where the ``custom_vjp`` runs the chunks again, the same
+    arithmetic compiled apart, and float32's last bit may differ."""
+    args = _kernel_inputs(s, dtype, h=heads)
+    cot = jnp.cos(args[2].astype(F32))
+    grads = [jax.jit(lambda *a: jax.vjp(functools.partial(
+        kda.kda_chunked, impl=impl), *a)[1](cot))(*args)
+        for impl in ("pallas", "jnp")]
+    for dx, dx_j, x in zip(*grads, args, strict=True):
+        assert dx.dtype == x.dtype and dx.shape == x.shape
+        if heads > kda.HEAD_GROUP:
+            np.testing.assert_array_equal(np.asarray(dx, np.float64),
+                                          np.asarray(dx_j, np.float64))
+        else:
+            _close(dx, dx_j, 1e-6 if dx.dtype == F32 else 1e-3)
+
+
+@pytest.mark.parametrize("case,dtype,d,takes", [
+    ("the_cells_shape", jnp.bfloat16, 128, True),
+    ("float32", F32, 128, True),
+    ("float64", jnp.float64, 128, False),
+    ("the_rehearsals_heads", jnp.bfloat16, 16, False)])
+def test_which_shapes_the_kernel_takes(monkeypatch, case, dtype, d, takes):
+    """By what the call can see in its operands: type and head size on a
+    TPU, nothing anywhere else."""
+    like = jax.ShapeDtypeStruct((2, 8192, 32, d), dtype)
+    assert not kda.uses_kernel(like, like, like)            # the CPU
+    monkeypatch.setattr(kda, "_on_tpu", lambda: True)
+    assert kda.uses_kernel(like, like, like) == takes
+    if not takes:
+        with pytest.raises(ValueError, match="kernel takes"):
+            kda.kda_chunked(*_kda_inputs(64, dtype)[:3], None, None,
+                            impl="pallas")
+    with pytest.raises(ValueError, match="unknown impl"):
+        kda.kda_chunked(like, like, like, like, like, impl="mosaic")
+
+
+def _kernel_calls(jaxpr) -> int:
+    """``pallas_call`` equations in a jaxpr and everything it holds."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "pallas_call"
+        for sub in jax.tree.leaves(
+                list(eqn.params.values()),
+                is_leaf=lambda x: hasattr(x, "eqns") or hasattr(x, "jaxpr")):
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                n += _kernel_calls(sub)
+    return n
+
+
+@pytest.mark.parametrize("policy,calls", [(T._SAVED_IN_REMAT, 1), (None, 2)],
+                         ids=["kda_out_saved", "nothing_saved"])
+def test_a_rematerialised_mixer_runs_the_kernel_once(monkeypatch, policy,
+                                                     calls):
+    """The ``custom_vjp`` keeps its inputs and nothing of the kernel's
+    output, and the mixer's output is saved by name: the gradient of a
+    rematerialised mixer holds one kernel call.  (With nothing saved the
+    region's backward needs the rule's output again and runs it twice:
+    the count sees both.)"""
+    wide = dict(CFG, linear_attn_config=dict(
+        CFG["linear_attn_config"], head_dim=128, num_heads=2))
+    p = family.make_layer(jax.random.PRNGKey(0), wide, 0, F32)["mixer"]
+    spec = T.KDA(2, 128)
+    monkeypatch.setattr(T, "kda_chunked", functools.partial(
+        kda.kda_chunked, impl="pallas"))
+    region = jax.checkpoint(lambda p_, x_: T._kda_mixer(spec, p_, x_),
+                            policy=policy)
+    grad = jax.grad(lambda p_, x_: jnp.sum(region(p_, x_)))
+    assert _kernel_calls(jax.make_jaxpr(grad)(p, _x()).jaxpr) == calls
+    jax.jit(grad).lower(p, _x())
 
 
 # ---------------------------------------------------------------- mixers
@@ -261,7 +424,18 @@ def test_a_step_counts_the_layers_that_overflowed(monkeypatch):
     assert stats["moe_rows"].sum(axis=1).tolist() == [pairs] * layers
 
 
-def test_a_bfloat16_kda_state_fails_the_comparison(monkeypatch):
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+def test_a_bfloat16_kda_state_fails_the_comparison(monkeypatch, impl):
+    """The plain path inside the mixer at the rehearsal's sizes against
+    the reference; the kernel (which takes its state's type from the
+    same function) alone at heads of 128 against the recurrence."""
+    if impl == "pallas":
+        args = _kernel_inputs(128)
+        exact = jax.jit(kda.kda_recurrent)(*args)
+        monkeypatch.setattr(kda, "_state_dtype", lambda q: jnp.bfloat16)
+        out = jax.jit(functools.partial(kda.kda_chunked, impl=impl))(*args)
+        assert _gap(out, exact) > 100 * KERNEL_TOL[F32]
+        return
     spec, p, x = TCFG.layers[0].mixer, _params()["blocks"][0]["mixer"], _x()
     plain = jax.jit(lambda p_, x_: ref.kda(PLAN, p_, x_, MM))(p, x)
     monkeypatch.setattr(kda, "_state_dtype", lambda q: jnp.bfloat16)

@@ -17,6 +17,7 @@ import pytest
 
 from mpi4torch_tpu.models import transformer as T
 from mpi4torch_tpu.ops import flash
+from mpi4torch_tpu.ops import kda
 from mpi4torch_tpu.ops import paged_attention as pa
 from mpi4torch_tpu.ops import ssd
 
@@ -49,6 +50,7 @@ def as_on_tpu(monkeypatch):
     monkeypatch.setattr(flash, "_on_tpu", lambda: True)
     monkeypatch.setattr(pa, "_on_tpu", lambda: True)
     monkeypatch.setattr(ssd, "_on_tpu", lambda: True)
+    monkeypatch.setattr(kda, "_on_tpu", lambda: True)
 
 
 def test_mla_blocks_compile_for_the_v5e_with_all_three_kernels(
@@ -737,3 +739,75 @@ def test_the_expert_layer_compiles_on_a_prefix_at_the_cells_shapes(
         # too (the parent's one body: 2.72 GB; this layer is not the
         # step's peak, which the delta rule's layers set: 7.7-7.8 GB)
         assert compiled.memory_analysis().temp_size_in_bytes < 3.6e9
+
+
+# ------------------------------------------------- the delta rule's kernel
+
+def _kda_calls(text: str) -> int:
+    return sum('custom_call_target="tpu_custom_call"' in line
+               and kda.KERNEL_NAME in line for line in text.splitlines())
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, F32],
+                         ids=["bfloat16", "float32"])
+def test_the_delta_rules_kernel_compiles_at_the_cells_shape(
+        one_v5e_chip, as_on_tpu, dtype):
+    """One KDA layer of `train_kda_8k` (2 x 8,192 tokens, 32 heads of
+    128): Mosaic takes the kernel at the block ``forward_block`` gives
+    it, whose count of fast memory stays under the default scoped limit
+    (no limit is asked for), and the operands are read where the
+    mixer's projections leave them, heads side by side: the compiled
+    program holds the kernel and no temporary, so no copy into another
+    tiling.  (An operand that arrives as ``(b, s, h, d)`` lies tiled
+    over ``(h, d)`` and would be copied first: 671 MB here.)"""
+    b, s, h, d = 2, 8192, 32, 128
+    tokens, staged = kda.forward_block(s, h, d, d, dtype)
+    assert tokens == 1024 and staged < flash._DEFAULT_SCOPED_VMEM // 2
+    like = lambda t, *shape: jax.ShapeDtypeStruct(shape, t,
+                                                  sharding=one_v5e_chip)
+    heads = lambda x: x.reshape(b, s, h, d)
+    assert kda.uses_kernel(*(like(dtype, b, s, h, d),) * 3)
+
+    def rule(q, k, v, g, beta):
+        return kda.kda_chunked(heads(q), heads(k), heads(v), heads(g),
+                               beta).reshape(b, s, h * d)
+
+    with jax.enable_x64(False):
+        compiled = jax.jit(rule).lower(
+            *(like(dtype, b, s, h * d),) * 3, like(F32, b, s, h * d),
+            like(F32, b, s, h)).compile()
+    assert _kda_calls(compiled.as_text()) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+def test_a_rematerialised_kda_mixer_compiles_with_one_kernel_call(
+        one_v5e_chip, as_on_tpu):
+    """The gradient of one rematerialised KDA mixer at the cell's real
+    size: the ``custom_vjp`` keeps the rule's inputs and nothing of its
+    output, the mixer's output is saved by name, so the backward does
+    not run the kernel again: one call in the compiled text (four a
+    step of `train_kda_8k`), beside the plain path's backward."""
+    import json
+    import os
+
+    from benchmarks.families import kimi_linear as family
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "kimi-linear-48b-a3b.json")) as f:
+        cfg = json.load(f)
+    lin = cfg["linear_attn_config"]
+    spec = T.KDA(lin["num_heads"], lin["head_dim"])
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                          sharding=one_v5e_chip)
+    p = jax.tree.map(like, jax.eval_shape(lambda: family.make_layer(
+        jax.random.PRNGKey(0), cfg, 0, jnp.bfloat16)["mixer"]))
+    y = jax.ShapeDtypeStruct((2, 8192, cfg["hidden_size"]), jnp.bfloat16,
+                             sharding=one_v5e_chip)
+    region = jax.checkpoint(lambda p_, y_: T._kda_mixer(spec, p_, y_),
+                            policy=T._SAVED_IN_REMAT)
+    loss = lambda p_, y_: jnp.sum(region(p_, y_).astype(F32))
+    with jax.enable_x64(False):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            p, y).compile()
+    assert _kda_calls(compiled.as_text()) == 1
