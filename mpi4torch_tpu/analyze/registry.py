@@ -230,31 +230,21 @@ def serve_policy_problems(parity_policies: Iterable) -> List[str]:
 
 
 def serve_paging_problems() -> List[str]:
-    """Paged-serving registry-sync guards (ISSUE 17): every scheduling
+    """Paged-serving registry-sync guard (ISSUE 17): every scheduling
     policy must also hold engine-vs-oracle parity UNDER BLOCK CHURN
-    (the paged matrix literal published by the serve-smoke lane), and
-    every ServeStats counter must be mirrored into the
-    ``mpi4torch_serve_*`` obs metrics surface (the mirror literal the
-    smoke lane asserts against ``prometheus_text()``) — a new counter
-    cannot ship unmirrored, a new policy cannot ship without paged
-    parity coverage."""
+    (the paged matrix literal published by the serve-smoke lane) — a
+    new policy cannot ship without paged parity coverage.  The serving
+    counters have one declaration, ``ServeStats._COUNTERS``, which the
+    smoke lane and ``tests/test_obs.py`` walk against the
+    ``mpi4torch_serve_*`` exposition: nothing of theirs can drift."""
     from ..serve import POLICIES
-    from ..serve.__main__ import (MIRRORED_SERVE_COUNTERS,
-                                  PAGED_PARITY_POLICIES)
-    from ..utils.profiling import ServeStats
+    from ..serve.__main__ import PAGED_PARITY_POLICIES
 
-    problems = set_drift(
+    return set_drift(
         POLICIES, PAGED_PARITY_POLICIES,
         "policy registry {registered} != paged-parity covered set "
         "{covered} — every scheduling policy needs oracle-parity "
         "coverage under block churn too")
-    problems += set_drift(
-        ServeStats._COUNTERS, MIRRORED_SERVE_COUNTERS,
-        "ServeStats counters {registered} != obs-mirrored set "
-        "{covered} — every serve counter must surface as an "
-        "mpi4torch_serve_* metric (serve/__main__.py smoke asserts "
-        "the exposition)")
-    return problems
 
 
 # ------------------------------------------------------------------- tune

@@ -9,8 +9,9 @@ the training stack's ingredients:
   a fixed-capacity slot table: ragged admission of new requests into
   free slots each step, eviction on EOS/budget, per-slot
   position/length state through ONE static-shape compiled step program
-  (no retrace as traffic churns), free slots NaN-poisoned and provably
-  inert.  Greedy and sampled decoding are BITWISE the per-request
+  (no retrace as traffic churns), free slots unmapped, masked and
+  provably inert, one paged cache manager behind every
+  ``ServeConfig``.  Greedy and sampled decoding are BITWISE the per-request
   ``models/transformer.generate`` tokens — the engine samples with the
   same rule under the same key discipline.
 * **KV sharding** (:mod:`.kv`) — heads sharded over the communicator

@@ -27,8 +27,8 @@ attached (the Makefile's ``serve-smoke`` target runs it on the
    requests sharing a system prompt: the ``prefill_tokens`` census
    counts the shared prefix ONCE, and the sharers' table rows hold the
    SAME page ids for the shared span;
-7. **counter mirror** — every ``ServeStats`` counter (pinned by
-   ``MIRRORED_SERVE_COUNTERS`` + the registry guard) appears in
+7. **counter mirror** — every ``ServeStats`` counter (its one
+   declaration, ``ServeStats._COUNTERS``) appears in
    ``obs.prometheus_text()`` as an ``mpi4torch_serve_*`` metric;
 8. **no-retrace census** — the paged decode step lowers to IDENTICAL
    program text across two different block-table states (the table is
@@ -44,6 +44,8 @@ from __future__ import annotations
 
 import sys
 
+from ..utils.profiling import ServeStats
+
 # The parity-covered policies: must equal serve.POLICIES (checked
 # below) so scheduling policies can never ship without oracle-parity
 # coverage — the registry-sync guard discipline of test_tune/
@@ -56,23 +58,10 @@ PARITY_POLICIES = ("fcfs", "shortest_first")
 # otherwise.
 PAGED_PARITY_POLICIES = ("fcfs", "shortest_first")
 
-# Every ServeStats counter mirrored into the obs metrics surface as
-# mpi4torch_serve_<name> (cell 7 asserts the exposition literally).
-# Must equal utils.profiling.ServeStats._COUNTERS — the registry guard
-# makes adding a counter without mirroring it a loud failure.
-MIRRORED_SERVE_COUNTERS = (
-    "steps", "admitted", "evicted", "finished", "rejected",
-    "decode_tokens", "occupancy_ticks", "slot_ticks",
-    "deadline_expired", "shed",
-    "prefix_hits", "prefix_misses", "prefill_tokens", "cow_copies",
-    "preempted", "blocks_in_use", "blocks_free", "blocks_cached",
-    "install_writes", "decode_pages_live", "decode_pages_read",
-    "decode_grid_steps", "decode_select_syncs", "moe_zero_pairs", "moe_live_pairs",
-    "moe_overflow_calls",
-    "dsa_rows_live", "dsa_rows_read", "dsa_rows_scored",
-    "ssm_states_live", "ssm_states_touched",
-    "decode_uploads", "step_compiles",
-)
+# Every ServeStats counter is mirrored into the obs metrics surface as
+# mpi4torch_serve_<name> (cell 7 asserts the exposition literally): the
+# counters are declared once, in utils.profiling.ServeStats._COUNTERS.
+MIRRORED_SERVE_COUNTERS = ServeStats._COUNTERS
 
 
 def _smoke() -> int:

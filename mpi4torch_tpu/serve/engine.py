@@ -1,19 +1,22 @@
 """The continuous-batching serving engine.
 
 One fixed-capacity **slot table** (``ServeConfig.slots`` concurrent
-sequences), one compiled decode-step program, a host-driven loop:
+sequences), ONE cache manager (a pool of pages behind a per-slot block
+table, :mod:`.paging`; ``ServeConfig.block_size=0`` is that pool at one
+page of ``max_seq`` tokens a slot), one compiled decode-step program, a
+host-driven loop:
 
 * **admission** — each step starts by filling free slots from the
   request queue (the active :data:`POLICIES` entry picks the order).
   A request is admitted by a per-request TP prefill at its TRUE prompt
   length (exactly what ``generate()`` does — the engine's first token
   and the oracle's come from the same batched-prefill logits), whose
-  cache rows are installed into the free slot.  Prefill compiles per
+  cache rows are installed into the slot's pages.  Prefill compiles per
   distinct prompt length, like ``generate`` itself; the DECODE loop
   never retraces.
-* **decode** — one :func:`~mpi4torch_tpu.serve.decode_step_tp` call
+* **decode** — one :func:`~mpi4torch_tpu.serve.decode_step_paged` call
   over the whole slot table per step: static shapes, per-slot
-  positions, free slots riding along as NaN-poisoned inert rows
+  positions, free slots riding along unmapped and masked
   (ops/ragged masks; see kv.py).  The step ends in
   :func:`select_rows`: every slot's token is chosen where the logits
   are, with ``models/transformer.select_token`` under the exact
@@ -26,8 +29,8 @@ sequences), one compiled decode-step program, a host-driven loop:
   the arrays in which they differ from what the device holds
   (:meth:`Engine._step_inputs`): a decode step behind a decode step
   sends nothing and fetches one array.
-* **eviction** — a slot finishes on EOS or its token budget; its cache
-  rows are re-poisoned and the slot returns to the free pool, ready
+* **eviction** — a slot finishes on EOS or its token budget; its pages
+  are unmapped and the slot returns to the free pool, ready
   for the next admission in the SAME step loop — no batch barrier,
   which is the whole point of continuous batching.
 
@@ -228,17 +231,19 @@ class ServeConfig:
     ``shed`` result status and the new submit is accepted —
     :data:`SHED_POLICIES` picks the victim.
 
-    **Paging (ISSUE 17).**  ``block_size > 0`` switches the KV cache
-    from the dense ``(slots, max_seq)`` rows to a pool of fixed-size
-    TP-sharded pages addressed through a per-slot block table
-    (``block_size`` must divide ``cfg.max_seq``; checked at engine
-    construction).  ``num_blocks`` sizes the pool (None = ``slots *
-    max_seq / block_size``, dense-equivalent capacity — shrink it to
-    overcommit on real length distributions, which is the point).
+    **Paging (ISSUE 17).**  The KV cache is a pool of fixed-size
+    TP-sharded pages of ``block_size`` tokens addressed through a
+    per-slot block table (``block_size`` must divide ``cfg.max_seq``;
+    checked at engine construction).  ``block_size=0`` is one page of
+    ``cfg.max_seq`` tokens a slot: nothing is shared, chunked or
+    overcommitted (``num_blocks`` and ``prefix_cache`` are not read).
+    ``num_blocks`` sizes the pool (None = ``slots * max_seq /
+    block_size``, every slot's whole extent — shrink it to overcommit
+    on real length distributions, which is the point).
     ``prefix_cache`` (on by default) shares identical prompt prefixes
     copy-on-write across requests, prefilled once; ``prefill_chunk``
-    (paged only) caps the prompt tokens prefilled per engine step —
-    longer prompts interleave chunk-by-chunk with ongoing decode steps
+    (``block_size > 0``) caps the prompt tokens prefilled per engine
+    step — longer prompts interleave chunk-by-chunk with ongoing decode steps
     so one long prompt never stalls resident slots' emission (the TTFT
     bound).  Both exactness-gate on ``cache_dtype`` matching the
     parameter dtype (a down-cast cache would re-quantize shared prefix
@@ -389,10 +394,18 @@ class Engine:
                 self._size = int(nranks or len(jax.devices()))
         else:
             self._size = self._comm.size
-        self._paged = self.serve_cfg.block_size > 0
+        # One cache manager for every configuration: block_size=0 (a
+        # slot owns max_seq rows; nothing shared, chunked or
+        # overcommitted) is the pool at one page of max_seq tokens a
+        # slot, with prefix sharing off.
+        if self.serve_cfg.block_size == 0:
+            bs, nb, share = cfg.max_seq, None, False
+        else:
+            bs, nb, share = (self.serve_cfg.block_size,
+                             self.serve_cfg.num_blocks,
+                             self.serve_cfg.prefix_cache)
         _kv.validate_tp(
-            cfg, self._size,
-            prefix_cache=self._paged and self.serve_cfg.prefix_cache,
+            cfg, self._size, prefix_cache=share,
             prefill_chunk=self.serve_cfg.prefill_chunk)
         # A layer that keeps a per-slot state beside the cache
         # (kv.STATE_LEAVES): made with the cache, donated and handed
@@ -402,8 +415,7 @@ class Engine:
         # (_exact_kv, below): both splice CACHE-dtype rows into prefill
         # attention, which is only bit-identical to the one-shot oracle
         # when the cache carries the compute dtype.  A down-cast cache
-        # keeps paging (storage) but prefills every prompt in full,
-        # like the dense path.
+        # keeps paging (storage) but prefills every prompt in full.
         self._dtype = (self.serve_cfg.cache_dtype
                        or params["embed"].dtype)
         self._exact_kv = (jnp.dtype(self._dtype)
@@ -424,21 +436,17 @@ class Engine:
             # every executed step.
             self._shards = self._shard_by_layer(
                 params, lambda fn: run_spmd(fn, **kw))
-            # The paged step takes its pool over (argument 1) and
-            # writes the new rows into it; the dense step's one-hot
-            # write builds a new cache and is left as it is.  Every
-            # step takes the slot state over (argument 2) and hands
-            # the next one back in its buffers: nothing is allocated or
-            # freed for it inside the call (0.15-0.27 ms a step on the
-            # chip, PERF.md section 6, PR 38).
+            # The step takes its pool over (argument 1) and writes the
+            # new rows into it, and takes the slot state over (argument
+            # 2) and hands the next one back in its buffers: nothing is
+            # allocated or freed for it inside the call (0.15-0.27 ms a
+            # step on the chip, PERF.md section 6, PR 38).
             self._step_call = run_spmd(
-                self._traced_step,
-                donate_argnums=(1, 2) if self._paged else (2,), **kw)
+                self._traced_step, donate_argnums=(1, 2), **kw)
             # One wrapper serves every prompt length: the jit under
             # run_spmd caches per input shape on its own.
             self._prefill_call = run_spmd(self._traced_prefill, **kw)
-            self._chunk_call = run_spmd(self._traced_prefill_chunk,
-                                        **kw) if self._paged else None
+            self._chunk_call = run_spmd(self._traced_prefill_chunk, **kw)
         else:
             # Eager: the rank is concrete here (rank thread or the
             # size-1 world) — shard once.
@@ -452,50 +460,39 @@ class Engine:
         self._prefilled: set = set()
 
         slots = self.serve_cfg.slots
-        if self._paged:
-            bs = self.serve_cfg.block_size
-            if cfg.max_seq % bs != 0:
-                raise ValueError(
-                    f"block_size={bs} must divide max_seq={cfg.max_seq} "
-                    "(a slot's table row covers the dense attention "
-                    "extent — see serve.kv.init_kv_pool_tp)")
-            self._blocks_per_seq = cfg.max_seq // bs
-            nb = (self.serve_cfg.num_blocks
-                  if self.serve_cfg.num_blocks is not None
-                  else slots * self._blocks_per_seq)
-            cache = _kv.init_kv_pool_tp(cfg, nb, bs, self._size,
-                                        self._dtype, slots=slots)
-            self._mgr = _paging.BlockManager(
-                nb, bs,
-                prefix_cache=(self.serve_cfg.prefix_cache
-                              and self._exact_kv))
-            # Host-side block table, mirrored into the step as DATA.
-            self._table = np.full((slots, self._blocks_per_seq), -1,
-                                  np.int32)
-            self._prefill_jobs: deque = deque()
-            self._admit_seq = 0                  # preemption-victim order
-            self._slot_seq = [0] * slots
-            self._chunk = (self.serve_cfg.prefill_chunk
-                           if self._exact_kv else None)
-            # Which read the decode step compiles, asked of the
-            # functions that decide it: what decode_pages_read and
-            # decode_grid_steps count.
-            self._grid_steps = self._kernel_grid_steps(cache, param_dtype)
-            self._kernel_read = self._grid_steps > 0
-        else:
-            cache = _kv.init_kv_cache_tp(cfg, slots, self._size,
-                                         self._dtype, poison=True)
-            self._mgr = None
-            self._table = None
-            self._chunk = None
+        if cfg.max_seq % bs != 0:
+            raise ValueError(
+                f"block_size={bs} must divide max_seq={cfg.max_seq} "
+                "(a slot's table row covers the dense attention "
+                "extent — see serve.kv.init_kv_pool_tp)")
+        self._blocks_per_seq = cfg.max_seq // bs
+        if nb is None:
+            nb = slots * self._blocks_per_seq
+        cache = _kv.init_kv_pool_tp(cfg, nb, bs, self._size,
+                                    self._dtype, slots=slots)
+        self._mgr = _paging.BlockManager(
+            nb, bs, prefix_cache=share and self._exact_kv)
+        # Host-side block table, mirrored into the step as DATA.
+        self._table = np.full((slots, self._blocks_per_seq), -1,
+                              np.int32)
+        self._prefill_jobs: deque = deque()
+        self._admit_seq = 0                  # preemption-victim order
+        self._slot_seq = [0] * slots
+        self._chunk = (self.serve_cfg.prefill_chunk
+                       if self._exact_kv else None)
+        # Which read the decode step compiles, asked of the
+        # functions that decide it: what decode_pages_read and
+        # decode_grid_steps count.
+        self._grid_steps = self._kernel_grid_steps(cache, param_dtype)
+        self._kernel_read = self._grid_steps > 0
         # Stacked per-rank state under SPMD: leading (size,) axis —
         # exactly the rank-major layout run_spmd's outputs carry, and
         # laid out as they are (read off the shards it just produced),
-        # so the state round-trips step to step unchanged and the paged
-        # install can reuse its buffers from the first call.  A paged
-        # pool also gets a buffer of its own per leaf (the template
-        # shares one): the install donates the whole pool, and one
-        # buffer cannot be donated twice in a call.  All of it leaf by
+        # so the state round-trips step to step unchanged and the
+        # install can reuse its buffers from the first call.  The pool
+        # also gets a buffer of its own per leaf (the template shares
+        # one): the install donates the whole pool, and one buffer
+        # cannot be donated twice in a call.  All of it leaf by
         # leaf: a second whole pool, even for a moment, would be the
         # peak of the process.
         state = self._state_sharding = \
@@ -507,15 +504,13 @@ class Engine:
                 a = jax.device_put(
                     jnp.broadcast_to(a[None], (self._size,) + a.shape),
                     state)
-            return jnp.copy(a) if self._paged else a
+            return jnp.copy(a)
 
         cache = jax.tree.map(own, cache)
         self._cache = cache
         # Built here, first called in step(): the engine may be
         # constructed with jit disabled.
-        self._install_call = self._build_install() if self._paged \
-            else None
-        self._cache_leaves = len(jax.tree.leaves(cache))
+        self._install_call = self._build_install()
         self._tokens = np.zeros((slots,), np.int32)
         self._pos = np.zeros((slots,), np.int32)
         # The slot state on the device, beside the cache and riding as
@@ -633,21 +628,17 @@ class Engine:
 
     def _decode(self, shards, cache, state):
         """One decode step over the slot ``state`` with this rank's
-        shards and cache, on every step path (compiled dense and paged,
-        eager): decode, then :meth:`_advance`.  Returns ``(chosen,
-        next state, new cache)``."""
+        shards and pool, on every step path (compiled, eager): decode,
+        then :meth:`_advance`.  Returns ``(chosen, next state, new
+        pool)``."""
         stats = {}
-        table = (state["table"],) if self._paged else ()
-        decode = _kv.decode_step_paged if self._paged \
-            else _kv.decode_step_tp
-        # Eager, the paged step takes each pool leaf over itself; the
+        # Eager, the step takes each pool leaf over itself; the
         # compiled one donates through run_spmd.
-        extra = {"donate": True} if self._paged and not self._spmd else {}
-        logits, cache = decode(
-            self.cfg, shards, cache, *table, state["tokens"],
+        logits, cache = _kv.decode_step_paged(
+            self.cfg, shards, cache, state["table"], state["tokens"],
             state["pos"], self._comm, overlap=self.serve_cfg.overlap,
             algorithm=self.serve_cfg.algorithm, active=state["live"],
-            stats=stats, **extra)
+            donate=not self._spmd, stats=stats)
         return (*self._advance(state, logits, stats), cache)
 
     def _advance(self, state, logits, counters):
@@ -668,22 +659,21 @@ class Engine:
         return chosen, _advanced(jnp, state, toks, keys)
 
     def _traced_step(self, shards, cache, state):
-        """Mode A decode step, dense or paged: slice this rank's
-        shards, cache (or pool) and slot state off the stacked leading
-        axis and :meth:`_decode` — run_spmd re-stacks the per-rank
-        outputs into the state layout, so the state goes into the next
-        step as it came out of this one.  A paged engine's block table
-        is part of the slot state, DATA: one compiled program for every
-        table state (no retrace as pages churn).  The logits are
-        replicated over the ranks (kv.shard_params_tp), so every rank
-        chooses the same tokens and the choice adds no collective."""
+        """Mode A decode step: slice this rank's shards, pool and slot
+        state off the stacked leading axis and :meth:`_decode` —
+        run_spmd re-stacks the per-rank outputs into the state layout,
+        so the state goes into the next step as it came out of this
+        one.  The block table is part of the slot state, DATA: one
+        compiled program for every table state (no retrace as pages
+        churn).  The logits are replicated over the ranks
+        (kv.shard_params_tp), so every rank chooses the same tokens and
+        the choice adds no collective."""
         return self._decode(*map(self._rank_slice,
                                  (shards, cache, state)))
 
     def _traced_prefill(self, shards, prompt):
         comm = COMM_WORLD
-        cache = _kv.init_kv_cache_tp(self.cfg, 1, comm.size, self._dtype,
-                                     poison=False)
+        cache = _kv.init_kv_cache_tp(self.cfg, 1, comm.size, self._dtype)
         stats = {}
         return (*_kv.prefill_tp(self.cfg, self._rank_slice(shards), cache,
                                 prompt, comm, stats=stats), stats)
@@ -724,19 +714,18 @@ class Engine:
             raise ValueError(
                 f"prompt {prompt.size} + n_new {budget} exceeds max_seq "
                 f"{self.cfg.max_seq}")
-        if self._paged:
-            # Worst-case page footprint (positions 0 .. p+budget-2; the
-            # final token is selected, never written): a request that
-            # could not run even ALONE on the pool would preempt-loop
-            # forever, so it is rejected here like the max_seq check.
-            bs = self.serve_cfg.block_size
-            need = -(-(int(prompt.size) + budget - 1) // bs)
-            if need > self._mgr.num_blocks:
-                raise ValueError(
-                    f"prompt {prompt.size} + n_new {budget} needs "
-                    f"{need} pages of {bs} tokens; the pool has only "
-                    f"{self._mgr.num_blocks} — raise num_blocks or "
-                    "shrink the request")
+        # Worst-case page footprint (positions 0 .. p+budget-2; the
+        # final token is selected, never written): a request that could
+        # not run even ALONE on the pool would preempt-loop forever, so
+        # it is rejected here like the max_seq check.
+        bs = self._mgr.block_size
+        need = -(-(int(prompt.size) + budget - 1) // bs)
+        if need > self._mgr.num_blocks:
+            raise ValueError(
+                f"prompt {prompt.size} + n_new {budget} needs "
+                f"{need} pages of {bs} tokens; the pool has only "
+                f"{self._mgr.num_blocks} — raise num_blocks or "
+                "shrink the request")
         if self.serve_cfg.temperature > 0 and key is None:
             raise ValueError("temperature > 0 requires a PRNG `key`")
         if key is not None and jnp.issubdtype(key.dtype,
@@ -861,56 +850,15 @@ class Engine:
             with span(SPAN_PLAN) as plan:
                 req = self._queue[chooser(self._queue)]
                 plan.rid = req.rid
-                if self._paged:
-                    job = self._plan_paged(req)
-                else:
-                    self._queue.remove(req)
-            if self._paged:
-                if job is None:
-                    # Page pool exhausted even after cache eviction:
-                    # defer admission (the request stays queued; decode
-                    # keeps draining pages).  Deadline expiry composes
-                    # — a deferred request past its deadline leaves
-                    # through the next sweep.
-                    break
-                self._start_paged(job, events)
-                continue
-            with span(SPAN_PREFILL, req.rid):
-                logits_row, rows = self._prefill_full(req.prompt)
-            with span(SPAN_FIRST_TOKEN, req.rid):
-                self.stats.mark(req.rid, "admitted")
-                self.stats.count("admitted")
-                tok = self._select(req, logits_row)
-                req.emitted.append(tok)
-                self.stats.mark(req.rid, "first_token")
-                events["admitted"].append(req.rid)
-                events["emitted"].setdefault(req.rid, []).append(tok)
-                done = req.finished(self.serve_cfg.eos)
-                if done:
-                    # Finished at admission (max_new=1 / immediate
-                    # EOS): it never occupied a slot, so no eviction
-                    # counts — but the event surface reports it like
-                    # any other completion.
-                    events["finished"].append(req.rid)
-                    self._finish(req)
-            if done:
-                continue
-            with span(SPAN_INSTALL, req.rid):
-                # Dispatch only: the writes drain under the next sync.
-                j = self._free_slots()[0]
-                self.slot_log.append((req.rid, j))
-                if self._spmd:
-                    self._cache = jax.tree.map(
-                        lambda s, r: s.at[:, j].set(r[:, 0]),
-                        self._cache, rows)
-                else:
-                    self._cache = jax.tree.map(
-                        lambda s, r: s.at[j].set(r[0]), self._cache,
-                        rows)
-                self.stats.count("install_writes", self._cache_leaves)
-                self._slot_req[j] = req
-                self._tokens[j] = tok
-                self._pos[j] = int(req.prompt.size)
+                job = self._plan(req)
+            if job is None:
+                # Page pool exhausted even after cache eviction: defer
+                # admission (the request stays queued; decode keeps
+                # draining pages).  Deadline expiry composes — a
+                # deferred request past its deadline leaves through the
+                # next sweep.
+                break
+            self._start(job, events)
 
     def _prefill_full(self, prompt):
         """The whole-prompt prefill — the IDENTICAL dispatch the
@@ -925,7 +873,7 @@ class Engine:
             logits = logits[0]
         else:
             cache1 = _kv.init_kv_cache_tp(
-                self.cfg, 1, self._size, self._dtype, poison=False)
+                self.cfg, 1, self._size, self._dtype)
             logits, rows = _kv.prefill_tp(
                 self.cfg, self._shards, cache1, pj, self._comm,
                 stats=stats)
@@ -1024,7 +972,7 @@ class Engine:
         — exact bits, and the write targets are private pages by the
         COW rule.  Whoever held ``self._cache``'s old leaves holds
         deleted arrays afterwards."""
-        bs = self.serve_cfg.block_size
+        bs = self._mgr.block_size
         first = lo // bs
         touched = -(-hi // bs) - first
         n_pages = _kv.install_page_count(
@@ -1045,7 +993,7 @@ class Engine:
         slot's whole table row through the gather), and the grid steps
         one call of that kernel walks for them (every slot's, live or
         free: the grid is the program's)."""
-        bs = self.serve_cfg.block_size
+        bs = self._mgr.block_size
         held = sum(int(self._pos[j]) // bs + 1 for j in active)
         self.stats.count("decode_pages_live", held)
         self.stats.count("decode_pages_read",
@@ -1059,7 +1007,7 @@ class Engine:
         the suffix/chunk prefill input: the cache's own tree, whatever
         kind each layer's entry is.  Stacked ``(size, 1, n, ...)``
         leaves under SPMD, ``(1, n, ...)`` eager."""
-        bs = self.serve_cfg.block_size
+        bs = self._mgr.block_size
         nblk = -(-n // bs)
         ids = jnp.asarray([int(self._table[j, bi])
                            for bi in range(nblk)], jnp.int32)
@@ -1073,14 +1021,14 @@ class Engine:
 
         return jax.tree.map(take, self._cache)
 
-    def _plan_paged(self, req: Request) -> Optional[_PrefillJob]:
-        """Plan a paged admission: prefix-match the prompt against the
+    def _plan(self, req: Request) -> Optional[_PrefillJob]:
+        """Plan an admission: prefix-match the prompt against the
         block index, adopt shared pages (COW-copying a partial tail),
         allocate private pages for the rest and reserve the slot.  The
-        returned job's ``done`` is the matched prefix; :meth:
-        `_start_paged` prefills the rest.  Returns None (request left
+        returned job's ``done`` is the matched prefix; :meth:`_start`
+        prefills the rest.  Returns None (request left
         queued) when the pool cannot supply the pages."""
-        bs = self.serve_cfg.block_size
+        bs = self._mgr.block_size
         prompt = np.asarray(req.prompt)
         p_len = int(prompt.size)
         # Cap the match at p_len - 1: admission needs last-token logits,
@@ -1114,8 +1062,8 @@ class Engine:
         self._pos[j] = l0          # rows installed so far
         return _PrefillJob(req=req, slot=j, seq=prompt, done=l0)
 
-    def _start_paged(self, job: _PrefillJob, events: dict) -> None:
-        """Prefill what :meth:`_plan_paged` did not match — in one shot
+    def _start(self, job: _PrefillJob, events: dict) -> None:
+        """Prefill what :meth:`_plan` did not match — in one shot
         if it fits ``ServeConfig.prefill_chunk`` (or chunking is off),
         else as a queued :class:`_PrefillJob` advanced one chunk per
         step."""
@@ -1123,8 +1071,8 @@ class Engine:
         rid = job.req.rid
         if l0 == 0 and (self._chunk is None or p_len <= self._chunk):
             # Whole-prompt miss that fits one shot: the ordinary full
-            # prefill — the IDENTICAL dispatch the dense engine and the
-            # generate() oracle use.
+            # prefill — the IDENTICAL dispatch the generate() oracle
+            # uses.
             with self.stats.span(SPAN_PREFILL, rid):
                 logits_row, rows = self._prefill_full(job.seq)
             with self.stats.span(SPAN_INSTALL, rid):
@@ -1133,8 +1081,8 @@ class Engine:
             job.done = p_len
             self._complete_admission(job, logits_row, events)
         elif self._chunk is None or p_len - l0 <= self._chunk:
-            # Suffix fits one shot: single chunk call at admission,
-            # like the dense path (first token this step).
+            # Suffix fits one shot: single chunk call at admission
+            # (first token this step).
             self._advance_job_chunk(job, events, cap=p_len - l0)
         else:
             # Long suffix: interleave — ONE chunk per step rides along
@@ -1184,7 +1132,7 @@ class Engine:
         instant EOS), releasing the pages through the registering
         release path."""
         req, j = job.req, job.slot
-        bs = self.serve_cfg.block_size
+        bs = self._mgr.block_size
         p_len = len(job.seq)
         with self.stats.span(SPAN_FIRST_TOKEN, req.rid):
             self.stats.mark(req.rid, "admitted")
@@ -1251,7 +1199,7 @@ class Engine:
         cached-then-evictable, so each round frees real capacity and
         the loop terminates (a request too big to EVER fit is rejected
         at submit)."""
-        bs = self.serve_cfg.block_size
+        bs = self._mgr.block_size
         for j in range(self.serve_cfg.slots):
             while True:
                 req = self._slot_req[j]
@@ -1269,11 +1217,10 @@ class Engine:
 
     def kv_bytes_resident(self) -> int:
         """Deterministic KV-residency census (one rank's shard): bytes
-        of cache RESERVED for request state right now — the dense
-        engine holds every occupied slot's full ``max_seq`` rows, the
-        paged engine only its in-use pages (a shared prefix counted
-        once).  It is a census, not a timer, so it regresses
-        deterministically on CPU smoke."""
+        of cache RESERVED for request state right now — the in-use
+        pages (a shared prefix counted once; at ``block_size=0`` every
+        occupied slot's one page of ``max_seq`` rows).  It is a census,
+        not a timer, so it regresses deterministically on CPU smoke."""
         # One token's rows over every cache leaf (a leaf is (..., rows
         # of a slot or a page, *row shape), behind the stacked axis),
         # and what an occupied slot keeps whatever its length (a
@@ -1288,10 +1235,8 @@ class Engine:
                     kept += size(a, lead - 1)
                 else:
                     row += size(a, lead)
-        if self._paged:
-            return self._mgr.blocks_in_use \
-                * self.serve_cfg.block_size * row + self.occupancy() * kept
-        return self.occupancy() * (self.cfg.max_seq * row + kept)
+        return self._mgr.blocks_in_use * self._mgr.block_size * row \
+            + self.occupancy() * kept
 
     def _finish(self, req: Request, status: str = STATUS_OK) -> None:
         self._results[req.rid] = np.concatenate(
@@ -1302,59 +1247,41 @@ class Engine:
         self.stats.count("finished" if status == STATUS_OK else status)
 
     def _release_slots(self, idxs: List[int]) -> None:
-        """Return slots to the free pool and re-poison their cache
-        rows in ONE pass: stale K/V must be provably inert, not
-        accidentally plausible.  Shared by eviction and the elastic
-        drain so the poisoning convention has a single home."""
-        if not idxs:
-            return
-        if self._paged:
-            bs = self.serve_cfg.block_size
-            for j in idxs:
-                req = self._slot_req[j]
-                if req is not None:
-                    # Register the written rows (prompt + emitted up to
-                    # the write frontier) before letting the pages go:
-                    # eviction, drain and preemption all leave the
-                    # prefix index able to hand the SAME pages back to
-                    # a re-admission — blocks-intact by content hash.
-                    n = int(self._pos[j])
-                    seq = np.concatenate(
-                        [np.asarray(req.prompt, np.int64),
-                         np.asarray(req.emitted, np.int64)])[:n]
-                    if n:
-                        ids = [int(self._table[j, bi])
-                               for bi in range(-(-n // bs))]
-                        self._mgr.register(seq, ids, n)
-                    held = [int(b) for b in self._table[j] if b >= 0]
-                    self._mgr.release(held)
-                    self._table[j, :] = -1
-                if self._prefilling[j]:
-                    self._prefilling[j] = False
-                    self._prefill_jobs = deque(
-                        job for job in self._prefill_jobs
-                        if job.slot != j)
-            for j in idxs:
-                self._slot_req[j] = None
-                self._tokens[j] = 0
-                self._pos[j] = 0
-            # No NaN poison: free pages are simply unmapped (-1 table
-            # entries), which read as zeros or are not read at all, and
-            # the causal frontier keeps stale mapped rows inert — same
-            # invariant, enforced by masking instead of poison.
-            return
+        """Return slots to the free pool and unmap their pages in ONE
+        pass.  Shared by eviction, preemption and the elastic drain so
+        the release convention has a single home.  Nothing is written
+        to the freed pages: unmapped (``-1`` table entries) they read as
+        zeros or are not read at all, and the causal frontier keeps
+        stale mapped rows inert."""
+        bs = self._mgr.block_size
+        for j in idxs:
+            req = self._slot_req[j]
+            if req is not None:
+                # Register the written rows (prompt + emitted up to
+                # the write frontier) before letting the pages go:
+                # eviction, drain and preemption all leave the
+                # prefix index able to hand the SAME pages back to
+                # a re-admission — blocks-intact by content hash.
+                n = int(self._pos[j])
+                seq = np.concatenate(
+                    [np.asarray(req.prompt, np.int64),
+                     np.asarray(req.emitted, np.int64)])[:n]
+                if n:
+                    ids = [int(self._table[j, bi])
+                           for bi in range(-(-n // bs))]
+                    self._mgr.register(seq, ids, n)
+                held = [int(b) for b in self._table[j] if b >= 0]
+                self._mgr.release(held)
+                self._table[j, :] = -1
+            if self._prefilling[j]:
+                self._prefilling[j] = False
+                self._prefill_jobs = deque(
+                    job for job in self._prefill_jobs
+                    if job.slot != j)
         for j in idxs:
             self._slot_req[j] = None
             self._tokens[j] = 0
             self._pos[j] = 0
-        if jnp.issubdtype(jnp.dtype(self._dtype), jnp.floating):
-            arr = jnp.asarray(idxs)
-            if self._spmd:
-                self._cache = jax.tree.map(
-                    lambda s: s.at[:, arr].set(jnp.nan), self._cache)
-            else:
-                self._cache = jax.tree.map(
-                    lambda s: s.at[arr].set(jnp.nan), self._cache)
 
     def _evict(self, j: int, status: str = STATUS_OK) -> None:
         req = self._slot_req[j]
@@ -1412,14 +1339,12 @@ class Engine:
                 self._expire_sweep(events)
             with span(SPAN_ADMIT):
                 self._admit(events)
-                if self._paged:
-                    self._prefill_tick(events)
-                    self._alloc_tick()
+                self._prefill_tick(events)
+                self._alloc_tick()
             active = [j for j, r in enumerate(self._slot_req)
                       if r is not None and not self._prefilling[j]]
             if not active:
-                if self._paged:
-                    self._pool_levels()
+                self._pool_levels()
                 return events
             with span(SPAN_DISPATCH):
                 # Ends when the step call has returned, not when the
@@ -1444,8 +1369,7 @@ class Engine:
                 # the device holds now, then the host's own arrays.
                 self._held = _advanced(np, self._held, toks, keys)
                 self.stats.tick(len(active), self.serve_cfg.slots)
-                if self._paged:
-                    self._count_pages(active)
+                self._count_pages(active)
                 syncs = self._select_syncs
                 for j in active:
                     req = self._slot_req[j]
@@ -1462,8 +1386,7 @@ class Engine:
                         self._evict(j)
                 self.stats.count("decode_select_syncs",
                                  self._select_syncs - syncs)
-                if self._paged:
-                    self._pool_levels()
+                self._pool_levels()
             return events
 
     def _fetch(self, out) -> np.ndarray:
@@ -1483,17 +1406,16 @@ class Engine:
 
     def _host_state(self) -> Dict[str, np.ndarray]:
         """The slot state as the host's arrays, the truth, have it:
-        ``tokens``, ``pos``, the ``live`` mask, a paged engine's
-        ``table``, and a sampling engine's ``keys``, the live slots'
+        ``tokens``, ``pos``, the ``live`` mask, the block ``table``,
+        and a sampling engine's ``keys``, the live slots'
         requests' keys as ``(slots, ...)`` raw key bits (zeros in the
         other rows; a greedy engine moves no key)."""
         slots = self.serve_cfg.slots
         live = np.asarray([self._slot_req[j] is not None
                            and not self._prefilling[j]
                            for j in range(slots)])
-        host = {"tokens": self._tokens, "pos": self._pos, "live": live}
-        if self._paged:
-            host["table"] = self._table
+        host = {"tokens": self._tokens, "pos": self._pos, "live": live,
+                "table": self._table}
         if self.serve_cfg.temperature > 0:
             rows = {j: np.asarray(self._slot_req[j].key)
                     for j in np.flatnonzero(live)}
@@ -1533,9 +1455,9 @@ class Engine:
         chose, still on the device: ``(slots,)`` tokens with the step's
         counters packed behind them (``moe_rows`` where a layer has
         experts), under SPMD stacked per rank.  The ``(slots, vocab)``
-        logits stay where they were computed.  A paged step takes the
-        pool over and writes into it, and every compiled step takes
-        the slot state over: whoever held ``self._cache``'s or
+        logits stay where they were computed.  The step takes the
+        pool over and writes into it, and a compiled step takes
+        the slot state over too: whoever held ``self._cache``'s or
         ``self._state``'s old leaves holds deleted arrays afterwards,
         as after an install."""
         span = self.stats.span
@@ -1605,21 +1527,20 @@ class Engine:
         for j, req in enumerate(self._slot_req):
             if req is not None:
                 recs.append(req)
-                if self._paged:
-                    # Block-table state rides the drain record: which
-                    # pages held this request's written rows, and how
-                    # many.  Re-admission into the same pool recovers
-                    # them through the content-addressed prefix index
-                    # (the registering _release_slots), so the ticket's
-                    # copy is the EXPLICIT form of what the hash chain
-                    # guarantees — drained paged requests re-admit with
-                    # their prefix-shared pages intact.
-                    n = int(self._pos[j])
-                    bs = self.serve_cfg.block_size
-                    pages[id(req)] = {
-                        "block_ids": [int(self._table[j, bi])
-                                      for bi in range(-(-n // bs))],
-                        "n_tokens": n}
+                # Block-table state rides the drain record: which pages
+                # held this request's written rows, and how many.
+                # Re-admission into the same pool recovers them through
+                # the content-addressed prefix index (the registering
+                # _release_slots), so the ticket's copy is the EXPLICIT
+                # form of what the hash chain guarantees — drained
+                # requests re-admit with their prefix-shared pages
+                # intact.
+                n = int(self._pos[j])
+                bs = self._mgr.block_size
+                pages[id(req)] = {
+                    "block_ids": [int(self._table[j, bi])
+                                  for bi in range(-(-n // bs))],
+                    "n_tokens": n}
         recs.extend(self._queue)
         return [{"rid": r.rid,
                  "prompt": np.array(r.prompt, copy=True),
@@ -1650,8 +1571,8 @@ class Engine:
     def drain(self) -> List[dict]:
         """Drain every unfinished request out of the engine: returns
         their records (prompt, tokens emitted so far, remaining budget,
-        the advanced sampling key) and releases their slots (cache rows
-        re-poisoned) and queue entries.  Finished results stay
+        the advanced sampling key) and releases their slots (pages
+        unmapped) and queue entries.  Finished results stay
         retrievable via :meth:`results`.  The elastic shrink/grow path:
         drain here, re-admit on the new world's engine through the
         ordinary admission POLICIES (``elastic.replan.readmit``)."""

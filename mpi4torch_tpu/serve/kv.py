@@ -272,22 +272,17 @@ def shard_block_tp(cfg: TransformerConfig, spec, blk, comm):
 
 
 def init_kv_cache_tp(cfg: TransformerConfig, slots: int, size: int,
-                     dtype=jnp.float32, poison: bool = False):
-    """Per-layer TP-sharded slot-table KV cache:
+                     dtype=jnp.float32):
+    """Per-layer TP-sharded slot-table KV cache, zeros:
     ``(slots, max_seq, kv_heads / size, head_dim)`` per rank — the GQA
     saving and the TP saving multiply, which is the whole point of
-    sharding the serving cache.
-
-    ``poison=True`` fills the buffers with NaN — the engine's free-slot
-    discipline: a poisoned slot that ever leaked into a live slot's
-    logits would be caught immediately (all per-slot compute is
-    row-local, and tests assert the inertness), while admission
-    overwrites the whole slot row so live slots never see the poison."""
-    fill = jnp.nan if poison and jnp.issubdtype(dtype, jnp.floating) \
-        else 0
+    sharding the serving cache.  What a whole-prompt prefill
+    (:func:`prefill_tp`) writes its rows into, one slot of it, and the
+    cache of the reference step :func:`decode_step_tp`; the engine's
+    own cache is the paged pool (:func:`init_kv_pool_tp`)."""
     return _cache_entries(
         cfg, (slots, cfg.max_seq), size,
-        lambda shape, dt=dtype: jnp.full(shape, fill, dt), slots,
+        lambda shape, dt=dtype: jnp.zeros(shape, dt), slots,
         jnp.promote_types(dtype, jnp.float32))
 
 
@@ -1015,6 +1010,14 @@ def decode_step_tp(cfg: TransformerConfig, shards, cache, tokens, pos,
     ``pos[slot]`` ``(slots,)``, updating this rank's KV-cache shard.
     Returns ``(logits (slots, vocab), new_cache)``.
 
+    **The reference step.**  The engine does not call this: it steps
+    through :func:`decode_step_paged` for every ``ServeConfig``.  This
+    is the step the paged view is held against — the same walk over a
+    dense ``(slots, max_seq)`` cache (:func:`init_kv_cache_tp`) with no
+    table, no scatter and no kernel — and the one oracle of a served
+    layer spec inside the package; a new mixer or cache entry writes
+    its view here too, so that the tests compare two independent reads.
+
     Per slot this is exactly ``models/transformer.decode_step``'s math
     (teacher-forcing equivalent to the training forward), vectorized
     over per-slot positions: the cache write is a
@@ -1036,12 +1039,12 @@ def decode_step_tp(cfg: TransformerConfig, shards, cache, tokens, pos,
 
     ``active`` (``(slots,)`` bool/int, optional) zeroes the FREE slots'
     rows of every collective payload before it touches the wire: a
-    poisoned free slot's NaN partial sums otherwise ride the allreduce
-    and trip PR 7's finite guard (``config.comm_finite_guard``) with a
-    false corruption attribution on healthy ranks.  Live rows pass
-    through the mask bit-identically (``where`` selects, never
-    scales), so the parity contract is untouched; the engine always
-    passes its slot-occupancy mask.
+    free slot's garbage (NaN where a caller poisons its rows) otherwise
+    rides the allreduce and trips PR 7's finite guard
+    (``config.comm_finite_guard``) with a false corruption attribution
+    on healthy ranks.  Live rows pass through the mask bit-identically
+    (``where`` selects, never scales), so the parity contract is
+    untouched.
 
     Inference-only: no VJP (serving never differentiates), and the
     sliding-window case attends the full buffer with the window mask

@@ -215,9 +215,9 @@ class ServeStats:
                  "prefix_hits", "prefix_misses", "prefill_tokens",
                  "cow_copies", "preempted",
                  "blocks_in_use", "blocks_free", "blocks_cached",
-                 # ISSUE 25: device writes dispatched when prefill rows
-                 # are installed into the cache (paged: pages x cache
-                 # leaves; dense: cache leaves).
+                 # ISSUE 25: dispatches of the compiled install that
+                 # writes prefill rows into the pool (one an install,
+                 # however many pages or cache leaves).
                  "install_writes",
                  # ISSUE 29: pages the paged decode steps' live slots
                  # held up to their frontiers (sum of pos // block_size

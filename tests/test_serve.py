@@ -594,8 +594,8 @@ class TestCensusAndLatencyTier:
         toks, state, cache = lowered.out_info
         # no expert layer, nothing counted behind the tokens
         assert (toks.shape, toks.dtype) == ((size, slots), jnp.int32)
-        assert set(state) == {"tokens", "pos", "live"} \
-            | ({"table"} if paged else set()) \
+        # the table rides for every block_size: one cache manager
+        assert set(state) == {"tokens", "pos", "live", "table"} \
             | ({"keys"} if temperature else set())
         # the state goes into the next step as it came out of this one
         assert jax.tree.map(lambda a: (a.shape, a.dtype), state) \
@@ -1124,7 +1124,7 @@ class TestPrefixSharing:
             CFG, params,
             serve.ServeConfig(slots=2, block_size=4,
                               cache_dtype=jnp.bfloat16))
-        assert eng._paged
+        assert eng._mgr.block_size == 4
         assert not eng._mgr.prefix_cache
         assert eng._chunk is None
         sys_p = np.arange(1, 9)
@@ -1270,6 +1270,53 @@ class TestPagedPoolAccounting:
         from mpi4torch_tpu.analyze.registry import serve_paging_problems
 
         assert serve_paging_problems() == []
+
+
+class TestOneCacheManager:
+    """``ServeConfig(block_size=0)`` is the paged pool at one page of
+    ``max_seq`` tokens a slot (``num_blocks = slots``, nothing shared):
+    the engine has one cache manager and no fork on the page size."""
+
+    @pytest.mark.parametrize("temperature", [0.0, 0.8],
+                             ids=["greedy", "sampled"])
+    @pytest.mark.parametrize(
+        "spmd", [{}, {"spmd": True, "nranks": 1},
+                 {"spmd": True, "nranks": 4}],
+        ids=["eager", "spmd1", "spmd4"])
+    def test_block_size_zero_is_one_page_a_slot(self, spmd, temperature):
+        params = _params(CFG)
+        slots = 2
+        keys = None if not temperature else [
+            jax.random.PRNGKey(7 + i) for i in range(len(PROMPTS))]
+
+        def served(**paging):
+            eng = serve.Engine(
+                CFG, params,
+                serve.ServeConfig(slots=slots, temperature=temperature,
+                                  top_k=5 if temperature else 0, **paging),
+                **spmd)
+            for i, (p, n) in enumerate(zip(PROMPTS, BUDGETS)):
+                eng.submit(p, max_new=n,
+                           key=None if keys is None else keys[i])
+            resident = []
+            while eng.pending():           # 4 requests through 2 slots
+                eng.step()
+                resident.append(eng.kv_bytes_resident())
+            return eng, resident
+
+        plain, resident = served()
+        paged, resident_paged = served(
+            block_size=CFG.max_seq, num_blocks=slots, prefix_cache=False)
+        assert plain.results().keys() == paged.results().keys()
+        for rid, toks in plain.results().items():
+            np.testing.assert_array_equal(toks, paged.results()[rid])
+        assert plain.slot_log == paged.slot_log
+        assert len(plain.slot_log) > slots          # slots were reused
+        assert plain.statuses() == paged.statuses()
+        assert resident == resident_paged and max(resident) > 0
+        if spmd:
+            assert plain.lower_step().as_text(debug_info=False) \
+                == paged.lower_step().as_text(debug_info=False)
 
 
 class TestPagedNoRetrace:
@@ -1626,12 +1673,18 @@ class TestPagedStepInPlace:
         assert eng.stats.counters["decode_grid_steps"] \
             == 3 * eng._grid_steps
 
-    def test_dense_engine_counts_no_pages(self):
+    def test_dense_engine_counts_one_page_a_slot(self):
+        """``block_size=0`` is the pool at one page of ``max_seq``
+        tokens a slot: each of the request's two decode steps holds,
+        and off the TPU gathers, its slot's one page."""
         eng = serve.Engine(CFG, _params(CFG), serve.ServeConfig(slots=2))
+        assert (eng._mgr.block_size, eng._mgr.num_blocks) \
+            == (CFG.max_seq, 2)
+        assert not eng._mgr.prefix_cache
         eng.submit(PROMPTS[0], max_new=3)
         eng.run()
-        assert eng.stats.counters["decode_pages_live"] == 0
-        assert eng.stats.counters["decode_pages_read"] == 0
+        assert eng.stats.counters["decode_pages_live"] == 2
+        assert eng.stats.counters["decode_pages_read"] == 2
         assert eng.stats.counters["decode_grid_steps"] == 0
 
     def test_engine_asks_the_kernels_own_predicate(self, monkeypatch):

@@ -30,7 +30,6 @@ from mpi4torch_tpu.utils import profiling as P
 CFG = T.TransformerConfig(vocab=37, d_model=16, n_heads=4, n_layers=2,
                           d_ff=32, max_seq=40)
 BLOCK = 4
-LEAVES = 2 * CFG.n_layers            # a K and a V leaf per layer
 
 ADMIT_PHASES = [E.SPAN_PLAN, E.SPAN_PREFILL, E.SPAN_INSTALL,
                 E.SPAN_FIRST_TOKEN]
@@ -119,7 +118,7 @@ class TestStepSpans:
             (span,) = [s for s in rec["spans"] if s[0] == phase]
             assert span[3] is None
         assert rec["admitted"] == 1 and rec["active"] == 1
-        assert rec["prefill_tokens"] == (5 if paged else 0)
+        assert rec["prefill_tokens"] == 5
 
     def test_decode_only_step(self, params, paged, spmd):
         eng = make_engine(params, paged, spmd)
@@ -166,10 +165,11 @@ class TestStepSpans:
         slot state ``_step_inputs`` sent up in it, those in which the
         host's state differs from what the device holds: after an
         admission what the admission wrote (tokens, positions, live
-        mask, the table of a paged engine, the keys where the engine
-        samples); in a decode-only step behind a decode-only step
-        nothing, and no transfer is made; where a slot crosses a page,
-        the table alone."""
+        mask, the table, the keys where the engine samples); in a
+        decode-only step behind a decode-only step nothing, and no
+        transfer is made; where a slot crosses a page (never at
+        ``block_size=0``: a slot's one page is its whole extent), the
+        table alone."""
         for temperature, keys in ((0.0, 0), (0.7, 1)):
             eng = make_engine(params, paged, spmd, temperature=temperature)
             sent, inputs = [], eng._step_inputs
@@ -192,7 +192,7 @@ class TestStepSpans:
 
             eng._step_inputs = watched
             key = jax.random.PRNGKey(3) if keys else None
-            everything = (1 if paged else 0) + 3 + keys
+            everything = 4 + keys
             # prompt of 5 at pages of 4: the slot holds rows 0..7 and
             # crosses into its third page when it writes position 8.
             eng.submit(np.arange(1, 6), max_new=8, key=key)
@@ -263,8 +263,8 @@ class TestStepSpans:
             writes.append(rec["install_writes"])
         assert counts[0] == counts[1] == 10 + 4
         # install_writes: one dispatch an install, however many pages
-        # (dense: one eager write a cache leaf).
-        assert writes == ([1, 1] if paged else [LEAVES, LEAVES])
+        # or cache leaves.
+        assert writes == [1, 1]
 
     def test_phase_totals_sum_the_log(self, params, paged, spmd):
         eng = make_engine(params, paged, spmd)
@@ -325,7 +325,7 @@ def test_a_wrapped_select_feeds_the_next_step(params, paged, spmd):
     for a, b, c in zip(kept, all_up, plain):
         np.testing.assert_array_equal(a, b)
         assert not np.array_equal(a, c)
-    everything = (1 if paged else 0) + 3
+    everything = 4
     assert [r["decode_uploads"] for r in every] \
         == [everything] * len(every)
     # Behind the admitting step the tokens alone go up, and the table
@@ -363,8 +363,7 @@ def test_a_new_prompt_length_names_the_step_that_compiled(params, paged,
     eng.submit(np.arange(1, 1 + n) % CFG.vocab, rid="again")
     eng.step()
     again = log_of(eng)[-1]
-    assert again["admitted"] == 1 and again["prefill_tokens"] == \
-        (n if paged else 0)
+    assert again["admitted"] == 1 and again["prefill_tokens"] == n
     assert again["step_compiles"] == 0 and "compiles" not in again
     assert eng.stats.counters["step_compiles"] \
         == sum(r["step_compiles"] for r in log_of(eng))
