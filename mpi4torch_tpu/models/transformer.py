@@ -11,11 +11,13 @@ thread-SPMD runtime, inside ``run_spmd``, or in a user-managed 2D
 ``shard_map`` via ``comm_from_mesh`` (the intended TPU deployment).
 
 A configuration may state its stack as data (``TransformerConfig.layers``,
-one :class:`LayerSpec` a layer): Kimi Delta Attention, latent attention
-or a Mamba-2 state-space mixer as the mixer, the held share of a top-k
-expert layer as the FFN, or a layer of one of the two parts alone
-(doc/layer_spec.md).  KDA runs on the training path only and Mamba-2 on
-the serving path only; latent attention and the expert share run on both
+one :class:`LayerSpec` a layer): Kimi Delta Attention, latent attention,
+a Mamba-2 state-space mixer or grouped-query attention with a window, a
+rotation, normed queries and keys and an output gate of its own as the
+mixer, the held share of a top-k expert layer as the FFN, or a layer of
+one of the two parts alone (doc/layer_spec.md).  KDA runs on the
+training path only and Mamba-2 on the serving path only; latent
+attention, the attention mixer and the expert share run on both
 (serve/kv.py).
 
 TPU-first shapes: all compute is batched matmul/einsum (MXU), parameters
@@ -181,11 +183,56 @@ class MLA:
 
 
 @dataclass(frozen=True)
+class GQA:
+    """Mixer: grouped-query softmax attention stated on the layer, for a
+    stack whose attention layers differ.  ``n_heads`` query heads and
+    ``n_kv_heads`` key-value heads of ``head_dim`` channels (query head
+    ``h`` reads KV head ``h // (n_heads // n_kv_heads)``; ``n_heads *
+    head_dim`` need not be the stream's width).  ``window > 0``: a
+    position reads itself and the ``window - 1`` before it, and on the
+    serving path the layer's pages lie in the WINDOW class, which holds
+    the pages the window touches and frees the rest (serve/paging.py);
+    ``0``: every position at or before it.  ``rope`` rotates every
+    channel of the queries and keys by position at ``rope_theta``;
+    without it the layer has no position signal of its own.
+    ``qk_norm``: an rmsnorm with a learned scale over each query head's
+    and each key head's channels, before the rotation (leaves
+    ``q_norm``, ``k_norm``).  ``gate``: the attention's output times
+    ``sigmoid`` of a projection of the layer's normed input, head by
+    head and channel by channel, before the output projection (the last
+    ``n_heads * head_dim`` columns of the fused ``wqkv``)."""
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    window: int = 0
+    rope: bool = False
+    rope_theta: float = 10000.0
+    qk_norm: bool = False
+    gate: bool = False
+
+    def __post_init__(self):
+        if self.n_kv_heads < 1 or self.n_heads % self.n_kv_heads:
+            raise ValueError(
+                f"n_heads={self.n_heads} must be a positive multiple of "
+                f"n_kv_heads={self.n_kv_heads}")
+        if self.window < 0:
+            raise ValueError(
+                f"GQA.window must be >= 0 (0 = every position before), "
+                f"got {self.window}")
+        if self.rope and self.head_dim % 2:
+            raise ValueError(
+                f"rope pairs channels: head_dim={self.head_dim} must be "
+                "even")
+
+
+@dataclass(frozen=True)
 class LayerSpec:
     """One layer of a stack whose layers differ.  ``mixer``: ``None`` is
     the configuration's own attention (``n_heads``, ``n_kv_heads``,
-    ``rope``, ``attn_window``), else a :class:`KDA`, an :class:`MLA` or
-    a :class:`Mamba2`.
+    ``rope``, ``attn_window``), else a :class:`KDA`, an :class:`MLA`, a
+    :class:`Mamba2` or a :class:`GQA` (attention with head counts, a
+    window and a rotation of the LAYER's own, which may stand beside an
+    expert FFN and post-norms).
     ``ffn``: ``None`` is the configuration's dense FFN (``ffn``,
     ``d_ff``), else the :class:`~mpi4torch_tpu.parallel.moe.Experts`
     share this rank holds.  ``post_norm`` puts a second norm on each
@@ -207,7 +254,7 @@ class LayerSpec:
     no ``ln1``, no mixer: ``mixer`` stays ``None`` and names nothing).
     One norm and one residual sum a layer; no second norm and no
     shortcut."""
-    mixer: Union[None, KDA, MLA, Mamba2] = None
+    mixer: Union[None, KDA, MLA, Mamba2, GQA] = None
     ffn: Optional[Experts] = None
     post_norm: bool = False
     branch: Optional[Experts] = None
@@ -241,7 +288,12 @@ class TransformerConfig:
     ``nope`` gives the configuration's own attention no position signal
     at all: no rotation (``rope`` must be off) and no learned table (no
     ``pos`` leaf), for a stack whose other layers carry the order (the
-    attention layers of a state-space hybrid)."""
+    attention layers of a state-space hybrid).
+
+    ``embed_scale`` multiplies the embedding rows a token looks up
+    (:func:`embed_tokens`): ``sqrt(d_model)`` for a model trained with
+    unit-size embeddings under a width-independent parametrisation;
+    ``1.0`` leaves the rows as they are."""
     vocab: int
     d_model: int
     n_heads: int
@@ -260,6 +312,7 @@ class TransformerConfig:
     aux_coef: float = 0.01
     remat: bool = False
     layers: Tuple[LayerSpec, ...] = ()
+    embed_scale: float = 1.0
 
     def __post_init__(self):
         if self.layers:
@@ -289,8 +342,9 @@ class TransformerConfig:
                    for s in self.layers):
                 raise ValueError(
                     "an expert FFN, a post-norm or a shortcut branch needs "
-                    "a KDA or MLA mixer: the configuration's own attention "
-                    "block carries its own FFN and norms")
+                    "a KDA or MLA mixer, or attention stated on the layer "
+                    "(GQA): the configuration's own attention block "
+                    "carries its own FFN and norms")
             scoring = None
             for i, s in enumerate(self.layers):
                 index = getattr(s.mixer, "index", None)
@@ -445,8 +499,8 @@ def init_transformer(key, cfg: TransformerConfig,
 
 
 def _init_mixer(key, spec, d_model: int, dtype) -> Dict[str, Any]:
-    """Leaves of a :class:`KDA`, :class:`MLA` or :class:`Mamba2`
-    mixer."""
+    """Leaves of a :class:`KDA`, :class:`MLA`, :class:`Mamba2` or
+    :class:`GQA` mixer."""
     ks = iter(jax.random.split(key, 10))
 
     def dense(m, n):
@@ -473,6 +527,15 @@ def _init_mixer(key, spec, d_model: int, dtype) -> Dict[str, Any]:
                 "k_norm": {"scale": jnp.ones((ix.head_dim,), dtype),
                            "bias": jnp.zeros((ix.head_dim,), dtype)},
                 "ww": dense(d_model, ix.n_heads)}
+        return out
+    if isinstance(spec, GQA):
+        h, h_kv, hd = spec.n_heads, spec.n_kv_heads, spec.head_dim
+        # One fused projection: [q | k | v], and the gate behind them.
+        out = {"wqkv": dense(d_model, (h * (1 + spec.gate) + 2 * h_kv) * hd),
+               "wo": dense(h * hd, d_model)}
+        if spec.qk_norm:
+            out["q_norm"] = {"scale": jnp.ones((hd,), dtype)}
+            out["k_norm"] = {"scale": jnp.ones((hd,), dtype)}
         return out
     if isinstance(spec, Mamba2):
         h = spec.n_heads
@@ -538,7 +601,8 @@ def _rope_rotate(cfg: TransformerConfig, x, positions):
     continuous-batching decode path (:mod:`mpi4torch_tpu.serve`) where
     every slot of the batch sits at its own position.  The rotation is
     per head-dim channel, so tensor-parallel head sharding composes
-    unchanged either way."""
+    unchanged either way.  ``cfg`` is read for ``rope_theta`` alone: a
+    :class:`GQA` mixer, which states its own, is passed in its place."""
     hd = x.shape[-1]
     half = hd // 2
     ct = _compute_dtype_rope(x)
@@ -585,6 +649,74 @@ def _split_qkv(cfg: TransformerConfig, blk, y, positions=None,
         q = _rope_rotate(cfg, q, positions)
         k = _rope_rotate(cfg, k, positions)
     return q, k, v
+
+
+def embed_tokens(cfg: TransformerConfig, params, tokens):
+    """The embedding rows of ``tokens``, times ``cfg.embed_scale``
+    where it is not 1 (the product in at least float32, rounded once):
+    what every forward pass starts from, training and serving."""
+    x = params["embed"][tokens]
+    if cfg.embed_scale == 1.0:
+        return x
+    return (x.astype(jnp.promote_types(x.dtype, jnp.float32))
+            * cfg.embed_scale).astype(x.dtype)
+
+
+def gqa_project(spec: GQA, p, y, positions):
+    """The projections of a :class:`GQA` mixer on the normed input ``y``
+    ``(b, s, d)``, the ONE place they live (the training forward and the
+    serving walk both come through here): ``(q, k, v, g)`` with ``q``
+    ``(b, s, n_heads, head_dim)``, ``k`` and ``v`` ``(b, s, n_kv_heads,
+    head_dim)``, queries and keys normed head by head (``qk_norm``) and
+    then rotated by ``positions`` (``rope``; ``(s,)`` or ``(b, s)``), so
+    that ``k`` and ``v`` are what a serving cache row holds; and ``g``
+    ``(b, s, n_heads, head_dim)`` the gate before its sigmoid, ``None``
+    without one."""
+    b, s, _ = y.shape
+    h, h_kv, hd = spec.n_heads, spec.n_kv_heads, spec.head_dim
+    qkv = y @ p["wqkv"]
+    q = qkv[..., :h * hd].reshape(b, s, h, hd)
+    k = qkv[..., h * hd:(h + h_kv) * hd].reshape(b, s, h_kv, hd)
+    v = qkv[..., (h + h_kv) * hd:(h + 2 * h_kv) * hd].reshape(
+        b, s, h_kv, hd)
+    g = qkv[..., (h + 2 * h_kv) * hd:].reshape(b, s, h, hd) \
+        if spec.gate else None
+    if spec.qk_norm:
+        q, k = _rms_norm(q, p["q_norm"]), _rms_norm(k, p["k_norm"])
+    if spec.rope:
+        if positions is None:
+            raise ValueError("GQA(rope=True) requires the caller's positions")
+        q = _rope_rotate(spec, q, positions)
+        k = _rope_rotate(spec, k, positions)
+    return q, k, v, g
+
+
+def gqa_out(spec: GQA, p, o, g):
+    """What follows a :class:`GQA` mixer's attention: ``o`` ``(..., n_heads,
+    head_dim)`` under the gate ``sigmoid(g)`` (the product in at least
+    float32, rounded once) where the mixer has one, then the output
+    projection: ``(..., d)``."""
+    if g is not None:
+        ct = jnp.promote_types(o.dtype, jnp.float32)
+        o = (o.astype(ct) * jax.nn.sigmoid(g.astype(ct).reshape(o.shape))
+             ).astype(o.dtype)
+    return o.reshape(*o.shape[:-2], -1) @ p["wo"]
+
+
+def gqa_scope(spec: GQA):
+    """The scope a :class:`GQA` mixer's attention itself runs under,
+    inside the mixer's ``layer_scope("attn")``: ``attn_window`` or
+    ``attn_full`` (cache write and read on the serving path)."""
+    return layer_scope("attn_window" if spec.window else "attn_full")
+
+
+def _gqa_mixer(spec: GQA, p, y, positions):
+    """Attention stated on the layer, on the normed input ``y``: the
+    flash path under the layer's own window."""
+    q, k, v, g = gqa_project(spec, p, y, positions)
+    with gqa_scope(spec):
+        o = flash_attention(q, k, v, causal=True, window=spec.window)
+    return gqa_out(spec, p, o, g)
 
 
 @jax.custom_vjp
@@ -1085,7 +1217,7 @@ def forward(cfg: TransformerConfig, params, tokens, comm_sp=None,
 
     With ``cfg.layers`` the loop walks the spec: each layer's mixer and
     FFN are what its :class:`LayerSpec` names, the new kinds each under
-    its scope (``mpi4torch.kda`` / ``.mla`` / ``.moe``).
+    its scope (``mpi4torch.kda`` / ``.mla`` / ``.attn`` / ``.moe``).
     """
     out, aux, _ = _forward(cfg, params, tokens, comm_sp, attn, comm_ep,
                            return_hidden)
@@ -1126,7 +1258,7 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
         positions = _zigzag_positions(comm_sp, s_local)
     else:
         positions = offset + jnp.arange(s_local, dtype=jnp.int32)
-    x = params["embed"][tokens]
+    x = embed_tokens(cfg, params, tokens)
     if cfg.pos_table:
         if zigzag_sharded:
             x = x + jnp.take(params["pos"], positions, axis=0)[None]
@@ -1170,6 +1302,10 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
             with layer_scope("kda"):
                 return x + branch_norm(cfg, spec, blk, _kda_mixer(
                     spec.mixer, blk["mixer"], y), "ln1_post")
+        if isinstance(spec.mixer, GQA):
+            with layer_scope("attn"):
+                return x + branch_norm(cfg, spec, blk, _gqa_mixer(
+                    spec.mixer, blk["mixer"], y, positions), "ln1_post")
         with layer_scope("mla"):
             return x + branch_norm(cfg, spec, blk, _mla_mixer(
                 cfg, spec.mixer, blk["mixer"], y, positions), "ln1_post")
@@ -1296,7 +1432,7 @@ def decode_step(cfg: TransformerConfig, params, cache, tokens, pos):
         pass
     pos = jnp.asarray(pos, jnp.int32)
 
-    x = params["embed"][tokens]
+    x = embed_tokens(cfg, params, tokens)
     if cfg.pos_table:
         x = x + jax.lax.dynamic_slice_in_dim(params["pos"], pos, 1, 0)[0]
 
@@ -1351,7 +1487,7 @@ def prefill(cfg: TransformerConfig, params, cache, prompt):
     return ``(last_logits (batch, vocab), new_cache)``."""
     refuse_layer_spec(cfg, "prefill")
     b, p_len = prompt.shape
-    x = params["embed"][prompt]
+    x = embed_tokens(cfg, params, prompt)
     if cfg.pos_table:
         x = x + params["pos"][None, :p_len]
     new_cache = []

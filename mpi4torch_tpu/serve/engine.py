@@ -54,6 +54,7 @@ Two execution modes behind one engine:
 
 from __future__ import annotations
 
+import functools
 import math
 import time
 from collections import deque
@@ -66,7 +67,7 @@ import numpy as np
 from jax.sharding import PartitionSpec
 
 from ..comm import COMM_WORLD
-from ..models.transformer import TransformerConfig, select_token
+from ..models.transformer import GQA, TransformerConfig, select_token
 from ..ops import paged_attention as _paged_attn
 from ..runtime import CommError
 from ..utils import profiling as _prof
@@ -248,7 +249,16 @@ class ServeConfig:
     bound).  Both exactness-gate on ``cache_dtype`` matching the
     parameter dtype (a down-cast cache would re-quantize shared prefix
     rows the per-request oracle keeps at full precision); the gate
-    disables sharing/chunking, never bitwise parity."""
+    disables sharing/chunking, never bitwise parity.
+
+    **Two classes of pages (ISSUE 45).**  Layers whose attention reads a
+    sliding window (a ``GQA`` mixer with a window) keep their pages in a
+    class of their own, of ``window_blocks`` page ids (None = what every
+    slot can hold at once: ``slots`` x the pages a window touches): a
+    slot holds the pages its window touches there and gives back the
+    rest while it decodes (:mod:`.paging`).  Not read for a
+    configuration without such a layer; with one, ``prefix_cache`` and
+    ``prefill_chunk`` are refused (``kv.validate_tp``)."""
     slots: int = 4
     max_new: int = 16
     eos: Optional[int] = None
@@ -264,6 +274,7 @@ class ServeConfig:
     num_blocks: Optional[int] = None
     prefix_cache: bool = True
     prefill_chunk: Optional[int] = None
+    window_blocks: Optional[int] = None
 
     def __post_init__(self):
         if self.slots < 1:
@@ -294,6 +305,10 @@ class ServeConfig:
         if self.num_blocks is not None and self.num_blocks < 1:
             raise ValueError(
                 f"num_blocks must be >= 1 or None, got {self.num_blocks}")
+        if self.window_blocks is not None and self.window_blocks < 1:
+            raise ValueError(
+                f"window_blocks must be >= 1 or None, got "
+                f"{self.window_blocks}")
         if self.prefill_chunk is not None:
             if self.block_size == 0:
                 raise ValueError(
@@ -468,13 +483,28 @@ class Engine:
         self._blocks_per_seq = cfg.max_seq // bs
         if nb is None:
             nb = slots * self._blocks_per_seq
-        cache = _kv.init_kv_pool_tp(cfg, nb, bs, self._size,
-                                    self._dtype, slots=slots)
+        # The class of pages each layer's entry lies in.  A window
+        # class (layers that read a sliding window) has a pool extent,
+        # a population of the manager and a table of its own.
+        self._classes = _kv.page_classes(cfg)
+        window = _kv.window_of(cfg)
+        nb_w = 0
+        if window:
+            nb_w = self.serve_cfg.window_blocks or slots * min(
+                _paging.pages_touched(window, bs), self._blocks_per_seq)
+        # The pool's leaves as shapes: each is made below, where it is
+        # to lie.
+        cache = jax.eval_shape(lambda: _kv.init_kv_pool_tp(
+            cfg, nb, bs, self._size, self._dtype, slots=slots,
+            window_blocks=nb_w))
         self._mgr = _paging.BlockManager(
-            nb, bs, prefix_cache=share and self._exact_kv)
-        # Host-side block table, mirrored into the step as DATA.
+            nb, bs, prefix_cache=share and self._exact_kv, window=window,
+            window_blocks=nb_w)
+        # Host-side block tables, mirrored into the step as DATA: the
+        # full class's, and the window class's where there is one.
         self._table = np.full((slots, self._blocks_per_seq), -1,
                               np.int32)
+        self._table_w = np.full_like(self._table, -1) if window else None
         self._prefill_jobs: deque = deque()
         self._admit_seq = 0                  # preemption-victim order
         self._slot_seq = [0] * slots
@@ -489,25 +519,20 @@ class Engine:
         # exactly the rank-major layout run_spmd's outputs carry, and
         # laid out as they are (read off the shards it just produced),
         # so the state round-trips step to step unchanged and the
-        # install can reuse its buffers from the first call.  The pool
-        # also gets a buffer of its own per leaf (the template shares
-        # one): the install donates the whole pool, and one buffer
-        # cannot be donated twice in a call.  All of it leaf by
-        # leaf: a second whole pool, even for a moment, would be the
-        # peak of the process.
+        # install can reuse its buffers from the first call.  Every
+        # leaf is a buffer of its own (the install donates the whole
+        # pool, and one buffer cannot be donated twice in a call), made
+        # as zeros where it is to lie and nowhere else first: a template
+        # of the pool beside it, or a leaf's copies on their way there,
+        # were the peak of the process (a class of one layer is ONE
+        # leaf of 1.1 GB in `serve_swa_mix_16k`).
         state = self._state_sharding = \
             jax.tree.leaves(self._shards)[0].sharding if self._spmd \
             else None
-
-        def own(a):
-            if self._spmd:
-                a = jax.device_put(
-                    jnp.broadcast_to(a[None], (self._size,) + a.shape),
-                    state)
-            return jnp.copy(a)
-
-        cache = jax.tree.map(own, cache)
-        self._cache = cache
+        lead = (self._size,) if self._spmd else ()
+        self._cache = jax.tree.map(
+            lambda a: jnp.zeros(lead + a.shape, a.dtype, device=state),
+            cache)
         # Built here, first called in step(): the engine may be
         # constructed with jit disabled.
         self._install_call = self._build_install()
@@ -608,9 +633,12 @@ class Engine:
                     entry["c"], spec.mixer.kv_rank)
             else:
                 staged = (entry["k"], entry["v"])
+                heads, hd = (spec.mixer.n_heads, spec.mixer.head_dim) \
+                    if isinstance(spec.mixer, GQA) else (
+                        cfg.n_heads // self._size,
+                        cfg.d_model // cfg.n_heads)
                 by_kernel = _paged_attn.uses_kernel(
-                    like(slots, cfg.n_heads // self._size,
-                         cfg.d_model // cfg.n_heads), entry["k"])
+                    like(slots, heads, hd), entry["k"])
             steps[spec.mixer] = by_kernel and math.prod(
                 _paged_attn.read_grid(slots, self._blocks_per_seq, *staged))
         return max(steps.values(), default=0) if all(steps.values()) else 0
@@ -632,10 +660,12 @@ class Engine:
         then :meth:`_advance`.  Returns ``(chosen, next state, new
         pool)``."""
         stats = {}
+        table = state["table"] if self._table_w is None else {
+            "full": state["table"], "window": state["table_window"]}
         # Eager, the step takes each pool leaf over itself; the
         # compiled one donates through run_spmd.
         logits, cache = _kv.decode_step_paged(
-            self.cfg, shards, cache, state["table"], state["tokens"],
+            self.cfg, shards, cache, table, state["tokens"],
             state["pos"], self._comm, overlap=self.serve_cfg.overlap,
             algorithm=self.serve_cfg.algorithm, active=state["live"],
             donate=not self._spmd, stats=stats)
@@ -726,6 +756,13 @@ class Engine:
                 f"{need} pages of {bs} tokens; the pool has only "
                 f"{self._mgr.num_blocks} — raise num_blocks or "
                 "shrink the request")
+        w = self._mgr.window
+        if w is not None and min(need, w.pages_a_slot) > w.num_blocks:
+            raise ValueError(
+                f"prompt {prompt.size} + n_new {budget} holds "
+                f"{min(need, w.pages_a_slot)} pages of the window class "
+                f"at once; its pool has only {w.num_blocks} — raise "
+                "window_blocks")
         if self.serve_cfg.temperature > 0 and key is None:
             raise ValueError("temperature > 0 requires a PRNG `key`")
         if key is not None and jnp.issubdtype(key.dtype,
@@ -943,15 +980,17 @@ class Engine:
         ``jax.jit``; under SPMD each rank writes its own slice of the
         stacked state in place, and the output keeps the state's
         layout."""
+        install = _kv.install_rows_paged if self._table_w is None \
+            else functools.partial(_kv.install_rows_paged,
+                                   classes=self._classes)
         if not self._spmd:
-            return jax.jit(_kv.install_rows_paged, donate_argnums=0)
+            return jax.jit(install, donate_argnums=0)
         state = jax.tree.leaves(self._cache)[0].sharding
 
         def per_rank(pool, rows, *index):
             (pool, rows) = jax.tree.map(lambda a: a[0], (pool, rows))
             return jax.tree.map(
-                lambda a: a[None],
-                _kv.install_rows_paged(pool, rows, *index))
+                lambda a: a[None], install(pool, rows, *index))
 
         # The page index, and the slot where a layer keeps a state.
         index = (PartitionSpec(),) * (1 + self._stateful)
@@ -977,11 +1016,23 @@ class Engine:
         touched = -(-hi // bs) - first
         n_pages = _kv.install_page_count(
             _kv.first_paged_leaf(rows).shape[-3], bs)
-        index = np.empty(2 + n_pages, np.int32)
-        index[0], index[1] = lo % bs, hi - lo
-        # Beyond the touched pages: ids outside the pool, dropped.
-        index[2:] = self._mgr.num_blocks + np.arange(n_pages)
-        index[2:2 + touched] = self._table[j, first:first + touched]
+
+        def index_of(table, mgr):
+            index = np.empty(2 + n_pages, np.int32)
+            index[0], index[1] = lo % bs, hi - lo
+            # Beyond the touched pages, and where the slot holds none
+            # (a window layer's pages behind its window): ids outside
+            # the pool, dropped.
+            index[2:] = mgr.num_blocks + np.arange(n_pages)
+            held = table[j, first:first + touched]
+            index[2:2 + touched] = np.where(held >= 0, held,
+                                            index[2:2 + touched])
+            return index
+
+        index = index_of(self._table, self._mgr)
+        if self._table_w is not None:
+            index = {"full": index,
+                     "window": index_of(self._table_w, self._mgr.window)}
         slot = (np.int32(j),) if self._stateful else ()
         self._cache = self._install_call(self._cache, rows, index, *slot)
         self.stats.count("install_writes")
@@ -992,14 +1043,28 @@ class Engine:
         engine compiled (the same pages through the kernel; every
         slot's whole table row through the gather), and the grid steps
         one call of that kernel walks for them (every slot's, live or
-        free: the grid is the program's)."""
+        free: the grid is the program's); each summed over the tables
+        where the pool has two classes.  With a window class also
+        ``window_pages_held`` and ``window_slots_live``: the pages of
+        that class the live slots hold by its table, and those slots."""
         bs = self._mgr.block_size
         held = sum(int(self._pos[j]) // bs + 1 for j in active)
+        tables, w = 1, self._mgr.window
+        if w is not None:
+            # A second table, the window class's: its reads visit the
+            # pages from the window's first on, which is what the slots
+            # hold of it while pages behind the window are released.
+            tables = 2
+            held += sum(int(self._pos[j]) // bs + 1
+                        - w.first_page(self._pos[j]) for j in active)
+            self.stats.count("window_pages_held",
+                             int(np.sum(self._table_w[active] >= 0)))
+            self.stats.count("window_slots_live", len(active))
         self.stats.count("decode_pages_live", held)
         self.stats.count("decode_pages_read",
                          held if self._kernel_read
-                         else len(active) * self._blocks_per_seq)
-        self.stats.count("decode_grid_steps", self._grid_steps)
+                         else tables * len(active) * self._blocks_per_seq)
+        self.stats.count("decode_grid_steps", tables * self._grid_steps)
 
     def _gather_past(self, j: int, n: int):
         """Exact-length past K/V (positions ``0..n-1``) for slot ``j``,
@@ -1043,8 +1108,21 @@ class Engine:
         fresh = self._mgr.alloc(n_new)
         if fresh is None:
             return None
+        w = self._mgr.window
+        if w is not None:
+            # The window class: pages from the first one the first
+            # decode step (at position p_len) reads; the prompt's
+            # earlier rows are behind the window before they are needed
+            # and are never installed.
+            first_w = w.first_page(p_len)
+            fresh_w = w.alloc(total - first_w)
+            if fresh_w is None:
+                self._mgr.release(fresh)
+                return None
         self._mgr.ref(shared)
         j = self._free_slots()[0]
+        if w is not None:
+            self._table_w[j, first_w:total] = fresh_w
         for bi in range(l0 // bs):
             self._table[j, bi] = shared[bi]
         for i, bi in enumerate(range(l0 // bs, total)):
@@ -1198,28 +1276,50 @@ class Engine:
         until the allocation lands — the preempted victim's pages go
         cached-then-evictable, so each round frees real capacity and
         the loop terminates (a request too big to EVER fit is rejected
-        at submit)."""
+        at submit).  With a window class a slot needs the page in both
+        tables, and either class running dry preempts."""
         bs = self._mgr.block_size
+        paged = [(self._table, self._mgr)]
+        if self._table_w is not None:
+            paged.append((self._table_w, self._mgr.window))
         for j in range(self.serve_cfg.slots):
             while True:
                 req = self._slot_req[j]
                 if req is None or self._prefilling[j]:
                     break
                 bi = int(self._pos[j]) // bs
-                if self._table[j, bi] >= 0:
-                    break
-                got = self._mgr.alloc(1)
-                if got is not None:
-                    self._table[j, bi] = got[0]
+                # Class by class: a page from each that lacks one.
+                for table, mgr in paged:
+                    if table[j, bi] < 0:
+                        got = mgr.alloc(1)
+                        if got is None:
+                            break
+                        table[j, bi] = got[0]
+                else:
                     break
                 if not self._preempt_one():
                     break
+
+    def _release_behind(self, j: int) -> None:
+        """Give back the window-class page slot ``j``'s window has left:
+        called behind the step that last read it, with the slot's
+        position already advanced, so at most one page lies behind the
+        next step's first.  The entry goes to ``-1`` (the read never
+        names a page behind its span) and the page to the class's free
+        list, for any slot's next allocation."""
+        w = self._mgr.window
+        before = w.first_page(self._pos[j]) - 1
+        if before >= 0 and self._table_w[j, before] >= 0:
+            w.release([int(self._table_w[j, before])])
+            self._table_w[j, before] = -1
+            self.stats.count("window_pages_freed")
 
     def kv_bytes_resident(self) -> int:
         """Deterministic KV-residency census (one rank's shard): bytes
         of cache RESERVED for request state right now — the in-use
         pages (a shared prefix counted once; at ``block_size=0`` every
-        occupied slot's one page of ``max_seq`` rows).  It is a census,
+        occupied slot's one page of ``max_seq`` rows), each class's at
+        the bytes a page of its layers holds.  It is a census,
         not a timer, so it regresses deterministically on CPU smoke."""
         # One token's rows over every cache leaf (a leaf is (..., rows
         # of a slot or a page, *row shape), behind the stacked axis),
@@ -1228,15 +1328,17 @@ class Engine:
         lead = 3 if self._spmd else 2
         size = lambda a, lead: int(np.prod(a.shape[lead:])) \
             * a.dtype.itemsize
-        row = kept = 0
-        for entry in self._cache:
+        kept, row = 0, {"full": 0, "window": 0}
+        for cls, entry in zip(self._classes, self._cache):
             for k, a in entry.items():
                 if k in _kv.STATE_LEAVES:
                     kept += size(a, lead - 1)
                 else:
-                    row += size(a, lead)
-        return self._mgr.blocks_in_use * self._mgr.block_size * row \
-            + self.occupancy() * kept
+                    row[cls] += size(a, lead)
+        pages = self._mgr.blocks_in_use * row["full"]
+        if self._mgr.window is not None:
+            pages += self._mgr.window.blocks_in_use * row["window"]
+        return pages * self._mgr.block_size + self.occupancy() * kept
 
     def _finish(self, req: Request, status: str = STATUS_OK) -> None:
         self._results[req.rid] = np.concatenate(
@@ -1273,6 +1375,10 @@ class Engine:
                 held = [int(b) for b in self._table[j] if b >= 0]
                 self._mgr.release(held)
                 self._table[j, :] = -1
+                if self._table_w is not None:
+                    self._mgr.window.release(
+                        [int(b) for b in self._table_w[j] if b >= 0])
+                    self._table_w[j, :] = -1
             if self._prefilling[j]:
                 self._prefilling[j] = False
                 self._prefill_jobs = deque(
@@ -1381,6 +1487,8 @@ class Engine:
                     self.stats.count("decode_tokens")
                     self._pos[j] += 1
                     self._tokens[j] = tok
+                    if self._table_w is not None:
+                        self._release_behind(j)
                     if req.finished(self.serve_cfg.eos):
                         events["finished"].append(req.rid)
                         self._evict(j)
@@ -1406,7 +1514,8 @@ class Engine:
 
     def _host_state(self) -> Dict[str, np.ndarray]:
         """The slot state as the host's arrays, the truth, have it:
-        ``tokens``, ``pos``, the ``live`` mask, the block ``table``,
+        ``tokens``, ``pos``, the ``live`` mask, the block ``table``
+        (with a window class, ``table_window`` beside it),
         and a sampling engine's ``keys``, the live slots'
         requests' keys as ``(slots, ...)`` raw key bits (zeros in the
         other rows; a greedy engine moves no key)."""
@@ -1416,6 +1525,8 @@ class Engine:
                            for j in range(slots)])
         host = {"tokens": self._tokens, "pos": self._pos, "live": live,
                 "table": self._table}
+        if self._table_w is not None:
+            host["table_window"] = self._table_w
         if self.serve_cfg.temperature > 0:
             rows = {j: np.asarray(self._slot_req[j].key)
                     for j in np.flatnonzero(live)}
@@ -1479,6 +1590,10 @@ class Engine:
         self.stats.level("blocks_in_use", self._mgr.blocks_in_use)
         self.stats.level("blocks_free", self._mgr.free_blocks)
         self.stats.level("blocks_cached", self._mgr.cached_blocks)
+        w = self._mgr.window
+        if w is not None:
+            self.stats.level("window_blocks_in_use", w.blocks_in_use)
+            self.stats.level("window_blocks_free", w.free_blocks)
 
     def run(self, max_steps: Optional[int] = None) -> Dict[Any, np.ndarray]:
         """Drive :meth:`step` until every submitted request finished

@@ -50,9 +50,10 @@ import jax.numpy as jnp
 
 from .. import config as _config
 from ..constants import MPI_SUM
-from ..models.transformer import KDA, MLA, Mamba2, TransformerConfig, \
-    _MLA_BLOCK, _blockwise_causal_attention, _norm, _split_qkv, \
-    branch_norm, dense_ffn, index_project, index_select_mask, mamba2_out, \
+from ..models.transformer import GQA, KDA, MLA, Mamba2, \
+    TransformerConfig, _MLA_BLOCK, _blockwise_causal_attention, _norm, \
+    _split_qkv, branch_norm, dense_ffn, embed_tokens, gqa_out, \
+    gqa_project, gqa_scope, index_project, index_select_mask, mamba2_out, \
     mamba2_project, mamba2_scan, mla_expand, mla_project, select_rows, \
     shortcut_branch
 from ..ops.flash import flash_attention, flash_block_attention, \
@@ -74,6 +75,8 @@ __all__ = [
     "init_kv_pool_tp",
     "install_page_count",
     "install_rows_paged",
+    "page_classes",
+    "window_of",
     "latent_width",
     "prefill_tp",
     "prefill_chunk_tp",
@@ -91,6 +94,32 @@ STATE_LEAVES = ("h", "conv")
 def has_state(cfg: TransformerConfig) -> bool:
     """Whether a layer of the configuration keeps a per-slot state."""
     return any(isinstance(sp.mixer, Mamba2) for sp in cfg.layer_specs)
+
+
+def page_classes(cfg: TransformerConfig) -> tuple:
+    """The class of pages each layer's cache entry lies in, one a layer:
+    ``"window"`` for a :class:`~mpi4torch_tpu.models.transformer.GQA`
+    mixer with a window (a slot holds the pages its window touches and
+    frees the rest), ``"full"`` for every other layer that keeps rows (a
+    slot holds every page up to its frontier), ``None`` for a layer that
+    keeps none in pages (an FFN alone, a Mamba-2 state).  Each class has
+    its own block-id space, pool extent and block table; a configuration
+    with no windowed ``GQA`` layer has the one class ``"full"``, because
+    its spec says so."""
+    def of(spec):
+        if spec.only == "ffn" or isinstance(spec.mixer, Mamba2):
+            return None
+        windowed = isinstance(spec.mixer, GQA) and spec.mixer.window
+        return "window" if windowed else "full"
+
+    return tuple(of(spec) for spec in cfg.layer_specs)
+
+
+def window_of(cfg: TransformerConfig) -> int:
+    """The window of the configuration's window class of pages, 0 where
+    it has none; the class has ONE window (:func:`validate_tp`)."""
+    return max((sp.mixer.window for sp in cfg.layer_specs
+                if isinstance(sp.mixer, GQA)), default=0)
 
 
 def first_paged_leaf(entries):
@@ -122,7 +151,11 @@ def validate_tp(cfg: TransformerConfig, size: int, *,
     ``prefill_chunk`` (the engine's options) are refused too: a prompt
     that starts from shared pages, or from its own earlier chunk, needs
     the state as it stood at that page's edge, and no snapshot of it is
-    kept."""
+    kept.  With a WINDOW class of pages (a ``GQA`` mixer with a window,
+    :func:`page_classes`) the same two options are refused: a prompt that
+    adopts shared pages, or goes on from its own earlier chunk, needs the
+    pages under the prefix's last ``window`` positions alive, and a
+    window layer has freed them; and the class has one window."""
     if cfg.n_experts > 0:
         raise CommError(
             "serve: MoE configs (n_experts > 0) are not supported by the "
@@ -158,6 +191,28 @@ def validate_tp(cfg: TransformerConfig, size: int, *,
                 "a Mamba2 layer: the chunk view carries K/V rows between "
                 "chunks and no recurrent state yet — prefill in one "
                 "piece")
+        windows = {sp.mixer.window for sp in cfg.layers
+                   if isinstance(sp.mixer, GQA) and sp.mixer.window}
+        if len(windows) > 1:
+            raise CommError(
+                f"serve: GQA layers with windows {sorted(windows)}: the "
+                "window class of pages holds ONE window's pages a slot; a "
+                "class a window is not written yet")
+        if windows and prefix_cache:
+            raise CommError(
+                "serve: prefix sharing (ServeConfig.prefix_cache) with a "
+                "window class of pages (a GQA mixer with a window): a hit "
+                "needs the pages under the prefix's last window of "
+                "positions alive in every window layer, and those layers "
+                "free the pages their window has left — pass "
+                "prefix_cache=False")
+        if windows and prefill_chunk is not None:
+            raise CommError(
+                "serve: chunked prefill (ServeConfig.prefill_chunk) with "
+                "a window class of pages (a GQA mixer with a window): the "
+                "chunk view reads a prompt's earlier chunks back from its "
+                "pages, and a window layer holds only the last window of "
+                "them — prefill in one piece")
         if size != 1 and any(getattr(sp.mixer, "index", None) is not None
                              for sp in cfg.layers):
             raise CommError(
@@ -287,12 +342,17 @@ def init_kv_cache_tp(cfg: TransformerConfig, slots: int, size: int,
 
 
 def _cache_entries(cfg: TransformerConfig, lead: tuple, size: int, make,
-                   slots: int = 0, state_dtype=jnp.float32):
+                   slots: int = 0, state_dtype=jnp.float32,
+                   window_lead: tuple = None):
     """One cache entry a layer, each leaf ``make(lead + its row's
     shape)`` (``make(shape, state_dtype)`` for the one leaf that is not
     of the cache's type, a recurrent state): ``{"k", "v"}`` of
     ``(kv_heads / size, head_dim)`` rows for the configuration's own
-    attention, ``{"c"}`` of ``(1,
+    attention and of ``(n_kv_heads, head_dim)`` rows, the mixer's own
+    counts, for a ``GQA`` mixer (under ``window_lead`` in the place of
+    ``lead`` where the mixer has a window and the caller gives one: the
+    window class's pool has an extent of its own, :func:`page_classes`),
+    ``{"c"}`` of ``(1,
     latent_width)`` rows for a latent layer — one row a token for all
     heads, the normed latent and the rotated shared key
     (:func:`latent_width`: 640 channels, 1,280 bytes in bfloat16, where
@@ -331,6 +391,11 @@ def _cache_entries(cfg: TransformerConfig, lead: tuple, size: int, make,
                 "h": leaf(m.n_heads, m.head_dim, m.d_state, lead=(slots,),
                           state=True),
                 "conv": leaf(m.conv - 1, m.conv_dim, lead=(slots,))})
+        elif isinstance(spec.mixer, GQA):
+            m = spec.mixer
+            kv = leaf(m.n_kv_heads, m.head_dim,
+                      lead=window_lead if m.window and window_lead else lead)
+            out.append({"k": kv, "v": kv})
         elif isinstance(spec.mixer, MLA):
             out.append({"c": leaf(1, latent_width(spec.mixer))})
             if spec.mixer.scores:
@@ -343,15 +408,18 @@ def _cache_entries(cfg: TransformerConfig, lead: tuple, size: int, make,
 
 def init_kv_pool_tp(cfg: TransformerConfig, num_blocks: int,
                     block_size: int, size: int, dtype=jnp.float32,
-                    slots: int = 0):
+                    slots: int = 0, window_blocks: int = 0):
     """Per-layer TP-sharded paged KV pool:
     ``(num_blocks, block_size, kv_heads / size, head_dim)`` per rank
     (a latent layer: one leaf ``(num_blocks, block_size, 1,
     latent_width)``, :func:`_cache_entries`) —
     the paged counterpart of :func:`init_kv_cache_tp`, addressed
     through a per-slot block table instead of a dense per-slot row.
-    One block-id space serves every layer (block ``i`` of each layer is
-    the same logical page, so one table drives all layers' reads).
+    One block-id space serves every layer of a class (block ``i`` of
+    each is the same logical page, so one table drives all their reads);
+    the layers of the WINDOW class (:func:`page_classes`) have a pool of
+    ``window_blocks`` pages and a table of their own (required where the
+    configuration has such a layer).
     A Mamba-2 layer's entry is not paged: its per-slot state and
     convolution inputs are made here, beside the pool, for ``slots``
     slots (:func:`_cache_entries`; required where the configuration has
@@ -378,10 +446,15 @@ def init_kv_pool_tp(cfg: TransformerConfig, num_blocks: int,
         raise CommError(
             "serve: a Mamba2 layer keeps a state a slot beside the pool: "
             "init_kv_pool_tp needs slots >= 1")
+    if window_of(cfg) and window_blocks < 1:
+        raise CommError(
+            "serve: a GQA layer with a window keeps its pages in a class "
+            "of their own: init_kv_pool_tp needs window_blocks >= 1")
     return _cache_entries(
         cfg, (num_blocks, block_size), size,
         lambda shape, dt=dtype: jnp.zeros(shape, dt), slots,
-        jnp.promote_types(dtype, jnp.float32))
+        jnp.promote_types(dtype, jnp.float32),
+        (window_blocks, block_size))
 
 
 def install_page_count(n_rows: int, block_size: int) -> int:
@@ -391,7 +464,7 @@ def install_page_count(n_rows: int, block_size: int) -> int:
     return (n_rows + 2 * block_size - 2) // block_size
 
 
-def install_rows_paged(pool, rows, index, slot=None):
+def install_rows_paged(pool, rows, index, slot=None, classes=None):
     """Write prefill K/V rows into one rank's page pool: the engine
     compiles this once per ``rows`` shape and calls it with ``pool``
     donated, so the pages are written in place.  Where a layer keeps a
@@ -409,36 +482,52 @@ def install_rows_paged(pool, rows, index, slot=None):
     ``0..n-1`` land at offsets ``off, off+1, ...`` of page ``id_0``
     and run on through ``id_1, ...``; rows from ``n`` on are ignored.
     Ids beyond the pages those rows touch must lie outside the pool
-    (and differ from each other): the scatter drops them.
+    (and differ from each other): the scatter drops them.  Where the
+    pool has two classes of pages, ``classes`` names each layer's
+    (:func:`page_classes`, static) and ``index`` is ``{class: vector}``:
+    a window layer's names the pages its slot holds, the prompt's last
+    ones, and ids outside its pool for the pages before them, whose rows
+    are dropped likewise, in the one dispatch.
 
     Exact bits: rows are cast to the pool's dtype and copied; a first
     or last page written in part keeps its other rows (the page is
     read, merged, and written back whole).  The device work is a few
     passes over ``R`` rows per leaf, never over the pool."""
-    off, n, ids = index[0], index[1], index[2:]
-
-    def leaf(p, r):
+    def leaf(p, r, index):
+        off, n, ids = index[0], index[1], index[2:]
         bs = p.shape[1]
         n_pages = install_page_count(r.shape[1], bs)
         if ids.shape[0] != n_pages:
             raise ValueError(
                 f"install index names {ids.shape[0]} pages; rows of "
                 f"{r.shape[1]} positions in pages of {bs} need {n_pages}")
-        at = jnp.arange(n_pages * bs, dtype=jnp.int32)
-        written = ((at >= off) & (at < off + n)).reshape(n_pages, bs, 1, 1)
+        # The pool's pages as the paged read views them, (block_size *
+        # heads) rows of whole lanes: gathered and scattered in the
+        # tiling the pool arrives in.  As (block_size, heads, channels)
+        # pages the scatter wanted another tiling of a pool of fewer
+        # than 8 heads, and a copy of the whole leaf there and back
+        # (2.3 GB of temporaries for a 2.3 GB class of 4 KV heads).
+        heads = p.shape[2]
+        flat = p.reshape(p.shape[0], bs * heads, p.shape[-1])
+        at = jnp.arange(n_pages * bs * heads, dtype=jnp.int32) // heads
+        written = ((at >= off) & (at < off + n)).reshape(n_pages, -1, 1)
         new = jax.lax.dynamic_update_slice_in_dim(
             jnp.zeros((n_pages * bs,) + p.shape[2:], p.dtype),
             r[0].astype(p.dtype), off, 0)
-        old = jnp.take(p, ids, axis=0, mode="clip")
+        old = jnp.take(flat, ids, axis=0, mode="clip")
         pages = jnp.where(written, new.reshape(old.shape), old)
-        return p.at[ids].set(pages, mode="drop", unique_indices=True)
+        return flat.at[ids].set(pages, mode="drop",
+                                unique_indices=True).reshape(p.shape)
 
     def state(p, r):
         return jax.lax.dynamic_update_index_in_dim(
             p, r[0].astype(p.dtype), slot, 0)
 
-    return [{k: (state if k in STATE_LEAVES else leaf)(p[k], r[k])
-             for k in sorted(p)} for p, r in zip(pool, rows)]
+    of = (lambda layer: index) if classes is None \
+        else (lambda layer: index[classes[layer]])
+    return [{k: state(p[k], r[k]) if k in STATE_LEAVES
+             else leaf(p[k], r[k], of(layer)) for k in sorted(p)}
+            for layer, (p, r) in enumerate(zip(pool, rows))]
 
 
 def _tp_size(cfg: TransformerConfig, shards) -> int:
@@ -609,8 +698,10 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
     step, split as sequences of one).  What differs between the four
     programs is in the callables:
 
-    * ``attend(layer, q, k, v) -> (o, entry)`` is the cache VIEW of a
-      layer of the configuration's own attention: it stores this pass's
+    * ``attend(layer, q, k, v, window) -> (o, entry)`` is the cache VIEW
+      of a layer of softmax attention over keys and values, the
+      configuration's own (``window`` is ``cfg.attn_window``) or a
+      ``GQA`` mixer's (its own): it stores this pass's
       K/V rows of layer ``layer`` its own way, attends ``q`` over what
       that layer may see, and returns the attention output (any shape
       that flattens to ``x``'s rows) and the layer's new cache entry
@@ -646,6 +737,9 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
     A layer is what its :class:`~mpi4torch_tpu.models.transformer.
     LayerSpec` names (``cfg.layer_specs``; without a spec every layer is
     the configuration's own): the mixer the configuration's attention, a
+    ``GQA`` mixer whole under ``layer_scope("attn")`` (``gqa_project``,
+    the view's ``attend`` under ``attn_window`` or ``attn_full``,
+    ``gqa_out``), a
     Mamba-2 mixer whole under ``layer_scope("ssm")`` (``mamba2_project``,
     the view's ``scan``, ``mamba2_out``) or
     latent attention under ``layer_scope("mla")``, through the
@@ -718,9 +812,21 @@ def _walk_layers(cfg: TransformerConfig, shards, x, positions, attend,
         y = _norm(cfg, x, blk["ln1"])
         if spec.mixer is None:
             q, k, v = _split_qkv(cfg, blk, seq(y), seq(positions), size)
-            o, entry = attend(layer, q, k, v)
+            o, entry = attend(layer, q, k, v, cfg.attn_window)
             o_part = o.reshape(*x.shape[:-1], -1).astype(x.dtype) \
                 @ blk["wo"]
+        elif isinstance(spec.mixer, GQA):
+            with layer_scope("attn"):
+                m = spec.mixer
+                q, k, v, g = gqa_project(m, blk["mixer"], seq(y),
+                                         seq(positions))
+                with gqa_scope(m):
+                    o, entry = attend(layer, q, k, v, m.window)
+                o = o.reshape(*x.shape[:-1], m.n_heads, m.head_dim)
+                o_part = branch_norm(
+                    cfg, spec, blk,
+                    gqa_out(m, blk["mixer"], o.astype(x.dtype), g),
+                    "ln1_post")
         elif isinstance(spec.mixer, Mamba2):
             with layer_scope("ssm"):
                 z, xBC, dt = mamba2_project(spec.mixer, blk["mixer"], seq(y))
@@ -889,12 +995,12 @@ def prefill_tp(cfg: TransformerConfig, shards, cache, prompt, comm=None,
     dict, receives the program's counters (``moe_rows`` where the
     configuration has an expert layer)."""
     p_len = prompt.shape[1]
-    x = shards["embed"][prompt]
+    x = embed_tokens(cfg, shards, prompt)
     if cfg.pos_table:
         x = x + shards["pos"][None, :p_len]
     positions = jnp.arange(p_len, dtype=jnp.int32)
 
-    def attend(layer, q, k, v):
+    def attend(layer, q, k, v, window):
         # Rows written at 0; attention over this pass's own K/V, so a
         # lower-precision cache does not touch the prompt's logits.
         c = cache[layer]
@@ -902,7 +1008,7 @@ def prefill_tp(cfg: TransformerConfig, shards, cache, prompt, comm=None,
             c["k"], k.astype(c["k"].dtype), 0, 1)
         cv = jax.lax.dynamic_update_slice_in_dim(
             c["v"], v.astype(c["v"].dtype), 0, 1)
-        o = flash_attention(q, k, v, causal=True, window=cfg.attn_window)
+        o = flash_attention(q, k, v, causal=True, window=window)
         return o, {"k": ck, "v": cv}
 
     def attend_latent(layer, q, rows, lat, selected):
@@ -960,12 +1066,12 @@ def prefill_chunk_tp(cfg: TransformerConfig, shards, past, chunk,
     validate_tp(cfg, _tp_size(cfg, shards), prefill_chunk=chunk.shape[1])
     c_len = chunk.shape[1]
     p_len = int(jax.tree.leaves(past[0])[0].shape[1])
-    x = shards["embed"][chunk]
+    x = embed_tokens(cfg, shards, chunk)
     if cfg.pos_table:
         x = x + shards["pos"][None, p_len:p_len + c_len]
     positions = jnp.arange(p_len, p_len + c_len, dtype=jnp.int32)
 
-    def attend(layer, q, k, v):
+    def attend(layer, q, k, v, window):
         # The chunk's rows go back to be installed; they attend
         # past ++ chunk from their global offset.
         p = past[layer]
@@ -974,7 +1080,7 @@ def prefill_chunk_tp(cfg: TransformerConfig, shards, past, chunk,
         vf = jnp.concatenate([p["v"].astype(v.dtype), v], axis=1)
         o, _ = flash_block_attention(
             q, kf, vf, causal=True, q_offset=p_len, kv_offset=0,
-            window=cfg.attn_window, impl="jnp")
+            window=window, impl="jnp")
         return o, rows
 
     def attend_latent(layer, q, rows, lat, selected):
@@ -1055,7 +1161,7 @@ def decode_step_tp(cfg: TransformerConfig, shards, cache, tokens, pos,
     live = _live_rows(active)
     reduce = _decode_reduce(comm, live, overlap, algorithm)
 
-    def attend(layer, q, k, v):
+    def attend(layer, q, k, v, window):
         # One-hot ``where`` write of each slot's row; attention over
         # the whole max_seq buffer behind per-row frontiers.
         c = cache[layer]
@@ -1064,7 +1170,7 @@ def decode_step_tp(cfg: TransformerConfig, shards, cache, tokens, pos,
         cv = jnp.where(wmask, v.astype(c["v"].dtype), c["v"])
         o, _ = flash_block_attention(
             q, ck, cv, causal=True, q_offset=pos, kv_offset=0,
-            window=cfg.attn_window, impl="jnp")
+            window=window, impl="jnp")
         return o, {"k": ck, "v": cv}
 
     def attend_latent(layer, q, rows, lat, selected):
@@ -1095,7 +1201,7 @@ def decode_step_tp(cfg: TransformerConfig, shards, cache, tokens, pos,
                            ix.top_k), cc
 
     with serve_step_scope("decode_step"):
-        x = shards["embed"][tokens]
+        x = embed_tokens(cfg, shards, tokens)
         if cfg.pos_table:
             x = x + jnp.take(shards["pos"], pos, axis=0)
         x, new_cache, counts = _walk_layers(
@@ -1120,7 +1226,11 @@ def decode_step_paged(cfg: TransformerConfig, shards, pool, table,
     replaced by ``pool`` (per-layer ``(num_blocks, block_size,
     kv_heads/size, head_dim)`` pages, :func:`init_kv_pool_tp`) plus a
     ``(slots, max_seq/block_size)`` block ``table`` (``-1`` =
-    unmapped).  Returns ``(logits, new_pool)``.
+    unmapped).  Returns ``(logits, new_pool)``.  Where the pool has two
+    classes of pages (:func:`page_classes`), ``table`` is ``{"full": ...,
+    "window": ...}``, a table a class: a layer writes and reads through
+    its class's, a window layer under its window, behind which its
+    table's entries are ``-1`` and its pages someone else's.
 
     The step reaches the pool only through the table, in both
     directions, and forms no array of the pool's size.  Per layer:
@@ -1159,34 +1269,39 @@ def decode_step_paged(cfg: TransformerConfig, shards, pool, table,
     no write, no page read, zero attention rows, payload rows zeroed
     before the wire (the dense step's rule)."""
     pos = jnp.asarray(pos, jnp.int32)
-    table = jnp.asarray(table, jnp.int32)
+    classes = page_classes(cfg)
+    tables = table if isinstance(table, dict) \
+        else dict.fromkeys(sorted(set(classes) - {None}), table)
+    tables = {c: jnp.asarray(t, jnp.int32) for c, t in tables.items()}
     bs = first_paged_leaf(pool).shape[1]
     live = _live_rows(active)
     reduce = _decode_reduce(comm, live, overlap, algorithm)
     write = _block_scatter_donated if donate else block_scatter
 
-    # The slot's current write page and in-page offset; a free slot's
-    # all--1 table row yields -1, which block_scatter drops.
-    wb = jnp.take_along_axis(
-        table, jnp.clip(pos // bs, 0, table.shape[1] - 1)[:, None],
-        axis=1)[:, 0]
+    # The slot's current write page in each class and its in-page
+    # offset; a free slot's all--1 table row yields -1, which
+    # block_scatter drops.
+    wbs = {c: jnp.take_along_axis(
+        t, jnp.clip(pos // bs, 0, t.shape[1] - 1)[:, None],
+        axis=1)[:, 0] for c, t in tables.items()}
     off = pos % bs
+    of_class = lambda layer: (tables[classes[layer]], wbs[classes[layer]])
 
-    def attend(layer, q, k, v):
+    def attend(layer, q, k, v, window):
         # One row a live slot scattered into its page; attention reads
-        # the pages through the table.
-        c = pool[layer]
+        # the pages through the table of the layer's class.
+        c, (table, wb) = pool[layer], of_class(layer)
         pk = write(c["k"], wb, off, k[:, 0], live)
         pv = write(c["v"], wb, off, v[:, 0], live)
         o = paged_decode_attention(
-            q[:, 0], pk, pv, table, pos, window=cfg.attn_window,
-            active=live)
+            q[:, 0], pk, pv, table, pos, window=window, active=live)
         return o, {"k": pk, "v": pv}
 
     def attend_latent(layer, q, rows, lat, selected):
         # One latent row a live slot scattered into its page; the read
         # is absorbed, page by page through the table, or, under a
         # selection, of the rows it names and no others.
+        table, wb = of_class(layer)
         pc = write(pool[layer]["c"], wb, off, rows[:, 0], live)
         if selected is None:
             u = paged_latent_attention(
@@ -1202,6 +1317,7 @@ def decode_step_paged(cfg: TransformerConfig, shards, pool, table,
         # One index key a live slot scattered into its page; every slot
         # scores its pages up to its frontier and keeps the top_k
         # positions.
+        table, wb = of_class(layer)
         pk = write(pool[layer]["ik"], wb, off, k_i[:, 0], live)
         scores = paged_index_scores(q_i[:, 0], w[:, 0], pk, table, pos,
                                     active=live)
@@ -1209,7 +1325,7 @@ def decode_step_paged(cfg: TransformerConfig, shards, pool, table,
                            ix.top_k), pk
 
     with serve_step_scope("decode_step"):
-        x = shards["embed"][tokens]
+        x = embed_tokens(cfg, shards, tokens)
         if cfg.pos_table:
             x = x + jnp.take(shards["pos"], pos, axis=0)
         x, new_pool, counts = _walk_layers(

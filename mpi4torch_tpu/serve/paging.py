@@ -43,6 +43,25 @@ The engine's write positions therefore always target private pages,
 which is what makes :func:`block_scatter`'s disjoint-cells invariant
 hold.
 
+**Two classes of pages.**  A layer whose attention reads a sliding
+window needs, of a slot's pages, those its window touches and no
+others.  Such layers form the WINDOW class, with a block-id space, a
+pool extent and a table of their own: ``BlockManager(...,
+window=<tokens>, window_blocks=<pages>)`` carries that class's
+population as :attr:`BlockManager.window`, a :class:`WindowBlocks`, and
+the engine still holds ONE manager.  A slot of the window class is given
+pages for the end of its prompt (from :meth:`WindowBlocks.first_page` of
+its first decode position on) and one more whenever it enters a page,
+and gives back the page its window has left behind the step that last
+read it: at most :attr:`WindowBlocks.pages_a_slot` at a time, whatever
+its length.  A released page goes to another slot while the first still
+decodes; nothing of the window class is shared, cached or copied on
+write (the engine refuses prefix sharing and chunked prefill with such a
+class), so its population is in use or free.  Every other layer is of
+the FULL class, the manager's own population, as before; capacity is
+checked per class (admission defers, decode preempts, when either runs
+dry).
+
 Determinism: every method is pure host bookkeeping over deterministic
 inputs, so N Mode B rank-thread engines make identical decisions —
 their tables never diverge under the decode collectives.
@@ -56,7 +75,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-__all__ = ["BlockManager"]
+__all__ = ["BlockManager", "WindowBlocks", "pages_touched"]
 
 _SEED = b"mpi4torch_tpu.serve.paging"
 
@@ -76,10 +95,13 @@ class BlockManager:
     ``block_size`` tokens.  ``prefix_cache=False`` turns the index off
     (every match misses, nothing registers) while keeping the
     alloc/free discipline — the engine's exactness gate for cache
-    dtypes below compute precision uses this."""
+    dtypes below compute precision uses this.  ``window > 0`` (tokens)
+    adds the window class of ``window_blocks`` pages as
+    :attr:`window` (``None`` without one)."""
 
     def __init__(self, num_blocks: int, block_size: int, *,
-                 prefix_cache: bool = True):
+                 prefix_cache: bool = True, window: int = 0,
+                 window_blocks: int = 0):
         if num_blocks < 1:
             raise ValueError(f"num_blocks must be >= 1, got {num_blocks}")
         if block_size < 1:
@@ -94,6 +116,8 @@ class BlockManager:
         self._full = {}      # chain hash -> block id
         self._partial = {}   # parent chain hash -> (token tuple, block id)
         self._keys = {}      # block id -> [("full"|"partial", hash), ...]
+        self.window: Optional[WindowBlocks] = WindowBlocks(
+            window_blocks, block_size, window) if window else None
 
     # ------------------------------------------------------------ census
 
@@ -226,3 +250,35 @@ class BlockManager:
                 self._partial[h] = (
                     tuple(int(x) for x in tokens[full * bs:n_tokens]), b)
                 self._keys.setdefault(b, []).append(("partial", h))
+
+
+def pages_touched(span: int, block_size: int) -> int:
+    """The most pages ``span`` consecutive positions touch, the first
+    anywhere inside its page."""
+    return (span + 2 * block_size - 2) // block_size
+
+
+class WindowBlocks(BlockManager):
+    """The window class's population: ``num_blocks`` pages of
+    ``block_size`` tokens for layers that read the last ``span``
+    positions, allocated and released like the full class's and never
+    indexed (no prefix entry: a page is in use or free).  What the window
+    means in pages is worked out here, so that the engine's admission,
+    its decode tick and its release agree with the read
+    (``ops.paged_attention._page_span``)."""
+
+    def __init__(self, num_blocks: int, block_size: int, span: int):
+        if span < 1:
+            raise ValueError(f"window must be >= 1 token, got {span}")
+        super().__init__(num_blocks, block_size, prefix_cache=False)
+        self.span = int(span)
+
+    @property
+    def pages_a_slot(self) -> int:
+        """What a slot holds at most: the pages its window touches."""
+        return pages_touched(self.span, self.block_size)
+
+    def first_page(self, pos: int) -> int:
+        """The first page a query at position ``pos`` reads; the pages
+        before it are behind the window."""
+        return max(int(pos) - (self.span - 1), 0) // self.block_size

@@ -64,6 +64,9 @@ _STEP_COUNTS = (("admitted", "admitted"),
                 ("dsa_rows_scored", "dsa_rows_scored"),
                 ("ssm_states_live", "ssm_states_live"),
                 ("ssm_states_touched", "ssm_states_touched"),
+                ("window_pages_held", "window_pages_held"),
+                ("window_slots_live", "window_slots_live"),
+                ("window_pages_freed", "window_pages_freed"),
                 ("decode_uploads", "decode_uploads"),
                 ("step_compiles", "step_compiles"),
                 ("occupancy_ticks", "active"))
@@ -136,7 +139,10 @@ LAYER_SCOPES = {"kda": "mpi4torch.kda", "mla": "mpi4torch.mla",
                 "moe": "mpi4torch.moe", "ffn": "mpi4torch.ffn",
                 "dsa": "mpi4torch.dsa", "ssm": "mpi4torch.ssm",
                 "ssm_scan": "mpi4torch.ssm_scan",
-                "ssm_update": "mpi4torch.ssm_update"}
+                "ssm_update": "mpi4torch.ssm_update",
+                "attn": "mpi4torch.attn",
+                "attn_window": "mpi4torch.attn_window",
+                "attn_full": "mpi4torch.attn_full"}
 
 
 def layer_scope(kind: str):
@@ -153,7 +159,12 @@ def layer_scope(kind: str):
     more INSIDE it around the convolution and the recurrence:
     ``ssm_scan`` where a prefill runs them over a prompt (the chunked
     scan), ``ssm_update`` where a decode step advances every slot's
-    kept state by one token."""
+    kept state by one token; or ``attn`` (an attention mixer stated on
+    the layer, ``GQA``, whole: projections, norms on queries and keys,
+    rotation, gate, output projection), with one of two more INSIDE it
+    around the attention itself (on the serving path the cache write
+    and the read): ``attn_window`` on a layer with a window,
+    ``attn_full`` on one without."""
     return _labeled_scope(LAYER_SCOPES[kind])
 
 
@@ -261,6 +272,17 @@ class ServeStats:
                  # (live slot, layer) pairs whose kept state the step had
                  # to advance, and those it read and wrote.
                  "ssm_states_live", "ssm_states_touched",
+                 # ISSUE 45: a window class of pages (layers whose
+                 # attention reads a sliding window; serve/paging.py).
+                 # Per decode step, the pages of that class the step's
+                 # live slots held and those slots (their ratio: at most
+                 # the pages a window touches), and the pages released
+                 # behind the step because the window had left them.
+                 # window_blocks_in_use / window_blocks_free are LEVELS,
+                 # the class's pool occupancy beside blocks_in_use.
+                 "window_pages_held", "window_slots_live",
+                 "window_pages_freed", "window_blocks_in_use",
+                 "window_blocks_free",
                  # ISSUE 36: host-to-device transfers the decode steps'
                  # ``decode.dispatch.inputs`` made (table, tokens,
                  # positions, live mask, and the keys where the engine
@@ -476,7 +498,9 @@ def serve_step_log() -> list:
     "moe_zero_pairs",
     "moe_live_pairs", "moe_overflow_calls", "dsa_rows_live",
     "dsa_rows_read", "dsa_rows_scored",
-    "ssm_states_live", "ssm_states_touched", "decode_uploads", "step_compiles", "active"}`` on
+    "ssm_states_live", "ssm_states_touched", "window_pages_held",
+    "window_slots_live", "window_pages_freed", "decode_uploads",
+    "step_compiles", "active"}`` on
     the ``time.perf_counter_ns()`` clock, the last :data:`STEP_LOG_CAP`
     steps (and ``moe_rows`` / ``compiles`` where :meth:`ServeStats.attach`
     put them).  ``engine`` is the ``ServeStats.engine`` serial of the

@@ -1860,3 +1860,61 @@ class TestBlockManager:
         m.release(a)
         with pytest.raises(ValueError, match="unreferenced"):
             m.release(a)
+
+    def test_window_class_allocates_releases_and_runs_dry(self):
+        """The window class is a population of its own behind the one
+        manager: pages in use or free, never cached, a capacity of its
+        own, and the full class untouched by what happens to it."""
+        from mpi4torch_tpu.serve import BlockManager
+        from mpi4torch_tpu.serve.paging import WindowBlocks
+
+        mgr = BlockManager(10, 8, prefix_cache=False, window=16,
+                           window_blocks=4)
+        w = mgr.window
+        assert isinstance(w, WindowBlocks) and w.window is None
+        assert (w.num_blocks, w.span, w.pages_a_slot) == (4, 16, 3)
+        assert BlockManager(10, 8).window is None
+        got = w.alloc(3)
+        assert sorted(got) == [0, 1, 2] and w.blocks_in_use == 3
+        assert w.alloc(2) is None and w.free_blocks == 1
+        w.release(got[:1])
+        assert w.blocks_in_use == 2 and w.cached_blocks == 0
+        # Released pages go to the END of the free list: the next
+        # allocation hands out the spare one first, the released after.
+        assert w.alloc(2) == [3, got[0]] and w.free_blocks == 0
+        assert mgr.blocks_in_use == 0 and mgr.free_blocks == 10
+        # Nothing of the class is indexed: a registration is a no-op.
+        w.register(np.arange(8), got[1:2], 8)
+        w.release(got[1:2])
+        assert w.cached_blocks == 0 and w.match(np.arange(8), 7) == ([], 0)
+        with pytest.raises(ValueError, match="unreferenced"):
+            w.release(got[1:2])
+        with pytest.raises(ValueError, match="window must be"):
+            WindowBlocks(4, 8, 0)
+
+    def test_window_first_page_is_the_reads_first_page(self):
+        """``first_page`` is the first page a query reads (positions
+        ``pos - 15 .. pos`` at a window of 16), the same arithmetic as
+        the read's own ``_page_span``."""
+        from mpi4torch_tpu.ops.paged_attention import _page_span
+        from mpi4torch_tpu.serve.paging import WindowBlocks
+
+        w = WindowBlocks(4, 8, 16)
+        assert [w.first_page(p) for p in (0, 15, 16, 22, 23, 24, 31)] \
+            == [0, 0, 0, 0, 1, 1, 2]
+        pos = jnp.arange(200, dtype=jnp.int32)
+        first, _ = _page_span(pos, 8, 25, 16)
+        assert [w.first_page(p) for p in range(200)] \
+            == [int(f) for f in first]
+
+    @pytest.mark.parametrize("window,bs,most", [
+        (2048, 128, 17), (16, 8, 3), (4096, 128, 33), (1, 8, 1)])
+    def test_window_pages_a_slot(self, window, bs, most):
+        """What a window of positions touches at most, against a count
+        over every alignment."""
+        from mpi4torch_tpu.serve.paging import WindowBlocks
+
+        w = WindowBlocks(1, bs, window)
+        seen = max(p // bs - w.first_page(p) + 1
+                   for p in range(2 * window + 4 * bs))
+        assert w.pages_a_slot == most == seen

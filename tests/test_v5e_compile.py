@@ -311,9 +311,12 @@ def _real_size(name: str, family, chip):
     return cfg, tcfg, mesh, stacked(params), count, stacked, like
 
 
-def _compiled_decode_step(tcfg, mesh, params, stacked, like, slots, bs, nb):
+def _compiled_decode_step(tcfg, mesh, params, stacked, like, slots, bs, nb,
+                          window_blocks=0):
     """The serving walk's paged decode step, as the engine traces it
-    (pool donated, tokens chosen inside), compiled from shapes alone."""
+    (pool donated, tokens chosen inside), compiled from shapes alone;
+    with ``window_blocks`` the pool has a window class of that many
+    pages and the step takes a table a class."""
     import mpi4torch_tpu as mpi
     from mpi4torch_tpu.ops.spmd import run_spmd
     from mpi4torch_tpu.serve import kv
@@ -321,8 +324,11 @@ def _compiled_decode_step(tcfg, mesh, params, stacked, like, slots, bs, nb):
 
     pool = jax.eval_shape(
         lambda: kv.init_kv_pool_tp(tcfg, nb, bs, 1, jnp.bfloat16,
-                                   slots=slots))
+                                   slots=slots, window_blocks=window_blocks))
     mine = lambda tree: jax.tree.map(lambda a: a[0], tree)
+    table = like((slots, tcfg.max_seq // bs), jnp.int32)
+    if window_blocks:
+        table = {"full": table, "window": table}
 
     def step(shards, pool, table, tokens, pos, active):
         stats = {}
@@ -335,8 +341,7 @@ def _compiled_decode_step(tcfg, mesh, params, stacked, like, slots, bs, nb):
         return run_spmd(
             step, mesh=mesh, axis_name="mpi",
             donate_argnums=(1,)).lower_as_called(
-                params, stacked(pool),
-                like((slots, tcfg.max_seq // bs), jnp.int32),
+                params, stacked(pool), table,
                 like((slots,), jnp.int32), like((slots,), jnp.int32),
                 like((slots,), bool)).compile()
 
@@ -530,16 +535,24 @@ def test_glms_programs_compile_at_their_real_size(
     assert held + pre.temp_size_in_bytes + pre.output_size_in_bytes < 15.7e9
 
 
-def _compiled_install(tcfg, mesh, stacked, like, slots, bs, nb, n):
+def _compiled_install(tcfg, mesh, stacked, like, slots, bs, nb, n,
+                      window_blocks=0):
     """The install of an ``n``-token prompt's rows (and, where a layer
     keeps one, its state into the slot's row), pool donated, as the
-    engine compiles it."""
+    engine compiles it; with ``window_blocks`` into a pool of two
+    classes, under an index a class."""
     from jax.sharding import PartitionSpec as P
 
     from mpi4torch_tpu.serve import kv
 
     pool = jax.eval_shape(lambda: kv.init_kv_pool_tp(
-        tcfg, nb, bs, 1, jnp.bfloat16, slots=slots))
+        tcfg, nb, bs, 1, jnp.bfloat16, slots=slots,
+        window_blocks=window_blocks))
+    index = like((2 + kv.install_page_count(n, bs),), jnp.int32)
+    classes = None
+    if window_blocks:
+        index, classes = {"full": index, "window": index}, \
+            kv.page_classes(tcfg)
     rows = jax.eval_shape(
         lambda: kv.init_kv_cache_tp(tcfg, 1, 1, jnp.bfloat16))
     rows = [{k: a if k in kv.STATE_LEAVES else jax.ShapeDtypeStruct(
@@ -547,8 +560,8 @@ def _compiled_install(tcfg, mesh, stacked, like, slots, bs, nb, n):
 
     def per_rank(pool, rows, index, slot):
         pool, rows = jax.tree.map(lambda a: a[0], (pool, rows))
-        return jax.tree.map(lambda a: a[None],
-                            kv.install_rows_paged(pool, rows, index, slot))
+        return jax.tree.map(lambda a: a[None], kv.install_rows_paged(
+            pool, rows, index, slot, classes=classes))
 
     with jax.enable_x64(False):
         return jax.jit(
@@ -556,8 +569,7 @@ def _compiled_install(tcfg, mesh, stacked, like, slots, bs, nb, n):
                           in_specs=(P("mpi"), P("mpi"), P(), P()),
                           out_specs=P("mpi"), check_vma=False),
             donate_argnums=0).lower(
-                stacked(pool), stacked(rows),
-                like((2 + kv.install_page_count(n, bs),), jnp.int32),
+                stacked(pool), stacked(rows), index,
                 like((), jnp.int32)).compile()
 
 
@@ -616,6 +628,108 @@ def test_nemotrons_programs_compile_at_their_real_size(
         print(f"\nnemotron-3-super: {count:,} parameters, "
               f"{held / 1e9:.2f} GB held; decode step temporaries "
               f"{mem.temp_size_in_bytes / 1e9:.3f} GB; prefill "
+              "temporaries + outputs " + ", ".join(
+                  f"{n}: {(m.temp_size_in_bytes + m.output_size_in_bytes) / 1e9:.3f} GB"
+                  for n, m in pre.items())
+              + f"; install temporaries {inst.temp_size_in_bytes / 1e9:.3f}"
+              " GB")
+
+
+def _trinity(chip):
+    from benchmarks.families import afmoe as fam
+
+    cfg, tcfg, mesh, params, count, stacked, like = _real_size(
+        "trinity-mini", fam, chip)
+    # The builder's count of the cut, the record: attention 27,263,232
+    # (the norms on queries and keys are 128 each, 256 a layer: ISSUE
+    # 45's own sum counted them twice and came to 256 more a layer) and
+    # four norms 8,192 a layer, the dense FFN 37,748,736, an expert
+    # layer's experts 128 x 6,291,456 with shared expert, router and
+    # selection bias 6,553,728, embedding + head + final norm
+    # 819,988,480.
+    attn = 2048 * (4096 + 512 + 512 + 4096) + 4096 * 2048 + 2 * 128
+    assert attn == 27_263_232
+    dense = attn + 8192 + 3 * 2048 * 6144
+    expert = attn + 8192 + 128 * 6_291_456 + 6_291_456 + 2048 * 128 + 128
+    assert (dense, expert) == (65_020_160, 839_131_520)
+    assert count == dense + 4 * expert + 2 * 200_192 * 2048 + 2048 \
+        == 4_241_534_720
+    return cfg, tcfg, mesh, params, count, stacked, like
+
+
+# The cell's engine: 64 slots of 17,408 positions in pages of 128.
+_TRINITY_POOL = dict(slots=64, bs=128, nb=8704, window_blocks=1152)
+
+
+def test_trinitys_decode_step_compiles_in_place_at_its_real_size(
+        one_v5e_chip, as_on_tpu, capsys):
+    """Trinity-Mini as `serve_swa_mix_16k` serves it (4.24 B parameters:
+    a dense and four expert layers, all 128 experts of each and the whole
+    vocabulary; the full class's 2.28 GB of pages for the one full layer
+    and the window class's 1.21 GB for the four sliding ones), from
+    shapes alone: every leaf of both classes aliased to its output, all
+    five layers' reads through the paged kernel under a table of their
+    class, two grouped products an expert layer, the three scopes in the
+    text, and the temporaries small beside the 12 GB the step is
+    handed.  With ONE class the same slots' pages alone would be 11.4
+    GB."""
+    cfg, tcfg, mesh, params, count, stacked, like = _trinity(one_v5e_chip)
+    slots, bs, nb, nb_w = (_TRINITY_POOL[k] for k in (
+        "slots", "bs", "nb", "window_blocks"))
+    page = bs * 4 * 128 * 2 * 2
+    full, window = nb * page, 4 * nb_w * page
+    assert (page, full, window) == (262_144, 2_281_701_376, 1_207_959_552)
+    assert slots * (tcfg.max_seq // bs) * 5 * page == 11_408_506_880
+    compiled = _compiled_decode_step(tcfg, mesh, params, stacked, like,
+                                     slots, bs, nb, window_blocks=nb_w)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == full + window
+    assert mem.temp_size_in_bytes < 1.0e9
+    assert len(_names(text, pa.KERNEL_NAMES[0])) == 5
+    assert len(_names(text, "ragged-dot-none")) == 2 * 4
+    for scope in ("mpi4torch.attn/", "mpi4torch.attn_window",
+                  "mpi4torch.attn_full", "mpi4torch.moe"):
+        assert scope in text, scope
+    held = 2 * count + full + window
+    assert held + mem.temp_size_in_bytes < 15.7e9
+    with capsys.disabled():
+        print(f"\ntrinity-mini: {count:,} parameters, {held / 1e9:.2f} GB "
+              f"held; decode step temporaries "
+              f"{mem.temp_size_in_bytes / 1e9:.3f} GB")
+
+
+@pytest.mark.slow
+def test_trinitys_prefills_and_install_compile_at_their_real_size(
+        one_v5e_chip, as_on_tpu, capsys):
+    """The cell's three one-piece prefills (1,024, 4,096 and 16,384
+    tokens: the sliding layers under their window, the expert layers in
+    pieces of 4,096 tokens) and the install into two classes under an
+    index a class compile, and the largest temporaries fit beside
+    weights and pages on a 16 GB chip.  Marked slow: the three prefills
+    at the real size take the TPU's compiler minutes; the decode step
+    above is the tier-1 guard.  The programs' temporaries are
+    printed."""
+    cfg, tcfg, mesh, params, count, stacked, like = _trinity(one_v5e_chip)
+    slots, bs, nb, nb_w = (_TRINITY_POOL[k] for k in (
+        "slots", "bs", "nb", "window_blocks"))
+    page = bs * 4 * 128 * 2 * 2
+    held = 2 * count + (nb + 4 * nb_w) * page
+    pre = {}
+    for n in (1024, 4096, 16384):
+        c = _compiled_prefill(tcfg, mesh, params, like, n)
+        text = c.as_text()
+        assert "mpi4torch.attn_window" in text \
+            and "mpi4torch.attn_full" in text
+        assert len(_names(text, flash.KERNEL_NAMES[0])) == 5
+        pre[n] = c.memory_analysis()
+        assert held + pre[n].temp_size_in_bytes \
+            + pre[n].output_size_in_bytes < 15.7e9
+    inst = _compiled_install(tcfg, mesh, stacked, like, slots, bs, nb,
+                             tcfg.max_seq, window_blocks=nb_w
+                             ).memory_analysis()
+    assert inst.alias_size_in_bytes == (nb + 4 * nb_w) * page
+    with capsys.disabled():
+        print(f"\ntrinity-mini: {held / 1e9:.2f} GB held; prefill "
               "temporaries + outputs " + ", ".join(
                   f"{n}: {(m.temp_size_in_bytes + m.output_size_in_bytes) / 1e9:.3f} GB"
                   for n, m in pre.items())
