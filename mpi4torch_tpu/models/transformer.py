@@ -28,6 +28,7 @@ sequence axis per rank is static so XLA tiles cleanly.
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple, Union
 
@@ -36,8 +37,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..constants import MPI_SUM
-from ..ops.flash import flash_attention, flash_block_attention, \
-    merge_partials
+from ..ops.flash import RESIDUAL_NAMES as _FLASH_RESIDUALS, \
+    flash_attention, flash_block_attention, merge_partials
 from ..ops.kda import kda_chunked
 from ..ops.ssd import CHUNK as _SSD_CHUNK, ssd_chunked, ssd_step
 from ..ops.paged_attention import index_scores
@@ -54,6 +55,21 @@ from ..utils.profiling import layer_scope
 
 # What a rematerialised mixer keeps of its forward pass.
 _SAVED_IN_REMAT = jax.checkpoint_policies.save_only_these_names("kda_out")
+# What a rematerialised uniform block keeps where the chip has the room
+# (:func:`_remat_kept_layers`): the outputs of the products its backward
+# reads again, ``y @ wqkv``, ``x + o @ wo`` and ``y @ w1``, beside the
+# flash kernel's ``(out, lse)``.  ``h @ w2`` is not among them: nothing
+# in the block reads it again.
+_KEPT_PRODUCTS = ("qkv", "attn_residual", "ffn_in")
+# The share of the device's memory that three times the parameters and
+# the kept outputs may fill together.  The quarter left free is what a
+# step holds and the rule does not count, as the TPU's compiler sized it
+# for a described v5e at Mistral-7B's widths, 8,192 tokens a chip
+# (memory_analysis(), PR 46): 1.75 GB of temporaries in a step that
+# keeps nothing (the logits and their gradient, one block's recomputed
+# forward) and a data-parallel step's averaged copy of the parameters,
+# 2.27 GB: 4.0 GB of the chip's 16.9, 24%.
+_REMAT_ROOM = 0.75
 
 
 @dataclass(frozen=True)
@@ -277,13 +293,33 @@ class TransformerConfig:
     parallel/moe.py); ``capacity`` is the per-(expert, source-rank) slot
     count, ``aux_coef`` weights the load-balancing loss in :func:`lm_loss`.
 
-    ``remat`` rematerializes each block in the backward pass
-    (``jax.checkpoint``): activation memory drops from O(layers) to O(1)
-    blocks at the cost of one extra forward — the HBM-for-FLOPs trade.
-    Collectives inside a rematted block re-execute during backward, which
-    is SPMD-symmetric (every rank reruns the same sequence, so no
-    deadlock); it requires the traced (SPMD/jit) path — the eager
-    thread-SPMD backend's ops execute imperatively and refuse tracing.
+    ``remat`` makes each block a ``jax.checkpoint`` region: the backward
+    pass keeps the block's input and runs its forward again, so
+    activation memory drops from O(layers) to O(1) blocks — the
+    HBM-for-FLOPs trade.  A uniform block (the configuration's own
+    attention and dense FFN) keeps, by name, the outputs of the products
+    its backward reads again — ``y @ wqkv``, ``x + o @ wo`` and
+    ``y @ w1`` — and the flash kernel's ``(out, lse)``:
+    ``(n_heads + 2 kv_heads) head_dim + 2 d_model + 2 d_ff`` values of
+    the parameters' dtype a token a layer (``d_ff`` under gelu) and a
+    float32 a head, 86,144 bytes at Mistral-7B's widths in bfloat16.
+    How many layers keep them is read at trace time from the local
+    token count, the parameters' bytes and the memory the first local
+    device reports (:func:`_remat_kept_layers`): the first layers, as
+    many as fit; every layer where the backend reports no limit (the
+    CPU); the layers past the count keep nothing.  Still recomputed in
+    every layer: the norms, the rotation, ``silu(gate) * up`` or the
+    gelu, the residual sums and the layout changes; the top-1
+    ``Alltoall`` expert FFN (``n_experts > 0``) with its collectives;
+    and, under sequence parallelism, the attention (ring, zigzag and
+    ulysses keep no pair: every ring step's block would keep its own).
+    ``h @ w2`` is neither kept nor run again: nothing reads it twice.
+    The regions of a per-layer spec (one a mixer, one an FFN) keep
+    ``kda_out`` and nothing else.  Collectives inside a rematted block
+    re-execute during backward, which is SPMD-symmetric (every rank
+    reruns the same sequence, so no deadlock); it requires the traced
+    (SPMD/jit) path — the eager thread-SPMD backend's ops execute
+    imperatively and refuse tracing.
 
     ``nope`` gives the configuration's own attention no position signal
     at all: no rotation (``rope`` must be off) and no learned table (no
@@ -639,7 +675,7 @@ def _split_qkv(cfg: TransformerConfig, blk, y, positions=None,
     b, s = y.shape[0], y.shape[1]
     h, h_kv = cfg.n_heads // size, cfg.kv_heads // size
     hd = cfg.d_model // cfg.n_heads
-    qkv = y @ blk["wqkv"]
+    qkv = checkpoint_name(y @ blk["wqkv"], "qkv")
     q = qkv[..., :h * hd].reshape(b, s, h, hd)
     k = qkv[..., h * hd:(h + h_kv) * hd].reshape(b, s, h_kv, hd)
     v = qkv[..., (h + h_kv) * hd:].reshape(b, s, h_kv, hd)
@@ -1100,11 +1136,11 @@ def _ffn_dense(cfg: TransformerConfig, blk, y):
     """The dense FFN product of the normed input ``y``, before the
     residual; with a serving shard's ``w1``/``w2`` it is this rank's
     partial sum."""
+    ffn_in = checkpoint_name(y @ blk["w1"], "ffn_in")
     if cfg.ffn == "swiglu":
-        gate_up = y @ blk["w1"]
-        gate, up = jnp.split(gate_up, 2, axis=-1)
+        gate, up = jnp.split(ffn_in, 2, axis=-1)
         return (jax.nn.silu(gate) * up) @ blk["w2"]
-    return jax.nn.gelu(y @ blk["w1"]) @ blk["w2"]
+    return jax.nn.gelu(ffn_in) @ blk["w2"]
 
 
 def dense_ffn(cfg: TransformerConfig, spec: LayerSpec, blk, y):
@@ -1197,6 +1233,42 @@ def _attention(q, k, v, comm_sp, attn: str, window: int = 0):
     return ulysses_attention(comm_sp, q, k, v, causal=True, window=window)
 
 
+def _is_uniform(spec: LayerSpec) -> bool:
+    """The configuration's own block: its attention and its FFN."""
+    return spec.mixer is None and spec.ffn is None and not spec.only
+
+
+def _memory_limit_bytes() -> Optional[int]:
+    """What the first local device says it may hold, or ``None`` where
+    the backend reports no limit (the CPU)."""
+    return (jax.local_devices()[0].memory_stats() or {}).get("bytes_limit")
+
+
+def _remat_kept_layers(cfg: TransformerConfig, tokens_shape, dtype,
+                       param_bytes: int, limit_bytes: Optional[int],
+                       flash_pair: bool = True) -> int:
+    """How many of the stack's uniform blocks keep their named outputs
+    (:data:`_KEPT_PRODUCTS`, and the flash pair where ``flash_pair``)
+    under ``cfg.remat``: the most whose bytes fit, with three times
+    ``param_bytes`` (the parameters, their gradients and the new
+    parameters stand beside them), in :data:`_REMAT_ROOM` of
+    ``limit_bytes``.  All of them where no limit is reported; the blocks
+    past the count keep nothing."""
+    n = sum(_is_uniform(s) for s in cfg.layer_specs)
+    if limit_bytes is None:
+        return n
+    hd = cfg.d_model // cfg.n_heads
+    width = (cfg.n_heads + 2 * cfg.kv_heads) * hd + cfg.d_model
+    if cfg.n_experts == 0:
+        width += (2 if cfg.ffn == "swiglu" else 1) * cfg.d_ff
+    a_token = width * jnp.dtype(dtype).itemsize
+    if flash_pair:
+        a_token += cfg.d_model * jnp.dtype(dtype).itemsize + cfg.n_heads \
+            * jnp.promote_types(dtype, jnp.float32).itemsize
+    room = _REMAT_ROOM * limit_bytes - 3 * param_bytes
+    return int(min(n, max(0, room // (math.prod(tokens_shape) * a_token))))
+
+
 def forward(cfg: TransformerConfig, params, tokens, comm_sp=None,
             attn: str = "ring", comm_ep=None, return_aux: bool = False,
             return_hidden: bool = False):
@@ -1278,7 +1350,8 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
         y = _norm(cfg, x, blk["ln1"])
         q, k, v = _split_qkv(cfg, blk, y, positions)
         o = _attention(q, k, v, comm_sp, attn, cfg.attn_window)
-        x = x + o.reshape(b, s_local, d) @ blk["wo"]
+        x = checkpoint_name(x + o.reshape(b, s_local, d) @ blk["wo"],
+                            "attn_residual")
         x, aux = _ffn_residual(cfg, blk, x, comm_ep)
         return x, aux
 
@@ -1342,14 +1415,32 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
 
     # With remat a uniform block is one rematerialised region; a new kind
     # of mixer and an expert FFN are one each, so that the backward holds
-    # the temporaries of one of them at a time, not of both.
+    # the temporaries of one of them at a time, not of both.  The first
+    # uniform blocks keep their products' outputs and the flash pair as
+    # far as the device has the room (_remat_kept_layers), the rest keep
+    # nothing; under sequence parallelism no block keeps the pair (every
+    # ring step's block would keep its own).  A mixer's or an FFN's
+    # region keeps ``kda_out`` alone: the other names do nothing there.
     remat = functools.partial(jax.checkpoint, policy=_SAVED_IN_REMAT) \
         if cfg.remat else (lambda f: f)
+    keeps = 0
+    if cfg.remat:
+        one_rank = comm_sp is None or comm_sp.size == 1
+        keeps = _remat_kept_layers(
+            cfg, tokens.shape, x.dtype,
+            sum(p.size * p.dtype.itemsize for p in jax.tree.leaves(params)),
+            _memory_limit_bytes(), flash_pair=one_rank)
+        kept = jax.checkpoint_policies.save_only_these_names(
+            *_KEPT_PRODUCTS, *(_FLASH_RESIDUALS if one_rank else ()))
     counted, carried = [], None
     for spec, blk in zip(cfg.layer_specs, params["blocks"]):
-        if spec.mixer is None and spec.ffn is None and not spec.only:
-            x, aux = (jax.checkpoint(block_fn) if cfg.remat
-                      else block_fn)(x, blk)
+        if _is_uniform(spec):
+            fn = block_fn
+            if cfg.remat:
+                fn = jax.checkpoint(block_fn,
+                                    policy=kept if keeps > 0 else None)
+                keeps -= 1
+            x, aux = fn(x, blk)
             aux_total = aux_total + aux
             continue
         if spec.only != "ffn":

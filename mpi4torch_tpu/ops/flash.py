@@ -42,6 +42,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 NEG_BIG = -1e30
 # The floor of every tile: one MXU pass and one lane tile wide.  The
@@ -82,6 +83,11 @@ _MOSAIC_SCRATCH = 1024 * 1024
 # and a profiler trace's kernel events are matched against.
 KERNEL_NAMES = ("mpi4torch_flash_fwd", "mpi4torch_flash_bwd_dq",
                 "mpi4torch_flash_bwd_dkv")
+# ``checkpoint_name``s of the forward's ``(out, lse)`` where they become
+# the backward's residuals: a ``jax.checkpoint`` policy that lists them
+# keeps the pair and does not run the forward again; under any other
+# policy, and outside a checkpoint region, the names do nothing.
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 
 def _lane_pad(d: int) -> int:
@@ -996,6 +1002,11 @@ def _block(q, k, v, q_off, kv_off, causal: bool, impl: str,
 def _block_fwd(q, k, v, q_off, kv_off, causal, impl, window=0):
     out, lse = _block_fwd_dispatch(q, k, v, q_off, kv_off, causal, impl,
                                    window)
+    # Named HERE, before the pair is both output and residual: a name put
+    # on the output outside the custom_vjp would save a copy of ``out``
+    # and still rerun the kernel for ``lse``.
+    out = checkpoint_name(out, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
     return (out, lse), (q, k, v, q_off, kv_off, out, lse)
 
 

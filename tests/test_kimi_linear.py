@@ -291,6 +291,38 @@ def test_a_rematerialised_mixer_runs_the_kernel_once(monkeypatch, policy,
     jax.jit(grad).lower(p, _x())
 
 
+def test_the_uniform_blocks_names_do_nothing_in_a_specs_regions(
+        monkeypatch, capsys):
+    """What the rematerialised regions of this stack keep is what they
+    kept before a uniform block's products and the flash kernel's
+    ``(out, lse)`` had names: ``kda_out`` of each KDA layer, every
+    region's output and nothing by another name, though the latent
+    attention's blocks run the named kernel and the dense FFN the named
+    product.  Read with the names in place and with every name but
+    ``kda_out`` taken out of both modules."""
+    from jax.ad_checkpoint import checkpoint_name, print_saved_residuals
+
+    from mpi4torch_tpu.ops import flash
+
+    def saved():
+        print_saved_residuals(lambda p: T.lm_loss(TCFG, p, _tokens()),
+                              _params())
+        return capsys.readouterr().out
+
+    # a line: the residual's type, then where it comes from
+    kept = lambda text: [line.split(" ", 1)[0] for line in text.splitlines()]
+    named = saved()
+    only_kda = lambda x, name: checkpoint_name(x, name) \
+        if name == "kda_out" else x
+    monkeypatch.setattr(T, "checkpoint_name", only_kda)
+    monkeypatch.setattr(flash, "checkpoint_name", only_kda)
+    assert kept(named) == kept(saved())
+    assert named.count("_kda_mixer") == sum(
+        isinstance(s.mixer, T.KDA) for s in TCFG.layers) > 0
+    for name in T._KEPT_PRODUCTS + flash.RESIDUAL_NAMES:
+        assert f"named '{name}'" not in named
+
+
 # ---------------------------------------------------------------- mixers
 
 @pytest.mark.parametrize("layer,mixer,reference", [

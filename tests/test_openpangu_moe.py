@@ -337,8 +337,13 @@ def test_a_layer_spec_is_served_on_one_rank_only():
 # replaced Kimi's and openPangu's: both hand out one more counter,
 # ``moe_overflow_calls``; their expert layers, under the row constant at
 # these sizes, are the parent's (``tests/test_moe_prefix.py``, bit for bit).
+# PR 46 replaced Kimi's: the names it put on the flash kernel's ``(out,
+# lse)`` and on ``y @ w1`` keep nothing in a spec's regions, and the text
+# is the parent's but for the number at the end of 76 private functions'
+# names, one higher each (``@silu_160`` -> ``@silu_161``); with those
+# numbers taken out the two texts are equal.
 PARENT_TEXTS = {
-    "kimi": "1a658da468a6c3461c38340fcbc6465ccd350c53a405299a82821bb6de3f158f",
+    "kimi": "c8f6723c62b880c17377b0e0b8a36e11f36a27c25731b3c74602c7af50198f9e",
     "internlm2": "ddcbb9f523103a01172891ee29d82bf8839323abf424f1e05587b9142ec5ad65",
     "openpangu": "cd48ae9b73a5afa03dceab1ab8e7f1069b6759f10c550555e566776f4e353fc7",
 }

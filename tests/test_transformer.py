@@ -837,3 +837,159 @@ def test_forward_shapes_and_unknown_strategy():
         T._attention(jnp.ones((1, 2, 2, 2)), jnp.ones((1, 2, 2, 2)),
                      jnp.ones((1, 2, 2, 2)),
                      type("C", (), {"size": 2})(), "dense")
+
+
+# ------------------------------- what a rematerialised uniform block keeps
+
+def _remat_cfg(ffn="swiglu", window=0, **kw):
+    base = dict(vocab=64, d_model=32, n_heads=4, n_layers=2, d_ff=48,
+                max_seq=16, n_kv_heads=2, rope=True, norm="rmsnorm",
+                ffn=ffn, attn_window=window)
+    return T.TransformerConfig(**{**base, **kw})
+
+
+def _loss_and_grads(cfg, params, tokens):
+    return jax.value_and_grad(lambda p: T.lm_loss(cfg, p, tokens))(params)
+
+
+@pytest.mark.parametrize("ffn,window", [("swiglu", 0), ("gelu", 0),
+                                        ("swiglu", 8), ("gelu", 8)])
+def test_kept_outputs_are_the_recomputed_ones_bit_for_bit(ffn, window):
+    """A kept value and a recomputed one are the same bits: loss and
+    gradients of a uniform stack under ``remat`` equal those without it,
+    one equation at a time (under ``jit`` the CPU's compiler contracts a
+    multiply and an add differently from one program to the next, which
+    is no doing of the policy's: there they agree to rounding)."""
+    cfg = _remat_cfg(ffn, window)
+    params = T.init_transformer(jax.random.PRNGKey(0), cfg, jnp.float32)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab)
+    kept = dataclasses.replace(cfg, remat=True)
+    assert T._remat_kept_layers(kept, tokens.shape, jnp.float32, 1,
+                                T._memory_limit_bytes()) == 2
+    l0, g0 = _loss_and_grads(cfg, params, tokens)
+    l1, g1 = _loss_and_grads(kept, params, tokens)
+    assert float(l0) == float(l1)
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+        np.asarray(a), np.asarray(b)), g1, g0)
+    l2, g2 = jax.jit(_loss_and_grads, static_argnums=0)(kept, params, tokens)
+    np.testing.assert_allclose(float(l2), float(l0), rtol=1e-6)
+    jax.tree.map(lambda a, b: np.testing.assert_array_less(
+        np.linalg.norm(a - b), 1e-5 * np.linalg.norm(b) + 1e-30), g2, g0)
+
+
+def _count(jaxpr, found) -> int:
+    """Equations of a jaxpr, and of every jaxpr it holds but a kernel's
+    own, that ``found`` takes."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += bool(found(eqn))
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.tree.leaves(
+                list(eqn.params.values()),
+                is_leaf=lambda x: hasattr(x, "eqns") or hasattr(x, "jaxpr")):
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                n += _count(sub, found)
+    return n
+
+
+def _over_a_weight(eqn) -> bool:
+    # Activations are (b, s, ...) all the way; only a weight has two axes.
+    return eqn.primitive.name == "dot_general" and any(
+        v.aval.ndim == 2 for v in eqn.invars)
+
+
+def _flash_forward(eqn) -> bool:
+    from mpi4torch_tpu.ops import flash
+    return eqn.primitive.name == "pallas_call" \
+        and eqn.params["name"] == flash.KERNEL_NAMES[0]
+
+
+def test_the_backward_of_a_kept_block_runs_each_product_once(monkeypatch):
+    """The gradient's jaxpr under the policy holds as many products over
+    a weight as without ``remat`` and one flash forward a layer; a
+    ``jax.checkpoint`` with no policy, which is what a block past the
+    rule's count gets, runs three products and the kernel again (not
+    ``h @ w2``: nothing reads it twice).  Traced as on the TPU (the
+    kernel's equations are looked for, nothing is lowered)."""
+    from mpi4torch_tpu.ops import flash
+    monkeypatch.setattr(flash, "_on_tpu", lambda: True)
+    cfg = _remat_cfg(d_model=128, n_heads=2, n_kv_heads=1, d_ff=192,
+                     max_seq=128)
+    tokens = jnp.zeros((2, 128), jnp.int32)
+
+    def counts(cfg, limit):
+        monkeypatch.setattr(T, "_memory_limit_bytes", lambda: limit)
+        with jax.enable_x64(False):
+            params = T.init_transformer(jax.random.PRNGKey(0), cfg,
+                                        jnp.float32)
+            jaxpr = jax.make_jaxpr(jax.grad(
+                lambda p: T.lm_loss(cfg, p, tokens)))(params).jaxpr
+        return _count(jaxpr, _over_a_weight), _count(jaxpr, _flash_forward)
+
+    layers = cfg.n_layers
+    plain = counts(cfg, None)
+    # forward and backward of four products a layer, and of the head
+    assert plain == (2 * (4 * layers + 1), layers)
+    remat = dataclasses.replace(cfg, remat=True)
+    assert counts(remat, None) == plain
+    assert counts(remat, 1) == (plain[0] + 3 * layers, 2 * layers)
+    # room for one layer's outputs beside the parameters: one keeps
+    a_layer = 2 * 128 * ((128 + 2 * 64) + 128 + 2 * 192 + 128) * 4 \
+        + 2 * 128 * 2 * 4
+    p_bytes = sum(p.size * 4 for p in jax.tree.leaves(jax.eval_shape(
+        lambda: T.init_transformer(jax.random.PRNGKey(0), cfg, jnp.float32))))
+    one = int((3 * p_bytes + 1.5 * a_layer) / T._REMAT_ROOM)
+    assert counts(remat, one) == (plain[0] + 3, layers + 1)
+
+
+def test_how_many_layers_keep_their_outputs():
+    """``_remat_kept_layers`` over made-up limits."""
+    cfg = _remat_cfg(n_layers=6, remat=True)
+    shape, p_bytes = (2, 16), 100_000
+    kept = lambda limit, **kw: T._remat_kept_layers(
+        cfg, shape, jnp.float32, p_bytes, limit, **kw)
+    # qkv 64 + the residual sum 32 + gate and up 96 + the kernel's output
+    # 32, and four heads' float32 lse, a token
+    a_layer = 32 * ((64 + 32 + 96 + 32) * 4 + 4 * 4)
+    assert kept(None) == 6                     # no limit reported: all
+    assert kept(p_bytes) == 0                  # the parameters alone pass it
+    assert kept(int(3 * p_bytes / T._REMAT_ROOM)) == 0
+    at = lambda k: int((3 * p_bytes + (k + 0.5) * a_layer) / T._REMAT_ROOM)
+    assert [kept(at(k)) for k in range(8)] == [0, 1, 2, 3, 4, 5, 6, 6]
+    counts = [kept(limit) for limit in range(0, at(7), 997)]
+    assert counts == sorted(counts) and set(counts) == set(range(7))
+    # without the pair (sequence parallelism) a layer's outputs are fewer
+    assert kept(at(3), flash_pair=False) == 4
+    # the top-1 expert FFN of a uniform block has no named product
+    moe = _remat_cfg("gelu", n_layers=6, remat=True, n_experts=2, capacity=4)
+    assert T._remat_kept_layers(moe, shape, jnp.float32, p_bytes, at(3)) > 3
+    # a spec's uniform layers count, its other layers do not
+    spec = _remat_cfg(n_layers=3, remat=True, layers=(
+        T.LayerSpec(), T.LayerSpec(only="ffn"), T.LayerSpec()))
+    assert T._remat_kept_layers(spec, shape, jnp.float32, p_bytes, None) == 2
+
+
+def test_the_mistral_cells_keep_all_four_layers():
+    """`train_1chip` and `train_dp4`: four layers at Mistral-7B's widths,
+    2 x 4,096 tokens a chip in bfloat16, on a chip that reports 16.9 GB:
+    705.7 MB a layer beside 3 x 2.27 GB of parameters.  A chip of 12 GB
+    has the room for three of them; the published 32 layers at the same
+    shape keep none, three times their parameters pass the chip's memory
+    by themselves."""
+    def kept(n_layers, limit):
+        cfg = T.TransformerConfig(
+            vocab=32000, d_model=4096, n_heads=32, n_layers=n_layers,
+            d_ff=14336, max_seq=4096, n_kv_heads=8, attn_window=4096,
+            rope=True, norm="rmsnorm", ffn="swiglu", remat=True)
+        shapes = jax.eval_shape(lambda: T.init_transformer(
+            jax.random.PRNGKey(0), cfg, jnp.bfloat16))
+        p_bytes = sum(p.size * 2 for p in jax.tree.leaves(shapes))
+        assert abs(p_bytes - (2.269e9 + (n_layers - 4) * 436.2e6)) < 1e6
+        return T._remat_kept_layers(cfg, (2, 4096), jnp.bfloat16, p_bytes,
+                                    limit)
+
+    assert kept(4, 16_909_336_064) == 4
+    assert kept(4, 12_000_000_000) == 3
+    assert kept(32, 16_909_336_064) == 0

@@ -925,3 +925,59 @@ def test_a_rematerialised_kda_mixer_compiles_with_one_kernel_call(
         compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
             p, y).compile()
     assert _kda_calls(compiled.as_text()) == 1
+
+
+# ------------------------------ what a rematerialised uniform block keeps
+
+@pytest.mark.parametrize("chips", [1, 4], ids=["train_1chip", "train_dp4"])
+def test_the_mistral_step_keeps_its_outputs_and_fits(v5e_2x2, as_on_tpu,
+                                                     monkeypatch, chips):
+    """The two Mistral cells' real step (4 layers, 2 x 4,096 tokens a
+    chip, bfloat16, remat on; data parallel over the 2 x 2) as the
+    benchmark builds it, with the limit a v5e reports.  The rule keeps
+    all four layers' outputs; the compiled step then holds each block's
+    forward products once (51 products where a step that keeps nothing
+    holds 63: `y @ wqkv`, `o @ wo` and `y @ w1` of four layers; `h @ w2`
+    was never run twice) and four flash forward calls, not eight; and
+    what it holds, arguments and temporaries, stays inside the share of
+    the chip the rule fills, though the rule counts the gradients and
+    the new parameters as standing beside the old and the compiled step
+    updates in place."""
+    import json
+    import os
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from benchmarks import program, weights
+
+    limit = 16_909_336_064               # bytes_limit of a v5e's memory_stats()
+    monkeypatch.setattr(T, "_memory_limit_bytes", lambda: limit)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "mistral-7b-v0.1.json")) as f:
+        cfg = json.load(f)
+    mesh = Mesh(np.asarray(v5e_2x2[:chips]), ("mpi",))
+    rep = NamedSharding(mesh, P())
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep)
+    params = jax.tree.map(like, jax.eval_shape(
+        lambda: weights.make_params(cfg, 0, jnp.bfloat16)))
+    tokens = jax.ShapeDtypeStruct((chips * 2, 4096), jnp.int32, sharding=rep)
+    tcfg = program.transformer_config(cfg, remat=True)
+    p_bytes = sum(p.size * p.dtype.itemsize for p in jax.tree.leaves(params))
+    assert T._remat_kept_layers(tcfg, (2, 4096), jnp.bfloat16, p_bytes,
+                                limit) == 4
+    step = program.build_train_step(tcfg, mesh, 2, 0.3, chips > 1)
+    with jax.enable_x64(False):
+        compiled = step.lower(params, tokens).compile()
+    text = re.sub(r"/\*.*?\*/", "", compiled.as_text())
+    assert len(re.findall(r"= \S+ convolution\(", text)) == 51
+    calls = lambda name: sum(
+        'custom_call_target="tpu_custom_call"' in line and name in line
+        for line in text.splitlines())
+    fwd, dq, dkv = flash.KERNEL_NAMES
+    assert (calls(fwd), calls(dq), calls(dkv)) == (4, 4, 4)
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    print(f"{chips} chip(s): arguments {mem.argument_size_in_bytes / 1e9:.3f}"
+          f" GB + temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB")
+    assert held < T._REMAT_ROOM * limit
