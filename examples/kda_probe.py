@@ -3,15 +3,20 @@
 No benchmark cell: a script that answers, on one TPU chip, what one call
 of ``ops/kda.py:kda_chunked`` costs at the shape
 ``kimi-linear-48b-a3b.train_kda_8k`` sends (``b, s, h, d`` = 2, 8,192,
-32, 128; ``q``, ``k``, ``v`` bfloat16, the log-decay ``g`` and the write
-strength ``beta`` float32, as ``models/transformer.py:_kda_mixer`` hands
-them), forward alone and under ``jax.value_and_grad`` of a sum of it, for
-``impl="jnp"`` and ``impl="pallas"``.  The backward of both is the plain
-path's, so ``grad - fwd`` of the ``pallas`` row is what a backward kernel
-is held against.  ``--baseline PATH`` loads another ``kda.py`` (the
-parent commit's; one without an ``impl`` argument is timed as ``jnp``)
-and times it beside this one; ``--check`` says how far the two
-implementations' outputs and gradients lie apart.
+32, 128; ``q``, ``k``, ``v`` bfloat16, or float32 under ``--dtype``, the
+log-decay ``g`` and the write strength ``beta`` float32, as
+``models/transformer.py:_kda_mixer`` hands them), forward alone and under ``jax.value_and_grad`` of a sum of it, for
+``impl="jnp"`` and ``impl="pallas"``.  The ``jnp`` row's backward is
+the plain path's (the chunks run again a head group at a time and
+transposed by autodiff); the ``pallas`` row's ``grad - fwd`` is the two
+backward kernels' (since PR 47; before it, and in a ``--baseline`` from
+before it, the plain path's behind the forward kernel).  ``--baseline
+PATH`` loads another ``kda.py`` (the parent commit's; one without an
+``impl`` argument is timed as ``jnp``) and times it beside this one:
+the before and after by pass that ``PERF.md`` quotes.  ``--check`` says
+how far each row's output and each of its five gradients (``grad_gaps``:
+``q``, ``k``, ``v``, ``g``, ``beta``; ``grad_gap`` the widest) lie from
+the first row's, ``|a - b| / |b|`` in norms.
 
 Times are the host's clock around calls that end in
 ``block_until_ready`` (each call is tens of milliseconds: the dispatch
@@ -60,7 +65,7 @@ def load_baseline(path):
     return mod
 
 
-def make_inputs(shape, seed: int):
+def make_inputs(shape, seed: int, dtype=jnp.bfloat16):
     """What ``_kda_mixer`` hands the rule: unit ``q`` and ``k``, a decay
     of a few percent a token and channel, ``beta`` in (0, 1); the heads
     side by side, ``(b, s, h * d)``, as the mixer's projections leave
@@ -69,7 +74,7 @@ def make_inputs(shape, seed: int):
     another tiling and would be copied into this one first)."""
     b, s, h, d = shape
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    bf, f32 = jnp.bfloat16, jnp.float32
+    bf, f32 = dtype, jnp.float32
     unit = lambda x: (x * jax.lax.rsqrt(
         jnp.sum(x * x, -1, keepdims=True) + 1e-6)).astype(bf)
     normal = lambda key, *sh: jax.random.normal(key, sh, f32)
@@ -108,13 +113,13 @@ def timed_ms(fn, args, iters: int) -> float:
     return statistics.median(took)
 
 
-def gap(a, b) -> float:
-    """The widest ``|a - b| / |b|`` (norms) over the leaves of two trees."""
+def gaps(a, b) -> list[float]:
+    """``|a - b| / |b|`` (norms), leaf by leaf of two trees."""
     rel = lambda x, y: float(
         np.linalg.norm(np.asarray(x, np.float64) - np.asarray(y, np.float64))
         / max(np.linalg.norm(np.asarray(y, np.float64)), 1e-30))
-    return max(rel(x, y) for x, y in zip(jax.tree.leaves(a),
-                                         jax.tree.leaves(b), strict=True))
+    return [rel(x, y) for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b),
+                                      strict=True)]
 
 
 def main(argv=None) -> int:
@@ -124,6 +129,9 @@ def main(argv=None) -> int:
     ap.add_argument("--check", action="store_true")
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--dtype", default="bfloat16",
+                    choices=("bfloat16", "float32"),
+                    help="of q, k and v (the cell's: bfloat16)")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
     if not args.rehearse and not _on_tpu():
@@ -131,7 +139,7 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 3
     shape = REHEARSAL if args.rehearse else CELL
-    inputs = make_inputs(shape, args.seed)
+    inputs = make_inputs(shape, args.seed, jnp.dtype(args.dtype))
     mods = [("this", kda)]
     if args.baseline:
         mods.append(("baseline", load_baseline(args.baseline)))
@@ -142,6 +150,7 @@ def main(argv=None) -> int:
         for impl in args.impl.split(",") if takes_impl else ["jnp"]:
             fwd, grad = programs(mod, impl)
             row = {"module": label, "impl": impl, "shape": list(shape),
+                   "dtype": args.dtype,
                    "fwd_ms": timed_ms(fwd, inputs, args.iters),
                    "grad_ms": timed_ms(grad, inputs, args.iters),
                    "device": {"platform": device.platform,
@@ -149,8 +158,11 @@ def main(argv=None) -> int:
             if args.check:
                 kept[label, impl] = (fwd(*inputs), grad(*inputs)[1])
                 first = next(iter(kept.values()))
-                row["out_gap"] = gap(kept[label, impl][0], first[0])
-                row["grad_gap"] = gap(kept[label, impl][1], first[1])
+                row["out_gap"], = gaps(kept[label, impl][0], first[0])
+                row["grad_gaps"] = dict(zip(
+                    ("q", "k", "v", "g", "beta"),
+                    gaps(kept[label, impl][1], first[1])))
+                row["grad_gap"] = max(row["grad_gaps"].values())
             rows.append(row)
             print(json.dumps(row), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)

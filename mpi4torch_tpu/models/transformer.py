@@ -815,8 +815,9 @@ def _kda_mixer(spec: KDA, p, y):
     g = -jnp.exp(p["a_log"].astype(ct))[:, None] * heads(rate)
     beta = jax.nn.sigmoid((y @ p["wb"]).astype(ct))
     # Named so that a rematerialised layer keeps it (_SAVED_IN_REMAT):
-    # the chunked rule already rematerialises itself head group by head
-    # group, and would otherwise run forward a third time.
+    # the chunked rule's backward runs a forward of its own from the
+    # rule's inputs, and the region would otherwise run the rule once
+    # more for an output it only hands on.
     o = checkpoint_name(kda_chunked(_l2_norm(q), _l2_norm(k), v, g, beta),
                         "kda_out")
     gate = jax.nn.sigmoid(heads(((y @ p["wg1"]) @ p["wg2"]).astype(ct)))

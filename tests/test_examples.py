@@ -274,7 +274,7 @@ class TestFlashTileProbe:
 
 class TestKdaProbe:
     """examples/kda_probe.py is a chip script; its control flow runs
-    here at a tiny shape, the kernel interpreted (its times mean
+    here at a tiny shape, the kernels interpreted (its times mean
     nothing)."""
 
     def test_rehearsal_times_both_paths_and_a_baseline(self, tmp_path,
@@ -293,8 +293,13 @@ class TestKdaProbe:
         for row in rows:
             assert row["fwd_ms"] > 0 and row["grad_ms"] > 0
             # bfloat16 operands: the two paths round differently on the
-            # way into each product; the gradients are one arithmetic
-            assert row["out_gap"] < 2e-3 and row["grad_gap"] < 1e-5
+            # way into each product, forward and (the kernels' backward
+            # is their own arithmetic) backward: 3.7e-3 seen in a
+            # gradient; the plain path twice is one program
+            assert row["out_gap"] < 2e-3 and set(row["grad_gaps"]) == set(
+                ("q", "k", "v", "g", "beta"))
+            assert row["grad_gap"] < (2e-2 if row["impl"] == "pallas"
+                                      else 1e-9)
 
     def test_refuses_to_time_off_the_tpu(self, capsys):
         assert _load("kda_probe").main([]) == 3

@@ -851,15 +851,26 @@ def test_the_expert_layer_compiles_on_a_prefix_at_the_cells_shapes(
                          r"\([^\n]*\) parameter\(0\)\n\}", text)
         # both bodies' temporaries are counted, the rest's of 98,304 rows
         # too (the parent's one body: 2.72 GB; this layer is not the
-        # step's peak, which the delta rule's layers set: 7.7-7.8 GB)
+        # step's peak: 7.86 GB of temporaries while the delta rule's
+        # plain backward set it, 6.06 GB since its kernels, PR 47)
         assert compiled.memory_analysis().temp_size_in_bytes < 3.6e9
 
 
 # ------------------------------------------------- the delta rule's kernel
 
-def _kda_calls(text: str) -> int:
+def _kda_calls(text: str, name: str = kda.KERNEL_NAME) -> int:
     return sum('custom_call_target="tpu_custom_call"' in line
-               and kda.KERNEL_NAME in line for line in text.splitlines())
+               and name in line for line in text.splitlines())
+
+
+def _the_rule_is_three_kernels(text: str):
+    """One call of the forward kernel and one of each of the backward's,
+    and nothing left of the plain path's backward: no loop over head
+    groups or chunks, no triangular solve."""
+    for name in kda.KERNEL_NAMES:
+        assert _kda_calls(text, name) == 1, name
+    assert not re.search(r" while\(", text)
+    assert "triangular" not in text.lower()
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, F32],
@@ -894,13 +905,51 @@ def test_the_delta_rules_kernel_compiles_at_the_cells_shape(
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, F32],
+                         ids=["bfloat16", "float32"])
+def test_the_delta_rules_gradient_compiles_as_three_kernels(
+        one_v5e_chip, as_on_tpu, dtype):
+    """The gradient of one KDA layer's rule at `train_kda_8k`'s shape:
+    Mosaic takes both backward kernels at the blocks ``backward_block``
+    gives them (1,024 tokens a step for the backward's own forward, 512
+    for the reverse walk, which stages eight more arrays), each count of
+    fast memory under half the default scoped limit (no limit is asked
+    for); the compiled program holds one call of each kernel, no
+    ``while`` and no triangular solve.  Its temporaries are what the
+    first backward kernel keeps for the second, a state (64 KB) and two
+    chunks' inverses (32 KB) a turn of 128 tokens, 268 + 134 MB in
+    float32, and the output's adjoint as this loss makes it (268 MB):
+    671 MB, where the plain path's backward held a head group's thirty
+    float32 arrays (2 GB)."""
+    b, s, h, d = 2, 8192, 32, 128
+    plan = kda.backward_block(s, h, d, d, dtype)
+    assert [tokens for tokens, _ in plan.values()] == [1024, 512]
+    assert tuple(plan) == kda.BACKWARD_KERNEL_NAMES
+    assert all(staged < flash._DEFAULT_SCOPED_VMEM // 2
+               for _, staged in plan.values())
+    like = lambda t, *shape: jax.ShapeDtypeStruct(shape, t,
+                                                  sharding=one_v5e_chip)
+    heads = lambda x: x.reshape(b, s, h, d)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(kda.kda_chunked(heads(q), heads(k), heads(v),
+                                       heads(g), beta) ** 2)
+
+    with jax.enable_x64(False):
+        compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+            *(like(dtype, b, s, h * d),) * 3, like(F32, b, s, h * d),
+            like(F32, b, s, h)).compile()
+    _the_rule_is_three_kernels(compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
 def test_a_rematerialised_kda_mixer_compiles_with_one_kernel_call(
         one_v5e_chip, as_on_tpu):
     """The gradient of one rematerialised KDA mixer at the cell's real
     size: the ``custom_vjp`` keeps the rule's inputs and nothing of its
     output, the mixer's output is saved by name, so the backward does
     not run the kernel again: one call in the compiled text (four a
-    step of `train_kda_8k`), beside the plain path's backward."""
+    step of `train_kda_8k`), beside one of each backward kernel."""
     import json
     import os
 
@@ -925,6 +974,7 @@ def test_a_rematerialised_kda_mixer_compiles_with_one_kernel_call(
         compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
             p, y).compile()
     assert _kda_calls(compiled.as_text()) == 1
+    _the_rule_is_three_kernels(compiled.as_text())
 
 
 # ------------------------------ what a rematerialised uniform block keeps
