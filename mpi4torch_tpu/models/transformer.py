@@ -45,7 +45,8 @@ from ..ops.paged_attention import index_scores
 from ..parallel.attention import ring_attention, \
     ulysses_attention, zigzag_ring_attention
 from ..parallel.dp import all_average_tree
-from ..parallel.moe import Experts, held_experts_ffn, init_experts, \
+from ..parallel.moe import Experts, experts_ffn, \
+    held_experts_ffn, init_experts, route_experts, \
     init_moe, moe_ffn, moe_ffn_dense
 from ..parallel.zero import zero3_step, zero_step
 from ..parallel.ring import ring_shift
@@ -269,13 +270,21 @@ class LayerSpec:
     FFN), ``"ffn"`` is ``x + FFN(N(x))`` (leaves ``ln2`` and the FFN's;
     no ``ln1``, no mixer: ``mixer`` stays ``None`` and names nothing).
     One norm and one residual sum a layer; no second norm and no
-    shortcut."""
+    shortcut.
+
+    ``route_on`` says which rows the expert FFN's router reads: ``""``,
+    the rows the experts read (``ln2``'s output, behind the mixer);
+    ``"input"``, the layer's input as it enters, before the first norm
+    and the mixer (a router placed before attention: the choice of
+    experts is known while the mixer runs).  The experts still read
+    ``ln2``'s output.  Training path only."""
     mixer: Union[None, KDA, MLA, Mamba2, GQA] = None
     ffn: Optional[Experts] = None
     post_norm: bool = False
     branch: Optional[Experts] = None
     join: bool = False
     only: str = ""
+    route_on: str = ""
 
     @property
     def shortcut(self) -> bool:
@@ -329,7 +338,12 @@ class TransformerConfig:
     ``embed_scale`` multiplies the embedding rows a token looks up
     (:func:`embed_tokens`): ``sqrt(d_model)`` for a model trained with
     unit-size embeddings under a width-independent parametrisation;
-    ``1.0`` leaves the rows as they are."""
+    ``1.0`` leaves the rows as they are.
+
+    ``norm_eps`` is what the stream's norms (``ln1``, ``ln2``, the
+    post-norms, ``ln_f``) add under their root, rmsnorm and layernorm
+    alike; a mixer's inner norms (QK norms, a latent's, a head norm)
+    keep ``1e-5``."""
     vocab: int
     d_model: int
     n_heads: int
@@ -349,6 +363,7 @@ class TransformerConfig:
     remat: bool = False
     layers: Tuple[LayerSpec, ...] = ()
     embed_scale: float = 1.0
+    norm_eps: float = 1e-5
 
     def __post_init__(self):
         if self.layers:
@@ -373,6 +388,17 @@ class TransformerConfig:
                         f"layer {i} is its {s.only} alone (LayerSpec.only): "
                         "it has one norm and one residual sum, and names no "
                         "other part, second norm or shortcut")
+            for i, s in enumerate(self.layers):
+                if s.route_on not in ("", "input"):
+                    raise ValueError(
+                        f"layer {i}: LayerSpec.route_on is \"\" or "
+                        f"\"input\", got {s.route_on!r}")
+                if s.route_on and (s.ffn is None or s.only or s.shortcut):
+                    raise ValueError(
+                        f"layer {i}: route_on={s.route_on!r} places the "
+                        "router of the layer's own expert FFN (LayerSpec."
+                        "ffn) before its mixer: it needs both parts and no "
+                        "shortcut")
             if any(s.mixer is None and not s.only
                    and (s.ffn is not None or s.post_norm or s.shortcut)
                    for s in self.layers):
@@ -605,21 +631,30 @@ def _init_mixer(key, spec, d_model: int, dtype) -> Dict[str, Any]:
             "wo": dense(h * hd, d_model)}
 
 
-def _layer_norm(x, p):
+# What an expert layer counted (``parallel.moe.experts_ffn``) and the name
+# ``_forward`` hands each count out under, a row an expert layer.
+_COUNTED = {"rows": "moe_rows", "overflow": "moe_overflow_calls",
+            "sent": "ep_rows_sent", "padding": "ep_padding_rows",
+            "rounds": "ep_overflow_rounds"}
+
+
+def _layer_norm(x, p, eps: float = 1e-5):
     mu = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.var(x, axis=-1, keepdims=True)
-    return (x - mu) / jnp.sqrt(var + 1e-5) * p["scale"] + p["bias"]
+    return (x - mu) / jnp.sqrt(var + eps) * p["scale"] + p["bias"]
 
 
-def _rms_norm(x, p):
+def _rms_norm(x, p, eps: float = 1e-5):
     # No centering, no bias: normalize by the root-mean-square alone —
     # one fewer reduction and a smaller param set than LayerNorm.
     ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x / jnp.sqrt(ms + 1e-5) * p["scale"]
+    return x / jnp.sqrt(ms + eps) * p["scale"]
 
 
 def _norm(cfg: TransformerConfig, x, p):
-    return _rms_norm(x, p) if cfg.norm == "rmsnorm" else _layer_norm(x, p)
+    """A norm of the stream, under the configuration's ``norm_eps``."""
+    norm = _rms_norm if cfg.norm == "rmsnorm" else _layer_norm
+    return norm(x, p, cfg.norm_eps)
 
 
 def _rope_rotate(cfg: TransformerConfig, x, positions):
@@ -1304,7 +1339,11 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
     such a layer): ``moe_rows``, the rows each held expert took in every
     expert layer, ``(expert layers, held)``, and ``moe_overflow_calls``,
     how many of those layers had held rows behind their prefix
-    (:func:`~mpi4torch_tpu.parallel.moe.held_experts_ffn`)."""
+    (:func:`~mpi4torch_tpu.parallel.moe.held_experts_ffn`); over an
+    expert-parallel communicator also what
+    :func:`~mpi4torch_tpu.parallel.moe.exchanged_experts_ffn` counted,
+    a row an expert layer: ``ep_rows_sent`` ``(expert layers, ranks)``,
+    ``ep_padding_rows`` and ``ep_overflow_rounds``."""
     b, s_local = tokens.shape
     h = cfg.n_heads
     if comm_sp is not None and comm_sp.size > 1:
@@ -1384,13 +1423,27 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
             return x + branch_norm(cfg, spec, blk, _mla_mixer(
                 cfg, spec.mixer, blk["mixer"], y, positions), "ln1_post")
 
-    def experts_fn(spec, x, blk):
+    if comm_ep is not None and comm_ep.size > 1 \
+            and any(s.shortcut for s in cfg.layers):
+        raise CommError(
+            "a shortcut branch over an expert-parallel communicator: the "
+            "branch's exchange beside the dense path is not written; the "
+            "layer's own expert FFN (LayerSpec.ffn) is exchanged")
+
+    def routing_fn(spec, x, blk):
+        # The router of a layer that places it before the mixer, on the
+        # layer's input: handed to the experts' region behind the mixer.
         with layer_scope("moe"):
-            y = _norm(cfg, x, blk["ln2"])
-            ff, taken, _, over = held_experts_ffn(
-                y.reshape(-1, d), blk["experts"], spec.ffn, comm_ep)
+            return route_experts(x.reshape(-1, d), blk["experts"], spec.ffn)
+
+    def experts_fn(spec, x, blk, routing=None):
+        with layer_scope("moe"):
+            y = _norm(cfg, x, blk["ln2"]).reshape(-1, d)
+            ff, c = experts_ffn(y, blk["experts"], spec.ffn, comm_ep,
+                                routing=routing)
             ff = branch_norm(cfg, spec, blk, ff.reshape(x.shape), "ln2_post")
-        return x + ff, [(taken, over)]
+        return x + ff, [{name: c[k] for k, name in _COUNTED.items()
+                         if k in c}]
 
     def shortcut_fn(spec, x, blk, carried):
         # A layer that carries or joins a shortcut branch: the branch
@@ -1400,7 +1453,7 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
         taken = []
         if spec.branch is not None:
             carried, rows, _, over = shortcut_branch(spec, blk, y, comm_ep)
-            taken.append((rows, over))
+            taken.append({"moe_rows": rows, "moe_overflow_calls": over})
         if spec.ffn is None:
             ff = dense_ffn(cfg, spec, blk, y)
         else:
@@ -1408,7 +1461,7 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
                 ff, rows, _, over = held_experts_ffn(
                     y.reshape(-1, d), blk["experts"], spec.ffn, comm_ep)
                 ff = ff.reshape(x.shape)
-            taken.append((rows, over))
+            taken.append({"moe_rows": rows, "moe_overflow_calls": over})
         x = x + branch_norm(cfg, spec, blk, ff, "ln2_post")
         if spec.join:
             x, carried = x + carried, None
@@ -1444,6 +1497,7 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
             x, aux = fn(x, blk)
             aux_total = aux_total + aux
             continue
+        routing = routing_fn(spec, x, blk) if spec.route_on else None
         if spec.only != "ffn":
             x = remat(functools.partial(mixer_fn, spec))(x, blk)
         if spec.only == "mixer":
@@ -1461,7 +1515,8 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
             x, _ = remat(lambda x_, blk_: _ffn_residual(
                 cfg, blk_, x_, comm_ep))(x, blk)
         else:
-            x, taken = remat(functools.partial(experts_fn, spec))(x, blk)
+            x, taken = remat(functools.partial(experts_fn, spec))(
+                x, blk, routing)
             counted += taken
     x = _norm(cfg, x, params["ln_f"])
     if return_hidden:
@@ -1470,9 +1525,11 @@ def _forward(cfg: TransformerConfig, params, tokens, comm_sp, attn: str,
         out = x @ params["unembed"]
     if not counted:
         return out, aux_total, None
-    rows, over = zip(*counted)
-    return out, aux_total, {"moe_rows": jnp.stack(rows),
-                            "moe_overflow_calls": sum(over)}
+    stats = {k: jnp.stack([c[k] for c in counted]) for k in counted[0]
+             if k != "moe_overflow_calls"}
+    stats["moe_overflow_calls"] = sum(c["moe_overflow_calls"]
+                                      for c in counted)
+    return out, aux_total, stats
 
 
 def init_kv_cache(cfg: TransformerConfig, batch: int, dtype=jnp.float32):
@@ -1889,6 +1946,41 @@ def zero3_train_step(cfg: TransformerConfig, p_shards, template, tokens,
     return loss, new_shards, new_state
 
 
+def held_expert_leaf(path) -> bool:
+    """Whether a parameter's path names a leaf that an expert-parallel
+    rank holds for itself: ``w1`` or ``w2`` of a spec'd layer's
+    ``experts``."""
+    keys = [getattr(k, "key", None) for k in path]
+    return keys[-2:] in (["experts", "w1"], ["experts", "w2"])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _scale_cotangent(x, scale: float):
+    return x
+
+
+_scale_cotangent.defvjp(lambda x, scale: (x, None),
+                        lambda scale, _, g: (g * scale,))
+
+
+def ep_average_tree(cfg: TransformerConfig, comm_ep, params):
+    """:func:`~mpi4torch_tpu.parallel.dp.all_average_tree` over the ep
+    axis for the leaves every rank holds alike; where the configuration
+    has a per-layer spec, a rank's own experts
+    (:func:`held_expert_leaf`) pass as they are, their cotangent times
+    ``1 / ep``: the adjoint exchange has summed it over the axis, the
+    average's adjoint would have divided it."""
+    if not any(s.ffn is not None for s in cfg.layers):
+        return all_average_tree(comm_ep, params)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    own = [held_expert_leaf(path) for path, _ in flat]
+    alike = iter(all_average_tree(
+        comm_ep, [leaf for (_, leaf), o in zip(flat, own) if not o]))
+    return jax.tree.unflatten(treedef, [
+        _scale_cotangent(leaf, 1.0 / comm_ep.size) if o else next(alike)
+        for (_, leaf), o in zip(flat, own)])
+
+
 def train_step(cfg: TransformerConfig, params, tokens, comm_sp=None,
                comm_dp=None, attn: str = "ring", lr: float = 1e-2,
                comm_ep=None, return_stats: bool = False):
@@ -1898,7 +1990,8 @@ def train_step(cfg: TransformerConfig, params, tokens, comm_sp=None,
     int32}``, the rows each held expert of a per-layer spec took and how
     many of the expert layers had held rows behind their prefix
     (:func:`~mpi4torch_tpu.parallel.moe.held_experts_ffn`; empty without
-    an expert layer).
+    an expert layer), and over an expert-parallel communicator the
+    exchange's (:func:`_forward`).
 
     DP follows the reference recipe exactly (parameter-averaging Allreduce
     + loss Allreduce over the dp axis) so replicas stay in lock-step.  The
@@ -1918,7 +2011,15 @@ def train_step(cfg: TransformerConfig, params, tokens, comm_sp=None,
     lock-step, and makes gradients match the dense single-rank oracle
     (tests/test_transformer.py): adjoint-Allreduce sums each rank's
     cotangents, and an expert block's whole-mesh gradient already
-    accumulates on its owner rank via the adjoint Alltoall."""
+    accumulates on its owner rank via the adjoint Alltoall.
+
+    The expert leaves of a per-layer spec (``blocks[i]["experts"]["w1"]``,
+    ``["w2"]``) are NOT replicated over ep: each rank passes its own
+    ``n_experts / ep`` experts (:func:`~mpi4torch_tpu.parallel.moe.
+    exchanged_experts_ffn`), so they are left out of the ep average
+    (:func:`ep_average_tree`) and their gradient, which the adjoint
+    exchange has already summed over every rank's tokens, is divided by
+    ``ep`` as the average divides the others'."""
 
     def global_loss(p):
         if comm_dp is not None and comm_dp.size > 1:
@@ -1926,7 +2027,7 @@ def train_step(cfg: TransformerConfig, params, tokens, comm_sp=None,
         if comm_sp is not None and comm_sp.size > 1:
             p = all_average_tree(comm_sp, p)
         if comm_ep is not None and comm_ep.size > 1:
-            p = all_average_tree(comm_ep, p)
+            p = ep_average_tree(cfg, comm_ep, p)
         loss, stats = _lm_loss(cfg, p, tokens, comm_sp, attn, None, comm_ep,
                                0)
         if comm_dp is not None and comm_dp.size > 1:

@@ -201,10 +201,14 @@ def ragged_alltoall(comm, x, send_counts) -> Tuple:
                                            send_counts, size, capacity)
 
     xz = _masked(x, send_counts, capacity)
-    # Gather sources along a fresh axis, keep my destination block:
-    # (size, cap, *feat) -> my (1, size*cap, *feat), source-major.
-    recv = comm.Alltoall(xz, gatheraxis=1, scatteraxis=0, numelem=1)
-    recv = recv.reshape((size, capacity) + x.shape[2:])
+    # Gather sources along a fresh axis of their own, keep my
+    # destination block: (size, 1, cap, *feat) -> my (1, size, cap,
+    # *feat).  The fresh axis is for the adjoint, which splits the
+    # gathered axis: gathered along the rows, that axis is size * cap
+    # long, and the v5e's compiler took minutes over its split at
+    # 100,000 rows (PERF.md section 6, PR 48).
+    recv = comm.Alltoall(xz[:, None], gatheraxis=1, scatteraxis=0, numelem=1)
+    recv = recv.reshape(x.shape)
     rc = comm.Alltoall(send_counts.reshape(size, 1), gatheraxis=1,
                        scatteraxis=0, numelem=1)
     return recv, rc.reshape(size)

@@ -26,7 +26,12 @@ renormalised or not, swiglu or relu² experts at the stream's width or in
 a latent behind one shared down- and up-projection, a shared expert and
 zero-compute (identity) experts behind the routed ones, for a rank that is told
 which experts it holds and computes their part of the result for every
-token routed to them — no capacity, nothing dropped, no exchange yet.
+token routed to them — no capacity, nothing dropped.  Given an expert-
+parallel communicator it is the whole layer
+(:func:`exchanged_experts_ffn`): every (token, choice) row goes to the
+rank that holds its expert and comes back, in rounds of a fixed buffer,
+over :func:`~mpi4torch_tpu.ops.ragged.ragged_alltoall`, forward and
+adjoint.
 """
 
 from __future__ import annotations
@@ -39,6 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from ..runtime import CommError
+from ..utils.profiling import layer_scope
 
 
 def top1_route(router_logits, capacity: int):
@@ -240,9 +246,15 @@ class Experts:
     the weighted sum over a token's experts is taken in the latent.  The
     router and the shared expert read the stream itself.  ``act`` is an
     expert's form, the shared one's too: ``"swiglu"`` (``W2 (silu(Wg x)
-    * Wu x)``, ``w1 = [gate | up]``) or ``"relu2"`` (``W2 relu(W1 x)^2``,
-    no gate).  ``d_shared`` is the shared expert's width where it is not
-    ``n_shared * d_expert``."""
+    * Wu x)``, ``w1 = [gate | up]``), ``"reglu"`` (``W2 (relu(Wg x) * Wu
+    x)``, the same leaves) or ``"relu2"`` (``W2 relu(W1 x)^2``, no
+    gate).  ``d_shared`` is the shared expert's width where it is not
+    ``n_shared * d_expert``.
+
+    Under an expert-parallel communicator of ``R`` ranks
+    (:func:`exchanged_experts_ffn`) the spec is every rank's alike:
+    ``n_held = n_experts / R``, ``first_expert = 0``, and rank ``r``
+    holds experts ``r * n_held`` to ``(r + 1) * n_held - 1``."""
     n_experts: int
     top_k: int
     d_expert: int
@@ -269,7 +281,7 @@ class Experts:
     def __post_init__(self):
         if self.score not in ("sigmoid", "softmax"):
             raise ValueError(f"unknown score function {self.score!r}")
-        if self.act not in ("swiglu", "relu2"):
+        if self.act not in _EXPERT:
             raise ValueError(f"unknown expert activation {self.act!r}")
         if self.n_zero < 0:
             raise ValueError(f"n_zero={self.n_zero} must be >= 0")
@@ -298,11 +310,12 @@ def init_experts(key, spec: Experts, d_model: int,
     """Parameters of one held share: the router at its full width
     (``spec.width``: zero-compute experts have an output each and no
     other leaf), a selection ``bias`` (zeros; it takes no gradient), the
-    held experts' ``w1`` (fused ``[gate | up]`` for swiglu) and ``w2``,
+    held experts' ``w1`` (fused ``[gate | up]`` for swiglu and reglu) and
+    ``w2``,
     the shared expert, and a latent layer's ``down`` and ``up``."""
     kr, k1, k2, k3, k4 = jax.random.split(key, 5)
     f, e = spec.d_expert, spec.n_held
-    fan = 2 if spec.act == "swiglu" else 1
+    fan = 1 if spec.act == "relu2" else 2
     d_in = spec.latent or d_model
 
     def dense(key, *shape):
@@ -487,11 +500,16 @@ def _swiglu(x, w1, w2, dot):
     return dot(jax.nn.silu(gate) * up, w2)
 
 
+def _reglu(x, w1, w2, dot):
+    gate, up = jnp.split(dot(x, w1), 2, axis=-1)
+    return dot(jax.nn.relu(gate) * up, w2)
+
+
 def _relu2(x, w1, w2, dot):
     return dot(jnp.square(jax.nn.relu(dot(x, w1))), w2)
 
 
-_EXPERT = {"swiglu": _swiglu, "relu2": _relu2}
+_EXPERT = {"swiglu": _swiglu, "reglu": _reglu, "relu2": _relu2}
 
 
 def _sorted_part(act, lo, n, x, w1, w2, weight, order, inverse, kept, rows):
@@ -574,7 +592,7 @@ _prefix_then_rest.defvjp(_prefix_then_rest_fwd, _prefix_then_rest_bwd)
 
 
 def held_experts_ffn(x, params: Dict[str, Any], spec: Experts,
-                     comm_ep=None, live=None):
+                     comm_ep=None, live=None, routing=None):
     """The held experts' part of the layer for ``x`` ``(T, d)``, plus the
     shared expert and the zero-compute experts: ``sum over chosen and
     held e of w_e E_e(x) + E_shared(x) + (sum over chosen zero-compute z
@@ -613,6 +631,16 @@ def held_experts_ffn(x, params: Dict[str, Any], spec: Experts,
     no expert's time and are not counted (a serving decode step's free
     slots; their ``y`` rows are the shared expert's alone).
 
+    ``routing`` (optional) is ``(chosen, weight)`` as :func:`route_topk`
+    gives them, taken by the caller from rows other than ``x`` (a layer
+    whose router reads its input before the mixer); without it the
+    router reads ``x``.
+
+    ``comm_ep`` of more than one rank makes this the whole layer: every
+    rank's rows go to the experts' owners and back
+    (:func:`exchanged_experts_ffn`; ``overflow`` is then 1 where a round
+    ran behind the first).
+
     Returns ``(y, rows, zero_pairs, overflow)``: ``rows`` ``(n_held,)``,
     the rows each held expert took, which are the group sizes the
     products are handed (cut at ``B`` between the prefix and the rest);
@@ -623,16 +651,34 @@ def held_experts_ffn(x, params: Dict[str, Any], spec: Experts,
     Inference runs the same code: a compiled prefill or decode step of
     ``mpi4torch_tpu.serve`` calls it on its rows and hands the counts
     out with the step's record."""
+    y, c = experts_ffn(x, params, spec, comm_ep, live, routing)
+    return y, c["rows"], c["zero_pairs"], c["overflow"]
+
+
+def experts_ffn(x, params: Dict[str, Any], spec: Experts, comm_ep=None,
+                live=None, routing=None):
+    """:func:`held_experts_ffn` with its counts by name, ``(y, {"rows",
+    "zero_pairs", "overflow"})``, and the ONE place that decides between
+    a rank's held share and the exchange: over an expert-parallel
+    communicator of more than one rank the counts are
+    :func:`exchanged_experts_ffn`'s, ``rounds``, ``sent`` and ``padding``
+    among them."""
     if comm_ep is not None and comm_ep.size > 1:
-        raise CommError(
-            "held_experts_ffn computes one rank's share and exchanges "
-            f"nothing; an expert-parallel communicator of size "
-            f"{comm_ep.size} needs the Alltoall exchange of the top-k "
-            "layer, which is not written yet")
+        if live is not None:
+            raise CommError(
+                "held_experts_ffn: the exchange over an expert-parallel "
+                f"communicator (size {comm_ep.size}) is the training "
+                "path's; a serving step's free slots (live) are not "
+                "exchanged yet")
+        return exchanged_experts_ffn(x, params, spec, comm_ep, routing)
+    y, rows, zero_pairs, overflow = _held_share(x, params, spec, live,
+                                                routing)
+    return y, {"rows": rows, "zero_pairs": zero_pairs, "overflow": overflow}
+
+
+def _held_share(x, params, spec: Experts, live, routing):
     k, held = spec.top_k, spec.n_held
-    chosen, weight = route_topk(x, params["router"], params["bias"], k,
-                                spec.scale, score=spec.score,
-                                renorm=spec.renorm)
+    chosen, weight = routing or route_experts(x, params, spec)
     local = chosen.reshape(-1) - spec.first_expert
     here = (local >= 0) & (local < held)
     if live is not None:
@@ -662,6 +708,16 @@ def held_experts_ffn(x, params: Dict[str, Any], spec: Experts,
             spec.act, prefix, (xin, params["w1"], params["w2"], weight),
             order, inverse, kept, rows)
         overflow = (jnp.sum(rows) > prefix).astype(jnp.int32)
+    y, zero_pairs = _at_home(x, y, chosen, weight, params, spec, live)
+    return y, rows, zero_pairs, overflow
+
+
+def _at_home(x, y, chosen, weight, params, spec: Experts, live=None):
+    """What a token's own rank adds to the routed experts' weighted sum
+    ``y``, in ``weight``'s type: the zero-compute experts' part, a
+    latent layer's up-projection and the shared expert.  Returns ``(y``
+    in ``x``'s type, the live pairs that chose a zero-compute
+    expert``)``."""
     zero_pairs = 0
     if spec.n_zero:
         is_zero = chosen >= spec.n_experts
@@ -674,6 +730,238 @@ def held_experts_ffn(x, params: Dict[str, Any], spec: Experts,
     if spec.latent:
         y = y @ params["up"]
     if spec.n_shared:
-        y = y + expert(x, params["shared_w1"], params["shared_w2"],
-                       jnp.matmul)
-    return y, rows, zero_pairs, overflow
+        y = y + _EXPERT[spec.act](x, params["shared_w1"],
+                                  params["shared_w2"], jnp.matmul)
+    return y, zero_pairs
+
+
+# ---------------------------------------------------------------------------
+# The same layer over an expert-parallel communicator: the rows' exchange
+# ---------------------------------------------------------------------------
+
+# A round's buffer holds this many times a destination's even share of a
+# rank's pairs (in whole row tiles).  Two readings on four v5e chips, both
+# under a routing that the seeded weights hold even (largest chip 1.009 of
+# the mean): a step of 2,176 ms at 1.25 and of 2,783 ms at 2.0, no round
+# behind the first in either (PERF.md section 6, PR 48).  Under an even
+# routing the smaller buffer wins by its padding alone; what the later
+# rounds of an uneven one cost against it was not measured.
+_EXCHANGE_ROOM = 1.25
+
+
+def _exchange_rows(pairs: int, size: int) -> int:
+    """``C``: the rows of a round's buffer for ONE destination:
+    :data:`_EXCHANGE_ROOM` times the even share ``pairs / size`` in whole
+    tiles of :data:`_ROW_TILE` rows, ``pairs`` itself where that is no
+    less.  ``ceil(pairs / C)`` rounds are always enough: a rank has
+    ``pairs`` rows in all."""
+    room = -(-int(_EXCHANGE_ROOM * pairs) // size)
+    return min(pairs, -(-room // _ROW_TILE) * _ROW_TILE)
+
+
+def route_experts(x, params: Dict[str, Any], spec: Experts):
+    """:func:`route_topk` of ``x`` ``(T, d)`` under ``spec``: ``(chosen,
+    weight)``, what :func:`held_experts_ffn` takes as ``routing``."""
+    return route_topk(x, params["router"], params["bias"], spec.top_k,
+                      spec.scale, score=spec.score, renorm=spec.renorm)
+
+
+def _first_then_rest(part, diff, needed, where):
+    """``sum over j < needed of part(j, *diff, *where)`` for a traced
+    ``needed >= 1`` that is the same on every rank: part 0 always, the
+    later ones in a loop of ``needed - 1`` turns over ONE body, forward
+    and backward, as :func:`_prefix_then_rest` takes its rest: the
+    backward has part 0's products from what the forward kept and runs a
+    later part's forward and backward inside its turn, and a part not
+    taken costs nothing.  ``part`` takes its number traced and may hold
+    collectives: every rank makes the same turns."""
+
+    def rest(y, diff, needed, where):
+        return jax.lax.fori_loop(
+            1, needed, lambda j, y: y + part(j, *diff, *where), y)
+
+    @jax.custom_vjp
+    def run(diff, needed, where):
+        return rest(part(0, *diff, *where), diff, needed, where)
+
+    def fwd(diff, needed, where):
+        y, pull = jax.vjp(lambda *d: part(0, *d, *where), *diff)
+        return rest(y, diff, needed, where), (pull, diff, needed, where)
+
+    def bwd(res, g):
+        pull, diff, needed, where = res
+        grads = jax.lax.fori_loop(
+            1, needed, lambda j, grads: jax.tree.map(
+                jnp.add, grads,
+                jax.vjp(lambda *d: part(j, *d, *where), *diff)[1](g)),
+            pull(g))
+        return grads, None, jax.tree.map(lambda _: None, where)
+
+    run.defvjp(fwd, bwd)
+    return run(diff, needed, where)
+
+
+def _place_in_group(group, groups: int):
+    """``(place, counts)``: how many earlier entries of ``group`` ``(n,)``
+    (ints in ``[0, groups)``) share each entry's group, and the groups'
+    sizes: what a stable sort by group needs, without a sort (a sort of
+    100,000 keys takes the v5e's compiler 20 s an instance).  The running
+    counts are products with a triangle of ones, a block of
+    :data:`_ROW_TILE` entries at a time: exact, the counts staying under
+    2**24."""
+    n = group.shape[0]
+    blk = min(_ROW_TILE, n)
+    pad = -n % blk
+    hot = jax.nn.one_hot(jnp.pad(group, (0, pad), constant_values=groups),
+                         groups, dtype=jnp.bfloat16).reshape(-1, blk, groups)
+    inner = jnp.einsum("ij,bjg->big", jnp.tril(jnp.ones((blk, blk), hot.dtype)),
+                       hot, preferred_element_type=jnp.float32)
+    sums = inner[:, -1, :]
+    before = jnp.matmul(
+        jnp.tril(jnp.ones((sums.shape[0],) * 2, sums.dtype), -1), sums,
+        precision=jax.lax.Precision.HIGHEST)
+    running = (inner + before[:, None, :]).reshape(-1, groups)[:n]
+    place = jnp.take_along_axis(
+        running, jnp.clip(group, 0, groups - 1)[:, None], axis=1)[:, 0] - 1
+    return place.astype(jnp.int32), \
+        (before[-1] + sums[-1]).astype(jnp.int32)
+
+
+def exchanged_experts_ffn(x, params: Dict[str, Any], spec: Experts, comm_ep,
+                          routing=None):
+    """The whole top-k layer for this rank's ``x`` ``(T, d)`` over an
+    expert-parallel communicator of ``R`` ranks, each of which holds
+    ``n_held = n_experts / R`` experts (``params["w1"]``, ``["w2"]``: this
+    rank's own, experts ``rank * n_held`` on; the router, and a shared
+    expert or a latent's projections, replicated): ``sum over chosen e of
+    w_e E_e(x)`` with every expert computed by its owner, plus what
+    :func:`held_experts_ffn` adds at a token's home.
+
+    Every rank routes its own tokens over all the experts; its (token,
+    choice) pairs, sorted by expert, lie sorted by owner.  A ROUND moves
+    at most ``C`` rows to each owner (:func:`_exchange_rows`; a Python
+    int from shapes alone): the ``(R, C, d)`` buffer goes out through
+    :func:`~mpi4torch_tpu.ops.ragged.ragged_alltoall`, the owner lays
+    what the ``R`` ranks sent out by expert, runs the two grouped
+    products of the one-rank path (``jax.lax.ragged_dot``) over them, the
+    rows go back through the same exchange into the slots they left
+    from, and the token's rank adds them up under their weights.  The
+    counts of each (sender, expert) go ahead once, in one small
+    ``Alltoall``, and every place (a pair's in its buffer, a row's among
+    its expert's at the owner) is arithmetic on running counts: no sort.
+    No row is ever dropped: round 0 runs always, and the rounds behind
+    it in a loop of ``ceil(most rows any rank has for one owner / C) -
+    1`` turns over one body, the count all-reduced so that every rank
+    makes the same turns, forward and backward
+    (:func:`_first_then_rest`); an even routing makes none, a collapsed
+    one ``R / 1.25``.  Forward and adjoint move through the facade's
+    differentiable ``Alltoall``: an expert's gradient arrives at its
+    owner summed over every rank's tokens.
+
+    Returns ``(y, counts)``: ``rows`` ``(n_held,)``, the rows each of
+    this rank's experts took from all ranks; ``rounds``, int32, the
+    rounds taken behind the first, and ``overflow``, 1 where there was
+    one; ``sent`` ``(R,)``, the rows this rank
+    sent to each rank (itself among them); ``padding``, the buffer rows
+    it sent that held no row; ``zero_pairs`` as
+    :func:`held_experts_ffn`."""
+    from ..constants import MPI_MAX
+    from ..ops.ragged import ragged_alltoall
+
+    size, k, held = comm_ep.size, spec.top_k, spec.n_held
+    if spec.first_expert or held * size != spec.n_experts:
+        raise ValueError(
+            f"over {size} expert-parallel ranks each holds n_experts / "
+            f"{size} experts from its own rank's first on: the spec says "
+            f"n_held={held} of {spec.n_experts}, first_expert="
+            f"{spec.first_expert}")
+    chosen, weight = routing or route_experts(x, params, spec)
+    xin = x @ params["down"] if spec.latent else x
+    pairs = chosen.size
+    cap = _exchange_rows(pairs, size)
+
+    # Pairs sorted by expert lie sorted by owner; a zero-compute expert's
+    # pairs stay at home, behind them all (group n_experts).
+    flat = chosen.reshape(-1)
+    group = jnp.minimum(flat, spec.n_experts)
+    among, per_expert = _place_in_group(group, spec.n_experts + 1)
+    per_expert = per_expert[:-1]
+    sent = jnp.sum(per_expert.reshape(size, held), axis=1)
+    owner = jnp.minimum(group // held, size - 1)
+    # A pair's place among the rows bound for its owner, the owner's
+    # rows by expert.
+    first = (jnp.cumsum(per_expert) - per_expert)[
+        jnp.minimum(group, spec.n_experts - 1)] - (
+            jnp.cumsum(sent) - sent)[owner]
+    place = jnp.where(group < spec.n_experts, first + among, -1)
+
+    with layer_scope("moe_exchange"):
+        # (sender, held expert): what each rank has for my experts.
+        taken = comm_ep.Alltoall(per_expert.reshape(size, 1, held),
+                                 gatheraxis=1, scatteraxis=0, numelem=1
+                                 ).reshape(size, held)
+        needed = -(-comm_ep.Allreduce(jnp.max(sent), MPI_MAX) // cap)
+    needed = jnp.clip(needed, 1, -(-pairs // cap))
+    expert = _EXPERT[spec.act]
+    buffer = size * cap
+
+    def one_round(j, xin, w1, w2, weight, place, owner, sent, taken):
+        lo = j * cap
+        sender = jnp.repeat(jnp.arange(size, dtype=jnp.int32), cap)
+        # Where each of my pairs lies in this round's buffer, and back:
+        # the pair a buffer row holds (a row that holds none names pair
+        # 0 and is masked).
+        at = jnp.where((place >= lo) & (place < lo + cap),
+                       owner * cap + place - lo, -1)
+        pair = jnp.arange(pairs, dtype=jnp.int32)
+        rows = jnp.zeros((buffer,), jnp.int32).at[
+            jnp.where(at >= 0, at, buffer + pair)].set(
+                pair, mode="drop", unique_indices=True)
+        slot = jnp.tile(jnp.arange(cap, dtype=jnp.int32), size)
+        full = (lo + slot < sent[sender])[:, None]
+        at = at.reshape(weight.shape)
+        xs = _part_rows(xin, rows, at, full)
+        with layer_scope("moe_exchange"):
+            got, count = ragged_alltoall(
+                comm_ep, xs.reshape(size, cap, -1),
+                jnp.clip(sent - lo, 0, cap))
+            # A sender's rows lie sorted by expert.  Sorted by expert
+            # over all senders (then by sender, then as they came) they
+            # are the grouped products' groups; the buffer rows that hold
+            # nothing follow them.  All of it from the counts that went
+            # ahead: which of its rows a sender has in this round, expert
+            # by expert.
+            ends = jnp.cumsum(taken, axis=1)
+            start = jnp.clip(ends - taken, lo, lo + cap) - lo
+            mine = jnp.clip(ends, lo, lo + cap) - lo - start
+            sizes = jnp.sum(mine, axis=0)
+            of = jnp.sum(slot[:, None] >= (start + mine)[sender], axis=1)
+            has = of < held
+            of = jnp.minimum(of, held - 1)
+            ahead = (jnp.cumsum(sizes) - sizes)[of] \
+                + (jnp.cumsum(mine, axis=0) - mine)[sender, of] \
+                + slot - start[sender, of]
+            empty = cap - count
+            spread = jnp.where(
+                has, ahead, jnp.sum(sizes) + (jnp.cumsum(empty) - empty)[
+                    sender] + slot - count[sender])
+            gather = jnp.zeros_like(spread).at[spread].set(
+                jnp.arange(buffer, dtype=spread.dtype), unique_indices=True)
+            grouped = _permute_rows(got.reshape(buffer, -1), gather, spread)
+        inside = (jnp.arange(buffer) < jnp.sum(sizes))[:, None]
+        ys = jnp.where(inside, expert(
+            grouped, w1, w2, lambda a, w: jax.lax.ragged_dot(a, w, sizes)), 0)
+        with layer_scope("moe_exchange"):
+            back, _ = ragged_alltoall(
+                comm_ep, _permute_rows(ys, spread, gather).reshape(
+                    size, cap, -1), count)
+        return _weighted_back(back.reshape(buffer, -1), weight, rows, at)
+
+    y = _first_then_rest(
+        one_round, (xin, params["w1"], params["w2"], weight), needed,
+        (place, owner, sent, taken))
+
+    y, zero_pairs = _at_home(x, y, chosen, weight, params, spec)
+    return y, {"rows": jnp.sum(taken, axis=0), "zero_pairs": zero_pairs,
+               "overflow": jnp.minimum(needed - 1, 1), "rounds": needed - 1,
+               "sent": sent, "padding": needed * buffer - jnp.sum(sent)}

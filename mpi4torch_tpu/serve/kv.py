@@ -143,7 +143,8 @@ def validate_tp(cfg: TransformerConfig, size: int, *,
     latent cache entry, a Mamba-2 mixer through a per-slot state entry
     beside the cache, the held share of an expert layer inside the
     compiled prefill and decode step.  Refused by name: a KDA mixer (its
-    state entry is not written yet), and more
+    state entry is not written yet), a router placed before the mixer
+    (``LayerSpec.route_on``), and more
     than one rank (the heads of a latent or a state-space layer and the
     experts' exchange are not sharded yet; a shortcut branch is named
     where the spec has one, since it is the branch's exchange that its
@@ -162,6 +163,12 @@ def validate_tp(cfg: TransformerConfig, size: int, *,
             "dense TP decode path — expert-parallel serving needs the "
             "Alltoall routing schedule")
     if cfg.layers:
+        if any(sp.route_on for sp in cfg.layers):
+            raise CommError(
+                "serve: LayerSpec.route_on (a router that reads the "
+                "layer's input, before the mixer) is the training path's: "
+                "the serving walk routes on the rows the experts read and "
+                "carries no routing across a layer's mixer yet")
         if any(isinstance(sp.mixer, KDA) for sp in cfg.layers):
             raise CommError(
                 "serve: a KDA mixer keeps a recurrent state of its own "

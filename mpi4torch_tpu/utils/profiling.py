@@ -142,7 +142,8 @@ LAYER_SCOPES = {"kda": "mpi4torch.kda", "mla": "mpi4torch.mla",
                 "ssm_update": "mpi4torch.ssm_update",
                 "attn": "mpi4torch.attn",
                 "attn_window": "mpi4torch.attn_window",
-                "attn_full": "mpi4torch.attn_full"}
+                "attn_full": "mpi4torch.attn_full",
+                "moe_exchange": "mpi4torch.moe_exchange"}
 
 
 def layer_scope(kind: str):
@@ -164,7 +165,13 @@ def layer_scope(kind: str):
     rotation, gate, output projection), with one of two more INSIDE it
     around the attention itself (on the serving path the cache write
     and the read): ``attn_window`` on a layer with a window,
-    ``attn_full`` on one without."""
+    ``attn_full`` on one without.  ``moe_exchange`` lies INSIDE ``moe``
+    where the layer runs over an expert-parallel communicator
+    (``parallel.moe.exchanged_experts_ffn``): the all-to-alls that carry
+    the rows to their experts' owners and back, the counts that go
+    ahead, and the owner's sort of what arrived; forward, recomputed and
+    backward.  A table that maps ``op_name`` s to scopes looks for it
+    before ``moe``, whose name it begins with."""
     return _labeled_scope(LAYER_SCOPES[kind])
 
 

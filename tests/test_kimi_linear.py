@@ -694,14 +694,21 @@ def test_a_spec_is_checked_when_the_configuration_is_made():
                         first_expert=first, n_held=held)
 
 
-def test_an_expert_parallel_communicator_is_refused():
+def test_an_expert_parallel_communicator_needs_every_expert_held():
+    """Over ``R`` ranks the layer exchanges its rows (tests/
+    test_smallthinker.py), and every expert then has an owner: this
+    configuration's share, 2 of 8 experts, does not lay the layer over 2
+    ranks, and a serving step's free slots are not exchanged."""
     class Two:
         size = 2
 
     p = _params()["blocks"][1]["experts"]
-    with pytest.raises(mpi.CommError, match="Alltoall exchange"):
-        moe.held_experts_ffn(_x().reshape(-1, CFG["hidden_size"]), p,
-                             TCFG.layers[1].ffn, comm_ep=Two())
+    x = _x().reshape(-1, CFG["hidden_size"])
+    with pytest.raises(ValueError, match="n_held=2 of 8"):
+        moe.held_experts_ffn(x, p, TCFG.layers[1].ffn, comm_ep=Two())
+    with pytest.raises(mpi.CommError, match="free slots"):
+        moe.held_experts_ffn(x, p, TCFG.layers[1].ffn, comm_ep=Two(),
+                             live=jnp.ones((x.shape[0],), bool))
 
 
 @pytest.mark.parametrize("call", [

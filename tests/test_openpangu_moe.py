@@ -342,11 +342,32 @@ def test_a_layer_spec_is_served_on_one_rank_only():
 # is the parent's but for the number at the end of 76 private functions'
 # names, one higher each (``@silu_160`` -> ``@silu_161``); with those
 # numbers taken out the two texts are equal.
+# PR 48 added the last five, from ITS parent (PR 47, 1e7a865): Mistral's
+# data-parallel training step over two devices (``all_average_tree``,
+# ``_norm``) and the paged decode steps of the four other expert serving
+# configurations (``held_experts_ffn``'s one-rank path, the spec walk,
+# ``GQA``).  With the first three these are the programs of all nine cells
+# the benchmark had: PR 48 split ``held_experts_ffn`` (``experts_ffn``,
+# ``_held_share``, ``_at_home``), gave the norms their ``eps`` as an
+# argument and hands ``_forward``'s counters out by name, and every one of
+# the eight lowers to its parent's text, letter for letter (no old cell
+# was run on the chip for that PR: four-chip machines were scarce).
 PARENT_TEXTS = {
     "kimi": "c8f6723c62b880c17377b0e0b8a36e11f36a27c25731b3c74602c7af50198f9e",
     "internlm2": "ddcbb9f523103a01172891ee29d82bf8839323abf424f1e05587b9142ec5ad65",
     "openpangu": "cd48ae9b73a5afa03dceab1ab8e7f1069b6759f10c550555e566776f4e353fc7",
+    "mistral_dp": "ef96cb53e8cd1abb240ece3fdeae805381c8e8061c82e4d8e10e48280cebf744",
+    "trinity": "31999685375c08a8e7afe257fd62224894c879429401a8ca6c89622960c5743d",
+    "longcat": "93a18b45bdaff5a8c9d0bddab3b1e5c4246db276631b09416f65975bd1ec4034",
+    "glm": "e3db8fb97a6e095b0c6495dc0e593c12c44975b8a687287abeec1fa33451768e",
+    "nemotron": "cc2ff00615d8e20a3aa91ebe6a0100453d23f9f40eddf4dc12ede076e32a0a66",
 }
+# (configuration, family) of the serving configurations whose family file
+# makes the parameters.
+_SERVED = {"trinity": ("trinity-mini", "afmoe"),
+           "longcat": ("longcat-flash-chat", "longcat_flash"),
+           "glm": ("glm-5.2", "glm_dsa"),
+           "nemotron": ("nemotron-3-super-120b-a12b", "nemotron_h")}
 
 
 def _lowered_text(which: str) -> str:
@@ -356,11 +377,37 @@ def _lowered_text(which: str) -> str:
     from benchmarks.families import kimi_linear
 
     name = {"kimi": "kimi-linear-48b-a3b", "internlm2": "internlm2-1.8b",
-            "openpangu": PUBLISHED["name"]}
+            "openpangu": PUBLISHED["name"], "mistral_dp": "mistral-7b-v0.1",
+            **{k: v[0] for k, v in _SERVED.items()}}
     with open(os.path.join(ROOT, "benchmarks", "configs",
                            name[which] + ".json")) as f:
         cfg = json.load(f)
     cfg = harness.merged(cfg, cfg["rehearsal"])
+    if which == "mistral_dp":
+        mesh = Mesh(np.asarray(jax.devices()[:2]), ("mpi",))
+        repl = NamedSharding(mesh, P())
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=repl),
+            jax.eval_shape(lambda: weights.make_params(cfg, 1, F32)))
+        step = program.build_train_step(
+            program.transformer_config(cfg, remat=True), mesh, 2, 0.3, True)
+        return step.lower(params, jax.ShapeDtypeStruct(
+            (4, 64), jnp.int32, sharding=repl)).as_text()
+    if which in _SERVED:
+        import importlib
+
+        family = importlib.import_module(
+            "benchmarks.families." + _SERVED[which][1])
+        # A window class of pages and a Mamba-2 layer refuse prefix
+        # sharing.
+        eng = serve.Engine(
+            family.transformer_config(cfg), family.make_params(cfg, 1, F32),
+            serve.ServeConfig(slots=4, block_size=8,
+                              prefix_cache=which in ("longcat", "glm")),
+            spmd=True, nranks=1)
+        eng.submit(np.arange(1, 10), max_new=3)
+        eng.step()
+        return eng.lower_step().as_text()
     if which == "kimi":
         mesh = Mesh(np.asarray(jax.devices()[:1]), ("mpi",))
         repl = NamedSharding(mesh, P())

@@ -1031,3 +1031,52 @@ def test_the_mistral_step_keeps_its_outputs_and_fits(v5e_2x2, as_on_tpu,
     print(f"{chips} chip(s): arguments {mem.argument_size_in_bytes / 1e9:.3f}"
           f" GB + temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB")
     assert held < T._REMAT_ROOM * limit
+
+
+def test_the_experts_exchange_compiles_without_a_sort_on_four_chips(v5e_2x2):
+    """SmallThinker's expert layer over the host's four chips at its
+    widths (2,560 wide, 16 of 64 experts of 768 a chip, top-6; 2,048
+    tokens a chip here for the compiler's time), forward and gradient:
+    the all-to-alls of rows (out and back; round 0 forward and backward,
+    and the one body of the later rounds each way) beside the small ones
+    of the counts, and NO sort: a sort of
+    100,000 keys takes the v5e's compiler 20 s an instance, and the
+    adjoint of an all-to-all gathered along the rows took it minutes
+    (PERF.md section 6, PR 48)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import mpi4torch_tpu as mpi
+    from mpi4torch_tpu.parallel import moe
+
+    mesh = Mesh(np.asarray(v5e_2x2), ("mpi",))
+    comm = mpi.comm_from_mesh(mesh, "mpi")
+    d, f, n, k, tokens = 2560, 768, 64, 6, 2048
+    spec = moe.Experts(n, k, f, 0, n // 4, score="softmax", act="reglu")
+    specs = {"router": P(), "bias": P(), "w1": P("mpi"), "w2": P("mpi")}
+    shapes = {"router": (d, n), "bias": (n,), "w1": (n, d, 2 * f),
+              "w2": (n, f, d)}
+    params = {name: jax.ShapeDtypeStruct(
+        shape, jnp.bfloat16, sharding=NamedSharding(mesh, specs[name]))
+        for name, shape in shapes.items()}
+    x = jax.ShapeDtypeStruct((4 * tokens, d), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("mpi")))
+
+    def body(p, x):
+        def loss(p, x):
+            y, counts = moe.exchanged_experts_ffn(x, p, spec, comm)
+            return jnp.sum(y.astype(F32)), counts["rounds"]
+
+        (_, rounds), grads = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)(p, x)
+        return grads, rounds[None]
+
+    with jax.enable_x64(False):
+        text = jax.jit(jax.shard_map(
+            body, mesh=mesh, in_specs=(specs, P("mpi")),
+            out_specs=((specs, P("mpi")), P("mpi")), check_vma=False)).lower(
+                params, x).compile().as_text()
+    assert not re.search(r"= \S+ sort\(", text)
+    wide = [m for m in re.finditer(r"= (\S+) all-to-all(?:-start)?\(", text)
+            if "2560" in m.group(1)]
+    assert 6 <= len(wide) <= 10 and not len(wide) % 2, len(wide)
+    assert "ragged-dot" in text
