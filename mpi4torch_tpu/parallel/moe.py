@@ -827,6 +827,18 @@ def _place_in_group(group, groups: int):
         (before[-1] + sums[-1]).astype(jnp.int32)
 
 
+def _by_sender(w, senders: int):
+    """An owner's ``(held, m, n)`` matrices as the groups of a buffer that
+    lies sender by sender: ``(senders * (held + 1), m, n)``, each sender's
+    the held experts' and one of zeros for its tail.  Broadcast, not
+    gathered by an index: the adjoint is then a sum over the sender axis
+    and no scatter-add, and what a tail's rows give falls into the slot
+    of zeros and is left there."""
+    w = jnp.pad(w, ((0, 1), (0, 0), (0, 0)))
+    return jnp.broadcast_to(w, (senders,) + w.shape).reshape(
+        (-1,) + w.shape[1:])
+
+
 def exchanged_experts_ffn(x, params: Dict[str, Any], spec: Experts, comm_ep,
                           routing=None):
     """The whole top-k layer for this rank's ``x`` ``(T, d)`` over an
@@ -841,14 +853,20 @@ def exchanged_experts_ffn(x, params: Dict[str, Any], spec: Experts, comm_ep,
     choice) pairs, sorted by expert, lie sorted by owner.  A ROUND moves
     at most ``C`` rows to each owner (:func:`_exchange_rows`; a Python
     int from shapes alone): the ``(R, C, d)`` buffer goes out through
-    :func:`~mpi4torch_tpu.ops.ragged.ragged_alltoall`, the owner lays
-    what the ``R`` ranks sent out by expert, runs the two grouped
-    products of the one-rank path (``jax.lax.ragged_dot``) over them, the
-    rows go back through the same exchange into the slots they left
-    from, and the token's rank adds them up under their weights.  The
-    counts of each (sender, expert) go ahead once, in one small
-    ``Alltoall``, and every place (a pair's in its buffer, a row's among
-    its expert's at the owner) is arithmetic on running counts: no sort.
+    :func:`~mpi4torch_tpu.ops.ragged.ragged_alltoall`, the owner runs
+    the two grouped products (``jax.lax.ragged_dot``) over the buffer
+    WHERE IT ARRIVED, the rows go back through the same exchange into
+    the slots they left from, and the token's rank adds them up under
+    their weights.  The counts of each (sender, expert) go ahead once,
+    in one small ``Alltoall``, and every place (a pair's in its buffer,
+    a group's length at the owner) is arithmetic on running counts: no
+    sort, and no row moved at the owner.  A sender's rows arrive sorted
+    by expert, so the buffer is, sender by sender, a run of rows a held
+    expert and the tail of its ``C`` slots that holds nothing: ``R *
+    (held + 1)`` groups that sum to ``R * C`` in every round, against
+    the owner's matrices with one slot of zeros for the tails, broadcast
+    over the senders (:func:`_by_sender`); a matrix's gradient is summed
+    sender by sender by the grouped product and then over the senders.
     No row is ever dropped: round 0 runs always, and the rounds behind
     it in a loop of ``ceil(most rows any rank has for one owner / C) -
     1`` turns over one body, the count all-reduced so that every rank
@@ -925,36 +943,31 @@ def exchanged_experts_ffn(x, params: Dict[str, Any], spec: Experts, comm_ep,
             got, count = ragged_alltoall(
                 comm_ep, xs.reshape(size, cap, -1),
                 jnp.clip(sent - lo, 0, cap))
-            # A sender's rows lie sorted by expert.  Sorted by expert
-            # over all senders (then by sender, then as they came) they
-            # are the grouped products' groups; the buffer rows that hold
-            # nothing follow them.  All of it from the counts that went
-            # ahead: which of its rows a sender has in this round, expert
-            # by expert.
-            ends = jnp.cumsum(taken, axis=1)
-            start = jnp.clip(ends - taken, lo, lo + cap) - lo
-            mine = jnp.clip(ends, lo, lo + cap) - lo - start
-            sizes = jnp.sum(mine, axis=0)
-            of = jnp.sum(slot[:, None] >= (start + mine)[sender], axis=1)
-            has = of < held
-            of = jnp.minimum(of, held - 1)
-            ahead = (jnp.cumsum(sizes) - sizes)[of] \
-                + (jnp.cumsum(mine, axis=0) - mine)[sender, of] \
-                + slot - start[sender, of]
-            empty = cap - count
-            spread = jnp.where(
-                has, ahead, jnp.sum(sizes) + (jnp.cumsum(empty) - empty)[
-                    sender] + slot - count[sender])
-            gather = jnp.zeros_like(spread).at[spread].set(
-                jnp.arange(buffer, dtype=spread.dtype), unique_indices=True)
-            grouped = _permute_rows(got.reshape(buffer, -1), gather, spread)
-        inside = (jnp.arange(buffer) < jnp.sum(sizes))[:, None]
-        ys = jnp.where(inside, expert(
-            grouped, w1, w2, lambda a, w: jax.lax.ragged_dot(a, w, sizes)), 0)
+        # A sender's rows arrive sorted by expert, so the buffer as it
+        # lies is, sender by sender, one run a held expert and the tail
+        # that holds nothing: the grouped products' groups, ``size *
+        # (held + 1)`` of them that sum to ``buffer`` in every round.  All
+        # of it from the counts that went ahead: which of its rows a
+        # sender has in this round, expert by expert.  No mask: a tail's
+        # rows came as zeros, meet matrices of zeros, and the way back
+        # masks by ``count`` again, forward and adjoint.
+        ends = jnp.cumsum(taken, axis=1)
+        mine = jnp.clip(ends, lo, lo + cap) - jnp.clip(ends - taken, lo,
+                                                       lo + cap)
+        sizes = jnp.concatenate([mine, (cap - count)[:, None]],
+                                axis=1).reshape(-1)
+        # The matrices' copies by sender are made when the rows are here
+        # and not ahead of them: left free, the v5e's scheduler makes
+        # them early and a step of eight such layers holds 0.9 GB more
+        # (9.54 against 8.62 GB of temporaries, compiled for a described
+        # v5e 2x2 at SmallThinker's shapes; PERF.md section 6, PR 49).
+        got, w1, w2 = jax.lax.optimization_barrier((got, w1, w2))
+        ys = expert(got.reshape(buffer, -1), _by_sender(w1, size),
+                    _by_sender(w2, size),
+                    lambda a, w: jax.lax.ragged_dot(a, w, sizes))
         with layer_scope("moe_exchange"):
-            back, _ = ragged_alltoall(
-                comm_ep, _permute_rows(ys, spread, gather).reshape(
-                    size, cap, -1), count)
+            back, _ = ragged_alltoall(comm_ep, ys.reshape(size, cap, -1),
+                                      count)
         return _weighted_back(back.reshape(buffer, -1), weight, rows, at)
 
     y = _first_then_rest(

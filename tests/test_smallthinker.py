@@ -19,6 +19,7 @@ import dataclasses
 import functools
 import json
 import os
+import re
 import sys
 
 import jax
@@ -229,68 +230,130 @@ def test_a_mesh_of_another_size_is_refused():
         family.build_train_step(TCFG4, _mesh(2), 1, LR, dp=True)
 
 
-# ------------------------- (c) a collapsed router: the rounds behind the first
+# ------------- (c) the exchange under any routing: even, with gaps, collapsed
 
-def _layer(ranks: int, spec, params, x, comm_of=None):
+def _layer(ranks: int, spec, params, x, lower=False):
     """``(y, gradient, counts)`` of ``sum(sin(layer(x)))`` with the layer
     over ``ranks`` ranks: ``x`` (ranks, T, d), a rank's own rows; the
-    gradient of the replicated leaves summed over the ranks."""
+    gradient towards the parameters (that of the replicated leaves summed
+    over the ranks) and, under ``"x"``, towards the rows.  ``lower``: the
+    program lowered for a TPU, as text, in the place of its results."""
     mesh = _mesh(ranks)
     comm = mpi.comm_from_mesh(mesh, "mpi")
     specs = {k: P("mpi") if k in ("w1", "w2") else P() for k in params}
 
     def body(p, x):
-        def loss(p):
+        def loss(p, x):
             y, c = moe.exchanged_experts_ffn(x[0], p, spec, comm)
             return jnp.sum(jnp.sin(y)), (y, c)
 
-        (_, (y, c)), g = jax.value_and_grad(loss, has_aux=True)(p)
+        (_, (y, c)), (g, g_x) = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)(p, x)
         g = {k: v if specs[k] == P("mpi") else jax.lax.psum(v, "mpi")
              for k, v in g.items()}
-        return y[None], g, jax.tree.map(lambda a: jnp.asarray(a)[None], c)
+        return y[None], dict(g, x=g_x), \
+            jax.tree.map(lambda a: jnp.asarray(a)[None], c)
 
-    return jax.jit(jax.shard_map(
+    run = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(specs, P("mpi")),
-        out_specs=(P("mpi"), specs, P("mpi")), check_vma=False))(params, x)
+        out_specs=(P("mpi"), dict(specs, x=P("mpi")), P("mpi")),
+        check_vma=False))
+    if lower:
+        return run.trace(params, x).lower(
+            lowering_platforms=("tpu",)).as_text()
+    return run(params, x)
 
 
-@pytest.mark.parametrize("collapsed", [False, True], ids=["even", "collapsed"])
-def test_no_row_is_dropped_whatever_the_routing(collapsed, monkeypatch):
-    """A round's buffer of 1.25 even shares in tiles of 4 rows; with a
-    selection bias that sends every token to rank 0's two experts the
-    other ranks' buffers overflow threefold: the rounds behind the first
-    run, the counter says how many, and the result and every gradient
-    are still the one-rank layer's of all the rows."""
-    monkeypatch.setattr(moe, "_ROW_TILE", 4)
+def _exchange_case(routing: str):
+    """``(whole, share, params, x)`` at 4 ranks x 24 tokens of 16, 8
+    experts top-2, two a rank.  ``"even"``: the seeded router.
+    ``"collapsed"``: a selection bias sends every token to rank 0's two
+    experts.  ``"gaps"``: the router reads a token's first 8 entries as
+    its scores, rank 0's tokens never choose experts 0 and 1 (owner 0
+    gets no row from sender 0: a buffer that is all tail), rank 1's never
+    1 and 2 (an owner's LAST run of a sender's rows empty before the
+    tail, another's FIRST)."""
     d, tokens = 16, 24
     whole = moe.Experts(8, 2, 8, 0, 8, score="softmax", act="reglu")
     share = dataclasses.replace(whole, n_held=2)
     p = moe.init_experts(jax.random.PRNGKey(0), whole, d, jnp.float64)
-    if collapsed:
-        p["bias"] = p["bias"].at[:2].set(10.0)
     x = jax.random.normal(jax.random.PRNGKey(1), (RANKS, tokens, d),
                           jnp.float64)
+    if routing == "collapsed":
+        p["bias"] = p["bias"].at[:2].set(10.0)
+    if routing == "gaps":
+        p["router"] = jnp.eye(d, 8, dtype=jnp.float64)
+        x = x.at[0, :, 0:2].add(-10.0).at[1, :, 1:3].add(-10.0)
+    return whole, share, p, x
 
-    def plain(p):
+
+@pytest.mark.parametrize("routing", ["even", "gaps", "collapsed"])
+def test_no_row_is_dropped_whatever_the_routing(routing, monkeypatch):
+    """A round's buffer of 1.25 even shares in tiles of 4 rows.  The
+    owner's grouped products take the buffer as it arrived, a sender's
+    runs by expert and then its tail: under an even routing, under one
+    that leaves runs of no rows between them and a sender's whole buffer
+    tail, and under a selection bias that sends every token to rank 0's
+    two experts, so that the other ranks' buffers overflow threefold, the
+    rounds behind the first run (every sender's tail empty at rank 0,
+    whole at the others) and the counter says how many: the result and
+    every gradient (towards the rows, both matrices and the router) are
+    the one-rank layer's of all the rows."""
+    monkeypatch.setattr(moe, "_ROW_TILE", 4)
+    whole, share, p, x = _exchange_case(routing)
+    tokens = x.shape[1]
+
+    def plain(p, x):
         ys = [moe.held_experts_ffn(row, p, whole)[0] for row in x]
         return sum(jnp.sum(jnp.sin(y)) for y in ys), jnp.stack(ys)
 
-    (_, y_plain), g_plain = jax.value_and_grad(plain, has_aux=True)(p)
+    (_, y_plain), (g_plain, g_x) = jax.value_and_grad(
+        plain, argnums=(0, 1), has_aux=True)(p, x)
     y, g, counts = _layer(RANKS, share, p, x)
     _close(y, y_plain, 1e-12)
+    _close(g["x"], g_x, 1e-12)
     for leaf in ("router", "w1", "w2"):
         _close(g[leaf], g_plain[leaf], 1e-12)
     cap = moe._exchange_rows(tokens * 2, RANKS)
     assert cap == 16
     rounds, sent = np.asarray(counts["rounds"]), np.asarray(counts["sent"])
     assert (sent.sum(axis=1) == tokens * 2).all()
-    if collapsed:
+    if routing == "collapsed":
         assert (sent[:, 0] == tokens * 2).all() and rounds.tolist() == [2] * 4
         assert np.asarray(counts["rows"])[0].sum() == RANKS * tokens * 2
     else:
         assert rounds.tolist() == [int(sent.max() > cap)] * 4
+    if routing == "gaps":
+        chosen = np.asarray(jax.vmap(
+            lambda r: moe.route_experts(r, p, whole)[0])(x))
+        assert sent[0, 0] == 0 and not np.isin(chosen[1], (1, 2)).any()
+        assert np.isin(chosen[2:], (0, 1, 2)).any()
     assert np.asarray(counts["padding"]).tolist() \
         == [(rounds[0] + 1) * RANKS * cap - tokens * 2] * RANKS
+
+
+def test_the_owner_permutes_no_buffer_and_keeps_the_grouped_products(
+        monkeypatch):
+    """The layer with its gradient, lowered for a TPU: 14 grouped
+    products, as many as when the owner regrouped the rows by expert
+    first (round 0: two forward, four backward; the one body of the later
+    rounds: two forward, and two recomputed and four in its backward
+    turn), which the benchmark's count of a step's ``ragged-dot`` events
+    rests on; and no gather that reads the ``(R * C, d)`` buffer into
+    another of ``R * C`` rows, of which there were ten.  What reads the
+    buffer still is the sender's own: its returned rows summed at their
+    tokens, and the adjoint of laying them out."""
+    monkeypatch.setattr(moe, "_ROW_TILE", 4)
+    _, share, p, x = _exchange_case("even")
+    text = _layer(RANKS, share, p, x, lower=True)
+    assert text.count('"chlo.ragged_dot"(') == 14
+    buffer = RANKS * moe._exchange_rows(x.shape[1] * 2, RANKS)
+    rows = f"tensor<{buffer}x{x.shape[2]}xf64>"
+    gathers = re.findall(
+        r'"stablehlo.gather"\(.*: \((tensor<[^>]*>), tensor<[^>]*>\) -> '
+        r"(tensor<[^>]*>)", text)
+    assert (rows, rows) not in gathers
+    assert sum(src == rows for src, _ in gathers) == 5
 
 
 def test_held_experts_ffn_exchanges_over_a_communicator():

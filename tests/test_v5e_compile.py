@@ -1042,7 +1042,20 @@ def test_the_experts_exchange_compiles_without_a_sort_on_four_chips(v5e_2x2):
     of the counts, and NO sort: a sort of
     100,000 keys takes the v5e's compiler 20 s an instance, and the
     adjoint of an all-to-all gathered along the rows took it minutes
-    (PERF.md section 6, PR 48)."""
+    (PERF.md section 6, PR 48).  Since PR 49 the owner's grouped products
+    read the buffer where it arrived: 12 ``ragged-dot`` instructions as
+    before (round 0: two forward, four backward; the later rounds' turn
+    of the backward: two recomputed and four; their forward turns are
+    dead code under this loss, whose gradient does not read ``y``.  A
+    layer's two forward, two recomputed and four backward are the eight
+    that ``families/smallthinker.py:kernel_calls`` counts on), each over
+    the whole ``(R * C, d)`` buffer in ``R * (held + 1) = 68`` groups,
+    no gather of ``R * C`` rows into another ``R * C``, and temporaries
+    under a stated ceiling: 1.84 GB here, where the regrouped form took
+    0.59 (the matrices' copies by sender and the partials of their
+    gradient, 0.53 GB each for ``w1``, are the cell's own, while the rows
+    are an eighth of the cell's; the cell's whole step: 8.62 against
+    7.53 GB, PERF.md section 6, PR 49)."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     import mpi4torch_tpu as mpi
@@ -1071,12 +1084,24 @@ def test_the_experts_exchange_compiles_without_a_sort_on_four_chips(v5e_2x2):
         return grads, rounds[None]
 
     with jax.enable_x64(False):
-        text = jax.jit(jax.shard_map(
+        lowered = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(specs, P("mpi")),
             out_specs=((specs, P("mpi")), P("mpi")), check_vma=False)).lower(
-                params, x).compile().as_text()
+                params, x)
+        compiled = lowered.compile()
+    text = compiled.as_text()
     assert not re.search(r"= \S+ sort\(", text)
     wide = [m for m in re.finditer(r"= (\S+) all-to-all(?:-start)?\(", text)
             if "2560" in m.group(1)]
     assert 6 <= len(wide) <= 10 and not len(wide) % 2, len(wide)
-    assert "ragged-dot" in text
+    buffer = 4 * moe._exchange_rows(tokens * k, 4)
+    dots = re.findall(r"ragged-dot-none[\w.]* = .*", text)
+    assert len(dots) == 12, len(dots)
+    assert all(f"[{buffer}," in dot and "[68," in dot for dot in dots), dots
+    rows = f"tensor<{buffer}x{d}xbf16>"
+    assert not re.search(
+        rf'"stablehlo.gather"\(.*: \({rows}, tensor<[^>]*>\) -> {rows}',
+        lowered.as_text())
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"temporaries {temp / 1e9:.3f} GB")
+    assert temp < 2.2e9
