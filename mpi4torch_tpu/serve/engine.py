@@ -107,6 +107,16 @@ SPAN_FETCH = SPAN_STEP + ".decode.fetch"
 SPAN_FETCH_TOKENS = SPAN_FETCH + ".tokens"
 SPAN_FETCH_COUNTERS = SPAN_FETCH + ".counters"
 SPAN_SELECT = SPAN_STEP + ".decode.select"
+# Outside a step (profiling.setup_spans): the constructor's phases, and
+# the compilations program_texts() makes to read the programs' texts.
+SPAN_CONSTRUCT = "mpi4torch.serve.construct"
+SPAN_SHARD = SPAN_CONSTRUCT + ".shard"
+SPAN_SHARD_TOP = SPAN_SHARD + ".top"
+SPAN_SHARD_TAKE = SPAN_SHARD + ".take"
+SPAN_SHARD_LAYER = SPAN_SHARD + ".layer"
+SPAN_POOL = SPAN_CONSTRUCT + ".pool"
+SPAN_BUILD_INSTALL = SPAN_CONSTRUCT + ".install"
+SPAN_PROGRAM_TEXTS = "mpi4torch.serve.program_texts"
 
 
 def select_rows(logits, keys, temperature: float, top_k: int):
@@ -382,6 +392,16 @@ class Engine:
                  serve_cfg: ServeConfig = None, *, spmd: bool = False,
                  nranks: Optional[int] = None, mesh=None,
                  axis_name: Optional[str] = None, clock=None):
+        # First, so that the constructor's own phases are spans of this
+        # engine (closed outside a step: profiling.setup_spans) and a
+        # compilation made in one names it (profiling.compile_log).
+        self.stats = _prof._register_serve_stats(_prof.ServeStats())
+        with self.stats.span(SPAN_CONSTRUCT):
+            self._construct(cfg, params, serve_cfg, spmd, nranks, mesh,
+                            axis_name, clock)
+
+    def _construct(self, cfg, params, serve_cfg, spmd, nranks, mesh,
+                   axis_name, clock):
         self.cfg = cfg
         self.serve_cfg = serve_cfg or ServeConfig()
         # The deadline clock: monotonic seconds.  Injectable so the
@@ -449,8 +469,9 @@ class Engine:
             # compiled step slices one rank's shards instead of
             # re-deriving them from the replicated full parameters
             # every executed step.
-            self._shards = self._shard_by_layer(
-                params, lambda fn: run_spmd(fn, **kw))
+            with self.stats.span(SPAN_SHARD):
+                self._shards = self._shard_by_layer(
+                    params, lambda fn: run_spmd(fn, **kw))
             # The step takes its pool over (argument 1) and writes the
             # new rows into it, and takes the slot state over (argument
             # 2) and hands the next one back in its buffers: nothing is
@@ -465,7 +486,8 @@ class Engine:
         else:
             # Eager: the rank is concrete here (rank thread or the
             # size-1 world) — shard once.
-            self._shards = self._shard_by_layer(params, lambda fn: fn)
+            with self.stats.span(SPAN_SHARD):
+                self._shards = self._shard_by_layer(params, lambda fn: fn)
             self._step_call = None
             self._prefill_call = None
             self._chunk_call = None
@@ -530,12 +552,14 @@ class Engine:
             jax.tree.leaves(self._shards)[0].sharding if self._spmd \
             else None
         lead = (self._size,) if self._spmd else ()
-        self._cache = jax.tree.map(
-            lambda a: jnp.zeros(lead + a.shape, a.dtype, device=state),
-            cache)
+        with self.stats.span(SPAN_POOL):
+            self._cache = jax.tree.map(
+                lambda a: jnp.zeros(lead + a.shape, a.dtype, device=state),
+                cache)
         # Built here, first called in step(): the engine may be
         # constructed with jit disabled.
-        self._install_call = self._build_install()
+        with self.stats.span(SPAN_BUILD_INSTALL):
+            self._install_call = self._build_install()
         self._tokens = np.zeros((slots,), np.int32)
         self._pos = np.zeros((slots,), np.int32)
         # The slot state on the device, beside the cache and riding as
@@ -563,7 +587,6 @@ class Engine:
         self._known_rids = set()
         self._next_rid = 0
         self.slot_log: List[tuple] = []   # (rid, slot) admission history
-        self.stats = _prof._register_serve_stats(_prof.ServeStats())
         # Optional self-tuning controller (mpi4torch_tpu.ctl): consulted
         # between steps, never during one — see attach_controller.
         self._controller = None
@@ -575,25 +598,37 @@ class Engine:
         ``compiled(fn)(leaves)`` — ``run_spmd`` for an SPMD engine (the
         leaves are ARGUMENTS of the sharding program and come back
         stacked per rank), the function itself for an eager one — and
-        let go before the next is taken."""
-        cfg = self.cfg
-        top = compiled(lambda t: t)
+        let go before the next is taken.  Spans (the caller's ``.shard``
+        lies over all of it): ``.shard.take`` around each ``next()`` of
+        the iterable (the CALLER's time, where it makes a layer as it is
+        asked; the last finds it done), ``.shard.layer`` around each
+        layer's sharding and ``.shard.top`` around the top's (the
+        program's), ``rid`` the layer's index."""
+        cfg, span = self.cfg, self.stats.span
         by_spec = {}
-        shards = top({k: v for k, v in params.items() if k != "blocks"})
+        with span(SPAN_SHARD_TOP):
+            shards = compiled(lambda t: t)(
+                {k: v for k, v in params.items() if k != "blocks"})
         shards["blocks"] = []
         specs = cfg.layer_specs
-        for blk in params["blocks"]:
+        blocks, done = iter(params["blocks"]), object()
+        while True:
             n = len(shards["blocks"])
+            with span(SPAN_SHARD_TAKE, n):
+                blk = next(blocks, done)
+            if blk is done:
+                break
             if n == len(specs):
                 raise ValueError(
                     f"params['blocks'] yields more than n_layers={n} "
                     "layers")
             spec = specs[n]
-            if spec not in by_spec:
-                by_spec[spec] = compiled(
-                    lambda b, spec=spec: _kv.shard_block_tp(
-                        cfg, spec, b, self._comm))
-            shards["blocks"].append(by_spec[spec](blk))
+            with span(SPAN_SHARD_LAYER, n):
+                if spec not in by_spec:
+                    by_spec[spec] = compiled(
+                        lambda b, spec=spec: _kv.shard_block_tp(
+                            cfg, spec, b, self._comm))
+                shards["blocks"].append(by_spec[spec](blk))
             del blk        # before the iterable makes the next one
         if len(shards["blocks"]) != len(specs):
             raise ValueError(
@@ -1717,12 +1752,15 @@ class Engine:
                 "construct the engine with spmd=True")
         text = lambda call, *args: call.lower_as_called(
             *args).compile().as_text()
-        out = {"decode": text(self._step_call, self._shards, self._cache,
-                              self._step_inputs())}
-        for n in sorted(self._prefilled):
-            out[f"prefill.{n}"] = text(
-                self._prefill_call, self._shards,
-                jax.ShapeDtypeStruct((1, n), jnp.int32))
+        # What is lowered and compiled here is on the compile log under
+        # this span: a reader of set-up leaves it out.
+        with self.stats.span(SPAN_PROGRAM_TEXTS):
+            out = {"decode": text(self._step_call, self._shards,
+                                  self._cache, self._step_inputs())}
+            for n in sorted(self._prefilled):
+                out[f"prefill.{n}"] = text(
+                    self._prefill_call, self._shards,
+                    jax.ShapeDtypeStruct((1, n), jnp.int32))
         return out
 
     def lower_step(self):

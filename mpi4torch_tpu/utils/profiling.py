@@ -30,12 +30,15 @@ import time
 from collections import deque
 
 from jax import monitoring as _monitoring
+from jax.core import unsafe_am_i_under_a_jit_DO_NOT_USE as _under_a_trace
 from jax.profiler import TraceAnnotation
 
 __all__ = ["profiler_trace", "bucket_scope", "serve_step_scope",
            "layer_scope", "LAYER_SCOPES", "ServeStats", "serve_stats",
            "reset_serve_stats",
-           "serve_step_log", "STEP_SPAN", "STEP_LOG_CAP"]
+           "serve_step_log", "STEP_SPAN", "STEP_LOG_CAP",
+           "compile_log", "compile_totals", "COMPILE_LOG_CAP",
+           "setup_spans", "SETUP_SPAN_CAP"]
 
 # The span that is one ``Engine.step()`` call; every other serving span
 # (``mpi4torch.serve.step.<phase>``, doc/serving.md) lies inside it.
@@ -70,12 +73,48 @@ _STEP_COUNTS = (("admitted", "admitted"),
                 ("decode_uploads", "decode_uploads"),
                 ("step_compiles", "step_compiles"),
                 ("occupancy_ticks", "active"))
-# JAX's own event when a backend compilation ends (a persistent-cache
-# hit included): the one ``benchmarks`` counts as ``compiles_in_window``.
+# JAX's own events when a program has been traced, lowered, and compiled
+# by the backend (a persistent-cache hit included: the last is the one
+# ``benchmarks`` counts as ``compiles_in_window``): event -> the ``kind``
+# of its record on the compile log.
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-# ``.stats``: the ServeStats whose step is open on this thread (one
-# engine's spans are written by the one thread that steps it, and a
-# compilation runs on the thread that called the program).
+_COMPILE_KINDS = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+                  "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+                  _COMPILE_EVENT: "compile"}
+# What the persistent cache says of a compilation, on the compiling
+# thread and inside the backend-compile interval: one of the two plain
+# events, and behind a hit the seconds the read took.
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+_CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+# The compile log: one record per such event of the process, newest
+# last, whoever compiled (an engine's step or constructor, a training
+# program, a bare ``jax.jit``); but a function traced INSIDE another's
+# trace (every ``jnp`` function a model calls is one: thousands a
+# program) is part of that program's trace, whose record covers it, and
+# is counted, not recorded.  Bounded like the step log; the totals per
+# (kind, cache) beside it are never capped.  A few hundred appends a
+# process, none in the steady state: a program that has compiled sends
+# no event.  The cap holds the most a cell's process has made with room
+# to spare: 8,488 in ``internlm2-1.8b.serve_chat``, whose constructor
+# runs with jit off and sends 2,793 small programs through trace,
+# lowering and the cache one by one (PERF.md, PR 50); the other cells'
+# make a few hundred.
+COMPILE_LOG_CAP = 16384
+_INNER_TRACES = ("trace.inner", None)     # their key in the totals
+_COMPILE_LOG: deque = deque(maxlen=COMPILE_LOG_CAP)
+_COMPILE_TOTALS: dict = {}                # (kind, cache) -> [ns, count]
+# Spans closed while no step was open (an engine's constructor,
+# ``program_texts``): ``(name, t0_ns, t1_ns, rid, engine)``, newest last.
+SETUP_SPAN_CAP = 4096
+_SETUP_SPANS: deque = deque(maxlen=SETUP_SPAN_CAP)
+_LOG_LOCK = threading.Lock()
+# Per thread: ``.stats``, the ServeStats whose step is open on this
+# thread; ``.stack``, the spans open on it, innermost last, with or
+# without a step (one engine's spans are written by the one thread that
+# steps it, and a compilation runs on the thread that called the
+# program); ``.cache``, what the persistent cache has said of the
+# compilation now running on it.
 _STEPPING = threading.local()
 
 
@@ -203,9 +242,10 @@ class ServeStats:
     :meth:`snapshot` derives time-to-first-token and end-to-end
     latencies.  Step spans (:meth:`span`): the phases of every
     ``Engine.step()`` call on ``time.perf_counter_ns()``, kept per step
-    on the process-wide step log (:func:`serve_step_log`) and summed
-    per phase under ``snapshot()["phase_s"]``; one engine's spans are
-    written by the one thread that steps it.
+    on the process-wide step log (:func:`serve_step_log`), those closed
+    outside a step (the constructor's) on :func:`setup_spans`, and all
+    summed per phase under ``snapshot()["phase_s"]``; one engine's spans
+    are written by the one thread that steps it.
     Thread-safe (Mode B runs one engine per rank thread);
     engines register here so :func:`serve_stats` aggregates
     process-wide.  ``evicted`` counts slots freed — a request finishing
@@ -308,7 +348,6 @@ class ServeStats:
         self._open = None                 # spans of the step that is open
         self._open_attached = {}          # what attach() put on it
         self._open_counts = None
-        self._stack = []                  # the open spans, innermost last
 
     def reset(self) -> None:
         """Zero the counters and drop the spans and phase totals (in
@@ -321,30 +360,38 @@ class ServeStats:
             self.phases.clear()
 
     def span(self, name: str, rid=None) -> "_Span":
-        """Context manager around one phase of ``Engine.step()``: a
+        """Context manager around one phase of the engine's work: a
         ``jax.profiler.TraceAnnotation(name)`` (so that in any xplane
         capture the span sits on the trace's own clock beside the
         device lines; not made at all while no profiler session records) and one
         ``(name, t0_ns, t1_ns, rid)`` from ``time.perf_counter_ns()``
         — the clock :meth:`mark` reads — appended to the record of the
-        step that is open.  :data:`STEP_SPAN` opens that record and,
-        closing, puts it on the process-wide step log
+        step that is open or, where none is (the constructor's phases,
+        ``program_texts``), with the engine's serial to the process-wide
+        set-up list (:func:`setup_spans`); either way it counts into
+        ``snapshot()["phase_s"]``.  :data:`STEP_SPAN` opens a step's
+        record and, closing, puts it on the process-wide step log
         (:func:`serve_step_log`).  Nesting gives the parent: a child
-        lies inside its parent's interval.  Always on, like the
+        lies inside its parent's interval.  While it is open the span is
+        the innermost one of its thread: what a record of
+        :func:`compile_log` made then names.  Always on, like the
         counters; ``rid`` may be set on the returned object before the
         block ends."""
         return _Span(self, name, rid)
 
     def attach(self, key: str, value) -> None:
         """Put ``value`` on the record of the step that is open, under
-        ``key``: a list, in the order attached (nothing where no step is
-        open).  For what a step's compiled programs count themselves:
+        ``key``: a list, in the order attached.  Outside a step nothing
+        is attached: the set-up list holds spans alone, and a
+        compilation made there is on :func:`compile_log` with its span.
+        For what a step's compiled programs count themselves:
         ``moe_rows``, one ``(program, rows)`` per call of a program with
         an expert layer (``serve.Engine._note_counters``); and
         ``compiles``, one ``(span, rid, seconds)`` per backend
         compilation that ended while the step was open, with the
-        innermost span open then.  A record has the key only where
-        something was attached."""
+        innermost span open then: the step's view of that record of the
+        compile log.  A record has the key only where something was
+        attached."""
         if self._open is not None:
             self._open_attached.setdefault(key, []).append(value)
 
@@ -355,16 +402,19 @@ class ServeStats:
         with self._lock:
             self._open_counts = [self.counters[c] for c, _ in _STEP_COUNTS]
 
-    def _compiled(self, seconds: float) -> None:
-        """A backend compilation ended on the thread of the open step
-        (whose own span is on the stack for as long as it is open)."""
-        inner = self._stack[-1]
-        self.attach("compiles", (inner._name, inner.rid, float(seconds)))
+    def _compiled(self, record: dict, seconds: float) -> None:
+        """A backend compilation ended on the thread of the open step:
+        the step's view of its record on the compile log."""
+        self.attach("compiles",
+                    (record["span"], record["rid"], float(seconds)))
         self.count("step_compiles")
 
     def _span_closed(self, name: str, t0: int, t1: int, rid) -> None:
         spans = self._open
-        if spans is None:       # no step is open: the annotation only
+        if spans is None:       # no step is open: the set-up list
+            with self._lock:
+                self._sum_phases(((name, t0, t1, rid),))
+            _SETUP_SPANS.append((name, t0, t1, rid, self.engine))
             return
         spans.append((name, t0, t1, rid))
         if name != STEP_SPAN:
@@ -376,11 +426,14 @@ class ServeStats:
         with self._lock:
             for (c, key), base in zip(_STEP_COUNTS, self._open_counts):
                 record[key] = self.counters[c] - base
-            for n, a, b, _ in spans:
-                tot = self.phases.setdefault(n, [0, 0])
-                tot[0] += b - a
-                tot[1] += 1
+            self._sum_phases(spans)
         _STEP_LOG.append(record)
+
+    def _sum_phases(self, spans) -> None:
+        for n, a, b, _ in spans:
+            tot = self.phases.setdefault(n, [0, 0])
+            tot[0] += b - a
+            tot[1] += 1
 
     def count(self, name: str, n: int = 1) -> None:
         with self._lock:
@@ -455,7 +508,7 @@ class _Span:
     """One :meth:`ServeStats.span`.  A class, not a generator-based
     context manager: the span runs a dozen times an engine step."""
 
-    __slots__ = ("rid", "_stats", "_name", "_ann", "_t0")
+    __slots__ = ("rid", "_stats", "_name", "_ann", "_t0", "_stack")
 
     def __init__(self, stats: ServeStats, name: str, rid):
         self.rid = rid
@@ -470,7 +523,12 @@ class _Span:
     def __enter__(self):
         if self._name == STEP_SPAN:
             self._stats._open_step()
-        self._stats._stack.append(self)
+        try:
+            stack = _STEPPING.stack
+        except AttributeError:
+            stack = _STEPPING.stack = []
+        self._stack = stack
+        stack.append(self)
         if self._ann is not None:
             self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
@@ -480,20 +538,105 @@ class _Span:
         t1 = time.perf_counter_ns()
         if self._ann is not None:
             self._ann.__exit__(*exc)
-        self._stats._stack.pop()
+        self._stack.pop()
         self._stats._span_closed(self._name, self._t0, t1, self.rid)
         return False
 
 
-def _on_compile(event: str, seconds: float, **_) -> None:
-    if event == _COMPILE_EVENT:
+def _on_compile(event: str, seconds: float, fun_name=None, **_) -> None:
+    """A program was traced, lowered or compiled on this thread: one
+    record on the compile log, named by the innermost span open here;
+    a backend compilation also goes to the step that is open here."""
+    kind = _COMPILE_KINDS.get(event)
+    if kind is None:
+        if event == _CACHE_RETRIEVAL_EVENT:
+            _STEPPING.cache = ("hit", float(seconds))
+        return
+    t1 = time.perf_counter_ns()
+    ns = int(round(seconds * 1e9))
+    if kind == "trace" and _under_a_trace():
+        _tally(_INNER_TRACES, ns)
+        return
+    stack = getattr(_STEPPING, "stack", None)
+    inner = stack[-1] if stack else None
+    record = {"kind": kind, "program": fun_name, "t0_ns": t1 - ns,
+              "t1_ns": t1,
+              "span": None if inner is None else inner._name,
+              "rid": None if inner is None else inner.rid,
+              "thread": threading.get_ident()}
+    cache = None
+    if kind == "compile":       # the cache's answer is for this one
+        cache, read_s = getattr(_STEPPING, "cache", ("off", None))
+        _STEPPING.cache = ("off", None)
+        record.update(cache=cache, retrieval_s=read_s)
+    _tally((kind, cache), ns)
+    _COMPILE_LOG.append(record)
+    if kind == "compile":
         stats = getattr(_STEPPING, "stats", None)
         if stats is not None:
-            stats._compiled(seconds)
+            stats._compiled(record, seconds)
 
 
-# Once a process; it does nothing until a compilation ends.
+def _tally(key: tuple, ns: int) -> None:
+    with _LOG_LOCK:
+        tot = _COMPILE_TOTALS.setdefault(key, [0, 0])
+        tot[0] += ns
+        tot[1] += 1
+
+
+def _on_cache_event(event: str, **_) -> None:
+    outcome = _CACHE_EVENTS.get(event)
+    if outcome is not None:
+        _STEPPING.cache = (outcome, None)
+
+
+# Once a process; they do nothing until a program is traced, lowered or
+# compiled, and a program that has compiled sends no event again.
 _monitoring.register_event_duration_secs_listener(_on_compile)
+_monitoring.register_event_listener(_on_cache_event)
+
+
+def compile_log() -> list:
+    """A copy of the process-wide compile log, oldest first: one record
+    per program's trace, lowering and backend compilation JAX made in
+    this process, ``{"kind": "trace" | "lower" | "compile", "program":`` JAX's
+    ``fun_name`` (``jit(step)``; a trace's lacks the ``jit()``)``,
+    "t0_ns", "t1_ns"`` on ``time.perf_counter_ns()``, the step log's
+    clock (``t1`` when the event arrived, ``t0`` the event's seconds
+    before it)``, "span", "rid":`` the innermost :meth:`ServeStats.span`
+    open on the compiling thread then, or None``, "thread"}``, and on a
+    ``compile`` record ``"cache": "hit" | "miss" | "off"`` (what the
+    persistent compilation cache said inside the interval; ``off``:
+    nothing, the program did not go by the cache) and ``"retrieval_s"``,
+    the seconds a hit took to read.  The last :data:`COMPILE_LOG_CAP`
+    records; :func:`compile_totals` counts all of them.  A function
+    traced inside another's trace leaves no record of its own (the
+    outer one's interval holds its seconds); a program traced while
+    another is LOWERED does, inside that interval: unite the intervals,
+    do not sum them.  A step record's ``compiles`` is the view of the
+    ``compile`` records that ended while that step was open."""
+    return list(_COMPILE_LOG)
+
+
+def compile_totals() -> dict:
+    """``{(kind, cache): {"seconds", "count"}}`` over every record the
+    compile log has held since the last reset, dropped ones included
+    (``cache`` is None off a ``compile`` record), and under
+    ``("trace.inner", None)`` the functions traced inside another's
+    trace, which the log does not hold: their seconds are part of the
+    outer traces' too."""
+    with _LOG_LOCK:
+        return {k: {"seconds": ns / 1e9, "count": c}
+                for k, (ns, c) in _COMPILE_TOTALS.items()}
+
+
+def setup_spans() -> list:
+    """A copy of the process-wide list of spans that closed while no
+    step was open, oldest first: ``(name, t0_ns, t1_ns, rid, engine)``
+    on the step log's clock, ``engine`` the ``ServeStats.engine`` serial
+    as on a step record; the last :data:`SETUP_SPAN_CAP` of them (an
+    engine's ``snapshot()["phase_s"]`` counts all of its own)."""
+    return list(_SETUP_SPANS)
 
 
 def serve_step_log() -> list:
@@ -567,7 +710,8 @@ def serve_stats() -> dict:
 
 def reset_serve_stats() -> None:
     """Zero every live engine's counters/spans IN PLACE, empty the
-    registry and the step log (test/bench isolation).  Engines
+    registry, the step log, the set-up spans and the compile log with
+    its totals (test/bench isolation).  Engines
     constructed before the reset keep counting on their own (now
     zeroed) ``stats`` object but drop out of the process aggregate — a
     reset mid-flight is a bookkeeping cut, not an engine restart."""
@@ -576,6 +720,10 @@ def reset_serve_stats() -> None:
     for e in sources().clear(_SERVE_GROUP):
         e.reset()
     _STEP_LOG.clear()
+    _SETUP_SPANS.clear()
+    _COMPILE_LOG.clear()
+    with _LOG_LOCK:
+        _COMPILE_TOTALS.clear()
 
 
 # Serving counters in the unified metrics namespace: a snapshot-time
