@@ -44,7 +44,7 @@ from ..ops.ssd import CHUNK as _SSD_CHUNK, ssd_chunked, ssd_step
 from ..ops.paged_attention import index_scores
 from ..parallel.attention import ring_attention, \
     ulysses_attention, zigzag_ring_attention
-from ..parallel.dp import all_average_tree
+from ..parallel.dp import all_average_tree, replicated_tree
 from ..parallel.moe import Experts, experts_ffn, \
     held_experts_ffn, init_experts, route_experts, \
     init_moe, moe_ffn, moe_ffn_dense
@@ -69,7 +69,9 @@ _KEPT_PRODUCTS = ("qkv", "attn_residual", "ffn_in")
 # (memory_analysis(), PR 46): 1.75 GB of temporaries in a step that
 # keeps nothing (the logits and their gradient, one block's recomputed
 # forward) and a data-parallel step's averaged copy of the parameters,
-# 2.27 GB: 4.0 GB of the chip's 16.9, 24%.
+# 2.27 GB: 4.0 GB of the chip's 16.9, 24%.  (Since PR 51 no step of this
+# file makes that copy, :func:`train_step`; whether the rule should then
+# keep more is ROADMAP C4's to measure.)
 _REMAT_ROOM = 0.75
 
 
@@ -1964,17 +1966,19 @@ _scale_cotangent.defvjp(lambda x, scale: (x, None),
 
 
 def ep_average_tree(cfg: TransformerConfig, comm_ep, params):
-    """:func:`~mpi4torch_tpu.parallel.dp.all_average_tree` over the ep
-    axis for the leaves every rank holds alike; where the configuration
+    """How :func:`train_step`'s parameters enter a loss over the ep
+    axis: the leaves every rank holds alike through
+    :func:`~mpi4torch_tpu.parallel.dp.replicated_tree` (as they are;
+    their cotangents averaged over the axis); where the configuration
     has a per-layer spec, a rank's own experts
-    (:func:`held_expert_leaf`) pass as they are, their cotangent times
-    ``1 / ep``: the adjoint exchange has summed it over the axis, the
-    average's adjoint would have divided it."""
+    (:func:`held_expert_leaf`) pass as they are too, their cotangent
+    times ``1 / ep``: the adjoint exchange has summed it over the axis,
+    the average divides the others'."""
     if not any(s.ffn is not None for s in cfg.layers):
-        return all_average_tree(comm_ep, params)
+        return replicated_tree(comm_ep, params)
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
     own = [held_expert_leaf(path) for path, _ in flat]
-    alike = iter(all_average_tree(
+    alike = iter(replicated_tree(
         comm_ep, [leaf for (_, leaf), o in zip(flat, own) if not o]))
     return jax.tree.unflatten(treedef, [
         _scale_cotangent(leaf, 1.0 / comm_ep.size) if o else next(alike)
@@ -1993,39 +1997,54 @@ def train_step(cfg: TransformerConfig, params, tokens, comm_sp=None,
     an expert layer), and over an expert-parallel communicator the
     exchange's (:func:`_forward`).
 
-    DP follows the reference recipe exactly (parameter-averaging Allreduce
-    + loss Allreduce over the dp axis) so replicas stay in lock-step.  The
-    parameters are averaged over the sp axis as well: the sp-summed loss
-    (``Allreduce_sp`` in :func:`lm_loss`, with no ``1/sp``) scales each
-    rank's cotangents by ``sp``, and only the ``1/sp`` in the sp
-    param-averaging adjoint cancels it — the same load-bearing trick as the
-    reference's DP example (doc/examples.rst:46-65), applied per axis.
+    The replicated parameters enter the loss through
+    :func:`~mpi4torch_tpu.parallel.dp.replicated_tree` on every axis
+    (dp, sp, ep): as they are, nothing sent, and with the adjoint of the
+    reference's parameter-averaging Allreduce (doc/examples.rst:46-65),
+    so each rank's gradient still comes out as the mean over the axis,
+    the same bits on every rank, and the loss is Allreduce-averaged over
+    dp as in the recipe.  The recipe's forward average is the identity
+    here, at an all-reduce of every parameter a step and a second copy
+    of them: the replicas ARE equal on the way in.  ``run_spmd`` hands
+    every rank the same arrays, and every step ends in ``p - lr * g`` on
+    a ``g`` that one all-reduce gave every rank in the same bits, so a
+    step that starts on equal replicas leaves equal replicas.  That is this
+    function's contract with its caller; one whose replicas may differ
+    averages them first
+    (:func:`~mpi4torch_tpu.parallel.dp.all_average_tree`, which is the
+    reference's recipe and makes them equal every forward pass).
+
+    On the sp axis the adjoint's ``1 / sp`` is load-bearing: the
+    sp-summed loss (``Allreduce_sp`` in :func:`lm_loss`, with no
+    ``1/sp``) scales each rank's cotangents by ``sp``, and only the mean
+    in the parameters' adjoint cancels it, the same trick as the
+    reference's DP example, applied per axis.
     Jittable end-to-end — on a 2D mesh the whole step is one XLA program
     mixing psum (dp/sp), the ppermute ring and masked collectives.
 
     The ep axis is treated as a *data* axis with the same recipe (ep ranks
-    hold different token shards): parameters are averaged over ep and the
-    loss is ep-averaged too.  This keeps every replicated leaf — gate,
-    embeddings, attention, and the (logically replicated) expert tensors
-    that :func:`~mpi4torch_tpu.parallel.moe.moe_ffn` slices per rank — in
-    lock-step, and makes gradients match the dense single-rank oracle
-    (tests/test_transformer.py): adjoint-Allreduce sums each rank's
-    cotangents, and an expert block's whole-mesh gradient already
-    accumulates on its owner rank via the adjoint Alltoall.
+    hold different token shards): the parameters' cotangents are averaged
+    over ep and the loss is ep-averaged too.  This keeps every replicated
+    leaf — gate, embeddings, attention, and the (logically replicated)
+    expert tensors that :func:`~mpi4torch_tpu.parallel.moe.moe_ffn` slices
+    per rank — in lock-step, and makes gradients match the dense
+    single-rank oracle (tests/test_transformer.py): adjoint-Allreduce sums
+    each rank's cotangents, and an expert block's whole-mesh gradient
+    already accumulates on its owner rank via the adjoint Alltoall.
 
     The expert leaves of a per-layer spec (``blocks[i]["experts"]["w1"]``,
     ``["w2"]``) are NOT replicated over ep: each rank passes its own
     ``n_experts / ep`` experts (:func:`~mpi4torch_tpu.parallel.moe.
-    exchanged_experts_ffn`), so they are left out of the ep average
+    exchanged_experts_ffn`), so they are left out of the ep mean
     (:func:`ep_average_tree`) and their gradient, which the adjoint
     exchange has already summed over every rank's tokens, is divided by
-    ``ep`` as the average divides the others'."""
+    ``ep`` as the mean divides the others'."""
 
     def global_loss(p):
         if comm_dp is not None and comm_dp.size > 1:
-            p = all_average_tree(comm_dp, p)
+            p = replicated_tree(comm_dp, p)
         if comm_sp is not None and comm_sp.size > 1:
-            p = all_average_tree(comm_sp, p)
+            p = replicated_tree(comm_sp, p)
         if comm_ep is not None and comm_ep.size > 1:
             p = ep_average_tree(cfg, comm_ep, p)
         loss, stats = _lm_loss(cfg, p, tokens, comm_sp, attn, None, comm_ep,

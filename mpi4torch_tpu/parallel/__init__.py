@@ -21,7 +21,7 @@ ICI/DCN).
 
 from . import attention, dp, moe, pp, ring, tp, zero
 
-from .dp import all_average_tree, dp_value_and_grad
+from .dp import all_average_tree, dp_value_and_grad, replicated_tree
 from .ring import halo_exchange, ring_shift
 from .attention import (dense_attention, ring_attention,
                         ulysses_attention, zigzag_positions, zigzag_slice,
@@ -59,6 +59,7 @@ __all__ = [
     "ring",
     "tp",
     "all_average_tree",
+    "replicated_tree",
     "dp_value_and_grad",
     "halo_exchange",
     "ring_shift",

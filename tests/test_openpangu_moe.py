@@ -352,11 +352,19 @@ def test_a_layer_spec_is_served_on_one_rank_only():
 # argument and hands ``_forward``'s counters out by name, and every one of
 # the eight lowers to its parent's text, letter for letter (no old cell
 # was run on the chip for that PR: four-chip machines were scarce).
+# PR 51 replaced Mistral's data-parallel step, the program it meant to
+# change: the parameters enter the loss through ``replicated_tree``, so the
+# forward ``all_reduce`` of the parameters' bucket and the mean's ``divide``
+# behind it left the text (4 ``stablehlo.all_reduce`` -> 3: the adjoint's
+# and the loss's stay), and the adjoint builds its bucket with one
+# ``concatenate`` of the cotangents where the transposed slices were 15
+# ``pad``s and their sums.  The other seven are the parent's, letter for
+# letter: their steps have no communicator larger than one.
 PARENT_TEXTS = {
     "kimi": "c8f6723c62b880c17377b0e0b8a36e11f36a27c25731b3c74602c7af50198f9e",
     "internlm2": "ddcbb9f523103a01172891ee29d82bf8839323abf424f1e05587b9142ec5ad65",
     "openpangu": "cd48ae9b73a5afa03dceab1ab8e7f1069b6759f10c550555e566776f4e353fc7",
-    "mistral_dp": "ef96cb53e8cd1abb240ece3fdeae805381c8e8061c82e4d8e10e48280cebf744",
+    "mistral_dp": "b8c45b1194fc1d905be33eedd5f7db620f98d49f8dfda222c1ddc3254fd9f808",
     "trinity": "31999685375c08a8e7afe257fd62224894c879429401a8ca6c89622960c5743d",
     "longcat": "93a18b45bdaff5a8c9d0bddab3b1e5c4246db276631b09416f65975bd1ec4034",
     "glm": "e3db8fb97a6e095b0c6495dc0e593c12c44975b8a687287abeec1fa33451768e",

@@ -737,30 +737,53 @@ def test_trinitys_prefills_and_install_compile_at_their_real_size(
               " GB")
 
 
-def test_dp_step_compiles_to_all_reduces_alone(v5e_2x2):
-    """`train_dp4`'s program at a small size on the four chips, as the
-    benchmark builds it: matrices of 8 MiB that travel alone beside
-    norm scales that share a bucket.  The chip's compiler makes an
-    all-reduce and a slice of a flat reduce-scatter, so the pair that a
-    bucket was until PR 35 ran an all-reduce AND an all-gather a
-    direction; a bucket is one all-reduce now and nothing is gathered.
-    The kernels are not this test's (the dispatch stays the CPU's)."""
+_DP_SMALL = {"vocab_size": 2048, "hidden_size": 2048,
+             "intermediate_size": 2048, "num_hidden_layers": 2,
+             "num_attention_heads": 16, "num_key_value_heads": 4,
+             "max_position_embeddings": 128, "sliding_window": 128,
+             "rms_norm_eps": 1e-5, "rope_theta": 1e4}
+
+
+def _dp_small(v5e_2x2):
+    """``(tcfg, mesh, params, tokens)`` of `train_dp4`'s program at a
+    small size on the four chips: matrices of 8 MiB that travel alone
+    beside norm scales that share a bucket."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from benchmarks import program, weights
 
-    cfg = {"vocab_size": 2048, "hidden_size": 2048, "intermediate_size": 2048,
-           "num_hidden_layers": 2, "num_attention_heads": 16,
-           "num_key_value_heads": 4, "max_position_embeddings": 128,
-           "sliding_window": 128, "rms_norm_eps": 1e-5, "rope_theta": 1e4}
     mesh = Mesh(np.asarray(v5e_2x2), ("mpi",))
     rep = NamedSharding(mesh, P())
     like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep)
     params = jax.tree.map(like, jax.eval_shape(
-        lambda: weights.make_params(cfg, 0, jnp.bfloat16)))
+        lambda: weights.make_params(_DP_SMALL, 0, jnp.bfloat16)))
     tokens = jax.ShapeDtypeStruct((4 * 2, 128), jnp.int32, sharding=rep)
-    step = program.build_train_step(
-        program.transformer_config(cfg, remat=True), mesh, 2, 0.3, True)
+    return program.transformer_config(_DP_SMALL, remat=True), mesh, params, \
+        tokens
+
+
+def _all_reduced_bytes(text: str) -> int:
+    """Bytes of every all-reduce's results in a compiled text."""
+    width = {"bf16": 2, "f32": 4}
+    total = 0
+    for shapes in re.findall(r"= ([^=\n]*)\sall-reduce\(", text):
+        for dtype, dims in re.findall(r"\b(bf16|f32)\[([\d,]*)\]", shapes):
+            total += width[dtype] * int(np.prod(
+                [int(d) for d in dims.split(",") if d] or [1]))
+    return total
+
+
+def test_dp_step_compiles_to_all_reduces_alone(v5e_2x2):
+    """`train_dp4`'s program at a small size on the four chips, as the
+    benchmark builds it.  The chip's compiler makes an
+    all-reduce and a slice of a flat reduce-scatter, so the pair that a
+    bucket was until PR 35 ran an all-reduce AND an all-gather a
+    direction; a bucket is one all-reduce now and nothing is gathered.
+    The kernels are not this test's (the dispatch stays the CPU's)."""
+    from benchmarks import program
+
+    tcfg, mesh, params, tokens = _dp_small(v5e_2x2)
+    step = program.build_train_step(tcfg, mesh, 2, 0.3, True)
     with jax.enable_x64(False):
         lowered = step.lower(params, tokens)
         text = lowered.compile().as_text()
@@ -771,9 +794,68 @@ def test_dp_step_compiles_to_all_reduces_alone(v5e_2x2):
     low = lowered.as_text()
     assert "stablehlo.all_gather" not in low
     assert "stablehlo.reduce_scatter" not in low
-    # the matrices' buckets, forward and adjoint, in their own shapes
-    assert low.count("tensor<2048x2048xbf16>) -> tensor<2048x2048xbf16>") \
-        >= 2 * 2 * 2
+    # the matrices' buckets in their own shapes, in the adjoint alone
+    # since PR 51: two layers' ``wo`` and ``w2``, the embedding, the head
+    assert len(re.findall(
+        r"\}\) : \(tensor<2048x2048xbf16>\) -> tensor<2048x2048xbf16>",
+        low)) == 2 * 2 + 2
+
+
+def test_dp_step_all_reduces_the_parameters_bytes_once(v5e_2x2):
+    """How often the mechanism of PR 51 engages, counted where the chip
+    will run it: the step's all-reduces move the parameters' bytes once
+    (the adjoint of ``replicated_tree``, and the loss), where the
+    reference's recipe written out with ``all_average_tree`` moves them
+    twice and keeps the averaged copy beside the parameters: the
+    temporaries fall by the parameters' bytes.  On the chip this is
+    `dp_collective_ms`."""
+    import mpi4torch_tpu as mpi
+    from benchmarks import program
+    from mpi4torch_tpu.constants import MPI_SUM
+    from mpi4torch_tpu.parallel.dp import all_average_tree
+
+    tcfg, mesh, params, tokens = _dp_small(v5e_2x2)
+    p_bytes = sum(p.size * p.dtype.itemsize for p in jax.tree.leaves(params))
+
+    def recipe_body(params, tokens):
+        comm = mpi.COMM_WORLD
+        local = jax.lax.dynamic_slice_in_dim(
+            tokens, jnp.asarray(comm.rank) * 2, 2, 0)
+
+        def global_loss(p):
+            loss = T.lm_loss(tcfg, all_average_tree(comm, p), local)
+            return comm.Allreduce(loss, MPI_SUM, compression=False) / comm.size
+
+        loss, grads = jax.value_and_grad(global_loss)(params)
+        return loss, jax.tree.map(lambda p, g: p - 0.3 * g, params, grads)
+
+    def recipe(params, tokens):
+        from jax.sharding import PartitionSpec as P
+
+        loss, stacked = mpi.run_spmd(recipe_body, mesh=mesh, axis_name="mpi",
+                                     jit=False)(params, tokens)
+        return loss, jax.shard_map(
+            lambda tree: jax.tree.map(lambda a: a[0], tree), mesh=mesh,
+            in_specs=P("mpi"), out_specs=P(), check_vma=False)(stacked)
+
+    with jax.enable_x64(False):
+        ours = program.build_train_step(tcfg, mesh, 2, 0.3, True).lower(
+            params, tokens).compile()
+        theirs = jax.jit(recipe, donate_argnums=(0,)).lower(
+            params, tokens).compile()
+    strip = lambda c: re.sub(r"/\*.*?\*/", "", c.as_text())
+    loss_bytes = 16                       # the loss, whatever it rides with
+    assert p_bytes <= _all_reduced_bytes(strip(ours)) <= p_bytes + loss_bytes
+    assert 2 * p_bytes <= _all_reduced_bytes(strip(theirs)) \
+        <= 2 * p_bytes + loss_bytes
+    fell = theirs.memory_analysis().temp_size_in_bytes \
+        - ours.memory_analysis().temp_size_in_bytes
+    print(f"parameters {p_bytes / 1e6:.1f} MB; temporaries "
+          f"{theirs.memory_analysis().temp_size_in_bytes / 1e6:.1f} -> "
+          f"{ours.memory_analysis().temp_size_in_bytes / 1e6:.1f} MB")
+    # 72 of 109 MB here, where some of the averaged copy shared its room
+    # with later temporaries; 2.18 of 2.27 GB at the cell's size (PERF.md)
+    assert 0.5 * p_bytes <= fell <= 1.1 * p_bytes
 
 
 def _ragged_dots(text: str):
